@@ -264,7 +264,7 @@ class Config:
     # module-global None check, zero gauges, wire byte-identical.
     # device_platform is the INTENDED jax platform ("tpu"/"gpu"/...);
     # when set, the sentinel convicts any run whose backend initialized
-    # as something else (the BENCH_r05 silent-CPU class, live).
+    # as something else (the silent-CPU class, live).
     devprof: bool = False                # BYTEPS_TPU_DEVPROF
     device_platform: str = ""            # BYTEPS_TPU_DEVICE_PLATFORM
 

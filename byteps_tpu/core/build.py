@@ -80,16 +80,41 @@ def _san_flags() -> list:
     return flags
 
 
-def build(force: bool = False, verbose: bool = False) -> str:
-    """Compile the native core if needed; returns the .so path.
+class BuildError(RuntimeError):
+    """g++ ran and refused the sources; the message carries its stderr."""
 
-    Raises CalledProcessError on compile failure (callers fall back to the
-    pure-Python implementation in that case).
-    """
+
+def _compile(cmd: list, out: str, verbose: bool = False) -> str:
+    """Run `cmd -o <temporary name>` and `os.replace` the result onto
+    `out`: the server child and the worker may both build at first
+    import, and neither may ever dlopen a half-written file.
+
+    A missing toolchain raises FileNotFoundError (the one case
+    core/native.py answers with the Python core); a compile error raises
+    BuildError with the compiler's own message."""
+    tmp = f"{out}.{os.getpid()}.tmp"
+    cmd = [*cmd, "-o", tmp]
+    if verbose:
+        print(" ".join(cmd), file=sys.stderr)
+    try:
+        r = subprocess.run(cmd, capture_output=True, text=True)
+        if r.returncode != 0:
+            raise BuildError(
+                f"{' '.join(cmd)} failed (rc={r.returncode}):\n{r.stderr}")
+        if verbose and r.stderr:
+            sys.stderr.write(r.stderr)
+        os.replace(tmp, out)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+    return out
+
+
+def build(force: bool = False, verbose: bool = False) -> str:
+    """Compile the native core if needed; returns the .so path."""
     if not force and not _needs_build():
         return lib_path()
-    srcs = [os.path.join(_CORE_DIR, s) for s in _SOURCES
-            if os.path.exists(os.path.join(_CORE_DIR, s))]
+    srcs = [os.path.join(_CORE_DIR, s) for s in _SOURCES]
     # -O3: the wire-codec inner loops (onebit expand, dense level
     # gather) only vectorize at -O3; measured ~2x on the codec micros
     # with no change anywhere else.  -ffp-contract=off: the codec's
@@ -97,15 +122,9 @@ def build(force: bool = False, verbose: bool = False) -> str:
     # numpy's two-step rounding for mu*m + x — on FMA-baseline targets
     # (aarch64) -O3 would otherwise legally contract it to fmadd and
     # drift the two paths.
-    cmd = [
-        "g++", "-O3", "-ffp-contract=off", "-std=c++17", "-shared",
-        "-fPIC", "-pthread", "-fvisibility=hidden", "-o", lib_path(),
-        *srcs,
-    ]
-    if verbose:
-        print(" ".join(cmd), file=sys.stderr)
-    subprocess.run(cmd, check=True, capture_output=not verbose)
-    return lib_path()
+    cmd = ["g++", "-O3", "-ffp-contract=off", "-std=c++17", "-shared",
+           "-fPIC", "-pthread", "-fvisibility=hidden", *srcs]
+    return _compile(cmd, lib_path(), verbose)
 
 
 if __name__ == "__main__":
@@ -130,6 +149,5 @@ def build_server_exe(force: bool = False) -> str:
             and os.path.getmtime(out) >= os.path.getmtime(src):
         return out
     cmd = ["g++", *_san_flags(), "-O3", "-ffp-contract=off", "-std=c++17",
-           "-pthread", "-DBPS_SERVER_MAIN", "-o", out, src]
-    subprocess.run(cmd, check=True, capture_output=True)
-    return out
+           "-pthread", "-DBPS_SERVER_MAIN", src]
+    return _compile(cmd, out)

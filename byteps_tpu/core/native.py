@@ -2,9 +2,10 @@
 
 The reference binds its C++ core to Python per-framework via pybind11/ctypes
 (reference: byteps/common/__init__.py:52-77 dlopens c_lib).  pybind11 is not
-available in this image, so we use a flat C ABI + ctypes.  If the toolchain is
-missing or the build fails we degrade to `_PyCore`, a behaviorally identical
-Python implementation — everything stays usable, just without native speed.
+available in this image, so we use a flat C ABI + ctypes.  On a host with no
+toolchain we degrade to `_PyCore`, a behaviorally identical Python
+implementation — everything stays usable, just without native speed.  A
+compile error is not that case: it raises.
 """
 
 from __future__ import annotations
@@ -425,19 +426,22 @@ _core_lock = threading.Lock()
 
 
 def get_core():
-    """Returns the process-wide core (native if buildable, Python otherwise)."""
+    """Returns the process-wide core: native, or the Python mirror on a
+    host with no toolchain.  A compile *error* is not that case and
+    propagates (build.BuildError carries g++'s message)."""
     global _core
     with _core_lock:
         if _core is None:
+            from . import build
             try:
-                from . import build
                 path = build.build()
-                _core = _CCore(ctypes.CDLL(path))
-                get_logger().debug("loaded native core from %s", path)
-            except Exception as e:  # toolchain missing / build failure
+            except FileNotFoundError as e:  # no g++ on this host
                 get_logger().warning(
                     "native core unavailable (%s); using Python fallback", e)
                 _core = _PyCore()
+            else:
+                _core = _CCore(ctypes.CDLL(path))
+                get_logger().debug("loaded native core from %s", path)
         return _core
 
 

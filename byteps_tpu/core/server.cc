@@ -61,6 +61,7 @@
 #include <cstring>
 #include <deque>
 #include <map>
+#include <memory>
 #include <mutex>
 #include <queue>
 #include <set>

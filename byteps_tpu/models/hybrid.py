@@ -42,8 +42,6 @@ import optax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..common.compat import axis_size as _axis_size
-from ..common.compat import shard_map as _shard_map
 from ..ops.ring_attention import ring_attention_shard
 from ..parallel import pipeline as pp_mod
 from ..parallel import tensor_parallel as tp_mod
@@ -277,8 +275,8 @@ def build_hybrid_train_step(
         # Normalize by the GLOBAL token count; mask to the last pp stage so
         # psum over pp double-counts neither the head path nor the input
         # path of the shared embedding.
-        denom = (B * _axis_size("dp") * _axis_size("ep")
-                 * S * _axis_size("sp"))
+        denom = (B * jax.lax.axis_size("dp") * jax.lax.axis_size("ep")
+                 * S * jax.lax.axis_size("sp"))
         loss = nll_sum / denom
         # Mask the token loss to the last pp stage so psum over pp
         # double-counts neither the head path nor the input path of the
@@ -290,8 +288,8 @@ def build_hybrid_train_step(
             # Mean aux over layers and over the (dp, ep, sp) shards — the
             # final psum over those axes turns the per-shard term into the
             # cross-shard mean.
-            shards = (_axis_size("dp") * _axis_size("ep")
-                      * _axis_size("sp"))
+            shards = (jax.lax.axis_size("dp") * jax.lax.axis_size("ep")
+                      * jax.lax.axis_size("sp"))
             loss = loss + cfg.aux_loss_weight * aux / (
                 cfg.num_layers * shards)
         return loss
@@ -330,7 +328,7 @@ def build_hybrid_train_step(
         def slice_dp(x, ax):
             if ax < 0:
                 return x
-            n = _axis_size("dp")
+            n = jax.lax.axis_size("dp")
             size = x.shape[ax] // n
             return lax.dynamic_slice_in_dim(
                 x, lax.axis_index("dp") * size, size, ax)
@@ -389,7 +387,7 @@ def build_hybrid_train_step(
                 dp_axes = jax.tree.map(dp_axis_of, specs, p_up,
                                        is_leaf=_is_spec)
                 o_specs = opt_state_specs(optimizer, params, p_up)
-                sm = _shard_map(
+                sm = jax.shard_map(
                     make_sm_step(make_grad_sync(dp_axes),
                                  make_update_leg(dp_axes)), mesh=mesh,
                     in_specs=(specs, o_specs, (batch_spec, batch_spec)),

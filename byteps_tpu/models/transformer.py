@@ -70,10 +70,10 @@ class TransformerConfig:
     # (measurements: docs/performance.md).
     remat_policy: str = "none"    # "none" | "dots" | "dots_no_batch" | "proj"
     attn_impl: str = "dense"           # "dense" | "flash" | "ring" (sp)
-    # Flash-kernel block size override (0 = flash_auto_block's measured
-    # rule: full-sequence at S <= 512, largest of 512/256/128/64 dividing
-    # S beyond).  Larger blocks at
-    # short S mean fewer, fatter kernel programs; must divide seq_len.
+    # Flash-kernel block size override (0 = flash_auto_block's rule:
+    # full-sequence at S <= 512, largest of 512/256/128 dividing S
+    # beyond).  Larger blocks at short S mean fewer, fatter kernel
+    # programs; must divide seq_len and be a multiple of 128.
     attn_block: int = 0
     # K/V tile override (0 = same as attn_block).  Decoupling lets long-S
     # sweeps trade per-iteration VMEM / causal masked waste (K tile)
@@ -84,7 +84,7 @@ class TransformerConfig:
     # materialized (forward OR backward — each chunk is rematerialised).
     # 0 = classic path through full logits.  At bert_large bench scale the
     # full f32 logits are 3.2 GB and their HBM traffic is the largest
-    # non-matmul cost in the step (round-3 profiling).
+    # non-matmul cost in the step.
     ce_chunk_rows: int = 0
     # Unroll factor for the layer scan (lax.scan unroll=).  > 1 groups
     # that many layers per scan iteration: more code, but XLA can
@@ -331,70 +331,56 @@ def dense_attention(q, k, v, causal: bool):
 def flash_auto_block(S: int) -> int:
     """The flash adapter's auto block-size rule, exported so records (e.g.
     bench.py's JSON detail) can state the block that actually runs without
-    duplicating the logic.  Returns 0 when no valid block exists (S not
-    divisible by 64).
+    duplicating the logic.  Returns 0 when no valid block exists: the
+    kernel's Q tile must be a multiple of 128 (ops/flash_attention.py
+    `check_blocks`), so S must be one.
 
-    S <= 512: the full sequence as one block (any multiple of 64 divides
-    itself) — measured on a v5e chip at BERT-large geometry, S=512, batch
-    48: block 512 = 33.7k tok/s vs 31.0k (256) vs 27.0k (128), i.e. the
-    old fixed-128 choice left 25% on the table
-    (bench_runs/r04_sweep1.jsonl); per-program VMEM stays small (block x
-    block f32 logits at 512 is 1 MB).  S > 512: the largest of
-    512/256/128/64 that divides S — the long-context regime was
-    re-measured on-chip at llama_300m S=2048 batch 8 (causal, f32-tile
-    kernel): block 512 = 27.0k tok/s vs 20.7k (256) vs 15.4k (128), so
-    the old 128 tile left 75% on the table; the extra masked compute on
-    causal diagonal blocks is far outweighed by fewer, fatter programs
-    (bench_runs/r04_sweep5{,b}.jsonl).  Caveat: measured at S=2048 on
-    the plain single-chip path; at gathered-sequence lengths (the
-    strict ring/Ulysses path, S >= 8k) the 512 preference is an
-    extrapolation — the relative diagonal waste only shrinks with S,
-    but it is unmeasured there (S=8192 A/B queued in tools/mfu_sweep.py;
-    attn_block=128 restores the old tile per-config if it regresses)."""
+    S <= 512: the full sequence as one block; per-program VMEM stays
+    small (block x block f32 logits at 512 is 1 MB).  S > 512: the largest
+    of 512/256/128 that divides S — fewer, fatter programs outweigh the
+    extra masked compute on causal diagonal blocks.  Which tile is fastest
+    on the chip is not measured in this round's ledger; attn_block pins
+    another tile per-config."""
+    if S % 128:
+        return 0
     if S <= 512:
-        return S if S % 64 == 0 else 0
-    for b in (512, 256, 128, 64):
-        if S % b == 0:
-            return b
-    return 0
+        return S
+    return next(b for b in (512, 256, 128) if S % b == 0)
 
 
-def flash_attention_fn(q, k, v, causal: bool, strict: bool = False,
-                       block: int = 0, block_k: int = 0):
+def flash_attention_fn(q, k, v, causal: bool, block: int = 0,
+                       block_k: int = 0):
     """Adapter: [B, H, S, Dh] heads-layout -> the Pallas flash-attention
-    kernel's [BH, S, Dh] layout, with automatic fallback to dense attention
-    when the shape doesn't meet the kernel's tiling constraints (S must
-    divide into 64- or 128-row blocks; Dh a multiple of 8).  strict=True
-    raises instead of falling back — for callers where silent dense
-    attention would materialize S x S logits at a length chosen precisely
-    to avoid that (e.g. Ulysses long-context).
+    kernel's [BH, S, Dh] layout.  A shape the kernel cannot tile (S not a
+    multiple of 128, or Dh not a multiple of 8) raises ValueError: an
+    explicit flash request never silently runs dense attention, which
+    would materialize the S x S logits the caller chose flash to avoid
+    and attribute dense throughput to a flash config.
 
     block=0 auto-selects via `flash_auto_block` (full-sequence block at
-    S <= 512, the largest of 512/256/128/64 dividing S beyond — both
-    regimes measured on-chip; see its docstring for the evidence).  A nonzero
+    S <= 512, the largest of 512/256/128 dividing S beyond).  A nonzero
     override trades grid-iteration overhead against VMEM per program by
     hand (TransformerConfig.attn_block / BENCH_ATTN_BLOCK sweep it
     on-chip); `block_k` additionally decouples the K/V tile from the Q
     tile (TransformerConfig.attn_block_k) — at long S the Q tile sets
     program count while the K tile sets per-iteration VMEM and masked
     waste on causal diagonals, and the optimum need not be square.
-    Overrides must divide S and be a multiple of 64 (the row-tile sizes
-    the kernel guarantees); anything else reverts to the AUTO choice —
-    never to dense, so a sweep value can't silently attribute dense
-    throughput to a flash config."""
+    Overrides must divide S and be a multiple of 128 (block) or 64
+    (block_k), the tile sizes the chip's compiler accepts; anything else
+    reverts to the AUTO choice."""
+    from ..ops.flash_attention import (BLOCK_K_MULTIPLE, BLOCK_Q_MULTIPLE,
+                                       flash_attention)
     B, H, S, Dh = q.shape
-    if not block or S % block or block % 64:
+    if not block or S % block or block % BLOCK_Q_MULTIPLE:
         block = flash_auto_block(S)
-    if not block_k or S % block_k or block_k % 64:
+    if not block_k or S % block_k or block_k % BLOCK_K_MULTIPLE:
         block_k = block
     if block == 0 or Dh % 8:
-        if strict:
-            raise ValueError(
-                f"flash attention needs seq_len divisible by 64 (got {S}) "
-                f"and head_dim a multiple of 8 (got {Dh}); pad the "
-                f"sequence or drop to attn='dense' explicitly")
-        return dense_attention(q, k, v, causal)
-    from ..ops.flash_attention import flash_attention
+        raise ValueError(
+            f"flash attention needs seq_len divisible by "
+            f"{BLOCK_Q_MULTIPLE} (got {S}) and head_dim a multiple of 8 "
+            f"(got {Dh}); pad the sequence or ask for attn='dense' "
+            f"explicitly")
 
     def fold(t):
         return t.reshape(B * H, S, Dh)

@@ -32,8 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..common.compat import axis_size as _axis_size
-
 from ..common.config import get_config
 
 PyTree = Any
@@ -62,7 +60,7 @@ def is_local() -> bool:
 
 
 def axis_size(axis_name: str) -> int:
-    return 1 if is_local() else _axis_size(axis_name)
+    return 1 if is_local() else jax.lax.axis_size(axis_name)
 
 
 # ---------------------------------------------------------------------------
@@ -88,7 +86,7 @@ def reduce_scatter(x: jax.Array, axis_name: str = "dp",
 
 def ring_permute(x: jax.Array, axis_name: str, shift: int = 1) -> jax.Array:
     """Neighbor exchange on the ring — building block for ring attention."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
 

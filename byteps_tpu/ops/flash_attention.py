@@ -32,10 +32,12 @@ attention kernels of its own); a TPU-native framework owns its hot ops
 custom-VJP pattern).
 
 Layout: q, k, v are [BH, S, D] (batch*heads folded into the grid's first
-axis).  The block sizes must divide S; D should be a multiple of 8 (128
-ideal for the MXU lane).  Callers that don't satisfy the constraints
-should fall back to dense attention — `models.transformer.
-flash_attention_fn` does exactly that.
+axis).  The block sizes must divide S; block_q must be a multiple of 128
+and block_k a multiple of 64 (`check_blocks` — the chip's lane rule, which
+interpret mode does not enforce); D should be a multiple of 8 (128 ideal
+for the MXU lane).  A shape that doesn't satisfy the constraints is
+refused up front, on every backend: the kernel never degrades to dense
+attention, and neither does `models.transformer.flash_attention_fn`.
 """
 
 from __future__ import annotations
@@ -54,6 +56,29 @@ NEG_INF = float("-inf")
 # K+V (resident path) above this many bytes switch to the streaming path;
 # ~16MB VMEM/core on current TPUs, leave room for q/o/do tiles + scratch.
 RESIDENT_VMEM_BUDGET = 6 * 1024 * 1024
+
+
+# The Q tile is the LANE dim of the per-row statistics blocks (log-sum-exp
+# and delta, shape (1, 1, block_q)), and the TPU lowering takes a block's
+# last dim only in multiples of 128.  Mosaic also refuses the resident
+# dK/dV kernel's lane-dim slices of those rows unless they are provably
+# 128-aligned, so "block_q == S" does not rescue an S that is an odd
+# multiple of 64.  K/V tiles only ever sit on a sublane dim.  Both facts
+# are from compiling for a described v5e (tests/test_tpu_aot_compile.py).
+BLOCK_Q_MULTIPLE = 128
+BLOCK_K_MULTIPLE = 64
+
+
+def check_blocks(s: int, block_q: int, block_k: int) -> None:
+    """Raise ValueError unless (block_q, block_k) tile a length-`s`
+    sequence in a way the chip's compiler accepts."""
+    if (block_q <= 0 or block_k <= 0 or s % block_q or s % block_k
+            or block_q % BLOCK_Q_MULTIPLE or block_k % BLOCK_K_MULTIPLE):
+        raise ValueError(
+            f"flash attention cannot tile seq_len {s} with "
+            f"block_q={block_q}, block_k={block_k}: both must divide the "
+            f"sequence, block_q must be a multiple of {BLOCK_Q_MULTIPLE} "
+            f"and block_k a multiple of {BLOCK_K_MULTIPLE}")
 
 
 def _use_interpret(interpret: Optional[bool]) -> bool:
@@ -445,11 +470,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                streaming):
     bh, s, d = q.shape
-    if s % block_q or s % block_k:
-        raise ValueError(
-            f"seq_len {s} must divide block_q={block_q}, block_k={block_k}"
-            " — use models.transformer.flash_attention_fn for the"
-            " auto-fallback to dense attention")
+    check_blocks(s, block_q, block_k)
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     out, lse = _fwd(q, k, v, scale, causal, block_q, block_k,
                     _use_interpret(interpret), _use_streaming(q, streaming))

@@ -23,9 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..common.compat import axis_size as _axis_size
-from ..common.compat import shard_map as _shard_map
-
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 
@@ -56,7 +53,7 @@ def ring_attention_shard(q, k, v, causal: bool, axis_name: str = "sp"):
     q,k,v: [B, H, S_local, D] — this device's sequence block along a ring of
     `axis_size(axis_name)` devices.  Returns [B, H, S_local, D].
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
     B, H, S, D = q.shape
 
@@ -105,8 +102,8 @@ def make_ring_attn_fn(mesh: Mesh, axis_name: str = "sp"):
     def attn_fn(q, k, v, causal):
         f = functools.partial(ring_attention_shard, causal=causal,
                               axis_name=axis_name)
-        return _shard_map(f, mesh=mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec, check_vma=False)(q, k, v)
+        return jax.shard_map(f, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)(q, k, v)
     return attn_fn
 
 
@@ -124,7 +121,7 @@ def ulysses_attention_shard(q, k, v, causal: bool, axis_name: str = "sp",
     better for moderate n on all-to-all-capable fabrics; requires
     num_heads % n == 0.
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
 
     def seq_to_heads(x):
         # [B, H, S/n, D] -> [B, H/n, S, D]
@@ -165,15 +162,10 @@ def make_ulysses_attn_fn(mesh: Mesh, axis_name: str = "sp", attn="dense"):
                 f"attn must be a callable or one of "
                 f"{sorted(_tfm._ATTN_IMPLS)}; got {attn!r}")
         inner = _tfm._ATTN_IMPLS[attn]
-        if attn == "flash":
-            # An explicit flash request at gathered-sequence length must
-            # not silently degrade to dense (that materializes the S x S
-            # logits this pairing exists to avoid).
-            inner = functools.partial(_tfm.flash_attention_fn, strict=True)
 
     def attn_fn(q, k, v, causal):
         f = functools.partial(ulysses_attention_shard, causal=causal,
                               axis_name=axis_name, attn=inner)
-        return _shard_map(f, mesh=mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec, check_vma=False)(q, k, v)
+        return jax.shard_map(f, mesh=mesh, in_specs=(spec, spec, spec),
+                             out_specs=spec, check_vma=False)(q, k, v)
     return attn_fn

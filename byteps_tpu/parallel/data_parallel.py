@@ -31,7 +31,6 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..common import devprof
-from ..common.compat import shard_map as _shard_map
 from ..common.config import get_config
 from ..ops import collectives
 from ..ops.compression import Compression, Compressor
@@ -341,7 +340,7 @@ def build_train_step(
         key = (jax.tree.structure(params), jax.tree.structure(opt_state))
         if key not in cache:
             state_specs = _opt_state_specs(opt_state, axis_name)
-            sm = _shard_map(
+            sm = jax.shard_map(
                 _step, mesh=mesh, in_specs=(P(), state_specs, batch_spec),
                 out_specs=(P(), state_specs, P()), check_vma=False)
             cache[key] = jax.jit(sm, donate_argnums=donate_argnums)

@@ -17,9 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..common.compat import axis_size as _axis_size
-from ..common.compat import shard_map as _shard_map
-
 PyTree = Any
 
 
@@ -76,7 +73,7 @@ def moe_core(gate_w: jax.Array, ffn_in: jax.Array, ffn_out: jax.Array,
     Shared by the standalone moe_layer and the hybrid model's FFN so the
     dispatch/capacity logic exists exactly once.
     """
-    world = _axis_size(axis_name)
+    world = jax.lax.axis_size(axis_name)
     e_local = ffn_in.shape[0]
     E = e_local * world
     T = x.shape[0]
@@ -125,7 +122,7 @@ def moe_layer(params: PyTree, x: jax.Array, mesh, capacity_factor: float = 2.0,
 
     f = functools.partial(moe_layer_shard, capacity_factor=capacity_factor,
                           axis_name=axis_name)
-    return _shard_map(
+    return jax.shard_map(
         f, mesh=mesh,
         in_specs=(specs, P(axis_name, None)),
         out_specs=(P(axis_name, None), P()),

@@ -10,9 +10,7 @@ slice talks across slices over DCN.  This module is that composition for
 the PS tier:
 
   1. the workers of one slice reduce their gradients in-graph — a
-     ``psum`` under ``shard_map`` on the slice's device mesh (routed
-     through :mod:`byteps_tpu.common.compat`, so both JAX spellings
-     work);
+     ``psum`` under ``shard_map`` on the slice's device mesh;
   2. exactly ONE leader per slice runs the wire ``push_pull`` (riding
      the existing fusion planner and ``PSSession.push_pull_group``
      unchanged — the server sums the per-slice sums, which equals the
@@ -122,22 +120,18 @@ def _psum_fn(mesh):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from ..common import compat
-
     axis = mesh.axis_names[-1]
 
     def body(x):
         return jax.lax.psum(x, axis)
 
-    return jax.jit(compat.shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=P(axis), out_specs=P()))
 
 
 def intra_slice_psum(stacked: np.ndarray, mesh=None) -> np.ndarray:
     """Sum ``stacked`` (members, n) over axis 0 IN-GRAPH: one member row
-    per device of the slice mesh, reduced by ``psum`` under ``shard_map``
-    (through the compat shims, so both the ``jax.shard_map`` and the
-    0.4.x ``jax.experimental.shard_map`` spellings work).
+    per device of the slice mesh, reduced by ``psum`` under ``shard_map``.
 
     Falls back to a deterministic host sum (ascending member order) when
     the process has fewer addressable devices than members — the values

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..common.compat import axis_size as _axis_size
-
 PyTree = Any
 
 
@@ -48,7 +46,7 @@ def gpipe_spmd(
     stage 0's embedding feed; summing across ranks happens in the caller's
     final loss psum.
     """
-    P = _axis_size(axis_name)
+    P = jax.lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     M = num_microbatches
     B = x.shape[0]

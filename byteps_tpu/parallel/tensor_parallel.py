@@ -17,8 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..common.compat import axis_size as _axis_size
-
 
 def col_parallel_dense(x: jax.Array, w_local: jax.Array,
                        b_local: jax.Array = None) -> jax.Array:
@@ -91,7 +89,7 @@ def reduce_from(axis_name: str):
 def tp_split(x: jax.Array, axis: int, axis_name: str = "tp") -> jax.Array:
     """Slice the local chunk of a replicated array along `axis` (activation
     entering a row-parallel layer)."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     size = x.shape[axis] // n
     return lax.dynamic_slice_in_dim(x, idx * size, size, axis)

@@ -16,10 +16,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from byteps_tpu.ops import collectives
 
-from byteps_tpu.common.compat import shard_map as _compat_shard_map
 
 def _shmap(f, mesh, in_specs, out_specs):
-    return jax.jit(_compat_shard_map(f, mesh=mesh, in_specs=in_specs,
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
                                  out_specs=out_specs, check_vma=False))
 
 
@@ -150,7 +149,7 @@ def test_hierarchical_all_reduce_matches_global_sum():
         local = xs.reshape(-1)  # this device's (1,16) slice flattened
         return collectives.hierarchical_all_reduce(local, "ici_dp", "dcn_dp")
 
-    out = jax.jit(_compat_shard_map(
+    out = jax.jit(jax.shard_map(
         step, mesh=mesh, in_specs=(P(("dcn_dp", "ici_dp")),), out_specs=P(),
         check_vma=False))(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(x.sum(0)),
@@ -168,7 +167,7 @@ def test_hierarchical_tree_all_reduce():
         return collectives.hierarchical_tree_all_reduce(
             local, average=True, partition_bytes=128)
 
-    out = jax.jit(_compat_shard_map(
+    out = jax.jit(jax.shard_map(
         step, mesh=mesh, in_specs=(P(("dcn_dp", "ici_dp")),), out_specs=P(),
         check_vma=False))(stacked)
     expect = jax.tree.map(lambda *xs: sum(xs) / 8, *trees)

@@ -17,7 +17,6 @@ import pytest
 import byteps_tpu as bps
 from byteps_tpu.ops import compressor as C
 
-from byteps_tpu.common.compat import shard_map as _compat_shard_map
 
 # ---------------------------------------------------------------------------
 # Independent numpy replicas (no imports from the package internals).
@@ -232,7 +231,7 @@ def _run_compressed_allreduce(tree, comp, mesh, **kw):
 
     state = C.init_compression_state(tree, comp)
 
-    @functools.partial(_compat_shard_map, mesh=mesh, in_specs=(P(), P()),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(P(), P()),
                        out_specs=(P(), P()), check_vma=False)
     def f(t, st):
         return C.compressed_tree_all_reduce(t, comp, st, axis_name="dp", **kw)
@@ -402,7 +401,7 @@ def test_tiny_buckets_skip_expanding_compression(mesh8):
         out, _ = compressed_tree_all_reduce(t, comp, average=False)
         return out
 
-    sm = _jax.jit(_compat_shard_map(f, mesh=mesh8, in_specs=(P(),),
+    sm = _jax.jit(jax.shard_map(f, mesh=mesh8, in_specs=(P(),),
                                  out_specs=P(), check_vma=False))
     out = sm(tree)
     # raw path: exact sum (no sign quantization error at all)

@@ -230,23 +230,28 @@ def test_sentinel_host_only_with_intent_stays_quiet(monkeypatch):
     assert probe["fallback"] is False       # nothing to convict yet
 
 
-def test_sentinel_wedge_convicts_and_rate_limits_tunnel(monkeypatch):
+def test_sentinel_probe_error_convicts_without_a_child(monkeypatch):
+    """A probe error convicts on the stamp alone.  The sentinel starts no
+    child process: a chip belongs to one process, so a child probing the
+    default backend while this one holds the chip could only fail or
+    hang."""
+    import subprocess
     monkeypatch.setattr(devprof, "device_stamp",
                         lambda: {"device_platform": "unknown(boom)",
                                  "device_fallback": True})
-    calls = []
-    monkeypatch.setattr(devprof, "tunnel_alive",
-                        lambda timeout=120.0: calls.append(1) is None
-                        and False)
+
+    def no_child(*a, **kw):
+        raise AssertionError("the sentinel must not start a process")
+
+    monkeypatch.setattr(subprocess, "Popen", no_child)
+    monkeypatch.setattr(subprocess, "run", no_child)
     prof = DeviceProfiler(intended_platform="tpu")
-    probe = prof.probe()
-    assert probe["fallback"] is True
-    assert probe["reason"].startswith("device probe failed")
-    assert probe["tunnel_alive"] is False
-    # Second probe inside TUNNEL_PROBE_MIN_S reuses the cached verdict.
-    probe2 = prof.probe()
-    assert probe2["tunnel_alive"] is False
-    assert len(calls) == 1
+    for _ in range(2):
+        probe = prof.probe()
+        assert probe["fallback"] is True
+        assert probe["reason"].startswith("device probe failed")
+        assert set(probe) == {"platform", "intended", "fallback",
+                              "reason", "stamp_fallback"}
 
 
 # ---------------------------------------------------------------------------

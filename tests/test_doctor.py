@@ -380,12 +380,10 @@ def test_replication_lag_boundary():
 
 
 def _dev(mfu=None, fallback=False, reason="", platform="cpu",
-         intended="", tunnel=None, **extra):
+         intended="", **extra):
     """One window's device section (devprof.window_roll shape)."""
     probe = {"platform": platform, "intended": intended,
              "fallback": fallback, "reason": reason}
-    if tunnel is not None:
-        probe["tunnel_alive"] = tunnel
     d = {"schema": "bps-device-v1", "probe": probe, "platform": platform,
          "steps": 10, "compute_s": 1.0, "device_step_ms": 100.0,
          "mfu": mfu}
@@ -401,8 +399,8 @@ def _wire_keys(wire_s):
 
 def test_device_fallback_boundary():
     """The sentinel's conviction (ISSUE 20): a convicting probe fires
-    from the FIRST window (gauge-snapshot law — the BENCH_r05 silent-CPU
-    class must not wait for persistence); a healthy probe, an intended
+    from the FIRST window (gauge-snapshot law — the silent-CPU class
+    must not wait for persistence); a healthy probe, an intended
     platform that matches, or no device section at all stay quiet."""
     hot = [W(0, **_dev(fallback=True, platform="cpu", intended="tpu",
                        reason="intended platform 'tpu' but the jax "
@@ -423,13 +421,14 @@ def test_device_fallback_boundary():
         [W(0, **_dev(platform="cpu"))])
     # No device section (devprof unarmed / pre-devprof bundle): quiet.
     assert "device_fallback" not in rules_fired([W(0)])
-    # The wedge path's tunnel corroboration lands in the message.
-    wedged = doctor.evaluate_stream([W(0, **_dev(
+    # A probe error convicts with its reason in the message, on the
+    # probe's own evidence (no second process is ever consulted).
+    errored = doctor.evaluate_stream([W(0, **_dev(
         fallback=True, platform="unknown(RuntimeError('dead'))",
-        reason="device probe errored", tunnel=False))])
-    f = next(x for x in wedged["open"] if x["rule"] == "device_fallback")
-    assert "tunnel" in f["summary"]
-    assert f["evidence"]["tunnel_alive"] is False
+        reason="device probe errored"))])
+    f = next(x for x in errored["open"] if x["rule"] == "device_fallback")
+    assert "device probe errored" in f["summary"]
+    assert set(f["evidence"]) == {"platform", "intended", "reason"}
 
 
 def test_mfu_regression_boundary():

@@ -1,15 +1,15 @@
 """Pallas flash-attention kernel: parity with dense attention (fwd + bwd).
 
-Runs in the Pallas interpreter on the CPU mesh; the same kernel compiles
-for TPU (measured there: ~1.6x over XLA dense attention at S=4096,
-docs/performance.md)."""
+Runs in the Pallas interpreter on the CPU mesh, which enforces none of
+the chip's tiling rules — so every block used here is one the chip's
+compiler accepts too (tests/test_tpu_aot_compile.py compiles the same
+kernel for a described v5e)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from byteps_tpu.common.compat import shard_map as _compat_shard_map
 from byteps_tpu.models.transformer import dense_attention, \
     flash_attention_fn
 from byteps_tpu.ops.flash_attention import flash_attention
@@ -26,7 +26,7 @@ def _ref(q, k, v, causal):
 @pytest.mark.parametrize("causal", [False, True])
 @pytest.mark.parametrize("bh,s,d,bq,bk", [
     (4, 256, 64, 128, 128),
-    (2, 256, 64, 64, 128),     # uneven q/k blocks
+    (2, 256, 64, 128, 256),    # uneven q/k blocks
     (1, 512, 128, 128, 64),
 ])
 def test_forward_parity(causal, bh, s, d, bq, bk):
@@ -72,64 +72,82 @@ def test_bf16_inputs():
                                np.asarray(want), atol=2e-2)
 
 
-def test_rejects_misaligned_seq():
-    q = jnp.zeros((1, 200, 64))
-    with pytest.raises(ValueError, match="must divide"):
-        flash_attention(q, q, q, False, None, 128, 128, True)
+@pytest.mark.parametrize("s,bq,bk", [
+    (200, 128, 128),    # blocks don't divide S
+    (512, 64, 64),      # 64-row Q tile: the chip's lane rule refuses it
+    (256, 64, 128),
+    (192, 192, 192),    # whole-axis Q tile that is not 128-aligned
+    (256, 128, 32),     # K tile below the 64 multiple
+])
+def test_rejects_blocks_the_chip_refuses(s, bq, bk):
+    """Refused up front, in interpret mode too — an interpret-mode pass
+    of a tile the chip's compiler rejects is the trap, not a feature."""
+    q = jnp.zeros((1, s, 64))
+    with pytest.raises(ValueError, match="cannot tile"):
+        flash_attention(q, q, q, False, None, bq, bk, True)
 
 
-def test_model_adapter_falls_back_on_bad_shapes():
+@pytest.mark.parametrize("shape", [
+    (2, 2, 100, 32),    # S=100: no 128 block divides it
+    (2, 2, 576, 32),    # odd multiple of 64: only the refused tile fits
+    (2, 2, 128, 12),    # head_dim not a multiple of 8
+])
+def test_model_adapter_never_silently_runs_dense(shape):
     """flash_attention_fn (the [B,H,S,D] adapter the transformer uses)
-    silently falls back to dense when S doesn't meet the tiling."""
-    rng = np.random.RandomState(3)
-    q = _rand(rng, 2, 2, 100, 32)  # S=100: no 64/128 block divides it
-    out = flash_attention_fn(q, q, q, causal=True)
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(dense_attention(q, q, q, True)),
-                               atol=1e-6)
+    raises on a shape the kernel can't tile: an explicit flash request
+    never degrades to dense attention."""
+    q = jnp.zeros(shape)
+    with pytest.raises(ValueError, match="divisible by 128"):
+        flash_attention_fn(q, q, q, causal=True)
 
 
 def test_block_override_parity():
     """An explicit block override (attn_block) must not change values; an
-    override that doesn't divide S falls back to the auto choice."""
+    override the kernel would refuse falls back to the auto choice."""
     rng = np.random.RandomState(7)
-    q = _rand(rng, 2, 2, 128, 32)
+    q = _rand(rng, 2, 2, 256, 32)
     base = flash_attention_fn(q, q, q, causal=True)
-    for blk in (64, 128):                     # valid overrides
+    for blk in (128, 256):                    # valid overrides
         out = flash_attention_fn(q, q, q, causal=True, block=blk)
         np.testing.assert_allclose(np.asarray(out), np.asarray(base),
                                    atol=1e-6)
-    for blk in (32, 96):  # not mult-of-64 / doesn't divide S -> AUTO block
+    for blk in (64, 96, 384):  # not mult-of-128 / doesn't divide S -> AUTO
         out = flash_attention_fn(q, q, q, causal=True, block=blk)
         np.testing.assert_allclose(np.asarray(out), np.asarray(base),
                                    atol=1e-6)
     # Threads through the model config
     from byteps_tpu.models import transformer as tfm
     cfg_b = tfm.get_config("tiny", causal=True, attn_impl="flash",
-                           attn_block=64)
-    cfg_f = tfm.get_config("tiny", causal=True, attn_impl="flash")
+                           attn_block=128, max_seq_len=256)
+    cfg_f = tfm.get_config("tiny", causal=True, attn_impl="flash",
+                           max_seq_len=256)
     params = tfm.init_params(jax.random.key(0), cfg_b)
-    batch = tfm.synthetic_batch(jax.random.key(1), 2, 128, cfg_b)
+    batch = tfm.synthetic_batch(jax.random.key(1), 2, 256, cfg_b)
     assert abs(float(tfm.loss_fn(params, batch, cfg_b))
                - float(tfm.loss_fn(params, batch, cfg_f))) < 1e-5
 
 
 def test_auto_block_rule():
-    """Pin the measured auto block-size policy (flash_auto_block
-    docstring carries the on-chip evidence): full-sequence block at
-    S <= 512, largest of 512/256/128/64 dividing S beyond, 0 when no
-    64-row block divides S."""
+    """Pin the auto block-size policy: full-sequence block at S <= 512,
+    largest of 512/256/128 dividing S beyond, 0 when S is not a multiple
+    of 128 (the only tile that would fit is the 64-row one the chip's
+    compiler refuses).  Every nonzero answer passes the kernel's own
+    check."""
     from byteps_tpu.models.transformer import flash_auto_block
-    assert flash_auto_block(64) == 64
+    from byteps_tpu.ops.flash_attention import check_blocks
+    assert flash_auto_block(128) == 128
     assert flash_auto_block(512) == 512
-    assert flash_auto_block(448) == 448      # mult of 64, <= 512
-    assert flash_auto_block(2048) == 512     # long-S: 512 tile wins
+    assert flash_auto_block(384) == 384      # mult of 128, <= 512
+    assert flash_auto_block(2048) == 512
     assert flash_auto_block(4096) == 512
     assert flash_auto_block(768) == 256      # 512 doesn't divide
     assert flash_auto_block(640) == 128
-    assert flash_auto_block(1088) == 64      # only 64 divides
+    for s in (64, 448, 576, 704, 1088):      # odd multiples of 64
+        assert flash_auto_block(s) == 0
     assert flash_auto_block(100) == 0        # no valid block
     assert flash_auto_block(1000) == 0
+    for s in range(128, 4097, 128):
+        check_blocks(s, flash_auto_block(s), flash_auto_block(s))
 
 
 def test_asymmetric_block_parity():
@@ -139,7 +157,7 @@ def test_asymmetric_block_parity():
     rng = np.random.RandomState(11)
     q = _rand(rng, 2, 2, 256, 32)
     base = flash_attention_fn(q, q, q, causal=True)
-    for bq, bk in ((128, 64), (64, 128), (256, 64)):
+    for bq, bk in ((128, 64), (128, 256), (256, 64)):
         out = flash_attention_fn(q, q, q, causal=True, block=bq,
                                  block_k=bk)
         np.testing.assert_allclose(np.asarray(out), np.asarray(base),
@@ -186,9 +204,9 @@ def test_flash_under_shard_map():
     q, k, v = (_rand(rng, 16, 128, 64) for _ in range(3))
 
     def f(q, k, v):
-        return flash_attention(q, k, v, True, None, 64, 64, True)
+        return flash_attention(q, k, v, True, None, 128, 64, True)
 
-    sm = jax.jit(_compat_shard_map(f, mesh=mesh,
+    sm = jax.jit(jax.shard_map(f, mesh=mesh,
                                in_specs=(P("dp"), P("dp"), P("dp")),
                                out_specs=P("dp"), check_vma=False))
     out = sm(q, k, v)
@@ -204,8 +222,8 @@ def test_streaming_path_parity(causal):
     rng = np.random.RandomState(5)
     q, k, v = (_rand(rng, 2, 256, 64) for _ in range(3))
     tgt = _rand(rng, 2, 256, 64)
-    stream = flash_attention(q, k, v, causal, None, 64, 64, True, True)
-    resident = flash_attention(q, k, v, causal, None, 64, 64, True, False)
+    stream = flash_attention(q, k, v, causal, None, 128, 64, True, True)
+    resident = flash_attention(q, k, v, causal, None, 128, 64, True, False)
     np.testing.assert_allclose(np.asarray(stream), np.asarray(resident),
                                atol=1e-6)
     np.testing.assert_allclose(np.asarray(stream),
@@ -215,7 +233,7 @@ def test_streaming_path_parity(causal):
     def loss(stream_flag):
         def f(q, k, v):
             return jnp.sum((flash_attention(
-                q, k, v, causal, None, 64, 64, True, stream_flag)
+                q, k, v, causal, None, 128, 64, True, stream_flag)
                 - tgt) ** 2)
         return f
 

@@ -14,7 +14,6 @@ import pytest
 import byteps_tpu as bps
 from byteps_tpu import models
 from byteps_tpu.models import transformer as tfm
-from byteps_tpu.common.compat import tree_flatten_with_path as _tree_flatten_with_path
 
 
 def test_transformer_forward_shapes():
@@ -143,7 +142,7 @@ def test_every_named_config_is_consistent():
         is_spec = lambda x: isinstance(x, jax.sharding.PartitionSpec)
         assert jax.tree.structure(shapes) == jax.tree.structure(
             specs, is_leaf=is_spec), name
-        for path, spec in _tree_flatten_with_path(
+        for path, spec in jax.tree.flatten_with_path(
                 specs, is_leaf=is_spec)[0]:
             leaf = shapes
             for p in path:

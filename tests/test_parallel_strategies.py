@@ -10,7 +10,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from byteps_tpu.parallel import expert, pipeline, tensor_parallel as tp
 
-from byteps_tpu.common.compat import shard_map as _compat_shard_map
 
 def _mesh(axes):
     sizes = {k: v for k, v in axes.items()}
@@ -36,7 +35,7 @@ def test_megatron_col_row_matches_dense():
         h = jax.nn.relu(tp.col_parallel_dense(x, w1l))
         return tp.row_parallel_dense(h, w2l, b2)
 
-    out = jax.jit(_compat_shard_map(
+    out = jax.jit(jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(), P(None, "tp"), P("tp", None), P()),
         out_specs=P(), check_vma=False))(x, w1, w2, b2)
@@ -51,7 +50,7 @@ def test_tp_split_gather_roundtrip():
     def f(x):
         return tp.tp_all_gather(tp.tp_split(x, axis=1), axis=1)
 
-    out = jax.jit(_compat_shard_map(f, mesh=mesh, in_specs=P(),
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(),
                                 out_specs=P(), check_vma=False))(x)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(x))
 
@@ -87,7 +86,7 @@ def test_gpipe_matches_sequential(num_microbatches):
         def inner(local_ws, x):
             return pipeline.gpipe_spmd(stage_fn, local_ws[0], x,
                                        num_microbatches)
-        return _compat_shard_map(inner, mesh=mesh,
+        return jax.shard_map(inner, mesh=mesh,
                              in_specs=(P("pp"), P()), out_specs=P(),
                              check_vma=False)(staged, x)
 
@@ -124,7 +123,7 @@ def test_gpipe_grads_match_sequential():
         def inner(local_ws, x):
             y = pipeline.gpipe_spmd(stage_fn, local_ws[0], x, 2)
             return (y ** 2).sum()
-        return _compat_shard_map(inner, mesh=mesh, in_specs=(P("pp"), P()),
+        return jax.shard_map(inner, mesh=mesh, in_specs=(P("pp"), P()),
                              out_specs=P(), check_vma=False)(staged, x)
 
     g_ref = jax.grad(seq_loss)(ws, x)

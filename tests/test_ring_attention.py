@@ -11,7 +11,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 from byteps_tpu.models.transformer import dense_attention
 from byteps_tpu.ops import ring_attention as ra
 
-from byteps_tpu.common.compat import shard_map as _compat_shard_map
 
 def _mesh_sp(n=8):
     return Mesh(np.array(jax.devices()[:n]), ("sp",))
@@ -29,7 +28,7 @@ def test_ring_attention_matches_dense(causal):
     expect = dense_attention(q, k, v, causal)
     spec = P(None, None, "sp", None)
     f = functools.partial(ra.ring_attention_shard, causal=causal)
-    out = jax.jit(_compat_shard_map(f, mesh=mesh, in_specs=(spec,) * 3,
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(spec,) * 3,
                                 out_specs=spec, check_vma=False))(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-5, atol=2e-5)
@@ -42,7 +41,7 @@ def test_ulysses_attention_matches_dense(causal):
     expect = dense_attention(q, k, v, causal)
     spec = P(None, None, "sp", None)
     f = functools.partial(ra.ulysses_attention_shard, causal=causal)
-    out = jax.jit(_compat_shard_map(f, mesh=mesh, in_specs=(spec,) * 3,
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(spec,) * 3,
                                 out_specs=spec, check_vma=False))(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-5, atol=2e-5)
@@ -71,7 +70,7 @@ def test_ring_attention_grads_flow():
 
     def loss(q, k, v):
         f = functools.partial(ra.ring_attention_shard, causal=True)
-        out = _compat_shard_map(f, mesh=mesh, in_specs=(spec,) * 3,
+        out = jax.shard_map(f, mesh=mesh, in_specs=(spec,) * 3,
                             out_specs=spec, check_vma=False)(q, k, v)
         return (out ** 2).sum()
 
@@ -91,7 +90,7 @@ def test_ulysses_rejects_bad_head_count():
     spec = P(None, None, "sp", None)
     with pytest.raises(ValueError, match="divisible"):
         f = functools.partial(ra.ulysses_attention_shard, causal=False)
-        _compat_shard_map(f, mesh=mesh, in_specs=(spec,) * 3,
+        jax.shard_map(f, mesh=mesh, in_specs=(spec,) * 3,
                       out_specs=spec, check_vma=False)(q, k, v)
 
 
@@ -140,5 +139,5 @@ def test_ulysses_with_flash_inner():
     # silently materializing the gathered S x S logits as dense.
     strict_fn = make_ulysses_attn_fn(mesh, attn="flash")
     bad = jnp.zeros((1, 8, 8 * 100, 32), jnp.float32)  # S/n=100 -> S=800?
-    with pytest.raises(ValueError, match="divisible by 64"):
+    with pytest.raises(ValueError, match="divisible by 128"):
         strict_fn(bad, bad, bad, False)

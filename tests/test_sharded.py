@@ -17,7 +17,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import byteps_tpu as bps
 from byteps_tpu.models import transformer as tfm
 from byteps_tpu.parallel import sharded
-from byteps_tpu.common.compat import tree_flatten_with_path as _tree_flatten_with_path
 
 
 def _tiny():
@@ -107,7 +106,7 @@ def test_zero1_respects_existing_dp_sharding(mesh8):
     specs = dict(specs)
     specs["embed"] = P("dp")
     z = sharded.zero1_opt_specs(opt, params, mesh8, specs)
-    trace = _tree_flatten_with_path(
+    trace = jax.tree.flatten_with_path(
         z, is_leaf=lambda x: isinstance(x, P))[0]
     for path, spec in trace:
         if any(getattr(k, "key", None) == "embed" for k in path):
@@ -162,7 +161,7 @@ def test_fsdp_composes_with_tp():
     base = tfm.param_specs(cfg)
     fspecs = sharded.fsdp_param_specs(params, mesh, base_specs=base,
                                       min_shard_elems=64)
-    flat = _tree_flatten_with_path(
+    flat = jax.tree.flatten_with_path(
         fspecs, is_leaf=lambda x: isinstance(x, P))[0]
     seen_tp = seen_both = False
     for path, spec in flat:

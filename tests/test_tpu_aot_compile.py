@@ -1,0 +1,153 @@
+"""The main path's kernels and one train step, compiled for a described
+TPU v5e — no chip attached, none needed.
+
+The TPU's compiler is part of the installation and compiles for a chip
+that is described, not present.  It refuses what the Pallas interpreter
+lets through (a block that breaks the lane rule, a kernel over its VMEM
+budget, a program over the device's memory), so these few compiles guard
+every later change at no chip time.  Nothing runs here: a compile that
+passes is not a chip run.
+
+Code that asks `jax.default_backend()` still sees the CPU under test, so
+the two switches that would pick the interpreter are steered here, in
+the test (not through an option of the program).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else it logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from byteps_tpu.ops import flash_attention as fa
+from byteps_tpu.ops.compressor import bitpack
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four described devices of a v5e 2x2 host."""
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e topology here: {e!r:.200}")
+    return list(topo.devices)
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    """A compile for a described device is written to the persistent
+    cache but cannot be read back without a chip: the next one would
+    warn and compile again.  Keep the cache off around these compiles."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    return jax.jit(fn).lower(*shapes).compile()
+
+
+def _flash_fwd_bwd(bh, s, d, block, streaming, device):
+    x = jax.ShapeDtypeStruct((bh, s, d), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(device))
+
+    def grads(q, k, v):
+        def loss(q, k, v):
+            out = fa.flash_attention(q, k, v, True, None, block, block,
+                                     False, streaming)
+            return jnp.sum(out.astype(jnp.float32))
+        return jax.grad(loss, (0, 1, 2))(q, k, v)
+
+    return _compile(grads, x, x, x)
+
+
+@pytest.mark.parametrize("bh,s,d,block,streaming", [
+    (64 * 16, 512, 64, 512, None),     # bert_large width, per-chip batch 64
+    (16, 2048, 64, 512, None),
+    (4, 8192, 64, 512, True),          # the streaming path
+    (16, 512, 128, 512, None),         # the MXU-ideal head dim
+], ids=["bert_large_s512", "s2048", "streaming_s8192", "d128"])
+def test_flash_fwd_bwd_compiles(v5e, bh, s, d, block, streaming):
+    hlo = _flash_fwd_bwd(bh, s, d, block, streaming, v5e[0]).as_text()
+    assert "tpu_custom_call" in hlo
+
+
+def test_flash_64_row_block_is_refused_up_front(v5e):
+    """F's decision: the kernel refuses a 64-row Q tile itself, with a
+    message that names the rule — because the chip's compiler refuses it
+    (shown by going around the check), and only the interpreter ever
+    accepted it."""
+    with pytest.raises(ValueError, match="multiple of 128"):
+        _flash_fwd_bwd(16, 512, 64, 64, None, v5e[0])
+    x = jax.ShapeDtypeStruct((16, 512, 64), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e[0]))
+    with pytest.raises(Exception, match="128"):
+        _compile(lambda q, k, v: fa._fwd(q, k, v, 0.125, True, 64, 64,
+                                         False, False), x, x, x)
+    # The K tile sits on a sublane dim: 64 rows are fine there.
+    _flash_fwd_bwd(16, 512, 64, 128, None, v5e[0])
+    _compile(lambda q, k, v: fa.flash_attention(q, k, v, True, None, 128,
+                                                64, False), x, x, x)
+
+
+@pytest.mark.parametrize("n", [1 << 20, 33 * bitpack.GRAN],
+                         ids=["1M", "33_tiles"])
+@pytest.mark.parametrize("op", ["pack", "unpack"])
+def test_bitpack_compiles(v5e, op, n):
+    one = SingleDeviceSharding(v5e[0])
+    if op == "pack":
+        c = _compile(lambda x: bitpack.pack_signs(x, impl="pallas"),
+                     jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one))
+    else:
+        c = _compile(
+            lambda w: bitpack.unpack_signs(w, n, impl="pallas"),
+            jax.ShapeDtypeStruct((bitpack.words_len(n),), jnp.uint32,
+                                 sharding=one))
+    assert "tpu_custom_call" in c.as_text()
+
+
+def test_bert_large_train_step_compiles(v5e, monkeypatch):
+    """One train step through the normal entry points at the flagship's
+    full width (chip_smoke.flagship_config), depth cut to 2 layers, per-chip
+    batch 64, on a one-device mesh of the described chip: the kernel is in
+    the program and the program fits the chip."""
+    import optax
+
+    import byteps_tpu as bps
+    import chip_smoke
+    from byteps_tpu.models import transformer as tfm
+
+    monkeypatch.setattr(fa, "_use_interpret", lambda interpret: False)
+    cfg = chip_smoke.flagship_config(num_layers=2)
+    mesh = bps.make_mesh(devices=v5e[:1])
+    opt = bps.DistributedOptimizer(optax.adamw(1e-4))
+    step = bps.build_train_step(lambda p, b: tfm.loss_fn(p, b, cfg), opt,
+                                mesh, donate=True)
+    params = jax.eval_shape(
+        lambda: tfm.init_params(jax.random.key(0), cfg))
+    opt_state = jax.eval_shape(opt.init, params)
+    batch = jax.eval_shape(lambda: tfm.synthetic_batch(
+        jax.random.key(1), chip_smoke.FULL.per_chip_batch,
+        chip_smoke.FULL.seq, cfg))
+
+    def on(spec):
+        sharding = NamedSharding(mesh, spec)
+        return lambda t: jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=sharding), t)
+
+    compiled = jax.jit(step).lower(on(P())(params), on(P())(opt_state),
+                                   on(P("dp"))(batch)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9

@@ -16,7 +16,6 @@ from jax.sharding import PartitionSpec as P
 
 import byteps_tpu as bps
 
-from byteps_tpu.common.compat import shard_map as _compat_shard_map
 
 def _mlp_init(key, sizes=(784, 64, 10)):
     params = []
@@ -179,7 +178,7 @@ def test_hierarchical_optimizer_trains():
 
     import functools
     @functools.partial(
-        _compat_shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P(), P(), P(("dcn_dp", "ici_dp"))),
         out_specs=(P(), P(), P()), check_vma=False)
     def _step(params, opt_state, batch):

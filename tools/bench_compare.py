@@ -2,13 +2,12 @@
 """bench_compare — regression gate over the BENCH_* / MULTICHIP_* record
 series.
 
-Every PR's driver run leaves ``BENCH_rNN.json`` / ``MULTICHIP_rNN.json``
-records at the repo root (bench.py wrapper shape: ``{"n", "cmd", "rc",
-"parsed": {"metric", "value", "unit", "detail": {...}}}``).  The r05
-incident (ROADMAP "bench reality check") showed how a silent regression
-rides that history: a CPU-fallback number that *reads* like an on-chip
-one becomes the implicit baseline.  bench.py now refuses to *write*
-such records unstamped; this tool closes the read side:
+Reads a directory of ``BENCH_rNN.json`` / ``MULTICHIP_rNN.json`` records
+(bench.py wrapper shape: ``{"n", "cmd", "rc", "parsed": {"metric",
+"value", "unit", "detail": {...}}}``).  A silent regression rides such a
+history when a CPU-fallback number that *reads* like an on-chip one
+becomes the implicit baseline.  bench.py's device modes no longer run on
+the CPU at all; this tool closes the read side for records that exist:
 
   For the LATEST record of each (headline metric, device platform)
   pair, compare against the BEST prior non-fallback record of the same
