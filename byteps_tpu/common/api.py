@@ -33,7 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import doctor as doctor_mod
-from . import devprof, flightrec, signals, telemetry
+from . import devprof, flightrec, signals, stage_spans, telemetry
 from .config import Config, get_config
 from .logging import get_logger, set_level, set_rank
 from ..core.native import get_core
@@ -752,8 +752,23 @@ def push_pull_tree(tree: PyTree, name: Optional[str] = None,
     sorted keys).  Unnamed leaves get deterministic names derived from
     the batch name + the leaf's TREE PATH (stable under structural
     growth elsewhere in the tree, unlike a flat index).
+
+    In PS mode, inside a trace window, the call is one ``ROUND`` span
+    and its stages on this thread are spans under it
+    (docs/timeline.md, "The round from inside").
     """
     _require_init()
+    sess = _state.ps_session
+    if sess is None:
+        return _push_pull_tree(tree, name, average, compression,
+                               leaf_names, fusion_bytes)
+    with sess.spans.round(name or "push_pull_tree"):
+        return _push_pull_tree(tree, name, average, compression,
+                               leaf_names, fusion_bytes)
+
+
+def _push_pull_tree(tree, name, average, compression, leaf_names,
+                    fusion_bytes):
     paths_leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
     if not paths_leaves:
         return tree
@@ -825,17 +840,22 @@ def push_pull_tree(tree: PyTree, name: Optional[str] = None,
             push_pull(leaves[i], name=leaf_name(i), average=average,
                       compression=comp)).astype(metas[i][1])
     if batch_idx:
-        flat = (jnp.concatenate([leaves[i].ravel().astype(jnp.float32)
-                                 for i in batch_idx])
-                if len(batch_idx) > 1
-                else leaves[batch_idx[0]].ravel().astype(jnp.float32))
+        span = _stage_span()
+        with span("PACK", name):
+            flat = (jnp.concatenate([leaves[i].ravel().astype(jnp.float32)
+                                     for i in batch_idx])
+                    if len(batch_idx) > 1
+                    else leaves[batch_idx[0]].ravel().astype(jnp.float32))
         out = jnp.asarray(push_pull(flat, name=name, average=average,
                                     compression=compression))
-        o = 0
-        for i in batch_idx:
-            shp, dt, n = metas[i]
-            outs[i] = out[o:o + n].reshape(shp).astype(dt)
-            o += n
+        with span("SCATTER", name) as sp:
+            if sp is not None:
+                sp.args["key"] = declare(name)
+            o = 0
+            for i in batch_idx:
+                shp, dt, n = metas[i]
+                outs[i] = out[o:o + n].reshape(shp).astype(dt)
+                o += n
     return jax.tree.unflatten(treedef, outs)
 
 
@@ -852,33 +872,49 @@ def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
     """
     from .fusion import plan_buckets
 
-    plan = plan_buckets(
-        tuple((i, metas[i][2], str(metas[i][1]),
-               jnp.dtype(metas[i][1]).itemsize) for i in batch_idx), fb)
-    plan.record_use()
+    sess = _state.ps_session
+    span = _stage_span()
 
-    # Dispatch units: (unit_name, payload, priority, compression, scatter)
-    # where scatter = [(leaf_idx, num_elems), ...] in pack order.
-    units = []
-    for b in plan.buckets:
-        members = [(li, n) for li, n in b.members]
-        packed = (jnp.concatenate([leaves[li].ravel() for li, _ in members])
-                  if len(members) > 1 else leaves[members[0][0]].ravel())
-        units.append((f"{name}.{b.tag}", packed, b.priority, compression,
-                      members))
-    for li, prio in plan.solo:
-        units.append((leaf_name(li), leaves[li].ravel(), prio, compression,
-                      [(li, metas[li][2])]))
-    for i in sep_idx:
-        # Forced-solo leaves (non-float exactness, registered wire
-        # compressors) join the same priority-ordered dispatch, minus any
-        # lossy intra-node cast for non-floats.  Raveled like every other
-        # unit: scatter() below slices elements, and a 0-d payload would
-        # not even be sliceable.
-        comp = (compression
-                if jnp.issubdtype(metas[i][1], jnp.floating) else None)
-        units.append((leaf_name(i), leaves[i].ravel(), i, comp,
-                      [(i, metas[i][2])]))
+    def plan_units(fb, only=None):
+        """The fusion plan under threshold `fb` and its dispatch units,
+        (unit_name, payload, priority, compression, scatter) where
+        scatter = [(leaf_idx, num_elems), ...] in pack order; with
+        `only`, just the units that carry one of those leaves."""
+        plan = plan_buckets(
+            tuple((i, metas[i][2], str(metas[i][1]),
+                   jnp.dtype(metas[i][1]).itemsize) for i in batch_idx), fb)
+        plan.record_use()
+        units = []
+        with span("PACK", name):
+            for b in plan.buckets:
+                members = [(li, n) for li, n in b.members]
+                if only is not None and not any(li in only
+                                                for li, _ in members):
+                    continue
+                packed = (jnp.concatenate(
+                    [leaves[li].ravel() for li, _ in members])
+                    if len(members) > 1
+                    else leaves[members[0][0]].ravel())
+                units.append((f"{name}.{b.tag}", packed, b.priority,
+                              compression, members))
+            for li, prio in plan.solo:
+                if only is None or li in only:
+                    units.append((leaf_name(li), leaves[li].ravel(), prio,
+                                  compression, [(li, metas[li][2])]))
+        return plan, units
+
+    plan, units = plan_units(fb)
+    with span("PACK", name):
+        for i in sep_idx:
+            # Forced-solo leaves (non-float exactness, registered wire
+            # compressors) join the same priority-ordered dispatch, minus
+            # any lossy intra-node cast for non-floats.  Raveled like
+            # every other unit: scatter() below slices elements, and a
+            # 0-d payload would not even be sliceable.
+            comp = (compression
+                    if jnp.issubdtype(metas[i][1], jnp.floating) else None)
+            units.append((leaf_name(i), leaves[i].ravel(), i, comp,
+                          [(i, metas[i][2])]))
     units.sort(key=lambda u: -u[2])
 
     outs: list = [None] * len(leaves)
@@ -890,7 +926,6 @@ def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
             outs[li] = jnp.asarray(vec[off:off + n]).reshape(shp).astype(dt)
             off += n
 
-    sess = _state.ps_session
     if sess is not None:
         from ..ops.compression import Compression
         hier = _state.hierarchy
@@ -937,24 +972,25 @@ def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
                          for _, p, _, _, _ in units)
         for attempt in range(3):
             items, ctxs, fusion_dks = [], [], []
-            for nm, payload, prio, comp, members in units:
-                _debug_sample("push", nm, payload)
-                comp = comp or Compression.none
-                wire, ctx = comp.compress(payload)
-                dk = declare(nm)
-                if nm in plan_unit_names:
-                    fusion_dks.append(dk)
-                if len(members) > 1 and get_core().trace_on:
-                    # Fused bucket inside a trace window: record its
-                    # member-leaf names so trace spans carry the real
-                    # parameters in args.members (the analyzer's
-                    # slow-bucket attribution).  Gated like every other
-                    # trace feed — an untraced run must not build name
-                    # lists per step.
-                    sess.set_trace_members(
-                        dk, [leaf_name(li) for li, _ in members])
-                items.append((dk, wire, prio))
-                ctxs.append((comp, ctx))
+            with span("PACK", name):
+                for nm, payload, prio, comp, members in units:
+                    _debug_sample("push", nm, payload)
+                    comp = comp or Compression.none
+                    wire, ctx = comp.compress(payload)
+                    dk = declare(nm)
+                    if nm in plan_unit_names:
+                        fusion_dks.append(dk)
+                    if len(members) > 1 and get_core().trace_on:
+                        # Fused bucket inside a trace window: record its
+                        # member-leaf names so trace spans carry the real
+                        # parameters in args.members (the analyzer's
+                        # slow-bucket attribution).  Gated like every
+                        # other trace feed — an untraced run must not
+                        # build name lists per step.
+                        sess.set_trace_members(
+                            dk, [leaf_name(li) for li, _ in members])
+                    items.append((dk, wire, prio))
+                    ctxs.append((comp, ctx))
             if fusion_dks:
                 sess.note_fusion_keys(fusion_dks)
             failed: set = set()
@@ -964,7 +1000,7 @@ def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
                 for (nm, _, _, _, members), h, (comp, ctx) in zip(
                         units, handles, ctxs):
                     try:
-                        out = comp.decompress(jnp.asarray(h.wait()), ctx)
+                        out = _pulled_to_device(sess.spans, h, h.key, nm)
                     except KnobReplan as kr:
                         if hier is not None:
                             # The slice broadcast can't re-plan under a
@@ -974,13 +1010,15 @@ def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
                         failed.update(li for li, _ in members)
                         replan_err = kr
                         continue
-                    if average:
-                        out = out / size()
-                    scatter(members, out)
-                    _debug_sample("pull", nm, out)
-                    if hier is not None:
-                        pulled_vecs.append(
-                            np.asarray(out, np.float32).ravel())
+                    with span("SCATTER", nm, key=h.key):
+                        out = comp.decompress(out, ctx)
+                        if average:
+                            out = out / size()
+                        scatter(members, out)
+                        _debug_sample("pull", nm, out)
+                        if hier is not None:
+                            pulled_vecs.append(
+                                np.asarray(out, np.float32).ravel())
             except Exception as e:
                 if hier is not None:
                     # Slice followers are blocked on the broadcast — a
@@ -1003,29 +1041,17 @@ def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
             live_fb = sess.live_fusion_bytes()
             if live_fb is not None:
                 fb = live_fb
-            plan = plan_buckets(
-                tuple((i, metas[i][2], str(metas[i][1]),
-                       jnp.dtype(metas[i][1]).itemsize)
-                      for i in batch_idx), fb)
-            plan.record_use()
-            units = []
-            for b in plan.buckets:
-                members = [(li, n) for li, n in b.members]
-                if not any(li in failed for li, _ in members):
-                    continue
-                packed = (jnp.concatenate(
-                    [leaves[li].ravel() for li, _ in members])
-                    if len(members) > 1
-                    else leaves[members[0][0]].ravel())
-                units.append((f"{name}.{b.tag}", packed, b.priority,
-                              compression, members))
-            for li, prio in plan.solo:
-                if li in failed:
-                    units.append((leaf_name(li), leaves[li].ravel(),
-                                  prio, compression,
-                                  [(li, metas[li][2])]))
+            plan, units = plan_units(fb, only=failed)
             units.sort(key=lambda u: -u[2])
             plan_unit_names = {u[0] for u in units}
+        with span("FREE", name):
+            # The round's host memory goes back here, under a span, and
+            # not at the return: the copies off the device (cached by
+            # the units' arrays) and the handles' result buffers, twice
+            # the tree's bytes, which takes as long as some stages do.
+            for owner in (units, items, ctxs, handles):
+                owner.clear()
+            payload = wire = ctx = h = out = None  # the last unit's
         if hier is not None:
             hier.publish_outs(rkey, pulled_vecs)
         cfg = _state.config or get_config()
@@ -1038,6 +1064,21 @@ def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
         for (nm, _, _, _, members), h in zip(units, handles):
             scatter(members, jnp.asarray(synchronize(h)))
     return outs
+
+
+def _stage_span():
+    """The PS session's `RoundSpans.span`; in collective mode, no span."""
+    sess = _state.ps_session
+    return sess.spans.span if sess is not None else stage_spans.off
+
+
+def _pulled_to_device(spans, handle, key: int, name: str) -> jax.Array:
+    """Wait for a PS handle and put what it pulled on the device: the
+    one place a unit's `WAIT` (written by `PSHandle.wait` itself, so
+    that callers outside a round get it too) and `H2D` come from."""
+    host = handle.wait()
+    with spans.span("H2D", name, key=key, bytes=int(host.nbytes)):
+        return jnp.asarray(host)
 
 
 def _debug_sample(stage: str, name: str, tensor) -> None:
@@ -1107,9 +1148,12 @@ def push_pull_async(tensor: jax.Array, name: Optional[str] = None,
             dk, np.asarray(tensor, np.float32).ravel(),
             priority=priority, leader_dispatch=_leader_dispatch)
 
-        def _resolve(ph=ph, shape=shape, dt=dt, avg=average):
-            out = jnp.asarray(ph.wait()).reshape(shape)
-            return (out / size() if avg else out).astype(dt)
+        def _resolve(ph=ph, shape=shape, dt=dt, avg=average,
+                     spans=_state.ps_session.spans):
+            out = _pulled_to_device(spans, ph, dk, name)
+            with spans.span("SCATTER", name, key=dk):
+                out = out.reshape(shape)
+                return (out / size() if avg else out).astype(dt)
 
         _resolve.ps_handle = ph
         cfg = _state.config or get_config()
@@ -1129,10 +1173,12 @@ def push_pull_async(tensor: jax.Array, name: Optional[str] = None,
         ps_handle = _state.ps_session.push_pull_async(
             dk, wire, priority=priority)
 
-        def _resolve(ph=ps_handle, comp=compression, cctx=ctx, avg=average):
-            out = jnp.asarray(ph.wait())
-            out = comp.decompress(out, cctx)
-            return out / size() if avg else out
+        def _resolve(ph=ps_handle, comp=compression, cctx=ctx, avg=average,
+                     spans=_state.ps_session.spans):
+            out = _pulled_to_device(spans, ph, dk, name)
+            with spans.span("SCATTER", name, key=dk):
+                out = comp.decompress(out, cctx)
+                return out / size() if avg else out
 
         _resolve.ps_handle = ps_handle
         out = _resolve
@@ -1960,8 +2006,13 @@ def _merge_server_trace(path: str, exiting: bool = False) -> None:
             if members:
                 for e in events:
                     k = (e.get("args") or {}).get("key")
-                    if k is not None and (k >> 16) in members:
-                        e["args"]["members"] = members[k >> 16]
+                    if k is None:
+                        continue
+                    # A partition's key holds its unit's declared key
+                    # above bit 16; a stage span carries the unit's own.
+                    dk = k if e.get("tid") in stage_spans.STAGES else k >> 16
+                    if dk in members:
+                        e["args"]["members"] = members[dk]
         prof = devprof.active()
         if prof is not None:
             # Device lane (pid = DEVICE_PID_BASE + rank): the profiler's

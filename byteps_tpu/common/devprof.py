@@ -25,11 +25,8 @@ host platform.  This module is the device plane:
   same ``time.monotonic_ns()//1000`` µs timebase as
   ``core.trace_now_us()``, so they land in the merged ``comm.json``
   (pid = ``DEVICE_PID_BASE + rank``) already time-aligned with the wire
-  spans; ``merge_xla_events`` folds parsed XLA profiler events onto the
-  same timebase via an explicit clock anchor (the PR-5 offset law), and
-  ``parse_xla_trace`` reads a ``jax.profiler`` capture's Chrome-JSON
-  output when the runtime emitted one (dependency-free; the protobuf
-  xplane format is out of scope without TensorFlow).
+  spans.  A ``jax.profiler`` capture is laid beside them through the
+  ``byteps.round`` annotations it holds (docs/timeline.md).
 - **The device sentinel**: bench.py's ``_device_stamp()`` platform
   probe, refactored here as the single shared detector (bench stamping
   and the live doctor can no longer drift).  Probed at ``bps.init()``
@@ -52,9 +49,6 @@ backend).
 
 from __future__ import annotations
 
-import glob
-import gzip
-import json
 import os
 import sys
 import threading
@@ -396,104 +390,6 @@ class DeviceProfiler:
                  "ts": ts, "dur": dur, "pid": pid, "tid": "DEVICE",
                  "args": {"step": i}}
                 for ts, dur, i in spans]
-
-    def merge_xla_events(self, raw_events, rank: int = 0,
-                         anchor: Optional[dict] = None) -> List[dict]:
-        """Parsed XLA device events → Chrome events on the device lane.
-
-        ``raw_events`` rows are ``{"name", "ts_us", "dur_us"}`` plus an
-        optional ``"lane"`` (sub-row, e.g. a TPU core) and free-form
-        extras (kept under ``args``).  XLA profiler timestamps live on
-        the PROFILER's epoch, not ours — ``anchor`` is a same-instant
-        ``{"profiler_us", "mono_us"}`` pair (the PR-5 clock-offset law:
-        one explicit anchor, never per-event guessing) mapping them onto
-        the worker's monotonic-µs timebase.  No anchor = events already
-        on our timebase."""
-        off = 0
-        if anchor:
-            try:
-                off = int(anchor["mono_us"]) - int(anchor["profiler_us"])
-            except (KeyError, TypeError, ValueError):
-                off = 0
-        pid = DEVICE_PID_BASE + int(rank)
-        out = []
-        for e in raw_events or ():
-            if not isinstance(e, dict):
-                continue
-            try:
-                ts = int(e["ts_us"]) + off
-                dur = max(1, int(e.get("dur_us", 1)))
-            except (KeyError, TypeError, ValueError):
-                continue
-            extra = {k: v for k, v in e.items()
-                     if k not in ("name", "ts_us", "dur_us", "lane")}
-            out.append({"name": str(e.get("name", "xla_op")),
-                        "cat": "device", "ph": "X", "ts": ts, "dur": dur,
-                        "pid": pid, "tid": str(e.get("lane", "XLA")),
-                        "args": extra})
-        return out
-
-    def capture(self, duration_s: float = 1.0,
-                out_dir: Optional[str] = None) -> dict:
-        """On-demand ``jax.profiler`` window capture (best-effort).
-
-        Starts a profiler trace, sleeps ``duration_s`` while the
-        trainer keeps stepping, stops, and tries to parse any
-        Chrome-JSON trace the runtime emitted (``parse_xla_trace``).
-        Returns ``{"ok", "dir", "events", "note"}`` — ``events`` in the
-        raw shape ``merge_xla_events`` consumes.  A backend/profiler
-        that can't capture (or emits only protobuf xplanes) downgrades
-        to ``ok=False`` with the note saying why; the self-recorded
-        step spans still populate the device lane either way."""
-        d = out_dir or os.path.join("/tmp", f"bps_devprof_{os.getpid()}")
-        try:
-            import jax
-            jax.profiler.start_trace(d)
-            time.sleep(max(0.0, float(duration_s)))
-            jax.profiler.stop_trace()
-        except Exception as e:  # noqa: BLE001 — capture must never kill a run
-            return {"ok": False, "dir": d, "events": [],
-                    "note": f"jax.profiler capture unavailable: {e!r:.80}"}
-        events = parse_xla_trace(d)
-        return {"ok": bool(events), "dir": d, "events": events,
-                "note": "" if events else
-                "no Chrome-JSON trace found under the capture dir "
-                "(protobuf-only profile output needs external tooling)"}
-
-
-def parse_xla_trace(capture_dir: str) -> List[dict]:
-    """Raw device events from a ``jax.profiler`` capture directory.
-
-    Looks for Chrome-JSON trace files (``*.trace.json[.gz]``, the
-    format older runtimes and some plugins emit) and converts their
-    complete (``ph == "X"``) events into the
-    ``{"name", "ts_us", "dur_us", "lane"}`` rows ``merge_xla_events``
-    consumes.  Dependency-free by design: parsing the newer
-    ``.xplane.pb`` protobufs would need TensorFlow, which this repo
-    does not ship."""
-    out: List[dict] = []
-    pats = (os.path.join(capture_dir, "**", "*.trace.json.gz"),
-            os.path.join(capture_dir, "**", "*.trace.json"))
-    for pat in pats:
-        for path in sorted(glob.glob(pat, recursive=True)):
-            try:
-                if path.endswith(".gz"):
-                    with gzip.open(path, "rt") as f:
-                        doc = json.load(f)
-                else:
-                    with open(path) as f:
-                        doc = json.load(f)
-            except (OSError, ValueError) as e:
-                get_logger().debug("unreadable xla trace %s: %s", path, e)
-                continue
-            for e in (doc.get("traceEvents") or []):
-                if e.get("ph") != "X" or "ts" not in e:
-                    continue
-                out.append({"name": str(e.get("name", "xla_op")),
-                            "ts_us": int(e["ts"]),
-                            "dur_us": max(1, int(e.get("dur", 1))),
-                            "lane": str(e.get("tid", "XLA"))})
-    return out
 
 
 # ---------------------------------------------------------------------------

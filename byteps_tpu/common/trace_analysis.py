@@ -26,6 +26,13 @@ remainder, and if measured chain components ever exceed the step envelope
 (overlapping rounds inside one step) they are scaled down proportionally —
 so ``sum(breakdown) == step duration`` always holds exactly.
 
+Beside the chain, ``round_breakdown_us`` is the round as the calling
+thread passed through it: the mean per ``ROUND`` span (one
+``push_pull_tree`` call in PS mode, common/stage_spans.py) of each stage
+span under it, and ``unspanned``, what of the ``ROUND`` no stage covers
+(Python between the spans).  The stages of a round do not overlap, so
+``sum(stages) + unspanned == round`` exactly.
+
 ``update_critical_path_gauges`` feeds the per-component means into the
 PR-4 telemetry registry as ``bps_step_critical_path_seconds{component=…}``
 (plus ``bps_step_straggler_wait_seconds{worker=…}``), so ``tools/bps_top``
@@ -35,7 +42,10 @@ and the Prometheus endpoint surface the breakdown live;
 
 from __future__ import annotations
 
+import statistics
 from typing import Dict, List, Optional
+
+from .stage_spans import STAGES as ROUND_STAGES
 
 # Server lanes start here in the merged file; worker lanes are the ranks.
 SERVER_PID_BASE = 10000
@@ -79,7 +89,8 @@ def analyze(events: List[dict], worker: int = 0, top_k: int = 5) -> dict:
                     "breakdown_us": {component: us}}],
          "mean_breakdown_us": {component: us},
          "top_blocking": [{"name", "total_us", "members"}],
-         "straggler_wait_us": {worker_id: us}}
+         "straggler_wait_us": {worker_id: us},
+         "round_breakdown_us": {"round", "pack", ..., "unspanned": us}}
     """
     xs = [e for e in events if e.get("ph") == "X"]
     # Worker-side spans and STEP envelopes are filtered to the selected
@@ -210,7 +221,61 @@ def analyze(events: List[dict], worker: int = 0, top_k: int = 5) -> dict:
             for c in COMPONENTS}
     top = sorted(blocking.values(), key=lambda r: -r["total_us"])[:top_k]
     return {"steps": step_rows, "mean_breakdown_us": mean,
-            "top_blocking": top, "straggler_wait_us": straggler}
+            "top_blocking": top, "straggler_wait_us": straggler,
+            "round_breakdown_us": round_breakdown(xs, worker)}
+
+
+def round_breakdown(spans: List[dict], worker: int = 0) -> Dict[str, int]:
+    """Mean microseconds per ``ROUND`` of the worker: ``round`` itself,
+    each stage under it (lower-case) and ``unspanned``; empty where the
+    events hold no ``ROUND``.  A stage span counts only if its
+    ``args.round`` names one of those ``ROUND``s: the same stages are
+    written with round 0 by callers that open none."""
+    mine = [e for e in spans if e.get("pid") == worker
+            and e.get("tid") in ROUND_STAGES]
+    rounds = {e["args"]["round"] for e in mine if e["tid"] == "ROUND"}
+    if not rounds:
+        return {}
+    total = dict.fromkeys(ROUND_STAGES, 0)
+    for e in mine:
+        if e["args"]["round"] in rounds:
+            total[e["tid"]] += int(e.get("dur", 0))
+    out = {s.lower(): total[s] // len(rounds) for s in ROUND_STAGES}
+    out["unspanned"] = out["round"] - sum(
+        v for s, v in out.items() if s != "round")
+    return out
+
+
+def profiler_offset(events: List[dict], xplane_path: str,
+                    worker: int = 0) -> Optional[Dict[str, float]]:
+    """What to add to a ``comm.json`` time (microseconds) to put it on
+    the clock of the ``jax.profiler`` trace at ``xplane_path``, taken
+    from the rounds both hold: every ``ROUND`` span is also a
+    ``byteps.round`` annotation carrying the same ``round``
+    (common/stage_spans.py).  ``offset_us`` is the median over those
+    rounds of annotation start less span start, ``spread_us`` the
+    distance between the largest and smallest difference (what the
+    alignment can be off by), ``rounds`` how many were in both.  None
+    where no round is in both."""
+    from jax.profiler import ProfileData
+    spans = {e["args"]["round"]: e["ts"] for e in events
+             if e.get("ph") == "X" and e.get("pid") == worker
+             and e.get("tid") == "ROUND"}
+    diffs = []
+    for plane in ProfileData.from_file(xplane_path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name != "byteps.round":
+                    continue
+                rnd = dict(ev.stats).get("round")
+                if rnd in spans:
+                    diffs.append(ev.start_ns / 1e3 - spans[rnd])
+    if not diffs:
+        return None
+    return {"offset_us": statistics.median(diffs),
+            "spread_us": max(diffs) - min(diffs), "rounds": len(diffs)}
 
 
 # Worker labels set by the previous update, per registry: the straggler
@@ -275,6 +340,17 @@ def format_report(result: dict) -> str:
         lines.append("mean per-step breakdown")
         for c in COMPONENTS:
             lines.append(f"      {c:<12}{_fmt_us(mean.get(c, 0))}")
+    rounds = result.get("round_breakdown_us", {})
+    if rounds:
+        lines.append("mean per-round breakdown on the calling thread "
+                     "(sums to the round)")
+        for stage, us in rounds.items():
+            lines.append(f"      {stage:<12}{_fmt_us(us)}")
+    clock = result.get("profiler_offset")
+    if clock:
+        lines.append(f"comm.json + {clock['offset_us']:.1f}us = the "
+                     f"profiler's clock (spread {clock['spread_us']:.2f}us "
+                     f"over {clock['rounds']} rounds)")
     top = result.get("top_blocking", [])
     if top:
         lines.append("top blocking tensors (chain extent, all steps)")

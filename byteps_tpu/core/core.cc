@@ -32,6 +32,7 @@
 #include <mutex>
 #include <string>
 #include <unordered_map>
+#include <utility>
 #include <vector>
 
 #define BPS_API extern "C" __attribute__((visibility("default")))
@@ -349,6 +350,9 @@ struct TraceEvent {
   int64_t key = -1;
   int64_t bytes = 0;
   int32_t priority = 0;
+  // Named integer args of a main-thread stage span (ROUND, D2H, ...:
+  // common/stage_spans.py).  When present they ARE the args object.
+  std::vector<std::pair<std::string, int64_t>> extra;
 };
 
 struct Tracer {
@@ -385,6 +389,26 @@ BPS_API void bps_trace_record_part(const char* name, const char* stage,
   if (!g_tracer.on) return;
   g_tracer.events.push_back(
       TraceEvent{name, stage, ts_us, dur_us, key, bytes, priority});
+}
+
+// Span with `n` named integer args: `keys` is their comma-separated names,
+// `vals` their values in the same order.
+BPS_API void bps_trace_record_args(const char* name, const char* stage,
+                                   int64_t ts_us, int64_t dur_us,
+                                   const char* keys, const int64_t* vals,
+                                   int32_t n) {
+  TraceEvent ev{name, stage, ts_us, dur_us};
+  const char* k = keys;
+  for (int32_t i = 0; i < n; ++i) {
+    const char* end = std::strchr(k, ',');
+    std::string key = end ? std::string(k, end - k) : std::string(k);
+    ev.extra.emplace_back(std::move(key), vals[i]);
+    if (!end) break;
+    k = end + 1;
+  }
+  std::lock_guard<std::mutex> lk(g_tracer.mu);
+  if (!g_tracer.on) return;
+  g_tracer.events.push_back(std::move(ev));
 }
 
 BPS_API int64_t bps_trace_count() {
@@ -434,7 +458,15 @@ BPS_API int32_t bps_trace_dump(const char* path, int32_t rank) {
                  "\"dur\":%lld,\"pid\":%d,\"tid\":\"%s\"",
                  json_escape(e.name).c_str(), (long long)e.ts_us,
                  (long long)e.dur_us, rank, json_escape(e.stage).c_str());
-    if (e.key >= 0) {
+    if (!e.extra.empty()) {
+      std::fputs(",\"args\":{", f);
+      for (size_t i = 0; i < e.extra.size(); ++i) {
+        std::fprintf(f, "%s\"%s\":%lld", i ? "," : "",
+                     json_escape(e.extra[i].first).c_str(),
+                     (long long)e.extra[i].second);
+      }
+      std::fputs("}", f);
+    } else if (e.key >= 0) {
       std::fprintf(f,
                    ",\"args\":{\"key\":%lld,\"bytes\":%lld,\"priority\":%d}",
                    (long long)e.key, (long long)e.bytes, e.priority);

@@ -78,6 +78,9 @@ class _CCore:
         L.bps_trace_record_part.argtypes = [
             ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
             ctypes.c_int64, ctypes.c_int64, ctypes.c_int32]
+        L.bps_trace_record_args.argtypes = [
+            ctypes.c_char_p, ctypes.c_char_p, ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64), ctypes.c_int32]
         L.bps_trace_count.restype = ctypes.c_int64
         L.bps_trace_dump.argtypes = [ctypes.c_char_p, ctypes.c_int32]
         L.bps_trace_dump.restype = ctypes.c_int32
@@ -161,6 +164,15 @@ class _CCore:
         (reference: per-partition spans in global.cc:463-579)."""
         self._lib.bps_trace_record_part(name.encode(), stage.encode(), ts_us,
                                         dur_us, key, nbytes, priority)
+
+    def trace_record_args(self, name: str, stage: str, ts_us: int,
+                          dur_us: int, args: dict) -> None:
+        """Span whose Chrome-trace args are the named integers of `args`
+        (the main-thread stage spans, common/stage_spans.py)."""
+        vals = (ctypes.c_int64 * len(args))(*args.values())
+        self._lib.bps_trace_record_args(
+            name.encode(), stage.encode(), ts_us, dur_us,
+            ",".join(args).encode(), vals, len(args))
 
     def trace_count(self) -> int:
         return self._lib.bps_trace_count()
@@ -386,6 +398,12 @@ class _PyCore:
             self._trace_events.append(
                 (name, stage, ts_us, dur_us,
                  {"key": key, "bytes": nbytes, "priority": priority}))
+
+    def trace_record_args(self, name, stage, ts_us, dur_us, args):
+        if self._trace_on:
+            self._trace_events.append(
+                (name, stage, ts_us, dur_us,
+                 {k: int(v) for k, v in args.items()}))
 
     def trace_count(self):
         return len(self._trace_events)

@@ -69,6 +69,7 @@ import numpy as np
 
 from ..common import flightrec as _flightrec
 from ..common import signals as _signals
+from ..common import stage_spans as _stage_spans
 from ..common.config import Config
 from ..common.logging import get_logger
 from ..common.ring import DEFAULT_VNODES, RingTable
@@ -1021,10 +1022,15 @@ class PSHandle:
     """Async push_pull completion handle (the torch-plugin handle analog,
     reference: handle_manager.h:33-46)."""
 
-    def __init__(self, shape, dtype, num_parts: int, out: np.ndarray):
+    def __init__(self, shape, dtype, num_parts: int, out: np.ndarray,
+                 spans: Optional["_stage_spans.RoundSpans"] = None,
+                 key: int = 0, label: str = ""):
         self.shape = shape
         self.dtype = dtype
         self.out = out                      # flat f32 result buffer
+        self.key = key                      # declared key (WAIT span)
+        self._label = label
+        self._span = spans.span if spans is not None else _stage_spans.off
         self._remaining = num_parts
         self._lock = threading.Lock()
         self._event = threading.Event()
@@ -1075,6 +1081,10 @@ class PSHandle:
         return self._event.is_set()
 
     def wait(self, timeout: Optional[float] = 300.0) -> np.ndarray:
+        with self._span("WAIT", self._label, key=self.key):
+            return self._wait(timeout)
+
+    def _wait(self, timeout: Optional[float]) -> np.ndarray:
         with self._lock:
             if self._timed_out:
                 # A handle that timed out once stays failed: a later wait()
@@ -1570,6 +1580,9 @@ class PSSession:
         self._clock_sync_stop = threading.Event()
         self._clock_sync_thread: Optional[threading.Thread] = None
         self._trace_members: Dict[int, list] = {}    # declared_key -> names
+        # Main-thread stage spans of this session's rounds (ROUND, D2H,
+        # STAGE, WAIT here; PACK, H2D, SCATTER in common/api.py).
+        self.spans = _stage_spans.RoundSpans()
         # Metrics-registry feeds (common/telemetry.py).  The objects are
         # resolved once here; the per-partition hot path then pays only a
         # lock-free observe()/set() per event.  The queue-depth gauge
@@ -5383,12 +5396,32 @@ class PSSession:
         """Partition + stage one tensor into _inflight (INITs included)
         WITHOUT enqueueing — the caller batches the queue adds so grouped
         pushes enter the scheduler atomically."""
-        arr = np.asarray(tensor)
-        payload = np.ascontiguousarray(arr, dtype=np.float32).ravel()
-        if copy and np.may_share_memory(payload, arr):
-            # Snapshot only when the wire view would alias the caller's
-            # memory — the non-f32/non-contiguous path already copied.
-            payload = payload.copy()
+        label = self._label(declared_key)
+        with self.spans.span("D2H", label, key=declared_key) as sp:
+            # Blocks until the tensor is computed and copied off the
+            # device, into memory the runtime allocates anew.
+            arr = np.asarray(tensor)
+            if sp is not None:
+                sp.args["bytes"] = arr.nbytes
+        with self.spans.span("STAGE", label, key=declared_key) as sp:
+            payload = np.ascontiguousarray(arr, dtype=np.float32).ravel()
+            if copy and np.may_share_memory(payload, arr):
+                # Snapshot only when the wire view would alias the
+                # caller's memory — the non-f32/non-contiguous path
+                # already copied.
+                payload = payload.copy()
+            handle, parts = self._stage_payload(
+                declared_key, arr, payload, label, priority, raw, seed)
+            if sp is not None:
+                sp.args["bytes"] = payload.nbytes
+                self.spans.count(units=1, bytes_out=payload.nbytes,
+                                 bytes_in=handle.out.nbytes)
+        return handle, parts
+
+    def _stage_payload(self, declared_key: int, arr: np.ndarray,
+                       payload: np.ndarray, label: str, priority: int,
+                       raw: bool, seed: bool) -> tuple:
+        """`_stage` from the contiguous float32 `payload` on."""
         # Zero-copy wire: partitions are sent as memoryview slices of the
         # caller's buffer (no tobytes snapshot) — the reference's ZPush
         # contract: the tensor must not be mutated until the handle
@@ -5400,7 +5433,8 @@ class PSSession:
         # returns it at all), so pre-zeroing a 64MB result buffer every
         # round was a pure memset tax on the pull path.
         handle = PSHandle(arr.shape, arr.dtype, len(plan),
-                          np.empty(payload.nbytes // 4, np.float32))
+                          np.empty(payload.nbytes // 4, np.float32),
+                          spans=self.spans, key=declared_key, label=label)
         mv = memoryview(payload).cast("B")
         # Pending codec renegotiation whose round boundary this push
         # reaches applies HERE, before the kwargs/INIT and any encode —
@@ -5411,7 +5445,6 @@ class PSSession:
         self._maybe_apply_knobs(self._round.get(plan[0][0], 0))
         comp = self._current_compressor(declared_key, plan)
         kw_bytes = comp.kwargs_string().encode() if comp else b""
-        label = self._label(declared_key)
         if self._health is not None and not raw and not seed:
             # Push-side value health (every Nth round of this key):
             # norm/absmax/NaN/Inf of the gradient about to ride the
@@ -5487,7 +5520,9 @@ class PSSession:
         # count against the first round staged after the lull.
         self._mark_progress()
         enq_mono = time.monotonic()
-        with self._cv:
+        # key -1: the queue insert of every unit staged so far.
+        with self.spans.span("STAGE", "enqueue", key=-1,
+                             bytes=0), self._cv:
             for parts, priority in staged:
                 for p in parts:
                     p.enq_ts = enq

@@ -156,6 +156,29 @@ def test_trace_record_and_dump(core, tmp_path):
     core.trace_enable(False)
 
 
+def test_trace_record_args_dumps_named_integers(core, tmp_path):
+    """A stage span's args object is its named integers, in both cores
+    alike (beyond 32 bits and negative included), and off is off."""
+    import json
+    args = {"round": 3, "key": -1, "bytes": 5 * 2**32, "units": 0}
+    core.trace_record_args("dropped", "D2H", 1, 2, args)
+    assert core.trace_count() == 0
+    core.trace_enable(True)
+    core.trace_record_args('t["a"]', "D2H", 10, 20, args)
+    core.trace_record_part("t.part0", "PUSH", 30, 5, 7 << 16, 64, 2)
+    core.trace_record_args("t", "PACK", 40, 1, {"round": 3})
+    core.trace_enable(False)
+    path = str(tmp_path / "comm.json")
+    assert core.trace_dump(path, rank=1) == 0
+    with open(path) as f:
+        d2h, push, pack = json.load(f)["traceEvents"]
+    assert (d2h["name"], d2h["tid"], d2h["ts"], d2h["dur"], d2h["pid"]) == (
+        't["a"]', "D2H", 10, 20, 1)
+    assert d2h["args"] == args
+    assert push["args"] == {"key": 7 << 16, "bytes": 64, "priority": 2}
+    assert pack["args"] == {"round": 3}
+
+
 def test_handle_manager(core):
     h = core.handle_allocate()
     assert core.handle_poll(h) == 0

@@ -4,7 +4,6 @@ conviction law, trace-lane schema + XLA merge, the signals/doctor/
 flightrec integrations, and the off-is-really-off wire contract.
 """
 
-import gzip
 import json
 import os
 import struct
@@ -271,50 +270,6 @@ def test_trace_events_land_on_device_lane():
     assert not trace_analysis._is_server(ev)
     assert trace_analysis._is_server(
         {"pid": trace_analysis.SERVER_PID_BASE})
-
-
-def test_merge_xla_events_anchor_offset_and_junk_rows():
-    prof = DeviceProfiler(telemetry_on=False)
-    raw = [
-        {"name": "fusion.1", "ts_us": 1000, "dur_us": 50,
-         "lane": "core0", "flops": 12},
-        "junk",                              # non-dict: skipped
-        {"name": "no-ts"},                   # missing ts_us: skipped
-        {"ts_us": "NaN"},                    # unparseable: skipped
-    ]
-    anchor = {"profiler_us": 500, "mono_us": 90_500}
-    (ev,) = prof.merge_xla_events(raw, rank=1, anchor=anchor)
-    assert ev["ts"] == 1000 + 90_000        # the one explicit offset
-    assert ev["dur"] == 50
-    assert ev["pid"] == trace_analysis.DEVICE_PID_BASE + 1
-    assert ev["tid"] == "core0"             # lane -> sub-row
-    assert ev["args"] == {"flops": 12}      # extras kept
-    # No anchor (or a broken one) = already on our timebase.
-    (ev0,) = prof.merge_xla_events(raw[:1])
-    assert ev0["ts"] == 1000
-    (ev0,) = prof.merge_xla_events(raw[:1], anchor={"mono_us": "z"})
-    assert ev0["ts"] == 1000
-
-
-def test_parse_xla_trace_reads_chrome_json(tmp_path):
-    nested = tmp_path / "plugins" / "profile"
-    nested.mkdir(parents=True)
-    doc = {"traceEvents": [
-        {"ph": "X", "name": "op_a", "ts": 10, "dur": 5, "tid": "c0"},
-        {"ph": "M", "name": "process_name"},     # metadata: skipped
-        {"ph": "X", "name": "no-ts"},            # no ts: skipped
-    ]}
-    (nested / "host.trace.json").write_text(json.dumps(doc))
-    with gzip.open(tmp_path / "a.trace.json.gz", "wt") as f:
-        json.dump({"traceEvents": [
-            {"ph": "X", "name": "op_b", "ts": 20, "dur": 1}]}, f)
-    rows = devprof.parse_xla_trace(str(tmp_path))
-    by_name = {r["name"]: r for r in rows}
-    assert set(by_name) == {"op_a", "op_b"}
-    assert by_name["op_a"] == {"name": "op_a", "ts_us": 10, "dur_us": 5,
-                               "lane": "c0"}
-    assert by_name["op_b"]["lane"] == "XLA"     # default lane
-    assert devprof.parse_xla_trace(str(tmp_path / "empty")) == []
 
 
 # ---------------------------------------------------------------------------

@@ -23,6 +23,7 @@ from byteps_tpu.server.client import (CMD_HELLO, CMD_INIT, CMD_PULL,
                                       DT_SPARSE_READ,
                                       HELLO_FLAG_OBSERVER, _REQ,
                                       PSSession)
+from byteps_tpu.core.native import get_core
 from byteps_tpu.server import wire
 from byteps_tpu.parallel.embedding import EmbeddingTable
 
@@ -519,6 +520,13 @@ def test_embedding_table_shards_across_servers(server_group):
         rows, width = 1001, 16
         rng = np.random.RandomState(0)
         init = rng.randn(rows, width).astype(np.float32)
+        # The two shards take the next two declared keys, and the server
+        # a key hashes to depends on how many tensors this process
+        # declared before: step past a count that puts both on one.
+        core = get_core()
+        while len({s._embed_srv(s._embed_pkey(core.num_declared() + i))
+                   for i in (0, 1)}) < 2:
+            core.declare_tensor(f"pad.{core.num_declared()}")
         t = EmbeddingTable(s, rows, width, name="t",
                            opt_kwargs={"opt": "adagrad", "lr": 0.1},
                            init=init)

@@ -547,3 +547,219 @@ bps.shutdown()
     members = bucket[0]["args"]["members"]
     assert len(members) == 3
     assert all("t7" in m for m in members)
+
+
+# ---------------------------------------------------------------------------
+# The round from inside: main-thread stage spans (ISSUE 26)
+# ---------------------------------------------------------------------------
+_ROUND_JOB = """
+import json, jax, jax.numpy as jnp
+import byteps_tpu as bps
+from byteps_tpu.core.native import get_core
+bps.init()
+tree = {"a": jnp.ones((100, 3)), "b": jnp.ones(200),
+        "c": jnp.full((300000,), 2.0), "d": jnp.ones((7, 5))}
+for step in range(4):
+    jax.block_until_ready(
+        bps.push_pull_tree(tree, name="t26", average=False))
+    if step == 0:
+        print("COUNT_OUTSIDE_WINDOW", get_core().trace_count())
+    bps.mark_step()
+bps.shutdown()
+"""
+
+
+def _run_round_job(port, tmp_path, fusion_bytes, trace_on=True):
+    import subprocess
+    import sys
+    env = {
+        "BYTEPS_TPU_PS_MODE": "1", "DMLC_NUM_WORKER": "1",
+        "DMLC_NUM_SERVER": "1", "DMLC_PS_ROOT_PORT": str(port - 1),
+        "BYTEPS_PARTITION_BYTES": "65536", "BYTEPS_LOG_LEVEL": "ERROR",
+    }
+    if trace_on:
+        env.update({"BYTEPS_TRACE_ON": "1",
+                    "BYTEPS_TRACE_DIR": str(tmp_path),
+                    "BYTEPS_TRACE_START_STEP": "1",
+                    "BYTEPS_TRACE_END_STEP": "2"})
+    if fusion_bytes is not None:
+        env["BYTEPS_TPU_FUSION_BYTES"] = fusion_bytes
+    r = subprocess.run([sys.executable, "-c", _ROUND_JOB], env=cpu_env(env),
+                       capture_output=True, text=True, timeout=240)
+    assert r.returncode == 0, r.stderr[-3000:]
+    return r.stdout
+
+
+_ROUND_EVENTS: dict = {}    # one recorded job per branch, for all its tests
+
+
+@pytest.fixture(params=[None, "0"], ids=["fused", "unfused"])
+def round_events(request, ps_server, tmp_path):  # noqa: F811
+    """The merged comm.json of two traced `push_pull_tree` rounds through
+    the fused (default BYTEPS_TPU_FUSION_BYTES) or the unfused (=0)
+    branch, and the job's output."""
+    if request.param not in _ROUND_EVENTS:
+        port = ps_server(num_workers=1)
+        out = _run_round_job(port, tmp_path, request.param)
+        with open(tmp_path / "0" / "comm.json") as f:
+            events = [e for e in json.load(f)["traceEvents"]
+                      if e.get("ph") == "X"]
+        _ROUND_EVENTS[request.param] = events, out
+    return _ROUND_EVENTS[request.param]
+
+
+def _end(e):
+    return e["ts"] + e["dur"]
+
+
+def _children(events, rnd):
+    from byteps_tpu.common import stage_spans
+    return [e for e in events if e["tid"] in stage_spans.STAGES[1:]
+            and e["args"]["round"] == rnd["args"]["round"]]
+
+
+def test_round_spans_one_round_per_call(round_events):
+    events, out = round_events
+    rounds = [e for e in events if e["tid"] == "ROUND"]
+    # steps 1 and 2 are the window: one ROUND per call, numbered apart
+    assert len(rounds) == 2
+    assert len({r["args"]["round"] for r in rounds}) == 2
+    assert all(r["args"]["round"] > 0 and r["name"] == "t26"
+               for r in rounds)
+    assert "COUNT_OUTSIDE_WINDOW 0" in out
+
+
+def test_round_spans_children_inside_and_disjoint(round_events):
+    events, _ = round_events
+    from byteps_tpu.common import stage_spans
+    staged = [e for e in events if e["tid"] in stage_spans.STAGES[1:]]
+    rounds = [e for e in events if e["tid"] == "ROUND"]
+    # every stage span of the window names one of its ROUNDs
+    assert {e["args"]["round"] for e in staged} == {
+        r["args"]["round"] for r in rounds}
+    for rnd in rounds:
+        kids = sorted(_children(events, rnd), key=lambda e: e["ts"])
+        # FREE is the fused branch's: the unfused one holds no lists of
+        # units whose buffers it could let go of under a span
+        assert {e["tid"] for e in kids} | {"FREE"} == set(
+            stage_spans.STAGES[1:])
+        assert ("FREE" in {e["tid"] for e in kids}) == any(
+            ".fb" in e["name"] for e in kids)
+        assert all(rnd["ts"] <= e["ts"] and _end(e) <= _end(rnd)
+                   for e in kids)
+        assert all(_end(a) <= b["ts"] for a, b in zip(kids, kids[1:]))
+
+
+def test_round_spans_count_the_tree(round_events):
+    events, _ = round_events
+    tree_bytes = 4 * (300 + 200 + 300000 + 35)
+    for rnd in (e for e in events if e["tid"] == "ROUND"):
+        kids = _children(events, rnd)
+        d2h = [e for e in kids if e["tid"] == "D2H"]
+        assert sum(e["args"]["bytes"] for e in d2h) == tree_bytes
+        a = rnd["args"]
+        assert a["units"] == len(d2h)
+        assert a["bytes_out"] == a["bytes_in"] == tree_bytes
+        assert set(a) == {"round", "units", "bytes_out", "bytes_in"}
+        # a unit's key is its partitions' key above bit 16
+        parts = [e for e in events if e["tid"] == "PUSH"
+                 and rnd["ts"] <= e["ts"] < _end(rnd)]
+        waited = {e["args"]["key"] for e in kids if e["tid"] == "WAIT"}
+        assert waited == {e["args"]["key"] >> 16 for e in parts}
+        for stage in ("D2H", "H2D", "SCATTER"):
+            assert {e["args"]["key"] for e in kids
+                    if e["tid"] == stage} == waited
+
+
+def test_round_breakdown_sums_to_the_round(round_events):
+    events, _ = round_events
+    got = trace_analysis.analyze(events, worker=0)["round_breakdown_us"]
+    assert set(got) == {"round", "pack", "d2h", "stage", "wait", "h2d",
+                        "scatter", "free", "unspanned"}
+    assert got["round"] == sum(v for k, v in got.items() if k != "round")
+    assert got["unspanned"] >= 0
+    rounds = [e for e in events if e["tid"] == "ROUND"]
+    assert got["round"] == sum(r["dur"] for r in rounds) // len(rounds)
+    # a stage written with no ROUND open (round 0) is not counted
+    stray = {"ph": "X", "pid": 0, "tid": "WAIT", "name": "x", "ts": 0,
+             "dur": 10**9, "args": {"round": 0, "key": 1}}
+    assert trace_analysis.round_breakdown(events + [stray]) == got
+    assert trace_analysis.analyze(
+        [e for e in events if e["tid"] != "ROUND"])[
+            "round_breakdown_us"] == {}
+    assert "per-round breakdown" in trace_analysis.format_report(
+        trace_analysis.analyze(events))
+
+
+def test_profiler_offset_from_a_recorded_pair():
+    """benchmark/tests/data/spans: three traced rounds recorded on the
+    CPU, comm.json and the profiler's .xplane.pb of the same run.  The
+    offset comes from the ROUNDs alone; the stages under them, which
+    took no part in it, must then meet their own annotations to within
+    the stated spread and the few microseconds between entering an
+    annotation and reading the program's clock."""
+    import os
+    from jax.profiler import ProfileData
+    data = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "tests", "data", "spans")
+    xplane = os.path.join(data, "plugins", "profile", "tiny",
+                          "tiny.xplane.pb")
+    with open(os.path.join(data, "0", "comm.json")) as f:
+        events = json.load(f)["traceEvents"]
+    clock = trace_analysis.profiler_offset(events, xplane)
+    assert clock["rounds"] == 3 and clock["spread_us"] < 5
+    host = [ev for plane in ProfileData.from_file(xplane).planes
+            if plane.name == "/host:CPU"
+            for line in plane.lines for ev in line.events]
+    for stage in ("D2H", "WAIT", "H2D"):
+        program = sorted(e["ts"] for e in events if e.get("tid") == stage)
+        profiler = sorted(ev.start_ns / 1e3 for ev in host
+                          if ev.name == "byteps." + stage.lower())
+        assert len(program) == len(profiler) == 6
+        for us, there in zip(program, profiler):
+            assert abs(us + clock["offset_us"] - there) <= (
+                clock["spread_us"] + 10)
+    # the same pair with the program's clock a second ahead: a known
+    # offset, recovered to the microsecond
+    ahead = trace_analysis.profiler_offset(
+        [{**e, "ts": e["ts"] + 1_000_000} if "ts" in e else e
+         for e in events], xplane)
+    assert ahead["offset_us"] == pytest.approx(
+        clock["offset_us"] - 1_000_000, abs=1e-3)
+    assert ahead["spread_us"] == pytest.approx(clock["spread_us"], abs=1e-3)
+    # no ROUND in common with the capture: no offset, not a zero
+    assert trace_analysis.profiler_offset(
+        [e for e in events if e.get("tid") != "ROUND"], xplane) is None
+    assert "profiler's clock" in trace_analysis.format_report(
+        {"profiler_offset": clock})
+
+
+@pytest.mark.parametrize("fusion_bytes", [None, "0"],
+                         ids=["fused", "unfused"])
+def test_round_spans_off_is_off(ps_server, tmp_path,  # noqa: F811
+                                fusion_bytes):
+    """With BYTEPS_TRACE_ON unset a round records nothing and writes no
+    file."""
+    port = ps_server(num_workers=1)
+    out = _run_round_job(port, tmp_path, fusion_bytes, trace_on=False)
+    assert "COUNT_OUTSIDE_WINDOW 0" in out
+    assert not (tmp_path / "0").exists()
+
+
+def test_stage_spans_outside_a_round_carry_round_zero(ps_server, tracing,  # noqa: F811
+                                                      tmp_path):
+    """`_stage` and `PSHandle.wait` reached with no ROUND open (a bare
+    session, as AsyncPSTrainer and ServerOptTrainer drive it) write
+    their spans with round 0."""
+    from byteps_tpu.common import stage_spans
+    port = ps_server(num_workers=1)
+    sess = PSSession(["127.0.0.1"], [port], worker_id=0, num_servers=1)
+    dk = get_core().num_declared() + 826
+    sess.push_pull(dk, np.ones(64, np.float32))
+    sess.close()
+    events = _dump(tracing, tmp_path)
+    mine = [e for e in events if e["tid"] in stage_spans.STAGES]
+    assert {e["tid"] for e in mine} == {"D2H", "STAGE", "WAIT"}
+    assert all(e["args"]["round"] == 0 for e in mine)
+    assert not set(stage_spans.STAGES) & set(trace_analysis.WORKER_STAGES)
+    assert trace_analysis.round_breakdown(events) == {}

@@ -12,10 +12,14 @@ Usage:
     python tools/trace_analyze.py traces/0/comm.json
     python tools/trace_analyze.py traces/*/comm.json --worker 0 --top 10
     python tools/trace_analyze.py traces/0/comm.json --json
+    python tools/trace_analyze.py traces/0/comm.json --xplane t.xplane.pb
 
 Multiple files merge before analysis: in a multi-worker run each server
 span is drained by exactly one worker, so pass every worker's file to see
-the whole fleet.  No dependencies beyond the stdlib + byteps_tpu.
+the whole fleet.  With ``--xplane`` (a ``jax.profiler`` trace of the
+same run) it also gives the offset that puts comm.json on the profiler's
+clock, from the rounds both record.  No dependencies beyond the stdlib +
+byteps_tpu.
 """
 
 from __future__ import annotations
@@ -40,6 +44,9 @@ def main(argv=None) -> int:
                     help="top-k blocking tensors (default 5)")
     ap.add_argument("--json", action="store_true",
                     help="machine-readable result instead of the report")
+    ap.add_argument("--xplane", metavar="PATH",
+                    help=".xplane.pb of the same run: also print the "
+                         "offset from comm.json's clock to the profiler's")
     args = ap.parse_args(argv)
 
     events = []
@@ -52,6 +59,9 @@ def main(argv=None) -> int:
         return 1
     result = trace_analysis.analyze(events, worker=args.worker,
                                     top_k=args.top)
+    if args.xplane:
+        result["profiler_offset"] = trace_analysis.profiler_offset(
+            events, args.xplane, worker=args.worker)
     if args.json:
         print(json.dumps(result, indent=2))
     else:
