@@ -731,10 +731,16 @@ def push_pull_tree(tree: PyTree, name: Optional[str] = None,
     (common/fusion.py) into dtype-homogeneous, size-capped buckets in
     reverse backprop order; each bucket rides ONE wire key at the max
     priority of its members, and larger leaves keep their own key and
-    backprop-position priority — so the PS dispatcher sends last-layer
+    backprop-position priority.  Units go to the PS scheduler one by
+    one, in (priority desc, declared key asc) order, each as soon as it
+    is copied off the device — so the PS dispatcher sends last-layer
     buckets first while earlier buckets still stage (the overlap the
     priority ScheduledQueues exist for), instead of one all-or-nothing
-    f32 vector that can't overlap with anything.
+    f32 vector that can't overlap with anything.  If a unit fails to
+    stage, the units before it are already on the wire and complete
+    their round unobserved; the exception surfaces, no key stays
+    wedged, and the next call goes through
+    (``PSSession.push_pull_group``).
 
     With fusion DISABLED (``BYTEPS_TPU_FUSION_BYTES=0``), floating
     leaves are flattened into one f32 vector reduced through a single
@@ -865,10 +871,13 @@ def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
 
     Builds dtype-homogeneous buckets over the fusable leaves, then sends
     every dispatch unit (bucket, over-threshold solo leaf, forced-solo
-    exact/compressed leaf) in priority-descending order.  In PS mode the
-    whole set rides PSSession.push_pull_group, so the scheduler sees all
-    units before the first dispatch; in collective mode the units are
-    issued as concurrent async push_pulls and synchronized together.
+    exact/compressed leaf) in the scheduler's own order, (priority
+    desc, declared key asc).  In PS mode the set rides
+    PSSession.push_pull_group, which puts each unit in the scheduler as
+    soon as it is off the device, so the units arrive in the order a
+    view of the whole set would have picked; in collective mode the
+    units are issued as concurrent async push_pulls and synchronized
+    together.
     """
     from .fusion import plan_buckets
 
@@ -903,6 +912,12 @@ def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
                                   compression, [(li, metas[li][2])]))
         return plan, units
 
+    def dispatch_order(units) -> None:
+        """The scheduler's own order, (priority desc, declared key
+        asc), with names first declared in priority order as ever."""
+        units.sort(key=lambda u: -u[2])
+        units.sort(key=lambda u: (-u[2], declare(u[0])))
+
     plan, units = plan_units(fb)
     with span("PACK", name):
         for i in sep_idx:
@@ -915,7 +930,7 @@ def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
                     if jnp.issubdtype(metas[i][1], jnp.floating) else None)
             units.append((leaf_name(i), leaves[i].ravel(), i, comp,
                           [(i, metas[i][2])]))
-    units.sort(key=lambda u: -u[2])
+    dispatch_order(units)
 
     outs: list = [None] * len(leaves)
 
@@ -1042,7 +1057,7 @@ def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
             if live_fb is not None:
                 fb = live_fb
             plan, units = plan_units(fb, only=failed)
-            units.sort(key=lambda u: -u[2])
+            dispatch_order(units)
             plan_unit_names = {u[0] for u in units}
         with span("FREE", name):
             # The round's host memory goes back here, under a span, and

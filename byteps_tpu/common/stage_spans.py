@@ -81,8 +81,8 @@ class RoundSpans:
             return
         with self._lock:
             self._rounds += 1
-            counts = {"round": self._rounds, "units": 0, "bytes_out": 0,
-                      "bytes_in": 0}
+            counts = {"round": self._rounds, "units": 0, "units_early": 0,
+                      "bytes_out": 0, "bytes_in": 0}
         self._open.counts = counts
         try:
             with _Span(core, "ROUND", name, counts):

@@ -147,7 +147,9 @@ class AsyncPSTrainer:
                 [(int(offs[li]), int(offs[li + 1]))], prio))
         if len(chunks) < 2:
             return None
-        chunks.sort(key=lambda c: -c[2])
+        # The scheduler's own order: push_pull_group queues each chunk as
+        # it is staged, so they arrive in the order it would have picked.
+        chunks.sort(key=lambda c: (-c[2], c[0]))
         return chunks
 
     def _dispatch(self, flat: np.ndarray, seed: bool = False):

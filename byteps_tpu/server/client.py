@@ -5344,58 +5344,46 @@ class PSSession:
         """
         handle, parts = self._stage(declared_key, tensor, priority, raw,
                                     seed, copy)
-        self._enqueue([(parts, priority)])
+        self._enqueue(parts, priority)
         return handle
 
     def push_pull_group(self, items, raw: bool = False, seed: bool = False,
                         copy: bool = False) -> List[PSHandle]:
-        """Grouped staging: stage EVERY (declared_key, tensor, priority)
-        item, then enqueue them all under one dispatcher wakeup.
+        """Streamed staging: each (declared_key, tensor, priority) item
+        is enqueued the moment it is staged, so the dispatcher pushes
+        and pulls item k while this thread still blocks in item k+1's
+        copy off the device.
 
-        This is the fusion layer's dispatch face (common/fusion.py): the
-        priority ScheduledQueue sees the whole bucket set before the
-        dispatcher picks, so buckets leave in strict (priority desc, key
-        asc) order even without a credit limit slowing the first pick —
-        and N buckets cost one lock round-trip instead of N.  Each item
-        follows the same zero-copy contract as push_pull_async.
+        This is the fusion layer's dispatch face (common/fusion.py).
+        Hand the items over in the scheduler's own order, (priority
+        desc, declared key asc): then what arrives in the queue is what
+        a view of the whole set would have picked, and every worker
+        sends the same sequence.  Each item follows the same zero-copy
+        contract as push_pull_async.
+
+        If staging item k raises, items 0..k-1 are already on the wire:
+        they complete like any push_pull_async whose handle is dropped,
+        item k has rolled back its own parts (`_stage`), the exception
+        surfaces, and no key is left wedged.
         """
-        staged: List[tuple] = []
         handles: List[PSHandle] = []
-        seen: set = set()
-        try:
-            for declared_key, tensor, priority in items:
-                if declared_key in seen:
-                    # A repeated key inside one group would deadlock: its
-                    # _stage blocks on the earlier round's completion,
-                    # which can't happen until that round is enqueued.
-                    # Flush what's staged so the guard can make progress.
-                    self._enqueue(staged)
-                    staged, seen = [], set()
-                h, parts = self._stage(declared_key, tensor, priority, raw,
-                                       seed, copy)
-                handles.append(h)
-                staged.append((parts, priority))
-                seen.add(declared_key)
-        except Exception:
-            # The failing item rolled back its own parts in _stage; the
-            # EARLIER items are staged but will never be enqueued — unpin
-            # them too, or their keys wedge every later push (the
-            # sequential-use guard would wait on done_evts nothing sets).
-            with self._inflight_lock:
-                for parts, _ in staged:
-                    for p in parts:
-                        if self._inflight.get(p.pkey) is p:
-                            del self._inflight[p.pkey]
-                        p.done_evt.set()
-            raise
-        self._enqueue(staged)
+        for declared_key, tensor, priority in items:
+            if handles:
+                # The units before this one are in the scheduler and
+                # this one's D2H has not begun.
+                self.spans.count(units_early=1)
+            handle, parts = self._stage(declared_key, tensor, priority,
+                                        raw, seed, copy)
+            self._enqueue(parts, priority)
+            handles.append(handle)
         return handles
 
     def _stage(self, declared_key: int, tensor, priority: int, raw: bool,
                seed: bool, copy: bool) -> tuple:
         """Partition + stage one tensor into _inflight (INITs included)
-        WITHOUT enqueueing — the caller batches the queue adds so grouped
-        pushes enter the scheduler atomically."""
+        WITHOUT enqueueing: the caller hands the parts to `_enqueue`
+        before it stages anything else.  A failure rolls back the parts
+        this call staged, and only those."""
         label = self._label(declared_key)
         with self.spans.span("D2H", label, key=declared_key) as sp:
             # Blocks until the tensor is computed and copied off the
@@ -5511,28 +5499,27 @@ class PSSession:
                     del self._inflight[p.pkey]
                 p.done_evt.set()
 
-    def _enqueue(self, staged) -> None:
-        """Enqueue staged partitions ([(parts, priority), ...]) into the
-        scheduler under ONE condition-variable hold."""
+    def _enqueue(self, parts: list, priority: int) -> None:
+        """Enqueue one staged unit's partitions into the scheduler under
+        one condition-variable hold, and wake the dispatcher."""
         core = get_core()
         enq = core.trace_now_us() if core.trace_on else 0
         # New work resets the stall clock: an idle session's age must not
         # count against the first round staged after the lull.
         self._mark_progress()
         enq_mono = time.monotonic()
-        # key -1: the queue insert of every unit staged so far.
+        # key -1: a queue insert, which belongs to no one unit's payload.
         with self.spans.span("STAGE", "enqueue", key=-1,
                              bytes=0), self._cv:
-            for parts, priority in staged:
-                for p in parts:
-                    p.enq_ts = enq
-                    p.enq_mono = enq_mono
-                    # credit_ln: actual wire bytes for ready parts; the
-                    # codec's worst-case bound for pipelined encodes (their
-                    # true size doesn't exist yet and p.wire_ln is racing
-                    # the encoder).  The queue returns the same figure at
-                    # get(), so report_finish stays symmetric either way.
-                    self._queue.add(p.pkey, priority, p.credit_ln)
+            for p in parts:
+                p.enq_ts = enq
+                p.enq_mono = enq_mono
+                # credit_ln: actual wire bytes for ready parts; the
+                # codec's worst-case bound for pipelined encodes (their
+                # true size doesn't exist yet and p.wire_ln is racing
+                # the encoder).  The queue returns the same figure at
+                # get(), so report_finish stays symmetric either way.
+                self._queue.add(p.pkey, priority, p.credit_ln)
             self._cv.notify_all()
 
     def _label(self, declared_key: int) -> str:

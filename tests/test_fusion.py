@@ -3,7 +3,7 @@ through it: push_pull_tree, PSSession.push_pull_group, AsyncPSTrainer).
 
 Covers the layer's contracts: deterministic dtype-homogeneous bucket
 composition in reverse backprop order, priority-descending dispatch
-through grouped staging, byte-identical fallback when disabled
+through streamed staging, byte-identical fallback when disabled
 (BYTEPS_TPU_FUSION_BYTES=0), stable keys across identical calls and
 across the elastic re-declare/restart path, and the streaming buffer's
 full/deadline flush law.
@@ -202,7 +202,7 @@ def test_fusion_disabled_is_byte_identical_to_pre_fusion_wire(
 
 
 # ---------------------------------------------------------------------------
-# Grouped staging + priority-descending dispatch (live PS server).
+# Streamed staging + priority-descending dispatch (live PS server).
 # ---------------------------------------------------------------------------
 def test_push_pull_group_correct_and_priority_descending(ps_server):
     from byteps_tpu.server.client import PSSession
@@ -226,8 +226,8 @@ def test_push_pull_group_correct_and_priority_descending(ps_server):
 
 def test_push_pull_group_duplicate_key_does_not_deadlock(ps_server):
     """A repeated declared key inside one group (two rounds of the same
-    tensor) must flush-and-proceed, not deadlock the sequential-use guard
-    against the group's own batched enqueue."""
+    tensor) must proceed, not deadlock the sequential-use guard: the
+    earlier round is already queued when the later one is staged."""
     from byteps_tpu.server.client import PSSession
 
     port = ps_server(num_workers=1)
@@ -238,6 +238,152 @@ def test_push_pull_group_duplicate_key_does_not_deadlock(ps_server):
     np.testing.assert_array_equal(h1.wait(timeout=60), a)   # round 0
     np.testing.assert_array_equal(h2.wait(timeout=60), b)   # round 1
     s.close()
+
+
+def _session(ps_server):
+    from byteps_tpu.server.client import PSSession
+
+    port = ps_server(num_workers=1)
+    return PSSession(["127.0.0.1"], [port], worker_id=0, num_servers=1)
+
+
+def test_push_pull_group_first_item_on_the_wire_while_second_stages(
+        ps_server):
+    """Streamed staging: an item is in the scheduler the moment it is
+    staged, so its push leaves while the next item's copy off the device
+    is still awaited (here: a `_stage` that waits for that push)."""
+    s = _session(ps_server)
+    s.record_push_order = True
+    real_stage, pushed_before_second = s._stage, []
+
+    def slow_stage(declared_key, *args):
+        if declared_key == 21:
+            deadline = time.time() + 20
+            while not s.push_order and time.time() < deadline:
+                time.sleep(0.005)
+            pushed_before_second.append(list(s.push_order))
+        return real_stage(declared_key, *args)
+
+    s._stage = slow_stage
+    a, b = np.ones(256, np.float32), np.full(256, 2.0, np.float32)
+    h1, h2 = s.push_pull_group([(20, a, 1), (21, b, 0)])
+    assert pushed_before_second == [[20 << 16]]
+    np.testing.assert_array_equal(h1.wait(timeout=60), a)
+    np.testing.assert_array_equal(h2.wait(timeout=60), b)
+    assert s.push_order == [20 << 16, 21 << 16]
+    s.close()
+
+
+def test_push_pull_group_strict_order_with_equal_priorities(ps_server):
+    """Under pause_dispatch the scheduler sees the whole set, however
+    the items arrive, and picks (priority desc, key asc): key breaks
+    the ties among equal priorities."""
+    s = _session(ps_server)
+    items = [(42, 1), (40, 1), (41, 2), (43, 0), (44, 1), (45, 2)]
+    tensors = {k: np.full(128, float(k), np.float32) for k, _ in items}
+    s.record_push_order = True
+    s.pause_dispatch()
+    handles = s.push_pull_group([(k, tensors[k], p) for k, p in items])
+    s.resume_dispatch()
+    for (k, _), h in zip(items, handles):
+        np.testing.assert_array_equal(h.wait(timeout=60), tensors[k])
+    assert s.push_order == [k << 16 for k in (41, 45, 40, 42, 44, 43)]
+    s.close()
+
+
+class _NoCopy:
+    """A tensor whose copy off the device fails."""
+
+    def __array__(self, *args, **kwargs):
+        raise RuntimeError("no copy off the device")
+
+
+@pytest.mark.parametrize("fails_in", ["d2h", "parts"])
+def test_push_pull_group_item_that_fails_to_stage(ps_server, fails_in):
+    """Item k fails mid-group: the items before it are already on the
+    wire and complete, the exception surfaces, nothing of item k stays
+    pinned, and the next group over the same keys goes through."""
+    s = _session(ps_server)
+    a, b, c = (np.full(300, v, np.float32) for v in (1.0, 2.0, 3.0))
+    real_stage, real_parts, staged = s._stage, s._stage_parts, []
+
+    def recording_stage(*args):
+        out = real_stage(*args)
+        staged.append(out[0])
+        return out
+
+    def failing_parts(plan, *args, **kwargs):
+        real_parts(plan, *args, **kwargs)
+        if plan[0][0] >> 16 == 31:      # after its parts are pinned
+            raise RuntimeError("staging failed")
+
+    s._stage = recording_stage
+    if fails_in == "parts":
+        s._stage_parts = failing_parts
+    bad = _NoCopy() if fails_in == "d2h" else b
+    with pytest.raises(RuntimeError, match="no copy|staging failed"):
+        s.push_pull_group([(30, a, 2), (31, bad, 1), (32, c, 0)])
+    assert len(staged) == 1             # item 0 only; item 2 never began
+    np.testing.assert_array_equal(staged[0].wait(timeout=60), a)
+    with s._inflight_lock:
+        assert not s._inflight
+    s._stage_parts = real_parts
+    handles = s.push_pull_group([(30, 2 * a, 2), (31, b, 1), (32, c, 0)])
+    for h, want in zip(handles, (2 * a, b, c)):
+        np.testing.assert_array_equal(h.wait(timeout=60), want)
+    s.close()
+
+
+def test_fused_tree_round_counts_units_early(ps_server):
+    """`units_early` on the ROUND of a traced fused push_pull_tree is
+    `units` - 1: every unit but the last was in the scheduler before the
+    round's last copy off the device began.  With tracing off no ROUND
+    is open, and the count adds to nothing."""
+    import subprocess
+    import sys
+
+    from testutil import cpu_env
+
+    port = ps_server(num_workers=1)
+    code = """
+import json, os, tempfile, numpy as np, jax.numpy as jnp
+import byteps_tpu as bps
+from byteps_tpu.core.native import get_core
+bps.init()
+core = get_core()
+sess = bps.get_ps_session()
+tree = {f"g{i:02d}": jnp.full((2000,), float(i), jnp.float32)
+        for i in range(12)}
+real_count, open_rounds = sess.spans.count, []
+def count(**add):
+    open_rounds.append(getattr(sess.spans._open, "counts", None))
+    real_count(**add)
+sess.spans.count = count
+bps.push_pull_tree(tree, average=False)          # tracing off
+assert open_rounds and all(c is None for c in open_rounds), open_rounds
+assert sess.spans._rounds == 0
+core.trace_enable(True)
+out = bps.push_pull_tree(tree, average=False)
+path = os.path.join(tempfile.mkdtemp(), "trace.json")
+core.trace_dump(path, 0)
+rows = json.load(open(path))["traceEvents"]
+(rnd,) = [r for r in rows if r["tid"] == "ROUND"]
+d2h = [r for r in rows if r["tid"] == "D2H"]
+assert rnd["args"]["units"] == len(d2h) >= 3, rnd
+assert rnd["args"]["units_early"] == rnd["args"]["units"] - 1, rnd
+for k in tree:
+    np.testing.assert_array_equal(np.asarray(out[k]), np.asarray(tree[k]))
+print("EARLY_OK")
+"""
+    env = cpu_env({
+        "BYTEPS_TPU_PS_MODE": "1", "DMLC_NUM_WORKER": "1",
+        "DMLC_NUM_SERVER": "1", "DMLC_PS_ROOT_PORT": str(port - 1),
+        "BYTEPS_TPU_FUSION_BYTES": "16384",
+    })
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=180)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "EARLY_OK" in r.stdout
 
 
 def test_fused_tree_trace_spans_priority_descending(ps_server):

@@ -660,7 +660,10 @@ def test_round_spans_count_the_tree(round_events):
         a = rnd["args"]
         assert a["units"] == len(d2h)
         assert a["bytes_out"] == a["bytes_in"] == tree_bytes
-        assert set(a) == {"round", "units", "bytes_out", "bytes_in"}
+        assert set(a) == {"round", "units", "units_early", "bytes_out",
+                          "bytes_in"}
+        # a group queues each unit before the next one's copy begins
+        assert a["units_early"] == (a["units"] - 1 if a["units"] > 1 else 0)
         # a unit's key is its partitions' key above bit 16
         parts = [e for e in events if e["tid"] == "PUSH"
                  and rnd["ts"] <= e["ts"] < _end(rnd)]
