@@ -349,7 +349,7 @@ def flash_auto_block(S: int) -> int:
 
 
 def flash_attention_fn(q, k, v, causal: bool, block: int = 0,
-                       block_k: int = 0):
+                       block_k: int = 0, window: Optional[int] = None):
     """Adapter: [B, H, S, Dh] heads-layout -> the Pallas flash-attention
     kernel's [BH, S, Dh] layout.  A shape the kernel cannot tile (S not a
     multiple of 128, or Dh not a multiple of 8) raises ValueError: an
@@ -367,7 +367,10 @@ def flash_attention_fn(q, k, v, causal: bool, block: int = 0,
     waste on causal diagonals, and the optimum need not be square.
     Overrides must divide S and be a multiple of 128 (block) or 64
     (block_k), the tile sizes the chip's compiler accepts; anything else
-    reverts to the AUTO choice."""
+    reverts to the AUTO choice.
+
+    `window` (causal only) is the kernel's sliding window: row i sees the
+    keys i - window < j <= i.  None is full attention."""
     from ..ops.flash_attention import (BLOCK_K_MULTIPLE, BLOCK_Q_MULTIPLE,
                                        flash_attention)
     B, H, S, Dh = q.shape
@@ -384,8 +387,9 @@ def flash_attention_fn(q, k, v, causal: bool, block: int = 0,
 
     def fold(t):
         return t.reshape(B * H, S, Dh)
+    windowed = () if window is None else (None, None, window)
     out = flash_attention(fold(q), fold(k), fold(v), causal, None,
-                          block, block_k)
+                          block, block_k, *windowed)
     return out.reshape(B, H, S, Dh)
 
 

@@ -94,31 +94,62 @@ def _use_streaming(q, streaming: Optional[bool]) -> bool:
     return 2 * s * d * q.dtype.itemsize > RESIDENT_VMEM_BUDGET
 
 
-def _causal_mask(s, qi, kb, block_q, block_k):
-    """Mask logits where key position > query position (global indices)."""
+def _causal_mask(s, qi, kb, block_q, block_k, window=None):
+    """Mask logits where key position > query position (global indices)
+    and, under a sliding `window`, where it is `window` or more behind:
+    row i sees the keys i - window < j <= i."""
     rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + qi * block_q
     cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + kb * block_k
-    return jnp.where(rows >= cols, s, NEG_INF)
+    keep = rows >= cols
+    if window is not None:
+        keep = keep & (rows - cols < window)
+    return jnp.where(keep, s, NEG_INF)
 
 
-def _block_live(causal, qi, kb, block_q, block_k):
+def _block_live(causal, qi, kb, block_q, block_k, window=None):
     """Whether any (row, col) in this (q block, k block) pair is visible."""
     if not causal:
         return True
-    return (qi + 1) * block_q - 1 >= kb * block_k
+    live = (qi + 1) * block_q - 1 >= kb * block_k
+    if window is not None:
+        # the block's first row still reaches the block's last column
+        live = live & (qi * block_q - ((kb + 1) * block_k - 1) < window)
+    return live
 
 
-def _online_step(q_scaled, k, v, carry, qi, kb, causal, block_q, block_k):
+def _first_kb(qi, block_q, block_k, window):
+    """The first k block a q block's rows can see under `window`."""
+    return jnp.maximum(qi * block_q - window + 1, 0) // block_k
+
+
+def _qb_end(ki, block_q, block_k, window, num_qb):
+    """One past the last q block that can see k block `ki` under
+    `window`: its last column is seen by rows up to window - 1 later."""
+    return jnp.minimum(
+        num_qb, ((ki + 1) * block_k + window - 2) // block_q + 1)
+
+
+def _online_step(q_scaled, k, v, carry, qi, kb, causal, block_q, block_k,
+                 window=None):
     """One online-softmax accumulation step shared by both forward paths."""
     m, l, acc = carry
     s = jax.lax.dot_general(
         q_scaled, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)              # (bq, bk)
     if causal:
-        s = _causal_mask(s, qi, kb, block_q, block_k)
+        s = _causal_mask(s, qi, kb, block_q, block_k, window)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-    alpha = jnp.exp(m - m_new)
-    p = jnp.exp(s - m_new)
+    if window is None:
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(s - m_new)
+    else:
+        # Under a window a row can meet a live block of which it sees
+        # nothing before it has seen any key: its running max is still
+        # -inf, and exp(-inf - -inf) would be NaN.  (Causal alone never
+        # meets this: every row sees column 0 of the first block.)
+        m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
+        alpha = jnp.exp(m - m_safe)
+        p = jnp.exp(s - m_safe)
     l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
     acc_new = acc * alpha + jnp.dot(p, v,
                                     preferred_element_type=jnp.float32)
@@ -126,12 +157,12 @@ def _online_step(q_scaled, k, v, carry, qi, kb, causal, block_q, block_k):
 
 
 def _dq_step(q, k, v, do, lse, delta, sm_scale, qi, kb, causal, block_q,
-             block_k):
+             block_k, window=None):
     s = sm_scale * jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
     if causal:
-        s = _causal_mask(s, qi, kb, block_q, block_k)
+        s = _causal_mask(s, qi, kb, block_q, block_k, window)
     p = jnp.exp(s - lse)                                 # (bq, bk)
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
@@ -141,12 +172,12 @@ def _dq_step(q, k, v, do, lse, delta, sm_scale, qi, kb, causal, block_q,
 
 
 def _dkv_step(q, k, v, do, lse, delta, sm_scale, qb, ki, causal, block_q,
-              block_k):
+              block_k, window=None):
     s = sm_scale * jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)              # (bq, bk)
     if causal:
-        s = _causal_mask(s, qb, ki, block_q, block_k)
+        s = _causal_mask(s, qb, ki, block_q, block_k, window)
     p = jnp.exp(s - lse)
     dv = jax.lax.dot_general(
         p, do, (((0,), (0,)), ((), ())),
@@ -165,7 +196,7 @@ def _dkv_step(q, k, v, do, lse, delta, sm_scale, qb, ki, causal, block_q,
 # Resident path: K/V whole in VMEM; grid (bh, q_blocks); fori_loop over k.
 # ---------------------------------------------------------------------------
 def _fwd_kernel_res(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
-                    causal, block_q, block_k, seq_len):
+                    causal, block_q, block_k, seq_len, window=None):
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * sm_scale          # (bq, d)
     bq, d = q.shape
@@ -178,12 +209,14 @@ def _fwd_kernel_res(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
         k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
         v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
         return _online_step(q, k, v, carry, qi, kb, causal, block_q,
-                            block_k)
+                            block_k, window)
 
     m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
     acc0 = jnp.zeros((bq, d), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, num_kb, body, (m0, l0, acc0))
+    first_kb = 0 if window is None else _first_kb(qi, block_q, block_k,
+                                                  window)
+    m, l, acc = jax.lax.fori_loop(first_kb, num_kb, body, (m0, l0, acc0))
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     # Layout (BH, 1, S): TPU block tiling needs the last two dims to be
     # (1, block) with both tile-divisible or dim-equal.
@@ -191,7 +224,8 @@ def _fwd_kernel_res(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
 
 
 def _dq_kernel_res(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   *, sm_scale, causal, block_q, block_k, seq_len):
+                   *, sm_scale, causal, block_q, block_k, seq_len,
+                   window=None):
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
@@ -207,22 +241,26 @@ def _dq_kernel_res(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
         v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
         return dq + _dq_step(q, k, v, do, lse, delta, sm_scale, qi, kb,
-                             causal, block_q, block_k)
+                             causal, block_q, block_k, window)
 
-    dq = jax.lax.fori_loop(0, num_kb, body,
+    first_kb = 0 if window is None else _first_kb(qi, block_q, block_k,
+                                                  window)
+    dq = jax.lax.fori_loop(first_kb, num_kb, body,
                            jnp.zeros((bq, d), jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
 def _dkv_kernel_res(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *, sm_scale, causal, block_q, block_k,
-                    seq_len):
+                    seq_len, window=None):
     ki = pl.program_id(1)
     k = k_ref[0].astype(jnp.float32)                     # (bk, d)
     v = v_ref[0].astype(jnp.float32)
     bk, d = k.shape
     num_qb = seq_len // block_q
     start_qb = (ki * block_k) // block_q if causal else 0
+    if window is not None:
+        num_qb = _qb_end(ki, block_q, block_k, window, num_qb)
 
     def body(qb, carry):
         dk, dv = carry
@@ -231,7 +269,7 @@ def _dkv_kernel_res(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         lse = lse_ref[0, 0, pl.ds(qb * block_q, block_q)][:, None]
         delta = delta_ref[0, 0, pl.ds(qb * block_q, block_q)][:, None]
         dk_i, dv_i = _dkv_step(q, k, v, do, lse, delta, sm_scale, qb, ki,
-                               causal, block_q, block_k)
+                               causal, block_q, block_k, window)
         return dk + dk_i, dv + dv_i
 
     z = jnp.zeros((bk, d), jnp.float32)
@@ -244,7 +282,8 @@ def _dkv_kernel_res(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # Streaming path: 3D grid, contraction axis innermost, scratch carries.
 # ---------------------------------------------------------------------------
 def _fwd_kernel_str(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                    acc_scr, *, sm_scale, causal, block_q, block_k):
+                    acc_scr, *, sm_scale, causal, block_q, block_k,
+                    window=None):
     qi = pl.program_id(1)
     kb = pl.program_id(2)
     last_kb = pl.num_programs(2) - 1
@@ -255,14 +294,14 @@ def _fwd_kernel_str(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(_block_live(causal, qi, kb, block_q, block_k))
+    @pl.when(_block_live(causal, qi, kb, block_q, block_k, window))
     def _step():
         q = q_ref[0].astype(jnp.float32) * sm_scale
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         m, l, acc = _online_step(q, k, v,
                                  (m_scr[:], l_scr[:], acc_scr[:]),
-                                 qi, kb, causal, block_q, block_k)
+                                 qi, kb, causal, block_q, block_k, window)
         m_scr[:], l_scr[:], acc_scr[:] = m, l, acc
 
     @pl.when(kb == last_kb)
@@ -273,7 +312,8 @@ def _fwd_kernel_str(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
 
 def _dq_kernel_str(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_scr, *, sm_scale, causal, block_q, block_k):
+                   dq_scr, *, sm_scale, causal, block_q, block_k,
+                   window=None):
     qi = pl.program_id(1)
     kb = pl.program_id(2)
     last_kb = pl.num_programs(2) - 1
@@ -282,7 +322,7 @@ def _dq_kernel_str(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(_block_live(causal, qi, kb, block_q, block_k))
+    @pl.when(_block_live(causal, qi, kb, block_q, block_k, window))
     def _step():
         dq_scr[:] = dq_scr[:] + _dq_step(
             q_ref[0].astype(jnp.float32),
@@ -290,7 +330,7 @@ def _dq_kernel_str(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             v_ref[0].astype(jnp.float32),
             do_ref[0].astype(jnp.float32),
             lse_ref[0, 0, :][:, None], delta_ref[0, 0, :][:, None],
-            sm_scale, qi, kb, causal, block_q, block_k)
+            sm_scale, qi, kb, causal, block_q, block_k, window)
 
     @pl.when(kb == last_kb)
     def _finish():
@@ -299,7 +339,7 @@ def _dq_kernel_str(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel_str(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale, causal,
-                    block_q, block_k):
+                    block_q, block_k, window=None):
     ki = pl.program_id(1)
     qb = pl.program_id(2)
     last_qb = pl.num_programs(2) - 1
@@ -309,7 +349,7 @@ def _dkv_kernel_str(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(_block_live(causal, qb, ki, block_q, block_k))
+    @pl.when(_block_live(causal, qb, ki, block_q, block_k, window))
     def _step():
         dk_i, dv_i = _dkv_step(
             q_ref[0].astype(jnp.float32),
@@ -317,7 +357,7 @@ def _dkv_kernel_str(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             v_ref[0].astype(jnp.float32),
             do_ref[0].astype(jnp.float32),
             lse_ref[0, 0, :][:, None], delta_ref[0, 0, :][:, None],
-            sm_scale, qb, ki, causal, block_q, block_k)
+            sm_scale, qb, ki, causal, block_q, block_k, window)
         dk_scr[:] = dk_scr[:] + dk_i
         dv_scr[:] = dv_scr[:] + dv_i
 
@@ -338,15 +378,28 @@ def _lse_spec(block_q):
     return pl.BlockSpec((1, 1, block_q), lambda b, i, *_: (b, 0, i))
 
 
-def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, streaming):
+def _windowed(window, kind):
+    """The extra keywords of a windowed call, for the kernel and for
+    `pallas_call`: the window, and a name that says the kind of call and
+    the window (`flash_fwd_w2048`), which is how a device trace tells a
+    sliding layer's calls from a full layer's.  None for `window=None`,
+    which leaves those calls exactly as they were."""
+    if window is None:
+        return {}, {}
+    return {"window": window}, {"name": f"flash_{kind}_w{window}"}
+
+
+def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, streaming,
+         window=None):
     bh, s, d = q.shape
+    kw, named = _windowed(window, "fwd")
     out_shape = [jax.ShapeDtypeStruct((bh, s, d), q.dtype),
                  jax.ShapeDtypeStruct((bh, 1, s), jnp.float32)]
     if streaming:
         return pl.pallas_call(
             functools.partial(_fwd_kernel_str, sm_scale=sm_scale,
                               causal=causal, block_q=block_q,
-                              block_k=block_k),
+                              block_k=block_k, **kw),
             grid=(bh, s // block_q, s // block_k),
             in_specs=[
                 _q_spec(block_q, d),
@@ -360,25 +413,27 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, streaming):
                 pltpu.VMEM((block_q, 1), jnp.float32),   # running sum l
                 pltpu.VMEM((block_q, d), jnp.float32),   # accumulator
             ],
-            interpret=interpret,
+            interpret=interpret, **named,
         )(q, k, v)
     kv_spec = pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0))
     return pl.pallas_call(
         functools.partial(_fwd_kernel_res, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=s),
+                          block_q=block_q, block_k=block_k, seq_len=s, **kw),
         grid=(bh, s // block_q),
         in_specs=[_q_spec(block_q, d), kv_spec, kv_spec],
         out_specs=[_q_spec(block_q, d), _lse_spec(block_q)],
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret, **named,
     )(q, k, v)
 
 
 def _bwd(sm_scale, causal, block_q, block_k, interpret, streaming,
-         residuals, g):
+         residuals, g, window=None):
     q, k, v, o, lse = residuals
     do = g
     bh, s, d = q.shape
+    kw, dq_named = _windowed(window, "dq")
+    dkv_named = _windowed(window, "dkv")[1]
     # delta_i = rowsum(dO_i * O_i): tiny elementwise pass, XLA fuses it.
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None, :]                 # (bh, 1, s)
@@ -386,7 +441,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, streaming,
         dq = pl.pallas_call(
             functools.partial(_dq_kernel_str, sm_scale=sm_scale,
                               causal=causal, block_q=block_q,
-                              block_k=block_k),
+                              block_k=block_k, **kw),
             grid=(bh, s // block_q, s // block_k),
             in_specs=[
                 _q_spec(block_q, d),
@@ -398,7 +453,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, streaming,
             out_specs=_q_spec(block_q, d),
             out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-            interpret=interpret,
+            interpret=interpret, **dq_named,
         )(q, k, v, do, lse, delta)
         kb_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
         qs_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0))
@@ -406,7 +461,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, streaming,
         dk, dv = pl.pallas_call(
             functools.partial(_dkv_kernel_str, sm_scale=sm_scale,
                               causal=causal, block_q=block_q,
-                              block_k=block_k),
+                              block_k=block_k, **kw),
             grid=(bh, s // block_k, s // block_q),
             in_specs=[qs_spec, kb_spec, kb_spec, qs_spec, ls_spec, ls_spec],
             out_specs=[kb_spec, kb_spec],
@@ -414,7 +469,7 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, streaming,
                        jax.ShapeDtypeStruct((bh, s, d), v.dtype)],
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                             pltpu.VMEM((block_k, d), jnp.float32)],
-            interpret=interpret,
+            interpret=interpret, **dkv_named,
         )(q, k, v, do, lse, delta)
         return dq, dk, dv
 
@@ -422,25 +477,25 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, streaming,
     full_lse2 = pl.BlockSpec((1, 1, s), lambda b, i: (b, 0, 0))
     dq = pl.pallas_call(
         functools.partial(_dq_kernel_res, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=s),
+                          block_q=block_q, block_k=block_k, seq_len=s, **kw),
         grid=(bh, s // block_q),
         in_specs=[_q_spec(block_q, d), full_spec2, full_spec2,
                   _q_spec(block_q, d), _lse_spec(block_q),
                   _lse_spec(block_q)],
         out_specs=_q_spec(block_q, d),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-        interpret=interpret,
+        interpret=interpret, **dq_named,
     )(q, k, v, do, lse, delta)
     kb2 = pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel_res, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_len=s),
+                          block_q=block_q, block_k=block_k, seq_len=s, **kw),
         grid=(bh, s // block_k),
         in_specs=[full_spec2, kb2, kb2, full_spec2, full_lse2, full_lse2],
         out_specs=[kb2, kb2],
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), k.dtype),
                    jax.ShapeDtypeStruct((bh, s, d), v.dtype)],
-        interpret=interpret,
+        interpret=interpret, **dkv_named,
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -448,13 +503,19 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, streaming,
 # ---------------------------------------------------------------------------
 # Public API
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, sm_scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
                     interpret: Optional[bool] = None,
-                    streaming: Optional[bool] = None) -> jax.Array:
+                    streaming: Optional[bool] = None,
+                    window: Optional[int] = None) -> jax.Array:
     """Blockwise (flash) attention.  q, k, v: [BH, S, D] -> [BH, S, D].
+
+    `window` (causal only) is a sliding window: row i attends to the keys
+    i - window < j <= i, and the blocks no row of a tile can see are
+    skipped in all three kernels, as the blocks above the diagonal are.
+    `window=None` is the plain kernel, unchanged.
 
     sm_scale defaults to 1/sqrt(D).  interpret=None auto-selects the
     Pallas interpreter off-TPU so tests run on the CPU mesh.
@@ -463,26 +524,31 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     streaming kernels beyond (O(block*D) VMEM at any S).
     """
     out, _ = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k,
-                        interpret, streaming)
+                        interpret, streaming, window)
     return out
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-               streaming):
+               streaming, window=None):
     bh, s, d = q.shape
     check_blocks(s, block_q, block_k)
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window={window} needs causal=True and at least "
+                         f"one key a row")
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     out, lse = _fwd(q, k, v, scale, causal, block_q, block_k,
-                    _use_interpret(interpret), _use_streaming(q, streaming))
+                    _use_interpret(interpret), _use_streaming(q, streaming),
+                    window)
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, streaming,
-               residuals, g):
+               window, residuals, g):
     d = residuals[0].shape[-1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     return _bwd(scale, causal, block_q, block_k, _use_interpret(interpret),
-                _use_streaming(residuals[0], streaming), residuals, g)
+                _use_streaming(residuals[0], streaming), residuals, g,
+                window)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

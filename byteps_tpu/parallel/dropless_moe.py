@@ -1,0 +1,256 @@
+"""One chip's share of a dropless mixture-of-experts layer.
+
+Expert parallelism gives each chip some of a layer's experts and every
+chip the whole router.  This is the part one chip computes: it is told
+which experts it holds, scores every token over ALL the experts, keeps
+the top `k` a token, and returns what its own experts add for the tokens
+routed to them,
+
+    out[t] = sum over e in sel[t], e held here, of w[t, e] * expert_e(x[t]),
+
+each expert a SwiGLU.  What the experts on the other chips would add is
+not here and nothing stands in for it: across chips the shares are summed
+by the exchange (`parallel/expert.py` has the all-to-all for the hybrid
+step's Switch layer, which is top-1 and drops tokens over a capacity; an
+exchange for this layer is not written yet).  On one chip the layer runs
+without it.
+
+No assignment to a held expert is dropped, whatever the routing:
+
+  - the (token, choice) pairs are sorted by the held expert they chose,
+    the pairs that chose an expert held elsewhere last;
+  - the first `rows` pairs (a static buffer, `capacity_factor` times the
+    even share, so that a usual step fits) are gathered, go through the
+    three expert products grouped by expert (`lax.ragged_dot`, which on a
+    TPU is a kernel that skips the tiles past the last group), are
+    weighted, and are scatter-added back to their tokens;
+  - pairs past the buffer go through the same code, a small buffer at a
+    time (`past_rows`, an eighth of the first), in a loop that runs only
+    while pairs are left (`_past_the_buffer`).  That is the exact path: no
+    faster a row, and taken only when the routing is more skewed than the
+    buffer allows.  Its buffers are small so that a routing a little past
+    the first buffer pays a little: a step's time then follows the rows,
+    and does not jump by a whole pass where they cross the buffer's edge.
+    `Routing.overflow` counts the pairs it took.
+
+The router's scores, top-k and weights are float32 (the score matmul at
+`highest` precision: on a TPU a float32 matmul is otherwise one bfloat16
+pass, and top-k is discontinuous in the scores).  `expert_bias` is the
+load balancer's buffer, added to the scores for the choice alone.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+
+@dataclasses.dataclass(frozen=True)
+class MoEConfig:
+    num_experts: int                 # the router's width: all the experts
+    top_k: int
+    held: Tuple[int, ...]            # ids of the experts this chip holds
+    route_scale: float = 1.0
+    route_norm: bool = True          # weights of a token sum to route_scale
+    score_func: str = "sigmoid"      # "sigmoid" | "softmax"
+    capacity_factor: float = 1.25    # the buffer over the even share
+    row_multiple: int = 512          # the buffer is a multiple of this
+
+    def __post_init__(self):
+        if self.score_func not in ("sigmoid", "softmax"):
+            raise ValueError(f"score_func={self.score_func!r}")
+        if not 1 <= self.top_k <= self.num_experts:
+            raise ValueError(f"top_k={self.top_k} of {self.num_experts}")
+        if (len(set(self.held)) != len(self.held) or not self.held
+                or not all(0 <= e < self.num_experts for e in self.held)):
+            raise ValueError(f"held={self.held} must be distinct ids below "
+                             f"{self.num_experts}")
+
+    def buffer_rows(self, n_tokens: int) -> int:
+        """Rows of the static buffer for `n_tokens` tokens: the even
+        share of the (token, choice) pairs times `capacity_factor`, up to
+        a multiple of `row_multiple`, and never more than every pair."""
+        pairs = n_tokens * self.top_k
+        even = pairs * len(self.held) / self.num_experts
+        m = self.row_multiple
+        rows = -(-int(even * self.capacity_factor + 0.5) // m) * m
+        return max(min(rows, -(-pairs // m) * m), m)
+
+    def past_rows(self, n_tokens: int) -> int:
+        """Rows of one buffer of the exact path: an eighth of the first
+        buffer, up to a multiple of `row_multiple`."""
+        m = self.row_multiple
+        return -(-self.buffer_rows(n_tokens) // (8 * m)) * m
+
+
+class Routing(NamedTuple):
+    """What the router decided, and the counters of the layer."""
+    sel: jax.Array        # [T, k] int32, the experts each token chose
+    weights: jax.Array    # [T, k] float32
+    held_rows: jax.Array  # () int32: pairs that chose an expert held here
+    counts: jax.Array     # [len(held)] int32: pairs a held expert
+    overflow: jax.Array   # () int32: pairs past the buffer (exact path)
+
+
+def route(x, router_w, cfg: MoEConfig, expert_bias=None, sel=None):
+    """Scores `x` [T, D] over all experts and returns `(sel, weights)`,
+    both [T, k].  `expert_bias` [E] moves the choice and not the weights
+    (the load balancer's buffer; None is zero).  `sel`, if given, is used
+    in place of the top-k: the scores and weights are then this router's
+    own at somebody else's choice, which is how a reference is compared
+    apart from the choice."""
+    with jax.default_matmul_precision("highest"):
+        logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+    if cfg.score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    if sel is None:
+        biased = scores if expert_bias is None else (
+            scores + lax.stop_gradient(expert_bias.astype(jnp.float32)))
+        _, sel = lax.top_k(lax.stop_gradient(biased), cfg.top_k)
+    weights = jnp.take_along_axis(scores, sel, axis=-1)
+    if cfg.route_norm:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return sel, weights * cfg.route_scale
+
+
+def _swiglu_grouped(xg, experts, group_sizes, dtype):
+    """The three products of every held expert on its own rows of `xg`
+    [rows, D], which lie grouped by expert, `group_sizes` rows each."""
+    def grouped(lhs, w):
+        return lax.ragged_dot(lhs, w.astype(dtype), group_sizes)
+    h = jax.nn.silu(grouped(xg, experts["gate_w"])) * grouped(
+        xg, experts["up_w"])
+    return grouped(h, experts["down_w"])
+
+
+class _Plan(NamedTuple):
+    """The sorted (token, choice) pairs; integers, nothing to
+    differentiate."""
+    order: jax.Array      # [rows + n * past] pair indices, held experts first
+    starts: jax.Array     # [len(held)] where each held expert's pairs begin
+    ends: jax.Array
+    held_rows: jax.Array  # ()
+
+
+def _plan(sel, cfg: MoEConfig, rows: int, past: int) -> _Plan:
+    """Sorts the pairs by the held expert they chose, pairs of experts
+    held elsewhere last, and pads the list to whole buffers: the first of
+    `rows`, the exact path's of `past` each."""
+    n_held = len(cfg.held)
+    slot_of = np.full((cfg.num_experts,), n_held, np.int32)
+    slot_of[list(cfg.held)] = np.arange(n_held)
+    slot = jnp.asarray(slot_of)[sel.reshape(-1)]
+    order = jnp.argsort(slot, stable=True).astype(jnp.int32)
+    counts = (slot[:, None] == jnp.arange(n_held, dtype=jnp.int32)).sum(
+        0, dtype=jnp.int32)
+    ends = jnp.cumsum(counts)
+    pad = (rows - order.size if order.size <= rows
+           else -(order.size - rows) % past)
+    order = jnp.concatenate([order, jnp.zeros((pad,), jnp.int32)])
+    return _Plan(order, ends - counts, ends, ends[-1])
+
+
+def _buffer(lo, x, experts, flat_w, plan: _Plan, k: int, rows: int):
+    """What the pairs `[lo, lo + rows)` of the sorted list add to the
+    layer's result, [T, D] float32."""
+    pair = lax.dynamic_slice_in_dim(plan.order, lo, rows)
+    live = lo + jnp.arange(rows, dtype=jnp.int32) < plan.held_rows
+    token = pair // k
+    group_sizes = jnp.clip(jnp.minimum(plan.ends, lo + rows)
+                           - jnp.maximum(plan.starts, lo), 0)
+    # On a TPU the grouped product leaves the rows past the last group
+    # as it found them, in the forward and in the backward products alike
+    # (on the CPU they are zeros): whatever is there, NaN included, must
+    # reach neither the result nor a gradient.  So the rows are masked on
+    # the way in, which masks the gradient of the gather, and on the way
+    # out BEFORE the weights are multiplied in, whose gradient is
+    # otherwise 0 * NaN.
+    dead = ~live[:, None]
+    xg = jnp.where(dead, 0, x[token])
+    y = _swiglu_grouped(xg, experts, group_sizes, x.dtype)
+    y = jnp.where(dead, 0, y).astype(jnp.float32) * flat_w[pair][:, None]
+    return jnp.zeros(x.shape, jnp.float32).at[token].add(y)
+
+
+def _past_buffers(rows: int, past: int, plan: _Plan):
+    """How many of the exact path's buffers hold a pair."""
+    return -(-jnp.maximum(plan.held_rows - rows, 0) // past)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _past_the_buffer(k, rows, past, x, experts, flat_w, plan):
+    """The exact path: what the pairs past the first buffer add, `past`
+    of them at a time while pairs are left.  Forward and backward are loops
+    with a trip count that depends on the routing (`lax.fori_loop` to a
+    traced bound), which reverse-mode differentiation cannot cross by
+    itself and a `lax.scan` over every possible buffer would pay for with
+    stacked residuals (1.9 GB at the published widths): so the backward
+    is written here, each buffer's own `jax.vjp` summed."""
+    return lax.fori_loop(
+        0, _past_buffers(rows, past, plan),
+        lambda i, acc: acc + _buffer(rows + i * past, x, experts, flat_w,
+                                     plan, k, past),
+        jnp.zeros(x.shape, jnp.float32))
+
+
+def _past_fwd(k, rows, past, x, experts, flat_w, plan):
+    return (_past_the_buffer(k, rows, past, x, experts, flat_w, plan),
+            (x, experts, flat_w, plan))
+
+
+def _past_bwd(k, rows, past, residuals, g):
+    x, experts, flat_w, plan = residuals
+
+    def body(i, acc):
+        _, vjp = jax.vjp(
+            lambda x, e, w: _buffer(rows + i * past, x, e, w, plan, k, past),
+            x, experts, flat_w)
+        return jax.tree.map(jnp.add, acc, vjp(g))
+
+    grads = lax.fori_loop(
+        0, _past_buffers(rows, past, plan), body,
+        jax.tree.map(jnp.zeros_like, (x, experts, flat_w)))
+    return (*grads, None)
+
+
+_past_the_buffer.defvjp(_past_fwd, _past_bwd)
+
+
+def held_experts(x, router_w, experts, cfg: MoEConfig, expert_bias=None,
+                 sel=None):
+    """`x` [T, D] -> `(out [T, D], Routing)`: the held experts' part of
+    the layer.  `experts` holds `gate_w`, `up_w` [len(held), D, F] and
+    `down_w` [len(held), F, D], in the order of `cfg.held`."""
+    T = x.shape[0]
+    k = cfg.top_k
+    sel, weights = route(x, router_w, cfg, expert_bias, sel)
+    rows, past = cfg.buffer_rows(T), cfg.past_rows(T)
+    plan = _plan(sel, cfg, rows, past)
+    flat_w = weights.reshape(-1)
+    out = _buffer(jnp.int32(0), x, experts, flat_w, plan, k, rows)
+    if plan.order.size > rows:
+        out = out + _past_the_buffer(k, rows, past, x, experts, flat_w, plan)
+    routing = Routing(sel, weights, plan.held_rows, plan.ends - plan.starts,
+                      jnp.maximum(plan.held_rows - rows, 0))
+    return out.astype(x.dtype), routing
+
+
+def counters(routing: Routing, n_tokens: int) -> dict:
+    """The layer's counters from its own routing: pairs routed to held
+    experts over tokens (`k * held / experts` under even routing, which
+    is 1.0 where a chip holds an eighth of the experts and a token takes
+    8), the fullest held expert's load over the mean, and the pairs the
+    exact path took."""
+    counts = routing.counts.astype(jnp.float32)
+    return {"held_rows_per_token": routing.held_rows / n_tokens,
+            "max_load_over_mean": counts.max() / jnp.maximum(counts.mean(),
+                                                             1e-9),
+            "overflow_rows": routing.overflow}

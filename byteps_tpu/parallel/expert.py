@@ -1,4 +1,13 @@
-"""Expert parallelism: Switch-style MoE with all-to-all dispatch over 'ep'.
+"""The hybrid step's Switch layer: top-1 MoE with all-to-all dispatch over
+'ep', used by `models/hybrid.py` alone.
+
+This is NOT a dropless layer: routing is top-1 softmax, each expert takes
+at most `capacity` tokens and the rest are dropped, and dispatch and
+combine are one-hot `[T, E, C]` float32 einsums, which at tens of
+thousands of tokens and a hundred experts do not fit.  A model that
+routes top-k without drops (`models/afmoe.py`) runs through
+`parallel/dropless_moe.py`, which computes one chip's share of such a
+layer and has no exchange yet; this module has the exchange.
 
 Absent from the reference (SURVEY §2.6) but first-class here.  Top-1
 (Switch) routing with capacity limiting; experts are sharded over the 'ep'

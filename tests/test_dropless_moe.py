@@ -1,0 +1,202 @@
+"""The dropless expert layer (`byteps_tpu/parallel/dropless_moe.py`): no
+pair routed to a held expert is lost, whatever the routing, in the result
+and in every gradient, through the static buffer and through the exact
+path behind it."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from byteps_tpu.parallel import dropless_moe as dm
+
+E, K, D, F, T = 32, 4, 16, 8, 96
+HELD = (3, 4, 9, 20)            # not a range, not in the router's order
+CFG = dm.MoEConfig(num_experts=E, top_k=K, held=HELD, route_scale=2.5,
+                   row_multiple=8)
+ELSEWHERE = [e for e in range(E) if e not in HELD]
+
+
+def _weights(seed=0):
+    k = jax.random.split(jax.random.key(seed), 5)
+    x = jax.random.normal(k[0], (T, D))
+    router_w = jax.random.normal(k[1], (D, E)) / 4
+    experts = {"gate_w": jax.random.normal(k[2], (len(HELD), D, F)) / 4,
+               "up_w": jax.random.normal(k[3], (len(HELD), D, F)) / 4,
+               "down_w": jax.random.normal(k[4], (len(HELD), F, D)) / 3}
+    return x, router_w, experts
+
+
+def _plain(x, router_w, experts, cfg, sel):
+    """Every held expert on every token, times the token's weight for it."""
+    _, w = dm.route(x, router_w, cfg, sel=sel)
+    out = jnp.zeros_like(x)
+    for slot, e in enumerate(cfg.held):
+        coef = jnp.where(sel == e, w, 0.0).sum(-1)
+        h = jax.nn.silu(x @ experts["gate_w"][slot]) * (
+            x @ experts["up_w"][slot])
+        out = out + coef[:, None] * (h @ experts["down_w"][slot])
+    return out
+
+
+def _forced(kind):
+    """[T, K] choices: every token's pairs put where `kind` says."""
+    t = np.arange(T)
+    away = np.stack([np.roll(ELSEWHERE, -i)[:K] for i in t])
+    sel = away.copy()
+    if kind == "one_held_expert":
+        sel[:, 0] = HELD[2]
+    elif kind == "spread_evenly":
+        sel[:, 0] = np.asarray(HELD)[t % len(HELD)]
+    elif kind == "every_pair_held":
+        sel = np.stack([np.roll(HELD, -i)[:K] for i in t])
+    else:
+        assert kind == "none_held"
+    return jnp.asarray(sel, jnp.int32)
+
+
+ROUTINGS = {
+    # kind: (pairs on held experts, of which past the buffer)
+    "none_held": 0,
+    "spread_evenly": T,
+    "one_held_expert": T,
+    "every_pair_held": T * K,
+}
+
+
+@pytest.mark.parametrize("kind", ROUTINGS)
+def test_no_row_is_lost_under_forced_routing(kind):
+    x, router_w, experts = _weights()
+    sel = _forced(kind)
+    rows = CFG.buffer_rows(T)
+    assert rows == 64 < T                 # the buffer is smaller than T
+
+    def layer(x, router_w, experts):
+        out, routing = dm.held_experts(x, router_w, experts, CFG, sel=sel)
+        return (out * jnp.cos(out)).sum(), routing
+
+    def plain(x, router_w, experts):
+        out = _plain(x, router_w, experts, CFG, sel)
+        return (out * jnp.cos(out)).sum()
+
+    (got, routing), g = jax.jit(jax.value_and_grad(
+        layer, (0, 1, 2), has_aux=True))(x, router_w, experts)
+    want, g_want = jax.value_and_grad(plain, (0, 1, 2))(x, router_w, experts)
+    assert int(routing.held_rows) == ROUTINGS[kind]
+    assert int(routing.counts.sum()) == ROUTINGS[kind]
+    assert int(routing.overflow) == max(ROUTINGS[kind] - rows, 0)
+    if kind == "one_held_expert":
+        assert routing.counts.tolist() == [0, 0, T, 0]
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-4, atol=1e-5)
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(g_want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-6)
+    if kind == "none_held":
+        out, _ = dm.held_experts(x, router_w, experts, CFG, sel=sel)
+        assert not np.asarray(out).any()
+
+
+@pytest.mark.parametrize("kind,passes", [
+    ("none_held", 0), ("spread_evenly", 4), ("every_pair_held", 40)])
+def test_exact_path_takes_small_buffers(kind, passes):
+    """A routing a little past the first buffer (64 rows) pays for the
+    rows past it, 8 at a time, not for a second whole buffer."""
+    rows, past = CFG.buffer_rows(T), CFG.past_rows(T)
+    assert (rows, past) == (64, 8)
+    plan = dm._plan(_forced(kind), CFG, rows, past)
+    assert plan.order.size == T * K == rows + 40 * past
+    assert int(dm._past_buffers(rows, past, plan)) == passes
+    odd = dm._plan(_forced(kind)[:T - 1], CFG, rows, past)
+    assert (odd.order.size - rows) % past == 0 and odd.order.size >= 380
+
+
+def test_own_routing_matches_plain_and_counts():
+    x, router_w, experts = _weights(1)
+    out, routing = dm.held_experts(x, router_w, experts, CFG)
+    assert routing.sel.shape == (T, K)
+    np.testing.assert_allclose(np.asarray(routing.weights.sum(-1)),
+                               CFG.route_scale, rtol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(_plain(x, router_w, experts, CFG, routing.sel)),
+        rtol=1e-5, atol=1e-6)
+    held = np.isin(np.asarray(routing.sel), HELD).sum()
+    assert int(routing.held_rows) == held
+    c = dm.counters(routing, T)
+    assert float(c["held_rows_per_token"]) == pytest.approx(held / T)
+    assert float(c["max_load_over_mean"]) >= 1.0
+
+
+def test_buffer_rows_and_config_errors():
+    cfg = dm.MoEConfig(num_experts=128, top_k=8, held=tuple(range(16)))
+    assert cfg.buffer_rows(32768) == 40960       # 1.25 x the even share
+    assert cfg.buffer_rows(8192) == 10240
+    assert cfg.buffer_rows(4) == 512             # never less than a tile
+    # the exact path's buffers: an eighth of the first, in whole tiles
+    assert cfg.past_rows(32768) == 5120
+    assert cfg.past_rows(8192) == 1536
+    assert cfg.past_rows(4) == 512
+    whole = dataclasses.replace(cfg, held=tuple(range(128)))
+    assert whole.buffer_rows(1024) == 8192       # never more than all
+    with pytest.raises(ValueError):
+        dm.MoEConfig(num_experts=8, top_k=2, held=(1, 1))
+    with pytest.raises(ValueError):
+        dm.MoEConfig(num_experts=8, top_k=2, held=(8,))
+    with pytest.raises(ValueError):
+        dm.MoEConfig(num_experts=8, top_k=9, held=(0,))
+
+
+def _as_on_the_chip():
+    """`lax.ragged_dot` as the TPU's kernel behaves: the rows past the last
+    group are neither read nor written, in the product and in both of its
+    gradients.  Here they come back as NaN, the worst the chip's memory
+    can hold (found on the chip: PERF.md, Findings, PR 29)."""
+    real = jax.lax.ragged_dot
+
+    def dead(a, group_sizes):
+        return (jnp.arange(a.shape[0]) >= group_sizes.sum())[:, None]
+
+    @jax.custom_vjp
+    def ragged_dot(lhs, rhs, group_sizes):
+        return jnp.where(dead(lhs, group_sizes), jnp.nan,
+                         real(lhs, rhs, group_sizes))
+
+    def fwd(lhs, rhs, group_sizes):
+        return ragged_dot(lhs, rhs, group_sizes), (lhs, rhs, group_sizes)
+
+    def bwd(residuals, g):
+        lhs, rhs, group_sizes = residuals
+        gone = dead(lhs, group_sizes)
+        _, vjp = jax.vjp(lambda a, b: real(a, b, group_sizes),
+                         jnp.where(gone, 0, lhs), rhs)
+        d_lhs, d_rhs = vjp(jnp.where(gone, 0, g))
+        return jnp.where(gone, jnp.nan, d_lhs), d_rhs, None
+
+    ragged_dot.defvjp(fwd, bwd)
+    return ragged_dot
+
+
+@pytest.mark.parametrize("kind", ["spread_evenly", "every_pair_held"])
+def test_rows_past_the_last_group_reach_nothing(kind, monkeypatch):
+    """The buffer is longer than the pairs it holds; what the kernel
+    leaves in the rest must reach neither the result nor any gradient."""
+    monkeypatch.setattr(dm.lax, "ragged_dot", _as_on_the_chip())
+    x, router_w, experts = _weights(2)
+    sel = _forced(kind)
+
+    def layer(x, router_w, experts):
+        out, _ = dm.held_experts(x, router_w, experts, CFG, sel=sel)
+        return (out * jnp.cos(out)).sum()
+
+    def plain(x, router_w, experts):
+        out = _plain(x, router_w, experts, CFG, sel)
+        return (out * jnp.cos(out)).sum()
+
+    got, g = jax.value_and_grad(layer, (0, 1, 2))(x, router_w, experts)
+    want, g_want = jax.value_and_grad(plain, (0, 1, 2))(x, router_w, experts)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-4, atol=1e-5)
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(g_want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-3, atol=1e-5)

@@ -251,3 +251,81 @@ def test_streaming_autoselect_threshold():
     assert _use_streaming(big, None)
     assert _use_streaming(small, True)                # explicit override
     assert not _use_streaming(big, False)
+
+
+# ---------------------------------------------------------------------------
+# Sliding window
+# ---------------------------------------------------------------------------
+def _windowed_ref(q, k, v, window):
+    """Dense masked softmax: row i sees the keys i - window < j <= i."""
+    s = jnp.einsum("bqd,bkd->bqk", q, k) / np.sqrt(q.shape[-1])
+    i = jnp.arange(q.shape[1])[:, None]
+    j = jnp.arange(q.shape[1])[None, :]
+    s = jnp.where((i >= j) & (i - j < window), s, -jnp.inf)
+    return jnp.einsum("bqk,bkd->bqd", jax.nn.softmax(s, -1), v)
+
+
+@pytest.mark.parametrize("streaming", [False, True],
+                         ids=["resident", "streaming"])
+@pytest.mark.parametrize("window,bq,bk", [
+    (1, 128, 128),       # a row sees itself alone
+    (50, 128, 128),      # smaller than a block
+    (128, 128, 128),     # a block
+    (200, 128, 64),      # larger than a block, uneven tiles
+    (200, 256, 128),
+    (512, 128, 128),     # the sequence
+    (1000, 128, 128),    # larger than the sequence: causal
+], ids=lambda x: str(x))
+def test_windowed_kernels_against_dense(window, bq, bk, streaming):
+    """Forward and both backward kernels, the blocks a window never sees
+    skipped."""
+    rng = np.random.RandomState(1)
+    q, k, v, g = (_rand(rng, 2, 512, 64) for _ in range(4))
+
+    def flash(q, k, v):
+        return (flash_attention(q, k, v, True, None, bq, bk, True, streaming,
+                                window) * g).sum()
+
+    def dense(q, k, v):
+        return (_windowed_ref(q, k, v, window) * g).sum()
+
+    got, got_g = jax.value_and_grad(flash, (0, 1, 2))(q, k, v)
+    want, want_g = jax.value_and_grad(dense, (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    for a, b in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=1e-4)
+
+
+def test_window_none_is_the_kernel_as_it_was():
+    """`window=None` adds nothing to the traced program: the same jaxpr
+    with the argument and without, unnamed kernels, the same bits; and the
+    adapter asks for a window only where it is given one."""
+    rng = np.random.RandomState(2)
+    q, k, v = (_rand(rng, 2, 256, 64) for _ in range(3))
+
+    def old(q, k, v):
+        return flash_attention(q, k, v, True, None, 128, 128, True).sum()
+
+    def new(q, k, v):
+        return flash_attention(q, k, v, True, None, 128, 128, True, None,
+                               None).sum()
+
+    a = jax.make_jaxpr(jax.grad(old, (0, 1, 2)))(q, k, v)
+    b = jax.make_jaxpr(jax.grad(new, (0, 1, 2)))(q, k, v)
+    assert str(a) == str(b)
+    assert "flash_" not in str(a)
+    windowed = jax.make_jaxpr(jax.grad(lambda q, k, v: flash_attention(
+        q, k, v, True, None, 128, 128, True, None, 64).sum(), (0, 1, 2)))(
+            q, k, v)
+    assert all(n in str(windowed) for n in
+               ("flash_fwd_w64", "flash_dq_w64", "flash_dkv_w64"))
+    for x, y in zip(jax.grad(old, (0, 1, 2))(q, k, v),
+                    jax.grad(new, (0, 1, 2))(q, k, v)):
+        assert np.array_equal(np.asarray(x), np.asarray(y))
+    q4, k4, v4 = (t[None] for t in (q, k, v))
+    assert str(jax.make_jaxpr(lambda *a: flash_attention_fn(*a, True))(
+        q4, k4, v4)) == str(jax.make_jaxpr(
+            lambda *a: flash_attention_fn(*a, True, window=None))(q4, k4, v4))
+    with pytest.raises(ValueError):
+        flash_attention(q, k, v, False, None, 128, 128, True, None, 64)

@@ -57,14 +57,14 @@ def _compile(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile()
 
 
-def _flash_fwd_bwd(bh, s, d, block, streaming, device):
+def _flash_fwd_bwd(bh, s, d, block, streaming, device, window=None):
     x = jax.ShapeDtypeStruct((bh, s, d), jnp.bfloat16,
                              sharding=SingleDeviceSharding(device))
 
     def grads(q, k, v):
         def loss(q, k, v):
             out = fa.flash_attention(q, k, v, True, None, block, block,
-                                     False, streaming)
+                                     False, streaming, window)
             return jnp.sum(out.astype(jnp.float32))
         return jax.grad(loss, (0, 1, 2))(q, k, v)
 
@@ -80,6 +80,52 @@ def _flash_fwd_bwd(bh, s, d, block, streaming, device):
 def test_flash_fwd_bwd_compiles(v5e, bh, s, d, block, streaming):
     hlo = _flash_fwd_bwd(bh, s, d, block, streaming, v5e[0]).as_text()
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize("streaming", [None, True],
+                         ids=["resident", "streaming"])
+def test_windowed_flash_compiles_at_trinity_shapes(v5e, streaming):
+    """Head size 128, 8192 positions, window 2048: the sliding layers of
+    the afmoe cell (resident there; the streaming kernels too)."""
+    compiled = _flash_fwd_bwd(8, 8192, 128, 512, streaming, v5e[0],
+                              window=2048)
+    text = compiled.as_text()
+    assert all(f"flash_{kind}_w2048" in text
+               for kind in ("fwd", "dq", "dkv"))
+
+
+def test_dropless_expert_layer_compiles_at_trinity_widths(v5e):
+    """16 of 128 experts, 8 a token, hidden 2048, expert width 1024, a
+    sequence of 8192 tokens: forward alone (which once broke the
+    compiler's scatter emitter inside a loop) and with its gradient, the
+    grouped products as the chip's own ragged-dot kernels."""
+    from byteps_tpu.parallel import dropless_moe as dm
+    cfg = dm.MoEConfig(num_experts=128, top_k=8, held=tuple(range(16)),
+                       route_scale=2.826)
+    one = SingleDeviceSharding(v5e[0])
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    x = shape(8192, 2048, dtype=jnp.bfloat16)
+    experts = {"gate_w": shape(16, 2048, 1024),
+               "up_w": shape(16, 2048, 1024),
+               "down_w": shape(16, 1024, 2048)}
+
+    def layers(x, router_w, experts):
+        # inside a scan, as the model has it
+        def body(x, _):
+            out, routing = dm.held_experts(x, router_w, experts, cfg)
+            return x + out, routing.counts
+        return jax.lax.scan(body, x, None, length=2)
+
+    def loss(x, router_w, experts):
+        return layers(x, router_w, experts)[0].astype(jnp.float32).sum()
+
+    args = (x, shape(2048, 128), experts)
+    assert "ragged-dot" in _compile(layers, *args).as_text()
+    text = _compile(jax.grad(loss, (0, 1, 2)), *args).as_text()
+    assert text.count("ragged-dot-none") >= 9      # 3 products, 3 passes
 
 
 def test_flash_64_row_block_is_refused_up_front(v5e):
