@@ -33,7 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import doctor as doctor_mod
-from . import devprof, flightrec, signals, stage_spans, telemetry
+from . import (devprof, flightrec, host_memory, signals, stage_spans,
+               telemetry)
 from .config import Config, get_config
 from .logging import get_logger, set_level, set_rank
 from ..core.native import get_core
@@ -183,6 +184,13 @@ def init(lazy: bool = True) -> None:
                 "BYTEPS_TPU_PS_MODE=1 requires the PS server tier "
                 "(byteps_tpu.server.client), which is missing from this "
                 "build") from e
+        if jax.default_backend() != "cpu":
+            # The worker stages round-sized host buffers every step and
+            # frees them: from here on the process keeps freed memory,
+            # so a steady round writes into pages it already has.  Not
+            # on the CPU backend, where XLA's own arrays share the heap
+            # (common/host_memory.py).
+            host_memory.keep_freed_memory()
         _state.ps_session = PSSession.from_config(cfg)
         _state.ps_session.barrier()
         if cfg.evict_timeout_s > 0:
@@ -1060,10 +1068,12 @@ def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
             dispatch_order(units)
             plan_unit_names = {u[0] for u in units}
         with span("FREE", name):
-            # The round's host memory goes back here, under a span, and
+            # The round's host memory is let go here, under a span, and
             # not at the return: the copies off the device (cached by
             # the units' arrays) and the handles' result buffers, twice
-            # the tree's bytes, which takes as long as some stages do.
+            # the tree's bytes.  Where the allocator hands them back to
+            # the kernel (a worker without common/host_memory.py's
+            # policy) that takes as long as some stages do.
             for owner in (units, items, ctxs, handles):
                 owner.clear()
             payload = wire = ctx = h = out = None  # the last unit's

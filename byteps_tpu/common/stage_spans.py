@@ -22,6 +22,7 @@ round (``args.round`` of the server's spans).
 from __future__ import annotations
 
 import contextlib
+import resource
 import threading
 
 from ..core.native import get_core
@@ -62,6 +63,10 @@ class _Span:
         return False
 
 
+def _minor_faults() -> int:
+    return resource.getrusage(resource.RUSAGE_SELF).ru_minflt
+
+
 class RoundSpans:
     """A PS session's round counter, the round open on each thread, and
     the spans written under it."""
@@ -74,7 +79,12 @@ class RoundSpans:
     @contextlib.contextmanager
     def round(self, name: str):
         """The `ROUND` span around one `push_pull_tree` call.  Its args
-        are the round's number and what `count` adds up inside it."""
+        are the round's number, what `count` adds up inside it, and
+        `minflt`: the minor page faults the process took while it was
+        open, on every thread (the dispatcher's `recv_into` first
+        touches the result buffers).  Near the round's bytes in pages
+        where its host memory is new, near nothing where it was kept
+        (common/host_memory.py)."""
         core = get_core()
         if not core.trace_on:
             yield
@@ -86,7 +96,11 @@ class RoundSpans:
         self._open.counts = counts
         try:
             with _Span(core, "ROUND", name, counts):
-                yield
+                faults = _minor_faults()
+                try:
+                    yield
+                finally:
+                    counts["minflt"] = _minor_faults() - faults
         finally:
             self._open.counts = None
 

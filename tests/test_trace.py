@@ -661,7 +661,9 @@ def test_round_spans_count_the_tree(round_events):
         assert a["units"] == len(d2h)
         assert a["bytes_out"] == a["bytes_in"] == tree_bytes
         assert set(a) == {"round", "units", "units_early", "bytes_out",
-                          "bytes_in"}
+                          "bytes_in", "minflt"}
+        # the process's minor page faults while the ROUND was open
+        assert isinstance(a["minflt"], int) and a["minflt"] >= 0
         # a group queues each unit before the next one's copy begins
         assert a["units_early"] == (a["units"] - 1 if a["units"] > 1 else 0)
         # a unit's key is its partitions' key above bit 16
