@@ -1875,8 +1875,7 @@ def get_transport_stats() -> Dict[str, int]:
     the byte-credit scheduler's working signal).  The get_codec_stats()
     analog for the transport layer; all-zero outside PS mode.  Numeric
     keys export through the metrics registry's transport collector
-    (`bps_transport_*`); the `lanes` list is accessor-only.  Used by the
-    chaos/transport tests and BENCH_FAULT=1 / BENCH_WIRE=1 bench.py."""
+    (`bps_transport_*`); the `lanes` list is accessor-only."""
     if _state.ps_session is not None:
         return _state.ps_session.transport_stats()
     from ..server.client import PSSession

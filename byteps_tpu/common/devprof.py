@@ -27,14 +27,13 @@ host platform.  This module is the device plane:
   (pid = ``DEVICE_PID_BASE + rank``) already time-aligned with the wire
   spans.  A ``jax.profiler`` capture is laid beside them through the
   ``byteps.round`` annotations it holds (docs/timeline.md).
-- **The device sentinel**: bench.py's ``_device_stamp()`` platform
-  probe, refactored here as the single shared detector (bench stamping
-  and the live doctor can no longer drift).  Probed at ``bps.init()``
-  and re-probed on every signal-window roll; an intended-vs-actual
-  platform mismatch (``BYTEPS_TPU_DEVICE_PLATFORM``) or a probe error
-  convicts — doctor rule ``device_fallback`` (critical) fires within
-  one window, and ``mfu_regression`` watches the windowed MFU trend
-  with the wire held flat.  The sentinel starts no child process: a
+- **The device sentinel**: ``device_stamp()``'s platform probe, run
+  at ``bps.init()`` and again on every signal-window roll; an
+  intended-vs-actual platform mismatch
+  (``BYTEPS_TPU_DEVICE_PLATFORM``) or a probe error convicts — doctor
+  rule ``device_fallback`` (critical) fires within one window, and
+  ``mfu_regression`` watches the windowed MFU trend with the wire held
+  flat.  The sentinel starts no child process: a
   chip belongs to one process, so a child that probed the default
   backend while this one holds the chip could only fail or hang.
 
@@ -62,7 +61,7 @@ from .trace_analysis import DEVICE_PID_BASE
 SCHEMA = "bps-device-v1"
 
 #: Peak dense bf16 FLOPs/s per chip by device kind (public spec
-#: sheets).  Shared with bench.py — ONE table, no bench-vs-live drift.
+#: sheets).
 PEAK_BF16 = {
     "TPU v4": 275e12,
     "TPU v5 lite": 197e12,   # v5e
@@ -81,14 +80,11 @@ RECENT_STEPS = 64
 def peak_flops(device=None, kind: Optional[str] = None) -> float:
     """Peak dense bf16 FLOPs/s for a device (or a device_kind string).
 
-    ``BYTEPS_TPU_PEAK_FLOPS`` overrides (live plane knob);
-    ``BYTEPS_BENCH_PEAK_FLOPS`` is honored second so existing bench
-    launch configs keep working unchanged.  A CPU host has no entry and
+    ``BYTEPS_TPU_PEAK_FLOPS`` overrides.  A CPU host has no entry and
     returns 0.0 — MFU is then reported as ``None``, never a made-up
     number.  A TPU whose ``device_kind`` is missing from the table is
     an error, not a default: a wrong peak is a wrong MFU."""
-    env = os.environ.get("BYTEPS_TPU_PEAK_FLOPS") \
-        or os.environ.get("BYTEPS_BENCH_PEAK_FLOPS")
+    env = os.environ.get("BYTEPS_TPU_PEAK_FLOPS")
     if env:
         try:
             return float(env)
@@ -108,30 +104,24 @@ def peak_flops(device=None, kind: Optional[str] = None) -> float:
 
 
 def device_stamp() -> dict:
-    """Platform-honesty stamp, shared by bench records and the live
-    sentinel.
+    """Platform-honesty stamp the live sentinel convicts by.
 
     ``device_platform`` is what the jax backend actually initialized as
     by stamp time — or ``"none(host-only)"`` when no backend was ever
     touched (detected WITHOUT initializing one: a host-only process
-    must not claim the chip just to be stamped).  ``device_fallback``
-    is True when the process ended up on the CPU host platform without
-    the run being an explicit local CPU one (BENCH_FORCE_CPU)."""
+    must not claim the chip just to be stamped) — or ``"unknown(...)"``
+    when the probe itself raised."""
     try:
         xb = sys.modules.get("jax._src.xla_bridge")
         if xb is None or not xb._backends:
             # jax never imported, or imported with no backend
             # initialized: a host-only process.
-            return {"device_platform": "none(host-only)",
-                    "device_fallback": False}
+            return {"device_platform": "none(host-only)"}
         import jax
         platform = jax.devices()[0].platform
     except Exception as e:  # noqa: BLE001 — a stamp must never kill a record
-        return {"device_platform": f"unknown({e!r:.60})",
-                "device_fallback": True}
-    explicit_cpu = os.environ.get("BENCH_FORCE_CPU", "0") == "1"
-    return {"device_platform": platform,
-            "device_fallback": platform == "cpu" and not explicit_cpu}
+        return {"device_platform": f"unknown({e!r:.60})"}
+    return {"device_platform": platform}
 
 
 def cost_analysis_flops(fn, args: tuple) -> Optional[float]:
@@ -218,9 +208,8 @@ class DeviceProfiler:
     def probe(self) -> dict:
         """One sentinel pass: stamp the backend, convict a fallback.
 
-        Conviction law (the live refinement of the bench stamp): a
-        probe ERROR (``unknown(...)`` platform — jax internals moved,
-        or the backend raised mid-run) always convicts;
+        Conviction law: a probe ERROR (``unknown(...)`` platform — jax
+        internals moved, or the backend raised mid-run) always convicts;
         an intended platform (``BYTEPS_TPU_DEVICE_PLATFORM``) convicts
         on mismatch once a backend actually initialized.  A bare-CPU
         run with NO intent declared is healthy — the tier-1 suite and
@@ -229,8 +218,7 @@ class DeviceProfiler:
         ``"none(host-only)"`` with an intent declared stays quiet too:
         no backend has been touched yet, so there is nothing to convict
         (the first trainer step changes that)."""
-        st = device_stamp()
-        platform = str(st["device_platform"])
+        platform = str(device_stamp()["device_platform"])
         fallback, reason = False, ""
         if platform.startswith("unknown("):
             fallback = True
@@ -243,8 +231,7 @@ class DeviceProfiler:
         probe = {"platform": platform,
                  "intended": self.intended,
                  "fallback": fallback,
-                 "reason": reason,
-                 "stamp_fallback": bool(st["device_fallback"])}
+                 "reason": reason}
         with self._lock:
             self._last_probe = probe
         return dict(probe)

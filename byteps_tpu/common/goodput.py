@@ -26,8 +26,7 @@ oversubscribed) — the ledger is exact by construction either way, the
 split between estimated categories is the approximate part.
 
 Armed via the same plane as everything fleet (``BYTEPS_TPU_FLEET``);
-exports ``bps_fleet_goodput_pct`` plus per-category gauges, and feeds
-the ``BENCH_FLEET=1`` headline numbers in bench.py.
+exports ``bps_fleet_goodput_pct`` plus per-category gauges.
 """
 
 from typing import Dict, List, Optional

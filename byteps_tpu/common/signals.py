@@ -271,7 +271,7 @@ class SignalPlane:
         now = time.monotonic() if now is None else now
         with self._lock:
             # ALL window bookkeeping swaps under the one lock: roll() is
-            # public (tests, bench) and may race the background thread —
+            # public (tests) and may race the background thread —
             # each event interval and accumulator batch must belong to
             # exactly one window.
             acc, self._acc = self._acc, {}

@@ -82,16 +82,16 @@ class TransformerConfig:
     # Fused LM-head cross-entropy: > 0 streams the readout matmul + softmax
     # in row chunks of this size so the [B*S, vocab] logits are never
     # materialized (forward OR backward — each chunk is rematerialised).
-    # 0 = classic path through full logits.  At bert_large bench scale the
-    # full f32 logits are 3.2 GB and their HBM traffic is the largest
-    # non-matmul cost in the step.
+    # 0 = classic path through full logits.  At vocab 32768 and 24k rows
+    # (batch 48 x 512) the full f32 logits are 3.2 GB and their HBM
+    # traffic is the largest non-matmul cost in the step.
     ce_chunk_rows: int = 0
     # Unroll factor for the layer scan (lax.scan unroll=).  > 1 groups
     # that many layers per scan iteration: more code, but XLA can
     # schedule/fuse across adjacent layers and the stacked-param slice
     # overhead amortizes.  Remat granularity is unchanged (each layer
     # body is checkpointed individually).  Must divide num_layers or be
-    # 1; sweep knob BENCH_UNROLL.
+    # 1.
     scan_unroll: int = 1
 
     def __post_init__(self):
@@ -162,8 +162,8 @@ CONFIGS: Dict[str, TransformerConfig] = {
     # ~300M-param llama geometry: the largest modern-LLM config whose f32
     # master weights + Adam moments (~4.8 GB) leave headroom for a real
     # batch at seq 2048 on one 16 GB chip — llama_1b's ~9.3 GB of
-    # optimizer state OOMs the single-chip bench, so long-sequence
-    # single-chip sweeps run here (multi-chip llama_1b shards the state).
+    # optimizer state does not fit one chip, so long-sequence
+    # single-chip runs use this one (multi-chip llama_1b shards the state).
     "llama_300m": TransformerConfig(vocab_size=32768, num_layers=24,
                                     d_model=1024, num_heads=16,
                                     num_kv_heads=4, d_ff=2816,
@@ -329,11 +329,11 @@ def dense_attention(q, k, v, causal: bool):
 
 
 def flash_auto_block(S: int) -> int:
-    """The flash adapter's auto block-size rule, exported so records (e.g.
-    bench.py's JSON detail) can state the block that actually runs without
-    duplicating the logic.  Returns 0 when no valid block exists: the
-    kernel's Q tile must be a multiple of 128 (ops/flash_attention.py
-    `check_blocks`), so S must be one.
+    """The flash adapter's auto block-size rule, exported so a caller can
+    state the block that actually runs without duplicating the logic.
+    Returns 0 when no valid block exists: the kernel's Q tile must be a
+    multiple of 128 (ops/flash_attention.py `check_blocks`), so S must be
+    one.
 
     S <= 512: the full sequence as one block; per-program VMEM stays
     small (block x block f32 logits at 512 is 1 MB).  S > 512: the largest
@@ -360,9 +360,9 @@ def flash_attention_fn(q, k, v, causal: bool, block: int = 0,
     block=0 auto-selects via `flash_auto_block` (full-sequence block at
     S <= 512, the largest of 512/256/128 dividing S beyond).  A nonzero
     override trades grid-iteration overhead against VMEM per program by
-    hand (TransformerConfig.attn_block / BENCH_ATTN_BLOCK sweep it
-    on-chip); `block_k` additionally decouples the K/V tile from the Q
-    tile (TransformerConfig.attn_block_k) — at long S the Q tile sets
+    hand (TransformerConfig.attn_block); `block_k` additionally
+    decouples the K/V tile from the Q tile
+    (TransformerConfig.attn_block_k) — at long S the Q tile sets
     program count while the K tile sets per-iteration VMEM and masked
     waste on causal diagonals, and the optimum need not be square.
     Overrides must divide S and be a multiple of 128 (block) or 64

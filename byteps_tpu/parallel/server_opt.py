@@ -203,7 +203,7 @@ class ServerOptTrainer:
 
     def opt_state_bytes(self) -> int:
         """Optimizer-state bytes THIS WORKER holds — the redundancy the
-        server mode eliminates (the BENCH_SERVEROPT headline)."""
+        server mode eliminates."""
         if self.mode == "server":
             return 0
         import jax

@@ -3206,11 +3206,11 @@ class PSSession:
         # With the auditor armed, the response is payload + 24 trailer
         # bytes, so the zero-copy sink cannot length-match: audited pulls
         # ride a pooled buffer instead and _complete_pull splits/verifies
-        # before landing the body (one extra body copy per pull — the
-        # armed-only cost BENCH_AUDIT=1 measures; the unarmed path is
-        # untouched).  Health-SAMPLED rounds skip the sink for the same
-        # reason: the pooled payload routes through the codec pool, so
-        # the O(n) non-finite scan never runs on the receiver thread.
+        # before landing the body (one extra body copy per pull, paid
+        # only when armed; the unarmed path is untouched).
+        # Health-SAMPLED rounds skip the sink for the same reason: the
+        # pooled payload routes through the codec pool, so the O(n)
+        # non-finite scan never runs on the receiver thread.
         part.audit = self._audit_wire
         health_due = (self._health is not None
                       and self._health.pull_due(part.round))

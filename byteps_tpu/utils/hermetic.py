@@ -1,13 +1,13 @@
 """CPU subprocess environments.
 
 A chip belongs to one process at a time, so a child that must stay off
-the accelerator (a virtual-device dryrun, a host-only bench server) gets
+the accelerator (a virtual-device dryrun, a host-only PS server) gets
 an environment pinned to the CPU backend: `JAX_PLATFORMS=cpu`, with the
 TPU discovery variables stripped so nothing in the child goes looking
 for a chip.
 
-Used by tests/testutil.cpu_env, __graft_entry__.virtual_cpu_env and
-bench.bench_ps.
+Used by tests/testutil.cpu_env, __graft_entry__.virtual_cpu_env,
+tools/wire_bench.py and benchmark/tests.
 """
 
 from __future__ import annotations

@@ -4,8 +4,7 @@
     python chip_smoke.py --chips 4   # four chips: phase 1, then the dp=4 phase only
 
 Drives both gradient paths once through the entry points a user calls, at
-the full width of the flagship (bert_large exactly as bench.py builds it;
-weights random from --seed):
+the full width of the flagship (bert_large; weights random from --seed):
 
   1. device   — jax.devices() must be TPUs of a kind the peak table knows;
   2. in-graph — bps.init / make_mesh / DistributedOptimizer /
@@ -68,9 +67,9 @@ FULL_DP4 = dataclasses.replace(FULL, per_chip_batch=16)
 
 
 def flagship_config(**overrides):
-    """bert_large exactly as bench.bench_flagship builds it: 24 layers,
-    d_model 1024, 16 heads, d_ff 4096, vocab 32768, seq 512, bf16
-    activations, flash block 512, CE chunk 2048, per-layer remat."""
+    """bert_large as the flagship: 24 layers, d_model 1024, 16 heads,
+    d_ff 4096, vocab 32768, seq 512, bf16 activations, flash block 512,
+    CE chunk 2048, per-layer remat."""
     from byteps_tpu.models import transformer as tfm
     return tfm.get_config(
         "bert_large", causal=True, vocab_size=32768, max_seq_len=512,

@@ -30,11 +30,10 @@ if TOOLS not in sys.path:
 
 @pytest.fixture(autouse=True)
 def _clean_plane(monkeypatch):
-    """No process-wide profiler and no peak-FLOPs overrides leak
+    """No process-wide profiler and no peak-FLOPs override leak
     between tests (the tier-1 environment must not change verdicts)."""
     devprof.disarm()
     monkeypatch.delenv("BYTEPS_TPU_PEAK_FLOPS", raising=False)
-    monkeypatch.delenv("BYTEPS_BENCH_PEAK_FLOPS", raising=False)
     yield
     devprof.disarm()
 
@@ -67,12 +66,10 @@ def test_peak_flops_table_prefix_match():
 
 
 def test_peak_flops_env_overrides(monkeypatch):
-    monkeypatch.setenv("BYTEPS_BENCH_PEAK_FLOPS", "2e12")
-    assert devprof.peak_flops(kind="cpu") == 2e12       # bench alias
     monkeypatch.setenv("BYTEPS_TPU_PEAK_FLOPS", "1.5e12")
-    assert devprof.peak_flops(kind="TPU v4") == 1.5e12  # live knob wins
+    assert devprof.peak_flops(kind="cpu") == 1.5e12
+    assert devprof.peak_flops(kind="TPU v4") == 1.5e12  # override wins
     monkeypatch.setenv("BYTEPS_TPU_PEAK_FLOPS", "not-a-number")
-    monkeypatch.delenv("BYTEPS_BENCH_PEAK_FLOPS")
     assert devprof.peak_flops(kind="TPU v4") == 275e12  # falls to table
 
 
@@ -203,9 +200,6 @@ def test_sentinel_bare_cpu_without_intent_is_healthy():
     assert probe["platform"] == "cpu"
     assert probe["fallback"] is False
     assert probe["reason"] == ""
-    # The bench stamp's own flag is separate: a CPU run without
-    # BENCH_FORCE_CPU still stamps as a bench-grade fallback.
-    assert probe["stamp_fallback"] is True
 
 
 def test_sentinel_intended_platform_mismatch_convicts():
@@ -223,8 +217,7 @@ def test_sentinel_intended_platform_mismatch_convicts():
 
 def test_sentinel_host_only_with_intent_stays_quiet(monkeypatch):
     monkeypatch.setattr(devprof, "device_stamp",
-                        lambda: {"device_platform": "none(host-only)",
-                                 "device_fallback": False})
+                        lambda: {"device_platform": "none(host-only)"})
     probe = DeviceProfiler(intended_platform="tpu").probe()
     assert probe["fallback"] is False       # nothing to convict yet
 
@@ -236,8 +229,7 @@ def test_sentinel_probe_error_convicts_without_a_child(monkeypatch):
     hang."""
     import subprocess
     monkeypatch.setattr(devprof, "device_stamp",
-                        lambda: {"device_platform": "unknown(boom)",
-                                 "device_fallback": True})
+                        lambda: {"device_platform": "unknown(boom)"})
 
     def no_child(*a, **kw):
         raise AssertionError("the sentinel must not start a process")
@@ -250,7 +242,7 @@ def test_sentinel_probe_error_convicts_without_a_child(monkeypatch):
         assert probe["fallback"] is True
         assert probe["reason"].startswith("device probe failed")
         assert set(probe) == {"platform", "intended", "fallback",
-                              "reason", "stamp_fallback"}
+                              "reason"}
 
 
 # ---------------------------------------------------------------------------

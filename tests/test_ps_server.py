@@ -37,7 +37,7 @@ def ps_server():
         free_port() is bind-then-close (TOCTOU): under parallel test
         workers another process can claim the port before the server
         binds it, killing the server at startup — retry with a fresh
-        port (same mitigation as bench.py's bench_ps)."""
+        port."""
         last = None
         for _ in range(3):
             try:

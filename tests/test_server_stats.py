@@ -300,6 +300,57 @@ def test_metrics_endpoint_during_two_worker_run(ps_server):
             assert exported[prefix + k] == v, (prefix, k)
 
 
+def test_metrics_endpoint_scraped_while_rounds_run(ps_server):
+    """The export plane under load: a scraper polls the endpoint (whose
+    refresh asks the server for CMD_STATS on the worker's own session)
+    while sync rounds are in flight.  Every round still returns its sum,
+    scrapes land between the first round and the last, and the last body
+    counts every round's push.  No timing is asserted."""
+    port = ps_server(num_workers=1)
+    sess = PSSession(["127.0.0.1"], [port], worker_id=0, num_servers=1)
+    exp = tm.TelemetryExporter(
+        tm.get_registry(), port=free_port(),
+        refresh=lambda: tm.update_round_lag(sess.server_stats(), 10)).start()
+    url = f"http://127.0.0.1:{exp.port}/metrics"
+    stop, bodies = threading.Event(), []
+
+    def rtt_count(body):
+        return int(next(l for l in body.splitlines()
+                        if l.startswith("bps_push_rtt_seconds_count")
+                        ).split()[-1])
+
+    def get():
+        return urllib.request.urlopen(url, timeout=10).read().decode()
+
+    def scrape():
+        while not stop.is_set():
+            bodies.append(get())
+
+    x = np.arange(1 << 18, dtype=np.float32)
+    scraper = threading.Thread(target=scrape, daemon=True)
+    try:
+        before = rtt_count(get())
+        scraper.start()
+        rounds = 0
+        while rounds < 8 or len(bodies) < 3:
+            np.testing.assert_array_equal(sess.push_pull(21, x), x)
+            rounds += 1
+            assert rounds < 2000, "the scraper never got a body"
+        stop.set()
+        scraper.join(timeout=30)
+        assert not scraper.is_alive()
+        last = get()
+    finally:
+        stop.set()
+        exp.stop()
+        sess.close()
+    assert rtt_count(last) - before >= rounds
+    assert 'bps_worker_round_lag{worker="0"} 0' in last
+    # Bodies taken mid-run parse and only ever count upwards.
+    counts = [rtt_count(b) for b in bodies]
+    assert counts == sorted(counts) and counts[-1] <= rtt_count(last)
+
+
 def test_api_metrics_endpoint_and_jsonl(ps_server):
     """API-level acceptance: BYTEPS_TPU_METRICS_PORT + _METRICS_LOG wired
     through bps.init() — the endpoint serves during a PS-mode run with

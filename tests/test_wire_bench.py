@@ -144,7 +144,7 @@ def test_wire_bench_echo_floor_smoke(uds):
     measured in interleaved batches on the SAME transport), the server's
     scatter path actually engaged, and the UDS run really rode AF_UNIX.
     No threshold on pct here — shared CI hosts swing the floor ~2x; the
-    number's home is BENCH_WIRE=1 / docs/performance.md."""
+    number is the host's to state (docs/performance.md "Transport")."""
     r = subprocess.run([sys.executable, _TOOL, "--quick", "--json",
                         "--echo-floor"] + (["--uds"] if uds else []),
                        env=cpu_env(), capture_output=True, text=True,

@@ -223,8 +223,8 @@ def codec_throughput(n: int, reps: int) -> list:
 
 
 def boot_server(extra_env=None):
-    """Native PS server subprocess on a freshly-probed port (the bind
-    race retry pattern of bench.py bench_ps)."""
+    """Native PS server subprocess on a freshly-probed port (retried:
+    another process can take the port between the probe and the bind)."""
     import tempfile
     for _ in range(4):
         with socket.socket() as sk:
@@ -722,7 +722,7 @@ def main(argv=None) -> int:
     if args.echo_floor:
         # The acceptance workload: 4 MiB partitions, raw f32, same-host
         # echo floor on the same transport.  16 MB tensor under --quick
-        # keeps the CI smoke short; 64 MB otherwise (the bench_ps shape).
+        # keeps the CI smoke short; 64 MB otherwise.
         ef_bytes = (16 << 20) if quick else (64 << 20)
         ef_reps = args.rounds or (5 if quick else 15)
         _log(f"wire_bench: echo floor vs PS goodput "
