@@ -395,6 +395,29 @@ def pushpull_speed_mbps() -> float:
 
 
 # ---------------------------------------------------------------------------
+# In-graph exchange (ops/collectives.py bucketed_tree_all_reduce)
+# ---------------------------------------------------------------------------
+def record_ingraph_exchange(leaves: int, groups: int,
+                            packed_bytes: int) -> None:
+    """The form of the last gradient exchange that was traced, written
+    once per trace (not per step: the call sits in the Python body of a
+    jitted step).  ``packed_bytes`` is what went through slice and
+    concatenate into flat buckets: the tree's whole size when a
+    compressor or the hierarchical reduce-scatter needs buckets as
+    vectors, 0 when every leaf is summed in its own shape."""
+    reg = get_registry()
+    reg.gauge("bps_ingraph_exchange_leaves",
+              help="non-empty leaves of the last traced in-graph exchange"
+              ).set(int(leaves))
+    reg.gauge("bps_ingraph_exchange_groups",
+              help="collectives it issued: one per group of leaves, or "
+                   "one per packed bucket").set(int(groups))
+    reg.gauge("bps_ingraph_exchange_packed_bytes",
+              help="bytes it packed into flat buckets (0 = leaves summed "
+                   "in their own shapes)").set(int(packed_bytes))
+
+
+# ---------------------------------------------------------------------------
 # Hierarchical reduction (parallel/hierarchy.py; BYTEPS_TPU_HIERARCHY=1)
 # ---------------------------------------------------------------------------
 def record_hierarchy_saved(nbytes: int,

@@ -6,7 +6,7 @@ ps-lite ZPush/ZPull, H2D copy, NCCL all-gather).  On TPU the whole path is a
 set of XLA collectives over mesh axes; what survives of the reference design
 is its *scheduling structure*:
 
-  - tensors are partitioned into <= BYTEPS_PARTITION_BYTES buckets
+  - a gradient tree is planned into <= BYTEPS_PARTITION_BYTES buckets
     (reference: operations.cc:140-180),
   - buckets are communicated in priority order — gradients produced first by
     the backward pass (the last layers) reduce first (reference:
@@ -17,8 +17,21 @@ is its *scheduling structure*:
     the analog of NCCL-local-reduce → ps-push/pull → NCCL-broadcast
     (reference: core_loops.cc:188-267,536-616).
 
+What the plan shapes depends on who has to see a bucket.  A compressor or
+the hierarchical reduce-scatter needs a flat vector of a planned length, so
+under a `bucket_transform` the leaves are sliced and concatenated into the
+plan's buckets.  A plain sum needs none: every leaf is then summed in the
+shape it has, and the plan gives only the order and the `byteps.bucket<N>`
+scope names.  On a TPU an array is tiled in memory, so flattening a
+[25088, 4096] leaf is a copy, not a view, and the compiler merges the sums it
+is handed into a few all-reduces whatever their grouping (VGG-16 at dp=4 on
+a v5e: 132 packed buckets became 5 all-reduces, 32 leaves become 3): packing
+bought no launch and cost two copies of the tree, 9.6 ms of a 77.9 ms step.
+
 All functions here are traced under jit/shard_map; they are pure and
-shape-static so XLA can pipeline the collectives with compute.
+shape-static.  What the chip shows of the schedule (PERF.md, section 5): the
+all-reduces run synchronously at the end of the backward pass, with nothing
+beside them; hiding them behind it is not done yet.
 """
 
 from __future__ import annotations
@@ -32,6 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..common import telemetry
 from ..common.config import get_config
 
 PyTree = Any
@@ -98,15 +112,18 @@ class BucketPlan:
     """Static plan mapping pytree leaves <-> priority-ordered buckets.
 
     Built once per (treedef, shapes) at trace time; the plan is pure Python
-    metadata, so it adds nothing to the compiled graph.
+    metadata, so it adds nothing to the compiled graph.  `partition_bytes`
+    sizes the flat buckets a `bucket_transform` sees (and the compressor
+    state built from the same plan); without a transform no bucket is ever
+    materialised and it decides only which leaves share a scope name.
     """
 
     def __init__(self, sizes: Sequence[int], partition_bytes: int,
                  itemsize: int, reverse: bool = True):
         # Leaf order is declaration order. The backward pass produces
-        # gradients roughly in reverse declaration order, so communicating
-        # buckets from the tail end first overlaps best — this is the
-        # reference's priority = -declared_key in bucket form.  The
+        # gradients roughly in reverse declaration order, so buckets go
+        # from the tail end first — the reference's priority =
+        # -declared_key in bucket form.  The
         # segment packing itself lives in the shared fusion planner
         # (common/fusion.py plan_segments), so the in-graph and PS-wire
         # planes agree on one bucket-composition algorithm.
@@ -134,17 +151,28 @@ def bucketed_tree_all_reduce(
     partition_bytes: Optional[int] = None,
     bucket_transform: Optional[Callable[[jax.Array, int], jax.Array]] = None,
 ) -> PyTree:
-    """Partitioned, priority-ordered all-reduce of a gradient pytree.
+    """Priority-ordered all-reduce of a gradient pytree.
 
-    Each <=partition_bytes bucket is reduced by its own `lax.psum`, issued in
-    backward-completion order so XLA can overlap early buckets' communication
-    with the rest of the backward pass.  `bucket_transform`, when given, maps
-    (bucket, bucket_index) -> reduced bucket and replaces the psum — this is
-    the hook the compression subsystem uses.
+    The form handed to the compiler depends on whether anything has to see
+    a bucket as a vector:
+
+    - Without a `bucket_transform` every non-empty leaf is summed in its own
+      shape (:func:`_reduce_leaves`): no reshape, slice or concatenate on
+      the way in or out.  `partition_bytes` then shapes nothing but the
+      scope names; a leaf larger than it goes whole.
+    - With one, leaves are packed into flat buckets of <= `partition_bytes`
+      (:func:`_reduce_packed`) and `bucket_transform` maps (bucket,
+      bucket_index) -> reduced bucket in place of the psum: the hook of the
+      compression subsystem and of the hierarchical reduce-scatter, which
+      need a vector of a planned length.
+
+    Either way the sums are issued in the plan's order, the last-declared
+    leaves first.  What was built is written to the metrics registry when
+    the step is traced (`telemetry.record_ingraph_exchange`).
     """
     if is_local() and bucket_transform is None:
         # Single-device: the sum over one worker is the identity and the
-        # average divides by 1 — skip the bucket round-trip entirely, as the
+        # average divides by 1 — skip the exchange entirely, as the
         # reference's non-distributed queue list skips PUSH/PULL
         # (reference: operations.cc:429-485).
         return tree
@@ -156,58 +184,81 @@ def bucketed_tree_all_reduce(
     leaves = [all_leaves[i] for i in nonempty_idx]
     if not leaves:
         return tree
-    # Promote everything to a common compute dtype for concat; remember
-    # originals to cast back.
-    orig_dtypes = [l.dtype for l in leaves]
-    comm_dtype = jnp.result_type(*orig_dtypes)
-    flat = [l.astype(comm_dtype).reshape(-1) for l in leaves]
+    # One common dtype for the exchange (a packed bucket needs it, and the
+    # per-leaf form sums in the same one); cast back afterwards.
+    comm_dtype = jnp.result_type(*(l.dtype for l in leaves))
+    itemsize = jnp.dtype(comm_dtype).itemsize
     sizes = tuple(l.size for l in leaves)
-    plan = _plan_cache(sizes, pb, jnp.dtype(comm_dtype).itemsize, True)
-
+    plan = _plan_cache(sizes, pb, itemsize, True)
     denom = jnp.asarray(axis_size(axis_name), comm_dtype) if average else None
+    wire = [l.astype(comm_dtype) for l in leaves]
+    if bucket_transform is None:
+        reduced, groups = _reduce_leaves(wire, plan, axis_name, denom)
+        packed_bytes = 0
+    else:
+        reduced = _reduce_packed(wire, plan, bucket_transform, denom)
+        groups, packed_bytes = plan.num_buckets(), sum(sizes) * itemsize
+    telemetry.record_ingraph_exchange(len(leaves), groups, packed_bytes)
+    out_leaves = list(all_leaves)
+    for i, leaf, r in zip(nonempty_idx, leaves, reduced):
+        out_leaves[i] = r.astype(leaf.dtype)
+    return jax.tree.unflatten(treedef, out_leaves)
 
-    out_segments: List[List[Optional[jax.Array]]] = [[] for _ in leaves]
-    seg_starts: List[List[int]] = [[] for _ in leaves]
+
+def _reduce_leaves(wire: List[jax.Array], plan: BucketPlan, axis_name: str,
+                   denom: Optional[jax.Array]
+                   ) -> Tuple[List[jax.Array], int]:
+    """Sum every leaf in the shape it has: one `lax.psum` for the leaves
+    whose first segment falls in the same bucket of `plan`, in the plan's
+    order.  Returns the reduced leaves and the number of sums issued."""
+    reduced: List[Optional[jax.Array]] = [None] * len(wire)
+    groups = 0
     for bi, bucket in enumerate(plan.buckets):
+        group = [li for (li, start, _) in bucket if start == 0]
+        if not group:
+            continue
+        groups += 1
         # Named scope per bucket: the in-graph analog of the reference's
         # per-partition trace spans (global.cc:463-579) — the XLA profiler
-        # attributes each bucket's collective to `byteps.bucket<N>` so the
-        # per-bucket timeline is visible in a jax.profiler trace
+        # attributes the group's collective to `byteps.bucket<N>`
         # (composition documented in docs/timeline.md).
+        with jax.named_scope(f"byteps.bucket{bi}"):
+            outs = all_reduce(tuple(wire[li] for li in group), axis_name)
+            for li, out in zip(group, outs):
+                reduced[li] = out if denom is None else out / denom
+    return reduced, groups
+
+
+def _reduce_packed(wire: List[jax.Array], plan: BucketPlan,
+                   bucket_transform: Callable[[jax.Array, int], jax.Array],
+                   denom: Optional[jax.Array]) -> List[jax.Array]:
+    """Pack the leaves into the plan's flat buckets, hand each to
+    `bucket_transform`, and cut the results back into the leaves' shapes."""
+    flat = [l.reshape(-1) for l in wire]
+    out_segments: List[List[jax.Array]] = [[] for _ in wire]
+    for bi, bucket in enumerate(plan.buckets):
         with jax.named_scope(f"byteps.bucket{bi}"):
             parts = [lax.dynamic_slice(flat[li], (start,), (length,))
                      for (li, start, length) in bucket]
             buf = jnp.concatenate(parts) if len(parts) > 1 else parts[0]
-            if bucket_transform is not None:
-                buf = bucket_transform(buf, bi)
-            else:
-                buf = all_reduce(buf, axis_name)
-            if average:
+            buf = bucket_transform(buf, bi)
+            if denom is not None:
                 buf = buf / denom
         off = 0
-        for (li, start, length) in bucket:
+        # A leaf's segments come in offset order (plan_segments), so
+        # appending restores it.
+        for (li, _, length) in bucket:
             out_segments[li].append(lax.dynamic_slice(buf, (off,), (length,)))
-            seg_starts[li].append(start)
             off += length
-    reduced = []
-    for li, leaf in enumerate(leaves):
-        segs = out_segments[li]
-        # Segments of one leaf arrive tail-first; restore offset order.
-        order = sorted(range(len(segs)), key=lambda i: seg_starts[li][i])
-        vec = jnp.concatenate([segs[i] for i in order]) if len(segs) > 1 \
-            else segs[0]
-        reduced.append(vec.reshape(leaf.shape).astype(orig_dtypes[li]))
-    out_leaves = list(all_leaves)
-    for i, r in zip(nonempty_idx, reduced):
-        out_leaves[i] = r
-    return jax.tree.unflatten(treedef, out_leaves)
+    return [(jnp.concatenate(segs) if len(segs) > 1 else segs[0])
+            .reshape(leaf.shape)
+            for segs, leaf in zip(out_segments, wire)]
 
 
 def tree_all_reduce(tree: PyTree, axis_name: str = "dp",
                     average: bool = True) -> PyTree:
-    """Unbucketed baseline: one psum per leaf (what naive DP in JAX does).
-
-    Kept for benchmarking against the bucketed path.
+    """Unplanned baseline: one psum per leaf in declaration order, each in
+    its own dtype (what naive DP in JAX does).  The tests count against it.
     """
     def f(x):
         y = all_reduce(x, axis_name)

@@ -4,19 +4,24 @@ The reference wraps each framework's optimizer so that every gradient is
 push_pull'd before the local update (reference: byteps/torch/__init__.py:
 115-214, byteps/mxnet/__init__.py:74-92, byteps/tensorflow/__init__.py:
 184-278).  The TPU-native equivalent wraps an optax GradientTransformation:
-`update()` runs the partitioned, priority-ordered all-reduce from
-ops.collectives over the mesh's dp axis (hierarchical over ici/dcn when the
-mesh is two-level), then applies the inner transform.  Everything is traced
-under jit — XLA overlaps the bucket collectives with backward compute, which
-is the cross-barrier effect the reference builds by hand with threads + locks
-(reference: torch/cross_barrier.py).
+`update()` runs the priority-ordered all-reduce from ops.collectives over the
+mesh's dp axis (hierarchical over ici/dcn when the mesh is two-level), then
+applies the inner transform.  Everything is traced under jit, so the
+exchange and the update are one program: with no compressor each gradient
+is summed in the shape it has and the division by the axis size fuses into
+the optimizer's update.  What that program does NOT do yet is the
+cross-barrier effect the reference builds by hand with threads + locks
+(reference: torch/cross_barrier.py): on the chip the compiler merges the
+sums into a few all-reduces and runs them synchronously after the backward
+pass, with nothing beside them (PERF.md, section 5).
 
 Bucket composition routes through the shared fusion planner
 (common/fusion.py, via ops.collectives.BucketPlan): the in-graph plane and
-the PS wire plane (push_pull_tree / AsyncPSTrainer) pack small leaves with
-the same reverse-backprop-order algorithm, so a model's overlap behavior is
-the same story on both planes and `bps.get_fusion_stats()` sees plan
-activity from either.
+the PS wire plane (push_pull_tree / AsyncPSTrainer) plan leaves with the
+same reverse-backprop-order algorithm, and `bps.get_fusion_stats()` sees
+plan activity from either.  In-graph the plan's buckets are materialised
+only for a compressor or the hierarchical reduce-scatter; `bps.get_metrics()`
+(`bps_ingraph_exchange_*`) says which form the last traced step took.
 """
 
 from __future__ import annotations

@@ -80,6 +80,70 @@ def test_bucketed_reduce_handles_mixed_dtypes(dp_mesh):
     np.testing.assert_allclose(np.asarray(out["f32"]), expect, rtol=1e-5)
 
 
+def _mixed_tree():
+    k = jax.random.PRNGKey(3)
+    return {"f32": jax.random.normal(k, (11,), jnp.float32),
+            "bf16": jax.random.normal(k, (7, 3), jnp.bfloat16)}
+
+
+def _psum_transform(buf, bucket_index):
+    """A transform that is the plain sum: it selects the packed path and
+    makes it what `bucketed_tree_all_reduce` was for every tree before
+    leaves were summed in their own shapes."""
+    return collectives.all_reduce(buf, "dp")
+
+
+@pytest.mark.parametrize("average", [True, False], ids=["mean", "sum"])
+@pytest.mark.parametrize("make_tree,partition_bytes", [
+    (_make_tree, 4 * 1024 * 1024),
+    (_make_tree, 64),              # w1 is 2244 B: 36 buckets' worth
+    (_mixed_tree, 4 * 1024 * 1024),
+    (lambda: {"a": jnp.arange(4.0), "empty": jnp.zeros((0,))}, 64),
+], ids=["plain", "leaf_over_partition", "mixed_dtypes", "zero_size_leaf"])
+def test_leaves_are_summed_in_their_own_shapes(dp_mesh, make_tree,
+                                               partition_bytes, average):
+    """Without a transform nothing is packed: the lowered program holds an
+    all_reduce a non-empty leaf and no reshape, slice or concatenate, gives
+    bit for bit what the packed path gives, and the registry says so."""
+    import byteps_tpu as bps
+    tree = make_tree()
+    nonempty = [l for l in jax.tree.leaves(tree) if l.size]
+
+    def exchange(transform):
+        def step(t):
+            # distinct per device with no reshape of the harness's own
+            w = jax.lax.axis_index("dp") + 1
+            local = jax.tree.map(lambda x: x * w.astype(x.dtype), t)
+            return collectives.bucketed_tree_all_reduce(
+                local, "dp", average=average,
+                partition_bytes=partition_bytes, bucket_transform=transform)
+        return _shmap(step, dp_mesh, (P(),), P())
+
+    def packed_bytes():
+        return bps.get_metrics()["bps_ingraph_exchange_packed_bytes"]
+
+    plain = exchange(None)
+    ir = str(plain.lower(tree).compiler_ir(dialect="stablehlo"))
+    assert packed_bytes() == 0
+    assert bps.get_metrics()["bps_ingraph_exchange_leaves"] == len(nonempty)
+    assert ir.count("stablehlo.all_reduce") == len(nonempty)
+    for op in ("concatenate", "dynamic_slice", "reshape"):
+        assert f"stablehlo.{op}" not in ir, op
+
+    got = plain(tree)
+    packed = exchange(_psum_transform)(tree)
+    itemsize = jnp.result_type(*nonempty).itemsize
+    assert packed_bytes() == sum(l.size for l in nonempty) * itemsize
+    for k, leaf in tree.items():
+        assert got[k].dtype == leaf.dtype and got[k].shape == leaf.shape
+        np.testing.assert_array_equal(np.asarray(got[k], np.float32),
+                                      np.asarray(packed[k], np.float32))
+        expect = np.asarray(leaf, np.float32) * (4.5 if average else 36)
+        np.testing.assert_allclose(np.asarray(got[k], np.float32), expect,
+                                   rtol=2e-2 if leaf.dtype == jnp.bfloat16
+                                   else 1e-5)
+
+
 def test_bucket_plan_partitions_and_reverse_priority():
     # 3 leaves of 10 elems at 16-elem buckets (4-byte items, 64B partitions):
     # reversed order -> leaf2 first.
@@ -117,7 +181,8 @@ def test_bucket_plan_random_property():
                 assert ln > 0
                 segs_by_leaf.setdefault(li, []).append((start, ln))
         for li, size in enumerate(sizes):
-            segs = sorted(segs_by_leaf.get(li, []))
+            segs = segs_by_leaf.get(li, [])
+            # in offset order as they come (the packed path appends them),
             # contiguous, non-overlapping, complete
             pos = 0
             for start, ln in segs:
@@ -203,18 +268,24 @@ def test_zero_size_leaf_passes_through(dp_mesh):
 
 
 def test_bucketed_issues_far_fewer_collectives(dp_mesh):
-    """Structural claim behind bucketing: 500 leaves naive -> 500
-    all-reduces; bucketed -> one per <=4MB bucket.  Counted in the lowered
-    HLO, so it holds on any backend."""
-    tree = {f"g{i}": jnp.ones((1000,), jnp.float32) for i in range(500)}
+    """Structural claim behind bucketing: 250 leaves naive -> 250
+    all-reduces; bucketed -> one per <=4MB bucket.  The program hands the
+    compiler one psum a bucket, which lowers to one StableHLO all_reduce an
+    operand, and it is the compiler that merges them: so the one is counted
+    in the compiled program.  (250 leaves: the CPU backend's combiner takes
+    at most 256 operands an all-reduce.)"""
+    import re
+    tree = {f"g{i}": jnp.ones((1000,), jnp.float32) for i in range(250)}
 
-    def count_all_reduce(fn) -> int:
-        ir = _shmap(fn, dp_mesh, (P(),), P()).lower(tree).compiler_ir(
-            dialect="stablehlo")
-        return str(ir).count("stablehlo.all_reduce")
+    def lower(fn):
+        return _shmap(fn, dp_mesh, (P(),), P()).lower(tree)
 
-    assert count_all_reduce(
-        lambda t: collectives.tree_all_reduce(t, "dp")) == 500
-    # 500 * 4000B = 2MB total -> a single 4MB bucket
-    assert count_all_reduce(
-        lambda t: collectives.bucketed_tree_all_reduce(t, "dp")) == 1
+    naive = str(lower(lambda t: collectives.tree_all_reduce(t, "dp"))
+                .compiler_ir(dialect="stablehlo"))
+    assert naive.count("stablehlo.all_reduce") == 250
+    # 250 * 4000B = 1MB total -> a single 4MB bucket
+    compiled = lower(
+        lambda t: collectives.bucketed_tree_all_reduce(t, "dp")
+    ).compile().as_text()
+    assert len(re.findall(r"^\s*%?[\w.-]+ = .* all-reduce\(", compiled,
+                          re.M)) == 1

@@ -13,7 +13,10 @@ the two switches that would pick the interpreter are steered here, in
 the test (not through an option of the program).
 """
 
+import math
 import os
+import re
+import time
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # else it logs under /tmp
 
@@ -160,6 +163,88 @@ def test_bitpack_compiles(v5e, op, n):
             jax.ShapeDtypeStruct((bitpack.words_len(n),), jnp.uint32,
                                  sharding=one))
     assert "tpu_custom_call" in c.as_text()
+
+
+def _vgg16_leaf_shapes():
+    """The 32 leaves of VGG-16 (arXiv:1409.1556 column D): 553 MB of f32,
+    411 MB of it the one leaf [25088, 4096]."""
+    shapes, cin = {}, 3
+    for i, width in enumerate((64, 64, 128, 128, 256, 256, 256,
+                               512, 512, 512, 512, 512, 512)):
+        shapes[f"conv{i:02d}"] = {"kernel": (3, 3, cin, width),
+                                  "bias": (width,)}
+        cin = width
+    fan_in = 7 * 7 * 512
+    for i, width in enumerate((4096, 4096, 1000)):
+        shapes[f"fc{i}"] = {"kernel": (fan_in, width), "bias": (width,)}
+        fan_in = width
+    return shapes
+
+
+def test_dp4_exchange_leaves_the_gradients_where_they_lie(v5e):
+    """The in-graph exchange plus an SGD update over VGG-16's leaves on
+    the four described chips, no model: every all-reduce operand keeps its
+    leaf's shape and tiling (a flat copy of a tiled [25088, 4096] array is
+    different bytes in memory, 411 MB of them), and nothing the size of a
+    gradient is concatenated.  Seconds to compile; its own limit is 60."""
+    import numpy as np
+    import optax
+    from jax.sharding import Mesh
+
+    import byteps_tpu as bps
+
+    mesh = Mesh(np.array(v5e), ("dp",))
+    opt = bps.DistributedOptimizer(optax.sgd(0.01, momentum=0.9))
+
+    def step(params, opt_state, weight):
+        grads = jax.tree.map(lambda p: p * weight[0], params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state
+
+    def on(spec, tree):
+        sharding = NamedSharding(mesh, spec)
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=sharding), tree)
+
+    params = jax.tree.map(
+        lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32),
+        _vgg16_leaf_shapes(), is_leaf=lambda x: isinstance(x, tuple))
+    leaves = jax.tree.leaves(params)
+    assert len(leaves) == 32
+    sharded = jax.shard_map(
+        step, mesh=mesh, in_specs=(P(), P(), P("dp")),
+        out_specs=(P(), P()), check_vma=False)
+    began = time.monotonic()
+    text = jax.jit(sharded, donate_argnums=(0, 1)).lower(
+        on(P(), params), on(P(), jax.eval_shape(opt.init, params)),
+        on(P("dp"), jax.ShapeDtypeStruct((4,), jnp.float32))
+    ).compile().as_text()
+    assert time.monotonic() - began < 60
+
+    metrics = bps.get_metrics()
+    assert metrics["bps_ingraph_exchange_leaves"] == 32
+    assert metrics["bps_ingraph_exchange_packed_bytes"] == 0
+
+    def elements(dims):
+        return math.prod(int(d) for d in dims.split(",") if d)
+
+    vector_leaves = {l.shape[0] for l in leaves if l.ndim == 1}
+    smallest_kernel = min(l.size for l in leaves if l.ndim > 1)
+    summed = 0
+    for line in text.splitlines():
+        made = re.match(r"\s*%?[\w.-]+ = (.*?) "
+                        r"(all-reduce(?:-start)?|concatenate)\(", line)
+        if not made:
+            continue
+        results = re.findall(r"f32\[([\d,]*)\]", made.group(1))
+        if made.group(2) == "concatenate":
+            assert all(elements(r) < smallest_kernel for r in results), line
+            continue
+        for dims in results:
+            summed += elements(dims)
+            assert "," in dims or elements(dims) in vector_leaves, \
+                f"a flat f32[{dims}] is summed: {line[:200]}"
+    assert summed == sum(l.size for l in leaves)
 
 
 def test_bert_large_train_step_compiles(v5e, monkeypatch):
