@@ -1,0 +1,27 @@
+"""State-space scan: the least time the chip could take for the scan's
+kernel calls of the window over the time they took, in percent.  A call's
+FLOPs and bytes are what the chunked form needs for the family's shapes
+(`benchmark/reduce/ssd_cost.py`, which says what is and is not counted);
+the recomputation under remat is a call like any other, as in
+`flash_roofline`.  Source: device trace."""
+
+from benchmark.reduce import flash_cost, ssd_cost
+
+
+def read(ctx):
+    shape = getattr(ctx.family, "scan_shape", None)
+    if shape is None:
+        return None
+    shape = shape()
+    sequences = ctx.samples_per_step // ctx.n_chips
+    least = took = 0.0
+    for name, start, end in ctx.ops(0):
+        call = ssd_cost.scan_call(name)
+        if call is None:
+            continue
+        kind, chunk = call
+        flops, nbytes = ssd_cost.cost(kind, **{**shape, "chunk": chunk})
+        least += sequences * flash_cost.least_seconds(
+            flops, nbytes, ctx.peaks)[0]
+        took += (end - start) / 1e9
+    return 100.0 * least / took if took else None

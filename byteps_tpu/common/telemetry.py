@@ -418,6 +418,23 @@ def record_ingraph_exchange(leaves: int, groups: int,
 
 
 # ---------------------------------------------------------------------------
+# State-space scan (ops/ssd.py, through models/granite_hybrid.py)
+# ---------------------------------------------------------------------------
+def record_ssd_scan(layers: int, chunk: int, state_bytes: int) -> None:
+    """The form of the state-space scans of the last model step that was
+    traced, written once per trace like `record_ingraph_exchange`."""
+    reg = get_registry()
+    reg.gauge("bps_ssd_scan_layers",
+              help="layers of the last traced step that run the chunked "
+                   "state-space scan").set(int(layers))
+    reg.gauge("bps_ssd_chunk",
+              help="positions a chunk of that scan holds").set(int(chunk))
+    reg.gauge("bps_ssd_state_bytes",
+              help="bytes of chunk states ONE such layer keeps from its "
+                   "forward pass for its backward pass").set(int(state_bytes))
+
+
+# ---------------------------------------------------------------------------
 # Hierarchical reduction (parallel/hierarchy.py; BYTEPS_TPU_HIERARCHY=1)
 # ---------------------------------------------------------------------------
 def record_hierarchy_saved(nbytes: int,

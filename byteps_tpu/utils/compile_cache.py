@@ -1,7 +1,7 @@
 """One place that decides where JAX's persistent compilation cache lives.
 
 For the scripts that run on the chip (chip_smoke.py, benchmark/run.py,
-tools/afmoe_check.py, tools/afmoe_drift.py).  The library itself
+tools/reference_check.py, tools/afmoe_drift.py).  The library itself
 (`bps.init`) sets no cache.
 
 The rule: where `JAX_COMPILATION_CACHE_DIR` is set, the cache was placed

@@ -1,0 +1,192 @@
+"""The chunked state-space scan (`byteps_tpu/ops/ssd.py`) in float32
+against the recurrence it computes, position by position, and against the
+quadratic form (one masked [S, S] product a head): values and the gradient
+of every input, both forms of the scan (the kernels in the Pallas
+interpreter, and the `jnp` form they are tested against), chunks of 16 and
+64, heads that forget at once and heads that hardly forget; the causal
+convolution against a loop."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax import lax
+
+from byteps_tpu.ops import ssd
+
+B, S, H, P, G, N = 2, 128, 4, 8, 2, 16
+INPUTS = ("x", "dt", "A", "B", "C", "D")
+# dt * A a position: near 0 the state hardly decays (exp(-0.001) a step),
+# far from it a head forgets within a position or two (exp(-8)).
+DECAYS = {"near_one": (1e-3, 1.0), "near_zero": (0.5, 16.0),
+          "mixed": (None, None)}
+
+
+def _inputs(decay: str):
+    ks = jax.random.split(jax.random.key(7), 7)
+    step, rate = DECAYS[decay]
+    if step is None:
+        dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)) - 2.0)
+        A = -jnp.exp(jax.random.uniform(ks[2], (H,), minval=0.0, maxval=2.7))
+    else:
+        dt = step * jax.random.uniform(ks[1], (B, S, H), minval=0.5,
+                                       maxval=1.0)
+        A = -rate * jax.random.uniform(ks[2], (H,), minval=0.5, maxval=1.0)
+    args = (jax.random.normal(ks[0], (B, S, H, P)), dt, A,
+            jax.random.normal(ks[3], (B, S, G, N)),
+            jax.random.normal(ks[4], (B, S, G, N)),
+            jax.random.normal(ks[5], (H,)))
+    return args, jax.random.normal(ks[6], (B, S, H, P))
+
+
+def recurrence(x, dt, A, Bm, Cm, D):
+    bh = jnp.repeat(Bm, H // G, axis=2)
+    ch = jnp.repeat(Cm, H // G, axis=2)
+
+    def step(state, inp):
+        x_t, dt_t, b_t, c_t = inp
+        state = (state * jnp.exp(dt_t * A)[..., None, None]
+                 + (dt_t[..., None] * x_t)[..., None] * b_t[:, :, None, :])
+        y_t = jnp.einsum("bhpn,bhn->bhp", state, c_t) + D[:, None] * x_t
+        return state, y_t
+
+    xs = tuple(t.swapaxes(0, 1) for t in (x, dt, bh, ch))
+    _, ys = lax.scan(step, jnp.zeros((B, H, P, N)), xs)
+    return ys.swapaxes(0, 1)
+
+
+def quadratic(x, dt, A, Bm, Cm, D):
+    """y_i = sum_{j <= i} exp(sum_{j < k <= i} dt_k A) (C_i . B_j) dt_j x_j
+    + D x_i, the whole sequence as one chunk."""
+    bh = jnp.repeat(Bm, H // G, axis=2)
+    ch = jnp.repeat(Cm, H // G, axis=2)
+    cs = jnp.cumsum(dt * A, axis=1)                          # [B, S, H]
+    keep = jnp.arange(S)[:, None] >= jnp.arange(S)[None, :]
+    decay = jnp.exp(jnp.where(keep[None, :, :, None],
+                              cs[:, :, None] - cs[:, None, :], -jnp.inf))
+    scores = jnp.einsum("bihn,bjhn->bijh", ch, bh) * decay * dt[:, None]
+    return jnp.einsum("bijh,bjhp->bihp", scores, x) + D[:, None] * x
+
+
+ORACLES = {"recurrence": recurrence, "quadratic": quadratic}
+
+
+@pytest.fixture(scope="module")
+def expected():
+    """Value and gradients of each oracle for each kind of decay, made
+    once."""
+    out = {}
+    with jax.default_matmul_precision("highest"):
+        for decay in DECAYS:
+            args, w = _inputs(decay)
+            for name, fn in ORACLES.items():
+                out[decay, name] = jax.jit(jax.value_and_grad(
+                    lambda *a, fn=fn, w=w: (fn(*a) * w).sum(),
+                    argnums=range(6)))(*args)
+    return out
+
+
+@pytest.mark.parametrize("oracle", ORACLES)
+@pytest.mark.parametrize("decay", DECAYS)
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("impl", ["jnp", "kernel"])
+def test_scan_against(expected, impl, chunk, decay, oracle):
+    args, w = _inputs(decay)
+    with jax.default_matmul_precision("highest"):
+        value, grads = jax.jit(jax.value_and_grad(
+            lambda *a: (ssd.ssd_scan(*a, chunk=chunk, impl=impl) * w).sum(),
+            argnums=range(6)))(*args)
+    want_value, want_grads = expected[decay, oracle]
+    # Where a head forgets within a position, what reaches A's and dt's
+    # gradients is the little that the off-diagonal lets through, e^-2 to
+    # e^-16 of the diagonal's terms, which cancel: a small difference of
+    # large float32 terms, in the scan (row sums less column sums) and in
+    # the quadratic form (one cumulative sum that reaches -1,000) alike.
+    tol = 1e-3 if decay == "near_zero" else 2e-5
+    np.testing.assert_allclose(float(value), float(want_value), rtol=tol)
+    for name, got, want in zip(INPUTS, grads, want_grads):
+        err = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))
+        assert err < tol, (name, err)
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_kernels_are_the_jnp_form(chunk):
+    """Values, not only their weighted sum: the two forms of the scan give
+    the same y, float32 to rounding, and bfloat16 inputs to bfloat16's."""
+    args, _ = _inputs("mixed")
+    with jax.default_matmul_precision("highest"):
+        a = ssd.ssd_scan(*args, chunk=chunk, impl="kernel")
+        b = ssd.ssd_scan(*args, chunk=chunk, impl="jnp")
+    np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
+                               rtol=2e-5)
+    low = tuple(t.astype(jnp.bfloat16) if t.ndim == 4 else t for t in args)
+    a = ssd.ssd_scan(*low, chunk=chunk, impl="kernel")
+    b = ssd.ssd_scan(*low, chunk=chunk, impl="jnp")
+    assert a.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(a, np.float32),
+                               np.asarray(b, np.float32), atol=0.25,
+                               rtol=0.05)
+
+
+def test_a_fast_head_neither_overflows_nor_leaks():
+    """dt A of -400 a position: every exponent is masked before the exp,
+    so nothing is inf or NaN in either pass, and y_t is D x_t plus the
+    position's own dt (C . B) x."""
+    args, w = _inputs("mixed")
+    x, dt, A, Bm, Cm, D = args
+    dt, A = jnp.full_like(dt, 25.0), jnp.full_like(A, -16.0)
+    for impl in ("jnp", "kernel"):
+        value, grads = jax.value_and_grad(
+            lambda *a: (ssd.ssd_scan(*a, chunk=16, impl=impl) * w).sum(),
+            argnums=range(6))(x, dt, A, Bm, Cm, D)
+        assert np.isfinite(float(value))
+        assert all(bool(jnp.isfinite(g).all()) for g in grads)
+    y = ssd.ssd_scan(x, dt, A, Bm, Cm, D, chunk=16, impl="jnp")
+    own = jnp.einsum("bshn,bshn->bsh", jnp.repeat(Cm, H // G, 2),
+                     jnp.repeat(Bm, H // G, 2))
+    np.testing.assert_allclose(
+        np.asarray(y), np.asarray((D[:, None] + (dt * own)[..., None]) * x),
+        rtol=1e-4, atol=1e-4)
+
+
+def test_arguments_are_checked():
+    args, _ = _inputs("mixed")
+    with pytest.raises(ValueError, match="does not divide"):
+        ssd.ssd_scan(*args, chunk=48)
+    with pytest.raises(ValueError, match="impl="):
+        ssd.ssd_scan(*args, chunk=16, impl="cuda")
+    with pytest.raises(ValueError, match="groups"):
+        ssd.ssd_scan(args[0], args[1], args[2], args[3][:, :, :1].repeat(3, 2),
+                     args[4][:, :, :1].repeat(3, 2), args[5], chunk=16)
+
+
+def test_state_bytes():
+    # the published widths: 64 heads x 32 chunks x [64, 128] float32
+    assert ssd.state_bytes(1, 64, 8192, 64, 128, 256) == 64 * 32 * 64 * 128 * 4
+
+
+@pytest.mark.parametrize("bias", [False, True], ids=["no_bias", "bias"])
+@pytest.mark.parametrize("taps", [1, 4])
+def test_causal_conv1d_against_a_loop(taps, bias):
+    ks = jax.random.split(jax.random.key(3), 3)
+    x = np.asarray(jax.random.normal(ks[0], (2, 9, 5)))
+    w = np.asarray(jax.random.normal(ks[1], (taps, 5)))
+    b = np.asarray(jax.random.normal(ks[2], (5,))) if bias else None
+    want = np.zeros_like(x)
+    for t in range(x.shape[1]):
+        for k in range(taps):
+            src = t - (taps - 1) + k
+            if src >= 0:
+                want[:, t] += w[k] * x[:, src]
+        if bias:
+            want[:, t] += b
+    got = ssd.causal_conv1d(jnp.asarray(x), jnp.asarray(w),
+                            None if b is None else jnp.asarray(b))
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
+    # and it sees nothing ahead: a change at position 6 moves 6, 7, 8 only
+    x2 = x.copy()
+    x2[:, 6] += 1.0
+    moved = np.abs(np.asarray(ssd.causal_conv1d(
+        jnp.asarray(x2), jnp.asarray(w))) - np.asarray(ssd.causal_conv1d(
+            jnp.asarray(x), jnp.asarray(w)))).sum((0, 2)) > 0
+    assert not moved[:6].any() and moved[6]

@@ -131,6 +131,33 @@ def test_dropless_expert_layer_compiles_at_trinity_widths(v5e):
     assert text.count("ragged-dot-none") >= 9      # 3 products, 3 passes
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_ssd_scan_compiles_at_granite_shapes(v5e, dtype):
+    """The chunked state-space scan of the granite-4.0-h-micro cell: one
+    sequence of 8192 positions, 64 heads of 64, one group, state 128,
+    chunks of 256; forward and backward kernels, under the names a device
+    trace tells them by."""
+    from byteps_tpu.ops import ssd
+    one = SingleDeviceSharding(v5e[0])
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def grads(x, dt, A, B, C, D):
+        def loss(*args):
+            return ssd.ssd_scan(*args, chunk=256, impl="kernel",
+                                interpret=False).astype(jnp.float32).sum()
+        return jax.grad(loss, tuple(range(6)))(x, dt, A, B, C, D)
+
+    group = shape(1, 8192, 1, 128, dtype=dtype)
+    text = _compile(grads, shape(1, 8192, 64, 64, dtype=dtype),
+                    shape(1, 8192, 64), shape(64), group, group,
+                    shape(64)).as_text()
+    assert "ssd_fwd_c256" in text and "ssd_bwd_c256" in text
+    assert text.count("tpu_custom_call") == 2
+
+
 def test_flash_64_row_block_is_refused_up_front(v5e):
     """F's decision: the kernel refuses a 64-row Q tile itself, with a
     message that names the rule — because the chip's compiler refuses it

@@ -1,0 +1,173 @@
+"""A cell's reference check alone, over seeds and over the broken variants
+of its program, at the cell's own widths:
+
+    python3 tools/reference_check.py --workload <cell> --seeds 11 12 13 \
+        [--variants all | name ...] [--leaves] \
+        [--out chiprun_out/reference_check.jsonl]
+
+One JSON line a comparison: what `benchmark/harness/correct.py` would put
+under `detail.reference` in a run of the cell, whether it passes the
+configuration's tolerances, the seconds it took and what the family keeps
+beside the three numbers (`EXTRAS` below).  The family is the one the
+cell's configuration names (`benchmark/families/<family>.py`), its
+variants `benchmark/tests/<family>_variants.py`; they are run on the
+first seed and each has to fail.  This is how the tolerances in a
+configuration's `reference_check` were measured (PERF.md, Findings, PR 29
+and PR 34); it measures no time of the program's, and runs wherever JAX
+does.  (Once two tools, `afmoe_check.py` and `granite_check.py`, which
+older notes name.)
+
+    python3 tools/reference_check.py --workload <cell> --record <out.pb>
+
+records instead the small trace that the family's readers are tested on
+(`RECORD` below; the granitehybrid cell alone has one).
+"""
+
+import argparse
+import dataclasses
+import importlib
+import json
+import os
+import shutil
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def _afmoe_extras(family, cell, params, seed, variant):
+    """What the reference saw of the program's choice of experts, the
+    program's own routing counters and, for the program as it is, the
+    counters of the step's own batch, all its sequences routed together
+    as the timed step routes them."""
+    import jax
+
+    from benchmark.harness import seeded
+    from byteps_tpu.models import afmoe
+    from byteps_tpu.parallel import dropless_moe
+    out = {"selection": list(family.selection),
+           "routing_counters": list(family.routing_counters)}
+    del family.selection[:], family.routing_counters[:]
+    if variant is None:
+        tokens = seeded.batch(family, seed, cell.job["per_chip_batch"])[0]
+        routing = jax.jit(lambda p, t: afmoe.routing(p, t, family.cfg))(
+            params, tokens)
+        out["step_counters"] = jax.tree.map(
+            lambda a: [float(x) for x in a],
+            jax.vmap(lambda r: dropless_moe.counters(r, tokens.size))(
+                routing))
+    return out
+
+
+def _granitehybrid_extras(family, cell, params, seed, variant):
+    """The scan alone in float32 against the recurrence, the number that
+    reaches `correct` through the loss, a sample of the check."""
+    import jax
+
+    from benchmark.harness import seeded
+    tokens = seeded.batch(family, seed, family.reference_check["samples"])[0]
+    scan = jax.jit(lambda p, t: family.scan_disagreement(p, t))
+    return {"scan_rel_diff": [float(scan(params, tokens[i:i + 1]))
+                              for i in range(tokens.shape[0])]}
+
+
+def _granitehybrid_record(cell, out: str) -> int:
+    """`benchmark/tests/data/tiny_granitehybrid.xplane.pb`: the cell at
+    tiny widths, three layers (mamba, attention, mamba) and chunks of 128,
+    five traced steps through the in-graph job."""
+    import jax
+
+    from benchmark.harness import chip, measure
+    from benchmark.reduce import xplane
+    from benchmark.tests import tiny_granitehybrid
+    config = tiny_granitehybrid.config(layers=[4, 5, 6])
+    # a chunk the chip's tiling takes: a block's last dimension is the
+    # array's own or a multiple of 128
+    config["published"]["mamba_chunk_size"] = 128
+    cell = dataclasses.replace(cell, config=config,
+                               job={**cell.job, **config["job"]})
+    line, _ = measure.run_cell(
+        cell, seed=3, seconds=1.0, trace=True, devices=jax.devices()[:1],
+        peaks=chip.require(jax.devices(), 1), t_start=0.0)
+    print(line)
+    shutil.copy(xplane.find(os.path.join(measure.TRACE_ROOT, cell.name)), out)
+    return 0 if line["correct"] else 1
+
+
+EXTRAS = {"afmoe": _afmoe_extras, "granitehybrid": _granitehybrid_extras}
+RECORD = {"granitehybrid": _granitehybrid_record}
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seeds", type=int, nargs="*", default=[])
+    ap.add_argument("--variants", nargs="*", default=[])
+    ap.add_argument("--out")
+    ap.add_argument("--leaves", action="store_true",
+                    help="every leaf's [difference, norm ratio] too (the "
+                         "gradients are computed a second time for it)")
+    ap.add_argument("--record")
+    args = ap.parse_args(argv)
+
+    import jax
+
+    from benchmark.harness import correct, manifest, seeded
+    from byteps_tpu.utils import compile_cache
+    compile_cache.enable()
+    cell = manifest.load_cell(args.workload)
+    name = cell.config["family"]
+    if args.record:
+        if name not in RECORD:
+            ap.error(f"no recorded trace for the {name} family")
+        return RECORD[name](cell, args.record)
+    family = importlib.import_module(
+        f"benchmark.families.{name}").Family(cell.config, cell.job)
+    variants = {}
+    if args.variants:
+        variants = importlib.import_module(
+            f"benchmark.tests.{name}_variants").VARIANTS
+    names = list(variants) if args.variants == ["all"] else args.variants
+    samples = family.reference_check["samples"]
+    out = open(args.out, "a") if args.out else None
+
+    def leaves(params, batch):
+        mine = jax.jit(jax.grad(family.loss))(params, batch)
+        ref = correct.reference_value_and_grad(family.reference_loss, params,
+                                               batch)[1]
+        both = jax.jit(lambda g, r: jax.tree.map(
+            correct._rel_diff_and_norm_ratio, g, r))(mine, ref)
+        return {jax.tree_util.keystr(path): [round(float(x), 5) for x in v]
+                for path, v in jax.tree_util.tree_flatten_with_path(both)[0]}
+
+    def compare(seed, variant):
+        t0 = time.perf_counter()
+        params = seeded.params(family, seed)
+        batch = seeded.batch(family, seed, samples)
+        got = correct.gradient_agreement(family.loss, family.reference_loss,
+                                         params, batch)
+        jax.effects_barrier()
+        line = {"seed": seed, "variant": variant,
+                "device": jax.devices()[0].device_kind,
+                "ok": correct.agreement_ok(got, family.reference_check),
+                **got, "seconds": time.perf_counter() - t0}
+        if name in EXTRAS:
+            line.update(EXTRAS[name](family, cell, params, seed, variant))
+        if args.leaves:
+            line["leaves"] = leaves(params, batch)
+        print(json.dumps(line), flush=True)
+        if out:
+            out.write(json.dumps(line) + "\n")
+            out.flush()
+        return line["ok"]
+
+    ok = all([compare(seed, None) for seed in args.seeds])
+    for variant in names:
+        with variants[variant](family):
+            ok = (not compare(args.seeds[0], variant)) and ok
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main())
