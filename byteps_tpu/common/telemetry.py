@@ -435,6 +435,27 @@ def record_ssd_scan(layers: int, chunk: int, state_bytes: int) -> None:
 
 
 # ---------------------------------------------------------------------------
+# Flash attention's schedule (ops/flash_attention.py, the resident path)
+# ---------------------------------------------------------------------------
+def record_flash_tiles(tiles_computed: int, tiles_masked: int,
+                       pairs_needed_share: float) -> None:
+    """What the last resident flash-attention call that was traced does
+    to its [S, S] square (`flash_attention.tile_schedule`), written once
+    per trace like `record_ingraph_exchange`."""
+    reg = get_registry()
+    reg.gauge("bps_flash_tiles_computed",
+              help="tiles of logits one head's forward kernel computes "
+                   "in the last traced flash call").set(int(tiles_computed))
+    reg.gauge("bps_flash_tiles_masked",
+              help="of those, the tiles it masks: the ones the causal "
+                   "diagonal or a window's edge crosses"
+              ).set(int(tiles_masked))
+    reg.gauge("bps_flash_pairs_needed_share",
+              help="(query, key) pairs the mask leaves over the pairs "
+                   "the computed tiles hold").set(float(pairs_needed_share))
+
+
+# ---------------------------------------------------------------------------
 # Hierarchical reduction (parallel/hierarchy.py; BYTEPS_TPU_HIERARCHY=1)
 # ---------------------------------------------------------------------------
 def record_hierarchy_saved(nbytes: int,

@@ -70,14 +70,16 @@ class TransformerConfig:
     # (measurements: docs/performance.md).
     remat_policy: str = "none"    # "none" | "dots" | "dots_no_batch" | "proj"
     attn_impl: str = "dense"           # "dense" | "flash" | "ring" (sp)
-    # Flash-kernel block size override (0 = flash_auto_block's rule:
-    # full-sequence at S <= 512, largest of 512/256/128 dividing S
-    # beyond).  Larger blocks at short S mean fewer, fatter kernel
-    # programs; must divide seq_len and be a multiple of 128.
+    # Flash-kernel override of the rows of a group (0 = the rule,
+    # `flash_auto_tiles`: square tiles, full-sequence at S <= 512 and the
+    # largest of 512/256/128 dividing S beyond; causal at S <= 1024,
+    # groups of S / 4 rows over all their keys).  Must divide seq_len and
+    # be a multiple of 128.
     attn_block: int = 0
-    # K/V tile override (0 = same as attn_block).  Decoupling lets long-S
-    # sweeps trade per-iteration VMEM / causal masked waste (K tile)
-    # against program count (Q tile) independently.
+    # Override of the tile of keys (0 = same as attn_block, or the rule's
+    # where that is 0 too).  The rows set what is computed above the
+    # causal diagonal, the tile of keys how often the softmax's per-row
+    # bookkeeping is paid.
     attn_block_k: int = 0
     # Fused LM-head cross-entropy: > 0 streams the readout matmul + softmax
     # in row chunks of this size so the [B*S, vocab] logits are never
@@ -328,24 +330,41 @@ def dense_attention(q, k, v, causal: bool):
     return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
 
-def flash_auto_block(S: int) -> int:
-    """The flash adapter's auto block-size rule, exported so a caller can
-    state the block that actually runs without duplicating the logic.
-    Returns 0 when no valid block exists: the kernel's Q tile must be a
-    multiple of 128 (ops/flash_attention.py `check_blocks`), so S must be
-    one.
+def flash_auto_tiles(S: int, causal: bool = False) -> Tuple[int, int]:
+    """The flash adapter's tile rule: `(block_q, block_k)` for a sequence
+    of `S`, or (0, 0) when no valid tile exists: the kernel's `block_q`
+    must be a multiple of 128 (ops/flash_attention.py `check_blocks`), so
+    S must be one.  `block_q` is the rows of a group, each with its own
+    stretch of keys; `block_k` the width of a tile of keys.
 
-    S <= 512: the full sequence as one block; per-program VMEM stays
-    small (block x block f32 logits at 512 is 1 MB).  S > 512: the largest
-    of 512/256/128 that divides S — fewer, fatter programs outweigh the
-    extra masked compute on causal diagonal blocks.  Which tile is fastest
-    on the chip is not measured in this round's ledger; attn_block pins
-    another tile per-config."""
+    Not causal, or S > 1024: square tiles, the whole sequence at
+    S <= 512, else the largest of 512/256/128 that divides S.  Causal and
+    S <= 1024, where one program owns a head's whole sequence and its
+    bounds are static: groups of a quarter of the sequence (at least 128
+    rows), each taking ALL its keys as one tile, `block_k` = S, so that
+    no pair is computed past the end of a group's diagonal and the
+    softmax's per-row bookkeeping is paid once.
+
+    From the chip (TPU v5e, the three calls alone under the profiler,
+    us a head for forward + forward + dQ + dK/dV; PERF.md section 6,
+    PR 35): at S = 1024, head size 64, causal, (256, 1024) 11.6,
+    (128, 1024) 12.1, (512, 512) 12.5, (512, 1024) 12.6, and (256, 256)
+    half as much again; at S = 8192, (512, 512) 4% under (256, 512), at
+    head size 64 and 128 alike.  A tile's time is the vector unit's, not the
+    MXU's: narrow tiles of keys pay the [rows, 1] statistics as often as
+    wide ones and lose."""
     if S % 128:
-        return 0
-    if S <= 512:
-        return S
-    return next(b for b in (512, 256, 128) if S % b == 0)
+        return 0, 0
+    if causal and S <= 1024:
+        return (S // 4 if S % 512 == 0 else 128), S
+    block = S if S <= 512 else next(b for b in (512, 256, 128) if S % b == 0)
+    return block, block
+
+
+def flash_auto_block(S: int, causal: bool = False) -> int:
+    """`block_q` of `flash_auto_tiles`, exported so a caller can state
+    the block that actually runs without duplicating the logic."""
+    return flash_auto_tiles(S, causal)[0]
 
 
 def flash_attention_fn(q, k, v, causal: bool, block: int = 0,
@@ -357,14 +376,10 @@ def flash_attention_fn(q, k, v, causal: bool, block: int = 0,
     would materialize the S x S logits the caller chose flash to avoid
     and attribute dense throughput to a flash config.
 
-    block=0 auto-selects via `flash_auto_block` (full-sequence block at
-    S <= 512, the largest of 512/256/128 dividing S beyond).  A nonzero
-    override trades grid-iteration overhead against VMEM per program by
-    hand (TransformerConfig.attn_block); `block_k` additionally
-    decouples the K/V tile from the Q tile
-    (TransformerConfig.attn_block_k) — at long S the Q tile sets
-    program count while the K tile sets per-iteration VMEM and masked
-    waste on causal diagonals, and the optimum need not be square.
+    block=0 auto-selects both tiles via `flash_auto_tiles`.  A nonzero
+    override sets the rows of a group by hand
+    (TransformerConfig.attn_block) and, unless `block_k` says otherwise
+    (TransformerConfig.attn_block_k), the tile of keys with it.
     Overrides must divide S and be a multiple of 128 (block) or 64
     (block_k), the tile sizes the chip's compiler accepts; anything else
     reverts to the AUTO choice.
@@ -374,10 +389,12 @@ def flash_attention_fn(q, k, v, causal: bool, block: int = 0,
     from ..ops.flash_attention import (BLOCK_K_MULTIPLE, BLOCK_Q_MULTIPLE,
                                        flash_attention)
     B, H, S, Dh = q.shape
+    auto_q, auto_k = flash_auto_tiles(S, causal)
     if not block or S % block or block % BLOCK_Q_MULTIPLE:
-        block = flash_auto_block(S)
+        block = 0
     if not block_k or S % block_k or block_k % BLOCK_K_MULTIPLE:
-        block_k = block
+        block_k = block or auto_k
+    block = block or auto_q
     if block == 0 or Dh % 8:
         raise ValueError(
             f"flash attention needs seq_len divisible by "

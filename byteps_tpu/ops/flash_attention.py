@@ -9,21 +9,39 @@ saved log-sum-exp instead of storing them.
 
 Two execution strategies, auto-selected by VMEM footprint:
 
-  - **resident** (short/medium S): K and V live in VMEM for the whole
-    kernel; each q block loops over them with `lax.fori_loop`.  K/V are
-    fetched from HBM once per (batch*head), which is what makes the
-    kernel beat XLA's fused dense attention (measured 1.6x at S=4096 on
-    v5e, docs/performance.md).
-  - **streaming** (long S): 3D grid with the contraction axis innermost —
-    (bh, q_blocks, k_blocks) forward/dq, (bh, k_blocks, q_blocks) dk/dv —
-    carrying running statistics in VMEM scratch across the innermost
-    iterations (the matmul k-loop pattern).  Per-program VMEM is
-    O(block * d) regardless of S, so the kernel keeps compiling at 32k+
-    contexts, at the price of re-streaming K/V once per q block.
+  - **resident** (K and V of a head within RESIDENT_VMEM_BUDGET: every
+    cell of the benchmark, GPT-2 at S=1024 with 256 KB a head, granite
+    and trinity-mini at S=8192 with 2 and 4 MiB): K and V live in VMEM
+    for the whole kernel and are fetched from HBM once per (batch*head).
+    A program owns the whole sequence up to PROGRAM_ROWS rows, beyond
+    that one tile's worth, and takes its rows in groups of `block_q`.  A group first takes its REGION in one piece, the keys
+    from the start of the `block_k` tile that holds its first row to its
+    last row's own key, masked: the diagonal's part, as wide as it has to
+    be and no wider.  Then it loops over the whole tiles before that,
+    which no diagonal crosses and nothing masks (a sliding window's far
+    edge crosses some: those are masked, in a loop of their own).  The
+    dK/dV kernel walks the same square by its keys, on tiles transposed.
+    Where one program owns the sequence (S <= 1024) every bound is a
+    Python int and the kernel is straight-line code; with `block_k` = S a
+    group is one region and a plain softmax, no running statistics.
+    `k_tiles` / `q_tiles` give the bounds, `tile_schedule` counts what
+    they visit (the `bps_flash_*` gauges, written when a call is traced).
+  - **streaming** (longer S; no cell): 3D grid with the contraction axis
+    innermost, (bh, q_blocks, k_blocks) forward/dq, (bh, k_blocks,
+    q_blocks) dk/dv, carrying running statistics in VMEM scratch across
+    the innermost iterations (the matmul k-loop pattern).  Per-program
+    VMEM is O(block * d) regardless of S, so the kernel keeps compiling
+    at 32k+ contexts, at the price of re-streaming K/V once per q block.
+    Blocks no row can see are predicated away (`pl.when`); every live
+    block of a causal call is masked.
 
-Causal grids predicate away upper-triangle blocks (`pl.when` in the
-streaming path, a shortened `fori_loop` bound in the resident path) so
-masked blocks' matmuls never issue.
+What a tile costs on a v5e is the vector unit's work on its float32
+logits, not the MXU's: head size 64 and 128 take the same time a tile,
+and bfloat16 operands, or bfloat16 probabilities, bought nothing
+measurable (PERF.md section 6, PR 35).  So Q is scaled once a group (in
+float32; K once a group in dK/dV), the per-row statistics are paid once a
+tile and tiles of keys are wide, and P and dS go into their products in
+float32.
 
 This is the compute-path counterpart of the reference's CUDA-side
 optimizations: the reference leaves model compute to torch/cudnn (no
@@ -34,8 +52,7 @@ custom-VJP pattern).
 Layout: q, k, v are [BH, S, D] (batch*heads folded into the grid's first
 axis).  The block sizes must divide S; block_q must be a multiple of 128
 and block_k a multiple of 64 (`check_blocks` — the chip's lane rule, which
-interpret mode does not enforce); D should be a multiple of 8 (128 ideal
-for the MXU lane).  A shape that doesn't satisfy the constraints is
+interpret mode does not enforce); D should be a multiple of 8.  A shape that doesn't satisfy the constraints is
 refused up front, on every backend: the kernel never degrades to dense
 attention, and neither does `models.transformer.flash_attention_fn`.
 """
@@ -51,6 +68,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common import telemetry
+
 NEG_INF = float("-inf")
 
 # K+V (resident path) above this many bytes switch to the streaming path;
@@ -58,13 +77,14 @@ NEG_INF = float("-inf")
 RESIDENT_VMEM_BUDGET = 6 * 1024 * 1024
 
 
-# The Q tile is the LANE dim of the per-row statistics blocks (log-sum-exp
-# and delta, shape (1, 1, block_q)), and the TPU lowering takes a block's
-# last dim only in multiples of 128.  Mosaic also refuses the resident
-# dK/dV kernel's lane-dim slices of those rows unless they are provably
-# 128-aligned, so "block_q == S" does not rescue an S that is an odd
-# multiple of 64.  K/V tiles only ever sit on a sublane dim.  Both facts
-# are from compiling for a described v5e (tests/test_tpu_aot_compile.py).
+# A group of block_q rows starts a slice of the LANE dim of the per-row
+# statistics (log-sum-exp and delta, shape (BH, 1, S)), and the TPU
+# lowering takes a block's last dim only in multiples of 128.  Mosaic also
+# refuses the resident dK/dV kernel's lane-dim slices of those rows unless
+# they are provably 128-aligned, so "block_q == S" does not rescue an S
+# that is an odd multiple of 64.  K/V tiles only ever sit on a sublane
+# dim.  Both facts are from compiling for a described v5e
+# (tests/test_tpu_aot_compile.py).
 BLOCK_Q_MULTIPLE = 128
 BLOCK_K_MULTIPLE = 64
 
@@ -94,15 +114,109 @@ def _use_streaming(q, streaming: Optional[bool]) -> bool:
     return 2 * s * d * q.dtype.itemsize > RESIDENT_VMEM_BUDGET
 
 
-def _causal_mask(s, qi, kb, block_q, block_k, window=None):
-    """Mask logits where key position > query position (global indices)
-    and, under a sliding `window`, where it is `window` or more behind:
-    row i sees the keys i - window < j <= i."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) + qi * block_q
-    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + kb * block_k
-    keep = rows >= cols
+def _pick(ints, traced, a, b):
+    if isinstance(a, int) and isinstance(b, int):
+        return ints(a, b)
+    return traced(a, b)
+
+
+def _least(a, b):
+    return _pick(min, jnp.minimum, a, b)
+
+
+def _most(a, b):
+    return _pick(max, jnp.maximum, a, b)
+
+
+def _clamp(x, lo, hi):
+    return _least(_most(x, lo), hi)
+
+
+def dkv_tile(block_k):
+    """The Q tile of the dK/dV kernel: `block_k`, made a multiple of 128
+    because it slices the log-sum-exp's lane dim."""
+    return math.lcm(block_k, BLOCK_Q_MULTIPLE)
+
+
+def k_tiles(r0, at, group, tile, num_tiles, causal, window=None):
+    """What the query rows [r0, r0 + group) visit of the keys, which lie
+    in tiles of `tile`:
+
+        (start, width, masked), (a, b, c)
+
+    First one REGION, the keys [start, start + width): under a causal
+    mask the stretch from the start of the tile that holds key r0 to the
+    rows' last key, r0 + group - 1, so its width is static and it ends
+    where the diagonal does.  Then the tiles [a, c) before it, of which
+    [a, b) are crossed by the far edge of a sliding `window` (row i sees
+    the keys i - window < j <= i) and are masked, and [b, c) are seen
+    whole by every row and are not.  Without a mask the region is tile 0
+    and [b, c) the rest.
+
+    `r0` is a Python int or a traced index, `at` = r0 % tile always an
+    int.  The kernels' bounds and `tile_schedule`'s counts both come from
+    here; `q_tiles` is the same square seen from the keys."""
+    if not causal:
+        return (0, tile, False), (1, 1, num_tiles)
+    last = (r0 - at) // tile
+    region = (r0 - at, at + group, True)
+    if window is None:
+        return region, (0, 0, last)
+    a = _least(_most(r0 - window + 1, 0) // tile, last)
+    # the first tile whose first key the LAST row still sees
+    whole_from = _most(r0 + group - window + tile - 1, 0) // tile
+    return region, (a, _clamp(whole_from, a, last), last)
+
+
+def q_tiles(c0, at, group, tile, num_tiles, causal, window=None):
+    """What visits the keys [c0, c0 + group), of the query rows in tiles
+    of `tile`: `(start, height, masked), (b, c, d)`.  The region is the
+    rows from c0 to the end of the tile that holds row c0 + group - 1;
+    the tiles [b, d) after it are past the diagonal, [b, c) seen whole
+    and [c, d) crossed by the window's edge.  `at` = c0 % tile."""
+    if not causal:
+        return (0, tile, False), (1, num_tiles, num_tiles)
+    spans = (at + group + tile - 1) // tile     # tiles the region touches
+    b = (c0 - at) // tile + spans
+    region = (c0, spans * tile - at, True)
+    if window is None:
+        return region, (b, num_tiles, num_tiles)
+    d = _clamp((c0 + group + window - 2) // tile + 1, b, num_tiles)
+    # tiles whose last row still sees key c0
+    return region, (b, _clamp((c0 + window) // tile, b, d), d)
+
+
+def tile_schedule(s, block_q, block_k, causal, window=None):
+    """What a call's forward and dQ kernels do to the [s, s] square, from
+    the bounds they loop over: the tiles (regions counted as tiles) they
+    compute, how many of those they mask, and the share of the computed
+    (query, key) pairs that the mask leaves.  (dK/dV walks the same
+    square by its keys, `q_tiles`, in groups and tiles of the same
+    sizes.)"""
+    computed = masked = pairs = 0
+    for r0 in range(0, s, block_q):
+        (_, width, edge), (a, b, c) = k_tiles(
+            r0, r0 % block_k, block_q, block_k, s // block_k, causal, window)
+        computed += 1 + c - a
+        masked += int(edge) + b - a
+        pairs += block_q * (width + (c - a) * block_k)
+    w = min(window or s, s)             # keys the last row sees
+    needed = w * (w + 1) // 2 + (s - w) * w if causal else s * s
+    return {"tiles_computed": computed, "tiles_masked": masked,
+            "pairs_needed_share": needed / pairs}
+
+
+def _mask(s, q0, k0, window, q_axis=0):
+    """Mask a tile of logits whose first query is `q0` and first key `k0`
+    (global positions; queries run along `q_axis`): a key after the query
+    goes, and under a sliding `window` one that is `window` or more
+    behind it."""
+    rel = (jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+           - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis))
+    ahead = k0 - q0                      # query - key = rel - ahead
+    keep = rel >= ahead
     if window is not None:
-        keep = keep & (rows - cols < window)
+        keep = keep & (rel < ahead + window)
     return jnp.where(keep, s, NEG_INF)
 
 
@@ -117,170 +231,222 @@ def _block_live(causal, qi, kb, block_q, block_k, window=None):
     return live
 
 
-def _first_kb(qi, block_q, block_k, window):
-    """The first k block a q block's rows can see under `window`."""
-    return jnp.maximum(qi * block_q - window + 1, 0) // block_k
+def _dot_nt(a, b):
+    """a [m, d] . b [n, d]^T -> [m, n] float32."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
 
 
-def _qb_end(ki, block_q, block_k, window, num_qb):
-    """One past the last q block that can see k block `ki` under
-    `window`: its last column is seen by rows up to window - 1 later."""
-    return jnp.minimum(
-        num_qb, ((ki + 1) * block_k + window - 2) // block_q + 1)
+def _dot_f32(p, x):
+    """p [m, n] float32 . x [n, d] -> [m, d] float32.  The probabilities
+    and dS stay float32 into their products, so `x` is upcast to meet
+    them."""
+    return jnp.dot(p, x.astype(jnp.float32),
+                   preferred_element_type=jnp.float32)
 
 
-def _online_step(q_scaled, k, v, carry, qi, kb, causal, block_q, block_k,
-                 window=None):
-    """One online-softmax accumulation step shared by both forward paths."""
+def _scaled(x, sm_scale):
+    """Q (dK/dV: K) in float32 times the softmax's scale, once for all
+    the tiles it meets: cheaper than scaling every tile of logits."""
+    return x.astype(jnp.float32) * sm_scale
+
+
+def _to_lanes(col):
+    """A [rows, 1] column as `(offset, [128] vector along lanes)` pieces,
+    the layout the log-sum-exp is stored in: 128 rows at a time, the
+    column broadcast along lanes, all but the diagonal zeroed, and summed
+    down the sublanes.  Exact, and a few passes of the vector unit over
+    [128, 128], where Mosaic's own relayout of `col[:, 0]` took a quarter
+    of a forward call at S = 1024."""
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (128, 128), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (128, 128), 1))
+    return [(r, jnp.sum(jnp.where(eye, col[r:r + 128], 0.0), axis=0))
+            for r in range(0, col.shape[0], 128)]
+
+
+def _online_step(q_scaled, k, v, carry, edge, window=None):
+    """One online-softmax accumulation step shared by both forward paths;
+    `carry` None starts one.  `edge` is None for a tile every row sees
+    whole, else the tile's `(first query, first key)`, and the tile is
+    masked."""
+    s = _dot_nt(q_scaled, k.astype(jnp.float32))          # (bq, bk)
+    if edge is not None:
+        s = _mask(s, *edge, window)
+    m_new = jnp.max(s, axis=-1, keepdims=True)
+    if carry is None:
+        p = jnp.exp(s - m_new)
+        return m_new, jnp.sum(p, axis=-1, keepdims=True), _dot_f32(p, v)
     m, l, acc = carry
-    s = jax.lax.dot_general(
-        q_scaled, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (bq, bk)
-    if causal:
-        s = _causal_mask(s, qi, kb, block_q, block_k, window)
-    m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-    if window is None:
+    m_new = jnp.maximum(m, m_new)
+    if edge is None or window is None:
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - m_new)
     else:
         # Under a window a row can meet a live block of which it sees
         # nothing before it has seen any key: its running max is still
-        # -inf, and exp(-inf - -inf) would be NaN.  (Causal alone never
-        # meets this: every row sees column 0 of the first block.)
+        # -inf, and exp(-inf - -inf) would be NaN.  (Only the streaming
+        # path meets this: a resident program starts each row at its own
+        # key.  Causal alone never does: every row sees key 0.)
         m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
         alpha = jnp.exp(m - m_safe)
         p = jnp.exp(s - m_safe)
     l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_new = acc * alpha + jnp.dot(p, v,
-                                    preferred_element_type=jnp.float32)
-    return m_new, l_new, acc_new
+    return m_new, l_new, acc * alpha + _dot_f32(p, v)
 
 
-def _dq_step(q, k, v, do, lse, delta, sm_scale, qi, kb, causal, block_q,
-             block_k, window=None):
-    s = sm_scale * jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    if causal:
-        s = _causal_mask(s, qi, kb, block_q, block_k, window)
+def _dq_step(q_scaled, k, v, do, lse, delta, edge, window=None):
+    """A tile's part of dQ, short of the softmax's scale."""
+    s = _dot_nt(q_scaled, k.astype(jnp.float32))
+    if edge is not None:
+        s = _mask(s, *edge, window)
     p = jnp.exp(s - lse)                                 # (bq, bk)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    ds = p * (dp - delta)
-    return sm_scale * jnp.dot(ds, k, preferred_element_type=jnp.float32)
+    return _dot_f32(p * (_dot_nt(do, v) - delta), k)
 
 
-def _dkv_step(q, k, v, do, lse, delta, sm_scale, qb, ki, causal, block_q,
-              block_k, window=None):
-    s = sm_scale * jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (bq, bk)
-    if causal:
-        s = _causal_mask(s, qb, ki, block_q, block_k, window)
-    p = jnp.exp(s - lse)
-    dv = jax.lax.dot_general(
-        p, do, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)              # (bk, d)
-    dp = jax.lax.dot_general(
-        do, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    ds = p * (dp - delta)
-    dk = sm_scale * jax.lax.dot_general(
-        ds, q, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    return dk, dv
+def _dkv_step(q, k_scaled, v, do, lse, delta, edge, window=None):
+    """A tile's parts of dK (short of the softmax's scale) and dV,
+    computed on the tile TRANSPOSED: keys down, queries across.  So `lse`
+    and `delta` come as the lane rows [1, bq] they are stored as, and
+    P^T dO and dS^T Q are plain products, where the tile the other way
+    round turns two [bq] rows into columns and transposes P and dS, every
+    turn of the loop."""
+    st = _dot_nt(k_scaled, q.astype(jnp.float32))         # (bk, bq)
+    if edge is not None:
+        st = _mask(st, *edge, window, q_axis=1)
+    pt = jnp.exp(st - lse)
+    dst = pt * (_dot_nt(v, do) - delta)
+    return _dot_f32(dst, q), _dot_f32(pt, do)
 
 
 # ---------------------------------------------------------------------------
-# Resident path: K/V whole in VMEM; grid (bh, q_blocks); fori_loop over k.
+# Resident path: K/V whole in VMEM.  A program takes its rows (dK/dV:
+# keys) group by group, each group with its own region and its own bounds
+# on the loop over the other axis.
 # ---------------------------------------------------------------------------
+# A short sequence wants small groups (fewer pairs computed above the
+# causal diagonal), wide tiles (the softmax's per-row bookkeeping is paid
+# once a tile whatever its width) and ONE program a head: a program that
+# owns the whole axis has Python ints for bounds and is straight-line
+# code, which at S = 1024 was worth more than either (PERF.md section 6,
+# PR 35).  A longer sequence got nothing from fatter programs (S = 8192:
+# 569.7 us a head at 2048 rows a program, 573.6 at 512) but their compile
+# time, so there a program owns as little as it can.
+PROGRAM_ROWS = 1024
+
+
+def _program_rows(s, group, tile):
+    """The rows one resident program owns: the whole sequence up to
+    PROGRAM_ROWS, else the least multiple of both `group` and `tile` (so
+    that where a group lies in its tile is static)."""
+    return s if s <= PROGRAM_ROWS else math.lcm(group, tile)
+
+
+def _groups(extent, s, group, tile):
+    """`(slice in the program's block, first row, first row % tile)` of
+    each group of a program's `extent` rows; the first row a Python int
+    where one program owns the whole axis."""
+    start = 0 if extent == s else pl.program_id(1) * extent
+    return [(pl.ds(t * group, group), start + t * group, t * group % tile)
+            for t in range(extent // group)]
+
+
+def _walk(runs, body, carry):
+    """`body(index, carry, masked)` over each `(lo, hi, masked)` run of
+    tiles, traced here and now (a group's closures never outlive its turn
+    of the Python loop); a run that is empty in Python leaves no loop
+    behind."""
+    for lo, hi, masked in runs:
+        if isinstance(lo, int) and isinstance(hi, int) and lo >= hi:
+            continue
+        carry = jax.lax.fori_loop(
+            lo, hi, functools.partial(body, masked=masked), carry)
+    return carry
+
+
 def _fwd_kernel_res(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale,
                     causal, block_q, block_k, seq_len, window=None):
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32) * sm_scale          # (bq, d)
-    bq, d = q.shape
-    num_kb = seq_len // block_k
-    if causal:
-        num_kb = jnp.minimum(num_kb,
-                             ((qi + 1) * block_q + block_k - 1) // block_k)
+    for rows, r0, at in _groups(q_ref.shape[1], seq_len, block_q, block_k):
+        q = _scaled(q_ref[0, rows, :], sm_scale)         # (bq, d)
+        (start, width, edge), (a, b, c) = k_tiles(
+            r0, at, block_q, block_k, seq_len // block_k, causal, window)
 
-    def body(kb, carry):
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        return _online_step(q, k, v, carry, qi, kb, causal, block_q,
-                            block_k, window)
+        def tile(k0, width, carry, masked):
+            keys = pl.ds(k0, width)
+            return _online_step(q, k_ref[0, keys, :], v_ref[0, keys, :],
+                                carry, (r0, k0) if masked else None, window)
 
-    m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc0 = jnp.zeros((bq, d), jnp.float32)
-    first_kb = 0 if window is None else _first_kb(qi, block_q, block_k,
-                                                  window)
-    m, l, acc = jax.lax.fori_loop(first_kb, num_kb, body, (m0, l0, acc0))
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    # Layout (BH, 1, S): TPU block tiling needs the last two dims to be
-    # (1, block) with both tile-divisible or dim-equal.
-    lse_ref[0, 0, :] = (m + jnp.log(l))[:, 0]
+        def body(kb, carry, masked):
+            return tile(kb * block_k, block_k, carry, masked)
+
+        m, l, acc = _walk([(a, b, True), (b, c, False)], body,
+                          tile(start, width, None, edge))
+        o_ref[0, rows, :] = (acc * (1.0 / l)).astype(o_ref.dtype)
+        # Layout (BH, 1, S): TPU block tiling needs the last two dims to
+        # be (1, block) with both tile-divisible or dim-equal.
+        for r, piece in _to_lanes(m + jnp.log(l)):
+            lse_ref[0, 0, pl.ds(rows.start + r, 128)] = piece
 
 
 def _dq_kernel_res(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                    *, sm_scale, causal, block_q, block_k, seq_len,
                    window=None):
-    qi = pl.program_id(1)
-    q = q_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0, 0, :][:, None]
-    delta = delta_ref[0, 0, :][:, None]
-    bq, d = q.shape
-    num_kb = seq_len // block_k
-    if causal:
-        num_kb = jnp.minimum(num_kb,
-                             ((qi + 1) * block_q + block_k - 1) // block_k)
+    for rows, r0, at in _groups(q_ref.shape[1], seq_len, block_q, block_k):
+        q, do = _scaled(q_ref[0, rows, :], sm_scale), do_ref[0, rows, :]
+        lse = lse_ref[0, 0, rows][:, None]
+        delta = delta_ref[0, 0, rows][:, None]
+        (start, width, edge), (a, b, c) = k_tiles(
+            r0, at, block_q, block_k, seq_len // block_k, causal, window)
 
-    def body(kb, dq):
-        k = k_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v = v_ref[0, pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        return dq + _dq_step(q, k, v, do, lse, delta, sm_scale, qi, kb,
-                             causal, block_q, block_k, window)
+        def tile(k0, width, masked):
+            keys = pl.ds(k0, width)
+            return _dq_step(q, k_ref[0, keys, :], v_ref[0, keys, :], do,
+                            lse, delta, (r0, k0) if masked else None, window)
 
-    first_kb = 0 if window is None else _first_kb(qi, block_q, block_k,
-                                                  window)
-    dq = jax.lax.fori_loop(first_kb, num_kb, body,
-                           jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+        def body(kb, dq, masked):
+            return dq + tile(kb * block_k, block_k, masked)
+
+        dq = _walk([(a, b, True), (b, c, False)], body,
+                   tile(start, width, edge))
+        dq_ref[0, rows, :] = (sm_scale * dq).astype(dq_ref.dtype)
 
 
 def _dkv_kernel_res(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, *, sm_scale, causal, block_q, block_k,
                     seq_len, window=None):
-    ki = pl.program_id(1)
-    k = k_ref[0].astype(jnp.float32)                     # (bk, d)
-    v = v_ref[0].astype(jnp.float32)
-    bk, d = k.shape
-    num_qb = seq_len // block_q
-    start_qb = (ki * block_k) // block_q if causal else 0
-    if window is not None:
-        num_qb = _qb_end(ki, block_q, block_k, window, num_qb)
+    # the keys in groups of block_q, the rows in tiles of block_k: the
+    # forward's square, walked the other way
+    rows_tile = dkv_tile(block_k)
+    for keys, c0, at in _groups(k_ref.shape[1], seq_len, block_q, rows_tile):
+        k, v = _scaled(k_ref[0, keys, :], sm_scale), v_ref[0, keys, :]
+        (start, height, edge), (b, c, d) = q_tiles(
+            c0, at, block_q, rows_tile, seq_len // rows_tile, causal, window)
 
-    def body(qb, carry):
-        dk, dv = carry
-        q = q_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        do = do_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(qb * block_q, block_q)][:, None]
-        delta = delta_ref[0, 0, pl.ds(qb * block_q, block_q)][:, None]
-        dk_i, dv_i = _dkv_step(q, k, v, do, lse, delta, sm_scale, qb, ki,
-                               causal, block_q, block_k, window)
-        return dk + dk_i, dv + dv_i
+        def tile(q0, height, masked):
+            rows = pl.ds(q0, height)
+            return _dkv_step(q_ref[0, rows, :], k, v, do_ref[0, rows, :],
+                             lse_ref[0, :, rows], delta_ref[0, :, rows],
+                             (q0, c0) if masked else None, window)
 
-    z = jnp.zeros((bk, d), jnp.float32)
-    dk, dv = jax.lax.fori_loop(start_qb, num_qb, body, (z, z))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+        def body(qb, carry, masked):
+            dk, dv = tile(qb * rows_tile, rows_tile, masked)
+            return carry[0] + dk, carry[1] + dv
+
+        dk, dv = _walk([(b, c, False), (c, d, True)], body,
+                       tile(start, height, edge))
+        dk_ref[0, keys, :] = (sm_scale * dk).astype(dk_ref.dtype)
+        dv_ref[0, keys, :] = dv.astype(dv_ref.dtype)
 
 
 # ---------------------------------------------------------------------------
 # Streaming path: 3D grid, contraction axis innermost, scratch carries.
 # ---------------------------------------------------------------------------
+def _edge(causal, q0, k0):
+    """A streaming program's tile is one of a traced grid's: which side
+    of the diagonal it lies on is not known in Python, so every tile of a
+    causal call is masked."""
+    return (q0, k0) if causal else None
+
+
 def _fwd_kernel_str(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
                     acc_scr, *, sm_scale, causal, block_q, block_k,
                     window=None):
@@ -296,12 +462,10 @@ def _fwd_kernel_str(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
     @pl.when(_block_live(causal, qi, kb, block_q, block_k, window))
     def _step():
-        q = q_ref[0].astype(jnp.float32) * sm_scale
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        m, l, acc = _online_step(q, k, v,
-                                 (m_scr[:], l_scr[:], acc_scr[:]),
-                                 qi, kb, causal, block_q, block_k, window)
+        m, l, acc = _online_step(
+            _scaled(q_ref[0], sm_scale), k_ref[0], v_ref[0],
+            (m_scr[:], l_scr[:], acc_scr[:]),
+            _edge(causal, qi * block_q, kb * block_k), window)
         m_scr[:], l_scr[:], acc_scr[:] = m, l, acc
 
     @pl.when(kb == last_kb)
@@ -325,16 +489,13 @@ def _dq_kernel_str(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     @pl.when(_block_live(causal, qi, kb, block_q, block_k, window))
     def _step():
         dq_scr[:] = dq_scr[:] + _dq_step(
-            q_ref[0].astype(jnp.float32),
-            k_ref[0].astype(jnp.float32),
-            v_ref[0].astype(jnp.float32),
-            do_ref[0].astype(jnp.float32),
+            _scaled(q_ref[0], sm_scale), k_ref[0], v_ref[0], do_ref[0],
             lse_ref[0, 0, :][:, None], delta_ref[0, 0, :][:, None],
-            sm_scale, qi, kb, causal, block_q, block_k, window)
+            _edge(causal, qi * block_q, kb * block_k), window)
 
     @pl.when(kb == last_kb)
     def _finish():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        dq_ref[0] = (sm_scale * dq_scr[:]).astype(dq_ref.dtype)
 
 
 def _dkv_kernel_str(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -352,18 +513,15 @@ def _dkv_kernel_str(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(_block_live(causal, qb, ki, block_q, block_k, window))
     def _step():
         dk_i, dv_i = _dkv_step(
-            q_ref[0].astype(jnp.float32),
-            k_ref[0].astype(jnp.float32),
-            v_ref[0].astype(jnp.float32),
-            do_ref[0].astype(jnp.float32),
-            lse_ref[0, 0, :][:, None], delta_ref[0, 0, :][:, None],
-            sm_scale, qb, ki, causal, block_q, block_k, window)
+            q_ref[0], _scaled(k_ref[0], sm_scale), v_ref[0], do_ref[0],
+            lse_ref[0], delta_ref[0],
+            _edge(causal, qb * block_q, ki * block_k), window)
         dk_scr[:] = dk_scr[:] + dk_i
         dv_scr[:] = dv_scr[:] + dv_i
 
     @pl.when(qb == last_qb)
     def _finish():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dk_ref[0] = (sm_scale * dk_scr[:]).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
@@ -416,12 +574,13 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, streaming,
             interpret=interpret, **named,
         )(q, k, v)
     kv_spec = pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0))
+    rows = _program_rows(s, block_q, block_k)
     return pl.pallas_call(
         functools.partial(_fwd_kernel_res, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_len=s, **kw),
-        grid=(bh, s // block_q),
-        in_specs=[_q_spec(block_q, d), kv_spec, kv_spec],
-        out_specs=[_q_spec(block_q, d), _lse_spec(block_q)],
+        grid=(bh, s // rows),
+        in_specs=[_q_spec(rows, d), kv_spec, kv_spec],
+        out_specs=[_q_spec(rows, d), _lse_spec(rows)],
         out_shape=out_shape,
         interpret=interpret, **named,
     )(q, k, v)
@@ -475,22 +634,23 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, streaming,
 
     full_spec2 = pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0))
     full_lse2 = pl.BlockSpec((1, 1, s), lambda b, i: (b, 0, 0))
+    rows = _program_rows(s, block_q, block_k)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel_res, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_len=s, **kw),
-        grid=(bh, s // block_q),
-        in_specs=[_q_spec(block_q, d), full_spec2, full_spec2,
-                  _q_spec(block_q, d), _lse_spec(block_q),
-                  _lse_spec(block_q)],
-        out_specs=_q_spec(block_q, d),
+        grid=(bh, s // rows),
+        in_specs=[_q_spec(rows, d), full_spec2, full_spec2,
+                  _q_spec(rows, d), _lse_spec(rows), _lse_spec(rows)],
+        out_specs=_q_spec(rows, d),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         interpret=interpret, **dq_named,
     )(q, k, v, do, lse, delta)
-    kb2 = pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0))
+    keys = _program_rows(s, block_q, dkv_tile(block_k))
+    kb2 = pl.BlockSpec((1, keys, d), lambda b, i: (b, i, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel_res, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_len=s, **kw),
-        grid=(bh, s // block_k),
+        grid=(bh, s // keys),
         in_specs=[full_spec2, kb2, kb2, full_spec2, full_lse2, full_lse2],
         out_specs=[kb2, kb2],
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), k.dtype),
@@ -512,10 +672,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     window: Optional[int] = None) -> jax.Array:
     """Blockwise (flash) attention.  q, k, v: [BH, S, D] -> [BH, S, D].
 
+    `block_q` is the rows of a group (in dK/dV: the keys of one), each
+    with its own bounds, and sets what is computed above a causal
+    diagonal; `block_k` is the tile of the other axis that a group walks
+    (models/transformer.py `flash_auto_tiles` is the rule).
+
     `window` (causal only) is a sliding window: row i attends to the keys
     i - window < j <= i, and the blocks no row of a tile can see are
     skipped in all three kernels, as the blocks above the diagonal are.
-    `window=None` is the plain kernel, unchanged.
+    `window=None` leaves the calls unnamed.
 
     sm_scale defaults to 1/sqrt(D).  interpret=None auto-selects the
     Pallas interpreter off-TPU so tests run on the CPU mesh.
@@ -536,9 +701,12 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         raise ValueError(f"window={window} needs causal=True and at least "
                          f"one key a row")
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
+    streaming = _use_streaming(q, streaming)
+    if not streaming:
+        telemetry.record_flash_tiles(
+            **tile_schedule(s, block_q, block_k, causal, window))
     out, lse = _fwd(q, k, v, scale, causal, block_q, block_k,
-                    _use_interpret(interpret), _use_streaming(q, streaming),
-                    window)
+                    _use_interpret(interpret), streaming, window)
     return out, (q, k, v, out, lse)
 
 

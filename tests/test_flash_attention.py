@@ -128,12 +128,14 @@ def test_block_override_parity():
 
 
 def test_auto_block_rule():
-    """Pin the auto block-size policy: full-sequence block at S <= 512,
-    largest of 512/256/128 dividing S beyond, 0 when S is not a multiple
-    of 128 (the only tile that would fit is the 64-row one the chip's
-    compiler refuses).  Every nonzero answer passes the kernel's own
-    check."""
-    from byteps_tpu.models.transformer import flash_auto_block
+    """Pin the auto tile policy.  Square tiles where nothing is masked or
+    S > 1024: full-sequence at S <= 512, largest of 512/256/128 dividing
+    S beyond.  Causal at S <= 1024: groups of a quarter of the sequence,
+    each over all its keys.  0 when S is not a multiple of 128 (the only
+    tile that would fit is the 64-row one the chip's compiler refuses).
+    Every nonzero answer passes the kernel's own check."""
+    from byteps_tpu.models.transformer import flash_auto_block, \
+        flash_auto_tiles
     from byteps_tpu.ops.flash_attention import check_blocks
     assert flash_auto_block(128) == 128
     assert flash_auto_block(512) == 512
@@ -144,10 +146,21 @@ def test_auto_block_rule():
     assert flash_auto_block(640) == 128
     for s in (64, 448, 576, 704, 1088):      # odd multiples of 64
         assert flash_auto_block(s) == 0
+        assert flash_auto_tiles(s, True) == (0, 0)
     assert flash_auto_block(100) == 0        # no valid block
     assert flash_auto_block(1000) == 0
+    assert flash_auto_tiles(512) == (512, 512)
+    assert flash_auto_tiles(1024) == (512, 512)
+    assert flash_auto_tiles(1024, True) == (256, 1024)   # GPT-2's
+    assert flash_auto_tiles(512, True) == (128, 512)
+    assert flash_auto_tiles(128, True) == (128, 128)
+    assert flash_auto_tiles(768, True) == (128, 768)
+    assert flash_auto_tiles(2048, True) == (512, 512)
+    assert flash_auto_tiles(8192, True) == (512, 512)    # the other cells'
+    assert flash_auto_block(1024, True) == 256
     for s in range(128, 4097, 128):
-        check_blocks(s, flash_auto_block(s), flash_auto_block(s))
+        for causal in (False, True):
+            check_blocks(s, *flash_auto_tiles(s, causal))
 
 
 def test_asymmetric_block_parity():
@@ -329,3 +342,138 @@ def test_window_none_is_the_kernel_as_it_was():
             lambda *a: flash_attention_fn(*a, True, window=None))(q4, k4, v4))
     with pytest.raises(ValueError):
         flash_attention(q, k, v, False, None, 128, 128, True, None, 64)
+
+
+# ---------------------------------------------------------------------------
+# The resident schedule: groups of rows, a region each, whole tiles
+# ---------------------------------------------------------------------------
+def _loss_and_grads(attn, q, k, v, g):
+    return jax.value_and_grad(lambda q, k, v: (attn(q, k, v) * g).sum(),
+                              (0, 1, 2))(q, k, v)
+
+
+def _assert_close_to_dense(bh, s, d, bq, bk, window=None, seed=3):
+    rng = np.random.RandomState(seed)
+    q, k, v, g = (_rand(rng, bh, s, d) for _ in range(4))
+    got, got_g = _loss_and_grads(
+        lambda q, k, v: flash_attention(q, k, v, True, None, bq, bk, True,
+                                        None, window), q, k, v, g)
+    dense = (lambda q, k, v: _ref(q, k, v, True)) if window is None else (
+        lambda q, k, v: _windowed_ref(q, k, v, window))
+    want, want_g = _loss_and_grads(dense, q, k, v, g)
+    np.testing.assert_allclose(float(got), float(want), rtol=2e-5)
+    for a, b in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("bq,bk", [
+    (256, 1024),     # the rule's, causal: a program a head, static bounds
+    (128, 1024),
+    (512, 1024),
+    (512, 512),      # the rule's where nothing is masked; as it was
+    (256, 512),      # a region narrower than its tile, then whole tiles
+    (256, 256),
+    (128, 64),       # a region wider than a tile
+], ids=lambda x: str(x))
+def test_gpt2_shape_against_dense(bq, bk):
+    """S = 1024, head size 64, causal: forward and both backward kernels
+    at the tiles the rule returns and the ones around them."""
+    _assert_close_to_dense(2, 1024, 64, bq, bk)
+
+
+@pytest.mark.parametrize("d,window", [(64, None), (128, None), (128, 2048)],
+                         ids=["granite", "trinity_full", "trinity_sliding"])
+def test_s8192_shapes_against_dense(d, window):
+    """The other cells' calls cut in batch x heads only: 8192 positions
+    in tiles of 512, sixteen programs a head with traced bounds."""
+    from byteps_tpu.models.transformer import flash_auto_tiles
+    _assert_close_to_dense(1, 8192, d, *flash_auto_tiles(8192, True),
+                           window=window)
+
+
+def _visible(s, causal, window):
+    i, j = np.arange(s)[:, None], np.arange(s)[None, :]
+    if not causal:
+        return np.ones((s, s), bool)
+    return (j <= i) if window is None else (j <= i) & (i - j < window)
+
+
+# every pair of tiles up to S = 1024; beyond, where a square of booleans
+# gets large, the pairs that differ in kind
+_SCHEDULES = [(s, bq, bk) for s in (128, 256, 384, 512, 768, 1024)
+              for bq in (128, 256, 512, 1024)
+              for bk in (64, 128, 256, 512, 1024, s)
+              if s % bq == 0 and s % bk == 0] + [
+    (s, bq, bk) for s in (2048, 4096)
+    for bq, bk in ((512, 512), (256, 512), (512, 128), (128, 64),
+                   (256, 1024))] + [(8192, 512, 512)]
+
+
+@pytest.mark.parametrize("s", sorted({s for s, _, _ in _SCHEDULES}))
+def test_bounds_visit_what_a_row_sees_and_mask_what_an_edge_crosses(s):
+    """`k_tiles` (forward, dQ) and `q_tiles` (dK/dV), the functions the
+    kernels take their bounds from: between them the region and the
+    tiles of every group cover each (query, key) pair the mask leaves,
+    once; none of them is dead; and a tile is marked for masking exactly
+    when the diagonal or the window's edge crosses it."""
+    from byteps_tpu.ops import flash_attention as fa
+    cases = ((False, None), (True, None), (True, 1), (True, 100),
+             (True, 128), (True, 300), (True, 2048), (True, 5000))
+    if s > 4096:
+        cases = ((True, None), (True, 2048))      # the cells' two masks
+    for _, bq, bk in (c for c in _SCHEDULES if c[0] == s):
+        rows_tile = fa.dkv_tile(bk)
+        for causal, window in cases:
+            vis = _visible(s, causal, window)
+            by_rows, by_keys = [], []
+            for r0 in range(0, s, bq):
+                (start, width, edge), (a, b, c) = fa.k_tiles(
+                    r0, r0 % bk, bq, bk, s // bk, causal, window)
+                by_rows += [(r0, bq, start, width, edge)]
+                by_rows += [(r0, bq, t * bk, bk, t < b) for t in range(a, c)]
+                (start, height, edge), (b, c, d) = fa.q_tiles(
+                    r0, r0 % rows_tile, bq, rows_tile, s // rows_tile,
+                    causal, window)
+                by_keys += [(start, height, r0, bq, edge)]
+                by_keys += [(t * rows_tile, rows_tile, r0, bq, t >= c)
+                            for t in range(b, d)]
+            for tiles in (by_rows, by_keys):
+                seen = np.zeros((s, s), np.int8)
+                for q0, h, k0, w, masked in tiles:
+                    tile = vis[q0:q0 + h, k0:k0 + w]
+                    assert tile.shape == (h, w)
+                    assert tile.any(), (bq, bk, window, q0, k0)
+                    assert masked == (not tile.all()), (
+                        bq, bk, causal, window, q0, k0)
+                    seen[q0:q0 + h, k0:k0 + w] += 1
+                assert seen.max() == 1 and not (vis & (seen == 0)).any(), (
+                    bq, bk, causal, window)
+            counts = fa.tile_schedule(s, bq, bk, causal, window)
+            assert counts["tiles_computed"] == len(by_rows)
+            assert counts["tiles_masked"] == sum(t[4] for t in by_rows)
+            assert counts["pairs_needed_share"] == pytest.approx(
+                vis.sum() / sum(h * w for _, h, _, w, _ in by_rows))
+
+
+def test_the_kernels_write_their_gauges_when_a_step_is_traced():
+    """GPT-2's attention call, traced and not run: a head's forward
+    kernel computes four regions (256 rows over 256, 512, 768 and 1024
+    keys), masks all four, and four fifths of their pairs are needed;
+    the tiling it replaced computed three tiles of 512 for two thirds."""
+    import byteps_tpu as bps
+    from byteps_tpu.ops.flash_attention import tile_schedule
+    q = jax.ShapeDtypeStruct((2, 4, 1024, 64), jnp.bfloat16)
+    jax.eval_shape(jax.grad(
+        lambda q, k, v: flash_attention_fn(q, k, v, True).astype(
+            jnp.float32).sum(), (0, 1, 2)), q, q, q)
+    metrics = bps.get_metrics()
+    assert metrics["bps_flash_tiles_computed"] == 4
+    assert metrics["bps_flash_tiles_masked"] == 4
+    assert metrics["bps_flash_pairs_needed_share"] == pytest.approx(
+        524800 / 655360)
+    before = tile_schedule(1024, 512, 512, True)
+    assert (before["tiles_computed"], before["tiles_masked"]) == (3, 2)
+    assert before["pairs_needed_share"] == pytest.approx(0.6673, abs=1e-4)
+    long = tile_schedule(8192, 512, 512, True)
+    assert (long["tiles_computed"], long["tiles_masked"]) == (136, 16)

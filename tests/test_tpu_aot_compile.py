@@ -60,13 +60,15 @@ def _compile(fn, *shapes):
     return jax.jit(fn).lower(*shapes).compile()
 
 
-def _flash_fwd_bwd(bh, s, d, block, streaming, device, window=None):
+def _flash_fwd_bwd(bh, s, d, block, streaming, device, window=None,
+                   block_k=None):
     x = jax.ShapeDtypeStruct((bh, s, d), jnp.bfloat16,
                              sharding=SingleDeviceSharding(device))
 
     def grads(q, k, v):
         def loss(q, k, v):
-            out = fa.flash_attention(q, k, v, True, None, block, block,
+            out = fa.flash_attention(q, k, v, True, None, block,
+                                     block_k or block,
                                      False, streaming, window)
             return jnp.sum(out.astype(jnp.float32))
         return jax.grad(loss, (0, 1, 2))(q, k, v)
@@ -83,6 +85,28 @@ def _flash_fwd_bwd(bh, s, d, block, streaming, device, window=None):
 def test_flash_fwd_bwd_compiles(v5e, bh, s, d, block, streaming):
     hlo = _flash_fwd_bwd(bh, s, d, block, streaming, v5e[0]).as_text()
     assert "tpu_custom_call" in hlo
+
+
+def test_flash_compiles_at_gpt2_shape_with_the_results_the_benchmark_reads(
+        v5e):
+    """The `gpt2-medium` cells' call: batch 32 x 16 heads, 1024 positions,
+    head size 64, at the tiles the rule gives a causal call (groups of
+    256 rows over all their keys, a program a head).  The benchmark tells
+    the three kernels by their results (`benchmark/reduce/flash_cost.py`
+    `classify`), so those keep their forms: forward `(bf16[N,S,D],
+    f32[N,1,S])`, dQ one `bf16[N,S,D]`, dK/dV two."""
+    from benchmark.reduce import flash_cost
+    from byteps_tpu.models.transformer import flash_auto_tiles
+    block, block_k = flash_auto_tiles(1024, True)
+    assert (block, block_k) == (256, 1024)
+    text = _flash_fwd_bwd(512, 1024, 64, block, None, v5e[0],
+                          block_k=block_k).as_text()
+    calls = [line for line in text.splitlines()
+             if flash_cost.is_kernel(line) and " custom-call(" in line]
+    kinds = sorted(flash_cost.classify(line) for line in calls)
+    assert kinds == [("dkv", 512, 1024, 64), ("dq", 512, 1024, 64),
+                     ("forward", 512, 1024, 64)], calls
+    assert not any("flash_" in line for line in calls)     # unnamed
 
 
 @pytest.mark.parametrize("streaming", [None, True],
@@ -165,11 +189,17 @@ def test_flash_64_row_block_is_refused_up_front(v5e):
     accepted it."""
     with pytest.raises(ValueError, match="multiple of 128"):
         _flash_fwd_bwd(16, 512, 64, 64, None, v5e[0])
-    x = jax.ShapeDtypeStruct((16, 512, 64), jnp.bfloat16,
-                             sharding=SingleDeviceSharding(v5e[0]))
+    # Around the check: where programs have traced bounds (S = 2048 is
+    # two a head) the dK/dV kernel slices the log-sum-exp's lane dim at a
+    # group's first key, and Mosaic wants that provably 128-aligned.
+    one = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((16, 2048, 64), jnp.bfloat16, sharding=one)
+    lse = jax.ShapeDtypeStruct((16, 1, 2048), jnp.float32, sharding=one)
     with pytest.raises(Exception, match="128"):
-        _compile(lambda q, k, v: fa._fwd(q, k, v, 0.125, True, 64, 64,
-                                         False, False), x, x, x)
+        _compile(lambda q, k, v, o, lse, g: fa._bwd(
+            0.125, True, 64, 64, False, False, (q, k, v, o, lse), g),
+            x, x, x, x, lse, x)
+    x = jax.ShapeDtypeStruct((16, 512, 64), jnp.bfloat16, sharding=one)
     # The K tile sits on a sublane dim: 64 rows are fine there.
     _flash_fwd_bwd(16, 512, 64, 128, None, v5e[0])
     _compile(lambda q, k, v: fa.flash_attention(q, k, v, True, None, 128,
