@@ -455,6 +455,29 @@ def record_flash_tiles(tiles_computed: int, tiles_masked: int,
                    "the computed tiles hold").set(float(pairs_needed_share))
 
 
+def record_flash_stream(steps: int, live: int, fetched: int,
+                        window: Optional[int] = None) -> None:
+    """What one head of a STREAMING flash-attention call does
+    (`flash_attention.stream_schedule`, the forward kernel's grid),
+    written when a call is traced, like `record_flash_tiles`.  One set of
+    gauges a `window` (label `window`, "none" for a call without one): the
+    last traced call of each kind, whatever the order the layers are
+    traced in."""
+    reg = get_registry()
+    labels = {"window": "none" if window is None else str(int(window))}
+    reg.gauge("bps_flash_stream_steps", labels=labels,
+              help="grid steps one head's forward kernel walks in the "
+                   "last traced streaming flash call of this window"
+              ).set(int(steps))
+    reg.gauge("bps_flash_stream_live", labels=labels,
+              help="of those, the steps that compute a tile of logits"
+              ).set(int(live))
+    reg.gauge("bps_flash_stream_fetched", labels=labels,
+              help="tiles of K (and as many of V) copied in: a step whose "
+                   "tile is the one before it copies nothing"
+              ).set(int(fetched))
+
+
 # ---------------------------------------------------------------------------
 # Hierarchical reduction (parallel/hierarchy.py; BYTEPS_TPU_HIERARCHY=1)
 # ---------------------------------------------------------------------------

@@ -127,7 +127,7 @@ class AfmoeConfig:
             capacity_factor=self.moe_capacity_factor)
 
 
-def _stack_plan(cfg: AfmoeConfig):
+def _stack_plan(cfg):
     """`[(key, kinds of one period, periods)]`: the dense layers, then the
     expert layers.  Each group's leaves are stacked on a leading layer
     axis and scanned a period at a time, the period's layers unrolled in
@@ -203,7 +203,7 @@ def init_params(rng: jax.Array, cfg: AfmoeConfig) -> PyTree:
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
-def _attn_fn(cfg: AfmoeConfig, kind: str):
+def _attn_fn(cfg, kind: str):
     """`(q, k, v) -> ctx`, all [B, H, S, Dh], causal, windowed in a sliding
     layer.  A window that reaches past the sequence is full attention."""
     window = cfg.sliding_window if kind == SLIDING else None
@@ -297,7 +297,7 @@ def _layer(x, lp, sel, cfg: AfmoeConfig, kind: str, is_moe: bool):
     return _feed_forward(_attention(x, lp, cfg, kind), lp, sel, cfg, is_moe)
 
 
-def _remat(fn, cfg: AfmoeConfig):
+def _remat(fn, cfg):
     if not cfg.remat:
         return fn
     policies = {
@@ -327,8 +327,9 @@ def _embed(params, tokens, cfg: AfmoeConfig):
     return x
 
 
-def forward_hidden(params: PyTree, tokens: jax.Array, cfg: AfmoeConfig,
-                   sel=None, with_routing: bool = False):
+def forward_hidden(params: PyTree, tokens: jax.Array, cfg,
+                   sel=None, with_routing: bool = False, layer=_layer,
+                   embed=_embed):
     """tokens [B, S] int32 (ids of the held slice) -> the final hidden
     states [B, S, D], after the last norm.
 
@@ -336,8 +337,13 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: AfmoeConfig,
     `dropless_moe.route`).  With `with_routing` the result is
     `(hidden, Routing)`, the `Routing`'s leaves stacked over the expert
     layers: the program's own choice and counters, for whoever asks; the
-    loss does not."""
-    x = _embed(params, tokens, cfg)
+    loss does not.
+
+    `layer` and `embed` are what a decoder of another family puts in
+    place of this one's (`models/mellum.py`): the period scan, the remat
+    and the held slice are the same machinery for both, and `cfg` then
+    that family's, with the fields `_stack_plan` and `_remat` read."""
+    x = embed(params, tokens, cfg)
     routings = None
     for key, kinds, periods in _stack_plan(cfg):
         is_moe = key == "moe"
@@ -353,9 +359,9 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: AfmoeConfig,
             lps = _unstack(lps, len(kinds))
             routed = []
             for i, kind in enumerate(kinds):
-                layer = _remat(functools.partial(
-                    _layer, cfg=cfg, kind=kind, is_moe=is_moe), cfg)
-                x, r = layer(x, lps[i], None if sels is None else sels[i])
+                one = _remat(functools.partial(
+                    layer, cfg=cfg, kind=kind, is_moe=is_moe), cfg)
+                x, r = one(x, lps[i], None if sels is None else sels[i])
                 routed.append(r)
             if is_moe and with_routing:
                 return x, jax.tree.map(lambda *a: jnp.stack(a), *routed)
@@ -376,11 +382,13 @@ def head_logits(x: jax.Array, head: jax.Array) -> jax.Array:
                       preferred_element_type=jnp.float32)
 
 
-def loss_fn(params: PyTree, batch, cfg: AfmoeConfig, sel=None) -> jax.Array:
+def loss_fn(params: PyTree, batch, cfg, sel=None,
+            hidden=forward_hidden) -> jax.Array:
     """Mean next-token cross-entropy over the held slice of the vocabulary.
-    batch = (tokens [B, S], targets [B, S])."""
+    batch = (tokens [B, S], targets [B, S]).  `hidden` is another
+    family's `forward_hidden`."""
     tokens, targets = batch
-    x = forward_hidden(params, tokens, cfg, sel=sel)
+    x = hidden(params, tokens, cfg, sel=sel)
     targets = targets - cfg.vocab_start
     if cfg.ce_chunk_rows:
         return fused_nll_sum(x, params["head"], targets,
@@ -389,14 +397,13 @@ def loss_fn(params: PyTree, batch, cfg: AfmoeConfig, sel=None) -> jax.Array:
     return -jnp.take_along_axis(logp, targets[..., None], axis=-1).mean()
 
 
-def routing(params: PyTree, tokens: jax.Array, cfg: AfmoeConfig):
+def routing(params: PyTree, tokens: jax.Array, cfg, hidden=forward_hidden):
     """The program's own routing on `tokens`, a `dropless_moe.Routing`
     with leaves stacked over the expert layers."""
-    return forward_hidden(params, tokens, cfg, with_routing=True)[1]
+    return hidden(params, tokens, cfg, with_routing=True)[1]
 
 
-def synthetic_batch(rng: jax.Array, batch_size: int, seq_len: int,
-                    cfg: AfmoeConfig):
+def synthetic_batch(rng: jax.Array, batch_size: int, seq_len: int, cfg):
     """Token ids uniform over the held slice of the vocabulary."""
     toks = jax.random.randint(rng, (batch_size, seq_len + 1),
                               cfg.vocab_start,

@@ -299,18 +299,28 @@ def _rms_norm(x, scale, bias, eps=1e-6):
 _NORMS = {"layernorm": _layer_norm, "rmsnorm": _rms_norm}
 
 
-def _rope(x, theta: float):
+def _rope(x, theta: float, inv_freq=None, amplitude: float = 1.0):
     """Rotary position embedding on [B, H, S, Dh] (half-split layout).
 
     The rotation runs in float32: at positions near max_seq_len, bf16
     cos/sin (~3 significant digits) visibly degrade the rotation, so cast
-    back to the compute dtype only after rotating (standard practice)."""
+    back to the compute dtype only after rotating (standard practice).
+
+    `inv_freq` [Dh / 2] puts a frequency of its own for each pair in
+    place of `theta ** (-2i / Dh)`, and `amplitude` multiplies cos and
+    sin: what scaled positions need (`models/mellum.py` `yarn_inv_freq`).
+    Left alone, the program is the one it was."""
     B, H, S, Dh = x.shape
     half = Dh // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
     angles = jnp.arange(S, dtype=jnp.float32)[:, None] * freqs[None, :]
     cos = jnp.cos(angles)                   # [S, half], f32
     sin = jnp.sin(angles)
+    if amplitude != 1.0:
+        cos, sin = cos * amplitude, sin * amplitude
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., :half], x32[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin,
@@ -352,7 +362,14 @@ def flash_auto_tiles(S: int, causal: bool = False) -> Tuple[int, int]:
     half as much again; at S = 8192, (512, 512) 4% under (256, 512), at
     head size 64 and 128 alike.  A tile's time is the vector unit's, not the
     MXU's: narrow tiles of keys pay the [rows, 1] statistics as often as
-    wide ones and lose."""
+    wide ones and lose.
+
+    The rule does not ask which path the kernel takes.  Where K and V of
+    a head pass the resident budget (S = 32,768 at head size 128: the
+    mellum cell) the same (512, 512) tiles the STREAMING kernels: a tile
+    is then a grid step, its K and V one copy of 2 x 128 KB, and a
+    windowed call's innermost grid axis is its band (3 steps under a
+    window of 1024, `flash_attention.k_band`)."""
     if S % 128:
         return 0, 0
     if causal and S <= 1024:

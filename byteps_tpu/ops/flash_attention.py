@@ -9,9 +9,9 @@ saved log-sum-exp instead of storing them.
 
 Two execution strategies, auto-selected by VMEM footprint:
 
-  - **resident** (K and V of a head within RESIDENT_VMEM_BUDGET: every
-    cell of the benchmark, GPT-2 at S=1024 with 256 KB a head, granite
-    and trinity-mini at S=8192 with 2 and 4 MiB): K and V live in VMEM
+  - **resident** (K and V of a head within RESIDENT_VMEM_BUDGET: GPT-2
+    at S=1024 with 256 KB a head, granite and trinity-mini at S=8192
+    with 2 and 4 MiB): K and V live in VMEM
     for the whole kernel and are fetched from HBM once per (batch*head).
     A program owns the whole sequence up to PROGRAM_ROWS rows, beyond
     that one tile's worth, and takes its rows in groups of `block_q`.  A group first takes its REGION in one piece, the keys
@@ -26,14 +26,24 @@ Two execution strategies, auto-selected by VMEM footprint:
     group is one region and a plain softmax, no running statistics.
     `k_tiles` / `q_tiles` give the bounds, `tile_schedule` counts what
     they visit (the `bps_flash_*` gauges, written when a call is traced).
-  - **streaming** (longer S; no cell): 3D grid with the contraction axis
-    innermost, (bh, q_blocks, k_blocks) forward/dq, (bh, k_blocks,
-    q_blocks) dk/dv, carrying running statistics in VMEM scratch across
-    the innermost iterations (the matmul k-loop pattern).  Per-program
-    VMEM is O(block * d) regardless of S, so the kernel keeps compiling
-    at 32k+ contexts, at the price of re-streaming K/V once per q block.
-    Blocks no row can see are predicated away (`pl.when`); every live
-    block of a causal call is masked.
+  - **streaming** (K and V of a head over the budget: one sequence of
+    32,768 positions at head size 128 is 16 MiB, the mellum cell): 3D
+    grid with the contraction axis innermost, (bh, q_blocks, key steps)
+    forward/dq, (bh, k_blocks, row steps) dk/dv, carrying running
+    statistics in VMEM scratch across the innermost iterations (the
+    matmul k-loop pattern).  Per-program VMEM is O(block * d) regardless
+    of S, so the kernel keeps compiling at 32k+ contexts, at the price of
+    re-streaming K/V once per q block.  A program walks its BAND of the
+    other axis and no more: `k_band` / `q_band` give the first and last
+    tile a block of rows (of keys) can see, the innermost grid axis is as
+    long as the longest band (under a window of 1024 in tiles of 512:
+    3 steps, not S / 512), a step's tile is `first + step`, and past the
+    band's end the index map repeats the last tile, so nothing is copied
+    for a step that computes nothing (`pl.when`).  A full causal call's
+    longest band is the whole axis; there the clamp alone keeps the
+    tiles above the diagonal from being fetched.  Every live block of a
+    causal call is masked.  `stream_schedule` counts the steps, the live
+    ones and the tiles copied (the `bps_flash_stream_*` gauges).
 
 What a tile costs on a v5e is the vector unit's work on its float32
 logits, not the MXU's: head size 64 and 128 take the same time a tile,
@@ -91,7 +101,10 @@ BLOCK_K_MULTIPLE = 64
 
 def check_blocks(s: int, block_q: int, block_k: int) -> None:
     """Raise ValueError unless (block_q, block_k) tile a length-`s`
-    sequence in a way the chip's compiler accepts."""
+    sequence in a way the chip's compiler accepts.  One rule for both
+    paths: a streaming call's blocks are its grid's tiles, and its band
+    walk (`k_band` / `q_band`) takes any pair this allows, rows wider
+    than keys or keys wider than rows."""
     if (block_q <= 0 or block_k <= 0 or s % block_q or s % block_k
             or block_q % BLOCK_Q_MULTIPLE or block_k % BLOCK_K_MULTIPLE):
         raise ValueError(
@@ -220,15 +233,60 @@ def _mask(s, q0, k0, window, q_axis=0):
     return jnp.where(keep, s, NEG_INF)
 
 
-def _block_live(causal, qi, kb, block_q, block_k, window=None):
-    """Whether any (row, col) in this (q block, k block) pair is visible."""
+def k_band(qi, block_q, block_k, num_tiles, causal, window=None):
+    """`(first, last)` tile of keys that the rows of block `qi` see, both
+    inclusive and every tile between them live: up to the tile of the
+    block's last row under a causal mask, from the tile of the oldest key
+    its FIRST row still sees under a `window`.  `qi` a Python int or a
+    traced index: the streaming kernels, their index maps and
+    `stream_schedule` all take their bounds from here (`q_band` is the
+    same band seen from the keys)."""
     if not causal:
-        return True
-    live = (qi + 1) * block_q - 1 >= kb * block_k
-    if window is not None:
-        # the block's first row still reaches the block's last column
-        live = live & (qi * block_q - ((kb + 1) * block_k - 1) < window)
-    return live
+        return 0, num_tiles - 1
+    last = ((qi + 1) * block_q - 1) // block_k
+    if window is None:
+        return 0, last
+    return _most(qi * block_q - window + 1, 0) // block_k, last
+
+
+def q_band(ki, block_q, block_k, num_tiles, causal, window=None):
+    """`(first, last)` tile of query rows that see the keys of block
+    `ki`: from the tile of the block's first key (the diagonal) to the
+    tile of the last row that still sees its LAST key under a `window`,
+    else to the end."""
+    if not causal:
+        return 0, num_tiles - 1
+    first = ki * block_k // block_q
+    if window is None:
+        return first, num_tiles - 1
+    return first, _least(((ki + 1) * block_k + window - 2) // block_q,
+                         num_tiles - 1)
+
+
+def _band_steps(band, programs, *args):
+    """The innermost grid axis of a streaming call: the longest band of
+    any of its `programs` blocks."""
+    return max(last - first + 1
+               for first, last in (band(i, *args) for i in range(programs)))
+
+
+def stream_schedule(s, block_q, block_k, causal, window=None):
+    """What one head of a streaming call's forward (and dQ) kernel does:
+    the grid steps it walks, how many of them compute, and the tiles of
+    K (and as many of V) copied in: a step whose tile is the one before
+    it copies nothing.  From the bounds the kernels use."""
+    nq, nk = s // block_q, s // block_k
+    steps = _band_steps(k_band, nq, block_q, block_k, nk, causal, window)
+    live = fetched = 0
+    before = None
+    for qi in range(nq):
+        first, last = k_band(qi, block_q, block_k, nk, causal, window)
+        live += last - first + 1
+        for j in range(steps):
+            tile = min(first + j, last)
+            fetched += tile != before
+            before = tile
+    return {"steps": nq * steps, "live": live, "fetched": fetched}
 
 
 def _dot_nt(a, b):
@@ -447,20 +505,35 @@ def _edge(causal, q0, k0):
     return (q0, k0) if causal else None
 
 
+def _band_maps(band, *args):
+    """The index maps of a streaming call's operands on the axis its
+    programs WALK, over a grid (bh, block, step): step `j` of block `i`
+    is tile `first + j` of the band, and past the band's end the last
+    tile again, which the pipeline does not copy twice.  `wide` for a
+    [BH, S, D] operand, `lanes` for a per-row statistic [BH, 1, S]."""
+    def tile(i, j):
+        first, last = band(i, *args)
+        return jnp.minimum(first + j, last)
+    return (lambda b, i, j: (b, tile(i, j), 0),
+            lambda b, i, j: (b, 0, tile(i, j)))
+
+
 def _fwd_kernel_str(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                    acc_scr, *, sm_scale, causal, block_q, block_k,
+                    acc_scr, *, sm_scale, causal, block_q, block_k, seq_len,
                     window=None):
     qi = pl.program_id(1)
-    kb = pl.program_id(2)
-    last_kb = pl.num_programs(2) - 1
+    step = pl.program_id(2)
+    first, last = k_band(qi, block_q, block_k, seq_len // block_k, causal,
+                         window)
+    kb = first + step
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(_block_live(causal, qi, kb, block_q, block_k, window))
+    @pl.when(kb <= last)
     def _step():
         m, l, acc = _online_step(
             _scaled(q_ref[0], sm_scale), k_ref[0], v_ref[0],
@@ -468,7 +541,7 @@ def _fwd_kernel_str(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
             _edge(causal, qi * block_q, kb * block_k), window)
         m_scr[:], l_scr[:], acc_scr[:] = m, l, acc
 
-    @pl.when(kb == last_kb)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         l = l_scr[:]
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
@@ -476,41 +549,45 @@ def _fwd_kernel_str(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
 
 
 def _dq_kernel_str(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_scr, *, sm_scale, causal, block_q, block_k,
+                   dq_scr, *, sm_scale, causal, block_q, block_k, seq_len,
                    window=None):
     qi = pl.program_id(1)
-    kb = pl.program_id(2)
-    last_kb = pl.num_programs(2) - 1
+    step = pl.program_id(2)
+    first, last = k_band(qi, block_q, block_k, seq_len // block_k, causal,
+                         window)
+    kb = first + step
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(_block_live(causal, qi, kb, block_q, block_k, window))
+    @pl.when(kb <= last)
     def _step():
         dq_scr[:] = dq_scr[:] + _dq_step(
             _scaled(q_ref[0], sm_scale), k_ref[0], v_ref[0], do_ref[0],
             lse_ref[0, 0, :][:, None], delta_ref[0, 0, :][:, None],
             _edge(causal, qi * block_q, kb * block_k), window)
 
-    @pl.when(kb == last_kb)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         dq_ref[0] = (sm_scale * dq_scr[:]).astype(dq_ref.dtype)
 
 
 def _dkv_kernel_str(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale, causal,
-                    block_q, block_k, window=None):
+                    block_q, block_k, seq_len, window=None):
     ki = pl.program_id(1)
-    qb = pl.program_id(2)
-    last_qb = pl.num_programs(2) - 1
+    step = pl.program_id(2)
+    first, last = q_band(ki, block_q, block_k, seq_len // block_q, causal,
+                         window)
+    qb = first + step
 
-    @pl.when(qb == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(_block_live(causal, qb, ki, block_q, block_k, window))
+    @pl.when(qb <= last)
     def _step():
         dk_i, dv_i = _dkv_step(
             q_ref[0], _scaled(k_ref[0], sm_scale), v_ref[0], do_ref[0],
@@ -519,7 +596,7 @@ def _dkv_kernel_str(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = dk_scr[:] + dk_i
         dv_scr[:] = dv_scr[:] + dv_i
 
-    @pl.when(qb == last_qb)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         dk_ref[0] = (sm_scale * dk_scr[:]).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -554,16 +631,15 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, streaming,
     out_shape = [jax.ShapeDtypeStruct((bh, s, d), q.dtype),
                  jax.ShapeDtypeStruct((bh, 1, s), jnp.float32)]
     if streaming:
+        band = (block_q, block_k, s // block_k, causal, window)
+        kv_spec = pl.BlockSpec((1, block_k, d), _band_maps(k_band, *band)[0])
         return pl.pallas_call(
             functools.partial(_fwd_kernel_str, sm_scale=sm_scale,
                               causal=causal, block_q=block_q,
-                              block_k=block_k, **kw),
-            grid=(bh, s // block_q, s // block_k),
-            in_specs=[
-                _q_spec(block_q, d),
-                pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            ],
+                              block_k=block_k, seq_len=s, **kw),
+            grid=(bh, s // block_q,
+                  _band_steps(k_band, s // block_q, *band)),
+            in_specs=[_q_spec(block_q, d), kv_spec, kv_spec],
             out_specs=[_q_spec(block_q, d), _lse_spec(block_q)],
             out_shape=out_shape,
             scratch_shapes=[
@@ -597,16 +673,16 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, streaming,
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None, :]                 # (bh, 1, s)
     if streaming:
+        band = (block_q, block_k, s // block_k, causal, window)
+        kv_spec = pl.BlockSpec((1, block_k, d), _band_maps(k_band, *band)[0])
         dq = pl.pallas_call(
             functools.partial(_dq_kernel_str, sm_scale=sm_scale,
                               causal=causal, block_q=block_q,
-                              block_k=block_k, **kw),
-            grid=(bh, s // block_q, s // block_k),
+                              block_k=block_k, seq_len=s, **kw),
+            grid=(bh, s // block_q,
+                  _band_steps(k_band, s // block_q, *band)),
             in_specs=[
-                _q_spec(block_q, d),
-                pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-                _q_spec(block_q, d),
+                _q_spec(block_q, d), kv_spec, kv_spec, _q_spec(block_q, d),
                 _lse_spec(block_q), _lse_spec(block_q),
             ],
             out_specs=_q_spec(block_q, d),
@@ -614,14 +690,17 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, streaming,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
             interpret=interpret, **dq_named,
         )(q, k, v, do, lse, delta)
+        band = (block_q, block_k, s // block_q, causal, window)
+        wide, lanes = _band_maps(q_band, *band)
         kb_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
-        qs_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, j, 0))
-        ls_spec = pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, j))
+        qs_spec = pl.BlockSpec((1, block_q, d), wide)
+        ls_spec = pl.BlockSpec((1, 1, block_q), lanes)
         dk, dv = pl.pallas_call(
             functools.partial(_dkv_kernel_str, sm_scale=sm_scale,
                               causal=causal, block_q=block_q,
-                              block_k=block_k, **kw),
-            grid=(bh, s // block_k, s // block_q),
+                              block_k=block_k, seq_len=s, **kw),
+            grid=(bh, s // block_k,
+                  _band_steps(q_band, s // block_k, *band)),
             in_specs=[qs_spec, kb_spec, kb_spec, qs_spec, ls_spec, ls_spec],
             out_specs=[kb_spec, kb_spec],
             out_shape=[jax.ShapeDtypeStruct((bh, s, d), k.dtype),
@@ -679,7 +758,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     `window` (causal only) is a sliding window: row i attends to the keys
     i - window < j <= i, and the blocks no row of a tile can see are
-    skipped in all three kernels, as the blocks above the diagonal are.
+    skipped in all three kernels, as the blocks above the diagonal are
+    (resident: never looped over; streaming: not in the grid).
     `window=None` leaves the calls unnamed.
 
     sm_scale defaults to 1/sqrt(D).  interpret=None auto-selects the
@@ -702,7 +782,11 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                          f"one key a row")
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     streaming = _use_streaming(q, streaming)
-    if not streaming:
+    if streaming:
+        telemetry.record_flash_stream(
+            **stream_schedule(s, block_q, block_k, causal, window),
+            window=window)
+    else:
         telemetry.record_flash_tiles(
             **tile_schedule(s, block_q, block_k, causal, window))
     out, lse = _fwd(q, k, v, scale, causal, block_q, block_k,

@@ -37,6 +37,20 @@ The router's scores, top-k and weights are float32 (the score matmul at
 `highest` precision: on a TPU a float32 matmul is otherwise one bfloat16
 pass, and top-k is discontinuous in the scores).  `expert_bias` is the
 load balancer's buffer, added to the scores for the choice alone.
+
+A share's backward pass (`MoEConfig.hold_held_weight`, off unless a model
+asks).  A share adds only the held experts' results, so its loss falls
+whenever a token's weight moves from an absent expert to a held one:
+every gradient says "send the held experts more", and a share that trains
+alone sends them most of every token's choices within a few steps, which
+no deployment does (there the absent experts' results come back from
+their chips and compete).  With the option the weight a token gives the
+held experts together, `W = sum over held of w`, is a CONSTANT of the
+backward pass: the weights are used as `w * stop_gradient(W) / W`, the
+same numbers, whose gradient is what the held experts' competition among
+themselves gives.  Under weights that sum to 1 that is the gradient the
+layer would have if each absent expert returned the weighted mean of the
+held ones the token chose: the absent experts' logits get none.
 """
 
 from __future__ import annotations
@@ -61,6 +75,7 @@ class MoEConfig:
     score_func: str = "sigmoid"      # "sigmoid" | "softmax"
     capacity_factor: float = 1.25    # the buffer over the even share
     row_multiple: int = 512          # the buffer is a multiple of this
+    hold_held_weight: bool = False   # a share's backward pass (above)
 
     def __post_init__(self):
         if self.score_func not in ("sigmoid", "softmax"):
@@ -118,7 +133,21 @@ def route(x, router_w, cfg: MoEConfig, expert_bias=None, sel=None):
     weights = jnp.take_along_axis(scores, sel, axis=-1)
     if cfg.route_norm:
         weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    if cfg.hold_held_weight and len(cfg.held) < cfg.num_experts:
+        weights = _held_weight_held(weights, sel, cfg)
     return sel, weights * cfg.route_scale
+
+
+def _held_weight_held(weights, sel, cfg: MoEConfig):
+    """`weights` [T, k], bit for bit, with the gradient of
+    `weights * stop_gradient(W) / W`, W the token's weight on the held
+    experts (1 where it chose none of them)."""
+    here = jnp.isin(sel, jnp.asarray(cfg.held, sel.dtype))
+    held = jnp.where(here, weights, 0.0).sum(-1, keepdims=True)
+    scaled = weights * jnp.where(
+        held > 0, lax.stop_gradient(held) / jnp.where(held > 0, held, 1.0),
+        1.0)
+    return lax.stop_gradient(weights) + (scaled - lax.stop_gradient(scaled))
 
 
 def _swiglu_grouped(xg, experts, group_sizes, dtype):

@@ -477,3 +477,106 @@ def test_the_kernels_write_their_gauges_when_a_step_is_traced():
     assert before["pairs_needed_share"] == pytest.approx(0.6673, abs=1e-4)
     long = tile_schedule(8192, 512, 512, True)
     assert (long["tiles_computed"], long["tiles_masked"]) == (136, 16)
+
+
+# ---------------------------------------------------------------------------
+# The streaming path's band walk
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("s,window,bq,bk", [
+    (1024, 256, 128, 128),    # a band of 3 steps in a grid of 8
+    (1024, 256, 256, 128),    # rows wider than keys
+    (1024, 300, 128, 256),    # keys wider than rows, an uneven window
+    (1024, None, 128, 128),   # causal alone: the clamped walk
+    (1024, None, 128, 256),
+    (512, 2000, 128, 128),    # the window passes the sequence
+], ids=lambda x: str(x))
+def test_streaming_band_walk_against_dense(s, window, bq, bk):
+    """The streaming kernels walk a band of the other axis, tile
+    `first + step` at every step and the last tile again past the band's
+    end: forward and all three gradients against dense attention."""
+    rng = np.random.RandomState(3)
+    q, k, v, g = (_rand(rng, 2, s, 64) for _ in range(4))
+
+    def flash(q, k, v):
+        return (flash_attention(q, k, v, True, None, bq, bk, True, True,
+                                window) * g).sum()
+
+    def dense(q, k, v):
+        if window is None:
+            return (_ref(q, k, v, True) * g).sum()
+        return (_windowed_ref(q, k, v, window) * g).sum()
+
+    got, got_g = jax.value_and_grad(flash, (0, 1, 2))(q, k, v)
+    want, want_g = jax.value_and_grad(dense, (0, 1, 2))(q, k, v)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    for a, b in zip(got_g, want_g):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=1e-4)
+    # the innermost grid axis is the longest band, not the whole axis
+    from byteps_tpu.ops.flash_attention import (_band_steps, k_band,
+                                                q_band)
+    w = None if window is None else min(window, s)
+    fwd = _band_steps(k_band, s // bq, bq, bk, s // bk, True, window)
+    dkv = _band_steps(q_band, s // bk, bq, bk, s // bq, True, window)
+    if w is None or w >= s:
+        assert (fwd, dkv) == (s // bk, s // bq)
+    else:
+        assert fwd <= (bq + w - 2) // bk + 2 < s // bk
+        assert dkv <= (bk + w - 2) // bq + 2 < s // bq
+
+
+@pytest.mark.parametrize("s,bq,bk,window,want", [
+    # the mellum cell's calls: 64 row tiles; a band of 3 tiles of keys
+    (32768, 512, 512, 1024, dict(steps=192, live=189, fetched=188)),
+    # causal alone: the square walked, the triangle computed and fetched
+    (32768, 512, 512, None, dict(steps=4096, live=2080, fetched=2079)),
+    (1024, 128, 128, 256, dict(steps=24, live=21, fetched=20)),
+    (1024, 128, 128, None, dict(steps=64, live=36, fetched=35)),
+], ids=lambda x: str(x) if not isinstance(x, dict) else "")
+def test_stream_schedule_counts_and_gauges(s, bq, bk, window, want):
+    """`stream_schedule` for known shapes, by hand: a row tile's band is
+    the tiles from its first row's oldest key to its last row's own; a
+    step past the band repeats the last tile and copies nothing, and the
+    first tile of a row tile is copied unless the row tile before ended
+    on it.  The gauges carry the last traced streaming call of each
+    window, under the label `window`."""
+    import byteps_tpu as bps
+    from byteps_tpu.ops.flash_attention import k_band, stream_schedule
+    assert stream_schedule(s, bq, bk, True, window) == want
+    nq, nk = s // bq, s // bk
+    live = 0
+    for qi in range(nq):
+        first, last = k_band(qi, bq, bk, nk, True, window)
+        tiles = [t for t in range(nk)
+                 if t * bk <= (qi + 1) * bq - 1
+                 and (window is None
+                      or (t + 1) * bk - 1 > qi * bq - window)]
+        assert tiles == list(range(first, last + 1))
+        live += len(tiles)
+    assert live == want["live"]
+    if s <= 1024:
+        q = jnp.zeros((1, s, 64), jnp.float32)
+        jax.make_jaxpr(lambda q: flash_attention(
+            q, q, q, True, None, bq, bk, True, True, window))(q)
+        metrics = bps.get_metrics()
+        label = '{window="%s"}' % ("none" if window is None else window)
+        assert metrics["bps_flash_stream_steps" + label] == want["steps"]
+        assert metrics["bps_flash_stream_live" + label] == want["live"]
+        assert metrics["bps_flash_stream_fetched" + label] == want["fetched"]
+
+
+def test_streaming_index_maps_repeat_the_last_tile_past_the_band():
+    """What keeps a dead step from copying K and V: past the band's end
+    the index map gives the band's last tile again."""
+    from byteps_tpu.ops.flash_attention import _band_maps, k_band, q_band
+    args = (128, 128, 8, True, 256)
+    wide, lanes = _band_maps(k_band, *args)
+    # row tile 5 sees the keys 385..767: tiles 3, 4, 5
+    assert [int(wide(0, 5, j)[1]) for j in range(4)] == [3, 4, 5, 5]
+    assert [int(wide(0, 0, j)[1]) for j in range(3)] == [0, 0, 0]
+    assert [int(lanes(7, 5, j)[2]) for j in range(3)] == [3, 4, 5]
+    wide, _ = _band_maps(q_band, *args)
+    # key tile 6 (keys 768..895) is seen by the rows 768..1023 of 1024
+    assert [int(wide(0, 6, j)[1]) for j in range(3)] == [6, 7, 7]
+    causal, _ = _band_maps(k_band, 128, 128, 8, True, None)
+    assert [int(causal(0, 2, j)[1]) for j in range(8)] == [0, 1, 2] + [2] * 5

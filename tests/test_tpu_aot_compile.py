@@ -121,6 +121,30 @@ def test_windowed_flash_compiles_at_trinity_shapes(v5e, streaming):
                for kind in ("fwd", "dq", "dkv"))
 
 
+@pytest.mark.parametrize("window", [1024, None], ids=["w1024", "full"])
+def test_streaming_flash_compiles_at_mellum_shapes(v5e, window):
+    """32 heads of 128 over ONE sequence of 32,768: K and V of a head are
+    16 MiB, past the resident budget, so the rule picks the streaming
+    kernels by itself; the band walk's index maps (a floor division and a
+    minimum of traced indices) are what the chip's compiler has to take.
+    The three results keep the forms the benchmark tells them by."""
+    from benchmark.reduce import afmoe_cost
+    from byteps_tpu.models.transformer import flash_auto_tiles
+    assert flash_auto_tiles(32768, True) == (512, 512)
+    q = jnp.zeros((32, 32768, 128), jnp.bfloat16)
+    assert fa._use_streaming(q, None)
+    text = _flash_fwd_bwd(32, 32768, 128, 512, None, v5e[0],
+                          window=window).as_text()
+    calls = [afmoe_cost.attention_call(line) for line in text.splitlines()
+             if " custom-call(" in line]
+    assert sorted(c[:4] for c in calls if c) == sorted(
+        (kind, 32, 32768, 128) for kind in ("dkv", "dq", "forward"))
+    # a bare `jax.grad` puts `jvp_` before a call's name, which a train
+    # step's remat does not: the window is read from the text here
+    assert all((f"flash_{kind}_w1024" in text) == (window is not None)
+               for kind in ("fwd", "dq", "dkv"))
+
+
 def test_dropless_expert_layer_compiles_at_trinity_widths(v5e):
     """16 of 128 experts, 8 a token, hidden 2048, expert width 1024, a
     sequence of 8192 tokens: forward alone (which once broke the

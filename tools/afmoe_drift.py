@@ -1,4 +1,5 @@
-"""How the afmoe cell's routing moves while the cell trains, step by step:
+"""How an expert decoder's cell (afmoe, mellum) moves its routing while it
+trains, step by step:
 
     python3 tools/afmoe_drift.py --workload <cell> --seeds 11 12 \
         [--inits 0.1 0.05] [--steps 32] [--every 3] [--tiny]
@@ -10,11 +11,15 @@ One JSON line a (seed, init): each step's milliseconds and loss (each step
 waited for, so a little over the timed loop's), and every `--every` steps
 the program's own counters of the step's batch under the weights of that
 moment.  `--inits` starts `post_attn_ln` elsewhere than the configuration
-does; only weights differ, so one compiled step serves them all.
+does (afmoe's `post_attn_norm_init`; the mellum cell pins no such option);
+only weights differ, so one compiled step serves them all.  This is how a
+cell's `moe_capacity_factor` is chosen: over what `held_rows_per_token`
+reaches inside a window.
 """
 
 import argparse
 import copy
+import importlib
 import json
 import os
 import sys
@@ -40,25 +45,31 @@ def main(argv=None) -> int:
     from jax.sharding import NamedSharding, PartitionSpec
 
     import byteps_tpu as bps
-    from benchmark.families import afmoe as families_afmoe
     from benchmark.harness import manifest, seeded
-    from byteps_tpu.models import afmoe
     from byteps_tpu.parallel import dropless_moe
     from byteps_tpu.utils import compile_cache
     compile_cache.enable()
     if args.tiny:
-        from benchmark.tests import tiny, tiny_afmoe  # noqa: F401
+        from benchmark.tests import (tiny, tiny_afmoe,  # noqa: F401
+                                     tiny_mellum)
         cell = tiny.tiny_cell(args.workload)
     else:
         cell = manifest.load_cell(args.workload)
+    name = cell.config["family"]
+    families = importlib.import_module(f"benchmark.families.{name}")
+    model = importlib.import_module(f"byteps_tpu.models.{name}")
     pinned = cell.config["program_options"]["pinned"]
+    option = "post_attn_norm_init"
+    if args.inits and option not in pinned:
+        ap.error(f"the {name} cell pins no {option}")
 
     def family_at(init):
         config = copy.deepcopy(cell.config)
-        config["program_options"]["pinned"]["post_attn_norm_init"] = init
-        return families_afmoe.Family(config, cell.job)
+        if init is not None:
+            config["program_options"]["pinned"][option] = init
+        return families.Family(config, cell.job)
 
-    family = family_at(pinned["post_attn_norm_init"])
+    family = family_at(pinned.get(option))
     bps.init()
     mesh = bps.make_mesh(devices=jax.devices()[:1])
     opt = bps.DistributedOptimizer(family.optimizer())
@@ -68,19 +79,19 @@ def main(argv=None) -> int:
 
     @jax.jit
     def counters(params, tokens):
-        routing = afmoe.routing(params, tokens, family.cfg)
+        routing = model.routing(params, tokens, family.cfg)
         return jax.vmap(
             lambda r: dropless_moe.counters(r, tokens.size))(routing)
 
     out = open(args.out, "a") if args.out else None
-    for init in args.inits or [pinned["post_attn_norm_init"]]:
+    for init in args.inits or [pinned.get(option)]:
         for seed in args.seeds:
             params = seeded.params(family_at(init), seed)
             opt_state = opt_init(params)
             batch = jax.device_put(
                 seeded.batch(family, seed, n),
                 NamedSharding(mesh, PartitionSpec("dp")))
-            line = {"seed": seed, "post_attn_norm_init": init,
+            line = {"seed": seed, option: init,
                     "device": jax.devices()[0].device_kind,
                     "step_ms": [], "loss": [], "counters": {}}
             for i in range(args.steps):

@@ -20,7 +20,7 @@ older notes name.)
     python3 tools/reference_check.py --workload <cell> --record <out.pb>
 
 records instead the small trace that the family's readers are tested on
-(`RECORD` below; the granitehybrid cell alone has one).
+(`RECORD` below: the granitehybrid and mellum cells).
 """
 
 import argparse
@@ -36,22 +36,24 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
-def _afmoe_extras(family, cell, params, seed, variant):
+def _expert_extras(family, cell, params, seed, variant):
     """What the reference saw of the program's choice of experts, the
     program's own routing counters and, for the program as it is, the
     counters of the step's own batch, all its sequences routed together
-    as the timed step routes them."""
+    as the timed step routes them.  For the families of expert decoders,
+    whose model module has the family's name."""
     import jax
 
     from benchmark.harness import seeded
-    from byteps_tpu.models import afmoe
     from byteps_tpu.parallel import dropless_moe
+    model = importlib.import_module(
+        f"byteps_tpu.models.{cell.config['family']}")
     out = {"selection": list(family.selection),
            "routing_counters": list(family.routing_counters)}
     del family.selection[:], family.routing_counters[:]
     if variant is None:
         tokens = seeded.batch(family, seed, cell.job["per_chip_batch"])[0]
-        routing = jax.jit(lambda p, t: afmoe.routing(p, t, family.cfg))(
+        routing = jax.jit(lambda p, t: model.routing(p, t, family.cfg))(
             params, tokens)
         out["step_counters"] = jax.tree.map(
             lambda a: [float(x) for x in a],
@@ -95,8 +97,42 @@ def _granitehybrid_record(cell, out: str) -> int:
     return 0 if line["correct"] else 1
 
 
-EXTRAS = {"afmoe": _afmoe_extras, "granitehybrid": _granitehybrid_extras}
-RECORD = {"granitehybrid": _granitehybrid_record}
+def _mellum_record(cell, out: str) -> int:
+    """`benchmark/tests/data/tiny_mellum.xplane.pb`: the cell at tiny
+    widths but with heads of 128 (the chip's lane width, as the streaming
+    kernels' tiles need), two layers (sliding, full), one sequence of
+    1,024 positions under a window of 256 in tiles of 128, with the
+    streaming kernels asked for by hand (a head's K and V are far inside
+    the resident budget at this length), five traced steps through the
+    in-graph job."""
+    from unittest import mock
+
+    import jax
+
+    from benchmark.harness import chip, measure
+    from benchmark.reduce import xplane
+    from benchmark.tests import tiny_mellum
+    from byteps_tpu.ops import flash_attention
+    config = tiny_mellum.config(layers=[2, 3])
+    config["published"].update(head_dim=128, sliding_window=256)
+    config["job"].update(per_chip_batch=1, seq_len=1024)
+    config["program_options"]["left_at_rule"].update(attn_block=128,
+                                                     attn_block_k=128)
+    cell = dataclasses.replace(cell, config=config,
+                               job={**cell.job, **config["job"]})
+    with mock.patch.object(flash_attention, "_use_streaming",
+                           lambda q, streaming: True):
+        line, _ = measure.run_cell(
+            cell, seed=3, seconds=1.0, trace=True, devices=jax.devices()[:1],
+            peaks=chip.require(jax.devices(), 1), t_start=0.0)
+    print(line)
+    shutil.copy(xplane.find(os.path.join(measure.TRACE_ROOT, cell.name)), out)
+    return 0 if line["correct"] else 1
+
+
+EXTRAS = {"afmoe": _expert_extras, "mellum": _expert_extras,
+          "granitehybrid": _granitehybrid_extras}
+RECORD = {"granitehybrid": _granitehybrid_record, "mellum": _mellum_record}
 
 
 def main(argv=None) -> int:
