@@ -367,9 +367,11 @@ def flash_auto_tiles(S: int, causal: bool = False) -> Tuple[int, int]:
     The rule does not ask which path the kernel takes.  Where K and V of
     a head pass the resident budget (S = 32,768 at head size 128: the
     mellum cell) the same (512, 512) tiles the STREAMING kernels: a tile
-    is then a grid step, its K and V one copy of 2 x 128 KB, and a
-    windowed call's innermost grid axis is its band (3 steps under a
-    window of 1024, `flash_attention.k_band`)."""
+    is then a grid step, its K and V one copy of 2 x 128 KB, and a tile
+    no row can see is no step at all (`flash_attention.stream_walk`: a
+    causal call's grid is the table of its live tiles, 2,080 a head at
+    S = 32,768; a windowed call's its blocks' bands, 3 steps each under
+    a window of 1024)."""
     if S % 128:
         return 0, 0
     if causal and S <= 1024:

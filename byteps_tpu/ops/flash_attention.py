@@ -27,23 +27,39 @@ Two execution strategies, auto-selected by VMEM footprint:
     `k_tiles` / `q_tiles` give the bounds, `tile_schedule` counts what
     they visit (the `bps_flash_*` gauges, written when a call is traced).
   - **streaming** (K and V of a head over the budget: one sequence of
-    32,768 positions at head size 128 is 16 MiB, the mellum cell): 3D
-    grid with the contraction axis innermost, (bh, q_blocks, key steps)
-    forward/dq, (bh, k_blocks, row steps) dk/dv, carrying running
-    statistics in VMEM scratch across the innermost iterations (the
-    matmul k-loop pattern).  Per-program VMEM is O(block * d) regardless
-    of S, so the kernel keeps compiling at 32k+ contexts, at the price of
-    re-streaming K/V once per q block.  A program walks its BAND of the
-    other axis and no more: `k_band` / `q_band` give the first and last
-    tile a block of rows (of keys) can see, the innermost grid axis is as
-    long as the longest band (under a window of 1024 in tiles of 512:
-    3 steps, not S / 512), a step's tile is `first + step`, and past the
-    band's end the index map repeats the last tile, so nothing is copied
-    for a step that computes nothing (`pl.when`).  A full causal call's
-    longest band is the whole axis; there the clamp alone keeps the
-    tiles above the diagonal from being fetched.  Every live block of a
-    causal call is masked.  `stream_schedule` counts the steps, the live
-    ones and the tiles copied (the `bps_flash_stream_*` gauges).
+    32,768 positions at head size 128 is 16 MiB, the mellum cell): a
+    grid step is one tile of K and V against one block of rows (dK/dV:
+    of Q and dO against a block of keys), and the carry lives in VMEM
+    scratch across a block's steps (the matmul k-loop pattern).
+    `stream_walk` gives a call its grid, one of two.  Where the blocks'
+    bands differ in length (a causal call: 1 to 64 tiles) a step is one
+    ENTRY OF A TABLE, on a grid (bh, entries): the table (`stream_table`,
+    built in Python from `k_band` / `q_band`) lists the live tiles of a
+    head's square and no other, in the order the kernel visits them,
+    forward and dQ row block by row block, each over its band of key
+    tiles first to last, dK/dV key block by key block over its band of
+    row tiles; an entry holds the block the program owns, the tile it
+    walks, and whether it is the FIRST and the LAST of its block.  The
+    table's columns are scalar-prefetch operands: the index maps read
+    them and so does the kernel.  Where the bands are all about as long
+    (a window: 3 tiles; no mask: the axis) a step is step `j` of block
+    `i`'s band, on a grid (bh, blocks, longest band): the tile is
+    `first + j`, computed and not looked up, and past the band's end the
+    last tile again, which is not copied twice and computes nothing.
+    Either way steps of one block keep the block's Q / O / dQ (dK/dV: K
+    / V / dK / dV), so nothing of those is copied or written until the
+    block's last step; the kernel starts its carry at a block's first
+    step and writes the result at its last.  The forward kernel's running
+    maximum and sum are kept in scratch REPLICATED along the lanes,
+    [block_q, 128], because a [block_q, 1] column's way out of scratch
+    and back cost more than the tile's arithmetic (`_spread`).  Every
+    tile of a causal call is masked (`_edge`).  Per-program VMEM is
+    O(block * d) regardless of S, so the kernel keeps compiling at 32k+
+    contexts, at the price of re-streaming K/V once per q block.
+    `stream_schedule` counts the steps, the live ones and the tiles
+    copied (the `bps_flash_stream_*` gauges): a causal call 2,080 steps,
+    all live, where the square has 4,096; a window of 1024 192 steps for
+    189 tiles.
 
 What a tile costs on a v5e is the vector unit's work on its float32
 logits, not the MXU's: head size 64 and 128 take the same time a tile,
@@ -71,7 +87,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -102,9 +118,9 @@ BLOCK_K_MULTIPLE = 64
 def check_blocks(s: int, block_q: int, block_k: int) -> None:
     """Raise ValueError unless (block_q, block_k) tile a length-`s`
     sequence in a way the chip's compiler accepts.  One rule for both
-    paths: a streaming call's blocks are its grid's tiles, and its band
-    walk (`k_band` / `q_band`) takes any pair this allows, rows wider
-    than keys or keys wider than rows."""
+    paths: a streaming call's blocks are its grid's tiles, and its walk
+    (`stream_walk`, from `k_band` / `q_band`) takes any pair this
+    allows, rows wider than keys or keys wider than rows."""
     if (block_q <= 0 or block_k <= 0 or s % block_q or s % block_k
             or block_q % BLOCK_Q_MULTIPLE or block_k % BLOCK_K_MULTIPLE):
         raise ValueError(
@@ -238,9 +254,9 @@ def k_band(qi, block_q, block_k, num_tiles, causal, window=None):
     inclusive and every tile between them live: up to the tile of the
     block's last row under a causal mask, from the tile of the oldest key
     its FIRST row still sees under a `window`.  `qi` a Python int or a
-    traced index: the streaming kernels, their index maps and
-    `stream_schedule` all take their bounds from here (`q_band` is the
-    same band seen from the keys)."""
+    traced index: the streaming kernels' table (`stream_table`), their
+    band grid's index maps and `stream_schedule` all take their bounds
+    from here (`q_band` is the same band seen from the keys)."""
     if not causal:
         return 0, num_tiles - 1
     last = ((qi + 1) * block_q - 1) // block_k
@@ -263,30 +279,84 @@ def q_band(ki, block_q, block_k, num_tiles, causal, window=None):
                          num_tiles - 1)
 
 
-def _band_steps(band, programs, *args):
-    """The innermost grid axis of a streaming call: the longest band of
-    any of its `programs` blocks."""
-    return max(last - first + 1
-               for first, last in (band(i, *args) for i in range(programs)))
+def _bands(s, block_q, block_k, causal, window, by_keys):
+    """`(blocks, band)` of a streaming kernel: the blocks its programs
+    own, of rows (`by_keys`, dK/dV: of keys), and `band(i)`, the `(first,
+    last)` tile of the other axis that block `i` walks."""
+    nq, nk = s // block_q, s // block_k
+    band, blocks, tiles = (q_band, nk, nq) if by_keys else (k_band, nq, nk)
+    return blocks, lambda i: band(i, block_q, block_k, tiles, causal, window)
+
+
+# What the table says of an entry besides its block and its tile.
+FIRST, LAST = 1, 2
+
+
+def stream_table(s, block_q, block_k, causal, window=None, by_keys=False):
+    """A streaming call's grid, one head of it: `(block, tile, flags)`,
+    three equally long tuples with an entry for every LIVE tile and no
+    other, in the order the kernel visits them.  Forward and dQ: row
+    block by row block, each over its band of key tiles (`k_band`) first
+    to last; `by_keys` (dK/dV): key block by key block, each over its
+    band of row tiles (`q_band`).  `block` is the block of the axis the
+    program owns, `tile` the tile of the axis it walks, `flags` FIRST and
+    LAST on the ends of a block's run (a block of one tile carries
+    both)."""
+    blocks, band = _bands(s, block_q, block_k, causal, window, by_keys)
+    block, tile, flags = [], [], []
+    for i in range(blocks):
+        first, last = band(i)
+        for j in range(first, last + 1):
+            block.append(i)
+            tile.append(j)
+            flags.append(FIRST * (j == first) | LAST * (j == last))
+    return tuple(block), tuple(tile), tuple(flags)
+
+
+# A call whose bands are all about as long walks them on a grid (bh, block,
+# step) and asks no table: up to this many grid steps for each live tile.
+# From the chip (PR 37, docs/performance.md "The streaming path"): an entry
+# read from the table costs a step 0.05-0.07 us, a dead step 0.08-0.11; at
+# the mellum cell's window of 1024 (192 steps for 189 tiles) the band grid
+# is 3% faster, at its causal call (4,096 for 2,080) the table 7%.
+BAND_GRID_SLACK = 1.25
+
+
+class Walk(NamedTuple):
+    """How a streaming call's grid visits its live tiles."""
+    table: tuple                # `stream_table`'s three columns
+    band: Optional[Callable]    # block -> (first, last) tile, on a band grid
+    grid: tuple                 # the grid's axes after bh
+
+
+def stream_walk(s, block_q, block_k, causal, window=None, by_keys=False):
+    """The `Walk` of a streaming call (`by_keys`: of its dK/dV kernel).
+    Either the grid is (bh, entry of the table), every step a live tile;
+    or, where blocks x the longest band is within BAND_GRID_SLACK of the
+    live tiles (a window; no mask at all), it is (bh, block, step of the
+    longest band): step `j` of block `i` is tile `first + j` of its band,
+    computed and not looked up, and past the band's end the last tile
+    again, which is not copied twice and computes nothing."""
+    table = stream_table(s, block_q, block_k, causal, window, by_keys)
+    blocks, band = _bands(s, block_q, block_k, causal, window, by_keys)
+    longest = max(last - first + 1
+                  for first, last in map(band, range(blocks)))
+    if blocks * longest > BAND_GRID_SLACK * len(table[0]):
+        return Walk(table, None, (len(table[0]),))
+    return Walk(table, band, (blocks, longest))
 
 
 def stream_schedule(s, block_q, block_k, causal, window=None):
-    """What one head of a streaming call's forward (and dQ) kernel does:
-    the grid steps it walks, how many of them compute, and the tiles of
-    K (and as many of V) copied in: a step whose tile is the one before
-    it copies nothing.  From the bounds the kernels use."""
-    nq, nk = s // block_q, s // block_k
-    steps = _band_steps(k_band, nq, block_q, block_k, nk, causal, window)
-    live = fetched = 0
-    before = None
-    for qi in range(nq):
-        first, last = k_band(qi, block_q, block_k, nk, causal, window)
-        live += last - first + 1
-        for j in range(steps):
-            tile = min(first + j, last)
-            fetched += tile != before
-            before = tile
-    return {"steps": nq * steps, "live": live, "fetched": fetched}
+    """What one head of a streaming call's forward (and dQ) kernel does,
+    counted from the walk it really takes: the grid steps, how many of
+    them compute (the table's entries: all, on a table grid), and the
+    tiles of K (and as many of V) copied in: a step whose tile is the
+    one before it copies nothing, on either grid."""
+    walk = stream_walk(s, block_q, block_k, causal, window)
+    tile = walk.table[1]
+    fetched = sum(a != b for a, b in zip(tile, (None,) + tile))
+    return {"steps": math.prod(walk.grid), "live": len(tile),
+            "fetched": fetched}
 
 
 def _dot_nt(a, b):
@@ -322,11 +392,25 @@ def _to_lanes(col):
             for r in range(0, col.shape[0], 128)]
 
 
+def _spread(x, width):
+    """A per-row statistic as wide as what it meets, [rows, width].  A
+    column [rows, 1] (the resident path's, a value a loop carries) is
+    left to broadcast.  One kept REPLICATED along the 128 lanes, [rows,
+    128] (the streaming path's, which lives in scratch between grid
+    steps), is the same vregs over again."""
+    if x.shape[1] == 1:
+        return x
+    reps = -(-width // x.shape[1])
+    wide = jnp.tile(x, (1, reps)) if reps > 1 else x
+    return wide if wide.shape[1] == width else wide[:, :width]
+
+
 def _online_step(q_scaled, k, v, carry, edge, window=None):
     """One online-softmax accumulation step shared by both forward paths;
     `carry` None starts one.  `edge` is None for a tile every row sees
     whole, else the tile's `(first query, first key)`, and the tile is
-    masked."""
+    masked.  The carry's `m` and `l` are columns or replicated along
+    lanes (`_spread`), and come back as they came."""
     s = _dot_nt(q_scaled, k.astype(jnp.float32))          # (bq, bk)
     if edge is not None:
         s = _mask(s, *edge, window)
@@ -338,7 +422,7 @@ def _online_step(q_scaled, k, v, carry, edge, window=None):
     m_new = jnp.maximum(m, m_new)
     if edge is None or window is None:
         alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s - _spread(m_new, s.shape[1]))
     else:
         # Under a window a row can meet a live block of which it sees
         # nothing before it has seen any key: its running max is still
@@ -347,9 +431,9 @@ def _online_step(q_scaled, k, v, carry, edge, window=None):
         # key.  Causal alone never does: every row sees key 0.)
         m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
         alpha = jnp.exp(m - m_safe)
-        p = jnp.exp(s - m_safe)
+        p = jnp.exp(s - _spread(m_safe, s.shape[1]))
     l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    return m_new, l_new, acc * alpha + _dot_f32(p, v)
+    return m_new, l_new, acc * _spread(alpha, acc.shape[1]) + _dot_f32(p, v)
 
 
 def _dq_step(q_scaled, k, v, do, lse, delta, edge, window=None):
@@ -496,98 +580,110 @@ def _dkv_kernel_res(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 # ---------------------------------------------------------------------------
-# Streaming path: 3D grid, contraction axis innermost, scratch carries.
+# Streaming path: grid (bh, entry of the table), scratch carries a block.
 # ---------------------------------------------------------------------------
 def _edge(causal, q0, k0):
-    """A streaming program's tile is one of a traced grid's: which side
-    of the diagonal it lies on is not known in Python, so every tile of a
-    causal call is masked."""
+    """Every tile of a causal streaming call is masked, the whole ones
+    too.  A step that asked the table whether an edge crosses its tile
+    and took one of two `pl.when` branches cost a causal forward call
+    0.05 us a tile more than it saved (PERF.md section 6, PR 37: a mask
+    costs nothing measurable, a branch around the step does)."""
     return (q0, k0) if causal else None
 
 
-def _band_maps(band, *args):
-    """The index maps of a streaming call's operands on the axis its
-    programs WALK, over a grid (bh, block, step): step `j` of block `i`
-    is tile `first + j` of the band, and past the band's end the last
-    tile again, which the pipeline does not copy twice.  `wide` for a
-    [BH, S, D] operand, `lanes` for a per-row statistic [BH, 1, S]."""
-    def tile(i, j):
-        first, last = band(i, *args)
-        return jnp.minimum(first + j, last)
-    return (lambda b, i, j: (b, tile(i, j), 0),
-            lambda b, i, j: (b, 0, tile(i, j)))
+def _entry(table, band):
+    """`(block, tile, first, last, live)` of the grid step a streaming
+    kernel is at: the block it owns, the tile it walks, whether the step
+    is the first / the last of the block, and whether it computes (None:
+    every step does).  From the table (its scalar-prefetch operands) on a
+    table grid; on a band grid (`band` given) from the step's place in
+    its block's band."""
+    if band is None:
+        t = pl.program_id(1)
+        block, tile, flags = (ref[t] for ref in table)
+        return block, tile, flags & FIRST != 0, flags & LAST != 0, None
+    i, j = pl.program_id(1), pl.program_id(2)
+    first, last = band(i)
+    return (i, first + j, j == 0, j == pl.num_programs(2) - 1,
+            first + j <= last)
 
 
-def _fwd_kernel_str(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr,
-                    acc_scr, *, sm_scale, causal, block_q, block_k, seq_len,
-                    window=None):
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    first, last = k_band(qi, block_q, block_k, seq_len // block_k, causal,
-                         window)
-    kb = first + step
+def _when(live, step):
+    """Run `step` where the grid step computes (`_entry`'s `live`)."""
+    if live is None:
+        step()
+    else:
+        pl.when(live)(step)
 
-    @pl.when(step == 0)
+
+def _fwd_kernel_str(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref,
+                    o_ref, lse_ref, m_scr, l_scr, acc_scr, *, sm_scale,
+                    causal, block_q, block_k, band, window=None):
+    qi, kb, first, last, live = _entry((block_ref, tile_ref, flags_ref),
+                                       band)
+
+    @pl.when(first)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    @pl.when(kb <= last)
     def _step():
+        # m and l live in scratch REPLICATED along lanes, [block_q, 128]:
+        # as [block_q, 1] columns their way out of scratch and back took
+        # 0.8 us of a tile's 1.9 (PERF.md section 6, PR 37)
         m, l, acc = _online_step(
             _scaled(q_ref[0], sm_scale), k_ref[0], v_ref[0],
             (m_scr[:], l_scr[:], acc_scr[:]),
             _edge(causal, qi * block_q, kb * block_k), window)
         m_scr[:], l_scr[:], acc_scr[:] = m, l, acc
 
-    @pl.when(step == pl.num_programs(2) - 1)
+    _when(live, _step)
+
+    @pl.when(last)
     def _finish():
         l = l_scr[:]
-        o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0, 0, :] = (m_scr[:] + jnp.log(l))[:, 0]
+        o_ref[0] = (acc_scr[:] / _spread(l, acc_scr.shape[1])).astype(
+            o_ref.dtype)
+        for r, piece in _to_lanes(m_scr[:] + jnp.log(l)):
+            lse_ref[0, 0, pl.ds(r, 128)] = piece
 
 
-def _dq_kernel_str(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_scr, *, sm_scale, causal, block_q, block_k, seq_len,
-                   window=None):
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    first, last = k_band(qi, block_q, block_k, seq_len // block_k, causal,
-                         window)
-    kb = first + step
+def _dq_kernel_str(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref,
+                   do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *, sm_scale,
+                   causal, block_q, block_k, band, window=None):
+    qi, kb, first, last, live = _entry((block_ref, tile_ref, flags_ref),
+                                       band)
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(kb <= last)
     def _step():
         dq_scr[:] = dq_scr[:] + _dq_step(
             _scaled(q_ref[0], sm_scale), k_ref[0], v_ref[0], do_ref[0],
             lse_ref[0, 0, :][:, None], delta_ref[0, 0, :][:, None],
             _edge(causal, qi * block_q, kb * block_k), window)
 
-    @pl.when(step == pl.num_programs(2) - 1)
+    _when(live, _step)
+
+    @pl.when(last)
     def _finish():
         dq_ref[0] = (sm_scale * dq_scr[:]).astype(dq_ref.dtype)
 
 
-def _dkv_kernel_str(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale, causal,
-                    block_q, block_k, seq_len, window=None):
-    ki = pl.program_id(1)
-    step = pl.program_id(2)
-    first, last = q_band(ki, block_q, block_k, seq_len // block_q, causal,
-                         window)
-    qb = first + step
+def _dkv_kernel_str(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref,
+                    do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr,
+                    dv_scr, *, sm_scale, causal, block_q, block_k, band,
+                    window=None):
+    ki, qb, first, last, live = _entry((block_ref, tile_ref, flags_ref),
+                                       band)
 
-    @pl.when(step == 0)
+    @pl.when(first)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    @pl.when(qb <= last)
     def _step():
         dk_i, dv_i = _dkv_step(
             q_ref[0], _scaled(k_ref[0], sm_scale), v_ref[0], do_ref[0],
@@ -596,7 +692,9 @@ def _dkv_kernel_str(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_scr[:] = dk_scr[:] + dk_i
         dv_scr[:] = dv_scr[:] + dv_i
 
-    @pl.when(step == pl.num_programs(2) - 1)
+    _when(live, _step)
+
+    @pl.when(last)
     def _finish():
         dk_ref[0] = (sm_scale * dk_scr[:]).astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -611,6 +709,46 @@ def _q_spec(block_q, d):
 
 def _lse_spec(block_q):
     return pl.BlockSpec((1, 1, block_q), lambda b, i, *_: (b, 0, i))
+
+
+def _walk_specs(walk, rows, d, column):
+    """`(wide, lanes)` BlockSpecs over a streaming call's grid: blocks of
+    `rows` of a [BH, S, D] operand and of a per-row statistic [BH, 1, S],
+    for `column` 0 the block the program owns, 1 the tile it walks.  On a
+    table grid (bh, entry) the index is the table's, read from the
+    scalar-prefetch operands; on a band grid (bh, block, step) it is the
+    block, or the band's tile at the step and past its end the last one
+    again.  Steps that follow each other with the same index copy
+    nothing."""
+    if walk.band is None:
+        def at(t, *table):
+            return table[column][t]
+    elif column == 0:
+        def at(i, j, *_):
+            return i
+    else:
+        def at(i, j, *_):
+            first, last = walk.band(i)
+            return jnp.minimum(first + j, last)
+    return (pl.BlockSpec((1, rows, d), lambda b, *step: (b, at(*step), 0)),
+            pl.BlockSpec((1, 1, rows), lambda b, *step: (b, 0, at(*step))))
+
+
+def _walk_call(kernel, walk, bh, in_specs, out_specs, scratch_shapes,
+               **kwargs):
+    """`pallas_call` of a streaming kernel over the grid (bh, *walk.grid),
+    the table's columns its scalar-prefetch operands: on a table grid
+    read by the index maps before a step's copies start, and by the
+    kernel; on a band grid by nobody."""
+    columns = [jnp.asarray(column, jnp.int32) for column in walk.table]
+    call = pl.pallas_call(
+        functools.partial(kernel, band=walk.band),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(columns), grid=(bh, *walk.grid),
+            in_specs=in_specs, out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        **kwargs)
+    return functools.partial(call, *columns)
 
 
 def _windowed(window, kind):
@@ -631,23 +769,21 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, streaming,
     out_shape = [jax.ShapeDtypeStruct((bh, s, d), q.dtype),
                  jax.ShapeDtypeStruct((bh, 1, s), jnp.float32)]
     if streaming:
-        band = (block_q, block_k, s // block_k, causal, window)
-        kv_spec = pl.BlockSpec((1, block_k, d), _band_maps(k_band, *band)[0])
-        return pl.pallas_call(
+        walk = stream_walk(s, block_q, block_k, causal, window)
+        rows, rows_lanes = _walk_specs(walk, block_q, d, 0)
+        keys, _ = _walk_specs(walk, block_k, d, 1)
+        return _walk_call(
             functools.partial(_fwd_kernel_str, sm_scale=sm_scale,
                               causal=causal, block_q=block_q,
-                              block_k=block_k, seq_len=s, **kw),
-            grid=(bh, s // block_q,
-                  _band_steps(k_band, s // block_q, *band)),
-            in_specs=[_q_spec(block_q, d), kv_spec, kv_spec],
-            out_specs=[_q_spec(block_q, d), _lse_spec(block_q)],
-            out_shape=out_shape,
+                              block_k=block_k, **kw),
+            walk, bh, in_specs=[rows, keys, keys],
+            out_specs=[rows, rows_lanes],
             scratch_shapes=[
-                pltpu.VMEM((block_q, 1), jnp.float32),   # running max m
-                pltpu.VMEM((block_q, 1), jnp.float32),   # running sum l
+                pltpu.VMEM((block_q, 128), jnp.float32),  # running max m
+                pltpu.VMEM((block_q, 128), jnp.float32),  # running sum l
                 pltpu.VMEM((block_q, d), jnp.float32),   # accumulator
             ],
-            interpret=interpret, **named,
+            out_shape=out_shape, interpret=interpret, **named,
         )(q, k, v)
     kv_spec = pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0))
     rows = _program_rows(s, block_q, block_k)
@@ -673,40 +809,35 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, streaming,
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None, :]                 # (bh, 1, s)
     if streaming:
-        band = (block_q, block_k, s // block_k, causal, window)
-        kv_spec = pl.BlockSpec((1, block_k, d), _band_maps(k_band, *band)[0])
-        dq = pl.pallas_call(
+        walk = stream_walk(s, block_q, block_k, causal, window)
+        rows, rows_lanes = _walk_specs(walk, block_q, d, 0)
+        keys, _ = _walk_specs(walk, block_k, d, 1)
+        dq = _walk_call(
             functools.partial(_dq_kernel_str, sm_scale=sm_scale,
                               causal=causal, block_q=block_q,
-                              block_k=block_k, seq_len=s, **kw),
-            grid=(bh, s // block_q,
-                  _band_steps(k_band, s // block_q, *band)),
-            in_specs=[
-                _q_spec(block_q, d), kv_spec, kv_spec, _q_spec(block_q, d),
-                _lse_spec(block_q), _lse_spec(block_q),
-            ],
-            out_specs=_q_spec(block_q, d),
-            out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+                              block_k=block_k, **kw),
+            walk, bh,
+            in_specs=[rows, keys, keys, rows, rows_lanes, rows_lanes],
+            out_specs=rows,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
             interpret=interpret, **dq_named,
         )(q, k, v, do, lse, delta)
-        band = (block_q, block_k, s // block_q, causal, window)
-        wide, lanes = _band_maps(q_band, *band)
-        kb_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
-        qs_spec = pl.BlockSpec((1, block_q, d), wide)
-        ls_spec = pl.BlockSpec((1, 1, block_q), lanes)
-        dk, dv = pl.pallas_call(
+        # the same square by its keys: a program owns a block of keys
+        walk = stream_walk(s, block_q, block_k, causal, window, by_keys=True)
+        keys, _ = _walk_specs(walk, block_k, d, 0)
+        rows, rows_lanes = _walk_specs(walk, block_q, d, 1)
+        dk, dv = _walk_call(
             functools.partial(_dkv_kernel_str, sm_scale=sm_scale,
                               causal=causal, block_q=block_q,
-                              block_k=block_k, seq_len=s, **kw),
-            grid=(bh, s // block_k,
-                  _band_steps(q_band, s // block_k, *band)),
-            in_specs=[qs_spec, kb_spec, kb_spec, qs_spec, ls_spec, ls_spec],
-            out_specs=[kb_spec, kb_spec],
-            out_shape=[jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-                       jax.ShapeDtypeStruct((bh, s, d), v.dtype)],
+                              block_k=block_k, **kw),
+            walk, bh,
+            in_specs=[rows, keys, keys, rows, rows_lanes, rows_lanes],
+            out_specs=[keys, keys],
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                             pltpu.VMEM((block_k, d), jnp.float32)],
+            out_shape=[jax.ShapeDtypeStruct((bh, s, d), k.dtype),
+                       jax.ShapeDtypeStruct((bh, s, d), v.dtype)],
             interpret=interpret, **dkv_named,
         )(q, k, v, do, lse, delta)
         return dq, dk, dv

@@ -480,66 +480,204 @@ def test_the_kernels_write_their_gauges_when_a_step_is_traced():
 
 
 # ---------------------------------------------------------------------------
-# The streaming path's band walk
+# The streaming path's table of live tiles
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("s,window,bq,bk", [
-    (1024, 256, 128, 128),    # a band of 3 steps in a grid of 8
-    (1024, 256, 256, 128),    # rows wider than keys
-    (1024, 300, 128, 256),    # keys wider than rows, an uneven window
-    (1024, None, 128, 128),   # causal alone: the clamped walk
-    (1024, None, 128, 256),
-    (512, 2000, 128, 128),    # the window passes the sequence
+_TABLE_SHAPES = [
+    # (s, bq, bk, causal, window)
+    (32768, 512, 512, True, 1024),    # the mellum cell's two calls
+    (32768, 512, 512, True, None),
+    (1024, 128, 128, True, 256),
+    (1024, 128, 128, True, None),
+    (1024, 256, 128, True, 256),      # rows wider than keys
+    (1024, 256, 128, True, None),
+    (1024, 128, 256, True, 300),      # keys wider than rows, uneven window
+    (1024, 128, 256, True, None),
+    (1024, 128, 128, True, 1),        # a row sees itself alone
+    (512, 128, 128, True, 2000),      # the window passes the sequence
+    (512, 128, 64, False, None),      # no mask: the whole square
+]
+
+
+def _keeps(q0, k0, bq, bk, causal, window):
+    """The (query, key) pairs of a tile that `_mask` keeps, from `_mask`
+    itself on a tile of ones."""
+    from byteps_tpu.ops.flash_attention import _mask
+    ones = jnp.ones((bq, bk), jnp.float32)
+    if not causal:
+        return np.ones((bq, bk), bool)
+    return np.asarray(_mask(ones, q0, k0, window)) == 1.0
+
+
+@pytest.mark.parametrize("by_keys", [False, True], ids=["rows", "keys"])
+@pytest.mark.parametrize("s,bq,bk,causal,window", _TABLE_SHAPES,
+                         ids=lambda x: str(x))
+def test_stream_table_lists_the_live_tiles_once_in_order(s, bq, bk, causal,
+                                                         window, by_keys):
+    """The table a streaming call's grid walks: every tile the band
+    functions call live and no other, once, block by block and tile by
+    tile; FIRST and LAST once a block, on its ends; every tile listed
+    keeps at least one pair under `_mask`, and no tile left out keeps
+    any."""
+    from byteps_tpu.ops.flash_attention import (FIRST, LAST, k_band, q_band,
+                                                stream_table)
+    block, tile, flags = stream_table(s, bq, bk, causal, window,
+                                      by_keys=by_keys)
+    nq, nk = s // bq, s // bk
+    band, blocks, tiles = (q_band, nk, nq) if by_keys else (k_band, nq, nk)
+    want = []
+    for i in range(blocks):
+        first, last = band(i, bq, bk, tiles, causal, window)
+        want += [(i, j) for j in range(first, last + 1)]
+    assert list(zip(block, tile)) == want
+    assert len(set(want)) == len(want)
+    assert sorted(want) == want                      # block, then tile
+    for i in range(blocks):
+        run = [f for b, f in zip(block, flags) if b == i]
+        assert [bool(f & FIRST) for f in run] == \
+            [True] + [False] * (len(run) - 1)
+        assert [bool(f & LAST) for f in run] == \
+            [False] * (len(run) - 1) + [True]
+    # the mask itself, on the tiles of a few blocks at each end and in
+    # the middle (every tile of the small shapes)
+    probe = set(range(blocks)) if s <= 1024 else {0, 1, 2, 3, blocks // 2,
+                                                   blocks - 1}
+    for b, t in zip(block, tile):
+        if b not in probe:
+            continue
+        qi, ki = (t, b) if by_keys else (b, t)
+        assert _keeps(qi * bq, ki * bk, bq, bk, causal, window).any(), \
+            (qi, ki)
+    if s <= 1024:
+        # and no live tile is left out: a tile not listed keeps nothing
+        listed = {((t, b) if by_keys else (b, t))
+                  for b, t in zip(block, tile)}
+        for qi in range(nq):
+            for ki in range(nk):
+                if (qi, ki) not in listed:
+                    assert not _keeps(qi * bq, ki * bk, bq, bk, causal,
+                                      window).any(), (qi, ki)
+
+
+@pytest.mark.parametrize("s,bq,bk,causal,window", [
+    (1024, 128, 128, True, 256),     # a band of 3 tiles a block: band grid
+    (1024, 256, 128, True, 256),     # rows wider than keys
+    (1024, 128, 256, True, 300),     # keys wider than rows, uneven window
+    (2048, 128, 128, True, 700),     # bands of 1 to 7 tiles on a band grid
+    (1024, 128, 128, True, None),    # causal alone: the table grid
+    (1024, 128, 256, True, None),
+    (512, 128, 128, True, 2000),     # the window passes the sequence: table
+    (512, 128, 128, True, 1),        # blocks of one tile: first and last
+    (512, 128, 64, False, None),     # no mask: the square, a band grid
+    (512, 256, 128, False, None),
 ], ids=lambda x: str(x))
-def test_streaming_band_walk_against_dense(s, window, bq, bk):
-    """The streaming kernels walk a band of the other axis, tile
-    `first + step` at every step and the last tile again past the band's
-    end: forward and all three gradients against dense attention."""
+def test_streaming_grids_against_dense_and_resident(s, bq, bk, causal,
+                                                    window):
+    """The streaming kernels on their table and band grids, in the interpreter:
+    result and all three gradients against dense attention and against
+    the resident kernels, causal, windowed and unmasked; the forward
+    kernel's running statistics replicated along lanes in scratch, tiles
+    of keys of 64, 128 and 256 and a head of 64 under them."""
     rng = np.random.RandomState(3)
     q, k, v, g = (_rand(rng, 2, s, 64) for _ in range(4))
 
-    def flash(q, k, v):
-        return (flash_attention(q, k, v, True, None, bq, bk, True, True,
-                                window) * g).sum()
+    def flash(streaming):
+        def f(q, k, v):
+            return (flash_attention(q, k, v, causal, None, bq, bk, True,
+                                    streaming, window) * g).sum()
+        return f
 
     def dense(q, k, v):
         if window is None:
-            return (_ref(q, k, v, True) * g).sum()
+            return (_ref(q, k, v, causal) * g).sum()
         return (_windowed_ref(q, k, v, window) * g).sum()
 
-    got, got_g = jax.value_and_grad(flash, (0, 1, 2))(q, k, v)
+    got, got_g = jax.value_and_grad(flash(True), (0, 1, 2))(q, k, v)
     want, want_g = jax.value_and_grad(dense, (0, 1, 2))(q, k, v)
+    res, res_g = jax.value_and_grad(flash(False), (0, 1, 2))(q, k, v)
     np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
-    for a, b in zip(got_g, want_g):
+    np.testing.assert_allclose(float(got), float(res), rtol=1e-5)
+    for a, b, c in zip(got_g, want_g, res_g):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-5, rtol=1e-4)
-    # the innermost grid axis is the longest band, not the whole axis
-    from byteps_tpu.ops.flash_attention import (_band_steps, k_band,
-                                                q_band)
-    w = None if window is None else min(window, s)
-    fwd = _band_steps(k_band, s // bq, bq, bk, s // bk, True, window)
-    dkv = _band_steps(q_band, s // bk, bq, bk, s // bq, True, window)
-    if w is None or w >= s:
-        assert (fwd, dkv) == (s // bk, s // bq)
-    else:
-        assert fwd <= (bq + w - 2) // bk + 2 < s // bk
-        assert dkv <= (bk + w - 2) // bq + 2 < s // bq
+        np.testing.assert_allclose(np.asarray(a), np.asarray(c),
+                                   atol=5e-5, rtol=1e-4)
+    out = flash_attention(q, k, v, causal, None, bq, bk, True, True, window)
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(flash_attention(q, k, v, causal, None, bq, bk, True,
+                                   False, window)), atol=2e-6)
+
+
+@pytest.mark.parametrize("window,grid", [
+    # the triangle: 64 steps for 36 tiles on a band grid, so the table's
+    (None, (2, 36)),
+    # bands of 3 tiles in 8 blocks, 24 steps for 21 tiles: the band grid
+    (256, (2, 8, 3)),
+], ids=["table", "band"])
+def test_streaming_grid_is_the_table_or_the_band(window, grid):
+    """`stream_walk` picks a call's grid: (heads, entries of the table)
+    where the bands differ in length, (heads, blocks, longest band) where
+    that has few dead steps.  The table's three columns are the call's
+    first operands either way."""
+    from byteps_tpu.ops.flash_attention import (BAND_GRID_SLACK, _fwd,
+                                                stream_walk)
+    q = jnp.zeros((2, 1024, 64), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q: _fwd(
+        q, q, q, 0.125, True, 128, 128, True, True, window))(q)
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    walk = stream_walk(1024, 128, 128, True, window)
+    live = len(walk.table[0])
+    assert call.params["grid_mapping"].grid == (2, *walk.grid) == grid
+    assert (walk.band is None) == (window is None)
+    # (a band grid of this call would walk 8 blocks x its longest band)
+    longest = max(walk.table[0].count(i) for i in range(8))
+    assert (8 * longest <= BAND_GRID_SLACK * live) == (walk.band is not None)
+    assert call.params["grid_mapping"].num_index_operands == 3
+    assert [v.aval.shape for v in call.invars[:3]] == [(live,)] * 3
+    if walk.band is not None:
+        # a band grid's steps: the band's tiles, then its last one again
+        for i in range(walk.grid[0]):
+            first, last = walk.band(i)
+            assert [t for b, t in zip(*walk.table[:2]) if b == i] == \
+                list(range(first, last + 1))
+
+
+def test_band_grid_index_maps_repeat_the_last_tile_past_the_band():
+    """What keeps a band grid's dead step from copying K and V: past the
+    band's end the index map gives the band's last tile again."""
+    from byteps_tpu.ops.flash_attention import _walk_specs, stream_walk
+    walk = stream_walk(1024, 128, 128, True, 256)
+    wide, lanes = _walk_specs(walk, 128, 64, 1)
+    # row tile 5 sees the keys 385..767: tiles 3, 4, 5
+    assert [int(wide.index_map(0, 5, j)[1]) for j in range(3)] == [3, 4, 5]
+    assert [int(wide.index_map(0, 0, j)[1]) for j in range(3)] == [0, 0, 0]
+    assert [int(lanes.index_map(7, 1, j)[2]) for j in range(3)] == [0, 1, 1]
+    own, _ = _walk_specs(walk, 128, 64, 0)
+    assert [int(own.index_map(0, 5, j)[1]) for j in range(3)] == [5, 5, 5]
+    # by the keys: key tile 6 (keys 768..895) is seen by rows 768..1023
+    walk = stream_walk(1024, 128, 128, True, 256, by_keys=True)
+    wide, _ = _walk_specs(walk, 128, 64, 1)
+    assert [int(wide.index_map(0, 6, j)[1]) for j in range(3)] == [6, 7, 7]
 
 
 @pytest.mark.parametrize("s,bq,bk,window,want", [
-    # the mellum cell's calls: 64 row tiles; a band of 3 tiles of keys
+    # the mellum cell's calls: 64 row tiles; a band of 3 tiles of keys,
+    # walked on a band grid
     (32768, 512, 512, 1024, dict(steps=192, live=189, fetched=188)),
-    # causal alone: the square walked, the triangle computed and fetched
-    (32768, 512, 512, None, dict(steps=4096, live=2080, fetched=2079)),
+    # causal alone: the table grid, the triangle's tiles and no other
+    (32768, 512, 512, None, dict(steps=2080, live=2080, fetched=2079)),
     (1024, 128, 128, 256, dict(steps=24, live=21, fetched=20)),
-    (1024, 128, 128, None, dict(steps=64, live=36, fetched=35)),
+    (1024, 128, 128, None, dict(steps=36, live=36, fetched=35)),
 ], ids=lambda x: str(x) if not isinstance(x, dict) else "")
 def test_stream_schedule_counts_and_gauges(s, bq, bk, window, want):
     """`stream_schedule` for known shapes, by hand: a row tile's band is
     the tiles from its first row's oldest key to its last row's own; a
-    step past the band repeats the last tile and copies nothing, and the
-    first tile of a row tile is copied unless the row tile before ended
-    on it.  The gauges carry the last traced streaming call of each
-    window, under the label `window`."""
+    causal call's grid is the table of those and every step computes, a
+    windowed call's is its blocks times its longest band; the first tile
+    of a row tile is copied unless the row tile before ended on it, and a
+    step past a band repeats the last tile and copies nothing.  The
+    gauges carry the last traced streaming call of each window, under
+    the label `window`."""
     import byteps_tpu as bps
     from byteps_tpu.ops.flash_attention import k_band, stream_schedule
     assert stream_schedule(s, bq, bk, True, window) == want
@@ -563,20 +701,3 @@ def test_stream_schedule_counts_and_gauges(s, bq, bk, window, want):
         assert metrics["bps_flash_stream_steps" + label] == want["steps"]
         assert metrics["bps_flash_stream_live" + label] == want["live"]
         assert metrics["bps_flash_stream_fetched" + label] == want["fetched"]
-
-
-def test_streaming_index_maps_repeat_the_last_tile_past_the_band():
-    """What keeps a dead step from copying K and V: past the band's end
-    the index map gives the band's last tile again."""
-    from byteps_tpu.ops.flash_attention import _band_maps, k_band, q_band
-    args = (128, 128, 8, True, 256)
-    wide, lanes = _band_maps(k_band, *args)
-    # row tile 5 sees the keys 385..767: tiles 3, 4, 5
-    assert [int(wide(0, 5, j)[1]) for j in range(4)] == [3, 4, 5, 5]
-    assert [int(wide(0, 0, j)[1]) for j in range(3)] == [0, 0, 0]
-    assert [int(lanes(7, 5, j)[2]) for j in range(3)] == [3, 4, 5]
-    wide, _ = _band_maps(q_band, *args)
-    # key tile 6 (keys 768..895) is seen by the rows 768..1023 of 1024
-    assert [int(wide(0, 6, j)[1]) for j in range(3)] == [6, 7, 7]
-    causal, _ = _band_maps(k_band, 128, 128, 8, True, None)
-    assert [int(causal(0, 2, j)[1]) for j in range(8)] == [0, 1, 2] + [2] * 5

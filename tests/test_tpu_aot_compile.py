@@ -125,20 +125,35 @@ def test_windowed_flash_compiles_at_trinity_shapes(v5e, streaming):
 def test_streaming_flash_compiles_at_mellum_shapes(v5e, window):
     """32 heads of 128 over ONE sequence of 32,768: K and V of a head are
     16 MiB, past the resident budget, so the rule picks the streaming
-    kernels by itself; the band walk's index maps (a floor division and a
-    minimum of traced indices) are what the chip's compiler has to take.
-    The three results keep the forms the benchmark tells them by."""
-    from benchmark.reduce import afmoe_cost
+    kernels by itself.  The causal call's grid is (heads, entries of the
+    table of live tiles), the table's three columns scalar-prefetch
+    operands that the index maps and the kernels read; the windowed
+    call's is (heads, blocks, the band's 3 steps), its index maps a floor
+    division and a minimum of traced indices: both are what the chip's
+    compiler has to take.  The table's columns are operands: the three
+    results keep the forms the benchmark tells the kernels by
+    (`flash_cost.classify`), forward `(bf16[N,S,D], f32[N,1,S])`, dQ one
+    `bf16[N,S,D]`, dK/dV two."""
+    from benchmark.reduce import afmoe_cost, flash_cost
     from byteps_tpu.models.transformer import flash_auto_tiles
     assert flash_auto_tiles(32768, True) == (512, 512)
     q = jnp.zeros((32, 32768, 128), jnp.bfloat16)
     assert fa._use_streaming(q, None)
     text = _flash_fwd_bwd(32, 32768, 128, 512, None, v5e[0],
                           window=window).as_text()
-    calls = [afmoe_cost.attention_call(line) for line in text.splitlines()
-             if " custom-call(" in line]
-    assert sorted(c[:4] for c in calls if c) == sorted(
-        (kind, 32, 32768, 128) for kind in ("dkv", "dq", "forward"))
+    lines = [line for line in text.splitlines()
+             if flash_cost.is_kernel(line) and " custom-call(" in line]
+    kinds = [(kind, 32, 32768, 128) for kind in ("dkv", "dq", "forward")]
+    assert sorted(flash_cost.classify(line) for line in lines) == kinds, \
+        lines
+    assert sorted(afmoe_cost.attention_call(line)[:4]
+                  for line in lines) == kinds
+    # the table is in the call: three s32 columns, one entry a live tile
+    walk = fa.stream_walk(32768, 512, 512, True, window)
+    assert walk.grid == ((64, 3) if window else (2080,))
+    entries = len(walk.table[0])
+    assert entries == (189 if window else 2080)
+    assert all(line.count(f"s32[{entries}]") >= 3 for line in lines), lines
     # a bare `jax.grad` puts `jvp_` before a call's name, which a train
     # step's remat does not: the window is read from the text here
     assert all((f"flash_{kind}_w1024" in text) == (window is not None)
