@@ -1,0 +1,6 @@
+"""`step.fwd_ms` under the name that moves `images_per_s`: a per-layer metric
+names the one end-to-end metric it should move."""
+
+from benchmark.harness.readers import reader
+
+read = reader("step.fwd_ms")

@@ -1739,6 +1739,21 @@ def get_device_profile() -> dict:
     return prof.profile()
 
 
+def get_step_scopes() -> Optional[dict]:
+    """Which part of the model each instruction of the compiled train
+    step belongs to: ``{instruction: {"scope": "mellum.moe/grouped",
+    "pass": "forward" | "backward" | "recompute" | "optimizer" | "other",
+    "op_name": ...}}`` for the last step ``build_train_step`` compiled, to
+    lay over a ``jax.profiler`` capture, whose events carry the
+    instructions' names (docs/timeline.md, "Scopes"); a kernel the
+    compiler made itself, which has no path, also carries ``"lent":
+    True``: its scope is what its neighbours' scopes share.  Needs no option
+    and costs a step nothing; the first request reads the text of the
+    executable the step holds and compiles nothing.  None where no step
+    was built, or where that fails (one logged line)."""
+    return devprof.get_step_scopes()
+
+
 def get_fleet() -> dict:
     """The fleet observability plane's merged view (``BYTEPS_TPU_FLEET=1``,
     PS mode): the last CMD_FLEET fetch (per-worker window rings), the

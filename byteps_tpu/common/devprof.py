@@ -49,6 +49,7 @@ backend).
 from __future__ import annotations
 
 import os
+import re
 import sys
 import threading
 import time
@@ -139,6 +140,303 @@ def cost_analysis_flops(fn, args: tuple) -> Optional[float]:
     if not isinstance(flops, (int, float)) or flops <= 0:
         return None
     return float(flops)
+
+
+# ---------------------------------------------------------------------------
+# The scope map of the compiled step (`bps.get_step_scopes()`).
+#
+# A `jax.named_scope` in the program is a span that costs nothing: it
+# changes no operation, only the `op_name` metadata of the instructions
+# traced under it, which holds JAX's name stack,
+#
+#     jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/
+#         rematted_computation/transformer.mlp/dot_general
+#
+# (a transform wraps the ONE component after it: `jvp(afmoe.moe)/.route`).
+# The device trace names instructions and nothing else; the compiled
+# module says where each came from.  So the trainer remembers, at each
+# compile of its callable, what it compiled (`remember_step`), and on
+# request the module's text is parsed into {instruction: scope, pass}.
+#
+# The vocabulary is a matter of FORM, and this module knows no model's
+# names: a scope the program opened is a component of the path with a
+# "." in it (`<family>.<part>`: `mellum.moe`, `byteps.optimizer`); JAX's
+# own components (`while`, `body`, `checkpoint`, `closed_call`, ...) and
+# a module system's have none, and a function's name stands in `jit()`.
+# A scope's child is opened with a RELATIVE name, a leading "."
+# (`.qkv` inside `mellum.attn.full_attention`), and the map drops that
+# dot: "mellum.attn.full_attention/qkv".  A new family brings its scopes
+# in its own files and nothing here changes.
+# ---------------------------------------------------------------------------
+#: Whatever this scope holds runs in pass "optimizer"; the trainer opens
+#: it (parallel/data_parallel.py).
+OPTIMIZER_SCOPE = "byteps.optimizer"
+PASSES = ("forward", "backward", "recompute", "optimizer", "other")
+
+_WRAPPED = re.compile(r"^([\w\-.]+)\((.*)\)$")
+_INSTRUCTION = re.compile(r"^\s+(ROOT\s+)?%?([\w.\-]+) = (.*)$")
+_COMPUTATION = re.compile(r"^(?:ENTRY\s+)?%?([\w.\-]+) \(.*\) -> .* \{$")
+_OPCODE = re.compile(r"(?<![\w.\-])([a-z][a-z0-9\-]*)\(")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLED = re.compile(
+    r"(?:calls|body|condition|to_apply|true_computation|false_computation)"
+    r"=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+#: Instructions whose called computations hold instructions that run, and
+#: show in a trace, as themselves.
+_CONTROL_FLOW = ("while", "call", "conditional", "async-start")
+#: Instructions that only hand a value on: a kernel's operand is made by
+#: whatever stands behind them.
+_THIN = ("get-tuple-element", "bitcast", "copy", "copy-start", "copy-done")
+#: What a fusion is named after if it holds one: the matrix product
+#: (which the TPU compiler writes as a convolution) or grouped product.
+_PRODUCTS = ("convolution", "dot", "ragged-dot")
+
+
+def classify_op_name(op_name: str) -> Tuple[str, str]:
+    """`(scope, pass)` of one `op_name`.  The scope is the program's own
+    components of the path, joined by `/` ("" where it has none): those
+    with a "." in them that are no function's name (`jit(...)`), a
+    child's leading "." dropped; the last component, the primitive's
+    name, never is one.  The pass, by rule: under `byteps.optimizer` ->
+    optimizer; under `rematted_computation` -> recompute; else under
+    `transpose(` -> backward; else under `jvp(` -> forward; else other.
+    Where the compiler made one instruction of two it writes both paths,
+    `a/b/x;b/y`: the first is read."""
+    parts = op_name.split(";")[0].split("/")
+    scope: List[str] = []
+    transforms = set()
+    for i, part in enumerate(parts):
+        m = _WRAPPED.match(part)
+        while m:
+            transforms.add(m.group(1))
+            if m.group(1) in ("jit", "pjit"):
+                part = ""           # a function's name, no scope
+                break
+            part = m.group(2)
+            m = _WRAPPED.match(part)
+        if part == "rematted_computation":
+            transforms.add(part)
+        elif i < len(parts) - 1 and "." in part:
+            scope.append(part.lstrip(".") if scope else part)
+    if OPTIMIZER_SCOPE in scope:
+        which = "optimizer"
+    elif "rematted_computation" in transforms:
+        which = "recompute"
+    elif "transpose" in transforms:
+        which = "backward"
+    elif "jvp" in transforms:
+        which = "forward"
+    else:
+        which = "other"
+    return "/".join(scope), which
+
+
+def parse_step_scopes(hlo_text: str) -> Dict[str, dict]:
+    """`{instruction: {"scope", "pass", "op_name"}}` for every instruction
+    of an optimized HLO module (`compiled.as_text()`) that can run as
+    itself: those of the entry computation and of the computations a
+    `while`, `call` or `conditional` reaches from it.  The instructions
+    INSIDE a fused computation are left out (a trace shows the fusion),
+    and so are the scalar computations of a `reduce` or a `sort`.
+
+    A fusion's path, by rule: that of the matrix product, convolution or
+    grouped product it holds, if it holds one (a scan's write of a
+    layer's gradient into its stack is fused around whatever computes
+    the gradient: the root alone would say "the scan"); else its own
+    metadata's, which is its root's; else, where that has no program
+    scope, the first inner instruction's that has one.
+
+    One kind of instruction is LENT a scope, and its entry says so
+    (`"lent": True`): a kernel the compiler made itself, a `custom-call`
+    whose `op_name` is no path of JAX's (`ragged-dot-none`, the grouped
+    product the TPU compiler builds out of a `lax.ragged_dot` whose path
+    it drops).  It takes what the scopes of its neighbours share: of the
+    instructions that make its operands and of those that read its
+    result, behind any that only hand a value on, those whose scope is
+    their own; and the pass of the first reader that has one, else of
+    the first maker.  A grouped product between the gather and the
+    activation lies somewhere in `mellum.moe`: no more can be said, so
+    no more is.  Everything else without a scope stays without: what the
+    compiler hoists out of a loop and strips of its path is listed by a
+    reader, not guessed at.  A pure function of the text."""
+    comps: Dict[str, list] = {}
+    entry = current = None
+    for line in hlo_text.splitlines():
+        if current is None:
+            m = _COMPUTATION.match(line)
+            if m:
+                current = comps.setdefault(m.group(1), [])
+                if line.startswith("ENTRY"):
+                    entry = m.group(1)
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        m = _INSTRUCTION.match(line)
+        if not m:
+            continue
+        rest = m.group(3)
+        code = _OPCODE.search(rest)
+        name = _OP_NAME.search(rest)
+        called = _CALLED.findall(rest)
+        for group in _BRANCHES.findall(rest):
+            called += [c.strip().lstrip("%") for c in group.split(",")]
+        current.append((m.group(2), code.group(1) if code else "",
+                        name.group(1) if name else "", called,
+                        _OPERAND.findall(rest.split(", metadata=")[0])))
+
+    def inner(comp: str, seen=None):
+        """Every instruction under a fused computation, nested ones too."""
+        seen = set() if seen is None else seen
+        if comp in seen:
+            return
+        seen.add(comp)
+        for ins in comps.get(comp, ()):
+            yield ins
+            if ins[1] == "fusion":
+                for c in ins[3]:
+                    yield from inner(c, seen)
+
+    def fusion_path(own: str, called: list) -> str:
+        first = ""
+        for c in called:
+            for _, code, op_name, _, _ in inner(c):
+                if code in _PRODUCTS and op_name:
+                    return op_name
+                if not first and op_name and classify_op_name(op_name)[0]:
+                    first = op_name
+        if own and classify_op_name(own)[0]:
+            return own
+        return first or own
+
+    out: Dict[str, dict] = {}
+    todo, walked = [entry] if entry else [], set()
+    while todo:
+        comp = todo.pop()
+        if comp in walked:
+            continue
+        walked.add(comp)
+        table: Dict[str, tuple] = {}    # instruction -> (opcode, operands)
+        reads: Dict[str, list] = {}     # instruction -> those that read it
+        kernels = []
+        for ins_name, code, op_name, called, operands in comps.get(comp, ()):
+            if code in _CONTROL_FLOW:
+                todo.extend(called)
+            elif code == "fusion":
+                op_name = fusion_path(op_name, called)
+            scope, which = classify_op_name(op_name)
+            out[ins_name] = {"scope": scope, "pass": which,
+                             "op_name": op_name}
+            table[ins_name] = (code, operands)
+            for operand in operands:
+                reads.setdefault(operand, []).append(ins_name)
+            if code == "custom-call" and op_name and "/" not in op_name:
+                kernels.append(ins_name)
+        for ins_name in kernels:
+            _lend(out, ins_name,
+                  _behind(table[ins_name][1], table, lambda n: table[n][1]),
+                  _behind(reads.get(ins_name, ()), table,
+                          lambda n: reads.get(n, ())))
+    return out
+
+
+def _behind(names, table: dict, following) -> list:
+    """`names`, each that only hands a value on (`_THIN`) replaced by what
+    stands behind it along `following`; those of another computation (a
+    parameter's, a loop's) are left out."""
+    found, todo, seen = [], list(names), set()
+    while todo:
+        n = todo.pop(0)
+        if n in seen or n not in table:
+            continue
+        seen.add(n)
+        if table[n][0] in _THIN:
+            todo.extend(following(n))
+        else:
+            found.append(n)
+    return found
+
+
+def _lend(out: dict, kernel: str, makers: list, readers: list) -> None:
+    """Gives `kernel`'s entry what the own scopes of `makers` and
+    `readers` share, and a reader's pass (`parse_step_scopes`)."""
+    def own(n):
+        return n in out and out[n]["scope"] and not out[n].get("lent")
+    paths = [out[n]["scope"].split("/") for n in makers + readers if own(n)]
+    shared = os.path.commonprefix(paths) if paths else None
+    if not shared:
+        return
+    passed = [out[n]["pass"] for n in readers + makers
+              if n in out and out[n]["pass"] != "other"]
+    out[kernel].update(scope="/".join(shared), lent=True,
+                       **({"pass": passed[0]} if passed else {}))
+
+
+#: `(callable, abstract arguments)` of the last train step a trainer
+#: compiled.  Module-level: like the registry's gauges it outlives
+#: `bps.shutdown()`, so that whoever traced a job can still ask.
+_step_record: Optional[tuple] = None
+_step_scopes: Optional[tuple] = None     # (record, the map or None)
+
+
+def remember_step(fn, args: tuple) -> None:
+    """Trainer hook, called after a call of `fn` that compiled it (the
+    first; a later one where the shapes or the placement changed) and on
+    no other step: keeps the callable and the shapes, dtypes and
+    shardings of `args`, which hold no buffer.  `build_train_step` passes
+    the step's own results in place of the state it was given: they are
+    what every later step receives (on a mesh the first call's arguments
+    lie elsewhere, and that program is not the one that goes on
+    running)."""
+    global _step_record
+    import jax
+    import jax.numpy as jnp
+    if any(isinstance(a, jax.core.Tracer) for a in jax.tree.leaves(args)):
+        return      # traced into somebody else's program: not ours to map
+
+    def abstract(a):
+        # An array nobody placed is lowered with no sharding, as the call
+        # itself lowers it: the same program, found again and not
+        # compiled a second time.
+        placed = getattr(a, "committed", False)
+        return jax.ShapeDtypeStruct(
+            jnp.shape(a), jnp.result_type(a),
+            sharding=a.sharding if placed else None,
+            weak_type=getattr(a, "weak_type", False))
+    _step_record = (fn, jax.tree.map(abstract, args))
+
+
+def get_step_scopes() -> Optional[Dict[str, dict]]:
+    """The scope map of the last train step `build_train_step` compiled
+    (`parse_step_scopes` says what it holds), or None where no step was
+    built.  On the first request the step's callable is lowered for the
+    remembered shapes, for which JAX hands back the executable the step
+    holds (nothing is compiled, and the map is of what ran), and its text
+    parsed; the map is kept.  Never raises: where the lowering, the
+    compile or the parse fails the answer is None, with one logged
+    line."""
+    global _step_scopes
+    record = _step_record
+    if record is None:
+        return None
+    if _step_scopes is not None and _step_scopes[0] is record:
+        return _step_scopes[1]
+    fn, args = record
+    t0 = time.monotonic()
+    try:
+        from ..utils import compile_cache
+        with compile_cache.scopes_in_key():     # as the step was compiled
+            text = fn.lower(*args).compile().as_text()
+        scopes = parse_step_scopes(text)
+        get_logger().info("scope map of the step: %d instructions in "
+                          "%.2f s", len(scopes), time.monotonic() - t0)
+    except Exception as e:  # noqa: BLE001 — a reader must not end a run
+        get_logger().warning("no scope map of the step: %r", e)
+        scopes = None
+    _step_scopes = (record, scopes)
+    return scopes
 
 
 class DeviceProfiler:
@@ -303,9 +601,10 @@ class DeviceProfiler:
                       labels={"worker": w}).set(sec["device_step_ms"])
         if sec["mfu"] is not None:
             reg.gauge("bps_mfu",
-                      help="model FLOPs utilization over the last signal "
-                           "window (cost_analysis FLOPs / device seconds "
-                           "/ platform peak)",
+                      help="FLOPs utilization over the last signal window "
+                           "(cost_analysis FLOPs / device seconds / "
+                           "platform peak); cost_analysis counts what the "
+                           "step executes, recomputed operations included",
                       labels={"worker": w}).set(sec["mfu"])
         reg.gauge("bps_device_fallback",
                   help="1 when the device sentinel convicted a platform "

@@ -395,87 +395,91 @@ def pushpull_speed_mbps() -> float:
 
 
 # ---------------------------------------------------------------------------
-# In-graph exchange (ops/collectives.py bucketed_tree_all_reduce)
+# The form of the last call traced: static counters, one recorder
 # ---------------------------------------------------------------------------
-def record_ingraph_exchange(leaves: int, groups: int,
-                            packed_bytes: int) -> None:
-    """The form of the last gradient exchange that was traced, written
-    once per trace (not per step: the call sits in the Python body of a
-    jitted step).  ``packed_bytes`` is what went through slice and
-    concatenate into flat buckets: the tree's whole size when a
-    compressor or the hierarchical reduce-scatter needs buckets as
-    vectors, 0 when every leaf is summed in its own shape."""
+def _gauge(name: str, help: str, cast=int) -> tuple:
+    # Spelled `_gauge("bps_...")` so that tools/check_metrics_docs.py,
+    # which finds a registered name by that form, sees the table's.
+    return name, help, cast
+
+
+#: family -> keyword of `record_static` -> the gauge it sets.  A family is
+#: what one call site knows when its Python body is traced into a jitted
+#: step: the exchange (`ops/collectives.py` `bucketed_tree_all_reduce`),
+#: the state-space scans (`models/granite_hybrid.py`, through
+#: `ops/ssd.py`), a resident flash-attention call's schedule
+#: (`flash_attention.tile_schedule`) and a streaming one's
+#: (`flash_attention.stream_schedule`; one set of gauges a `window`
+#: label, "none" for a call without one, so a step with both kinds of
+#: layer keeps both whatever the order they are traced in).
+_STATIC = {
+    "ingraph_exchange": {
+        "leaves": _gauge(
+            "bps_ingraph_exchange_leaves",
+            "non-empty leaves of the last traced in-graph exchange"),
+        "groups": _gauge(
+            "bps_ingraph_exchange_groups",
+            "collectives it issued: one per group of leaves, or one per "
+            "packed bucket"),
+        # what went through slice and concatenate into flat buckets: the
+        # tree's whole size when a compressor or the hierarchical
+        # reduce-scatter needs buckets as vectors
+        "packed_bytes": _gauge(
+            "bps_ingraph_exchange_packed_bytes",
+            "bytes it packed into flat buckets (0 = leaves summed in "
+            "their own shapes)"),
+    },
+    "ssd_scan": {
+        "layers": _gauge(
+            "bps_ssd_scan_layers",
+            "layers of the last traced step that run the chunked "
+            "state-space scan"),
+        "chunk": _gauge(
+            "bps_ssd_chunk", "positions a chunk of that scan holds"),
+        "state_bytes": _gauge(
+            "bps_ssd_state_bytes",
+            "bytes of chunk states ONE such layer keeps from its forward "
+            "pass for its backward pass"),
+    },
+    "flash_tiles": {
+        "tiles_computed": _gauge(
+            "bps_flash_tiles_computed",
+            "tiles of logits one head's forward kernel computes in the "
+            "last traced flash call"),
+        "tiles_masked": _gauge(
+            "bps_flash_tiles_masked",
+            "of those, the tiles it masks: the ones the causal diagonal "
+            "or a window's edge crosses"),
+        "pairs_needed_share": _gauge(
+            "bps_flash_pairs_needed_share",
+            "(query, key) pairs the mask leaves over the pairs the "
+            "computed tiles hold", float),
+    },
+    "flash_stream": {
+        "steps": _gauge(
+            "bps_flash_stream_steps",
+            "grid steps one head's forward kernel walks in the last "
+            "traced streaming flash call of this window"),
+        "live": _gauge(
+            "bps_flash_stream_live",
+            "of those, the steps that compute a tile of logits"),
+        "fetched": _gauge(
+            "bps_flash_stream_fetched",
+            "tiles of K (and as many of V) copied in: a step whose tile "
+            "is the one before it copies nothing"),
+    },
+}
+
+
+def record_static(family: str, labels: Optional[Dict[str, str]] = None,
+                  **gauges) -> None:
+    """Sets the gauges of one `_STATIC` family: the form of the last
+    call of its kind that was TRACED, written once per trace and not per
+    step (the call sits in the Python body of a jitted step)."""
     reg = get_registry()
-    reg.gauge("bps_ingraph_exchange_leaves",
-              help="non-empty leaves of the last traced in-graph exchange"
-              ).set(int(leaves))
-    reg.gauge("bps_ingraph_exchange_groups",
-              help="collectives it issued: one per group of leaves, or "
-                   "one per packed bucket").set(int(groups))
-    reg.gauge("bps_ingraph_exchange_packed_bytes",
-              help="bytes it packed into flat buckets (0 = leaves summed "
-                   "in their own shapes)").set(int(packed_bytes))
-
-
-# ---------------------------------------------------------------------------
-# State-space scan (ops/ssd.py, through models/granite_hybrid.py)
-# ---------------------------------------------------------------------------
-def record_ssd_scan(layers: int, chunk: int, state_bytes: int) -> None:
-    """The form of the state-space scans of the last model step that was
-    traced, written once per trace like `record_ingraph_exchange`."""
-    reg = get_registry()
-    reg.gauge("bps_ssd_scan_layers",
-              help="layers of the last traced step that run the chunked "
-                   "state-space scan").set(int(layers))
-    reg.gauge("bps_ssd_chunk",
-              help="positions a chunk of that scan holds").set(int(chunk))
-    reg.gauge("bps_ssd_state_bytes",
-              help="bytes of chunk states ONE such layer keeps from its "
-                   "forward pass for its backward pass").set(int(state_bytes))
-
-
-# ---------------------------------------------------------------------------
-# Flash attention's schedule (ops/flash_attention.py, the resident path)
-# ---------------------------------------------------------------------------
-def record_flash_tiles(tiles_computed: int, tiles_masked: int,
-                       pairs_needed_share: float) -> None:
-    """What the last resident flash-attention call that was traced does
-    to its [S, S] square (`flash_attention.tile_schedule`), written once
-    per trace like `record_ingraph_exchange`."""
-    reg = get_registry()
-    reg.gauge("bps_flash_tiles_computed",
-              help="tiles of logits one head's forward kernel computes "
-                   "in the last traced flash call").set(int(tiles_computed))
-    reg.gauge("bps_flash_tiles_masked",
-              help="of those, the tiles it masks: the ones the causal "
-                   "diagonal or a window's edge crosses"
-              ).set(int(tiles_masked))
-    reg.gauge("bps_flash_pairs_needed_share",
-              help="(query, key) pairs the mask leaves over the pairs "
-                   "the computed tiles hold").set(float(pairs_needed_share))
-
-
-def record_flash_stream(steps: int, live: int, fetched: int,
-                        window: Optional[int] = None) -> None:
-    """What one head of a STREAMING flash-attention call does
-    (`flash_attention.stream_schedule`, the forward kernel's grid),
-    written when a call is traced, like `record_flash_tiles`.  One set of
-    gauges a `window` (label `window`, "none" for a call without one): the
-    last traced call of each kind, whatever the order the layers are
-    traced in."""
-    reg = get_registry()
-    labels = {"window": "none" if window is None else str(int(window))}
-    reg.gauge("bps_flash_stream_steps", labels=labels,
-              help="grid steps one head's forward kernel walks in the "
-                   "last traced streaming flash call of this window"
-              ).set(int(steps))
-    reg.gauge("bps_flash_stream_live", labels=labels,
-              help="of those, the steps that compute a tile of logits"
-              ).set(int(live))
-    reg.gauge("bps_flash_stream_fetched", labels=labels,
-              help="tiles of K (and as many of V) copied in: a step whose "
-                   "tile is the one before it copies nothing"
-              ).set(int(fetched))
+    for key, value in gauges.items():
+        name, help, cast = _STATIC[family][key]
+        reg.gauge(name, help=help, labels=labels).set(cast(value))
 
 
 # ---------------------------------------------------------------------------

@@ -248,26 +248,30 @@ def _attention(x, lp, cfg: AfmoeConfig, kind: str):
     H, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     norm = functools.partial(_rms_norm, bias=None, eps=cfg.rms_norm_eps)
     with jax.named_scope(f"afmoe.attn.{kind}"):
-        a = norm(x, lp["input_ln"])
-        qkvg = jnp.einsum("bsd,de->bse", a, lp["qkvg_w"].astype(dt))
-        q, k, v, g = jnp.split(
-            qkvg, [H * Dh, (H + Hkv) * Dh, (H + 2 * Hkv) * Dh], axis=-1)
+        with jax.named_scope(".qkv"):
+            a = norm(x, lp["input_ln"])
+            qkvg = jnp.einsum("bsd,de->bse", a, lp["qkvg_w"].astype(dt))
+            q, k, v, g = jnp.split(
+                qkvg, [H * Dh, (H + Hkv) * Dh, (H + 2 * Hkv) * Dh], axis=-1)
 
-        def heads(t):
-            return t.reshape(B, S, -1, Dh).transpose(0, 2, 1, 3)
-        q = norm(heads(q), lp["q_norm"])
-        k = norm(heads(k), lp["k_norm"])
-        v = heads(v)
-        if kind == SLIDING:
-            q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
-        if Hkv != H:
-            k = jnp.repeat(k, H // Hkv, axis=1)
-            v = jnp.repeat(v, H // Hkv, axis=1)
+            def heads(t):
+                return t.reshape(B, S, -1, Dh).transpose(0, 2, 1, 3)
+            q = norm(heads(q), lp["q_norm"])
+            k = norm(heads(k), lp["k_norm"])
+            v = heads(v)
+            if kind == SLIDING:
+                q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+            if Hkv != H:
+                k = jnp.repeat(k, H // Hkv, axis=1)
+                v = jnp.repeat(v, H // Hkv, axis=1)
+        # the kernels and the transpose after them stay the half's own:
+        # an unnamed call is called after the innermost scope around it
         ctx = _attn_fn(cfg, kind)(q, k, v)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H * Dh)
-        o = jnp.einsum("bse,ed->bsd", _gated(ctx, g),
-                       lp["attn_out_w"].astype(dt))
-        return x + norm(o, lp["post_attn_ln"])
+        with jax.named_scope(".out"):
+            o = jnp.einsum("bse,ed->bsd", _gated(ctx, g),
+                           lp["attn_out_w"].astype(dt))
+            return x + norm(o, lp["post_attn_ln"])
 
 
 def _feed_forward(x, lp, sel, cfg: AfmoeConfig, is_moe: bool):
@@ -276,20 +280,20 @@ def _feed_forward(x, lp, sel, cfg: AfmoeConfig, is_moe: bool):
     dt = cfg.dtype
     B, S, D = x.shape
     norm = functools.partial(_rms_norm, bias=None, eps=cfg.rms_norm_eps)
-    m = norm(x, lp["pre_mlp_ln"])
-    routing = None
     if not is_moe:
         with jax.named_scope("afmoe.mlp"):
-            f = _swiglu(m, lp, "mlp_", dt)
-    else:
-        with jax.named_scope("afmoe.moe"):
-            experts = {n: lp["expert_" + n]
-                       for n in ("gate_w", "up_w", "down_w")}
-            routed, routing = dropless_moe.held_experts(
-                m.reshape(B * S, D), lp["router_w"], experts, cfg.moe,
-                expert_bias=lp.get("expert_bias"), sel=sel)
-            f = _swiglu(m, lp, "shared_", dt) + routed.reshape(B, S, D)
-    return x + norm(f, lp["post_mlp_ln"]), routing
+            f = _swiglu(norm(x, lp["pre_mlp_ln"]), lp, "mlp_", dt)
+            return x + norm(f, lp["post_mlp_ln"]), None
+    with jax.named_scope("afmoe.moe"):
+        m = norm(x, lp["pre_mlp_ln"])
+        experts = {n: lp["expert_" + n] for n in ("gate_w", "up_w", "down_w")}
+        routed, routing = dropless_moe.held_experts(
+            m.reshape(B * S, D), lp["router_w"], experts, cfg.moe,
+            expert_bias=lp.get("expert_bias"), sel=sel)
+        with jax.named_scope(".shared"):
+            shared = _swiglu(m, lp, "shared_", dt)
+        f = shared + routed.reshape(B, S, D)
+        return x + norm(f, lp["post_mlp_ln"]), routing
 
 
 def _layer(x, lp, sel, cfg: AfmoeConfig, kind: str, is_moe: bool):
@@ -321,15 +325,16 @@ def _unstack(group: dict, n: int):
 
 
 def _embed(params, tokens, cfg: AfmoeConfig):
-    x = params["embed"].astype(cfg.dtype)[tokens - cfg.vocab_start]
-    if cfg.mup_enabled:
-        x = x * jnp.asarray(math.sqrt(cfg.hidden_size), cfg.dtype)
-    return x
+    with jax.named_scope("afmoe.embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens - cfg.vocab_start]
+        if cfg.mup_enabled:
+            x = x * jnp.asarray(math.sqrt(cfg.hidden_size), cfg.dtype)
+        return x
 
 
 def forward_hidden(params: PyTree, tokens: jax.Array, cfg,
                    sel=None, with_routing: bool = False, layer=_layer,
-                   embed=_embed):
+                   embed=_embed, family: str = "afmoe"):
     """tokens [B, S] int32 (ids of the held slice) -> the final hidden
     states [B, S, D], after the last norm.
 
@@ -342,7 +347,9 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg,
     `layer` and `embed` are what a decoder of another family puts in
     place of this one's (`models/mellum.py`): the period scan, the remat
     and the held slice are the same machinery for both, and `cfg` then
-    that family's, with the fields `_stack_plan` and `_remat` read."""
+    that family's, with the fields `_stack_plan` and `_remat` read, and
+    `family` the prefix of its scopes (`<family>.head` holds the last norm
+    here and the head in `loss_fn`)."""
     x = embed(params, tokens, cfg)
     routings = None
     for key, kinds, periods in _stack_plan(cfg):
@@ -371,7 +378,8 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg,
         if is_moe and with_routing:
             routings = jax.tree.map(
                 lambda a: a.reshape(periods * p, *a.shape[2:]), r)
-    x = _rms_norm(x, params["final_ln"], None, eps=cfg.rms_norm_eps)
+    with jax.named_scope(family + ".head"):
+        x = _rms_norm(x, params["final_ln"], None, eps=cfg.rms_norm_eps)
     return (x, routings) if with_routing else x
 
 
@@ -383,18 +391,20 @@ def head_logits(x: jax.Array, head: jax.Array) -> jax.Array:
 
 
 def loss_fn(params: PyTree, batch, cfg, sel=None,
-            hidden=forward_hidden) -> jax.Array:
+            hidden=forward_hidden, family: str = "afmoe") -> jax.Array:
     """Mean next-token cross-entropy over the held slice of the vocabulary.
     batch = (tokens [B, S], targets [B, S]).  `hidden` is another
-    family's `forward_hidden`."""
+    family's `forward_hidden`, `family` the prefix of its scopes."""
     tokens, targets = batch
     x = hidden(params, tokens, cfg, sel=sel)
-    targets = targets - cfg.vocab_start
-    if cfg.ce_chunk_rows:
-        return fused_nll_sum(x, params["head"], targets,
-                             cfg.ce_chunk_rows) / targets.size
-    logp = jax.nn.log_softmax(head_logits(x, params["head"]), axis=-1)
-    return -jnp.take_along_axis(logp, targets[..., None], axis=-1).mean()
+    with jax.named_scope(family + ".head"):
+        targets = targets - cfg.vocab_start
+        if cfg.ce_chunk_rows:
+            return fused_nll_sum(x, params["head"], targets,
+                                 cfg.ce_chunk_rows) / targets.size
+        logp = jax.nn.log_softmax(head_logits(x, params["head"]), axis=-1)
+        return -jnp.take_along_axis(logp, targets[..., None],
+                                    axis=-1).mean()
 
 
 def routing(params: PyTree, tokens: jax.Array, cfg, hidden=forward_hidden):

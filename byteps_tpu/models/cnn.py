@@ -78,20 +78,23 @@ class ResNet(nn.Module):
         conv = partial(nn.Conv, use_bias=False, dtype=self.dtype)
         norm = partial(nn.BatchNorm, use_running_average=not train,
                        momentum=0.9, epsilon=1e-5, dtype=self.dtype)
-        x = conv(self.num_filters, (7, 7), (2, 2),
-                 padding=[(3, 3), (3, 3)], name="conv_init")(x.astype(self.dtype))
-        x = norm(name="bn_init")(x)
-        x = nn.relu(x)
-        x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
-        for i, block_size in enumerate(self.stage_sizes):
-            for j in range(block_size):
-                strides = (2, 2) if i > 0 and j == 0 else (1, 1)
-                x = self.block_cls(self.num_filters * 2 ** i,
-                                   conv=conv, norm=norm, act=nn.relu,
-                                   strides=strides)(x)
-        x = jnp.mean(x, axis=(1, 2))
-        x = nn.Dense(self.num_classes, dtype=jnp.float32)(x)
-        return x
+        with jax.named_scope("cnn.features"):
+            x = conv(self.num_filters, (7, 7), (2, 2),
+                     padding=[(3, 3), (3, 3)],
+                     name="conv_init")(x.astype(self.dtype))
+            x = norm(name="bn_init")(x)
+            x = nn.relu(x)
+            x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
+            for i, block_size in enumerate(self.stage_sizes):
+                for j in range(block_size):
+                    strides = (2, 2) if i > 0 and j == 0 else (1, 1)
+                    x = self.block_cls(self.num_filters * 2 ** i,
+                                       conv=conv, norm=norm, act=nn.relu,
+                                       strides=strides)(x)
+        with jax.named_scope("cnn.classifier"):
+            x = jnp.mean(x, axis=(1, 2))
+            x = nn.Dense(self.num_classes, dtype=jnp.float32)(x)
+            return x
 
 
 ResNet18 = partial(ResNet, stage_sizes=[2, 2, 2, 2], block_cls=ResNetBlock)
@@ -110,21 +113,23 @@ class VGG(nn.Module):
 
     @nn.compact
     def __call__(self, x, train: bool = True):
-        x = x.astype(self.dtype)
-        for v in self.cfg:
-            if v == "M":
-                x = nn.max_pool(x, (2, 2), strides=(2, 2))
-            else:
-                x = nn.Conv(v, (3, 3), padding=[(1, 1), (1, 1)],
-                            dtype=self.dtype)(x)
-                x = nn.relu(x)
-        x = x.reshape((x.shape[0], -1))
-        x = nn.Dense(4096, dtype=self.dtype)(x)
-        x = nn.relu(x)
-        x = nn.Dense(4096, dtype=self.dtype)(x)
-        x = nn.relu(x)
-        x = nn.Dense(self.num_classes, dtype=jnp.float32)(x)
-        return x
+        with jax.named_scope("cnn.features"):
+            x = x.astype(self.dtype)
+            for v in self.cfg:
+                if v == "M":
+                    x = nn.max_pool(x, (2, 2), strides=(2, 2))
+                else:
+                    x = nn.Conv(v, (3, 3), padding=[(1, 1), (1, 1)],
+                                dtype=self.dtype)(x)
+                    x = nn.relu(x)
+        with jax.named_scope("cnn.classifier"):
+            x = x.reshape((x.shape[0], -1))
+            x = nn.Dense(4096, dtype=self.dtype)(x)
+            x = nn.relu(x)
+            x = nn.Dense(4096, dtype=self.dtype)(x)
+            x = nn.relu(x)
+            x = nn.Dense(self.num_classes, dtype=jnp.float32)(x)
+            return x
 
 
 _VGG16_CFG = [64, 64, "M", 128, 128, "M", 256, 256, 256, "M",
@@ -162,6 +167,8 @@ def cnn_loss_fn(model: nn.Module):
     def loss(variables, batch):
         images, labels = batch
         logits = model.apply(variables, images, train=False)
-        logp = jax.nn.log_softmax(logits)
-        return -jnp.take_along_axis(logp, labels[:, None], axis=-1).mean()
+        with jax.named_scope("cnn.head"):
+            logp = jax.nn.log_softmax(logits)
+            return -jnp.take_along_axis(logp, labels[:, None],
+                                        axis=-1).mean()
     return loss

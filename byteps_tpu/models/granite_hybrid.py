@@ -218,17 +218,20 @@ def _gate_norm(y, z, scale, cfg):
     return _norm(y * jax.nn.silu(z), scale, cfg)
 
 
-def _mamba(u, lp, cfg: GraniteHybridConfig):
-    """The Mamba-2 mixer.  u [B, S, D], normed -> [B, S, D]."""
+def _mamba(x, lp, cfg: GraniteHybridConfig):
+    """The Mamba-2 mixer, its input norm included.  x [B, S, D] ->
+    [B, S, D]."""
     dt = cfg.dtype
-    B, S, _ = u.shape
+    B, S, _ = x.shape
     H, P = cfg.mamba_n_heads, cfg.mamba_d_head
     G, N, I = cfg.mamba_n_groups, cfg.mamba_d_state, cfg.d_inner
-    zxbcdt = jnp.einsum("bsd,de->bse", u, lp["in_proj_w"].astype(dt))
-    z, xbc, raw = jnp.split(zxbcdt, [I, I + cfg.conv_dim], axis=-1)
+    with jax.named_scope("granite.mamba.in_proj"):
+        u = _norm(x, lp["input_ln"], cfg)
+        zxbcdt = jnp.einsum("bsd,de->bse", u, lp["in_proj_w"].astype(dt))
+        z, xbc, raw = jnp.split(zxbcdt, [I, I + cfg.conv_dim], axis=-1)
     with jax.named_scope("granite.mamba.conv"):
         xbc = _conv(xbc, lp)
-    x, bm, cm = jnp.split(xbc, [I, I + G * N], axis=-1)
+        x, bm, cm = jnp.split(xbc, [I, I + G * N], axis=-1)
     with jax.named_scope("granite.mamba.scan"):
         y = ssd.ssd_scan(
             x.reshape(B, S, H, P), _step_size(raw, lp["dt_bias"]),
@@ -237,7 +240,8 @@ def _mamba(u, lp, cfg: GraniteHybridConfig):
             chunk=min(cfg.mamba_chunk_size, S))
     with jax.named_scope("granite.mamba.gate_norm"):
         y = _gate_norm(y.reshape(B, S, I), z, lp["gate_norm"], cfg)
-    return jnp.einsum("bse,ed->bsd", y, lp["out_proj_w"].astype(dt))
+    with jax.named_scope("granite.mamba.out_proj"):
+        return jnp.einsum("bse,ed->bsd", y, lp["out_proj_w"].astype(dt))
 
 
 def _attend(q, k, v, cfg: GraniteHybridConfig):
@@ -249,27 +253,36 @@ def _attend(q, k, v, cfg: GraniteHybridConfig):
     return flash_attention_fn(q * jnp.asarray(scale, q.dtype), k, v, True)
 
 
-def _attention(u, lp, cfg: GraniteHybridConfig):
+def _attention(x, lp, cfg: GraniteHybridConfig):
+    """The attention mixer, its input norm included.  x [B, S, D] ->
+    [B, S, D]."""
     dt = cfg.dtype
-    B, S, _ = u.shape
+    B, S, _ = x.shape
     H, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     with jax.named_scope("granite.attn"):
-        qkv = jnp.einsum("bsd,de->bse", u, lp["qkv_w"].astype(dt))
-        q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
+        with jax.named_scope(".qkv"):
+            u = _norm(x, lp["input_ln"], cfg)
+            qkv = jnp.einsum("bsd,de->bse", u, lp["qkv_w"].astype(dt))
+            q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
 
-        def heads(t):
-            return t.reshape(B, S, -1, Dh).transpose(0, 2, 1, 3)
-        q, k, v = heads(q), heads(k), heads(v)
-        if Hkv != H:
-            k = jnp.repeat(k, H // Hkv, axis=1)
-            v = jnp.repeat(v, H // Hkv, axis=1)
+            def heads(t):
+                return t.reshape(B, S, -1, Dh).transpose(0, 2, 1, 3)
+            q, k, v = heads(q), heads(k), heads(v)
+            if Hkv != H:
+                k = jnp.repeat(k, H // Hkv, axis=1)
+                v = jnp.repeat(v, H // Hkv, axis=1)
+        # the kernels and the transpose after them stay the half's own
         ctx = _attend(q, k, v, cfg)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H * Dh)
-        return jnp.einsum("bse,ed->bsd", ctx, lp["attn_out_w"].astype(dt))
+        with jax.named_scope(".out"):
+            return jnp.einsum("bse,ed->bsd", ctx,
+                              lp["attn_out_w"].astype(dt))
 
 
-def _mlp(u, lp, cfg: GraniteHybridConfig):
+def _mlp(x, lp, cfg: GraniteHybridConfig):
+    """The shared SwiGLU, its input norm included."""
     dt = cfg.dtype
+    u = _norm(x, lp["post_ln"], cfg)
     ab = jnp.einsum("bsd,df->bsf", u, lp["mlp_in_w"].astype(dt))
     a, b = jnp.split(ab, 2, axis=-1)
     return jnp.einsum("bsf,fd->bsd", jax.nn.silu(a) * b,
@@ -280,21 +293,24 @@ def _layer(x, lp, cfg: GraniteHybridConfig, kind: str):
     """One layer.  x [B, S, D] -> [B, S, D]."""
     mixer = _mamba if kind == MAMBA else _attention
     r = jnp.asarray(cfg.residual_multiplier, x.dtype)
-    x = x + r * mixer(_norm(x, lp["input_ln"], cfg), lp, cfg)
-    return x + r * _mlp(_norm(x, lp["post_ln"], cfg), lp, cfg)
+    x = x + r * mixer(x, lp, cfg)
+    with jax.named_scope("granite.mlp"):
+        return x + r * _mlp(x, lp, cfg)
 
 
 def _embed(params, tokens, cfg: GraniteHybridConfig):
-    x = params["embed"].astype(cfg.dtype)[tokens - cfg.vocab_start]
-    return x * jnp.asarray(cfg.embedding_multiplier, cfg.dtype)
+    with jax.named_scope("granite.embed"):
+        x = params["embed"].astype(cfg.dtype)[tokens - cfg.vocab_start]
+        return x * jnp.asarray(cfg.embedding_multiplier, cfg.dtype)
 
 
 def _record_scan(cfg: GraniteHybridConfig, batch: int, seq_len: int) -> None:
     chunk = min(cfg.mamba_chunk_size, seq_len)
-    telemetry.record_ssd_scan(
-        cfg.count(MAMBA), chunk,
-        ssd.state_bytes(batch, cfg.mamba_n_heads, seq_len, cfg.mamba_d_head,
-                        cfg.mamba_d_state, chunk))
+    telemetry.record_static(
+        "ssd_scan", layers=cfg.count(MAMBA), chunk=chunk,
+        state_bytes=ssd.state_bytes(batch, cfg.mamba_n_heads, seq_len,
+                                    cfg.mamba_d_head, cfg.mamba_d_state,
+                                    chunk))
 
 
 def forward_hidden(params: PyTree, tokens: jax.Array,
@@ -314,7 +330,8 @@ def forward_hidden(params: PyTree, tokens: jax.Array,
         return x, None
 
     x, _ = lax.scan(period, x, params["layers"])
-    return _norm(x, params["final_ln"], cfg)
+    with jax.named_scope("granite.head"):
+        return _norm(x, params["final_ln"], cfg)
 
 
 def head_logits(x: jax.Array, embed: jax.Array,
@@ -331,10 +348,11 @@ def loss_fn(params: PyTree, batch, cfg: GraniteHybridConfig) -> jax.Array:
     batch = (tokens [B, S], targets [B, S])."""
     tokens, targets = batch
     x = forward_hidden(params, tokens, cfg)
-    targets = targets - cfg.vocab_start
-    scaled = x / jnp.asarray(cfg.logits_scaling, x.dtype)
-    return fused_nll_sum(scaled, params["embed"], targets,
-                         cfg.ce_chunk_rows) / targets.size
+    with jax.named_scope("granite.head"):
+        targets = targets - cfg.vocab_start
+        scaled = x / jnp.asarray(cfg.logits_scaling, x.dtype)
+        return fused_nll_sum(scaled, params["embed"], targets,
+                             cfg.ce_chunk_rows) / targets.size
 
 
 def synthetic_batch(rng: jax.Array, batch_size: int, seq_len: int,

@@ -216,14 +216,17 @@ def _attention(x, lp, cfg: MellumConfig, kind: str):
     B, S, D = x.shape
     H, Hkv = cfg.num_heads, cfg.num_kv_heads
     with jax.named_scope(f"mellum.attn.{kind}"):
-        q, k, v = _qkv(x, lp, cfg, kind)
-        if Hkv != H:
-            k = jnp.repeat(k, H // Hkv, axis=1)
-            v = jnp.repeat(v, H // Hkv, axis=1)
+        with jax.named_scope(".qkv"):
+            q, k, v = _qkv(x, lp, cfg, kind)
+            if Hkv != H:
+                k = jnp.repeat(k, H // Hkv, axis=1)
+                v = jnp.repeat(v, H // Hkv, axis=1)
+        # the kernels and the transpose after them stay the half's own
         ctx = afmoe._attn_fn(cfg, kind)(q, k, v)
         ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, -1)
-        return x + jnp.einsum("bse,ed->bsd", ctx,
-                              lp["attn_out_w"].astype(cfg.dtype))
+        with jax.named_scope(".out"):
+            return x + jnp.einsum("bse,ed->bsd", ctx,
+                                  lp["attn_out_w"].astype(cfg.dtype))
 
 
 def _experts_input(x, lp, cfg: MellumConfig):
@@ -235,8 +238,8 @@ def _experts_input(x, lp, cfg: MellumConfig):
 
 def _experts(x, lp, sel, cfg: MellumConfig):
     """The other half: x -> `(x + held experts(norm(x)), routing)`."""
-    m = _experts_input(x, lp, cfg)
     with jax.named_scope("mellum.moe"):
+        m = _experts_input(x, lp, cfg)
         experts = {n: lp["expert_" + n] for n in ("gate_w", "up_w", "down_w")}
         routed, routing = dropless_moe.held_experts(
             m, lp["router_w"], experts, cfg.moe, sel=sel)
@@ -250,11 +253,13 @@ def _layer(x, lp, sel, cfg: MellumConfig, kind: str, is_moe: bool = True):
 
 
 def _embed(params, tokens, cfg: MellumConfig):
-    return params["embed"].astype(cfg.dtype)[tokens - cfg.vocab_start]
+    with jax.named_scope("mellum.embed"):
+        return params["embed"].astype(cfg.dtype)[tokens - cfg.vocab_start]
 
 
 forward_hidden = functools.partial(afmoe.forward_hidden, layer=_layer,
-                                   embed=_embed)
-loss_fn = functools.partial(afmoe.loss_fn, hidden=forward_hidden)
+                                   embed=_embed, family="mellum")
+loss_fn = functools.partial(afmoe.loss_fn, hidden=forward_hidden,
+                            family="mellum")
 routing = functools.partial(afmoe.routing, hidden=forward_hidden)
 synthetic_batch = afmoe.synthetic_batch

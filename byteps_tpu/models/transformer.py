@@ -457,43 +457,51 @@ def _block(x, lp, cfg: TransformerConfig, attn_fn):
         b = bias(name)
         return t if b is None else t + b
 
-    h = norm(x, lp["ln1_scale"], bias("ln1_bias"))
-    qkv = _ckpt_name(
-        add_bias(jnp.einsum("bsd,de->bse", h, lp["qkv_w"].astype(dt)),
-                 "qkv_b"), "qkv")
-    q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
+    with jax.named_scope("transformer.attn"):
+        # a child's name is relative, a leading ".": the scope map
+        # (`bps.get_step_scopes()`) reads `transformer.attn/qkv`
+        with jax.named_scope(".qkv"):
+            h = norm(x, lp["ln1_scale"], bias("ln1_bias"))
+            qkv = _ckpt_name(
+                add_bias(jnp.einsum("bsd,de->bse", h,
+                                    lp["qkv_w"].astype(dt)), "qkv_b"), "qkv")
+            q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
 
-    def heads(t):
-        return t.reshape(B, S, -1, Dh).transpose(0, 2, 1, 3)
-    q, k, v = heads(q), heads(k), heads(v)
-    if cfg.pos == "rope":
-        q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
-    if Hkv != H:
-        # GQA: each query-head group shares one kv head — expand for the
-        # attention kernel (the bandwidth saving is in params/KV-cache,
-        # not this training-time broadcast).
-        k = jnp.repeat(k, H // Hkv, axis=1)
-        v = jnp.repeat(v, H // Hkv, axis=1)
-    attn = attn_fn(q, k, v, cfg.causal)
-    attn = _ckpt_name(attn.transpose(0, 2, 1, 3).reshape(B, S, -1),
-                      "attn_ctx")
-    attn = _ckpt_name(add_bias(
-        jnp.einsum("bse,ed->bsd", attn, lp["attn_out_w"].astype(dt)),
-        "attn_out_b"), "attn_proj")
-    x = x + attn
+            def heads(t):
+                return t.reshape(B, S, -1, Dh).transpose(0, 2, 1, 3)
+            q, k, v = heads(q), heads(k), heads(v)
+            if cfg.pos == "rope":
+                q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+            if Hkv != H:
+                # GQA: each query-head group shares one kv head — expand
+                # for the attention kernel (the bandwidth saving is in
+                # params/KV-cache, not this training-time broadcast).
+                k = jnp.repeat(k, H // Hkv, axis=1)
+                v = jnp.repeat(v, H // Hkv, axis=1)
+        # the kernels and the transpose after them stay the half's own:
+        # the trace calls an unnamed call after the innermost scope
+        attn = attn_fn(q, k, v, cfg.causal)
+        attn = _ckpt_name(attn.transpose(0, 2, 1, 3).reshape(B, S, -1),
+                          "attn_ctx")
+        with jax.named_scope(".out"):
+            attn = _ckpt_name(add_bias(
+                jnp.einsum("bse,ed->bsd", attn, lp["attn_out_w"].astype(dt)),
+                "attn_out_b"), "attn_proj")
+            x = x + attn
 
-    h = norm(x, lp["ln2_scale"], bias("ln2_bias"))
-    up = add_bias(jnp.einsum("bsd,df->bsf", h, lp["mlp_in_w"].astype(dt)),
-                  "mlp_in_b")
-    if cfg.act == "swiglu":
-        gate = jnp.einsum("bsd,df->bsf", h, lp["mlp_gate_w"].astype(dt))
-        h = jax.nn.silu(gate) * up
-    else:
-        h = jax.nn.gelu(up)
-    h = _ckpt_name(
-        add_bias(jnp.einsum("bsf,fd->bsd", h, lp["mlp_out_w"].astype(dt)),
-                 "mlp_out_b"), "ffn_out")
-    return x + h
+    with jax.named_scope("transformer.mlp"):
+        h = norm(x, lp["ln2_scale"], bias("ln2_bias"))
+        up = add_bias(jnp.einsum("bsd,df->bsf", h, lp["mlp_in_w"].astype(dt)),
+                      "mlp_in_b")
+        if cfg.act == "swiglu":
+            gate = jnp.einsum("bsd,df->bsf", h, lp["mlp_gate_w"].astype(dt))
+            h = jax.nn.silu(gate) * up
+        else:
+            h = jax.nn.gelu(up)
+        h = _ckpt_name(
+            add_bias(jnp.einsum("bsf,fd->bsd", h, lp["mlp_out_w"].astype(dt)),
+                     "mlp_out_b"), "ffn_out")
+        return x + h
 
 
 def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
@@ -523,9 +531,10 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
                                         block_k=cfg.attn_block_k)
     dt = cfg.dtype
     B, S = tokens.shape
-    x = params["embed"].astype(dt)[tokens]
-    if cfg.pos == "learned":
-        x = x + params["pos_embed"].astype(dt)[:S]
+    with jax.named_scope("transformer.embed"):
+        x = params["embed"].astype(dt)[tokens]
+        if cfg.pos == "learned":
+            x = x + params["pos_embed"].astype(dt)[:S]
 
     def body(carry, lp):
         y = _block(carry, lp, cfg, attn_fn)
@@ -556,7 +565,9 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
     else:
         step = body
     x, _ = lax.scan(step, x, params["layers"], unroll=cfg.scan_unroll)
-    return _NORMS[cfg.norm](x, params["ln_f_scale"], params.get("ln_f_bias"))
+    with jax.named_scope("transformer.head"):
+        return _NORMS[cfg.norm](x, params["ln_f_scale"],
+                                params.get("ln_f_bias"))
 
 
 def forward(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
@@ -569,9 +580,10 @@ def forward(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
     multi-pass emulation on TPU.
     """
     x = forward_hidden(params, tokens, cfg, attn_fn=attn_fn)
-    return jnp.einsum("bsd,vd->bsv", x,
-                      params["embed"].astype(x.dtype),
-                      preferred_element_type=jnp.float32)
+    with jax.named_scope("transformer.head"):
+        return jnp.einsum("bsd,vd->bsv", x,
+                          params["embed"].astype(x.dtype),
+                          preferred_element_type=jnp.float32)
 
 
 def fused_nll_sum(x: jax.Array, embed: jax.Array, targets: jax.Array,
@@ -637,12 +649,14 @@ def loss_fn(params: PyTree, batch: Tuple[jax.Array, jax.Array],
     tokens, targets = batch
     if cfg.ce_chunk_rows:
         x = forward_hidden(params, tokens, cfg, attn_fn=attn_fn)
-        return fused_nll_sum(x, params["embed"], targets,
-                             cfg.ce_chunk_rows) / targets.size
+        with jax.named_scope("transformer.head"):
+            return fused_nll_sum(x, params["embed"], targets,
+                                 cfg.ce_chunk_rows) / targets.size
     logits = forward(params, tokens, cfg, attn_fn=attn_fn)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return nll.mean()
+    with jax.named_scope("transformer.head"):
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        return nll.mean()
 
 
 def num_params(params: PyTree) -> int:

@@ -168,7 +168,7 @@ def bucketed_tree_all_reduce(
 
     Either way the sums are issued in the plan's order, the last-declared
     leaves first.  What was built is written to the metrics registry when
-    the step is traced (`telemetry.record_ingraph_exchange`).
+    the step is traced (`telemetry.record_static`).
     """
     if is_local() and bucket_transform is None:
         # Single-device: the sum over one worker is the identity and the
@@ -198,7 +198,8 @@ def bucketed_tree_all_reduce(
     else:
         reduced = _reduce_packed(wire, plan, bucket_transform, denom)
         groups, packed_bytes = plan.num_buckets(), sum(sizes) * itemsize
-    telemetry.record_ingraph_exchange(len(leaves), groups, packed_bytes)
+    telemetry.record_static("ingraph_exchange", leaves=len(leaves),
+                            groups=groups, packed_bytes=packed_bytes)
     out_leaves = list(all_leaves)
     for i, leaf, r in zip(nonempty_idx, leaves, reduced):
         out_leaves[i] = r.astype(leaf.dtype)

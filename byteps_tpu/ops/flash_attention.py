@@ -914,11 +914,13 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     streaming = _use_streaming(q, streaming)
     if streaming:
-        telemetry.record_flash_stream(
-            **stream_schedule(s, block_q, block_k, causal, window),
-            window=window)
+        telemetry.record_static(
+            "flash_stream",
+            labels={"window": "none" if window is None else str(window)},
+            **stream_schedule(s, block_q, block_k, causal, window))
     else:
-        telemetry.record_flash_tiles(
+        telemetry.record_static(
+            "flash_tiles",
             **tile_schedule(s, block_q, block_k, causal, window))
     out, lse = _fwd(q, k, v, scale, causal, block_q, block_k,
                     _use_interpret(interpret), streaming, window)

@@ -39,6 +39,7 @@ from ..common import devprof
 from ..common.config import get_config
 from ..ops import collectives
 from ..ops.compression import Compression, Compressor
+from ..utils import compile_cache
 
 PyTree = Any
 
@@ -295,6 +296,14 @@ def build_train_step(
         return loss_sum * inv, jax.tree.map(
             lambda g, p: (g * inv).astype(p.dtype), g_sum, params)
 
+    def _update(params, opt_state, grads):
+        # One scope for the optimizer's share of a step
+        # (`bps.get_step_scopes()`, pass "optimizer"); the exchange's
+        # `byteps.bucket<N>` scopes nest in it.
+        with jax.named_scope("byteps.optimizer"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state
+
     if mesh.devices.size == 1:
         # Single-device fast path: the reference's non-distributed mode
         # builds a queue list with no PUSH/PULL (operations.cc:429-485); here
@@ -304,29 +313,20 @@ def build_train_step(
         def _local_step(params, opt_state, batch):
             with collectives.local_mode():
                 loss, grads = _value_and_grad(params, batch)
-                updates, opt_state = optimizer.update(grads, opt_state,
-                                                      params)
-                params = optax.apply_updates(params, updates)
+                params, opt_state = _update(params, opt_state, grads)
             return params, opt_state, loss
 
-        jitted = jax.jit(_local_step, donate_argnums=donate_argnums)
+        jitted = _JittedStep(
+            jax.jit(_local_step, donate_argnums=donate_argnums))
 
         def local_call(params, opt_state, batch):
-            opt_state = _retile_comp_state(opt_state, 1)
-            # Device-plane hook (common/devprof.py): unarmed this is one
-            # None check; armed it resolves cached FLOPs pre-dispatch
-            # and syncs in step_end to record a true device step time.
-            tok = devprof.step_begin(jitted, (params, opt_state, batch))
-            out = jitted(params, opt_state, batch)
-            devprof.step_end(tok, out)
-            return out
+            return jitted(params, _retile_comp_state(opt_state, 1), batch)
 
         return local_call
 
     def _step(params, opt_state, batch):
         loss, grads = _value_and_grad(params, batch)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params, opt_state = _update(params, opt_state, grads)
         # Per-shard losses -> global mean for reporting.
         loss = jax.lax.pmean(loss, axis_name)
         return params, opt_state, loss
@@ -348,15 +348,43 @@ def build_train_step(
             sm = jax.shard_map(
                 _step, mesh=mesh, in_specs=(P(), state_specs, batch_spec),
                 out_specs=(P(), state_specs, P()), check_vma=False)
-            cache[key] = jax.jit(sm, donate_argnums=donate_argnums)
-        fn = cache[key]
-        # Device-plane hook: same contract as the single-device path.
-        tok = devprof.step_begin(fn, (params, opt_state, batch))
-        out = fn(params, opt_state, batch)
-        devprof.step_end(tok, out)
-        return out
+            cache[key] = _JittedStep(
+                jax.jit(sm, donate_argnums=donate_argnums))
+        return cache[key](params, opt_state, batch)
 
     return call
+
+
+class _JittedStep:
+    """A jitted train step as both paths of `build_train_step` call it:
+    between the device plane's hooks, compiled into cache entries of its
+    own (`compile_cache.scopes_in_key`), and remembered for the scope map
+    (`bps.get_step_scopes()`) whenever a call compiled it."""
+
+    def __init__(self, fn):
+        self.fn = fn
+        self.programs = 0       # how many the callable held when last asked
+
+    def __call__(self, params, opt_state, batch):
+        with compile_cache.scopes_in_key():
+            # Device-plane hook (common/devprof.py): unarmed this is one
+            # None check; armed it resolves cached FLOPs pre-dispatch
+            # (lowering the step, hence in here) and syncs in step_end to
+            # record a true device step time.
+            tok = devprof.step_begin(self.fn, (params, opt_state, batch))
+            out = self.fn(params, opt_state, batch)
+        # A call that compiled leaves the callable one program more (the
+        # first; on a mesh the second, whose state comes back placed
+        # otherwise than a script hands it in; a new batch shape whenever).
+        # The record takes the step's own results in place of the state it
+        # was given: they are what the next step receives.
+        count = getattr(self.fn, "_cache_size", None)   # a jitted callable's
+        programs = count() if count is not None else 1
+        if programs != self.programs:
+            self.programs = programs
+            devprof.remember_step(self.fn, (out[0], out[1], batch))
+        devprof.step_end(tok, out)
+        return out
 
 
 def _tile_state(comp: PyTree, world: int) -> PyTree:

@@ -190,23 +190,26 @@ def _plan(sel, cfg: MoEConfig, rows: int, past: int) -> _Plan:
 def _buffer(lo, x, experts, flat_w, plan: _Plan, k: int, rows: int):
     """What the pairs `[lo, lo + rows)` of the sorted list add to the
     layer's result, [T, D] float32."""
-    pair = lax.dynamic_slice_in_dim(plan.order, lo, rows)
-    live = lo + jnp.arange(rows, dtype=jnp.int32) < plan.held_rows
-    token = pair // k
-    group_sizes = jnp.clip(jnp.minimum(plan.ends, lo + rows)
-                           - jnp.maximum(plan.starts, lo), 0)
-    # On a TPU the grouped product leaves the rows past the last group
-    # as it found them, in the forward and in the backward products alike
-    # (on the CPU they are zeros): whatever is there, NaN included, must
-    # reach neither the result nor a gradient.  So the rows are masked on
-    # the way in, which masks the gradient of the gather, and on the way
-    # out BEFORE the weights are multiplied in, whose gradient is
-    # otherwise 0 * NaN.
-    dead = ~live[:, None]
-    xg = jnp.where(dead, 0, x[token])
-    y = _swiglu_grouped(xg, experts, group_sizes, x.dtype)
-    y = jnp.where(dead, 0, y).astype(jnp.float32) * flat_w[pair][:, None]
-    return jnp.zeros(x.shape, jnp.float32).at[token].add(y)
+    with jax.named_scope(".gather"):
+        pair = lax.dynamic_slice_in_dim(plan.order, lo, rows)
+        live = lo + jnp.arange(rows, dtype=jnp.int32) < plan.held_rows
+        token = pair // k
+        group_sizes = jnp.clip(jnp.minimum(plan.ends, lo + rows)
+                               - jnp.maximum(plan.starts, lo), 0)
+        # On a TPU the grouped product leaves the rows past the last group
+        # as it found them, in the forward and in the backward products
+        # alike (on the CPU they are zeros): whatever is there, NaN
+        # included, must reach neither the result nor a gradient.  So the
+        # rows are masked on the way in, which masks the gradient of the
+        # gather, and on the way out BEFORE the weights are multiplied in,
+        # whose gradient is otherwise 0 * NaN.
+        dead = ~live[:, None]
+        xg = jnp.where(dead, 0, x[token])
+    with jax.named_scope(".grouped"):
+        y = _swiglu_grouped(xg, experts, group_sizes, x.dtype)
+    with jax.named_scope(".scatter"):
+        y = jnp.where(dead, 0, y).astype(jnp.float32) * flat_w[pair][:, None]
+        return jnp.zeros(x.shape, jnp.float32).at[token].add(y)
 
 
 def _past_buffers(rows: int, past: int, plan: _Plan):
@@ -260,15 +263,25 @@ def held_experts(x, router_w, experts, cfg: MoEConfig, expert_bias=None,
     `down_w` [len(held), F, D], in the order of `cfg.held`."""
     T = x.shape[0]
     k = cfg.top_k
-    sel, weights = route(x, router_w, cfg, expert_bias, sel)
     rows, past = cfg.buffer_rows(T), cfg.past_rows(T)
-    plan = _plan(sel, cfg, rows, past)
-    flat_w = weights.reshape(-1)
+    # The layer's parts are children of whatever scope it is called under
+    # (`<family>.moe`): a leading "." says so, and `bps.get_step_scopes()`
+    # reads `<family>.moe/route`.
+    with jax.named_scope(".route"):
+        sel, weights = route(x, router_w, cfg, expert_bias, sel)
+        plan = _plan(sel, cfg, rows, past)
+        flat_w = weights.reshape(-1)
     out = _buffer(jnp.int32(0), x, experts, flat_w, plan, k, rows)
     if plan.order.size > rows:
-        out = out + _past_the_buffer(k, rows, past, x, experts, flat_w, plan)
-    routing = Routing(sel, weights, plan.held_rows, plan.ends - plan.starts,
-                      jnp.maximum(plan.held_rows - rows, 0))
+        # around the CALL: a hand-written backward pass is traced under
+        # the scopes around its call, not those its forward opened
+        with jax.named_scope(".exact"):
+            out = out + _past_the_buffer(k, rows, past, x, experts, flat_w,
+                                         plan)
+    with jax.named_scope(".route"):
+        routing = Routing(sel, weights, plan.held_rows,
+                          plan.ends - plan.starts,
+                          jnp.maximum(plan.held_rows - rows, 0))
     return out.astype(x.dtype), routing
 
 
