@@ -252,7 +252,9 @@ def parse_step_scopes(hlo_text: str) -> Dict[str, dict]:
     (`"lent": True`): a kernel the compiler made itself, a `custom-call`
     whose `op_name` is no path of JAX's (`ragged-dot-none`, the grouped
     product the TPU compiler builds out of a `lax.ragged_dot` whose path
-    it drops).  It takes what the scopes of its neighbours share: of the
+    it drops: the expert layer's at a width its own kernels cannot tile,
+    `ops/grouped_matmul.py`; those carry their path like any Pallas call
+    and are lent nothing).  It takes what the scopes of its neighbours share: of the
     instructions that make its operands and of those that read its
     result, behind any that only hand a value on, those whose scope is
     their own; and the pass of the first reader that has one, else of

@@ -411,7 +411,9 @@ def _gauge(name: str, help: str, cast=int) -> tuple:
 #: (`flash_attention.tile_schedule`) and a streaming one's
 #: (`flash_attention.stream_schedule`; one set of gauges a `window`
 #: label, "none" for a call without one, so a step with both kinds of
-#: layer keeps both whatever the order they are traced in).
+#: layer keeps both whatever the order they are traced in), and the
+#: expert layer's grouped product (`ops/grouped_matmul.py`: which path
+#: ran and on what tiles; `parallel/dropless_moe.py` adds the walk).
 _STATIC = {
     "ingraph_exchange": {
         "leaves": _gauge(
@@ -467,6 +469,34 @@ _STATIC = {
             "bps_flash_stream_fetched",
             "tiles of K (and as many of V) copied in: a step whose tile "
             "is the one before it copies nothing"),
+    },
+    "grouped_matmul": {
+        "kernel": _gauge(
+            "bps_grouped_kernel",
+            "1 where the last traced grouped product of the expert layer "
+            "runs the program's Pallas kernels, 0 where its shape went "
+            "to lax.ragged_dot"),
+        "tile_rows": _gauge(
+            "bps_grouped_tile_rows", "rows of a tile of those kernels"),
+        "tile_fwd_k": _gauge(
+            "bps_grouped_tile_fwd_k",
+            "contracted width a step of the forward kernel takes"),
+        "tile_drows_n": _gauge(
+            "bps_grouped_tile_drows_n",
+            "contracted width a step of the rows' gradient takes"),
+        "tile_dweights_k": _gauge(
+            "bps_grouped_tile_dweights_k",
+            "rows of a group's weight gradient one program owns"),
+        "row_tiles_walked": _gauge(
+            "bps_grouped_row_tiles_walked",
+            "grid steps over the rows at the even routing: a tile a "
+            "group's edge crosses once a group"),
+        "row_tiles_needed": _gauge(
+            "bps_grouped_row_tiles_needed",
+            "row tiles that hold a live row at the even routing"),
+        "row_tiles_buffer": _gauge(
+            "bps_grouped_row_tiles_buffer",
+            "row tiles of the whole buffer, padding included"),
     },
 }
 

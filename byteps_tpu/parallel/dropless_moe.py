@@ -21,9 +21,11 @@ No assignment to a held expert is dropped, whatever the routing:
     the pairs that chose an expert held elsewhere last;
   - the first `rows` pairs (a static buffer, `capacity_factor` times the
     even share, so that a usual step fits) are gathered, go through the
-    three expert products grouped by expert (`lax.ragged_dot`, which on a
-    TPU is a kernel that skips the tiles past the last group), are
-    weighted, and are scatter-added back to their tokens;
+    three expert products grouped by expert (`ops/grouped_matmul.py`:
+    the program's own Pallas kernels, whose grid walks only the row tiles
+    that hold a live row; a width they cannot tile, no multiple of 128,
+    goes to `lax.ragged_dot`), are weighted, and are scatter-added back
+    to their tokens;
   - pairs past the buffer go through the same code, a small buffer at a
     time (`past_rows`, an eighth of the first), in a loop that runs only
     while pairs are left (`_past_the_buffer`).  That is the exact path: no
@@ -63,6 +65,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from ..ops import grouped_matmul as gm
 
 
 @dataclasses.dataclass(frozen=True)
@@ -153,8 +157,11 @@ def _held_weight_held(weights, sel, cfg: MoEConfig):
 def _swiglu_grouped(xg, experts, group_sizes, dtype):
     """The three products of every held expert on its own rows of `xg`
     [rows, D], which lie grouped by expert, `group_sizes` rows each."""
+    walk = gm.row_walk(group_sizes, xg.shape[0])    # one routing: once
+
     def grouped(lhs, w):
-        return lax.ragged_dot(lhs, w.astype(dtype), group_sizes)
+        return gm.grouped_matmul(lhs, w.astype(dtype), group_sizes,
+                                 walk=walk)
     h = jax.nn.silu(grouped(xg, experts["gate_w"])) * grouped(
         xg, experts["up_w"])
     return grouped(h, experts["down_w"])
@@ -196,13 +203,14 @@ def _buffer(lo, x, experts, flat_w, plan: _Plan, k: int, rows: int):
         token = pair // k
         group_sizes = jnp.clip(jnp.minimum(plan.ends, lo + rows)
                                - jnp.maximum(plan.starts, lo), 0)
-        # On a TPU the grouped product leaves the rows past the last group
-        # as it found them, in the forward and in the backward products
-        # alike (on the CPU they are zeros): whatever is there, NaN
-        # included, must reach neither the result nor a gradient.  So the
-        # rows are masked on the way in, which masks the gradient of the
-        # gather, and on the way out BEFORE the weights are multiplied in,
-        # whose gradient is otherwise 0 * NaN.
+        # The grouped product's kernels (`ops/grouped_matmul.py`, as the
+        # compiler's own before them) leave the rows past the last group
+        # as they found them, in the forward and in the backward products
+        # alike (the CPU's `lax.ragged_dot` writes zeros): whatever is
+        # there, NaN included, must reach neither the result nor a
+        # gradient.  So the rows are masked on the way in, which masks
+        # the gradient of the gather, and on the way out BEFORE the
+        # weights are multiplied in, whose gradient is otherwise 0 * NaN.
         dead = ~live[:, None]
         xg = jnp.where(dead, 0, x[token])
     with jax.named_scope(".grouped"):
@@ -264,6 +272,8 @@ def held_experts(x, router_w, experts, cfg: MoEConfig, expert_bias=None,
     T = x.shape[0]
     k = cfg.top_k
     rows, past = cfg.buffer_rows(T), cfg.past_rows(T)
+    gm.record_walk(rows, *experts["gate_w"].shape[1:], len(cfg.held), x.dtype,
+                   live=T * k * len(cfg.held) // cfg.num_experts)
     # The layer's parts are children of whatever scope it is called under
     # (`<family>.moe`): a leading "." says so, and `bps.get_step_scopes()`
     # reads `<family>.moe/route`.

@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import byteps_tpu as bps
 from byteps_tpu.parallel import dropless_moe as dm
 
 E, K, D, F, T = 32, 4, 16, 8, 96
@@ -149,10 +150,12 @@ def test_buffer_rows_and_config_errors():
 
 
 def _as_on_the_chip():
-    """`lax.ragged_dot` as the TPU's kernel behaves: the rows past the last
-    group are neither read nor written, in the product and in both of its
-    gradients.  Here they come back as NaN, the worst the chip's memory
-    can hold (found on the chip: PERF.md, Findings, PR 29)."""
+    """The grouped product (`ops/grouped_matmul.py` `grouped_matmul`, the
+    layer's entry) as its kernels behave on the chip, and the compiler's
+    own before them: the rows past the last group are neither read nor
+    written, in the product and in both of its gradients.  Here they come
+    back as NaN, the worst the chip's memory can hold (found on the chip:
+    PERF.md, Findings, PR 29)."""
     real = jax.lax.ragged_dot
 
     def dead(a, group_sizes):
@@ -175,14 +178,16 @@ def _as_on_the_chip():
         return jnp.where(gone, jnp.nan, d_lhs), d_rhs, None
 
     ragged_dot.defvjp(fwd, bwd)
-    return ragged_dot
+    # (the layer hands the entry its routing's tables too: `walk=`)
+    return lambda lhs, rhs, group_sizes, walk=None: ragged_dot(
+        lhs, rhs, group_sizes)
 
 
 @pytest.mark.parametrize("kind", ["spread_evenly", "every_pair_held"])
 def test_rows_past_the_last_group_reach_nothing(kind, monkeypatch):
     """The buffer is longer than the pairs it holds; what the kernel
     leaves in the rest must reach neither the result nor any gradient."""
-    monkeypatch.setattr(dm.lax, "ragged_dot", _as_on_the_chip())
+    monkeypatch.setattr(dm.gm, "grouped_matmul", _as_on_the_chip())
     x, router_w, experts = _weights(2)
     sel = _forced(kind)
 
@@ -195,6 +200,41 @@ def test_rows_past_the_last_group_reach_nothing(kind, monkeypatch):
         return (out * jnp.cos(out)).sum()
 
     got, g = jax.value_and_grad(layer, (0, 1, 2))(x, router_w, experts)
+    want, g_want = jax.value_and_grad(plain, (0, 1, 2))(x, router_w, experts)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-4, atol=1e-5)
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(g_want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-3, atol=1e-5)
+
+
+@pytest.mark.parametrize("kind", ["spread_evenly", "one_held_expert",
+                                  "every_pair_held"])
+def test_layer_through_the_kernels_at_lane_widths(kind):
+    """At widths the kernels tile (multiples of 128) the layer runs them,
+    here in the interpreter, through the buffer and the exact path behind
+    it, and loses no row in the result or in any gradient."""
+    D, F = 128, 256
+    cfg = dataclasses.replace(CFG, row_multiple=128)
+    k = jax.random.split(jax.random.key(5), 5)
+    x = jax.random.normal(k[0], (T, D))
+    router_w = jax.random.normal(k[1], (D, E)) / 8
+    experts = {"gate_w": jax.random.normal(k[2], (len(HELD), D, F)) / 11,
+               "up_w": jax.random.normal(k[3], (len(HELD), D, F)) / 11,
+               "down_w": jax.random.normal(k[4], (len(HELD), F, D)) / 16}
+    sel = _forced(kind)
+    assert cfg.buffer_rows(T) == 128 and cfg.past_rows(T) == 128
+
+    def layer(x, router_w, experts):
+        out, _ = dm.held_experts(x, router_w, experts, cfg, sel=sel)
+        return (out * jnp.cos(out)).sum()
+
+    def plain(x, router_w, experts):
+        out = _plain(x, router_w, experts, cfg, sel)
+        return (out * jnp.cos(out)).sum()
+
+    got, g = jax.jit(jax.value_and_grad(layer, (0, 1, 2)))(
+        x, router_w, experts)
+    assert bps.get_metrics()["bps_grouped_kernel"] == 1
     want, g_want = jax.value_and_grad(plain, (0, 1, 2))(x, router_w, experts)
     np.testing.assert_allclose(float(got), float(want), rtol=1e-4, atol=1e-5)
     for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(g_want)):

@@ -8,6 +8,7 @@ the CPU.
 """
 
 import contextlib
+import copy
 import dataclasses
 import logging
 import os
@@ -62,12 +63,17 @@ FAMILIES = {
                 "byteps.optimizer"}, True),
 }
 # The names the device trace was read by before this map: an unnamed
-# kernel call is called after the innermost scope around it.
+# kernel call is called after the innermost scope around it.  The expert
+# layer's grouped products are the program's own kernels since PR 40,
+# named `ragged-dot-none_*` under `<family>.moe/grouped` (the exact
+# path's under `<family>.moe/exact/grouped`).
 KERNEL_SCOPES = {
     "gpt2": {"transformer.attn"},
-    "afmoe": {"afmoe.attn.sliding_attention", "afmoe.attn.full_attention"},
+    "afmoe": {"afmoe.attn.sliding_attention", "afmoe.attn.full_attention",
+              "afmoe.moe/grouped", "afmoe.moe/exact/grouped"},
     "granitehybrid": {"granite.mamba.scan", "granite.attn"},
-    "mellum": {"mellum.attn.sliding_attention", "mellum.attn.full_attention"},
+    "mellum": {"mellum.attn.sliding_attention", "mellum.attn.full_attention",
+               "mellum.moe/grouped", "mellum.moe/exact/grouped"},
 }
 PRODUCTS = ("fusion", "custom-call", "dot", "convolution", "ragged-dot")
 WORK = ("dot_general", "conv_general_dilated", "pallas_call")
@@ -115,6 +121,12 @@ def _family(name: str):
     elif name == "mellum":  # sliding, full
         cell = dataclasses.replace(cell,
                                    config=tiny_mellum.config(layers=[2, 3]))
+    if name in ("afmoe", "mellum"):
+        # the narrowest widths the grouped kernels tile: a lane tile each
+        # (the tiny cuts' 64 and 32 go to `lax.ragged_dot`, the compiler's)
+        config = copy.deepcopy(cell.config)
+        config["published"].update(hidden_size=128, moe_intermediate_size=128)
+        cell = dataclasses.replace(cell, config=config)
     family = measure._module("families", cell.config["family"]).Family(
         cell.config, cell.job)
     if name == "granitehybrid":
@@ -209,22 +221,27 @@ def test_every_familys_tiny_step_is_mapped(name, v5e, kernels,
     cell_name, expected, remat = FAMILIES[name]
     text, scopes = _map(family, int(cell.job["per_chip_batch"]), v5e[:1])
     _check(scopes, text, expected, remat)
-    # the program's own Pallas calls; the compiler's grouped-product
-    # kernels are the same target, carry no path and are LENT a scope:
-    # what the gather, the activation and the scatter round them share
+    # the program's own Pallas calls, the grouped products among them:
+    # each under the scope its call was traced in, and nothing is LENT
+    # one (only a kernel the compiler made itself, with no path, is:
+    # `lax.ragged_dot`'s was, until PR 40)
     kernels = {n: e for n, e in scopes.items()
                if 'custom_call_target="tpu_custom_call"' in _line(text, n)}
-    assert {e["scope"] for e in kernels.values()
-            if not e.get("lent")} == KERNEL_SCOPES.get(name, set())
-    lent = {n: e for n, e in scopes.items() if e.get("lent")}
-    assert set(lent) <= set(kernels)        # nothing else is lent a scope
+    assert {e["scope"] for e in kernels.values()} == KERNEL_SCOPES.get(
+        name, set())
+    assert not [n for n, e in scopes.items() if e.get("lent")]
+    grouped = {n: e for n, e in kernels.items()
+               if n.startswith("ragged-dot-none_")}
     if name in ("afmoe", "mellum"):
-        grouped = [e for n, e in lent.items() if n.startswith("ragged-dot")]
-        assert grouped and all(
-            e["scope"].split("/")[0] == f"{name}.moe"
-            and e["pass"] != "other" for e in grouped), grouped
+        assert {n.split(".")[0].rsplit("_", 1)[1] for n in grouped} == {
+            "fwd", "drows", "dweights"}
+        assert all(e["scope"].startswith(f"{name}.moe/")
+                   and e["pass"] != "other" for e in grouped.values())
+        assert {"forward", "recompute", "backward"} <= {
+            e["pass"] for e in grouped.values()}
+        assert "ragged-dot-metadata" not in text
     else:
-        assert not lent
+        assert not grouped
 
 
 def test_the_dp4_step_is_mapped_with_the_exchange_in_the_optimizer(

@@ -160,38 +160,74 @@ def test_streaming_flash_compiles_at_mellum_shapes(v5e, window):
                for kind in ("fwd", "dq", "dkv"))
 
 
-def test_dropless_expert_layer_compiles_at_trinity_widths(v5e):
-    """16 of 128 experts, 8 a token, hidden 2048, expert width 1024, a
-    sequence of 8192 tokens: forward alone (which once broke the
-    compiler's scatter emitter inside a loop) and with its gradient, the
-    grouped products as the chip's own ragged-dot kernels."""
+@pytest.mark.parametrize(
+    "experts,hidden,width,tokens,capacity,what",
+    [(128, 2048, 1024, 8192, 1.25, "forward"),
+     (128, 2048, 1024, 8192, 1.25, "gradient"),
+     (64, 2304, 896, 32768, 4.0, "gradient")],
+    ids=["trinity_widths-forward", "trinity_widths-gradient",
+         "mellum_widths-gradient"])
+def test_dropless_expert_layer_compiles(v5e, monkeypatch, experts, hidden,
+                                        width, tokens, capacity, what):
+    """16 held experts, 8 a token, at trinity-mini's widths (of 128
+    experts, hidden 2048, expert width 1024, 8192 tokens) and at mellum's
+    (of 64, 2304 / 896 = 18 and 7 lane tiles, one sequence of 32,768; a
+    buffer that holds every pair, so the exact path, which trinity's case
+    compiles, is not compiled twice): forward alone (which once broke the
+    compiler's scatter emitter inside a loop) and the gradient under
+    remat inside a scan, as the models have it.  The grouped products are
+    the program's own kernels under the names the trace's readers know a
+    grouped product by, none of the compiler's is left, and each kernel's
+    instruction is what `benchmark/reduce/afmoe_cost.py` takes it for."""
+    from benchmark.reduce import afmoe_cost
     from byteps_tpu.parallel import dropless_moe as dm
-    cfg = dm.MoEConfig(num_experts=128, top_k=8, held=tuple(range(16)),
-                       route_scale=2.826)
+    monkeypatch.setattr(fa, "_use_interpret", lambda interpret: False)
+    cfg = dm.MoEConfig(num_experts=experts, top_k=8, held=tuple(range(16)),
+                       capacity_factor=capacity)
     one = SingleDeviceSharding(v5e[0])
 
     def shape(*dims, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
 
-    x = shape(8192, 2048, dtype=jnp.bfloat16)
-    experts = {"gate_w": shape(16, 2048, 1024),
-               "up_w": shape(16, 2048, 1024),
-               "down_w": shape(16, 1024, 2048)}
+    x = shape(tokens, hidden, dtype=jnp.bfloat16)
+    weights = {"gate_w": shape(16, hidden, width),
+               "up_w": shape(16, hidden, width),
+               "down_w": shape(16, width, hidden)}
 
-    def layers(x, router_w, experts):
-        # inside a scan, as the model has it
-        def body(x, _):
-            out, routing = dm.held_experts(x, router_w, experts, cfg)
+    def layers(x, router_w, weights):
+        # inside a scan, each layer rematerialised, under the model's scope
+        @jax.checkpoint
+        def layer(x):
+            with jax.named_scope("family.moe"):
+                out, routing = dm.held_experts(x, router_w, weights, cfg)
             return x + out, routing.counts
-        return jax.lax.scan(body, x, None, length=2)
+        return jax.lax.scan(lambda x, _: layer(x), x, None, length=2)
 
-    def loss(x, router_w, experts):
-        return layers(x, router_w, experts)[0].astype(jnp.float32).sum()
+    def loss(x, router_w, weights):
+        return layers(x, router_w, weights)[0].astype(jnp.float32).sum()
 
-    args = (x, shape(2048, 128), experts)
-    assert "ragged-dot" in _compile(layers, *args).as_text()
+    args = (x, shape(hidden, experts), weights)
+    if what == "forward":
+        text = _compile(layers, *args).as_text()
+        assert "ragged-dot-none_fwd" in text
+        assert "ragged-dot-metadata" not in text
+        return
     text = _compile(jax.grad(loss, (0, 1, 2)), *args).as_text()
-    assert text.count("ragged-dot-none") >= 9      # 3 products, 3 passes
+    assert "ragged-dot-metadata" not in text        # the compiler's own
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    names = [afmoe_cost.xplane.op_name(line.strip()) for line in calls]
+    # 3 products: forward, again under remat, and two gradients each
+    for kind, least in (("fwd", 6), ("drows", 3), ("dweights", 3)):
+        assert sum(n.startswith(f"ragged-dot-none_{kind}")
+                   for n in names) >= least, names
+    assert all(n.startswith("ragged-dot-none_") for n in names), names
+    for line in map(str.strip, calls):
+        assert afmoe_cost.is_grouped(line)
+        assert afmoe_cost.attention_call(line) is None
+        assert afmoe_cost.grouped_call(line) in (
+            (16, hidden, width), (16, width, hidden)), line
+        assert "/family.moe/" in line and ".grouped" in line
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
