@@ -54,6 +54,7 @@ class _State:
     ps_session: Optional[Any] = None  # PS-mode client session, when enabled
     exporter: Optional[Any] = None    # TelemetryExporter, when enabled
     trace_atexit: bool = False        # crash-flush guard registered
+    comm_dir: Optional[str] = None    # where this PS worker wrote a comm.json
     # Elastic membership: the last fetched view (get_membership /
     # the on_membership_change poller), the registered callback, and the
     # poller plumbing.  size() reads the cached view, so a resize is
@@ -329,6 +330,16 @@ def shutdown() -> None:
     # device plane disarms, so a run that never reached its trace end
     # step still gets its device lanes in the final merged export.
     _maybe_dump_trace(final=True)
+    if _state.ps_session is not None and _state.comm_dir is not None:
+        # The wire's floor on this host, once a process, beside the
+        # comm.json whose rounds it is read against: after the last
+        # ROUND and before the lanes close (server/wire_floor.py).  A
+        # run that traced nothing starts no child.
+        from ..server import wire_floor
+        try:
+            wire_floor.probe_at_shutdown(_state.ps_session, _state.comm_dir)
+        except Exception:
+            get_logger().exception("wire floor probe failed")
     prof = devprof.active()
     if prof is not None:
         # Freeze the bundle's device section to the final snapshot (the
@@ -1959,6 +1970,8 @@ def _maybe_dump_trace(final: bool = False, exiting: bool = False) -> None:
     path = os.path.join(d, "comm.json")
     core.trace_dump(path, rank())
     _merge_server_trace(path, exiting=exiting)
+    if _state.ps_session is not None:
+        _state.comm_dir = d
 
 
 def _dump_trace_on_exit() -> None:

@@ -67,14 +67,28 @@ def _minor_faults() -> int:
     return resource.getrusage(resource.RUSAGE_SELF).ru_minflt
 
 
+def _wire_delta(before: dict, after: dict) -> dict:
+    """What the lanes counted between two `PSSession.wire_counts`."""
+    busy, was = after["lane_busy_us"], before["lane_busy_us"]
+    if len(was) == len(busy):     # else the pool was resized in between
+        busy = [b - a for a, b in zip(was, busy)]
+    delta = {k: v - before[k] for k, v in after.items()
+             if k != "lane_busy_us"}
+    return {**delta, "lanes": len(busy), "lane_busy_us": busy}
+
+
 class RoundSpans:
     """A PS session's round counter, the round open on each thread, and
     the spans written under it."""
 
-    def __init__(self):
+    def __init__(self, wire=None):
         self._rounds = 0
         self._lock = threading.Lock()
         self._open = threading.local()    # .counts: the open ROUND's args
+        # `PSSession.wire_counts`: the lanes' lifetime counters, which a
+        # ROUND carries the deltas of.
+        self._wire = wire
+        self.last = None    # the args of the last ROUND that closed
 
     @contextlib.contextmanager
     def round(self, name: str):
@@ -84,7 +98,11 @@ class RoundSpans:
         open, on every thread (the dispatcher's `recv_into` first
         touches the result buffers).  Near the round's bytes in pages
         where its host memory is new, near nothing where it was kept
-        (common/host_memory.py)."""
+        (common/host_memory.py).  Taken where `minflt` is, because the
+        dispatcher and the receivers cannot reach this thread's counts:
+        what the session's lanes counted while it was open
+        (`PSSession.WIRE_COUNTS`), and `lanes` / `lane_busy_us`, each
+        lane's time with bytes outstanding."""
         core = get_core()
         if not core.trace_on:
             yield
@@ -97,12 +115,16 @@ class RoundSpans:
         try:
             with _Span(core, "ROUND", name, counts):
                 faults = _minor_faults()
+                wire = self._wire() if self._wire is not None else None
                 try:
                     yield
                 finally:
                     counts["minflt"] = _minor_faults() - faults
+                    if wire is not None:
+                        counts.update(_wire_delta(wire, self._wire()))
         finally:
             self._open.counts = None
+            self.last = counts
 
     def span(self, stage: str, name: str, **args):
         """A context manager for one stage span, yielding the span (add

@@ -512,6 +512,19 @@ def record_static(family: str, labels: Optional[Dict[str, str]] = None,
         reg.gauge(name, help=help, labels=labels).set(cast(value))
 
 
+def record_wire_floor(result: dict) -> None:
+    """`bps_wire_floor_gbps{dir=out|in|duplex}`: what server/wire_floor.py
+    measured on this host, over what the session's lanes are."""
+    reg = get_registry()
+    for direction in ("out", "in", "duplex"):
+        reg.gauge("bps_wire_floor_gbps",
+                  help="GB/s this host moves between this process and "
+                       "another over the session's kind and number of "
+                       "lanes: the floor under the PS wire",
+                  labels={"dir": direction}
+                  ).set(result[direction]["GB_per_s"])
+
+
 # ---------------------------------------------------------------------------
 # Hierarchical reduction (parallel/hierarchy.py; BYTEPS_TPU_HIERARCHY=1)
 # ---------------------------------------------------------------------------

@@ -31,7 +31,12 @@ thread passed through it: the mean per ``ROUND`` span (one
 ``push_pull_tree`` call in PS mode, common/stage_spans.py) of each stage
 span under it, and ``unspanned``, what of the ``ROUND`` no stage covers
 (Python between the spans).  The stages of a round do not overlap, so
-``sum(stages) + unspanned == round`` exactly.
+``sum(stages) + unspanned == round`` exactly.  ``round_wire`` is what the
+session's lanes counted inside those rounds, mean per ``ROUND``: the
+socket calls, the time in them and waiting for a send lock, the pulls'
+wait for their first byte, and each lane's time with bytes outstanding
+(docs/timeline.md, "What the wire waited for"); empty for a program
+whose ``ROUND`` carries none.
 
 ``update_critical_path_gauges`` feeds the per-component means into the
 PR-4 telemetry registry as ``bps_step_critical_path_seconds{component=…}``
@@ -90,7 +95,8 @@ def analyze(events: List[dict], worker: int = 0, top_k: int = 5) -> dict:
          "mean_breakdown_us": {component: us},
          "top_blocking": [{"name", "total_us", "members"}],
          "straggler_wait_us": {worker_id: us},
-         "round_breakdown_us": {"round", "pack", ..., "unspanned": us}}
+         "round_breakdown_us": {"round", "pack", ..., "unspanned": us},
+         "round_wire": {"send_calls", ..., "lanes", "lane_busy_us": [us]}}
     """
     xs = [e for e in events if e.get("ph") == "X"]
     # Worker-side spans and STEP envelopes are filtered to the selected
@@ -222,7 +228,8 @@ def analyze(events: List[dict], worker: int = 0, top_k: int = 5) -> dict:
     top = sorted(blocking.values(), key=lambda r: -r["total_us"])[:top_k]
     return {"steps": step_rows, "mean_breakdown_us": mean,
             "top_blocking": top, "straggler_wait_us": straggler,
-            "round_breakdown_us": round_breakdown(xs, worker)}
+            "round_breakdown_us": round_breakdown(xs, worker),
+            "round_wire": round_wire(xs, worker)}
 
 
 def round_breakdown(spans: List[dict], worker: int = 0) -> Dict[str, int]:
@@ -243,6 +250,28 @@ def round_breakdown(spans: List[dict], worker: int = 0) -> Dict[str, int]:
     out = {s.lower(): total[s] // len(rounds) for s in ROUND_STAGES}
     out["unspanned"] = out["round"] - sum(
         v for s, v in out.items() if s != "round")
+    return out
+
+
+def round_wire(spans: List[dict], worker: int = 0) -> Dict[str, object]:
+    """Mean per ``ROUND`` of what the session's lanes counted while it
+    was open (its ``args``, but for the stage counts that
+    ``round_breakdown`` and the byte counts cover); ``lane_busy_us`` a
+    list, by lane.  Empty where no ``ROUND`` carries the counts."""
+    args = [e["args"] for e in spans if e.get("pid") == worker
+            and e.get("tid") == "ROUND" and "send_calls" in e["args"]]
+    if not args:
+        return {}
+    n = len(args)
+    out: Dict[str, object] = {
+        k: sum(a[k] for a in args) // n for k in args[0]
+        if k not in ("round", "units", "units_early", "bytes_out",
+                     "bytes_in", "minflt", "lanes", "lane_busy_us")}
+    out["lanes"] = max(a["lanes"] for a in args)
+    out["lane_busy_us"] = [
+        sum(a["lane_busy_us"][i] for a in args
+            if i < len(a["lane_busy_us"])) // n
+        for i in range(out["lanes"])]
     return out
 
 
@@ -346,6 +375,17 @@ def format_report(result: dict) -> str:
                      "(sums to the round)")
         for stage, us in rounds.items():
             lines.append(f"      {stage:<12}{_fmt_us(us)}")
+    wire = result.get("round_wire", {})
+    if wire:
+        lines.append("what the wire waited for, mean per round (sums over "
+                     "lanes and threads)")
+        for key, value in wire.items():
+            if key.endswith("_us") and key != "lane_busy_us":
+                lines.append(f"      {key[:-3]:<22}{_fmt_us(value)}")
+            elif key != "lane_busy_us":
+                lines.append(f"      {key:<22}{value:>10}")
+        lines.append("      lane busy             " + " ".join(
+            _fmt_us(us).strip() for us in wire["lane_busy_us"]))
     clock = result.get("profiler_offset")
     if clock:
         lines.append(f"comm.json + {clock['offset_us']:.1f}us = the "

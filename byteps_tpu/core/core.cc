@@ -392,7 +392,9 @@ BPS_API void bps_trace_record_part(const char* name, const char* stage,
 }
 
 // Span with `n` named integer args: `keys` is their comma-separated names,
-// `vals` their values in the same order.
+// `vals` their values in the same order.  Consecutive args whose name ends
+// in "[]" and is the same are one list in the dump, under the name
+// without it.
 BPS_API void bps_trace_record_args(const char* name, const char* stage,
                                    int64_t ts_us, int64_t dur_us,
                                    const char* keys, const int64_t* vals,
@@ -461,9 +463,21 @@ BPS_API int32_t bps_trace_dump(const char* path, int32_t rank) {
     if (!e.extra.empty()) {
       std::fputs(",\"args\":{", f);
       for (size_t i = 0; i < e.extra.size(); ++i) {
-        std::fprintf(f, "%s\"%s\":%lld", i ? "," : "",
-                     json_escape(e.extra[i].first).c_str(),
+        const std::string& key = e.extra[i].first;
+        const size_t len = key.size();
+        if (len < 2 || key.compare(len - 2, 2, "[]") != 0) {
+          std::fprintf(f, "%s\"%s\":%lld", i ? "," : "",
+                       json_escape(key).c_str(),
+                       (long long)e.extra[i].second);
+          continue;
+        }
+        std::fprintf(f, "%s\"%s\":[%lld", i ? "," : "",
+                     json_escape(key.substr(0, len - 2)).c_str(),
                      (long long)e.extra[i].second);
+        while (i + 1 < e.extra.size() && e.extra[i + 1].first == key) {
+          std::fprintf(f, ",%lld", (long long)e.extra[++i].second);
+        }
+        std::fputs("]", f);
       }
       std::fputs("}", f);
     } else if (e.key >= 0) {

@@ -168,11 +168,16 @@ class _CCore:
     def trace_record_args(self, name: str, stage: str, ts_us: int,
                           dur_us: int, args: dict) -> None:
         """Span whose Chrome-trace args are the named integers of `args`
-        (the main-thread stage spans, common/stage_spans.py)."""
-        vals = (ctypes.c_int64 * len(args))(*args.values())
+        (the main-thread stage spans, common/stage_spans.py).  A value
+        that is a list of integers goes as its name with `[]` once an
+        element, which the dump writes back as one list."""
+        flat = [(k + "[]", x) if isinstance(v, list) else (k, v)
+                for k, v in args.items()
+                for x in (v if isinstance(v, list) else (v,))]
+        vals = (ctypes.c_int64 * len(flat))(*(v for _, v in flat))
         self._lib.bps_trace_record_args(
             name.encode(), stage.encode(), ts_us, dur_us,
-            ",".join(args).encode(), vals, len(args))
+            ",".join(k for k, _ in flat).encode(), vals, len(flat))
 
     def trace_count(self) -> int:
         return self._lib.bps_trace_count()
@@ -403,7 +408,8 @@ class _PyCore:
         if self._trace_on:
             self._trace_events.append(
                 (name, stage, ts_us, dur_us,
-                 {k: int(v) for k, v in args.items()}))
+                 {k: [int(x) for x in v] if isinstance(v, list) else int(v)
+                  for k, v in args.items()}))
 
     def trace_count(self):
         return len(self._trace_events)

@@ -74,6 +74,7 @@ from ..common.config import Config
 from ..common.logging import get_logger
 from ..common.ring import DEFAULT_VNODES, RingTable
 from ..core.native import get_core
+from . import wire_floor as _wire_floor
 from .codec_pool import CompressionPool
 
 _REQ = struct.Struct("<BBHIIQQ")   # cmd dtype flags req_id worker_id key len
@@ -460,11 +461,20 @@ class _RecvBufPool:
             return self.hits, self.misses, held
 
 
+def _now_us() -> int:
+    """The clock of the wire's time counters, read only while the tracer
+    is on (a test holds the untraced path to that).  It is the tracer's
+    clock (`steady_clock`, CLOCK_MONOTONIC), read without leaving the
+    interpreter: `core.trace_now_us` is a ctypes call, which gives up
+    the GIL, and a few thousand a round each wait to get it back."""
+    return time.monotonic_ns() // 1000
+
+
 class _Future:
     """Completion slot for one outstanding request."""
 
     __slots__ = ("event", "data", "error", "callback", "sink", "sink_live",
-                 "pool_ok", "cmd", "key", "req_id", "t0")
+                 "pool_ok", "cmd", "key", "req_id", "t0", "sent_us")
 
     def __init__(self, callback: Optional[Callable] = None,
                  sink: Optional[memoryview] = None,
@@ -492,6 +502,9 @@ class _Future:
         self.key = 0
         self.req_id = 0
         self.t0 = time.monotonic()
+        # Tracer clock at a traced pull's issue (0 = not stamped): the
+        # receiver adds header arrival - sent_us to recv_first_byte_us.
+        self.sent_us = 0
 
     def resolve(self, data: bytes, error: Optional[Exception]) -> None:
         self.data, self.error = data, error
@@ -561,6 +574,27 @@ class _ServerConn:
         self.outstanding_bytes = 0
         self.lane_bytes_total = 0
         self.lane_sends = 0
+        # What the wire's time went to (docs/timeline.md, "The round from
+        # inside"; a ROUND span carries their deltas).  The calls are
+        # always counted: plain integers, send_calls under `lock`,
+        # recv_calls on the receiver thread alone.  The times are kept
+        # only while the core tracer is on, on its clock (`_now_us`):
+        # send_lock_wait_us getting `lock`; send_us / recv_us inside the
+        # socket calls (recv_us leaves out the wait for a response
+        # header, which is idle time or the next); recv_first_byte_us
+        # from a pull's issue to its response header, over `pulls`
+        # pulls; busy_us with outstanding_bytes > 0 (`busy_since` while
+        # it is).
+        self._core = get_core()     # get_core() takes a lock a call
+        self.send_calls = 0
+        self.recv_calls = 0
+        self.send_lock_wait_us = 0
+        self.send_us = 0
+        self.recv_us = 0
+        self.recv_first_byte_us = 0
+        self.pulls = 0
+        self.busy_us = 0
+        self.busy_since = 0
         # WIRE_CONNS knob: a retiring lane takes no NEW dispatches
         # (excluded from _pick_lane) while its outstanding bytes drain;
         # the resize worker closes it once quiet (_resize_lanes).
@@ -617,18 +651,14 @@ class _ServerConn:
         """Apply BYTEPS_TPU_SOCK_BUF_KB (0 = kernel default) to both
         directions; best-effort — the kernel clamps/doubles as it sees
         fit, and an EPERM on an exotic transport must not kill a dial."""
-        if self.sock_buf_kb <= 0:
-            return
-        nbytes = self.sock_buf_kb * 1024
-        for opt in (socket.SO_SNDBUF, socket.SO_RCVBUF):
-            try:
-                sock.setsockopt(socket.SOL_SOCKET, opt, nbytes)
-            except OSError:
-                pass
+        _wire_floor.tune(sock, self.sock_buf_kb)
 
     # -- byte-credit lane accounting ------------------------------------
     def lane_charge(self, nbytes: int) -> None:
         with self._lane_lock:
+            if (nbytes > 0 and self.outstanding_bytes == 0
+                    and self._core.trace_on):
+                self.busy_since = _now_us()
             self.outstanding_bytes += nbytes
             self.lane_bytes_total += nbytes
             self.lane_sends += 1
@@ -636,6 +666,16 @@ class _ServerConn:
     def lane_return(self, nbytes: int) -> None:
         with self._lane_lock:
             self.outstanding_bytes = max(0, self.outstanding_bytes - nbytes)
+            if self.busy_since and self.outstanding_bytes == 0:
+                self.busy_us += _now_us() - self.busy_since
+                self.busy_since = 0
+
+    def busy_us_now(self) -> int:
+        """`busy_us` with the open busy stretch, if any, counted so far."""
+        with self._lane_lock:
+            if not self.busy_since:
+                return self.busy_us
+            return self.busy_us + _now_us() - self.busy_since
 
     def state(self) -> str:
         """'up' | 'reconnecting' | 'closed' — for watchdog dumps/stats."""
@@ -655,8 +695,9 @@ class _ServerConn:
              callback: Optional[Callable] = None,
              sink: Optional[memoryview] = None,
              sink_live: Optional[Callable[[], bool]] = None,
-             pool_ok: bool = False) -> _Future:
+             pool_ok: bool = False, sent_us: int = 0) -> _Future:
         fut = _Future(callback, sink, sink_live, pool_ok)
+        fut.sent_us = sent_us
         with self._pending_lock:
             if self._closed:
                 raise ConnectionError("PS connection closed")
@@ -674,8 +715,11 @@ class _ServerConn:
         hdr = _REQ.pack(cmd, dtype, flags & 0xFFFF, req_id, worker_id, key,
                         len(payload))
         sock = self.sock   # the socket this send commits to (see except arm)
+        timed = self._core.trace_on
+        t0 = _now_us() if timed else 0
         try:
             with self.lock:
+                t1 = _now_us() if timed else 0
                 if len(payload) >= 65536:
                     # Zero-copy gather send for data partitions: the
                     # memoryview goes straight to the socket (the
@@ -688,6 +732,10 @@ class _ServerConn:
                     self._send_gather(sock, hdr, payload)
                 else:
                     sock.sendall(hdr + bytes(payload))
+                    self.send_calls += 1
+                if timed:
+                    self.send_lock_wait_us += t1 - t0
+                    self.send_us += _now_us() - t1
         except OSError as e:
             # Wake the receiver so IT drives the reconnect (single owner):
             # shut down the exact socket this send wrote to — if a re-dial
@@ -712,12 +760,14 @@ class _ServerConn:
         mv_h, mv_p = memoryview(hdr), memoryview(payload)
         total = len(mv_h) + len(mv_p)
         sent = sock.sendmsg([mv_h, mv_p])
+        self.send_calls += 1
         while sent < total:
             if sent < len(mv_h):
                 sent += sock.sendmsg([mv_h[sent:], mv_p])
             else:
                 sock.sendall(mv_p[sent - len(mv_h):])
                 sent = total
+            self.send_calls += 1
 
     def request(self, cmd: int, key: int = 0, payload: bytes = b"",
                 worker_id: int = 0, dtype: int = 0, flags: int = 0,
@@ -804,7 +854,7 @@ class _ServerConn:
         hdr = bytearray(_RESP.size)
         hdr_mv = memoryview(hdr)
         while True:
-            self._recv_into(hdr_mv)
+            self._recv_into(hdr_mv, timed=False)
             status, req_id, rkey, length = _RESP.unpack(hdr)
             # Pop BEFORE the payload read: this thread owns the future
             # (and its sink buffer) exclusively, so a concurrent
@@ -813,6 +863,9 @@ class _ServerConn:
             # it if the connection dies mid-payload — no orphaning.
             with self._pending_lock:
                 fut = self._pending.pop(req_id, None)
+            if fut is not None and fut.sent_us:
+                self.recv_first_byte_us += _now_us() - fut.sent_us
+                self.pulls += 1
             pooled = None
             try:
                 if (fut is not None and fut.sink is not None
@@ -995,14 +1048,18 @@ class _ServerConn:
         self._recv_into(memoryview(buf))
         return buf
 
-    def _recv_into(self, view: memoryview) -> None:
+    def _recv_into(self, view: memoryview, timed: bool = True) -> None:
         n = len(view)
         got = 0
+        t0 = _now_us() if timed and self._core.trace_on else 0
         while got < n:
             r = self.sock.recv_into(view[got:], n - got)
+            self.recv_calls += 1
             if r == 0:
                 raise ConnectionError("PS server closed connection")
             got += r
+        if t0:
+            self.recv_us += _now_us() - t0
 
     def close(self):
         with self._pending_lock:
@@ -1227,10 +1284,23 @@ class PSSession:
         "pool_buffers_held": 0,   # buffers currently on pool freelists
         "lane_bytes_total": 0,    # lifetime payload bytes across lanes
         "lane_outstanding_bytes": 0,  # payload bytes in flight right now
+        "send_calls": 0,          # socket calls that sent, all lanes
+        "recv_calls": 0,          # socket calls that received, all lanes
+        # The next five only grow while the core tracer is on:
+        "send_lock_wait_us": 0,   # senders' wait for a lane's send lock
+        "send_us": 0,             # inside the sending socket calls
+        "recv_us": 0,             # inside the payloads' receiving calls
+        "recv_first_byte_us": 0,  # pull issue -> its response header
+        "pulls": 0,               # pulls that recv_first_byte_us timed
         "lanes": [],              # per-lane rows: {server, lane,
         #                           transport, bytes_total,
-        #                           outstanding_bytes, sends}
+        #                           outstanding_bytes, sends,
+        #                           send_calls, recv_calls, busy_us}
     }
+    # The numeric wire counters above: sums of the lanes' own, and with
+    # `lane_busy_us` what a ROUND span carries the deltas of.
+    WIRE_COUNTS = ("send_calls", "recv_calls", "send_lock_wait_us",
+                   "send_us", "recv_us", "recv_first_byte_us", "pulls")
 
     def __init__(self, hosts: List[str], ports: List[int], worker_id: int,
                  num_servers: int, hash_fn: str = "djb2",
@@ -1582,7 +1652,7 @@ class PSSession:
         self._trace_members: Dict[int, list] = {}    # declared_key -> names
         # Main-thread stage spans of this session's rounds (ROUND, D2H,
         # STAGE, WAIT here; PACK, H2D, SCATTER in common/api.py).
-        self.spans = _stage_spans.RoundSpans()
+        self.spans = _stage_spans.RoundSpans(wire=self.wire_counts)
         # Metrics-registry feeds (common/telemetry.py).  The objects are
         # resolved once here; the per-partition hot path then pays only a
         # lock-free observe()/set() per event.  The queue-depth gauge
@@ -3218,13 +3288,14 @@ class PSSession:
         if not part.bidirectional and not part.audit and not health_due:
             sink = memoryview(part.handle.out).cast("B")[
                 part.off:part.off + part.ln]
+        traced = get_core().trace_on
         part.conn.send(
             CMD_PULL, part.pkey, worker_id=self.worker_id,
             dtype=DT_AUDIT_PULL if part.audit else 0,
-            flags=_round_flags(part.round, get_core().trace_on),
+            flags=_round_flags(part.round, traced),
             sink=sink,
             sink_live=lambda h=part.handle: not h.failed(),
-            pool_ok=True,
+            pool_ok=True, sent_us=_now_us() if traced else 0,
             callback=lambda data, err, pkey=part.pkey:
                 self._on_pull(pkey, data, err))
 
@@ -4537,13 +4608,28 @@ class PSSession:
                     "bytes_total": c.lane_bytes_total,
                     "outstanding_bytes": c.outstanding_bytes,
                     "sends": c.lane_sends,
+                    "send_calls": c.send_calls,
+                    "recv_calls": c.recv_calls,
+                    "busy_us": c.busy_us,
                 })
                 total_bytes += c.lane_bytes_total
                 outstanding += c.outstanding_bytes
         s["lane_bytes_total"] = total_bytes
         s["lane_outstanding_bytes"] = outstanding
         s["lanes"] = lanes
+        counts = self.wire_counts()
+        s.update((k, counts[k]) for k in self.WIRE_COUNTS)
         return s
+
+    def wire_counts(self) -> dict:
+        """Lifetime `WIRE_COUNTS` summed over every data lane, and
+        `lane_busy_us`, a lane's time with bytes outstanding so far, by
+        lane: what a `ROUND` span carries the deltas of."""
+        conns = [c for pool in self._data_conns for c in pool]
+        counts = {k: sum(getattr(c, k) for c in conns)
+                  for k in self.WIRE_COUNTS}
+        counts["lane_busy_us"] = [c.busy_us_now() for c in conns]
+        return counts
 
     def server_stats(self, timeout: float = 10.0) -> dict:
         """Server-side CMD_STATS snapshot, merged across all servers.

@@ -553,9 +553,12 @@ bps.shutdown()
 # The round from inside: main-thread stage spans (ISSUE 26)
 # ---------------------------------------------------------------------------
 _ROUND_JOB = """
-import json, jax, jax.numpy as jnp
+import json, os, jax, jax.numpy as jnp
 import byteps_tpu as bps
 from byteps_tpu.core.native import get_core
+from byteps_tpu.server import wire_floor
+start_peer = wire_floor._start_peer
+wire_floor._start_peer = lambda *a: print("PEER_STARTED") or start_peer(*a)
 bps.init()
 tree = {"a": jnp.ones((100, 3)), "b": jnp.ones(200),
         "c": jnp.full((300000,), 2.0), "d": jnp.ones((7, 5))}
@@ -565,7 +568,13 @@ for step in range(4):
     if step == 0:
         print("COUNT_OUTSIDE_WINDOW", get_core().trace_count())
     bps.mark_step()
+trace_dir = os.environ.get("BYTEPS_TRACE_DIR")
 bps.shutdown()
+floor = trace_dir and os.path.join(trace_dir, "0", "wire_floor.json")
+print("WIRE_FLOOR", json.dumps(json.load(open(floor)))
+      if floor and os.path.isfile(floor) else None)
+from byteps_tpu.common import telemetry
+print(telemetry.get_registry().render_prometheus())
 """
 
 
@@ -661,7 +670,8 @@ def test_round_spans_count_the_tree(round_events):
         assert a["units"] == len(d2h)
         assert a["bytes_out"] == a["bytes_in"] == tree_bytes
         assert set(a) == {"round", "units", "units_early", "bytes_out",
-                          "bytes_in", "minflt"}
+                          "bytes_in", "minflt", "lanes", "lane_busy_us",
+                          *PSSession.WIRE_COUNTS}
         # the process's minor page faults while the ROUND was open
         assert isinstance(a["minflt"], int) and a["minflt"] >= 0
         # a group queues each unit before the next one's copy begins
@@ -674,6 +684,106 @@ def test_round_spans_count_the_tree(round_events):
         for stage in ("D2H", "H2D", "SCATTER"):
             assert {e["args"]["key"] for e in kids
                     if e["tid"] == stage} == waited
+
+
+def test_round_counts_what_the_wire_waited_for(round_events):
+    """A traced ROUND carries what the session's lanes counted while it
+    was open (docs/timeline.md, "The round from inside")."""
+    events, _ = round_events
+    for rnd in (e for e in events if e["tid"] == "ROUND"):
+        a = rnd["args"]
+        pushes = [e for e in events if e["tid"] == "PUSH"
+                  and rnd["ts"] <= e["ts"] < _end(rnd)]
+        # a push and a pull request a partition at the least, and a
+        # header and a payload received for the pull
+        assert a["send_calls"] >= 2 * len(pushes) > 0
+        assert a["recv_calls"] >= 2 * len(pushes)
+        assert a["pulls"] == len(pushes)
+        for k in ("send_lock_wait_us", "send_us", "recv_us",
+                  "recv_first_byte_us"):
+            assert isinstance(a[k], int) and 0 <= a[k], k
+        # the socket calls are inside the round, on `lanes` threads
+        # that send and as many that receive
+        assert a["send_us"] + a["recv_us"] <= 2 * a["lanes"] * rnd["dur"]
+        assert a["lanes"] == 4 == len(a["lane_busy_us"])
+        assert all(0 <= b <= rnd["dur"] for b in a["lane_busy_us"])
+        assert sum(a["lane_busy_us"]) > 0
+    # the analyzer's mean per round, and its report
+    rounds = [e["args"] for e in events if e["tid"] == "ROUND"]
+    result = trace_analysis.analyze(events, worker=0)
+    got = result["round_wire"]
+    assert set(got) == {"lanes", "lane_busy_us", *PSSession.WIRE_COUNTS}
+    assert got["send_calls"] == sum(
+        a["send_calls"] for a in rounds) // len(rounds)
+    assert got["lane_busy_us"] == [
+        sum(a["lane_busy_us"][i] for a in rounds) // len(rounds)
+        for i in range(4)]
+    assert "what the wire waited for" in trace_analysis.format_report(
+        result)
+    for e in events:        # a program whose ROUND carries none
+        if e["tid"] == "ROUND":
+            e = dict(e, args={"round": e["args"]["round"]})
+            assert trace_analysis.round_wire([e]) == {}
+
+
+def test_a_traced_worker_leaves_its_floor_beside_comm_json(
+        round_events, tmp_path_factory):
+    """`bps.shutdown()` of the traced job probed the floor over the
+    session's own lanes (server/wire_floor.py)."""
+    _, out = round_events
+    assert out.count("PEER_STARTED") == 1
+    floor = json.loads(out.split("WIRE_FLOOR ", 1)[1].splitlines()[0])
+    assert (floor["transport"], floor["lanes"], floor["frame_bytes"],
+            floor["sock_buf_kb"]) == ("tcp", 4, 65536, 0)
+    tree_bytes = 4 * (300 + 200 + 300000 + 35)
+    for direction in ("out", "in", "duplex"):
+        got = floor[direction]
+        # the last traced round's bytes, in whole frames a lane
+        assert tree_bytes <= got["bytes"] / (1 + (direction == "duplex")) \
+            <= tree_bytes + 4 * 65536
+        assert got["GB_per_s"] == pytest.approx(
+            got["bytes"] / got["seconds"] / 1e9)
+    assert 'bps_wire_floor_gbps{dir="duplex"} ' + repr(
+        floor["duplex"]["GB_per_s"]) in out
+
+
+def test_an_untraced_round_reads_no_clock_and_starts_no_child(
+        ps_server, tmp_path, monkeypatch):  # noqa: F811
+    """With the tracer off the wire's counters make no clock read (they
+    all read `client._now_us`), a ROUND counts nothing, and shutdown
+    probes no floor; the calls are counted all the same."""
+    from byteps_tpu.server import client
+
+    def no_clock():
+        raise AssertionError("a clock read with the tracer off")
+
+    monkeypatch.setattr(client, "_now_us", no_clock)
+    sess = PSSession(["127.0.0.1"], [ps_server(num_workers=1)], worker_id=0,
+                     num_servers=1, partition_bytes=65536)
+    try:
+        x = np.arange(100000, dtype=np.float32)
+        with sess.spans.round("untraced"):
+            np.testing.assert_array_equal(sess.push_pull(7, x), x)
+        assert sess.spans.last is None
+        stats = sess.transport_stats()
+        assert set(PSSession.WIRE_COUNTS) <= set(stats)
+        assert set(PSSession.WIRE_COUNTS) <= set(
+            PSSession.TRANSPORT_ZERO_STATS)
+        parts = -(-x.nbytes // 65536)
+        assert stats["send_calls"] >= 2 * parts
+        assert stats["recv_calls"] >= 2 * parts
+        assert all(stats[k] == 0 for k in PSSession.WIRE_COUNTS
+                   if k.endswith("_us") or k == "pulls")
+        assert sum(r["send_calls"] for r in stats["lanes"]) \
+            == stats["send_calls"]
+        assert all(r["busy_us"] == 0 for r in stats["lanes"])
+    finally:
+        sess.close()
+    # the untraced twin of the traced job: no file, no child
+    out = _run_round_job(ps_server(num_workers=1), tmp_path, None,
+                         trace_on=False)
+    assert "WIRE_FLOOR None" in out and "PEER_STARTED" not in out
+    assert not list(tmp_path.rglob("*.json"))
 
 
 def test_round_breakdown_sums_to_the_round(round_events):

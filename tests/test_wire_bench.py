@@ -140,9 +140,10 @@ def test_wire_bench_sparse_sweep_smoke():
 @pytest.mark.parametrize("uds", [False, True], ids=["tcp", "uds"])
 def test_wire_bench_echo_floor_smoke(uds):
     """--echo-floor structural smoke on both transports: the bench emits
-    the pct_of_floor acceptance number itself (floor and PS goodput
-    measured in interleaved batches on the SAME transport), the server's
-    scatter path actually engaged, and the UDS run really rode AF_UNIX.
+    the pct_of_floor acceptance number itself (the package's floor probe,
+    server/wire_floor.py, and PS goodput measured in interleaved batches
+    on the SAME transport and lanes), the server's scatter path actually
+    engaged, and the UDS run really rode AF_UNIX.
     No threshold on pct here — shared CI hosts swing the floor ~2x; the
     number is the host's to state (docs/performance.md "Transport")."""
     r = subprocess.run([sys.executable, _TOOL, "--quick", "--json",
@@ -153,6 +154,9 @@ def test_wire_bench_echo_floor_smoke(uds):
     ef = json.loads(r.stdout)["echo_floor"]
     assert ef["transport"] == ("uds" if uds else "tcp")
     assert ef["floor_gbps"] > 0 and ef["goodput_gbps"] > 0
+    assert ef["lanes"] == 4                # the session's pool, dialled too
+    assert ef["floor_out_gbps"] > 0 and ef["floor_in_gbps"] > 0
+    assert ef["floor_gbps"] == max(ef["floor_batches_gbps"])
     assert ef["pct_of_floor"] == pytest.approx(
         100.0 * ef["goodput_gbps"] / ef["floor_gbps"], abs=0.1)
     assert ef["target_pct_of_floor"] == 85.0

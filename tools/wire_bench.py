@@ -49,7 +49,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
 
-from byteps_tpu.server import wire                      # noqa: E402
+from byteps_tpu.server import wire, wire_floor          # noqa: E402
 from byteps_tpu.server.client import PSSession          # noqa: E402
 from byteps_tpu.utils.hermetic import cpu_subprocess_env  # noqa: E402
 
@@ -263,91 +263,24 @@ def boot_server(extra_env=None):
     raise RuntimeError("PS server lost the port race 4 times")
 
 
-def measure_echo_floor(nbytes: int, reps: int,
-                       uds_path: str = "") -> float:
-    """Raw synchronous send+recv echo — the transport ceiling for a
-    Python client on this host, measured over the SAME transport the PS
-    session uses (loopback TCP, or AF_UNIX when ``uds_path`` is set):
-    no protocol, no framing, no summing, no store.  Returns GB/s of
-    2 * nbytes * reps (the echo moves each byte both ways)."""
-    import threading
-
-    if uds_path:
-        srv = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-        path = f"{uds_path}.echo.{os.getpid()}"
-        try:
-            os.unlink(path)
-        except OSError:
-            pass
-        srv.bind(path)
-        addr = path
-    else:
-        srv = socket.socket()
-        srv.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-        srv.bind(("127.0.0.1", 0))
-        addr = ("127.0.0.1", srv.getsockname()[1])
-    srv.listen(1)
-
-    def serve():
-        c, _ = srv.accept()
-        buf = bytearray(nbytes)
-        view = memoryview(buf)
-        for _ in range(reps + 1):
-            got = 0
-            while got < nbytes:
-                r = c.recv_into(view[got:], nbytes - got)
-                if r == 0:
-                    return
-                got += r
-            c.sendall(buf)
-
-    th = threading.Thread(target=serve, daemon=True)
-    th.start()
-    if uds_path:
-        c = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
-        c.connect(addr)
-    else:
-        c = socket.create_connection(addr)
-    data = bytes(nbytes)
-    out = bytearray(nbytes)
-    oview = memoryview(out)
-
-    def rt():
-        c.sendall(data)
-        got = 0
-        while got < nbytes:
-            got += c.recv_into(oview[got:], nbytes - got)
-
-    rt()  # warm
-    t0 = time.perf_counter()
-    for _ in range(reps):
-        rt()
-    dt = time.perf_counter() - t0
-    c.close()
-    srv.close()
-    if uds_path:
-        try:
-            os.unlink(path)
-        except OSError:
-            pass
-    return 2 * nbytes * reps / dt / 1e9
-
-
 def echo_floor_section(nbytes: int, part_bytes: int, reps: int,
                        uds: bool = False, wire_conns: int = 0) -> dict:
     """The ≥85%-of-wire-floor acceptance number, emitted by the bench
-    instead of hand-calculated: raw-socket echo floor and full-PS raw
-    push_pull goodput on the SAME host and transport, as a percentage.
+    instead of hand-calculated: the floor and full-PS raw push_pull
+    goodput on the SAME host, transport and lanes, as a percentage.
 
-    The PS goodput counts logical push+pull bytes (2 * tensor bytes per
-    round) against wall time — the same accounting as the floor's
-    send+recv — so pct_of_floor is exactly "how much of the achievable
-    wire rate the full KV semantics (partitioned, summed, round-tracked)
-    sustain"."""
+    The floor is the package's own probe (server/wire_floor.py, the one a
+    traced worker runs at shutdown): another PROCESS, as many lanes as
+    the session holds, frames of its partition size streamed both ways
+    at once (`duplex`).  The PS goodput counts logical push+pull bytes
+    (2 * tensor bytes per round) against wall time — the same accounting
+    as duplex's out+in — so pct_of_floor is exactly "how much of the
+    achievable wire rate the full KV semantics (partitioned, summed,
+    round-tracked) sustain"."""
     uds_path = f"/tmp/bps_wire_bench_{os.getpid()}" if uds else ""
     batches = 4
     batch_reps = max(2, reps // batches)
-    _log(f"  echo floor ({nbytes / 1e6:.0f} MB, {batches} interleaved "
+    _log(f"  wire floor ({nbytes / 1e6:.0f} MB, {batches} interleaved "
          f"batches x {batch_reps} reps, {'uds' if uds else 'tcp'}) ...")
     proc, port = boot_server(
         {"BYTEPS_TPU_SERVER_UDS": uds_path} if uds else None)
@@ -366,15 +299,20 @@ def echo_floor_section(nbytes: int, part_bytes: int, reps: int,
         # single floor-then-PS sequence reports whatever the host was
         # doing that second.  Alternating short batches and taking each
         # side's best compares like with like.
-        floors, goods = [], []
+        probes, goods = [], []
         for _ in range(batches):
-            floors.append(measure_echo_floor(nbytes, batch_reps,
-                                             uds_path=uds_path))
+            got = wire_floor.probe_session(
+                sess, bytes_out=nbytes * batch_reps,
+                bytes_in=nbytes * batch_reps)
+            if got is None:
+                raise RuntimeError("the wire floor probe failed")
+            probes.append(got)
             t0 = time.perf_counter()
             for _ in range(batch_reps):
                 sess.push_pull(1, x)
             goods.append(2 * x.nbytes * batch_reps
                          / (time.perf_counter() - t0) / 1e9)
+        floors = [p["duplex"]["GB_per_s"] for p in probes]
         floor, goodput = max(floors), max(goods)
         stats = sess.server_stats()
         tstats = sess.transport_stats()
@@ -387,8 +325,13 @@ def echo_floor_section(nbytes: int, part_bytes: int, reps: int,
         "tensor_mb": round(nbytes / 1e6, 1),
         "partitions": (nbytes + part_bytes - 1) // part_bytes,
         "reps": batches * batch_reps,
+        "lanes": probes[0]["lanes"],
         "floor_gbps": round(floor, 3),
         "floor_batches_gbps": [round(f, 3) for f in floors],
+        "floor_out_gbps": round(max(p["out"]["GB_per_s"]
+                                    for p in probes), 3),
+        "floor_in_gbps": round(max(p["in"]["GB_per_s"]
+                                   for p in probes), 3),
         "goodput_gbps": round(goodput, 3),
         "goodput_batches_gbps": [round(g, 3) for g in goods],
         "pct_of_floor": round(100.0 * goodput / floor, 1),
@@ -721,11 +664,11 @@ def main(argv=None) -> int:
 
     if args.echo_floor:
         # The acceptance workload: 4 MiB partitions, raw f32, same-host
-        # echo floor on the same transport.  16 MB tensor under --quick
+        # wire floor on the same transport and lanes.  16 MB tensor under --quick
         # keeps the CI smoke short; 64 MB otherwise.
         ef_bytes = (16 << 20) if quick else (64 << 20)
         ef_reps = args.rounds or (5 if quick else 15)
-        _log(f"wire_bench: echo floor vs PS goodput "
+        _log(f"wire_bench: wire floor vs PS goodput "
              f"({ef_bytes >> 20} MB, 4 MiB partitions, {ef_reps} reps)")
         ef = echo_floor_section(ef_bytes, 4 << 20, ef_reps, uds=args.uds,
                                 wire_conns=args.wire_conns)
