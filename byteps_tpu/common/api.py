@@ -1898,8 +1898,11 @@ def get_transport_stats() -> Dict[str, int]:
     `pool_buffers_held`, aggregate `lane_bytes_total`/
     `lane_outstanding_bytes`, and a per-lane `lanes` row list ({server,
     lane, transport(tcp|uds), bytes_total, outstanding_bytes, sends} —
-    the byte-credit scheduler's working signal).  The get_codec_stats()
-    analog for the transport layer; all-zero outside PS mode.  Numeric
+    the byte-credit scheduler's working signal), and the wire's own
+    counts (`PSSession.WIRE_COUNTS`: socket calls, `push_handoffs`, the
+    pushes the lanes' senders sent, and, while the tracer is on, the
+    times of docs/timeline.md "What the wire waited for").  The
+    get_codec_stats() analog for the transport layer; all-zero outside PS mode.  Numeric
     keys export through the metrics registry's transport collector
     (`bps_transport_*`); the `lanes` list is accessor-only."""
     if _state.ps_session is not None:

@@ -96,7 +96,9 @@ def analyze(events: List[dict], worker: int = 0, top_k: int = 5) -> dict:
          "top_blocking": [{"name", "total_us", "members"}],
          "straggler_wait_us": {worker_id: us},
          "round_breakdown_us": {"round", "pack", ..., "unspanned": us},
-         "round_wire": {"send_calls", ..., "lanes", "lane_busy_us": [us]}}
+         "round_wire": {"send_calls", ..., "push_handoffs", "send_wall_us",
+                        "handoff_wait_us", "lanes_sending", "lanes",
+                        "lane_busy_us": [us]}}
     """
     xs = [e for e in events if e.get("ph") == "X"]
     # Worker-side spans and STEP envelopes are filtered to the selected
@@ -257,7 +259,11 @@ def round_wire(spans: List[dict], worker: int = 0) -> Dict[str, object]:
     """Mean per ``ROUND`` of what the session's lanes counted while it
     was open (its ``args``, but for the stage counts that
     ``round_breakdown`` and the byte counts cover); ``lane_busy_us`` a
-    list, by lane.  Empty where no ``ROUND`` carries the counts."""
+    list, by lane.  ``lanes_sending``, where the program counts
+    ``send_wall_us``: the rounds' ``send_us`` over it, the mean number
+    of lanes inside a sending call while any was (1.0 for one sender,
+    towards ``lanes`` for a sender a lane).  Empty where no ``ROUND``
+    carries the counts."""
     args = [e["args"] for e in spans if e.get("pid") == worker
             and e.get("tid") == "ROUND" and "send_calls" in e["args"]]
     if not args:
@@ -267,6 +273,10 @@ def round_wire(spans: List[dict], worker: int = 0) -> Dict[str, object]:
         k: sum(a[k] for a in args) // n for k in args[0]
         if k not in ("round", "units", "units_early", "bytes_out",
                      "bytes_in", "minflt", "lanes", "lane_busy_us")}
+    wall = sum(a.get("send_wall_us", 0) for a in args)
+    if wall:
+        out["lanes_sending"] = round(
+            sum(a["send_us"] for a in args) / wall, 3)
     out["lanes"] = max(a["lanes"] for a in args)
     out["lane_busy_us"] = [
         sum(a["lane_busy_us"][i] for a in args

@@ -15,6 +15,16 @@ TPU-host redesign of that data path:
     ScheduledQueue, gated by a credit of
     BYTEPS_SCHEDULING_CREDIT x BYTEPS_PARTITION_BYTES bytes in flight;
     completions return credit (reference: scheduled_queue.cc:26-46,136-139),
+  - the dispatcher decides and a sender a lane writes: every data lane
+    has a sender thread beside its receiver, which owns the socket's
+    write side for payload frames.  The dispatcher hands a push to the
+    lane it picked and goes back for the next, so a round's pushes are
+    inside as many `sendmsg` calls at once as the session has lanes.  A
+    lane holds at most HANDOFF_DEPTH frames behind the one being sent,
+    and the dispatcher pops nothing while no lane has a place, so a late
+    high-priority partition still overtakes what is in the queue; a
+    pull's request goes ahead of the pushes waiting there, and the
+    receiver that issues it never waits for the socket,
   - each connection multiplexes outstanding requests by req_id, the
     redesign of ps-lite's completion callbacks (core_loops.cc:536-616),
     so per-partition pushes/pulls to one server pipeline instead of
@@ -470,6 +480,46 @@ def _now_us() -> int:
     return time.monotonic_ns() // 1000
 
 
+# Frames a lane's sender may hold BEHIND the one it is sending.  Shallow
+# on purpose: at scheduling credit 0 the hand-over is the dispatcher's
+# only back-pressure, and what it has handed over a late high-priority
+# partition can no longer overtake.  One keeps a sender from ever
+# waiting for the dispatcher between two frames.
+HANDOFF_DEPTH = 1
+
+
+class _SendWall:
+    """Time with AT LEAST ONE of a session's senders inside a sending
+    call: the lanes' `send_us` over it is the mean number of lanes
+    sending at once.  Kept only while the core tracer is on, from clock
+    reads `_ServerConn.send` makes anyway."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._active = 0
+        self._since = 0
+        self._us = 0
+
+    def enter(self, now: int) -> None:
+        with self._lock:
+            if not self._active:
+                self._since = now
+            self._active += 1
+
+    def leave(self, now: int) -> None:
+        with self._lock:
+            self._active -= 1
+            if not self._active:
+                self._us += now - self._since
+
+    def us_now(self) -> int:
+        """The time so far, an open stretch counted up to now."""
+        with self._lock:
+            if not self._active:
+                return self._us
+            return self._us + _now_us() - self._since
+
+
 class _Future:
     """Completion slot for one outstanding request."""
 
@@ -530,7 +580,10 @@ class _ServerConn:
 
     Any thread may `send`; a dedicated receiver thread matches responses to
     futures by req_id and runs completion callbacks (the ZPush/ZPull
-    callback model, reference: core_loops.cc:564-616).
+    callback model, reference: core_loops.cc:564-616).  A round's frames
+    do not block their issuer in `send`: `hand_over` gives them to the
+    lane's sender thread, which sends them in the order handed, a pull's
+    request ahead of the pushes waiting (`_send_loop`).
 
     With ``reconnect_attempts > 0`` the connection survives transport
     faults: on a drop the receiver resolves every pending future with a
@@ -549,7 +602,9 @@ class _ServerConn:
                  on_give_up: Optional[Callable] = None,
                  uds_path: str = "",
                  sock_buf_kb: int = 0,
-                 recv_pool: Optional[_RecvBufPool] = None):
+                 recv_pool: Optional[_RecvBufPool] = None,
+                 send_wall: Optional[_SendWall] = None,
+                 on_room: Optional[Callable] = None):
         self.host, self.port = host, port
         self.timeout = timeout
         self.reconnect_attempts = max(0, int(reconnect_attempts))
@@ -595,6 +650,21 @@ class _ServerConn:
         self.pulls = 0
         self.busy_us = 0
         self.busy_since = 0
+        # The sender's hand-over.  `_ahead` holds requests with no
+        # payload (a pull's), sent before anything in `_waiting` (the
+        # pushes); `frames_held` counts the pushes handed over and not
+        # yet through `send`, the one being sent included, and
+        # `on_room` tells the dispatcher when it falls.  `push_handoffs`
+        # counts the pushes this lane's sender sent; `_send_wall` is the
+        # session's (None for a connection of no session).
+        self._handoff = threading.Condition()
+        self._ahead: deque = deque()
+        self._waiting: deque = deque()
+        self._sender_done = False
+        self.frames_held = 0
+        self.push_handoffs = 0
+        self._send_wall = send_wall
+        self._on_room = on_room
         # WIRE_CONNS knob: a retiring lane takes no NEW dispatches
         # (excluded from _pick_lane) while its outstanding bytes drain;
         # the resize worker closes it once quiet (_resize_lanes).
@@ -613,6 +683,9 @@ class _ServerConn:
         self._recv_thread = threading.Thread(
             target=self._recv_loop, daemon=True, name="bps-ps-recv")
         self._recv_thread.start()
+        self._send_thread = threading.Thread(
+            target=self._send_loop, daemon=True, name="bps-ps-send")
+        self._send_thread.start()
 
     def _dial(self) -> socket.socket:
         if self.uds_path:
@@ -717,25 +790,33 @@ class _ServerConn:
         sock = self.sock   # the socket this send commits to (see except arm)
         timed = self._core.trace_on
         t0 = _now_us() if timed else 0
+        wall = self._send_wall if timed else None
         try:
             with self.lock:
                 t1 = _now_us() if timed else 0
-                if len(payload) >= 65536:
-                    # Zero-copy gather send for data partitions: the
-                    # memoryview goes straight to the socket (the
-                    # reference's ZPush zero-copy SArray stance,
-                    # core_loops.cc:564-569) and header+payload ride ONE
-                    # sendmsg — under TCP_NODELAY a separate header
-                    # sendall is its own packet + syscall + server-reader
-                    # wakeup per partition (mirror of the server-side
-                    # Respond coalescing).
-                    self._send_gather(sock, hdr, payload)
-                else:
-                    sock.sendall(hdr + bytes(payload))
-                    self.send_calls += 1
-                if timed:
-                    self.send_lock_wait_us += t1 - t0
-                    self.send_us += _now_us() - t1
+                if wall is not None:
+                    wall.enter(t1)
+                try:
+                    if len(payload) >= 65536:
+                        # Zero-copy gather send for data partitions: the
+                        # memoryview goes straight to the socket (the
+                        # reference's ZPush zero-copy SArray stance,
+                        # core_loops.cc:564-569) and header+payload ride
+                        # ONE sendmsg — under TCP_NODELAY a separate
+                        # header sendall is its own packet + syscall +
+                        # server-reader wakeup per partition (mirror of
+                        # the server-side Respond coalescing).
+                        self._send_gather(sock, hdr, payload)
+                    else:
+                        sock.sendall(hdr + bytes(payload))
+                        self.send_calls += 1
+                finally:
+                    if timed:
+                        t2 = _now_us()
+                        self.send_lock_wait_us += t1 - t0
+                        self.send_us += t2 - t1
+                        if wall is not None:
+                            wall.leave(t2)
         except OSError as e:
             # Wake the receiver so IT drives the reconnect (single owner):
             # shut down the exact socket this send wrote to — if a re-dial
@@ -768,6 +849,77 @@ class _ServerConn:
                 sock.sendall(mv_p[sent - len(mv_h):])
                 sent = total
             self.send_calls += 1
+
+    # -- the lane's sender ---------------------------------------------
+    def has_room(self) -> bool:
+        """Whether the dispatcher may hand this lane another push: at
+        most HANDOFF_DEPTH wait behind the one being sent."""
+        return self.frames_held <= HANDOFF_DEPTH
+
+    def quiet(self) -> bool:
+        """Nothing handed over that the sender has not dealt with."""
+        with self._handoff:
+            return not (self.frames_held or self._ahead)
+
+    def hand_over(self, on_error: Callable[[Exception], None], cmd: int,
+                  key: int = 0, payload: bytes = b"", **kw) -> None:
+        """Give one frame to this lane's sender and return at once; the
+        sender calls `send(cmd, key, payload, **kw)`.  A frame with no
+        payload goes ahead of every frame with one, so a receiver's pull
+        never waits behind more than the push inside its `sendmsg`;
+        among their kind frames leave in the order handed.  Whatever
+        `send` would have raised goes to `on_error` instead, on the
+        sender's thread, or here where the sender has gone (a closed
+        connection)."""
+        frame = (on_error, cmd, key, payload, kw)
+        with self._handoff:
+            gone = self._sender_done
+            if not gone:
+                if len(payload):
+                    self._waiting.append(frame)
+                    self.frames_held += 1
+                else:
+                    self._ahead.append(frame)
+                self._handoff.notify()
+        if gone:
+            on_error(ConnectionError("PS connection closed"))
+
+    def _send_loop(self) -> None:
+        """The sender: owns the socket's write side for what `hand_over`
+        brings.  It ends once the connection is closed for good
+        (`_fail_pending`) and nothing waits; what still waits then fails
+        in `send`, so every frame handed over meets `send` or
+        `on_error`, once."""
+        while True:
+            with self._handoff:
+                while not (self._ahead or self._waiting):
+                    if self._sender_done:
+                        return
+                    self._handoff.wait()
+                held = not self._ahead
+                on_error, cmd, key, payload, kw = (
+                    self._waiting if held else self._ahead).popleft()
+            try:
+                self.send(cmd, key, payload, **kw)
+                if held:
+                    self.push_handoffs += 1
+            except Exception as e:
+                try:
+                    on_error(e)
+                except Exception:
+                    get_logger().exception("PS send-failure handler failed")
+            if held:
+                with self._handoff:
+                    self.frames_held -= 1
+                if self._on_room is not None:
+                    self._on_room()
+
+    def join_sender(self, timeout: Optional[float]) -> threading.Thread:
+        """Wait for the sender of a closed connection to end; returns
+        its thread (alive still where the wait ran out)."""
+        if threading.current_thread() is not self._send_thread:
+            self._send_thread.join(timeout)
+        return self._send_thread
 
     def request(self, cmd: int, key: int = 0, payload: bytes = b"",
                 worker_id: int = 0, dtype: int = 0, flags: int = 0,
@@ -1034,6 +1186,9 @@ class _ServerConn:
         with self._pending_lock:
             self._closed = True
             pending, self._pending = self._pending, {}
+        with self._handoff:       # every way to `_closed` comes by here
+            self._sender_done = True
+            self._handoff.notify()
         for fut in pending.values():
             try:
                 fut.resolve(b"", ConnectionError(f"PS connection lost: {exc}"))
@@ -1292,15 +1447,26 @@ class PSSession:
         "recv_us": 0,             # inside the payloads' receiving calls
         "recv_first_byte_us": 0,  # pull issue -> its response header
         "pulls": 0,               # pulls that recv_first_byte_us timed
+        "push_handoffs": 0,       # pushes the lanes' senders sent
+        # and these two, like the five, only while the tracer is on:
+        "send_wall_us": 0,        # with at least one sender sending:
+        #                           send_us over it = lanes sending at once
+        "handoff_wait_us": 0,     # the dispatcher's wait for a lane to
+        #                           have a place for the next push
         "lanes": [],              # per-lane rows: {server, lane,
         #                           transport, bytes_total,
         #                           outstanding_bytes, sends,
-        #                           send_calls, recv_calls, busy_us}
+        #                           send_calls, recv_calls,
+        #                           push_handoffs, busy_us}
     }
-    # The numeric wire counters above: sums of the lanes' own, and with
-    # `lane_busy_us` what a ROUND span carries the deltas of.
-    WIRE_COUNTS = ("send_calls", "recv_calls", "send_lock_wait_us",
-                   "send_us", "recv_us", "recv_first_byte_us", "pulls")
+    # The numeric wire counters above: sums of the lanes' own and the
+    # two the session keeps (one clock over all its senders, one
+    # dispatcher), and with `lane_busy_us` what a ROUND span carries the
+    # deltas of.
+    LANE_COUNTS = ("send_calls", "recv_calls", "send_lock_wait_us",
+                   "send_us", "recv_us", "recv_first_byte_us", "pulls",
+                   "push_handoffs")
+    WIRE_COUNTS = LANE_COUNTS + ("send_wall_us", "handoff_wait_us")
 
     def __init__(self, hosts: List[str], ports: List[int], worker_id: int,
                  num_servers: int, hash_fn: str = "djb2",
@@ -1451,6 +1617,8 @@ class PSSession:
         per-connection threads).  Control traffic (barrier/hello/
         shutdown) stays on the primary."""
         self._recv_pool = _RecvBufPool()
+        self._send_wall = _SendWall()
+        self.handoff_wait_us = 0
         self._wire_conns = wire_conns
         self._hosts, self._ports = list(hosts), list(ports)
 
@@ -1487,7 +1655,21 @@ class PSSession:
             uds_path=(self.uds_path
                       if h in self._LOOPBACK_HOSTS else ""),
             sock_buf_kb=self.sock_buf_kb,
-            recv_pool=self._recv_pool)
+            recv_pool=self._recv_pool,
+            send_wall=self._send_wall,
+            on_room=self._wake_dispatcher)
+
+    def _wake_dispatcher(self) -> None:
+        with self._cv:
+            self._cv.notify_all()
+
+    def _close_lanes(self, join_timeout: float) -> None:
+        """Close every data lane, then see its sender out."""
+        conns = [c for pool in self._data_conns for c in pool]
+        for c in conns:
+            c.close()
+        for c in conns:
+            self._warn_if_wedged(c.join_sender(join_timeout))
 
     def _abort_init(self) -> None:
         _flightrec.remove_extra_provider("session", owner=self)
@@ -1507,9 +1689,7 @@ class PSSession:
             self._warn_if_wedged(self._dispatcher)
         if getattr(self, "_codec_pool", None) is not None:
             self._codec_pool.close()
-        for pool in self._data_conns:
-            for c in pool:
-                c.close()
+        self._close_lanes(5)
 
     def _init_state(self, scheduling_credit: int) -> None:
         self._inited: Dict[int, tuple] = {}     # pkey -> (length, kwargs)
@@ -2545,15 +2725,15 @@ class PSSession:
 
     def _drain_retired_lanes(self, to_drain: List[tuple]) -> None:
         """Close retiring lanes once quiet: outstanding byte credit
-        returned AND no response outstanding — a lane is never cut with
-        a round trip in flight, so a WIRE_CONNS shrink can never lose a
-        push ack or a pull payload."""
+        returned, no response outstanding AND nothing in its sender's
+        hand-over — a lane is never cut with a round trip in flight, so
+        a WIRE_CONNS shrink can never lose a push ack or a pull payload."""
         deadline = time.monotonic() + 60.0
         for pool, c in to_drain:
             while time.monotonic() < deadline:
                 with c._pending_lock:
                     busy = bool(c._pending)
-                if c.outstanding_bytes <= 0 and not busy:
+                if c.outstanding_bytes <= 0 and not busy and c.quiet():
                     break
                 time.sleep(0.02)
             else:
@@ -2566,6 +2746,7 @@ class PSSession:
                 pass
             try:
                 c.close()
+                c.join_sender(5)
             except Exception:
                 pass
 
@@ -3106,7 +3287,27 @@ class PSSession:
         live = [c for c in pool
                 if not getattr(c, "retiring", False)] or pool
         up = [c for c in live if c.state() == "up"] or live
+        # Among the lanes whose sender has a place for a frame: the
+        # dispatcher waits for one before it picks (_await_room).
+        up = [c for c in up if c.has_room()] or up
         return min(up, key=lambda c: (c.outstanding_bytes, c.lane_sends))
+
+    def _await_room(self, pools) -> bool:
+        """Hold the dispatcher until a lane of `pools` has a place for a
+        push (`_ServerConn.has_room`; a sender that frees one notifies
+        `_cv`) or the session closes (False).  The hand-over's
+        back-pressure: `handoff_wait_us` is the time spent here."""
+        def room():
+            return any(c.has_room() for pool in pools for c in pool)
+        if room():
+            return True
+        t0 = _now_us() if get_core().trace_on else 0
+        with self._cv:
+            while not self._closed and not room():
+                self._cv.wait(timeout=1.0)
+        if t0:
+            self.handoff_wait_us += _now_us() - t0
+        return not self._closed
 
     def _lane_settle(self, part: "_PartTask") -> None:
         """Return a partition's outstanding-byte charge to its lane —
@@ -3118,7 +3319,15 @@ class PSSession:
 
     # -- dispatcher ---------------------------------------------------------
     def _dispatch_loop(self) -> None:
+        """Decide, in (priority desc, key asc) order, which partition
+        leaves next and on which lane; the lane's sender writes it
+        (`_ServerConn.hand_over`).  Nothing is popped while no lane has
+        a place, so what is popped is handed over at once and a
+        partition staged late is weighed against everything still in
+        the queue."""
         while True:
+            if not self._await_room(self._data_conns):
+                return
             with self._cv:
                 while not self._closed and (
                         self._paused or self._queue.pending() == 0):
@@ -3194,19 +3403,29 @@ class PSSession:
             # Byte-credit lane pick, charged with the push payload plus
             # the expected pull reply (both legs ride this conn).
             self._lane_settle(part)     # replays drop any stale charge
+            if not self._await_room([self._data_conns[part.srv]]):
+                self._queue.report_finish(nbytes)
+                return
             part.conn = self._pick_lane(part.srv, part.wire_ln + part.ln)
             part.lane_debt = part.wire_ln + part.ln
-            try:
-                part.conn.send(
-                    CMD_PUSH, pkey, part.payload, worker_id=self.worker_id,
-                    dtype=part.dtype,
-                    flags=_round_flags(part.round, core.trace_on),
-                    callback=lambda data, err, pkey=pkey, nbytes=nbytes:
-                        self._on_push_ack(pkey, nbytes, err))
-            except ConnectionError as e:
-                self._queue.report_finish(nbytes)
-                if not self._park_part(pkey, "push", e):
-                    self._finish_part(pkey, e)
+            part.conn.hand_over(
+                lambda e, pkey=pkey, nbytes=nbytes:
+                    self._on_push_unsent(pkey, nbytes, e),
+                CMD_PUSH, pkey, part.payload, worker_id=self.worker_id,
+                dtype=part.dtype,
+                flags=_round_flags(part.round, core.trace_on),
+                callback=lambda data, err, pkey=pkey, nbytes=nbytes:
+                    self._on_push_ack(pkey, nbytes, err))
+
+    def _on_push_unsent(self, pkey: int, nbytes: int,
+                        error: Exception) -> None:
+        """A push's `send` raised, on its lane's sender: the queue's
+        credit comes back and the partition is parked for replay or
+        fails its handle, as after a push whose ack was lost."""
+        self._queue.report_finish(nbytes)
+        self._wake_dispatcher()
+        if not self._park_part(pkey, "push", error):
+            self._finish_part(pkey, error)
 
     def _on_push_ack(self, pkey: int, nbytes: int,
                      error: Optional[Exception]) -> None:
@@ -3258,15 +3477,19 @@ class PSSession:
             core.trace_record_part(part.label, "PUSH", part.push_ts,
                                    part.pull_ts - part.push_ts, pkey,
                                    part.wire_ln, part.priority)
-        try:
-            self._issue_pull(part)
-        except ConnectionError as e:
-            if not self._park_part(pkey, "pull", e):
-                self._finish_part(pkey, e)
+        self._issue_pull(part)
+
+    def _on_pull_unsent(self, pkey: int, error: Exception) -> None:
+        """A pull request's `send` raised: park the leg or fail the
+        handle, as after a pull whose response was lost."""
+        if not self._park_part(pkey, "pull", error):
+            self._finish_part(pkey, error)
 
     def _issue_pull(self, part: "_PartTask") -> None:
-        """Send one partition's pull leg (first issue and replay share
-        this).  Raises ConnectionError if the conn can't take it."""
+        """Hand one partition's pull leg to its lane's sender (first
+        issue and replay share this), ahead of the pushes waiting there:
+        the caller is the lane's receiver as a rule, and must get back
+        to its socket."""
         # Non-compressed pulls land straight in the output buffer (the
         # receiver matches on length); bidirectional compressed pulls
         # come back re-encoded at a different length and take the
@@ -3289,7 +3512,8 @@ class PSSession:
             sink = memoryview(part.handle.out).cast("B")[
                 part.off:part.off + part.ln]
         traced = get_core().trace_on
-        part.conn.send(
+        part.conn.hand_over(
+            lambda e, pkey=part.pkey: self._on_pull_unsent(pkey, e),
             CMD_PULL, part.pkey, worker_id=self.worker_id,
             dtype=DT_AUDIT_PULL if part.audit else 0,
             flags=_round_flags(part.round, traced),
@@ -4610,6 +4834,7 @@ class PSSession:
                     "sends": c.lane_sends,
                     "send_calls": c.send_calls,
                     "recv_calls": c.recv_calls,
+                    "push_handoffs": c.push_handoffs,
                     "busy_us": c.busy_us,
                 })
                 total_bytes += c.lane_bytes_total
@@ -4627,7 +4852,9 @@ class PSSession:
         lane: what a `ROUND` span carries the deltas of."""
         conns = [c for pool in self._data_conns for c in pool]
         counts = {k: sum(getattr(c, k) for c in conns)
-                  for k in self.WIRE_COUNTS}
+                  for k in self.LANE_COUNTS}
+        counts["send_wall_us"] = self._send_wall.us_now()
+        counts["handoff_wait_us"] = self.handoff_wait_us
         counts["lane_busy_us"] = [c.busy_us_now() for c in conns]
         return counts
 
@@ -6135,14 +6362,13 @@ class PSSession:
             self._m_queue_depth.set(0)
         # Dispatcher first (it may be waiting on an encode the pool still
         # owes), then the codec pool (drains queued jobs so every staged
-        # handle resolves), then the sockets.
+        # handle resolves), then the sockets and, once a closed socket
+        # has let go of them, the lanes' senders.
         self._dispatcher.join(timeout=self._join_timeout_s)
         self._warn_if_wedged(self._dispatcher)
         if self._codec_pool is not None:
             self._codec_pool.close()
-        for pool in self._data_conns:
-            for c in pool:
-                c.close()
+        self._close_lanes(self._join_timeout_s)
         if self._watchdog is not None:
             self._watchdog.join(timeout=5)
 

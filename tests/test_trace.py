@@ -712,7 +712,8 @@ def test_round_counts_what_the_wire_waited_for(round_events):
     rounds = [e["args"] for e in events if e["tid"] == "ROUND"]
     result = trace_analysis.analyze(events, worker=0)
     got = result["round_wire"]
-    assert set(got) == {"lanes", "lane_busy_us", *PSSession.WIRE_COUNTS}
+    assert set(got) == {"lanes", "lane_busy_us", "lanes_sending",
+                        *PSSession.WIRE_COUNTS}
     assert got["send_calls"] == sum(
         a["send_calls"] for a in rounds) // len(rounds)
     assert got["lane_busy_us"] == [
@@ -724,6 +725,66 @@ def test_round_counts_what_the_wire_waited_for(round_events):
         if e["tid"] == "ROUND":
             e = dict(e, args={"round": e["args"]["round"]})
             assert trace_analysis.round_wire([e]) == {}
+
+
+def test_round_counts_who_sent_its_pushes(round_events):
+    """A traced ROUND carries the sender-a-lane counts: every push of it
+    left through a lane's sender, some sender was inside a sending call
+    for no longer than the round, and `send_us` over that time, the
+    lanes sending at once, lies between one and the lanes."""
+    events, _ = round_events
+    rounds = [e for e in events if e["tid"] == "ROUND"]
+    for rnd in rounds:
+        a = rnd["args"]
+        pushes = [e for e in events if e["tid"] == "PUSH"
+                  and rnd["ts"] <= e["ts"] < _end(rnd)]
+        assert a["push_handoffs"] == len(pushes) > 0
+        assert 0 < a["send_wall_us"] <= rnd["dur"]
+        assert a["send_wall_us"] <= a["send_us"] \
+            <= a["lanes"] * a["send_wall_us"]
+        assert isinstance(a["handoff_wait_us"], int)
+        assert 0 <= a["handoff_wait_us"] <= rnd["dur"]
+    result = trace_analysis.analyze(events, worker=0)
+    got = result["round_wire"]
+    assert got["push_handoffs"] == sum(
+        r["args"]["push_handoffs"] for r in rounds) // len(rounds)
+    assert 1.0 <= got["lanes_sending"] <= got["lanes"]
+    assert got["lanes_sending"] == pytest.approx(
+        sum(r["args"]["send_us"] for r in rounds)
+        / sum(r["args"]["send_wall_us"] for r in rounds), abs=1e-3)
+    report = trace_analysis.format_report(result)
+    for word in ("lanes_sending", "push_handoffs", "handoff_wait"):
+        assert word in report
+    # a program whose ROUND has no `send_wall_us` (before the senders)
+    old = [dict(e, args={k: v for k, v in e["args"].items()
+                         if k != "send_wall_us"}) for e in rounds]
+    assert "lanes_sending" not in trace_analysis.round_wire(old)
+
+
+def test_trace_analyze_round_wire_prints_lanes_sending():
+    """`tools/trace_analyze.py`'s `round_wire` on two recorded ROUNDs:
+    means a round, and the senders' three counts as it prints them."""
+    def rnd(n, send_us, wall_us, handoffs, wait_us):
+        return {"ph": "X", "pid": 0, "tid": "ROUND", "name": "t", "ts": n,
+                "dur": 1000, "args": {
+                    "round": n, "units": 1, "units_early": 0,
+                    "bytes_out": 8, "bytes_in": 8, "minflt": 0,
+                    "send_calls": 4, "recv_calls": 8,
+                    "send_lock_wait_us": 2, "send_us": send_us,
+                    "recv_us": 50, "recv_first_byte_us": 20, "pulls": 2,
+                    "push_handoffs": handoffs, "send_wall_us": wall_us,
+                    "handoff_wait_us": wait_us, "lanes": 4,
+                    "lane_busy_us": [10, 20, 30, 40]}}
+    got = trace_analysis.round_wire([rnd(1, 900, 300, 340, 250),
+                                     rnd(2, 700, 340, 342, 150)])
+    assert got["lanes_sending"] == 2.5            # 1600 / 640
+    assert got["push_handoffs"] == 341 and got["handoff_wait_us"] == 200
+    assert got["send_wall_us"] == 320 and got["lanes"] == 4
+    report = trace_analysis.format_report({"round_wire": got})
+    lines = {l.split()[0]: l.split()[1:] for l in report.splitlines()[1:]}
+    assert lines["lanes_sending"] == ["2.5"]
+    assert lines["push_handoffs"] == ["341"]
+    assert lines["handoff_wait"] == ["200us"]
 
 
 def test_a_traced_worker_leaves_its_floor_beside_comm_json(
@@ -772,6 +833,7 @@ def test_an_untraced_round_reads_no_clock_and_starts_no_child(
         parts = -(-x.nbytes // 65536)
         assert stats["send_calls"] >= 2 * parts
         assert stats["recv_calls"] >= 2 * parts
+        assert stats["push_handoffs"] == parts    # on the senders' threads
         assert all(stats[k] == 0 for k in PSSession.WIRE_COUNTS
                    if k.endswith("_us") or k == "pulls")
         assert sum(r["send_calls"] for r in stats["lanes"]) \

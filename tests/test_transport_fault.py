@@ -25,7 +25,7 @@ import pytest
 
 from byteps_tpu.server.client import (
     PSSession, PSHandle, _ServerConn, _REQ, _RESP,
-    CMD_PING, CMD_PULL,
+    CMD_PING, CMD_PULL, CMD_PUSH,
 )
 from byteps_tpu.common.logging import get_logger
 
@@ -253,6 +253,129 @@ def test_reconnect_recovers_midpayload_reset_raw(ps_server):
         # The session keeps working for later rounds.
         np.testing.assert_array_equal(s.push_pull(4, warm), warm)
         s.close()
+
+
+class _CreditBook:
+    """The scheduler queue with its credit counted: the bytes of what
+    the dispatcher popped, and the bytes given back."""
+
+    def __init__(self, queue):
+        self._queue, self.popped, self.returned = queue, 0, 0
+
+    def get(self):
+        task = self._queue.get()
+        if task is not None:
+            self.popped += task[2]
+        return task
+
+    def report_finish(self, nbytes):
+        self.returned += nbytes
+        self._queue.report_finish(nbytes)
+
+    def __getattr__(self, name):
+        return getattr(self._queue, name)
+
+
+def _on_pushes(conn, hook):
+    """Run `hook(nth, conn)` on the lane's sender before its nth push
+    goes to the socket; the hook may raise what `send` would."""
+    real, seen = conn.send, []
+
+    def send(cmd, key=0, *args, **kw):
+        if cmd == CMD_PUSH:
+            seen.append(key)
+            hook(len(seen), conn)
+        return real(cmd, key, *args, **kw)
+
+    conn.send = send
+    return seen
+
+
+@pytest.mark.parametrize("attempts", [5, 0], ids=["reconnect", "fail-fast"])
+def test_a_send_that_fails_in_a_lanes_sender(ps_server, attempts):
+    """A push whose `send` raises on its lane's sender gives the queue's
+    credit back once, and is parked and replayed where reconnect is on,
+    or fails its handle where it is off; the session goes on."""
+    s = _session(ps_server(), attempts=attempts, backoff_ms=20.0,
+                 wire_conns=1, partition_bytes=65536, scheduling_credit=1)
+    try:
+        x = np.arange(3 * 65536 // 4, dtype=np.float32)     # 3 partitions
+        np.testing.assert_array_equal(s.push_pull(4, x), x)
+        book = s._queue = _CreditBook(s._queue)
+        lane = s._data_conns[0][0]
+        assert threading.current_thread() is not lane._send_thread
+
+        def fail_first(nth, conn):
+            assert threading.current_thread() is conn._send_thread
+            if nth == 1:
+                raise conn._lost_exc("injected send failure")
+
+        _on_pushes(lane, fail_first)
+        h = s.push_pull_async(4, x * 2)
+        if attempts:
+            np.testing.assert_array_equal(h.wait(30.0), x * 2)
+        else:
+            with pytest.raises(ConnectionError, match="injected"):
+                h.wait(30.0)
+            # the other partitions' round trips end before the next round
+            deadline = time.time() + 10
+            while s._inflight and time.time() < deadline:
+                time.sleep(0.01)
+        st = s.transport_stats()
+        assert st["parked_total"] == st["replayed_pushes"] == (
+            1 if attempts else 0), st
+        assert st["parked_parts"] == 0, st
+        # one partition of credit in all: a credit not given back would
+        # stop the dispatcher here, one given back twice shows in the book
+        y = np.full(2 * 65536 // 4, 3.0, np.float32)
+        np.testing.assert_array_equal(s.push_pull(6, y), y)
+        assert book.popped == book.returned > 0
+        assert s.transport_stats()["lane_outstanding_bytes"] == 0
+    finally:
+        s.close()
+
+
+def test_a_frame_waiting_in_a_dropped_lanes_hand_over_is_replayed(ps_server):
+    """The lane drops while one push is on the socket and another waits
+    in its sender's hand-over: both are parked and replayed, the sum is
+    exact and the credit balances."""
+    port = ps_server()
+    with ChaosProxy("127.0.0.1", port) as proxy:
+        s = _session(proxy.port, attempts=8, backoff_ms=300.0, wire_conns=1,
+                     partition_bytes=256 * 1024)
+        try:
+            n = 4 * 256 * 1024 // 4                      # 4 partitions
+            warm = np.ones(n, np.float32)
+            np.testing.assert_array_equal(s.push_pull(4, warm), warm)
+            book = s._queue = _CreditBook(s._queue)
+            waited = []
+
+            def hook(nth, conn):
+                deadline = time.time() + 10
+                if nth == 1:        # on the socket once one waits behind
+                    while conn.frames_held < 2 and time.time() < deadline:
+                        time.sleep(0.002)
+                    waited.append(conn.frames_held)
+                elif nth == 2:      # taken up once the lane is down
+                    while conn.state() == "up" and time.time() < deadline:
+                        time.sleep(0.002)
+                    waited.append(conn.state())
+
+            _on_pushes(s._data_conns[0][0], hook)
+            proxy.reset_after(100 * 1024)
+            x = np.random.RandomState(11).randn(n).astype(np.float32)
+            np.testing.assert_array_equal(s.push_pull(4, x), x)
+            assert waited[:2] == [2, "reconnecting"]
+            st = s.transport_stats()
+            assert st["reconnects"] >= 1, st
+            assert st["parked_total"] >= 2, st
+            assert st["replayed_pushes"] >= 2, st
+            assert st["parked_parts"] == 0, st
+            assert book.popped == book.returned
+            assert st["lane_outstanding_bytes"] == 0, st
+            np.testing.assert_array_equal(s.push_pull(4, warm), warm)
+        finally:
+            s.close()
 
 
 def test_reconnect_compressed_bit_identical_to_uninterrupted(ps_server):

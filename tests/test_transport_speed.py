@@ -15,13 +15,14 @@ import socket
 import struct
 import subprocess
 import sys
+import threading
 import time
 
 import numpy as np
 import pytest
 
 from byteps_tpu.server.client import (
-    PSSession, _RecvBufPool, _REQ, _RESP,
+    HANDOFF_DEPTH, PSSession, _RecvBufPool, _REQ, _RESP,
     CMD_INIT, CMD_PUSH, CMD_PULL,
 )
 
@@ -175,6 +176,9 @@ class _StubConn:
     def state(self):
         return self._state
 
+    def has_room(self):
+        return True
+
 
 def test_credit_scheduler_picks_least_loaded_lane():
     a, b, c = _StubConn(100), _StubConn(5), _StubConn(50)
@@ -208,6 +212,168 @@ def test_lane_credit_settles_to_zero_and_spreads(ps_server):
         assert all(l["sends"] > 0 for l in lanes), lanes
     finally:
         s.close()
+
+
+# ---------------------------------------------------------------------------
+# a sender a lane: the dispatcher decides, the lane's sender writes
+# ---------------------------------------------------------------------------
+class _GatedLane:
+    """Stands in for a lane's socket: a push's `send` waits at a gate
+    the test opens a frame at a time, and notes what waited behind it."""
+
+    def __init__(self, conn):
+        self.conn, self.real = conn, conn.send
+        self.gate = threading.Semaphore(0)
+        self.pushes = []            # keys, in the order the sender took them
+        self.most_waiting = 0
+        conn.send = self
+
+    def __call__(self, cmd, key=0, *args, **kw):
+        if cmd == CMD_PUSH:
+            self.pushes.append(key)
+            self.most_waiting = max(self.most_waiting,
+                                    len(self.conn._waiting))
+            self.gate.acquire()
+        return self.real(cmd, key, *args, **kw)
+
+    def open(self, frames=1000):
+        for _ in range(frames):
+            self.gate.release()
+
+
+def _until(cond, what, seconds=10.0):
+    deadline = time.time() + seconds
+    while not cond():
+        assert time.time() < deadline, what
+        time.sleep(0.005)
+
+
+def test_hand_over_is_shallow_and_keeps_priority(ps_server):
+    """On lanes whose socket the test holds shut: the dispatcher hands a
+    lane one frame behind the one being sent and pops no more, and a
+    high-priority partition staged while every lane is full is the next
+    one handed over when a place comes free."""
+    s = _session(ps_server(), partition_bytes=65536, wire_conns=2)
+    lanes = [_GatedLane(c) for c in s._data_conns[0]]
+    try:
+        s.record_push_order = True
+        low = np.arange(10 * 65536 // 4, dtype=np.float32)  # 10 partitions
+        h_low = s.push_pull_async(3, low, priority=0)
+        held = 1 + HANDOFF_DEPTH
+        _until(lambda: all(l.conn.frames_held == held for l in lanes),
+               "the lanes never filled")
+        time.sleep(0.1)         # a dispatcher that went on would show now
+        assert len(s.push_order) == 2 * held
+        assert s._queue.pending() == 10 - 2 * held
+        assert [len(l.conn._waiting) for l in lanes] == [HANDOFF_DEPTH] * 2
+        assert not any(l.conn.has_room() for l in lanes)
+        urgent = np.full(100, 7.0, np.float32)              # 1 partition
+        h_urgent = s.push_pull_async(4, urgent, priority=9)
+        _until(lambda: s._queue.pending() == 11 - 2 * held,
+               "the urgent partition never reached the queue")
+        assert len(s.push_order) == 2 * held    # full lanes: nothing pops
+        lanes[1].open(1)
+        _until(lambda: len(s.push_order) == 2 * held + 1,
+               "a freed place was not filled")
+        assert s.push_order[-1] >> 16 == 4
+        assert lanes[1].conn._waiting[-1][2] == s.push_order[-1]
+        for l in lanes:
+            l.open()
+        np.testing.assert_array_equal(h_urgent.wait(30.0), urgent)
+        np.testing.assert_array_equal(h_low.wait(30.0), low)
+        assert max(l.most_waiting for l in lanes) <= HANDOFF_DEPTH
+        assert sorted(k for l in lanes for k in l.pushes) == sorted(
+            s.push_order)
+        st = s.transport_stats()
+        assert st["push_handoffs"] == 11
+        assert st["lane_outstanding_bytes"] == 0, st
+    finally:
+        for l in lanes:
+            l.open()
+        s.close()
+
+
+def test_a_pull_request_goes_ahead_of_the_pushes_waiting(ps_server):
+    """A frame with no payload (a pull's request) handed to a lane is
+    sent before the pushes waiting there, and `hand_over` returns at
+    once whatever the socket is doing."""
+    s = _session(ps_server(), partition_bytes=65536, wire_conns=1)
+    lane = _GatedLane(s._data_conns[0][0])
+    order = []
+    real = lane.real
+
+    def noting(cmd, key=0, *args, **kw):
+        order.append((cmd, key))
+        return real(cmd, key, *args, **kw)
+
+    lane.real = noting
+    try:
+        x = np.arange(3 * 65536 // 4, dtype=np.float32)     # 3 partitions
+        h = s.push_pull_async(5, x)
+        _until(lambda: lane.conn.frames_held == 1 + HANDOFF_DEPTH,
+               "the lane never filled")
+        errors = []
+        t0 = time.time()
+        lane.conn.hand_over(errors.append, CMD_PULL, 12345, worker_id=0,
+                            callback=lambda data, err: errors.append(err))
+        assert time.time() - t0 < 0.5
+        lane.open()
+        np.testing.assert_array_equal(h.wait(30.0), x)
+        # the request went out after the push the gate held, before the
+        # push that waited behind it
+        frames = [f for f in order if f[0] in (CMD_PUSH, CMD_PULL)]
+        assert frames[0][0] == CMD_PUSH
+        assert frames[1] == (CMD_PULL, 12345), frames
+        assert sum(c == CMD_PUSH for c, _ in frames) == 3
+    finally:
+        lane.open()
+        s.close()
+
+
+def test_every_push_leaves_through_its_lanes_sender(ps_server):
+    """A round over three lanes against a live server: every push was
+    sent by a lane's sender, every lane carried some, and the lanes'
+    byte credit settles."""
+    s = _session(ps_server(), partition_bytes=65536, wire_conns=3)
+    try:
+        senders = {c._send_thread for c in s._data_conns[0]}
+        assert len(senders) == 3 and all(
+            t.is_alive() and t.name == "bps-ps-send" for t in senders)
+        x = np.arange(12 * 65536 // 4, dtype=np.float32)   # 12 partitions
+        rounds = 3
+        for _ in range(rounds):
+            np.testing.assert_array_equal(s.push_pull(8, x), x)
+        st = s.transport_stats()
+        assert st["push_handoffs"] == 12 * rounds
+        assert sum(l["push_handoffs"] for l in st["lanes"]) == 12 * rounds
+        assert all(l["sends"] > 0 and l["push_handoffs"] > 0
+                   for l in st["lanes"]), st["lanes"]
+        assert st["lane_outstanding_bytes"] == 0, st
+        assert all(c.quiet() for c in s._data_conns[0])
+    finally:
+        s.close()
+
+
+def test_close_and_a_lane_shrink_leave_no_sender_behind(ps_server):
+    """`close()` and a `wire_conns` shrink see the lanes' senders out:
+    none of them is left among the live threads."""
+    s = _session(ps_server(), partition_bytes=65536, wire_conns=4)
+    try:
+        x = np.arange(8 * 65536 // 4, dtype=np.float32)
+        np.testing.assert_array_equal(s.push_pull(9, x), x)
+        pool = s._data_conns[0]
+        before = {c: c._send_thread for c in pool}
+        s._resize_lanes(1)
+        _until(lambda: len(pool) == 1, "the retired lanes never drained")
+        kept = pool[0]
+        retired = [t for c, t in before.items() if c is not kept]
+        _until(lambda: not any(t.is_alive() for t in retired),
+               "a retired lane's sender lives on")
+        assert before[kept].is_alive()
+        np.testing.assert_array_equal(s.push_pull(9, x * 2), x * 2)
+    finally:
+        s.close()
+    assert not set(before.values()) & set(threading.enumerate())
 
 
 # ---------------------------------------------------------------------------
