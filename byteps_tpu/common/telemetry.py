@@ -413,7 +413,8 @@ def _gauge(name: str, help: str, cast=int) -> tuple:
 #: label, "none" for a call without one, so a step with both kinds of
 #: layer keeps both whatever the order they are traced in), and the
 #: expert layer's grouped product (`ops/grouped_matmul.py`: which path
-#: ran and on what tiles; `parallel/dropless_moe.py` adds the walk).
+#: ran and on what tiles; `parallel/dropless_moe.py` adds the walk), and
+#: the attention over selected keys (`ops/sparse_attention.py`).
 _STATIC = {
     "ingraph_exchange": {
         "leaves": _gauge(
@@ -497,6 +498,26 @@ _STATIC = {
         "row_tiles_buffer": _gauge(
             "bps_grouped_row_tiles_buffer",
             "row tiles of the whole buffer, padding included"),
+    },
+    "sparse_attention": {
+        "rows": _gauge(
+            "bps_sparse_rows",
+            "query rows of the last traced attention call over selected "
+            "keys (ops/sparse_attention.py)"),
+        "topk": _gauge(
+            "bps_sparse_topk", "keys a row of that call selects at most"),
+        "selected_pairs": _gauge(
+            "bps_sparse_selected_pairs",
+            "(query, key) pairs the selection leaves a sequence: the sum "
+            "over its rows of min(t + 1, topk)", float),
+        "visible_pairs": _gauge(
+            "bps_sparse_visible_pairs",
+            "pairs the causal mask alone leaves, which the indexer "
+            "scores", float),
+        "tiles_walked": _gauge(
+            "bps_sparse_tiles_walked",
+            "tiles a sequence's forward kernel computes: every causal "
+            "tile, since which pairs are selected is data"),
     },
 }
 

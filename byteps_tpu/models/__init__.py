@@ -6,6 +6,16 @@ torchvision.models, example/mxnet uses gluon model_zoo); this package is
 the in-tree TPU-native equivalent: a transformer LM family (flagship —
 BERT-large is the reference's headline benchmark, README.md:38-46), a CNN
 family (ResNet/VGG — docs/performance.md benchmarks), and an MNIST MLP.
+
+Beside them, imported by name where they are used (docs/models.md): the
+decoders the benchmark runs as one chip's share of a deployment, `afmoe`
+(layers of more than one kind, sparse experts beside a shared one),
+`mellum` (sparse experts in every layer, windowed and YaRN full
+attention), `keye` (attention over the keys a learned indexer selects,
+rotary positions in three streams) and `granite_hybrid` (state-space
+scans beside attention).  Their attention calls come from one table,
+`afmoe._ATTENTION`: `sliding_attention`, `full_attention`,
+`selected_attention`.
 """
 
 from . import transformer

@@ -52,6 +52,8 @@ from .transformer import (_rms_norm, _rope, dense_attention,
 
 PyTree = Any
 SLIDING, FULL = "sliding_attention", "full_attention"
+# not a `layer_types` entry of this model: the kind of `models/keye.py`
+SELECTED = "selected_attention"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -83,7 +85,7 @@ class AfmoeConfig:
     attn_block: int = 0                # as TransformerConfig's
     attn_block_k: int = 0
     remat: bool = True                 # per layer
-    remat_policy: str = "none"         # "none" | "dots" | "dots_no_batch"
+    remat_policy: str = "none"         # see `_remat`
     ce_chunk_rows: int = 0             # > 0: streamed head + cross-entropy
     moe_capacity_factor: float = 1.25  # dropless_moe's static buffer
     # What `post_attn_ln`'s scale starts at.  At 1 a random model routes
@@ -203,10 +205,10 @@ def init_params(rng: jax.Array, cfg: AfmoeConfig) -> PyTree:
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
-def _attn_fn(cfg, kind: str):
-    """`(q, k, v) -> ctx`, all [B, H, S, Dh], causal, windowed in a sliding
-    layer.  A window that reaches past the sequence is full attention."""
-    window = cfg.sliding_window if kind == SLIDING else None
+def _positional(cfg, window):
+    """`(q, k, v) -> ctx`, all [B, H, S, Dh], causal, over the keys a row's
+    POSITION leaves it: all before it, or a `window` of them.  A window
+    that reaches past the sequence is full attention."""
     if cfg.attn_impl == "flash":
         def flash(q, k, v):
             w = window if window is not None and window < q.shape[2] else None
@@ -227,6 +229,40 @@ def _attn_fn(cfg, kind: str):
         probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
         return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
     return dense_windowed
+
+
+def _selected(cfg):
+    """`(q, k, v, index) -> (ctx, kept)`: causal attention over the
+    `cfg.index_topk` keys an indexer picks for each row
+    (`ops/sparse_attention.py`, which says how).  q [B, H, S, Dh]; k and
+    v [B, Hkv, S, Dh], NOT repeated over the query heads; `index` the
+    indexer's `(queries [B, J, S, Di], keys [B, S, Di], weights
+    [B, S, J])`, read as constants.  `kept` [B, S] counts the pairs the
+    attention kept a row."""
+    from ..ops import sparse_attention
+
+    def selected(q, k, v, index):
+        if cfg.attn_impl == "flash":
+            return sparse_attention.selected_attention(
+                q, k, v, *index, cfg.index_topk, cfg.attn_block,
+                cfg.attn_block_k)
+        return sparse_attention.selected_attention_dense(
+            q, k, v, *index, cfg.index_topk)
+    return selected
+
+
+# A layer's kind -> what builds its attention call from the configuration.
+_ATTENTION = {
+    SLIDING: lambda cfg: _positional(cfg, cfg.sliding_window),
+    FULL: lambda cfg: _positional(cfg, None),
+    SELECTED: _selected,
+}
+
+
+def _attn_fn(cfg, kind: str):
+    """The attention call of a layer of `kind`, for every decoder of this
+    package: one table, which a new kind joins."""
+    return _ATTENTION[kind](cfg)
 
 
 def _swiglu(x, lp, prefix: str, dt):
@@ -304,11 +340,16 @@ def _layer(x, lp, sel, cfg: AfmoeConfig, kind: str, is_moe: bool):
 def _remat(fn, cfg):
     if not cfg.remat:
         return fn
+    from ..ops.sparse_attention import SELECTION_NAME
     policies = {
         "none": None,
         "dots": jax.checkpoint_policies.checkpoint_dots,
         "dots_no_batch":
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+        # what a layer that selects its keys found (16 MB a layer at
+        # 32,768 rows), so that the backward pass does not find it again
+        "selection": jax.checkpoint_policies.save_only_these_names(
+            SELECTION_NAME),
     }
     if cfg.remat_policy not in policies:
         raise ValueError(f"remat_policy={cfg.remat_policy!r}; options: "

@@ -236,9 +236,11 @@ def _experts_input(x, lp, cfg: MellumConfig):
     return m.reshape(-1, x.shape[-1])
 
 
-def _experts(x, lp, sel, cfg: MellumConfig):
-    """The other half: x -> `(x + held experts(norm(x)), routing)`."""
-    with jax.named_scope("mellum.moe"):
+def _experts(x, lp, sel, cfg, family: str = "mellum"):
+    """The other half: x -> `(x + held experts(norm(x)), routing)`;
+    `family` the prefix of its scope (`models/keye.py` has this half
+    too)."""
+    with jax.named_scope(family + ".moe"):
         m = _experts_input(x, lp, cfg)
         experts = {n: lp["expert_" + n] for n in ("gate_w", "up_w", "down_w")}
         routed, routing = dropless_moe.held_experts(

@@ -33,6 +33,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
@@ -299,7 +300,8 @@ def _rms_norm(x, scale, bias, eps=1e-6):
 _NORMS = {"layernorm": _layer_norm, "rmsnorm": _rms_norm}
 
 
-def _rope(x, theta: float, inv_freq=None, amplitude: float = 1.0):
+def _rope(x, theta: float, inv_freq=None, amplitude: float = 1.0,
+          positions=None, sections=None):
     """Rotary position embedding on [B, H, S, Dh] (half-split layout).
 
     The rotation runs in float32: at positions near max_seq_len, bf16
@@ -309,14 +311,36 @@ def _rope(x, theta: float, inv_freq=None, amplitude: float = 1.0):
     `inv_freq` [Dh / 2] puts a frequency of its own for each pair in
     place of `theta ** (-2i / Dh)`, and `amplitude` multiplies cos and
     sin: what scaled positions need (`models/mellum.py` `yarn_inv_freq`).
-    Left alone, the program is the one it was."""
+
+    `positions` puts the rows' positions in place of 0 .. S - 1: ONE
+    stream, [S] or [B, S]; or, with `sections`, one stream for each
+    section, [n, S] or [n, B, S], and `sections` (n counts that sum to
+    Dh / 2) says how many of the pairs, in order, turn by each stream
+    (`models/keye.py`: temporal, height, width).  Left alone, the program
+    is the one it was."""
     B, H, S, Dh = x.shape
     half = Dh // 2
     if inv_freq is None:
         freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     else:
         freqs = jnp.asarray(inv_freq, jnp.float32)
-    angles = jnp.arange(S, dtype=jnp.float32)[:, None] * freqs[None, :]
+    if positions is None:
+        angles = jnp.arange(S, dtype=jnp.float32)[:, None] * freqs[None, :]
+    else:
+        where = jnp.asarray(positions, jnp.float32)
+        if sections is None:
+            where = where[..., None]                # [.., S, 1]
+        else:
+            if sum(sections) != half or where.shape[0] != len(sections):
+                raise ValueError(
+                    f"sections {tuple(sections)} over {where.shape[0]} "
+                    f"streams do not divide {half} pairs")
+            # each pair's own stream: [half, .., S] -> [.., S, half]
+            pair = np.repeat(np.arange(len(sections)), sections)
+            where = jnp.moveaxis(where[pair], 0, -1)
+        angles = where * freqs                      # [S, half] | [B, S, half]
+        if angles.ndim == 3:
+            angles = angles[:, None]                # over the heads
     cos = jnp.cos(angles)                   # [S, half], f32
     sin = jnp.sin(angles)
     if amplitude != 1.0:

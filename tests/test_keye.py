@@ -1,0 +1,325 @@
+"""The keye model (`byteps_tpu/models/keye.py`) and its attention over
+selected keys (`byteps_tpu/ops/sparse_attention.py`) at tiny widths
+against the plain float32 reference (`benchmark/reference/keye.py`),
+through the benchmark's own family and comparison: loss and every gradient
+leaf, a share and the whole model, both discontinuous choices apart from
+the arithmetic (the ten broken variants: `test_keye_variants.py`), the
+selection exact against a sort with ties, short rows against dense attention, sectioned rotary
+positions, the eight shares against the uncut layer, and the machinery
+shared with `afmoe.py` and `mellum.py` left as it was."""
+
+import dataclasses
+import hashlib
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.families import afmoe as family_afmoe
+from benchmark.families import keye as family_keye
+from benchmark.families import mellum as family_mellum
+from benchmark.harness import correct, manifest, seeded
+from benchmark.reference import keye as reference
+from benchmark.tests import tiny_afmoe, tiny_keye, tiny_mellum
+from byteps_tpu.models import afmoe, keye
+from byteps_tpu.models import transformer as tfm
+from byteps_tpu.ops import sparse_attention as sa
+from byteps_tpu.parallel import dropless_moe
+
+
+_family, _agreement = tiny_keye.family, tiny_keye.agreement
+
+
+# (layers of the model that are run, experts held)
+CUTS = {
+    "one_layer": ([0], None),
+    "the_cells_four_layers": (None, None),
+    "whole_model_two_layers": ([2, 3], range(128)),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("cut", CUTS)
+def test_against_reference(cut, dtype):
+    """In float32 the program IS the reference up to rounding, its
+    selection the reference's own in every row; in bfloat16 it is within
+    the family's tolerances at these widths."""
+    layers, experts = CUTS[cut]
+    family = _family(
+        dtype, tiny_keye.FLOAT32 if dtype == jnp.float32 else None,
+        layers=layers, experts=experts)
+    got = _agreement(family)
+    assert correct.agreement_ok(got, family.reference_check), got
+    for record in family.selection:
+        assert record["miscounted_rows"] == 0
+        assert record["unexplained_rows"] == 0
+        if dtype == jnp.float32:
+            assert record["swapped_share"] == 0
+            assert record["key_swapped_share"] == 0
+            assert got["worst_grad_rel_diff"] < 1e-5
+            assert got["loss_rel_diff"] < 1e-6
+
+
+def test_selection_is_exact_against_a_sort_with_ties():
+    """Integer-valued indexer operands make every sum exact and most
+    rows' thresholds tied: the kernels' mask is the stable sort's first
+    `topk` of the visible keys in every row, and the forward kernel's
+    counter says min(t + 1, topk)."""
+    B, H, Hkv, S, D, J, Di, topk = 1, 4, 2, 512, 32, 3, 16, 64
+    k = jax.random.split(jax.random.key(0), 6)
+    q = jax.random.normal(k[0], (B, H, S, D))
+    kk, v = (jax.random.normal(k[i], (B, Hkv, S, D)) for i in (1, 2))
+    qi = jnp.round(jax.random.normal(k[3], (B, J, S, Di)))
+    ki = jnp.round(jax.random.normal(k[4], (B, S, Di)))
+    w = jnp.round(2 * jax.random.normal(k[5], (B, S, J)))
+    kit = ki.transpose(0, 2, 1)
+    aux = sa.select(qi, kit, w, topk, 128)
+    keep = np.asarray(sa.keep_mask(qi, kit, aux, 128, 128))[0].astype(bool)
+    scores = np.asarray(sa.index_scores(qi, ki, w))[0]
+    want = np.zeros((S, S), bool)
+    tied = 0
+    for t in range(S):
+        n = min(t + 1, topk)
+        order = np.argsort(-scores[t, :t + 1], kind="stable")[:n]
+        want[t, order] = True
+        tied += (scores[t, :t + 1] == scores[t, order[-1]]).sum() > 1
+    assert tied > S // 2
+    assert (keep == want).all()
+    assert (np.asarray(sa.dense_keep(qi, ki, w, topk))[0] == want).all()
+    out, kept = sa.selected_attention(q, kk, v, qi, ki, w, topk, 128, 128)
+    assert (np.asarray(kept)[0] == np.minimum(np.arange(S) + 1, topk)).all()
+    dense, kept_dense = sa.selected_attention_dense(q, kk, v, qi, ki, w, topk)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(dense), atol=2e-5)
+    assert (np.asarray(kept_dense) == np.asarray(kept)).all()
+    # the backward pass holds the selection constant
+    g = jax.random.normal(k[0], out.shape)
+    grads = [jax.grad(lambda *a: (f(*a, qi, ki, w, topk)[0] * g).sum(),
+                      (0, 1, 2))(q, kk, v)
+             for f in (lambda *a: sa.selected_attention(*a, 128, 128),
+                       sa.selected_attention_dense)]
+    for mine, plain in zip(*grads):
+        np.testing.assert_allclose(np.asarray(mine), np.asarray(plain),
+                                   atol=5e-5)
+
+
+def test_rows_short_of_topk_attend_to_everything():
+    """A sequence no longer than `topk`: every row takes all it sees, and
+    the layer's attention is dense causal attention."""
+    B, H, Hkv, S, D, J, Di = 1, 4, 2, 256, 32, 3, 16
+    k = jax.random.split(jax.random.key(1), 6)
+    q = jax.random.normal(k[0], (B, H, S, D))
+    kk, v = (jax.random.normal(k[i], (B, Hkv, S, D)) for i in (1, 2))
+    qi = jax.random.normal(k[3], (B, J, S, Di))
+    ki = jax.random.normal(k[4], (B, S, Di))
+    w = jax.random.normal(k[5], (B, S, J))
+    out, kept = sa.selected_attention(q, kk, v, qi, ki, w, S, 128, 128)
+    assert (np.asarray(kept)[0] == np.arange(S) + 1).all()
+    rep = [jnp.repeat(t, H // Hkv, axis=1) for t in (kk, v)]
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(tfm.dense_attention(q, *rep, causal=True)),
+        atol=2e-5)
+
+
+def test_sectioned_rotary_and_plain_rotary_as_it_was():
+    x = jax.random.normal(jax.random.key(0), (2, 3, 64, 16))
+    t = jnp.arange(64)
+    equal = jnp.stack([t, t, t])
+    plain = tfm._rope(x, 10000.0)
+    np.testing.assert_allclose(
+        np.asarray(tfm._rope(x, 10000.0, positions=equal,
+                             sections=(2, 2, 4))), np.asarray(plain),
+        atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(tfm._rope(x, 10000.0, positions=t)), np.asarray(plain),
+        atol=1e-6)
+    grid = jnp.stack([t, t // 8, t % 8])
+    turned = np.asarray(tfm._rope(x, 10000.0, positions=grid,
+                                  sections=(2, 2, 4)))
+    # pairs 0-1 turn by the first stream, as plain rotary; the others not
+    both = np.asarray(plain)
+    for pair in range(8):
+        same = np.allclose(turned[..., [pair, 8 + pair]],
+                           both[..., [pair, 8 + pair]], atol=1e-6)
+        assert same == (pair < 2), pair
+    # against the reference's closed form, a batch of streams
+    streams = jnp.broadcast_to(grid[:, None], (3, 2, 64))
+    got = tfm._rope(x, 10000.0, positions=streams, sections=(2, 2, 4))
+    want = jnp.stack([reference.rotary(x[b], 10000.0, streams[:, b],
+                                       (2, 2, 4)) for b in range(2)])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+    with pytest.raises(ValueError):
+        tfm._rope(x, 10000.0, positions=grid, sections=(2, 2, 2))
+
+    # existing callers: the same jaxpr as the function had before it
+    # learnt of positions
+    def as_it_was(x, theta, inv_freq=None, amplitude=1.0):
+        half = x.shape[-1] // 2
+        if inv_freq is None:
+            freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+        else:
+            freqs = jnp.asarray(inv_freq, jnp.float32)
+        angles = jnp.arange(x.shape[2], dtype=jnp.float32)[:, None] \
+            * freqs[None, :]
+        cos, sin = jnp.cos(angles), jnp.sin(angles)
+        if amplitude != 1.0:
+            cos, sin = cos * amplitude, sin * amplitude
+        x32 = x.astype(jnp.float32)
+        x1, x2 = x32[..., :half], x32[..., half:]
+        return jnp.concatenate([x1 * cos - x2 * sin,
+                                x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+    xb = x.astype(jnp.bfloat16)
+    inv = np.linspace(1.0, 0.01, 8).astype(np.float32)
+    for args in ((10000.0,), (0.0, inv, 1.5)):
+        assert str(jax.make_jaxpr(lambda x: tfm._rope(x, *args))(xb)) == str(
+            jax.make_jaxpr(lambda x: as_it_was(x, *args))(xb))
+
+
+def test_the_shares_add_up_to_the_layer():
+    """Guide, section 4: over the eight chips that share a layer, the
+    routed parts the shares compute (there is no shared expert to count
+    once) are the uncut reference's expert layer, for the same tokens,
+    every pair on exactly one chip; what every chip computes alike, the
+    attention over the selected keys, is the uncut reference's; and the
+    eight slices' logits laid side by side are the whole head's."""
+    family = _family(jnp.float32, layers=[0])
+    cfg, spec = family.cfg, family.spec
+    E, D, F = cfg.num_experts, cfg.hidden_size, cfg.moe_intermediate_size
+    k = jax.random.split(jax.random.key(0), 5)
+    whole = {
+        "router_w": jax.random.normal(k[0], (D, E)) / 8,
+        "expert_gate_w": jax.random.normal(k[1], (E, D, F)) / 8,
+        "expert_up_w": jax.random.normal(k[2], (E, D, F)) / 8,
+        "expert_down_w": jax.random.normal(k[3], (E, F, D)) / 6,
+    }
+    m = jax.random.normal(k[4], (192, D))
+    with jax.default_matmul_precision("highest"):
+        uncut, _ = reference.experts_layer(
+            m, whole, {**spec, "held": tuple(range(E))})
+    total, rows = 0.0, 0
+    for chip in range(8):
+        held = tuple(range(chip * E // 8, (chip + 1) * E // 8))
+        assert len(held) == 16
+        moe = dataclasses.replace(cfg.moe, held=held)
+        experts = {n: whole["expert_" + n][jnp.asarray(held)]
+                   for n in ("gate_w", "up_w", "down_w")}
+        part, routing = dropless_moe.held_experts(m, whole["router_w"],
+                                                  experts, moe)
+        total, rows = total + part, rows + int(routing.held_rows)
+    assert rows == m.shape[0] * cfg.num_experts_per_tok
+    np.testing.assert_allclose(np.asarray(total), np.asarray(uncut),
+                               atol=2e-5, rtol=2e-5)
+
+    # the attention half holds no share: the program's on one chip is the
+    # reference's own, selection and all
+    params = seeded.params(family, 0)
+    lp = jax.tree.map(lambda a: a[0], params["moe"])
+    x = jax.random.normal(k[0], (1, 256, D))
+    mine, kept = keye._attention(x, lp, cfg, afmoe.FULL)
+    with jax.default_matmul_precision("highest"):
+        ctx, _ = reference.attention_half(
+            x, lp, spec, reference.text_positions(jnp.zeros((1, 256))))
+    np.testing.assert_allclose(np.asarray(mine - x), np.asarray(ctx),
+                               atol=2e-5)
+    assert (np.asarray(kept)[0]
+            == np.minimum(np.arange(256) + 1, cfg.index_topk)).all()
+
+    V = 8 * 40
+    head = jax.random.normal(k[0], (V, D))
+    x = jax.random.normal(k[1], (2, 16, D))
+    side_by_side = jnp.concatenate(
+        [afmoe.head_logits(x, head[c * 40:(c + 1) * 40]) for c in range(8)],
+        axis=-1)
+    np.testing.assert_allclose(np.asarray(side_by_side),
+                               np.asarray(x @ head.T), atol=1e-4, rtol=1e-5)
+
+
+def test_the_indexer_gets_no_gradient_and_the_choice_is_found_once():
+    """The indexer's columns of `in_w` and of `k_norm` get EXACTLY zero
+    from the loss, the main heads' do not; under the remat policy
+    "selection" a layer's backward pass keeps what the forward pass
+    selected and `index_topk` runs once a layer, under "none" twice."""
+    family = _family(jnp.float32, layers=[0, 1])
+    cfg = family.cfg
+    params, batch = seeded.params(family, 0), seeded.batch(family, 0, 1)
+    grads = jax.grad(family.loss)(params, batch)["moe"]
+    first = (cfg.num_heads + 2 * cfg.num_kv_heads) * cfg.head_dim
+    assert not np.asarray(grads["in_w"][..., first:]).any()
+    assert not np.asarray(grads["k_norm"][..., cfg.head_dim:]).any()
+    assert np.asarray(grads["in_w"][..., :first]).any()
+    assert np.asarray(grads["k_norm"][..., :cfg.head_dim]).any()
+    assert cfg.remat_policy == "selection"
+
+    def selections(policy):
+        changed = dataclasses.replace(cfg, remat_policy=policy)
+        text = str(jax.make_jaxpr(jax.grad(
+            lambda p: keye.loss_fn(p, batch, changed)))(params))
+        return text.count("name=index_topk")
+    # a scan over one period of one layer: calls a layer
+    assert (selections("selection"), selections("none")) == (1, 2)
+
+
+def test_parameter_count_at_the_published_widths():
+    """The cell's share, counted from the built tree: 465,390,848, of
+    which the indexer 2,260,992 a layer; 7.45 GB at 16 bytes each."""
+    with open(os.path.join(manifest.BENCH, "configs",
+                           "keye-vl-2.0-30b-a3b.json")) as f:
+        config = json.load(f)
+    family = family_keye.Family(config, config["job"])
+    shapes = jax.eval_shape(family.init, jax.random.key(0))
+    sizes = {k: int(np.prod(v.shape)) for k, v in shapes["moe"].items()}
+    layer = sum(sizes.values()) // 4
+    assert sizes["in_w"] // 4 == 2048 * (5120 + 1024 + 64 + 16)
+    assert layer == (2048 * 5120 + 4096 * 2048 + 2_260_992 + 262_144
+                     + 16 * 3 * 2048 * 768 + 2048 + 2048 + 128 + 192)
+    total = sum(int(np.prod(v.shape)) for v in jax.tree.leaves(shapes))
+    assert total == 4 * layer + 2 * 18_992 * 2048 + 2048 == 465_390_848
+    assert family.cfg.moe.hold_held_weight
+    assert config["reduced"] == ["num_hidden_layers", "num_experts",
+                                 "vocab_size"]
+    # no width differs from the source
+    for key, value in config["published"].items():
+        if key not in config["reduced"]:
+            assert config[key] == value, key
+    assert f"{total:,}" in config["deployment"]["parameters"]
+
+
+# sha256 of the lowered text of `value_and_grad(family.loss)` at tiny
+# widths on the parent commit (9c5663d), before the attention adapter
+# became a table and `_rope` learnt of positions
+PARENTS_LOWERED_STEPS = {
+    "mellum": (tiny_mellum, family_mellum,
+               "0cd38a11df0792bd3174569285fb1026"
+               "81abcef7a69a9e2598d4c315d5d9a45d"),
+    "afmoe": (tiny_afmoe, family_afmoe,
+              "a2761718d3cc091ab688ae19f26c3f8d"
+              "5664e4474ddf6a7d6e5f62c1d3585ccc"),
+}
+
+
+@pytest.mark.parametrize("name", PARENTS_LOWERED_STEPS)
+def test_the_other_decoders_steps_lower_to_the_text_they_had(name):
+    tiny, module, digest = PARENTS_LOWERED_STEPS[name]
+    config = tiny.config()
+    family = module.Family(config, config["job"])
+    params = jax.eval_shape(family.init, jax.random.key(0))
+    batch = jax.eval_shape(lambda k: family.make_batch(k, 2),
+                           jax.random.key(0))
+    text = jax.jit(jax.value_and_grad(family.loss)).lower(
+        params, batch).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == digest
+
+
+def test_the_attention_adapter_is_one_table():
+    assert set(afmoe._ATTENTION) == {afmoe.SLIDING, afmoe.FULL,
+                                     afmoe.SELECTED}
+    with pytest.raises(KeyError):
+        afmoe._attn_fn(None, "no_such_attention")
+    with pytest.raises(ValueError):
+        sa.check_blocks(384, 128, 256)
+    assert sa.auto_blocks(32768) == (128, 512) and sa.auto_blocks(200) == (
+        0, 0)
+    assert sa.selected_pairs(32768, 2048) == 2048 * 2049 // 2 + 30720 * 2048

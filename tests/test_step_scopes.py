@@ -25,7 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import byteps_tpu as bps
 from benchmark.harness import measure
 from benchmark.tests import (tiny, tiny_afmoe, tiny_granitehybrid,  # noqa: F401
-                             tiny_mellum)       # they join `tiny`'s table
+                             tiny_keye, tiny_mellum)    # join `tiny`'s table
 from byteps_tpu.common import devprof
 from byteps_tpu.ops import flash_attention as fa
 from byteps_tpu.ops import ssd
@@ -61,6 +61,14 @@ FAMILIES = {
                 "mellum.moe/route", "mellum.moe/gather", "mellum.moe/grouped",
                 "mellum.moe/scatter", "mellum.moe/exact", "mellum.head",
                 "byteps.optimizer"}, True),
+    "keye": ("keye-vl-2.0-30b-a3b.ingraph-1chip",
+             {"keye.embed", "keye.attn.full_attention",
+              "keye.attn.full_attention/qkv", "keye.attn.full_attention/index",
+              "keye.attn.full_attention/select",
+              "keye.attn.full_attention/sparse",
+              "keye.attn.full_attention/out", "keye.moe", "keye.moe/route",
+              "keye.moe/gather", "keye.moe/grouped", "keye.moe/scatter",
+              "keye.moe/exact", "keye.head", "byteps.optimizer"}, True),
 }
 # The names the device trace was read by before this map: an unnamed
 # kernel call is called after the innermost scope around it.  The expert
@@ -74,6 +82,9 @@ KERNEL_SCOPES = {
     "granitehybrid": {"granite.mamba.scan", "granite.attn"},
     "mellum": {"mellum.attn.sliding_attention", "mellum.attn.full_attention",
                "mellum.moe/grouped", "mellum.moe/exact/grouped"},
+    "keye": {"keye.attn.full_attention/select",
+             "keye.attn.full_attention/sparse", "keye.moe/grouped",
+             "keye.moe/exact/grouped"},
 }
 PRODUCTS = ("fusion", "custom-call", "dot", "convolution", "ragged-dot")
 WORK = ("dot_general", "conv_general_dilated", "pallas_call")
@@ -121,7 +132,15 @@ def _family(name: str):
     elif name == "mellum":  # sliding, full
         cell = dataclasses.replace(cell,
                                    config=tiny_mellum.config(layers=[2, 3]))
-    if name in ("afmoe", "mellum"):
+    elif name == "keye":    # two layers; heads and indexer heads as wide
+        config = tiny_keye.config(layers=[0, 1])    # as the chip's tiles
+        config["published"].update(
+            head_dim=128, rope_scaling={"mrope_section": [16, 24, 24],
+                                        "rope_type": "default"},
+            sa_config={**config["published"]["sa_config"],
+                       "indexer_head_dim": 64})
+        cell = dataclasses.replace(cell, config=config)
+    if name in ("afmoe", "mellum", "keye"):
         # the narrowest widths the grouped kernels tile: a lane tile each
         # (the tiny cuts' 64 and 32 go to `lax.ragged_dot`, the compiler's)
         config = copy.deepcopy(cell.config)
@@ -232,7 +251,7 @@ def test_every_familys_tiny_step_is_mapped(name, v5e, kernels,
     assert not [n for n, e in scopes.items() if e.get("lent")]
     grouped = {n: e for n, e in kernels.items()
                if n.startswith("ragged-dot-none_")}
-    if name in ("afmoe", "mellum"):
+    if name in ("afmoe", "mellum", "keye"):
         assert {n.split(".")[0].rsplit("_", 1)[1] for n in grouped} == {
             "fwd", "drows", "dweights"}
         assert all(e["scope"].startswith(f"{name}.moe/")

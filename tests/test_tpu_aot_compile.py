@@ -414,3 +414,49 @@ def test_bert_large_train_step_compiles(v5e, monkeypatch):
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("what", ["select", "attention"])
+def test_sparse_attention_compiles_at_keye_shapes(v5e, monkeypatch, what):
+    """32 query heads over 4 key-value heads of 128, an indexer of 16
+    heads of 64 over one key head, ONE sequence of 32,768 of which a row
+    selects 2,048: the keye cell's calls.  `index_topk` holds 128 rows'
+    sortable scores against every key in VMEM (16 MiB of scratch, which
+    the chip's compiler has to take with the limit the kernel asks for);
+    the attention kernels walk the table of causal tiles, 128 rows by 512
+    keys, all heads a step, under the names the trace's readers know them
+    by (`benchmark/reduce/sparse_cost.py`)."""
+    from benchmark.reduce import sparse_cost
+    from byteps_tpu.ops import sparse_attention as sa
+    monkeypatch.setattr(fa, "_use_interpret", lambda interpret: False)
+    one = SingleDeviceSharding(v5e[0])
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    S = 32768
+    assert sa.auto_blocks(S) == (128, 512)
+    q, kv = shape(1, 32, S, 128), shape(1, 4, S, 128)
+    qi, kit = shape(1, 16, S, 64), shape(1, 64, S)
+    w = shape(1, S, 16, dtype=jnp.float32)
+    aux = shape(1, S, sa.AUX_LANES, dtype=jnp.float32)
+    if what == "select":
+        text = _compile(lambda qi, kit, w: sa.select(qi, kit, w, 2048),
+                        qi, kit, w).as_text()
+        names = {"select"}
+    else:
+        def grads(q, k, v, qi, kit, aux):
+            def loss(q, k, v):
+                out, _ = sa.sparse_attention(q, k, v, qi, kit, aux)
+                return jnp.sum(out.astype(jnp.float32))
+            return jax.grad(loss, (0, 1, 2))(q, k, v)
+        text = _compile(grads, q, kv, kv, qi, kit, aux).as_text()
+        names = {"forward", "dq", "dkv"}
+        # the table is in the calls: one entry a causal tile
+        assert text.count("s32[8320]") >= 9
+    # a bare `jax.grad` puts `jvp_` and `transpose_` before a call's
+    # name, which a train step's remat does not
+    calls = [line.strip().removeprefix("ROOT ").replace("%transpose_", "%")
+             .replace("%jvp_", "%") for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    assert {sparse_cost.kernel(line) for line in calls} == names, calls

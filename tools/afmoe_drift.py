@@ -51,7 +51,7 @@ def main(argv=None) -> int:
     compile_cache.enable()
     if args.tiny:
         from benchmark.tests import (tiny, tiny_afmoe,  # noqa: F401
-                                     tiny_mellum)
+                                     tiny_keye, tiny_mellum)
         cell = tiny.tiny_cell(args.workload)
     else:
         cell = manifest.load_cell(args.workload)
