@@ -518,6 +518,15 @@ _STATIC = {
             "bps_sparse_tiles_walked",
             "tiles a sequence's forward kernel computes: every causal "
             "tile, since which pairs are selected is data"),
+        "index_passes": _gauge(
+            "bps_sparse_index_passes",
+            "of the three kernels of that call's forward and backward "
+            "pass, those that compute a tile's index scores (the others "
+            "read the mask as bits)"),
+        "mask_bytes": _gauge(
+            "bps_sparse_mask_bytes",
+            "bytes of packed mask the forward kernel writes a sequence: "
+            "the causal part of its words", float),
     },
 }
 

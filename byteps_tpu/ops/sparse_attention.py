@@ -13,8 +13,9 @@ head.  Which keys those are is DATA, other ones for every row and every
 step, so no schedule of live tiles can be made in Python as
 `flash_attention.stream_table` makes a window's.
 
-How S_t is realised here: as a THRESHOLD a row, and flash tiles that
-recompute the index scores and mask by it.
+How S_t is realised here: as a THRESHOLD a row, flash tiles whose
+forward kernel recomputes the index scores and masks by it, and backward
+kernels that read the forward kernel's mask.
 
   - `select` (kernel `index_topk`) takes a block of rows, computes their
     scores against every key up to the diagonal tile by tile into VMEM
@@ -27,14 +28,26 @@ recompute the index scores and mask by it.
     and leaves it as two numbers a row, `tau` and `cut`.
   - `sparse_attention` (kernels `sparse_fwd`, `sparse_dq`, `sparse_dkv`)
     is streaming flash attention over the table of causal tiles, all the
-    main heads of a tile in one grid step: the step computes the tile's
-    index scores ONCE, by the same function on the same operands as
-    `select` did, so bit for bit the same numbers, keeps the pairs
-    `s <= t and (I > tau or (I == tau and s <= cut))`, and runs the
-    online softmax of every head under that one mask, the query heads of
-    a key-value head stacked as rows of one product.  The forward kernel
-    also counts the pairs it kept a row (`count`): exactly
-    min(t + 1, topk) where select and attention agree.
+    main heads of a tile in one grid step, the query heads of a key-value
+    head stacked as rows of one product, every head's online softmax
+    under one mask.  WHO COMPUTES THE SCORES: `sparse_fwd` alone.  Its
+    step computes the tile's index scores once, by the same function on
+    the same operands as `select` did, so bit for bit the same numbers,
+    and keeps the pairs `s <= t and (I > tau or (I == tau and s <=
+    cut))`.  It counts the pairs it kept a row (`count`): exactly
+    min(t + 1, topk) where select and attention agree.  And it writes the
+    mask out, a bit a pair: `bits` [B, S, 128 * ceil(S / 4096)] int32,
+    bit b of word [t, c * 128 + lane] says whether row t takes key
+    c * 4096 + b * 128 + lane.  A tile of `block_k` keys is then
+    `block_k / 128` bits of ONE [block_q, 128] block of words, put in and
+    taken out by a shift a 128-lane piece with no move across lanes; the
+    forward kernel revisits the block over the 4096 / `block_k` tiles
+    that share it, as it does its accumulators.  Words past a row block's
+    diagonal are never written and never read.  `sparse_dq` and
+    `sparse_dkv` READ THE BITS: no index product, no threshold, no
+    position (the causal mask is in the bits); `sparse_dkv`, whose tile
+    is keys down and rows across, turns the unpacked tile (turning the
+    words first, a fourth of it, measured the same).
   - `keep_mask` (kernel `sparse_keep`) writes the same mask out as int8,
     for the tests and for the reference check, which hands the plain
     reference the program's choice.
@@ -43,13 +56,20 @@ The backward pass holds the selection constant: `sparse_attention` is a
 `custom_vjp` whose cotangents are those of q, k and v alone; the indexer's
 operands get zeros.  What the selection costs to keep for the backward
 pass is `aux`, [B, S, 128] float32 (the weights, `tau`, `cut`): 16 MB a
-layer at 32,768 rows.
+layer at 32,768 rows, which a layer rematerialised under the policy
+"selection" keeps from its forward pass; and `bits`, S^2 / 8 bytes, 134
+MB at 32,768 rows, a residual of the `custom_vjp` beside `o` and `lse`
+that such a layer's recomputed forward call writes for its own two
+backward kernels, so one layer's is alive at a time.
 
 A mask over dense tiles does the causal triangle's work whatever topk is:
 no tile of a random model's selection is empty.  The work the SELECTION
 leaves is what `benchmark/reduce/sparse_cost.py` counts, so this
-realisation reads low against its roofline and a kernel that gathers the
-chosen keys can raise it (ROADMAP.md).
+realisation reads low against its roofline: about 12% at most, the
+selected share of the pairs.  A kernel that gathers the chosen keys feeds
+a product the rows of ONE selection, the query heads of a key-value head,
+8 on an array 128 deep, and pays only where a row selects under 1 / 16 of
+its keys (ROADMAP.md R12).
 
 Layouts: q [B, H, S, D]; k, v [B, Hkv, S, D] (NOT repeated over the query
 heads: head h reads key-value head h // (H / Hkv)); qI [B, J, S, Di];
@@ -82,6 +102,9 @@ VMEM_LIMIT = 96 * 1024 * 1024
 # `select`'s slab of sortable scores, [rows, S] int32, is held to this.
 SELECT_SLAB_BYTES = 16 * 1024 * 1024
 INT_MIN = -2 ** 31
+# `bits`: a word holds a row's mask over 32 pieces of 128 keys, one bit a
+# piece, so 128 lanes of words cover this many keys of the row.
+WORD_KEYS = 32 * 128
 # The name `aux` carries for `jax.checkpoint`: a layer rematerialised under
 # `save_only_these_names(SELECTION_NAME)` (`models/afmoe.py` `_remat`,
 # policy "selection") keeps it and does not select a second time.
@@ -169,6 +192,30 @@ def _lane_sums(x):
     costs the vector unit an add a piece and no move across lanes."""
     return functools.reduce(
         jnp.add, [x[:, c:c + 128] for c in range(0, x.shape[1], 128)])
+
+
+def words(s: int) -> int:
+    """The width of a row of `bits`: 128 words for every WORD_KEYS keys."""
+    return 128 * -(-s // WORD_KEYS)
+
+
+def _first_bit(k0):
+    """Where in its words the tile of keys from `k0` on begins."""
+    return k0 % WORD_KEYS // 128
+
+
+def _pack(keep, bit):
+    """A tile's mask [rows, n * 128] bool as [rows, 128] int32: the n-th
+    128-lane piece at bit `bit` + n, the other bits 0.  A shift and an OR
+    a piece, and no move across lanes."""
+    return functools.reduce(jnp.bitwise_or, [
+        jnp.left_shift(keep[:, c:c + 128].astype(jnp.int32), bit + c // 128)
+        for c in range(0, keep.shape[1], 128)])
+
+
+def _unpack(word, bit, pieces):
+    """`_pack` undone, as the pieces one by one: [rows, 128] bool each."""
+    return [jnp.right_shift(word, bit + n) & 1 != 0 for n in range(pieces)]
 
 
 def _sortable(x):
@@ -372,8 +419,9 @@ def _online_step(s, v, m, l, acc):
 
 
 def _fwd_kernel(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref, qi_ref,
-                kit_ref, aux_ref, o_ref, lse_ref, count_ref, m_scr, l_scr,
-                acc_scr, count_scr, *, sm_scale, heads, block_q, block_k):
+                kit_ref, aux_ref, o_ref, lse_ref, count_ref, bits_ref, m_scr,
+                l_scr, acc_scr, count_scr, *, sm_scale, heads, block_q,
+                block_k):
     qb, kb, first, last = _entry(block_ref, tile_ref, flags_ref)
     _, hkv, group, _, d = q_ref.shape
 
@@ -386,8 +434,13 @@ def _fwd_kernel(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref, qi_ref,
 
     bias = _tile_bias(qi_ref, kit_ref, aux_ref, heads, qb * block_q,
                       kb * block_k)
-    count_scr[:] = count_scr[:] + _lane_sums(
-        (bias > NEG_INF).astype(jnp.float32))
+    keep = bias > NEG_INF
+    count_scr[:] = count_scr[:] + _lane_sums(keep.astype(jnp.float32))
+    # the row block's words are one block of the result over the tiles
+    # that share them: begun by the first, OR-ed into by the others
+    bit = _first_bit(kb * block_k)
+    word = _pack(keep, bit)
+    bits_ref[0] = jnp.where(bit == 0, word, bits_ref[0] | word)
     bias = jnp.concatenate([bias] * group, axis=0)        # [G * bq, bk]
     for g in range(hkv):
         s = _dot_nt(_scaled(_stacked(q_ref, g), sm_scale),
@@ -424,18 +477,25 @@ def _lanes(ref, g, group):
         [ref[0, g * group + i] for i in range(group)], axis=1)
 
 
+def _bits_bias(bits_ref, k0, block_k):
+    """The tile's bias [rows, block_k] from the words the forward kernel
+    wrote, with no product, score or position."""
+    keep = jnp.concatenate(
+        _unpack(bits_ref[0], _first_bit(k0), block_k // 128), axis=1)
+    return jnp.where(keep, 0.0, NEG_INF)
+
+
 def _dq_kernel(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
-               lse_ref, delta_ref, qi_ref, kit_ref, aux_ref, dq_ref, dq_scr,
-               *, sm_scale, heads, block_q, block_k):
-    qb, kb, first, last = _entry(block_ref, tile_ref, flags_ref)
-    _, hkv, group, _, d = q_ref.shape
+               lse_ref, delta_ref, bits_ref, dq_ref, dq_scr, *, sm_scale,
+               block_k):
+    _, kb, first, last = _entry(block_ref, tile_ref, flags_ref)
+    _, hkv, group, block_q, d = q_ref.shape
 
     @pl.when(first)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    bias = _tile_bias(qi_ref, kit_ref, aux_ref, heads, qb * block_q,
-                      kb * block_k)
+    bias = _bits_bias(bits_ref, kb * block_k, block_k)
     bias = jnp.concatenate([bias] * group, axis=0)
     for g in range(hkv):
         k = k_ref[0, g]
@@ -454,13 +514,13 @@ def _dq_kernel(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
 
 
 def _dkv_kernel(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
-                lse_ref, delta_ref, qi_ref, kit_ref, aux_ref, dk_ref, dv_ref,
-                dk_scr, dv_scr, *, sm_scale, heads, block_q, block_k):
+                lse_ref, delta_ref, bits_ref, dk_ref, dv_ref, dk_scr, dv_scr,
+                *, sm_scale, block_k):
     # the block is of keys here and the tile of rows; the tile of logits
     # is computed TRANSPOSED, keys down and rows across, as
     # `flash_attention._dkv_step` does and for its reasons.  The mask is
-    # computed as the forward computed it and then turned.
-    kb, qb, first, last = _entry(block_ref, tile_ref, flags_ref)
+    # unpacked as the forward kernel packed it and then turned.
+    kb, _, first, last = _entry(block_ref, tile_ref, flags_ref)
     _, hkv, group, _, d = q_ref.shape
 
     @pl.when(first)
@@ -468,8 +528,7 @@ def _dkv_kernel(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    bias = _tile_bias(qi_ref, kit_ref, aux_ref, heads, qb * block_q,
-                      kb * block_k).T                     # [bk, bq]
+    bias = _bits_bias(bits_ref, kb * block_k, block_k).T  # [bk, bq]
     bias = jnp.concatenate([bias] * group, axis=1)        # [bk, G * bq]
     for g in range(hkv):
         q, do = _stacked(q_ref, g), _stacked(do_ref, g)
@@ -486,12 +545,13 @@ def _dkv_kernel(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-def _specs(shapes, block_q, block_k, rows_column):
+def _specs(shapes, block_q, block_k, rows_column, index=None):
     """BlockSpecs over the grid (batch, entry of the table).  `rows_column`
     is the table's column that holds the step's block of ROWS (0 where
     the program owns rows, 1 where it owns keys); the other holds its
-    block of keys."""
-    hkv, group, d, heads, di = shapes
+    block of keys.  `index`: the indexer's (heads, size), for the kernel
+    that reads its operands."""
+    hkv, group, d = shapes
     keys_column = 1 - rows_column
 
     def rows(b, t, *table):
@@ -499,7 +559,7 @@ def _specs(shapes, block_q, block_k, rows_column):
 
     def keys(b, t, *table):
         return table[keys_column][t]
-    return {
+    spec = {
         "q": pl.BlockSpec((1, hkv, group, block_q, d),
                           lambda b, t, *tb: (b, 0, 0, rows(b, t, *tb), 0)),
         "kv": pl.BlockSpec((1, hkv, block_k, d),
@@ -508,13 +568,22 @@ def _specs(shapes, block_q, block_k, rows_column):
                              lambda b, t, *tb: (b, 0, 0, rows(b, t, *tb))),
         "count": pl.BlockSpec((1, 1, block_q),
                               lambda b, t, *tb: (b, 0, rows(b, t, *tb))),
-        "qi": pl.BlockSpec((1, heads, block_q, di),
-                           lambda b, t, *tb: (b, 0, rows(b, t, *tb), 0)),
-        "kit": pl.BlockSpec((1, di, block_k),
-                            lambda b, t, *tb: (b, 0, keys(b, t, *tb))),
-        "aux": pl.BlockSpec((1, block_q, AUX_LANES),
-                            lambda b, t, *tb: (b, rows(b, t, *tb), 0)),
+        # the words of the step's rows that hold its tile of keys
+        "bits": pl.BlockSpec(
+            (1, block_q, 128),
+            lambda b, t, *tb: (b, rows(b, t, *tb),
+                               keys(b, t, *tb) * block_k // WORD_KEYS)),
     }
+    if index:
+        heads, di = index
+        spec.update(
+            qi=pl.BlockSpec((1, heads, block_q, di),
+                            lambda b, t, *tb: (b, 0, rows(b, t, *tb), 0)),
+            kit=pl.BlockSpec((1, di, block_k),
+                             lambda b, t, *tb: (b, 0, keys(b, t, *tb))),
+            aux=pl.BlockSpec((1, block_q, AUX_LANES),
+                             lambda b, t, *tb: (b, rows(b, t, *tb), 0)))
+    return spec
 
 
 def _table_call(kernel, table, batch, in_specs, out_specs, scratch_shapes,
@@ -534,10 +603,10 @@ def _table_call(kernel, table, batch, in_specs, out_specs, scratch_shapes,
     return functools.partial(call, *columns)
 
 
-def _shapes(q, k, qi):
+def _shapes(q, k):
     b, h, s, d = q.shape
     hkv = k.shape[1]
-    return b, s, (hkv, h // hkv, d, qi.shape[1], qi.shape[-1])
+    return b, s, (hkv, h // hkv, d)
 
 
 def _grouped(x, hkv):
@@ -547,17 +616,18 @@ def _grouped(x, hkv):
 
 
 def _forward(q, k, v, qi, kit, aux, sm_scale, block_q, block_k, interpret):
-    b, s, shapes = _shapes(q, k, qi)
-    hkv, group, d, heads, _ = shapes
-    spec = _specs(shapes, block_q, block_k, 0)
+    b, s, shapes = _shapes(q, k)
+    hkv, group, d = shapes
+    heads = qi.shape[1]
+    spec = _specs(shapes, block_q, block_k, 0, (heads, qi.shape[-1]))
     rows = group * block_q
-    o, lse, count = _table_call(
+    o, lse, count, bits = _table_call(
         functools.partial(_fwd_kernel, sm_scale=sm_scale, heads=heads,
                           block_q=block_q, block_k=block_k),
         stream_table(s, block_q, block_k, True), b,
         in_specs=[spec["q"], spec["kv"], spec["kv"], spec["qi"],
                   spec["kit"], spec["aux"]],
-        out_specs=[spec["q"], spec["stat"], spec["count"]],
+        out_specs=[spec["q"], spec["stat"], spec["count"], spec["bits"]],
         scratch_shapes=[pltpu.VMEM((hkv, rows, 128), jnp.float32),
                         pltpu.VMEM((hkv, rows, 128), jnp.float32),
                         pltpu.VMEM((hkv, rows, d), jnp.float32),
@@ -565,29 +635,28 @@ def _forward(q, k, v, qi, kit, aux, sm_scale, block_q, block_k, interpret):
         out_shape=[
             jax.ShapeDtypeStruct((b, hkv, group, s, d), q.dtype),
             jax.ShapeDtypeStruct((b, hkv * group, 1, s), jnp.float32),
-            jax.ShapeDtypeStruct((b, 1, s), jnp.float32)],
+            jax.ShapeDtypeStruct((b, 1, s), jnp.float32),
+            jax.ShapeDtypeStruct((b, s, words(s)), jnp.int32)],
         interpret=interpret, name="sparse_fwd",
     )(_grouped(q, hkv), k, v, qi, kit, aux)
-    return o.reshape(q.shape), lse, count[:, 0]
+    return o.reshape(q.shape), lse, count[:, 0], bits
 
 
-def _backward(q, k, v, qi, kit, aux, o, lse, do, sm_scale, block_q, block_k,
+def _backward(q, k, v, bits, o, lse, do, sm_scale, block_q, block_k,
               interpret):
-    b, s, shapes = _shapes(q, k, qi)
-    hkv, group, d, heads, _ = shapes
+    b, s, shapes = _shapes(q, k)
+    hkv, group, d = shapes
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, :, None, :]               # [B, H, 1, S]
-    operands = (_grouped(q, hkv), k, v, _grouped(do, hkv), lse, delta, qi,
-                kit, aux)
+    operands = (_grouped(q, hkv), k, v, _grouped(do, hkv), lse, delta, bits)
 
     def in_specs(spec):
         return [spec["q"], spec["kv"], spec["kv"], spec["q"], spec["stat"],
-                spec["stat"], spec["qi"], spec["kit"], spec["aux"]]
+                spec["stat"], spec["bits"]]
 
     spec = _specs(shapes, block_q, block_k, 0)
     dq = _table_call(
-        functools.partial(_dq_kernel, sm_scale=sm_scale, heads=heads,
-                          block_q=block_q, block_k=block_k),
+        functools.partial(_dq_kernel, sm_scale=sm_scale, block_k=block_k),
         stream_table(s, block_q, block_k, True), b,
         in_specs=in_specs(spec), out_specs=spec["q"],
         scratch_shapes=[pltpu.VMEM((hkv, group * block_q, d), jnp.float32)],
@@ -596,8 +665,7 @@ def _backward(q, k, v, qi, kit, aux, o, lse, do, sm_scale, block_q, block_k,
     )(*operands)
     spec = _specs(shapes, block_q, block_k, 1)
     dk, dv = _table_call(
-        functools.partial(_dkv_kernel, sm_scale=sm_scale, heads=heads,
-                          block_q=block_q, block_k=block_k),
+        functools.partial(_dkv_kernel, sm_scale=sm_scale, block_k=block_k),
         stream_table(s, block_q, block_k, True, by_keys=True), b,
         in_specs=in_specs(spec), out_specs=[spec["kv"], spec["kv"]],
         scratch_shapes=[pltpu.VMEM((hkv, block_k, d), jnp.float32),
@@ -625,6 +693,10 @@ def _blocks(s, block_q, block_k):
     auto_q, auto_k = auto_blocks(s)
     block_q, block_k = block_q or auto_q, block_k or auto_k
     check_blocks(s, block_q, block_k)
+    if WORD_KEYS % block_k:
+        raise ValueError(
+            f"sparse attention keeps a tile's mask in one block of words: "
+            f"block_k={block_k} must divide {WORD_KEYS}")
     return block_q, block_k
 
 
@@ -633,19 +705,19 @@ def _sparse_fwd(q, k, v, qi, kit, aux, sm_scale, block_q, block_k,
     s, d = q.shape[2], q.shape[3]
     block_q, block_k = _blocks(s, block_q, block_k)
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    o, lse, count = _forward(q, k, v, qi, kit, aux, scale, block_q, block_k,
-                             _use_interpret(interpret))
-    return (o, count), (q, k, v, qi, kit, aux, o, lse)
+    o, lse, count, bits = _forward(q, k, v, qi, kit, aux, scale, block_q,
+                                   block_k, _use_interpret(interpret))
+    # qi, kit and aux are kept for the shapes of their zero cotangents
+    return (o, count), (q, k, v, qi, kit, aux, o, lse, bits)
 
 
 def _sparse_bwd(sm_scale, block_q, block_k, interpret, residuals, cotangent):
-    q, k, v, qi, kit, aux, o, lse = residuals
+    q, k, v, qi, kit, aux, o, lse, bits = residuals
     s, d = q.shape[2], q.shape[3]
     block_q, block_k = _blocks(s, block_q, block_k)
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    dq, dk, dv = _backward(q, k, v, qi, kit, aux, o, lse, cotangent[0],
-                           scale, block_q, block_k,
-                           _use_interpret(interpret))
+    dq, dk, dv = _backward(q, k, v, bits, o, lse, cotangent[0], scale,
+                           block_q, block_k, _use_interpret(interpret))
     return (dq, dk, dv, jnp.zeros_like(qi), jnp.zeros_like(kit),
             jnp.zeros_like(aux))
 
@@ -676,11 +748,13 @@ def selected_attention(q, k, v, qi, ki, w, topk: int, block_q: int = 0,
         aux = checkpoint_name(
             _alone(select(qi, kit, w, topk, block_k, interpret)),
             SELECTION_NAME)
+    block, tile, _ = stream_table(s, block_q, block_k, True)
+    written = {(i, j * block_k // WORD_KEYS) for i, j in zip(block, tile)}
     telemetry.record_static(
         "sparse_attention", rows=s, topk=min(topk, s),
         selected_pairs=selected_pairs(s, topk),
-        visible_pairs=s * (s + 1) // 2,
-        tiles_walked=len(stream_table(s, block_q, block_k, True)[0]))
+        visible_pairs=s * (s + 1) // 2, tiles_walked=len(block),
+        index_passes=1, mask_bytes=len(written) * block_q * 128 * 4)
     with jax.named_scope(".sparse"):
         return _alone(sparse_attention(q, k, v, qi, kit, aux, None, block_q,
                                        block_k, interpret))

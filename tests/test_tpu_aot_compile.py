@@ -460,3 +460,62 @@ def test_sparse_attention_compiles_at_keye_shapes(v5e, monkeypatch, what):
              .replace("%jvp_", "%") for line in text.splitlines()
              if " custom-call(" in line and "tpu_custom_call" in line]
     assert {sparse_cost.kernel(line) for line in calls} == names, calls
+
+
+def test_keye_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch):
+    """The keye cell's step (`benchmark/configs/keye-vl-2.0-30b-a3b.json`:
+    four layers, one sequence of 32,768, a plain `value_and_grad` and
+    adamw) for one described chip: every attention kernel inside the
+    limit it asks of VMEM, the backward kernels fed the forward kernel's
+    words ([1, 32768, 1024] int32, 134 MB a layer) and not the indexer's
+    operands, the selection made once a layer and the tiles' scores
+    computed by `sparse_fwd` alone, twice under the layer's remat; and
+    the program beside its arguments inside the chip's memory."""
+    import json
+
+    import optax
+
+    from benchmark.families import keye as family_keye
+    from benchmark.harness import manifest
+    from benchmark.reduce import sparse_cost
+    from byteps_tpu.ops import sparse_attention as sa
+    monkeypatch.setattr(fa, "_use_interpret", lambda interpret: False)
+    with open(os.path.join(manifest.BENCH, "configs",
+                           "keye-vl-2.0-30b-a3b.json")) as f:
+        config = json.load(f)
+    family = family_keye.Family(config, config["job"])
+    opt = family.optimizer()
+    one = SingleDeviceSharding(v5e[0])
+
+    def step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(family.loss)(params, batch)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+    params = jax.eval_shape(family.init, jax.random.key(0))
+    opt_state = jax.eval_shape(opt.init, params)
+    batch = jax.eval_shape(lambda k: family.make_batch(k, 1),
+                           jax.random.key(0))
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(opt_state), on_chip(batch)).compile()
+    text = compiled.as_text()
+    calls = [line for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    kinds = [sparse_cost.kernel(line.strip().removeprefix("ROOT "))
+             for line in calls]
+    # a scan over the layers: its body holds a layer's calls once
+    assert sorted(k for k in kinds if k) == ["dkv", "dq", "forward",
+                                             "forward", "select"]
+    words = f"s32[1,32768,{sa.words(32768)}]"
+    for line, kind in zip(calls, kinds):
+        operands = line.split(" custom-call(", 1)[1]
+        if kind in ("dq", "dkv"):
+            assert words in operands, line
+            assert "bf16[1,16,32768,64]" not in operands, line
+        if kind == "forward":
+            assert words in line.split(" custom-call(", 1)[0], line
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.9e9
