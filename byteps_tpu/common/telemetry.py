@@ -527,6 +527,12 @@ _STATIC = {
             "bps_sparse_mask_bytes",
             "bytes of packed mask the forward kernel writes a sequence: "
             "the causal part of its words", float),
+        "kept_bytes": _gauge(
+            "bps_sparse_kept_bytes",
+            "bytes that call names for the backward pass a sequence and "
+            "layer (o, lse and the mask's words whole): what the remat "
+            "policy \"selection\" keeps so that the forward kernel runs "
+            "once", float),
     },
 }
 

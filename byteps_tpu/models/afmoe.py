@@ -340,16 +340,18 @@ def _layer(x, lp, sel, cfg: AfmoeConfig, kind: str, is_moe: bool):
 def _remat(fn, cfg):
     if not cfg.remat:
         return fn
-    from ..ops.sparse_attention import SELECTION_NAME
+    from ..ops.sparse_attention import KEPT_NAMES
     policies = {
         "none": None,
         "dots": jax.checkpoint_policies.checkpoint_dots,
         "dots_no_batch":
             jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
         # what a layer that selects its keys found (16 MB a layer at
-        # 32,768 rows), so that the backward pass does not find it again
+        # 32,768 rows) and what its attention call made under it (o, lse
+        # and the mask as bits: 407 MB), so that the backward pass neither
+        # selects nor calls the forward kernel again
         "selection": jax.checkpoint_policies.save_only_these_names(
-            SELECTION_NAME),
+            *KEPT_NAMES),
     }
     if cfg.remat_policy not in policies:
         raise ValueError(f"remat_policy={cfg.remat_policy!r}; options: "

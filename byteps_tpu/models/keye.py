@@ -97,6 +97,7 @@ class KeyeConfig:
     attn_block_k: int = 0              # and keys of a tile; 0: its rule
     remat: bool = True                 # per layer
     remat_policy: str = "none"         # "selection" keeps a layer's choice
+                                       # and its attention's o, lse, bits
     ce_chunk_rows: int = 0             # > 0: streamed head + cross-entropy
     moe_capacity_factor: float = 1.25  # dropless_moe's static buffer
     num_dense_layers = 0               # what afmoe's `_stack_plan` reads

@@ -54,13 +54,21 @@ kernels that read the forward kernel's mask.
 
 The backward pass holds the selection constant: `sparse_attention` is a
 `custom_vjp` whose cotangents are those of q, k and v alone; the indexer's
-operands get zeros.  What the selection costs to keep for the backward
-pass is `aux`, [B, S, 128] float32 (the weights, `tau`, `cut`): 16 MB a
-layer at 32,768 rows, which a layer rematerialised under the policy
-"selection" keeps from its forward pass; and `bits`, S^2 / 8 bytes, 134
-MB at 32,768 rows, a residual of the `custom_vjp` beside `o` and `lse`
-that such a layer's recomputed forward call writes for its own two
-backward kernels, so one layer's is alive at a time.
+operands get zeros.  What a layer rematerialised under the policy
+"selection" (`models/afmoe.py` `_remat`) KEEPS from its forward pass, by
+name: `aux` (`SELECTION_NAME`), [B, S, 128] float32 (the weights, `tau`,
+`cut`), 16 MB a layer at 32,768 rows, so the layer does not select a
+second time; and what the attention call made (`ATTENTION_NAME`): `o`,
+`lse` and `bits`, the `custom_vjp`'s residuals, `o` the call's result as
+well, so ONE `o` a layer.  The recomputed layer then has no consumer of
+`sparse_fwd` left and does not call it: the kernel runs once a layer and
+step, and its two backward kernels read what that one call wrote.  Kept a
+sequence and layer: S x (H x D x 2 + H x 4 + S / 8) bytes for bfloat16
+heads (S / 8 rounded up to 512 a 4,096 keys): 268 + 4 + 134 = 407 MB at
+32,768 rows of 32 heads of 128, in the layer scan's stacks from the
+forward pass to the backward pass (`bps_sparse_kept_bytes`).  A job whose
+stage holds more layers than its memory has room for picks "none", which
+keeps nothing and runs selection and forward kernel twice.
 
 A mask over dense tiles does the causal triangle's work whatever topk is:
 no tile of a random model's selection is empty.  The work the SELECTION
@@ -105,10 +113,16 @@ INT_MIN = -2 ** 31
 # `bits`: a word holds a row's mask over 32 pieces of 128 keys, one bit a
 # piece, so 128 lanes of words cover this many keys of the row.
 WORD_KEYS = 32 * 128
-# The name `aux` carries for `jax.checkpoint`: a layer rematerialised under
-# `save_only_these_names(SELECTION_NAME)` (`models/afmoe.py` `_remat`,
-# policy "selection") keeps it and does not select a second time.
+# The names a call's results carry for `jax.checkpoint`: a layer
+# rematerialised under `save_only_these_names(*KEPT_NAMES)`
+# (`models/afmoe.py` `_remat`, policy "selection") keeps `aux` and does not
+# select a second time, and keeps the forward kernel's `o`, `lse` and
+# `bits` and does not call it a second time.  Two names, for what two
+# kernels made: a policy of a user's own can keep the selection's 16 MB
+# without the attention's 407 (at 32,768 rows).
 SELECTION_NAME = "sparse.selection"
+ATTENTION_NAME = "sparse.attention"
+KEPT_NAMES = (SELECTION_NAME, ATTENTION_NAME)
 
 
 def _use_interpret(interpret: Optional[bool]) -> bool:
@@ -700,13 +714,30 @@ def _blocks(s, block_q, block_k):
     return block_q, block_k
 
 
+def _alone(results):
+    """A kernel's results behind a barrier.  The compiler otherwise fuses
+    what consumes them INTO the kernel's instruction (a scan's stacking of
+    `count` was; its stacking of `o`, `lse` and `bits` for the backward
+    pass is three more such consumers), and such a fusion is held to the
+    compiler's own 16 MiB of VMEM whatever `vmem_limit_bytes` the kernel
+    was given: the compile then ends with `Ran out of memory in memory
+    space vmem`."""
+    return lax.optimization_barrier(results)
+
+
 def _sparse_fwd(q, k, v, qi, kit, aux, sm_scale, block_q, block_k,
                 interpret):
     s, d = q.shape[2], q.shape[3]
     block_q, block_k = _blocks(s, block_q, block_k)
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    o, lse, count, bits = _forward(q, k, v, qi, kit, aux, scale, block_q,
-                                   block_k, _use_interpret(interpret))
+    # The barrier stands between the kernel and the names: a recomputed
+    # layer needs the `o` that comes out of it, so with the names inside it
+    # the barrier would be run again, want `count`, and `count` the kernel.
+    o, lse, count, bits = _alone(_forward(
+        q, k, v, qi, kit, aux, scale, block_q, block_k,
+        _use_interpret(interpret)))
+    o, lse, bits = (checkpoint_name(t, ATTENTION_NAME)
+                    for t in (o, lse, bits))
     # qi, kit and aux are kept for the shapes of their zero cotangents
     return (o, count), (q, k, v, qi, kit, aux, o, lse, bits)
 
@@ -725,22 +756,14 @@ def _sparse_bwd(sm_scale, block_q, block_k, interpret, residuals, cotangent):
 sparse_attention.defvjp(_sparse_fwd, _sparse_bwd)
 
 
-def _alone(results):
-    """A kernel's results behind a barrier.  The compiler otherwise fuses
-    what consumes them INTO the kernel's instruction (a scan's stacking of
-    `count` was), and such a fusion is held to the compiler's own 16 MiB
-    of VMEM whatever `vmem_limit_bytes` the kernel was given: the compile
-    then ends with `Ran out of memory in memory space vmem`."""
-    return lax.optimization_barrier(results)
-
-
 def selected_attention(q, k, v, qi, ki, w, topk: int, block_q: int = 0,
                        block_k: int = 0, interpret: Optional[bool] = None):
     """Selection and attention together: `(o, count)`.  qi, ki and w are
     read as constants (`lax.stop_gradient`): the choice of keys has no
     gradient.  A call that is traced records its form
-    (`bps_sparse_*`)."""
-    s = q.shape[2]
+    (`bps_sparse_*`).  The kernels' results come from behind barriers
+    (`_alone`), the attention's inside its forward rule."""
+    _, h, s, d = q.shape
     block_q, block_k = _blocks(s, block_q, block_k)
     qi, ki, w = (lax.stop_gradient(t) for t in (qi, ki, w))
     kit = ki.transpose(0, 2, 1)
@@ -754,10 +777,12 @@ def selected_attention(q, k, v, qi, ki, w, topk: int, block_q: int = 0,
         "sparse_attention", rows=s, topk=min(topk, s),
         selected_pairs=selected_pairs(s, topk),
         visible_pairs=s * (s + 1) // 2, tiles_walked=len(block),
-        index_passes=1, mask_bytes=len(written) * block_q * 128 * 4)
+        index_passes=1, mask_bytes=len(written) * block_q * 128 * 4,
+        # o, lse and bits of a sequence: what `ATTENTION_NAME` names
+        kept_bytes=s * (h * d * q.dtype.itemsize + h * 4 + words(s) * 4))
     with jax.named_scope(".sparse"):
-        return _alone(sparse_attention(q, k, v, qi, kit, aux, None, block_q,
-                                       block_k, interpret))
+        return sparse_attention(q, k, v, qi, kit, aux, None, block_q,
+                                block_k, interpret)
 
 
 # ---------------------------------------------------------------------------

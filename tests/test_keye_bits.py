@@ -9,6 +9,8 @@ those of the same attention with the [S, S] scores whole; the backward
 calls take no operand of the indexer's.  A file beside `test_keye.py` so
 that the two run on two workers."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -125,26 +127,89 @@ def test_the_backward_kernels_take_no_indexer_operand():
         sa.selected_attention(*_operands(1536), 64, 128, 384)
 
 
-def test_a_layer_selects_once_and_scores_its_tiles_twice():
-    """Under the cell's remat policy a layer's step calls `index_topk`
-    once, `sparse_fwd` twice (the second writes the bits its two backward
-    kernels read), `sparse_dq` and `sparse_dkv` once; the gauges say who
-    computes index scores and what the mask weighs."""
-    family = tiny_keye.family(jnp.float32, layers=[0, 1])
+def _layer_step(dtype):
+    """The tiny family cut to a scan over one period of one layer:
+    `(cfg, rows of a sequence, params, loss_under)`, `loss_under(policy,
+    remat)` the loss of the parameters under that remat policy."""
+    family = tiny_keye.family(dtype, layers=[0, 1])
     cfg = family.cfg
-    assert cfg.remat_policy == "selection"
+    assert cfg.remat and cfg.remat_policy == "selection"
     params, batch = seeded.params(family, 0), seeded.batch(family, 0, 1)
-    text = str(jax.make_jaxpr(jax.grad(
-        lambda p: keye.loss_fn(p, batch, cfg)))(params))
+
+    def loss_under(policy, remat=True):
+        changed = dataclasses.replace(cfg, remat=remat, remat_policy=policy)
+        return lambda p: keye.loss_fn(p, batch, changed)
+    return cfg, family.seq_len, params, loss_under
+
+
+def _kept_bytes(s, heads, kv_heads, d):
+    """`o`, `lse` and `bits` of a sequence, from the forward rule's own
+    residuals."""
+    q, k, v, qi, ki, _ = _operands(s, heads=heads, kv_heads=kv_heads, d=d)
+    aux = jax.ShapeDtypeStruct((1, s, sa.AUX_LANES), jnp.float32)
+    _, residuals = jax.eval_shape(
+        lambda *a: sa._sparse_fwd(*a, None, 0, 0, True), q, k, v, qi,
+        ki.transpose(0, 2, 1), aux)
+    return sum(t.size * t.dtype.itemsize for t in residuals[-3:])
+
+
+@pytest.mark.parametrize("policy,calls", [
+    ("selection", {"index_topk": 1, "sparse_fwd": 1, "sparse_dq": 1,
+                   "sparse_dkv": 1}),
+    ("none", {"index_topk": 2, "sparse_fwd": 2, "sparse_dq": 1,
+              "sparse_dkv": 1})])
+def test_a_layer_selects_once_and_scores_its_tiles_once(policy, calls):
+    """Under the cell's remat policy a layer's step calls every kernel
+    ONCE: the recomputed layer reads the `aux` its forward pass selected
+    and the `o`, `lse` and `bits` its forward kernel wrote, by name.
+    Under "none", the control, selection and forward kernel run twice.
+    The gauges say who computes index scores, what the mask weighs and
+    what the names keep."""
+    cfg, rows, params, loss_under = _layer_step(jnp.float32)
+    text = str(jax.make_jaxpr(jax.grad(loss_under(policy)))(params))
     # a scan over one period of one layer: calls a layer
-    counted = {name: text.count(f"name={name}") for name in
-               ("index_topk", "sparse_fwd", "sparse_dq", "sparse_dkv")}
-    assert counted == {"index_topk": 1, "sparse_fwd": 2, "sparse_dq": 1,
-                       "sparse_dkv": 1}
+    assert {name: text.count(f"name={name}") for name in calls} == calls
     metrics = bps.get_metrics()
     assert metrics["bps_sparse_index_passes"] == 1
-    rows = family.seq_len
     assert metrics["bps_sparse_rows"] == rows
     # every row block's words up to its diagonal's chunk
     assert metrics["bps_sparse_mask_bytes"] == sum(
         (q0 + 127) // 4096 + 1 for q0 in range(0, rows, 128)) * 128 * 128 * 4
+    assert metrics["bps_sparse_kept_bytes"] == _kept_bytes(
+        rows, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_gradients_are_the_same_bits_whatever_is_kept(dtype):
+    """The same kernels on the same operands: every gradient leaf under
+    "selection" (each kernel once) equals, bit for bit, the one under
+    "none" (selection and forward kernel twice) and the one with no
+    rematerialisation at all."""
+    _, _, params, loss_under = _layer_step(dtype)
+    kept, twice, whole = (
+        jax.tree.leaves(jax.jit(jax.grad(loss_under(policy, remat)))(params))
+        for policy, remat in (("selection", True), ("none", True),
+                              ("none", False)))
+    assert len(kept) > 3 and any(np.asarray(leaf).any() for leaf in kept)
+    for other in (twice, whole):
+        for mine, theirs in zip(kept, other):
+            assert mine.dtype == theirs.dtype
+            np.testing.assert_array_equal(np.asarray(mine),
+                                          np.asarray(theirs))
+
+
+def test_what_a_call_keeps_at_the_cells_shapes():
+    """`bps_sparse_kept_bytes` at 32,768 rows of 32 bfloat16 heads of 128:
+    o 268,435,456 + lse 4,194,304 + bits 134,217,728, from the shapes
+    alone, and as the module's docstring reckons it."""
+    shapes = {"q": (1, 32, 32768, 128), "kv": (1, 4, 32768, 128),
+              "qi": (1, 16, 32768, 64), "ki": (1, 32768, 64),
+              "w": (1, 32768, 16)}
+    q, kv, qi, ki, w = (jax.ShapeDtypeStruct(shapes[n], jnp.bfloat16)
+                        for n in ("q", "kv", "qi", "ki", "w"))
+    o, count = jax.eval_shape(
+        lambda *a: sa.selected_attention(*a, 2048), q, kv, kv, qi, ki, w)
+    assert o.shape == q.shape and count.shape == (1, 32768)
+    assert bps.get_metrics()["bps_sparse_kept_bytes"] == 406_847_488
+    assert 32768 * (32 * 128 * 2 + 32 * 4 + 32768 // 8) == 406_847_488

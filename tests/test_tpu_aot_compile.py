@@ -468,9 +468,12 @@ def test_keye_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch):
     adamw) for one described chip: every attention kernel inside the
     limit it asks of VMEM, the backward kernels fed the forward kernel's
     words ([1, 32768, 1024] int32, 134 MB a layer) and not the indexer's
-    operands, the selection made once a layer and the tiles' scores
-    computed by `sparse_fwd` alone, twice under the layer's remat; and
-    the program beside its arguments inside the chip's memory."""
+    operands, and every kernel ONCE a layer: the scan bodies hold `dkv`,
+    `dq`, `forward`, `select`, because the layer's remat policy keeps by
+    name what selection and forward kernel made (a second `forward` was
+    there until PR 45); and the program's peak inside the chip's memory
+    with the four layers' 1.63 GB of `o`, `lse` and `bits` in its
+    stacks."""
     import json
 
     import optax
@@ -508,7 +511,7 @@ def test_keye_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch):
              for line in calls]
     # a scan over the layers: its body holds a layer's calls once
     assert sorted(k for k in kinds if k) == ["dkv", "dq", "forward",
-                                             "forward", "select"]
+                                             "select"]
     words = f"s32[1,32768,{sa.words(32768)}]"
     for line, kind in zip(calls, kinds):
         operands = line.split(" custom-call(", 1)[1]
@@ -517,5 +520,13 @@ def test_keye_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch):
             assert "bf16[1,16,32768,64]" not in operands, line
         if kind == "forward":
             assert words in line.split(" custom-call(", 1)[0], line
+    # The compiler's peak plus its code is what tells the chip's
+    # `memory_peak_bytes`: PR 44's step 12,667,947,520 + 84e6 here and
+    # 13,047,652,864 measured; this step 14,164,443,136 + 80e6 here and
+    # 14,254,139,392 measured (my chip runs, PR 45).  `argument_size_in_bytes +
+    # temp_size_in_bytes`, which this line held under 16.9e9 until PR 45,
+    # reads 16.63e9 and 19.02e9 for the two: 3.6e9 over what the chip
+    # measured for the first, and no bound on what fits.
     mem = compiled.memory_analysis()
-    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.9e9
+    assert (mem.peak_memory_in_bytes
+            + mem.generated_code_size_in_bytes) < 15.5e9
