@@ -744,35 +744,34 @@ def push_pull_tree(tree: PyTree, name: Optional[str] = None,
     gradient batching, torch/parallel/distributed.py:235-243; per-tensor
     eager push_pull pays one crossing per gradient).
 
-    With fusion enabled (``BYTEPS_TPU_FUSION_BYTES`` > 0, the default
-    1 MiB; the ``fusion_bytes`` argument overrides per call), leaves
-    below the threshold are packed by the fusion planner
-    (common/fusion.py) into dtype-homogeneous, size-capped buckets in
-    reverse backprop order; each bucket rides ONE wire key at the max
-    priority of its members, and larger leaves keep their own key and
-    backprop-position priority.  Units go to the PS scheduler one by
-    one, in (priority desc, declared key asc) order, each as soon as it
-    is copied off the device — so the PS dispatcher sends last-layer
-    buckets first while earlier buckets still stage (the overlap the
-    priority ScheduledQueues exist for), instead of one all-or-nothing
-    f32 vector that can't overlap with anything.  If a unit fails to
-    stage, the units before it are already on the wire and complete
-    their round unobserved; the exception surfaces, no key stays
-    wedged, and the next call goes through
+    The tree crosses as ONE round of dispatch units.  Leaves below the
+    fusion threshold (``BYTEPS_TPU_FUSION_BYTES``, default 1 MiB; the
+    ``fusion_bytes`` argument overrides per call) are packed by the
+    fusion planner (common/fusion.py) into dtype-homogeneous,
+    size-capped buckets in reverse backprop order; each bucket rides ONE
+    wire key at the max priority of its members, and larger leaves keep
+    their own key and backprop-position priority.  Units go to the PS
+    scheduler one by one, in (priority desc, declared key asc) order,
+    each as soon as it is copied off the device — so the PS dispatcher
+    sends last-layer buckets first while earlier buckets still stage
+    (the overlap the priority ScheduledQueues exist for).  If a unit
+    fails to stage, the units before it are already on the wire and
+    complete their round unobserved; the exception surfaces, no key
+    stays wedged, and the next call goes through
     (``PSSession.push_pull_group``).
 
-    With fusion DISABLED (``BYTEPS_TPU_FUSION_BYTES=0``), floating
-    leaves are flattened into one f32 vector reduced through a single
-    push_pull — byte-identical to the pre-fusion wire path.
+    ``BYTEPS_TPU_FUSION_BYTES=0`` means NO PACKING: the plan has no
+    bucket, and every leaf is a unit of the same round under its own
+    key.
 
-    Two classes of leaves are deliberately never fused/batched:
-      - non-floating leaves (ints, bools): an f32 round-trip corrupts
-        values above 2^24 and truncates averages — they ride individual
-        exact push_pulls;
+    Two classes of leaves are deliberately never packed; each is a unit
+    of its own in the same round:
+      - non-floating leaves (ints, bools): a lossy intra-node cast would
+        corrupt them — they ride uncompressed and exact;
       - leaves whose `leaf_names[i]` has a PS wire compressor registered
         (register_compressor): folding them into a shared key would
         silently drop the user's compression config — they keep their own
-        named push_pull so the compressed wire still applies.
+        named key so the compressed wire still applies.
     `leaf_names` aligns with the FLATTENED leaf order (for a dict tree:
     sorted keys).  Unnamed leaves get deterministic names derived from
     the batch name + the leaf's TREE PATH (stable under structural
@@ -784,22 +783,22 @@ def push_pull_tree(tree: PyTree, name: Optional[str] = None,
     """
     _require_init()
     sess = _state.ps_session
-    if sess is None:
-        return _push_pull_tree(tree, name, average, compression,
-                               leaf_names, fusion_bytes)
-    with sess.spans.round(name or "push_pull_tree"):
+    with (sess.spans.round(name or "push_pull_tree") if sess is not None
+          else stage_spans.off()):
         return _push_pull_tree(tree, name, average, compression,
                                leaf_names, fusion_bytes)
 
 
 def _push_pull_tree(tree, name, average, compression, leaf_names,
                     fusion_bytes):
+    from .fusion import plan_buckets
+
     paths_leaves, treedef = jax.tree_util.tree_flatten_with_path(tree)
     if not paths_leaves:
         return tree
     leaves = [jnp.asarray(l) for _, l in paths_leaves]
     metas = [(l.shape, l.dtype, int(l.size)) for l in leaves]
-    cfg = _state.config or get_config()
+    sess = _state.ps_session
     if fusion_bytes is not None:
         fb = int(fusion_bytes)
     else:
@@ -808,13 +807,11 @@ def _push_pull_tree(tree, name, average, compression, leaf_names,
         # whose round boundary this session has reached, so every worker
         # flips to the new threshold at the same round and the
         # composition-derived bucket keys line up fleet-wide.
-        fb = (_state.ps_session.live_fusion_bytes()
-              if _state.ps_session is not None else None)
+        fb = sess.live_fusion_bytes() if sess is not None else None
         if fb is None:
-            fb = cfg.fusion_bytes
+            fb = (_state.config or get_config()).fusion_bytes
 
-    compressed_keys = (set(_state.ps_session._compressors)
-                       if _state.ps_session is not None else set())
+    compressed_keys = set(sess._compressors) if sess is not None else set()
 
     def separate(i, l) -> bool:
         if not jnp.issubdtype(l.dtype, jnp.floating):
@@ -848,66 +845,15 @@ def _push_pull_tree(tree, name, average, compression, leaf_names,
             return str(leaf_names[i])
         return f"{name}{jax.tree_util.keystr(paths_leaves[i][0])}"
 
-    if fb > 0 and len(batch_idx) > 1:
-        outs = _fused_tree_push_pull(
-            name, leaves, metas, sep_idx, batch_idx, leaf_name,
-            average, compression, fb)
-        return jax.tree.unflatten(treedef, outs)
-
-    outs: list = [None] * len(leaves)
-    for i in sep_idx:
-        # Non-float leaves are separated precisely for exactness: a lossy
-        # intra-node cast (fp16) would corrupt them worse than the f32
-        # batch they were pulled out of.
-        comp = (compression
-                if jnp.issubdtype(metas[i][1], jnp.floating) else None)
-        outs[i] = jnp.asarray(
-            push_pull(leaves[i], name=leaf_name(i), average=average,
-                      compression=comp)).astype(metas[i][1])
-    if batch_idx:
-        span = _stage_span()
-        with span("PACK", name):
-            flat = (jnp.concatenate([leaves[i].ravel().astype(jnp.float32)
-                                     for i in batch_idx])
-                    if len(batch_idx) > 1
-                    else leaves[batch_idx[0]].ravel().astype(jnp.float32))
-        out = jnp.asarray(push_pull(flat, name=name, average=average,
-                                    compression=compression))
-        with span("SCATTER", name) as sp:
-            if sp is not None:
-                sp.args["key"] = declare(name)
-            o = 0
-            for i in batch_idx:
-                shp, dt, n = metas[i]
-                outs[i] = out[o:o + n].reshape(shp).astype(dt)
-                o += n
-    return jax.tree.unflatten(treedef, outs)
-
-
-def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
-                          leaf_name, average, compression, fb) -> list:
-    """Dispatch a tree through the fusion planner.
-
-    Builds dtype-homogeneous buckets over the fusable leaves, then sends
-    every dispatch unit (bucket, over-threshold solo leaf, forced-solo
-    exact/compressed leaf) in the scheduler's own order, (priority
-    desc, declared key asc).  In PS mode the set rides
-    PSSession.push_pull_group, which puts each unit in the scheduler as
-    soon as it is off the device, so the units arrive in the order a
-    view of the whole set would have picked; in collective mode the
-    units are issued as concurrent async push_pulls and synchronized
-    together.
-    """
-    from .fusion import plan_buckets
-
-    sess = _state.ps_session
-    span = _stage_span()
+    rnd = _Round(name, metas, average, leaf_name)
+    span = rnd.span
 
     def plan_units(fb, only=None):
-        """The fusion plan under threshold `fb` and its dispatch units,
-        (unit_name, payload, priority, compression, scatter) where
-        scatter = [(leaf_idx, num_elems), ...] in pack order; with
-        `only`, just the units that carry one of those leaves."""
+        """The dispatch units of the fusion plan under threshold `fb`,
+        (unit_name, payload, priority, compression, members) where
+        members = [(leaf_idx, num_elems), ...] in pack order, and their
+        names; with `only`, just the units that carry one of those
+        leaves."""
         plan = plan_buckets(
             tuple((i, metas[i][2], str(metas[i][1]),
                    jnp.dtype(metas[i][1]).itemsize) for i in batch_idx), fb)
@@ -929,41 +875,72 @@ def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
                 if only is None or li in only:
                     units.append((leaf_name(li), leaves[li].ravel(), prio,
                                   compression, [(li, metas[li][2])]))
-        return plan, units
+        return units, {u[0] for u in units}
 
-    def dispatch_order(units) -> None:
-        """The scheduler's own order, (priority desc, declared key
-        asc), with names first declared in priority order as ever."""
-        units.sort(key=lambda u: -u[2])
-        units.sort(key=lambda u: (-u[2], declare(u[0])))
+    def replan(failed):
+        # A FUSION_BYTES switch withdrew some units mid-flight: re-plan
+        # the FULL fusable set under the live threshold (every worker
+        # re-plans identically — the switch is global and
+        # boundary-synchronized, so the new composition-derived bucket
+        # keys line up fleet-wide), then re-dispatch only the units
+        # carrying a withdrawn leaf.  Idempotent CMD_INIT declares the
+        # new bucket keys; withdrawn handles never advanced their round,
+        # so the replay stages the same round.
+        live_fb = sess.live_fusion_bytes()
+        return plan_units(fb if live_fb is None else live_fb, only=failed)
 
-    plan, units = plan_units(fb)
+    units, plan_names = plan_units(fb)
     with span("PACK", name):
         for i in sep_idx:
             # Forced-solo leaves (non-float exactness, registered wire
             # compressors) join the same priority-ordered dispatch, minus
             # any lossy intra-node cast for non-floats.  Raveled like
-            # every other unit: scatter() below slices elements, and a
-            # 0-d payload would not even be sliceable.
+            # every other unit: the scatter slices elements, and a 0-d
+            # payload would not even be sliceable.
             comp = (compression
                     if jnp.issubdtype(metas[i][1], jnp.floating) else None)
             units.append((leaf_name(i), leaves[i].ravel(), i, comp,
                           [(i, metas[i][2])]))
-    dispatch_order(units)
+    rnd.dispatch(units, plan_names)
+    return jax.tree.unflatten(treedef, rnd.collect(replan))
 
-    outs: list = [None] * len(leaves)
 
-    def scatter(members, vec) -> None:
-        off = 0
-        for li, n in members:
-            shp, dt, _ = metas[li]
-            outs[li] = jnp.asarray(vec[off:off + n]).reshape(shp).astype(dt)
-            off += n
+class _Round:
+    """How a set of arrays leaves this worker and comes back summed: one
+    crossing of the reduction plane by dispatch units `(name, payload,
+    priority, compression, members)`, members = [(leaf_idx, num_elems),
+    ...] into `metas`.  `dispatch` sends them, `collect` brings them back
+    as the leaves `outs`; `push_pull_tree` runs one after the other,
+    `push_pull_async` returns between them.  What carries a unit is the
+    PS session's `push_pull_group`; with no session, the eager
+    all-reduce or, for a lone worker, nothing.
+    """
 
-    if sess is not None:
+    def __init__(self, name, metas, average, leaf_name=None):
+        self.name, self.metas, self.leaf_name = name, metas, leaf_name
+        self.average, self.sess = average, _state.ps_session
+        self.hier = _state.hierarchy if self.sess is not None else None
+        # The session's `RoundSpans.span`; in collective mode, no span.
+        self.span = (self.sess.spans.span if self.sess is not None
+                     else stage_spans.off)
+        self.outs: list = [None] * len(metas)
+        self.rkey = self.handles = None
+
+    def dispatch(self, units: list, plan_names=frozenset()) -> None:
+        """Order, slice-reduce, compress, declare and send `units`.
+        `plan_names`: the units whose KEY IDENTITY derives from the
+        fusion plan (buckets and plan solos — a different FUSION_BYTES
+        re-composes them).  Registered with the session so a mid-flight
+        FUSION_BYTES switch withdraws their pushes with KnobReplan
+        instead of merging old-layout bytes into orphaned keys; other
+        units keep layout-independent keys and replay in place."""
         from ..ops.compression import Compression
-        hier = _state.hierarchy
-        rkey = None
+        sess, hier = self.sess, self.hier
+        # The scheduler's own order, (priority desc, declared key asc),
+        # with names first declared in priority order as ever.
+        units.sort(key=lambda u: -u[2])
+        units.sort(key=lambda u: (-u[2], declare(u[0])))
+        self.units = units
         if hier is not None:
             # Hierarchical reduction: slice-reduce every unit's RAW f32
             # payload in one in-graph psum BEFORE any wire compression
@@ -974,69 +951,104 @@ def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
             # non-float units: the PS wire is f32 for every payload
             # (PSSession._stage casts), so flat PS mode already sums
             # them in f32 — the slice psum is the same precision class.
-            rkey = tuple(declare(nm) for nm, _, _, _, _ in units)
+            self.rkey = tuple(declare(u[0]) for u in units)
             reduced = hier.reduce_payloads(
-                rkey, [np.asarray(p, np.float32).ravel()
-                       for _, p, _, _, _ in units])
-            units = [(nm, jnp.asarray(red), prio, comp, members)
-                     for (nm, _p, prio, comp, members), red
-                     in zip(units, reduced)]
+                self.rkey, [np.asarray(u[1], np.float32).ravel()
+                            for u in units])
+            units[:] = [(nm, jnp.asarray(red), prio, comp, members)
+                        for (nm, _p, prio, comp, members), red
+                        in zip(units, reduced)]
             if not hier.is_leader:
                 # Followers never touch the data plane: the leader's
                 # broadcast delivers the round's averaged unit outputs.
-                skipped = sum(int(np.size(r)) * 4 for r in reduced)
-                for (nm, p, _, _, _) in units:
+                self.skipped = sum(int(np.size(r)) * 4 for r in reduced)
+                for nm, p, _, _, _ in units:
                     _debug_sample("push", nm, p)
-                outs_vecs = hier.await_outs(rkey, skipped_bytes=skipped)
-                for (nm, _, _, _, members), vec in zip(units, outs_vecs):
-                    scatter(members, jnp.asarray(vec))
-                    _debug_sample("pull", nm, vec)
-                return outs
+                return
+        items, self.ctxs, fusion_dks = [], [], []
+        with self.span("PACK", self.name):
+            for nm, payload, prio, comp, members in units:
+                _debug_sample("push", nm, payload)
+                comp = comp or Compression.none
+                wire, ctx = comp.compress(payload)
+                dk = declare(nm)
+                if nm in plan_names:
+                    fusion_dks.append(dk)
+                if sess is not None and len(members) > 1 \
+                        and get_core().trace_on:
+                    # Fused bucket inside a trace window: record its
+                    # member-leaf names so trace spans carry the real
+                    # parameters in args.members (the analyzer's
+                    # slow-bucket attribution).  Gated like every
+                    # other trace feed — an untraced run must not
+                    # build name lists per step.
+                    sess.set_trace_members(
+                        dk, [self.leaf_name(li) for li, _ in members])
+                items.append((dk, wire, prio))
+                self.ctxs.append((dk, comp, ctx))
+        try:
+            if sess is not None:
+                if fusion_dks:
+                    sess.note_fusion_keys(fusion_dks)
+                self.handles = sess.push_pull_group(items)
+            elif size() > 1 or (_state.config
+                                or get_config()).force_distributed:
+                # BYTEPS_FORCE_DISTRIBUTED exercises the real
+                # communication path even at world size 1 — the
+                # reference's test hook (reference: global.cc:149-152,
+                # tests/meta_test.py:27-33).
+                self.handles = [_eager_sum_across_processes(wire)
+                                for _, wire, _ in items]
+            else:
+                self.handles = [wire for _, wire, _ in items]  # one worker
+        except Exception as e:
+            if hier is not None:    # as in `collect`
+                hier.publish_failure(self.rkey, e)
+            raise
+
+    def done(self) -> bool:
+        """Whether `collect` would find every unit arrived."""
+        if self.handles is None:    # a follower: has the leader published?
+            return self.hier.group.poll(self.hier.worker_id, self.rkey)
+        return all(h.done() if self.sess is not None else h.is_ready()
+                   for h in self.handles)
+
+    def _scatter(self, members, vec) -> None:
+        off = 0
+        for li, n in members:
+            shp, dt, _ = self.metas[li]
+            self.outs[li] = jnp.asarray(
+                vec[off:off + n]).reshape(shp).astype(dt)
+            off += n
+
+    def collect(self, replan=None) -> list:
+        """Wait for the units, put them on the device, decompress,
+        average and scatter them into the leaves; a slice's leader then
+        hands them to its followers.  `replan(failed_leaves)` gives the
+        units and plan names to dispatch again for withdrawn leaves."""
         from ..server.client import KnobReplan
-        # Units whose KEY IDENTITY derives from the fusion plan (buckets
-        # and plan solos — a different FUSION_BYTES re-composes them).
-        # Registered with the session so a mid-flight FUSION_BYTES
-        # switch withdraws their pushes with KnobReplan instead of
-        # merging old-layout bytes into orphaned keys; forced-solo units
-        # keep layout-independent keys and replay in place.
-        plan_unit_names = ({f"{name}.{b.tag}" for b in plan.buckets}
-                           | {leaf_name(li) for li, _ in plan.solo})
+        sess, hier, span = self.sess, self.hier, self.span
+        if self.handles is None:
+            vecs = hier.await_outs(self.rkey, skipped_bytes=self.skipped)
+            for (nm, _, _, _, members), vec in zip(self.units, vecs):
+                self._scatter(members, jnp.asarray(vec))
+                _debug_sample("pull", nm, vec)
+            # No telemetry: a follower sent nothing, and recording its
+            # bytes would make the push/pull counters deny the very
+            # traffic reduction the saved-bytes counter reports.
+            return self.outs
         pulled_vecs = []
-        unit_bytes = sum(int(p.size * p.dtype.itemsize)
-                         for _, p, _, _, _ in units)
         for attempt in range(3):
-            items, ctxs, fusion_dks = [], [], []
-            with span("PACK", name):
-                for nm, payload, prio, comp, members in units:
-                    _debug_sample("push", nm, payload)
-                    comp = comp or Compression.none
-                    wire, ctx = comp.compress(payload)
-                    dk = declare(nm)
-                    if nm in plan_unit_names:
-                        fusion_dks.append(dk)
-                    if len(members) > 1 and get_core().trace_on:
-                        # Fused bucket inside a trace window: record its
-                        # member-leaf names so trace spans carry the real
-                        # parameters in args.members (the analyzer's
-                        # slow-bucket attribution).  Gated like every
-                        # other trace feed — an untraced run must not
-                        # build name lists per step.
-                        sess.set_trace_members(
-                            dk, [leaf_name(li) for li, _ in members])
-                    items.append((dk, wire, prio))
-                    ctxs.append((comp, ctx))
-            if fusion_dks:
-                sess.note_fusion_keys(fusion_dks)
             failed: set = set()
             replan_err = None
             try:
-                handles = sess.push_pull_group(items)
-                for (nm, _, _, _, members), h, (comp, ctx) in zip(
-                        units, handles, ctxs):
+                for (nm, _, _, _, members), h, (dk, comp, ctx) in zip(
+                        self.units, self.handles, self.ctxs):
                     try:
-                        out = _pulled_to_device(sess.spans, h, h.key, nm)
+                        out = (_pulled_to_device(sess.spans, h, dk, nm)
+                               if sess is not None else h)
                     except KnobReplan as kr:
-                        if hier is not None:
+                        if hier is not None or replan is None:
                             # The slice broadcast can't re-plan under a
                             # follower's feet — surface it like any
                             # other wire failure.
@@ -1044,11 +1056,11 @@ def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
                         failed.update(li for li, _ in members)
                         replan_err = kr
                         continue
-                    with span("SCATTER", nm, key=h.key):
+                    with span("SCATTER", nm, key=dk):
                         out = comp.decompress(out, ctx)
-                        if average:
+                        if self.average:
                             out = out / size()
-                        scatter(members, out)
+                        self._scatter(members, out)
                         _debug_sample("pull", nm, out)
                         if hier is not None:
                             pulled_vecs.append(
@@ -1058,54 +1070,29 @@ def _fused_tree_push_pull(name, leaves, metas, sep_idx, batch_idx,
                     # Slice followers are blocked on the broadcast — a
                     # leader-side wire failure must fail the whole
                     # slice's round loudly, not strand it.
-                    hier.publish_failure(rkey, e)
+                    hier.publish_failure(self.rkey, e)
                 raise
             if not failed:
                 break
             if attempt == 2:
                 raise replan_err
-            # A FUSION_BYTES switch withdrew some units mid-flight:
-            # re-plan the FULL fusable set under the live threshold
-            # (every worker re-plans identically — the switch is global
-            # and boundary-synchronized, so the new composition-derived
-            # bucket keys line up fleet-wide), then re-dispatch only the
-            # units carrying a withdrawn leaf.  Idempotent CMD_INIT
-            # declares the new bucket keys; withdrawn handles never
-            # advanced their round, so the replay stages the same round.
-            live_fb = sess.live_fusion_bytes()
-            if live_fb is not None:
-                fb = live_fb
-            plan, units = plan_units(fb, only=failed)
-            dispatch_order(units)
-            plan_unit_names = {u[0] for u in units}
-        with span("FREE", name):
+            self.dispatch(*replan(failed))
+        with span("FREE", self.name):
             # The round's host memory is let go here, under a span, and
             # not at the return: the copies off the device (cached by
             # the units' arrays) and the handles' result buffers, twice
             # the tree's bytes.  Where the allocator hands them back to
             # the kernel (a worker without common/host_memory.py's
             # policy) that takes as long as some stages do.
-            for owner in (units, items, ctxs, handles):
+            for owner in (self.units, self.ctxs, self.handles):
                 owner.clear()
-            payload = wire = ctx = h = out = None  # the last unit's
+            comp = ctx = h = out = None  # the last unit's
         if hier is not None:
-            hier.publish_outs(rkey, pulled_vecs)
-        cfg = _state.config or get_config()
-        if cfg.telemetry_on:
-            telemetry.record_pushpull(unit_bytes)
-    else:
-        handles = [push_pull_async(payload, name=nm, average=average,
-                                   priority=prio, compression=comp)
-                   for nm, payload, prio, comp, _ in units]
-        for (nm, _, _, _, members), h in zip(units, handles):
-            scatter(members, jnp.asarray(synchronize(h)))
-    return outs
-
-
-def _stage_span():
-    """The PS session's `RoundSpans.span`; in collective mode, no span."""
-    sess = _state.ps_session
-    return sess.spans.span if sess is not None else stage_spans.off
+            hier.publish_outs(self.rkey, pulled_vecs)
+        if (_state.config or get_config()).telemetry_on:
+            telemetry.record_pushpull(sum(
+                n * jnp.dtype(dt).itemsize for _, dt, n in self.metas))
+        return self.outs
 
 
 def _pulled_to_device(spans, handle, key: int, name: str) -> jax.Array:
@@ -1143,98 +1130,20 @@ def _debug_sample(stage: str, name: str, tensor) -> None:
 def push_pull_async(tensor: jax.Array, name: Optional[str] = None,
                     average: bool = True, priority: int = 0,
                     compression=None) -> int:
+    """A round of ONE unit whose second half is deferred: the tensor is
+    on its way when this returns, `synchronize` collects it."""
     _require_init()
-    from ..ops.compression import Compression
-    compression = compression or Compression.none
     tensor = jnp.asarray(tensor)
     if name is None:
         name = f"byteps_tpu.tensor_{get_core().num_declared()}"
-    _debug_sample("push", name, tensor)
-    dk = declare(name)
     core = get_core()
     handle = core.handle_allocate()
     t0 = core.trace_now_us()
-    hier = _state.hierarchy
-    if _state.ps_session is not None and hier is not None:
-        # Hierarchical reduction: slice-reduce the RAW tensor in-graph
-        # first; only the slice leader compresses and rides the wire,
-        # and the decompressed pull broadcasts back — so a follower's
-        # push_pull costs zero wire bytes.  The intra-slice reduce is
-        # f32 (in-graph psum); the wire codec then applies to the slice
-        # sum once instead of S per-chip gradients.
-        shape, dt = tensor.shape, tensor.dtype
-
-        def _leader_dispatch(reduced, comp=compression, prio=priority):
-            wire, cctx = comp.compress(jnp.asarray(reduced))
-            inner = _state.ps_session.push_pull_async(
-                dk, wire, priority=prio)
-
-            class _Decomp:
-                def done(self):
-                    return inner.done()
-
-                def wait(self, timeout=300.0):
-                    return np.asarray(
-                        comp.decompress(jnp.asarray(inner.wait(timeout)),
-                                        cctx), np.float32)
-
-            return _Decomp()
-
-        ph = hier.dispatch_round(
-            dk, np.asarray(tensor, np.float32).ravel(),
-            priority=priority, leader_dispatch=_leader_dispatch)
-
-        def _resolve(ph=ph, shape=shape, dt=dt, avg=average,
-                     spans=_state.ps_session.spans):
-            out = _pulled_to_device(spans, ph, dk, name)
-            with spans.span("SCATTER", name, key=dk):
-                out = out.reshape(shape)
-                return (out / size() if avg else out).astype(dt)
-
-        _resolve.ps_handle = ph
-        cfg = _state.config or get_config()
-        if cfg.telemetry_on and getattr(ph, "carried_wire", True):
-            # Followers sent nothing: recording their tensor bytes would
-            # make the push/pull counters deny the very traffic
-            # reduction the saved-bytes counter reports (the fused path
-            # skips follower recording the same way).
-            telemetry.record_pushpull(tensor.size * tensor.dtype.itemsize)
-        with _state.lock:
-            _state.handles[handle] = (_resolve, name, t0)
-        return handle
-    wire, ctx = compression.compress(tensor)
-    if _state.ps_session is not None:
-        # True async: partitions go through the session's priority-scheduled
-        # dispatcher; the handle resolves on the last partition's pull.
-        ps_handle = _state.ps_session.push_pull_async(
-            dk, wire, priority=priority)
-
-        def _resolve(ph=ps_handle, comp=compression, cctx=ctx, avg=average,
-                     spans=_state.ps_session.spans):
-            out = _pulled_to_device(spans, ph, dk, name)
-            with spans.span("SCATTER", name, key=dk):
-                out = comp.decompress(out, cctx)
-                return out / size() if avg else out
-
-        _resolve.ps_handle = ps_handle
-        out = _resolve
-    else:
-        cfg0 = _state.config or get_config()
-        if size() > 1 or cfg0.force_distributed:
-            # BYTEPS_FORCE_DISTRIBUTED exercises the real communication
-            # path even at world size 1 — the reference's test hook
-            # (reference: global.cc:149-152, tests/meta_test.py:27-33).
-            out = _eager_sum_across_processes(wire)
-        else:
-            out = wire  # sum over a single worker
-        out = compression.decompress(out, ctx)
-        if average:
-            out = out / size()
-    cfg = _state.config or get_config()
-    if cfg.telemetry_on:
-        telemetry.record_pushpull(tensor.size * tensor.dtype.itemsize)
+    n = int(tensor.size)
+    rnd = _Round(name, [(tensor.shape, tensor.dtype, n)], average)
+    rnd.dispatch([(name, tensor.ravel(), priority, compression, [(0, n)])])
     with _state.lock:
-        _state.handles[handle] = (out, name, t0)
+        _state.handles[handle] = (rnd, name, t0)
     return handle
 
 
@@ -1246,11 +1155,8 @@ def synchronize(handle: int) -> jax.Array:
         if handle not in _state.handles:
             raise ValueError(
                 f"unknown or already-synchronized handle {handle}")
-        out, name, t0 = _state.handles.pop(handle)
-    if callable(out):  # PS-mode deferred result
-        out = out()
-    out = jax.block_until_ready(out)
-    _debug_sample("pull", name, out)
+        rnd, name, t0 = _state.handles.pop(handle)
+    out = jax.block_until_ready(rnd.collect()[0])
     core = get_core()
     core.handle_mark_done(handle)
     core.trace_record(name, "PUSH_PULL", t0, core.trace_now_us() - t0)
@@ -1259,8 +1165,8 @@ def synchronize(handle: int) -> jax.Array:
 
 
 def poll(handle: int) -> bool:
-    """True if the async op has completed.  JAX's async dispatch means the
-    value exists as soon as dispatch returns; completion == buffer ready.
+    """True if the async op has completed: every partition pulled in PS
+    mode, the buffer ready otherwise.
     Raises ValueError for a handle that was never allocated or was already
     synchronized (matching the reference's check in torch/ops.cc poll)."""
     with _state.lock:
@@ -1271,15 +1177,7 @@ def poll(handle: int) -> bool:
             raise ValueError(
                 f"unknown or already-synchronized handle {handle}")
         return status == 1
-    out = entry[0]
-    if callable(out):  # PS-mode: completed when the last partition pulled
-        ph = getattr(out, "ps_handle", None)
-        return ph.done() if ph is not None else True
-    try:
-        # Committed when the underlying buffer is ready.
-        return out.is_ready() if hasattr(out, "is_ready") else True
-    except Exception:
-        return True
+    return entry[0].done()
 
 
 # ---------------------------------------------------------------------------

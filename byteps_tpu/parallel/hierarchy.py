@@ -329,8 +329,6 @@ class _LeaderHandle:
     """Leader-side round handle: wait the wire handle, broadcast the
     pulled value to the slice, return it."""
 
-    carried_wire = True     # this worker's round produced wire traffic
-
     def __init__(self, reducer: "HierarchicalReducer", key, inner):
         self._r = reducer
         self._key = key
@@ -355,8 +353,6 @@ class _LeaderHandle:
 class _FollowerHandle:
     """Follower-side round handle: the pulled value arrives via the
     leader's broadcast — zero wire traffic on this worker."""
-
-    carried_wire = False
 
     def __init__(self, reducer: "HierarchicalReducer", key):
         self._r = reducer
@@ -386,9 +382,10 @@ class HierarchicalReducer:
     """One worker's hierarchical push_pull plane.
 
     ``dispatch_round`` is the trainer face (one flat vector per round);
-    ``reduce_payloads``/``publish_outs``/``await_outs`` are the
-    fused-tree face api.py rides (the leader keeps the existing
-    fusion-planner + ``push_pull_group`` dispatch verbatim).
+    ``reduce_payloads``/``publish_outs``/``await_outs`` are the face
+    every round of api.py rides, ``push_pull``'s one unit and a tree's
+    many alike (the leader keeps the fusion-planner +
+    ``push_pull_group`` dispatch verbatim).
     """
 
     def __init__(self, session, worker_id: int, slice_size: int,
@@ -427,7 +424,6 @@ class HierarchicalReducer:
 
     # -- trainer face: one flat vector per round ----------------------------
     def dispatch_round(self, key, flat: np.ndarray, seed: bool = False,
-                       priority: int = 0,
                        leader_dispatch: Optional[Callable] = None):
         """One hierarchical round: slice-reduce ``flat`` in-graph, the
         leader dispatches the reduced vector on the wire, everyone gets
@@ -450,7 +446,7 @@ class HierarchicalReducer:
             try:
                 if leader_dispatch is None:
                     inner = self.session.push_pull_async(
-                        key, reduced, priority=priority, seed=seed)
+                        key, reduced, seed=seed)
                 else:
                     inner = leader_dispatch(reduced)
             except Exception as e:
@@ -478,7 +474,7 @@ class HierarchicalReducer:
         shape: the pull IS the updated parameters there)."""
         return self.dispatch_round(key, flat, seed=seed).wait(timeout)
 
-    # -- fused-tree face (api._fused_tree_push_pull) ------------------------
+    # -- round face (api._Round) --------------------------------------------
     def reduce_payloads(self, key, payloads: List[np.ndarray]
                         ) -> List[np.ndarray]:
         """Slice-reduce every dispatch unit's raw f32 payload in ONE

@@ -146,7 +146,7 @@ def test_identical_calls_reuse_keys_with_nonfloat_leaf(bps_initialized):
     out = bps.push_pull_tree(tree, average=False)    # must reuse every key
     assert get_core().num_declared() == n1
     np.testing.assert_array_equal(np.asarray(out["count"]), [3])
-    # The disabled path reuses keys too.
+    # With no packing every leaf rides its own key, call after call.
     bps.push_pull_tree(tree, average=False, fusion_bytes=0)
     n2 = get_core().num_declared()
     bps.push_pull_tree(tree, average=False, fusion_bytes=0)
@@ -165,40 +165,174 @@ def test_leaf_names_are_tree_path_deterministic(bps_initialized):
     assert get_core().get_declared_key("pathkeys['flag']") >= 0
 
 
-def test_fusion_disabled_is_byte_identical_to_pre_fusion_wire(
-        bps_initialized, monkeypatch):
-    """BYTEPS_TPU_FUSION_BYTES=0 must produce byte-identical wire traffic
-    to the pre-fusion path: ONE f32 batch vector over the floating leaves
-    (in flattened order) plus one exact message per non-float leaf —
-    captured at the push_pull boundary, where the payload bytes ARE the
-    wire payload."""
-    bps = bps_initialized
+class _RecordingSession:
+    """What `common/api.py` asks of a PS session, recording what
+    `push_pull_group` is handed: (declared name, priority, the f32 bytes
+    staged), with `_stage`'s spans and a real handle each.  One worker:
+    the pull is the push."""
+
+    def __init__(self):
+        from byteps_tpu.common import stage_spans
+        self.spans = stage_spans.RoundSpans()
+        self._compressors = {}
+        self.sent, self.fusion_keys = [], []
+
+    def live_fusion_bytes(self):
+        return None
+
+    def note_fusion_keys(self, declared_keys):
+        self.fusion_keys.extend(declared_keys)
+
+    def set_trace_members(self, declared_key, names):
+        pass
+
+    def push_pull_group(self, items):
+        from byteps_tpu.core.native import get_core
+        from byteps_tpu.server.client import PSHandle
+        handles = []
+        for dk, tensor, priority in items:
+            label = get_core().declared_name(dk)
+            with self.spans.span("D2H", label, key=dk):
+                arr = np.asarray(tensor)
+            with self.spans.span("STAGE", label, key=dk):
+                payload = np.ascontiguousarray(arr, np.float32).ravel()
+                self.spans.count(units=1)
+            self.sent.append((label, priority, payload.tobytes()))
+            h = PSHandle(arr.shape, arr.dtype, 1, payload.copy(),
+                         spans=self.spans, key=dk, label=label)
+            h._part_done()
+            handles.append(h)
+        return handles
+
+
+@pytest.fixture
+def recording_session(bps_initialized, monkeypatch):
     from byteps_tpu.common import api
+    sess = _RecordingSession()
+    monkeypatch.setattr(api._state, "ps_session", sess)
+    return sess
 
-    sent = []
 
-    def capture(tensor, name=None, average=True, priority=0,
-                compression=None):
-        sent.append((name, np.asarray(tensor).tobytes()))
-        return tensor
-
-    monkeypatch.setattr(api, "push_pull", capture)
-    a = jnp.arange(300, dtype=jnp.float32)
+def test_fusion_bytes_zero_means_no_packing(bps_initialized,
+                                            recording_session):
+    """BYTEPS_TPU_FUSION_BYTES=0: the plan has no bucket, every leaf is
+    a unit of the same round under its own key, in the scheduler's
+    order, carrying its own values widened to f32 — and the tree that
+    comes back is the one `fusion_bytes=4096` gives, bit for bit."""
+    bps, sess = bps_initialized, recording_session
+    a = jnp.arange(300, dtype=jnp.float32).reshape(20, 15)
     b = jnp.full((7,), 1.5, jnp.bfloat16)
     n = jnp.array([11, 22], jnp.int32)
+    tree = {"a": a, "b": b, "n": n}
     before = bps.get_fusion_stats()
-    api.push_pull_tree({"a": a, "b": b, "n": n}, name="parity",
-                       average=False, fusion_bytes=0)
-    # Exactly the pre-fusion message set: the separated int leaf, then the
-    # single f32 batch of every floating leaf.
-    assert [nm for nm, _ in sent] == ["parity['n']", "parity"]
-    assert sent[0][1] == np.asarray([11, 22], np.int32).tobytes()
-    expect_batch = np.concatenate(
-        [np.asarray(a, np.float32).ravel(),
-         np.asarray(b, np.float32).ravel()]).tobytes()
-    assert sent[1][1] == expect_batch
-    # And the fusion layer stayed completely out of it.
-    assert bps.get_fusion_stats() == before
+    out0 = bps.push_pull_tree(tree, name="parity", average=False,
+                              fusion_bytes=0)
+    # (priority desc, declared key asc): the separated int leaf rides at
+    # its position like the solo leaves.
+    assert [(nm, prio) for nm, prio, _ in sess.sent] == [
+        ("parity['n']", 2), ("parity['b']", 1), ("parity['a']", 0)]
+    for (_, _, wire), leaf in zip(sess.sent, (n, b, a)):
+        assert wire == np.asarray(leaf, np.float32).tobytes()
+    after = bps.get_fusion_stats()
+    assert after["buckets_built"] == before["buckets_built"]
+    assert after["leaves_fused"] == before["leaves_fused"]
+    assert after["leaves_solo"] == before["leaves_solo"] + 2
+    # only the plan's units may be withdrawn by a FUSION_BYTES switch
+    from byteps_tpu.core.native import get_core
+    assert sorted(get_core().declared_name(k)
+                  for k in sess.fusion_keys) == ["parity['a']",
+                                                 "parity['b']"]
+    del sess.sent[:]
+    out1 = bps.push_pull_tree(tree, name="parity", average=False,
+                              fusion_bytes=4096)
+    assert any(".fb" in nm for nm, _, _ in sess.sent)
+    for k in tree:
+        assert out0[k].dtype == out1[k].dtype == tree[k].dtype
+        assert out0[k].shape == tree[k].shape
+        assert np.asarray(out0[k]).tobytes() == np.asarray(
+            out1[k]).tobytes() == np.asarray(tree[k]).tobytes()
+
+
+def _stage_rows(core, tmp_path, tag):
+    import json
+
+    from byteps_tpu.common import stage_spans
+    path = tmp_path / f"{tag}.json"
+    core.trace_dump(str(path), 0)
+    rows = [r for r in json.load(open(path))["traceEvents"]
+            if r.get("tid") in stage_spans.STAGES]
+    return sorted(rows, key=lambda r: r["ts"])
+
+
+@pytest.mark.parametrize("hierarchy", [False, True],
+                         ids=["flat", "hierarchy"])
+def test_push_pull_is_a_tree_of_one_unit(bps_initialized,
+                                         recording_session, monkeypatch,
+                                         tmp_path, hierarchy):
+    """`bps.push_pull(x)` and `bps.push_pull_tree([x])` are the same
+    round: one key, the same bytes, the same kinds of span in the same
+    order (the tree's under a ROUND, with its planner's PACKs before the
+    round's own), the same telemetry; under BYTEPS_TPU_HIERARCHY both
+    take the same hand-over (reduce_payloads, then the leader's
+    publish_outs)."""
+    from byteps_tpu.common import api, telemetry
+    from byteps_tpu.core.native import get_core
+    from byteps_tpu.parallel import hierarchy as H
+    bps, sess, core = bps_initialized, recording_session, get_core()
+    calls = []
+    if hierarchy:
+        H.reset_slice_groups()
+        red = H.HierarchicalReducer(sess, 0, 1, world=1)
+
+        def recording(face, real):
+            def call(key, *args, **kwargs):
+                calls.append((face, key))
+                return real(key, *args, **kwargs)
+            return call
+
+        for face in ("reduce_payloads", "publish_outs", "await_outs",
+                     "publish_failure", "dispatch_round"):
+            monkeypatch.setattr(red, face,
+                                recording(face, getattr(red, face)))
+        monkeypatch.setattr(api._state, "hierarchy", red)
+    x = jnp.arange(24, dtype=jnp.bfloat16).reshape(4, 6) / 2
+
+    def sent_bytes():
+        return telemetry.get_registry().snapshot().get(
+            "bps_pushpull_bytes_total", 0)
+
+    got = {}
+    core.trace_enable(True)
+    try:
+        for tag, call in (
+                ("one", lambda: bps.push_pull(x, name="one.x",
+                                              average=False)),
+                ("tree", lambda: bps.push_pull_tree(
+                    [x], name="one", leaf_names=["one.x"], average=False,
+                    fusion_bytes=0)[0])):
+            b0 = sent_bytes()
+            out = call()
+            assert out.dtype == x.dtype and out.shape == x.shape
+            assert np.asarray(out).tobytes() == np.asarray(x).tobytes()
+            rows = _stage_rows(core, tmp_path, tag)
+            kinds = [r["tid"] for r in rows if r["tid"] != "ROUND"]
+            got[tag] = (list(sess.sent), [k for i, k in enumerate(kinds)
+                                          if i == 0 or kinds[i - 1] != k],
+                        sent_bytes() - b0, list(calls),
+                        [r["tid"] for r in rows].count("ROUND"))
+            del sess.sent[:], calls[:]
+    finally:
+        core.trace_enable(False)
+    assert got["one"][:4] == got["tree"][:4]
+    sent, kinds, nbytes, faces, _ = got["one"]
+    assert sent == [("one.x", 0, np.asarray(x, np.float32).tobytes())]
+    assert kinds == ["PACK", "D2H", "STAGE", "WAIT", "H2D", "SCATTER",
+                     "FREE"]
+    assert nbytes == x.size * 2
+    assert (got["one"][4], got["tree"][4]) == (0, 1)
+    key = (core.get_declared_key("one.x"),)
+    assert faces == ([("reduce_payloads", key), ("publish_outs", key)]
+                     if hierarchy else [])
 
 
 # ---------------------------------------------------------------------------

@@ -551,6 +551,13 @@ def test_async_trainer_hierarchical_matches_flat(ps_server):
                 updated = {"w": np.asarray(tr.params["w"], np.float32)
                            + deltas[(w, r)]}
                 tr.step(updated)
+            # An async server answers a pull with whatever it has summed
+            # so far: in the flat run worker 0's last pull may leave the
+            # server before worker 1's last delta arrives.  Once every
+            # worker's last step has returned its delta is in the store,
+            # so a step that moves nothing pulls the whole sum.
+            barrier.wait()
+            tr.step(tr.params)
             finals[w] = np.asarray(tr.finalize()["w"], np.float32)
 
         ts = [threading.Thread(target=worker, args=(w,))
@@ -568,9 +575,9 @@ def test_async_trainer_hierarchical_matches_flat(ps_server):
     hier = run(True)
     want = sum(deltas[(w, r)] for w in range(world)
                for r in range(rounds))
-    np.testing.assert_array_equal(flat[0], want)
-    np.testing.assert_array_equal(hier[0], want)
-    np.testing.assert_array_equal(hier[1], want)
+    for w in range(world):
+        np.testing.assert_array_equal(flat[w], want)
+        np.testing.assert_array_equal(hier[w], want)
 
 
 # ---------------------------------------------------------------------------

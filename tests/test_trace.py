@@ -604,9 +604,10 @@ _ROUND_EVENTS: dict = {}    # one recorded job per branch, for all its tests
 
 @pytest.fixture(params=[None, "0"], ids=["fused", "unfused"])
 def round_events(request, ps_server, tmp_path):  # noqa: F811
-    """The merged comm.json of two traced `push_pull_tree` rounds through
-    the fused (default BYTEPS_TPU_FUSION_BYTES) or the unfused (=0)
-    branch, and the job's output."""
+    """The merged comm.json of two traced `push_pull_tree` rounds with
+    the default BYTEPS_TPU_FUSION_BYTES (fused: the small leaves in one
+    bucket) or with 0 (unfused: no packing, a unit a leaf), and the
+    job's output."""
     if request.param not in _ROUND_EVENTS:
         port = ps_server(num_workers=1)
         out = _run_round_job(port, tmp_path, request.param)
@@ -638,7 +639,7 @@ def test_round_spans_one_round_per_call(round_events):
     assert "COUNT_OUTSIDE_WINDOW 0" in out
 
 
-def test_round_spans_children_inside_and_disjoint(round_events):
+def test_round_spans_children_inside_and_disjoint(round_events, request):
     events, _ = round_events
     from byteps_tpu.common import stage_spans
     staged = [e for e in events if e["tid"] in stage_spans.STAGES[1:]]
@@ -648,12 +649,18 @@ def test_round_spans_children_inside_and_disjoint(round_events):
         r["args"]["round"] for r in rounds}
     for rnd in rounds:
         kids = sorted(_children(events, rnd), key=lambda e: e["ts"])
-        # FREE is the fused branch's: the unfused one holds no lists of
-        # units whose buffers it could let go of under a span
-        assert {e["tid"] for e in kids} | {"FREE"} == set(
-            stage_spans.STAGES[1:])
-        assert ("FREE" in {e["tid"] for e in kids}) == any(
-            ".fb" in e["name"] for e in kids)
+        # One round, whatever the threshold: the same kinds of span in
+        # the same order, a unit staged at a time and then a unit
+        # collected at a time; only the number of units differs (two
+        # with the default, a bucket and the large leaf; four leaves
+        # with no packing).
+        units = rnd["args"]["units"]
+        no_packing = request.node.callspec.params["round_events"] == "0"
+        assert units == (4 if no_packing else 2)
+        kinds = [e["tid"] for e in kids if e["tid"] != "PACK"]
+        assert kinds == (["D2H", "STAGE", "STAGE"] * units
+                         + ["WAIT", "H2D", "SCATTER"] * units + ["FREE"])
+        assert [e["tid"] for e in kids][:3] == ["PACK"] * 3
         assert all(rnd["ts"] <= e["ts"] and _end(e) <= _end(rnd)
                    for e in kids)
         assert all(_end(a) <= b["ts"] for a, b in zip(kids, kids[1:]))
