@@ -406,8 +406,10 @@ def _gauge(name: str, help: str, cast=int) -> tuple:
 #: family -> keyword of `record_static` -> the gauge it sets.  A family is
 #: what one call site knows when its Python body is traced into a jitted
 #: step: the exchange (`ops/collectives.py` `bucketed_tree_all_reduce`),
-#: the state-space scans (`models/granite_hybrid.py`, through
-#: `ops/ssd.py`), a resident flash-attention call's schedule
+#: the state-space scans (`models/granite_hybrid.py` and
+#: `models/nemotron_h.py`, through `ops/ssd.py`), the plan of a model
+#: whose layers are one part each (`models/nemotron_h.py`), a resident
+#: flash-attention call's schedule
 #: (`flash_attention.tile_schedule`) and a streaming one's
 #: (`flash_attention.stream_schedule`; one set of gauges a `window`
 #: label, "none" for a call without one, so a step with both kinds of
@@ -443,6 +445,21 @@ _STATIC = {
             "bps_ssd_state_bytes",
             "bytes of chunk states ONE such layer keeps from its forward "
             "pass for its backward pass"),
+        "groups": _gauge(
+            "bps_ssd_scan_groups",
+            "groups of heads that share one B and C in that scan: a "
+            "chunk's C B^T is computed once a group"),
+    },
+    "layer_plan": {
+        "stacks": _gauge(
+            "bps_layer_plan_stacks",
+            "stacks of leaves the last traced step of a model with "
+            "one-part layers walks (models/nemotron_h.py): one a KIND of "
+            "layer, whatever the order of the layers"),
+        "layers": _gauge(
+            "bps_layer_plan_layers",
+            "layers of this kind in that step (label kind: mamba, moe, "
+            "attention)"),
     },
     "flash_tiles": {
         "tiles_computed": _gauge(
@@ -488,6 +505,10 @@ _STATIC = {
         "tile_dweights_k": _gauge(
             "bps_grouped_tile_dweights_k",
             "rows of a group's weight gradient one program owns"),
+        "tile_dweights_n": _gauge(
+            "bps_grouped_tile_dweights_n",
+            "columns of it that program owns: the whole width wherever "
+            "the rows can be cut"),
         "row_tiles_walked": _gauge(
             "bps_grouped_row_tiles_walked",
             "grid steps over the rows at the even routing: a tile a "

@@ -12,8 +12,10 @@ decoders the benchmark runs as one chip's share of a deployment, `afmoe`
 (layers of more than one kind, sparse experts beside a shared one),
 `mellum` (sparse experts in every layer, windowed and YaRN full
 attention), `keye` (attention over the keys a learned indexer selects,
-rotary positions in three streams) and `granite_hybrid` (state-space
-scans beside attention).  Their attention calls come from one table,
+rotary positions in three streams), `granite_hybrid` (state-space
+scans beside attention) and `nemotron_h` (layers that are one part each:
+a Mamba-2 mixer in 8 groups, squared-ReLU experts, grouped attention;
+stacked by kind).  Their attention calls come from one table,
 `afmoe._ATTENTION`: `sliding_attention`, `full_attention`,
 `selected_attention`.
 """

@@ -119,7 +119,13 @@ def _stack_plan(cfg: GraniteHybridConfig):
     of one kind.  The parameter tree holds one group of leaves a run,
     `params["layers"][i]`, stacked `[periods, layers of the run, ...]`:
     the stack is scanned a period at a time and each run scans its own
-    leaves, so no leaf is ever cut or joined, in either pass."""
+    leaves, so no leaf is ever cut or joined, in either pass.  That
+    suits a list that a short period tiles (the published 4 x (5 mamba,
+    attention, 4 mamba)).  A list that nothing tiles would be one period
+    with a run, a group of leaves and a compiled body for every change of
+    kind: `models/nemotron_h.py`, whose layers are one part each in an
+    order no period tiles, stacks its leaves by KIND instead
+    (`nemotron_h.layer_plan`)."""
     kinds = cfg.layer_types
     n = len(kinds)
     p = next(p for p in range(1, n + 1)
@@ -218,33 +224,49 @@ def _gate_norm(y, z, scale, cfg):
     return _norm(y * jax.nn.silu(z), scale, cfg)
 
 
-def _mamba(x, lp, cfg: GraniteHybridConfig):
+def _gate_norm_grouped(y, z, scale, cfg, groups: int):
+    """The gated norm over each of `groups` stretches of the inner width
+    apart, under the one learned scale (`models/nemotron_h.py`)."""
+    gated = y * jax.nn.silu(z)
+    split = (*gated.shape[:-1], groups, gated.shape[-1] // groups)
+    return _norm(gated.reshape(split), scale.reshape(split[-2:]),
+                 cfg).reshape(gated.shape)
+
+
+def _mamba(x, lp, cfg, scope: str = "granite.mamba", norm_groups: int = 1):
     """The Mamba-2 mixer, its input norm included.  x [B, S, D] ->
-    [B, S, D]."""
+    [B, S, D].  `cfg` is this model's or another's with the same fields
+    (`models/nemotron_h.py`, which names its own `scope` and norms the
+    gated result by the scan's groups)."""
     dt = cfg.dtype
     B, S, _ = x.shape
     H, P = cfg.mamba_n_heads, cfg.mamba_d_head
     G, N, I = cfg.mamba_n_groups, cfg.mamba_d_state, cfg.d_inner
-    with jax.named_scope("granite.mamba.in_proj"):
+    with jax.named_scope(scope + ".in_proj"):
         u = _norm(x, lp["input_ln"], cfg)
         zxbcdt = jnp.einsum("bsd,de->bse", u, lp["in_proj_w"].astype(dt))
         z, xbc, raw = jnp.split(zxbcdt, [I, I + cfg.conv_dim], axis=-1)
-    with jax.named_scope("granite.mamba.conv"):
+    with jax.named_scope(scope + ".conv"):
         xbc = _conv(xbc, lp)
         x, bm, cm = jnp.split(xbc, [I, I + G * N], axis=-1)
-    with jax.named_scope("granite.mamba.scan"):
+    with jax.named_scope(scope + ".scan"):
         y = ssd.ssd_scan(
             x.reshape(B, S, H, P), _step_size(raw, lp["dt_bias"]),
             -jnp.exp(lp["A_log"].astype(jnp.float32)),
             bm.reshape(B, S, G, N), cm.reshape(B, S, G, N), lp["D"],
             chunk=min(cfg.mamba_chunk_size, S))
-    with jax.named_scope("granite.mamba.gate_norm"):
-        y = _gate_norm(y.reshape(B, S, I), z, lp["gate_norm"], cfg)
-    with jax.named_scope("granite.mamba.out_proj"):
+    with jax.named_scope(scope + ".gate_norm"):
+        y = y.reshape(B, S, I)
+        if norm_groups == 1:    # this model's, under the name and the
+            # arguments its broken variants patch
+            y = _gate_norm(y, z, lp["gate_norm"], cfg)
+        else:
+            y = _gate_norm_grouped(y, z, lp["gate_norm"], cfg, norm_groups)
+    with jax.named_scope(scope + ".out_proj"):
         return jnp.einsum("bse,ed->bsd", y, lp["out_proj_w"].astype(dt))
 
 
-def _attend(q, k, v, cfg: GraniteHybridConfig):
+def _attend(q, k, v, cfg):
     """q, k, v [B, H, S, Dh] -> ctx, causal, scores times
     `attention_multiplier`.  The shared flash adapter divides by sqrt(Dh),
     so the model's multiplier goes on q (the published 1/64 at head size
@@ -253,30 +275,37 @@ def _attend(q, k, v, cfg: GraniteHybridConfig):
     return flash_attention_fn(q * jnp.asarray(scale, q.dtype), k, v, True)
 
 
-def _attention(x, lp, cfg: GraniteHybridConfig):
-    """The attention mixer, its input norm included.  x [B, S, D] ->
-    [B, S, D]."""
-    dt = cfg.dtype
+def _qkv(x, lp, cfg):
+    """What a layer's attention call is given: x [B, S, D] normed and
+    projected, queries [B, H, S, Dh], keys and values [B, Hkv, S, Dh]."""
     B, S, _ = x.shape
     H, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    with jax.named_scope("granite.attn"):
-        with jax.named_scope(".qkv"):
-            u = _norm(x, lp["input_ln"], cfg)
-            qkv = jnp.einsum("bsd,de->bse", u, lp["qkv_w"].astype(dt))
-            q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
+    u = _norm(x, lp["input_ln"], cfg)
+    qkv = jnp.einsum("bsd,de->bse", u, lp["qkv_w"].astype(cfg.dtype))
+    q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
 
-            def heads(t):
-                return t.reshape(B, S, -1, Dh).transpose(0, 2, 1, 3)
-            q, k, v = heads(q), heads(k), heads(v)
+    def heads(t):
+        return t.reshape(B, S, -1, Dh).transpose(0, 2, 1, 3)
+    return heads(q), heads(k), heads(v)
+
+
+def _attention(x, lp, cfg, scope: str = "granite.attn"):
+    """The attention mixer, its input norm included.  x [B, S, D] ->
+    [B, S, D].  `cfg` and `scope` as `_mamba`'s."""
+    B, S, _ = x.shape
+    H, Hkv = cfg.num_heads, cfg.num_kv_heads
+    with jax.named_scope(scope):
+        with jax.named_scope(".qkv"):
+            q, k, v = _qkv(x, lp, cfg)
             if Hkv != H:
                 k = jnp.repeat(k, H // Hkv, axis=1)
                 v = jnp.repeat(v, H // Hkv, axis=1)
         # the kernels and the transpose after them stay the half's own
         ctx = _attend(q, k, v, cfg)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, H * Dh)
+        ctx = ctx.transpose(0, 2, 1, 3).reshape(B, S, -1)
         with jax.named_scope(".out"):
             return jnp.einsum("bse,ed->bsd", ctx,
-                              lp["attn_out_w"].astype(dt))
+                              lp["attn_out_w"].astype(cfg.dtype))
 
 
 def _mlp(x, lp, cfg: GraniteHybridConfig):
@@ -304,10 +333,11 @@ def _embed(params, tokens, cfg: GraniteHybridConfig):
         return x * jnp.asarray(cfg.embedding_multiplier, cfg.dtype)
 
 
-def _record_scan(cfg: GraniteHybridConfig, batch: int, seq_len: int) -> None:
+def _record_scan(cfg, batch: int, seq_len: int) -> None:
     chunk = min(cfg.mamba_chunk_size, seq_len)
     telemetry.record_static(
         "ssd_scan", layers=cfg.count(MAMBA), chunk=chunk,
+        groups=cfg.mamba_n_groups,
         state_bytes=ssd.state_bytes(batch, cfg.mamba_n_heads, seq_len,
                                     cfg.mamba_d_head, cfg.mamba_d_state,
                                     chunk))

@@ -43,7 +43,11 @@ group's rows in the weights' gradient) and rounded once, to the dtype
 
 Tiles come from the shapes (`grouped_tiles`); a shape they cannot serve
 is `lax.ragged_dot`'s as before (`grouped_matmul` says which ran,
-`bps_grouped_*`).  Off the TPU the kernels run in the Pallas interpreter.
+`bps_grouped_*`).  A width that is a multiple of 64 and not of 128 (an
+expert of 1856 = 14.5 x 128) is served too: a block may always hold a
+dimension WHOLE, so such a width is never cut, and where a whole width
+of K does not leave the weights' gradient room, that kernel cuts N
+instead.  Off the TPU the kernels run in the Pallas interpreter.
 """
 
 from __future__ import annotations
@@ -62,6 +66,9 @@ from . import flash_attention
 
 _F32 = jnp.float32
 LANE = 128
+# The least a width is a multiple of: half a lane tile, whole rows of
+# packed bfloat16 (16) where the width is a block's second-to-last.
+HALF_LANE = 64
 
 # What a kernel's blocks may take of the chip's VMEM (128 MiB on a v5e, of
 # which a kernel gets 16 unless it asks): the tile rule fits its blocks in
@@ -75,7 +82,8 @@ class Tiles(NamedTuple):
     rows: int       # rows of a tile, all three kernels
     fwd_k: int      # forward: K a step (N whole)
     drows_n: int    # rows' gradient: N a step (K whole)
-    dweights_k: int  # weights' gradient: rows of K a program owns (N whole)
+    dweights_k: int  # weights' gradient: rows of K a program owns ...
+    dweights_n: int  # ... and columns of N (whole wherever K can be cut)
 
 
 def _rows_vmem(tm, c, tc, to, itemsize):
@@ -94,15 +102,19 @@ def _dweights_vmem(tm, tk, tn, itemsize):
 
 
 def _divisors(width):
-    """`width`'s divisors that are multiples of 128, largest first."""
-    return [d for d in range(width, 0, -LANE) if width % d == 0]
+    """What a block may take of `width`, largest first: the whole of it,
+    then its divisors that are multiples of 128 (none where the width
+    itself is none: a block's last dimension is whole lane tiles or the
+    array's own)."""
+    return [width] + [d for d in range(width - width % LANE, 0, -LANE)
+                      if d != width and width % d == 0]
 
 
 def grouped_tiles(rows: int, k: int, n: int, groups: int,
                   dtype) -> Optional[Tiles]:
     """The tile rule: `Tiles` for `[rows, k] x [groups, k, n]`, or None
     where the kernels cannot tile the shape (a width that is no multiple
-    of 128, rows that no tile of 128 divides, a result's width whose
+    of 64, rows that no tile of 128 divides, a result's width whose
     blocks alone pass `VMEM_BUDGET`) and `lax.ragged_dot` runs.
 
     The result's width is always whole, so a tile of rows is read once
@@ -113,8 +125,12 @@ def grouped_tiles(rows: int, k: int, n: int, groups: int,
     once a tile of rows, since the block's index does not change between
     a group's tiles, and a step's product goes straight to the result
     with no float32 sum read and written beside it.  Else the contracted
-    width goes in its largest divisor that fits.  `_tile_rows` gives the
-    rows of a tile.
+    width goes in its largest divisor that fits.  A width of whole HALF
+    lane tiles (1856 = 29 x 64) has no such divisor and stays whole in
+    every kernel; the weights' gradient, whose float32 sum is [K, N],
+    then takes N in its largest divisor that fits (1856 x 2688: N in
+    thirds of 896, the rows of K read three times).  `_tile_rows` gives
+    the rows of a tile.
 
     From the chip (TPU v5e, the products alone, 65,536 live rows of
     81,920 on 16 experts; docs/performance.md, "Grouped products"): at
@@ -125,7 +141,7 @@ def grouped_tiles(rows: int, k: int, n: int, groups: int,
     rows an expert 256 rows beat 512 in all three kinds, 976 against
     1,020 us forward (the compiler's 1,314)."""
     itemsize = jnp.dtype(dtype).itemsize
-    if k % LANE or n % LANE or groups < 1:
+    if k % HALF_LANE or n % HALF_LANE or groups < 1:
         return None
     tm = _tile_rows(rows)
     if tm is None:
@@ -137,10 +153,15 @@ def grouped_tiles(rows: int, k: int, n: int, groups: int,
 
     fwd_k = fit(k, lambda d: _rows_vmem(tm, k, d, n, itemsize))
     drows_n = fit(n, lambda d: _rows_vmem(tm, n, d, k, itemsize))
-    dweights_k = fit(k, lambda d: _dweights_vmem(tm, d, n, itemsize))
-    if None in (fwd_k, drows_n, dweights_k):
+    dweights = None
+    for tn in _divisors(n):       # N whole wherever some cut of K fits
+        tk = fit(k, lambda d: _dweights_vmem(tm, d, tn, itemsize))
+        if tk is not None:
+            dweights = (tk, tn)
+            break
+    if None in (fwd_k, drows_n, dweights):
         return None
-    return Tiles(tm, fwd_k, drows_n, dweights_k)
+    return Tiles(tm, fwd_k, drows_n, *dweights)
 
 
 def row_tiles(rows: int, groups: int, tile: int, live: int) -> dict:
@@ -347,7 +368,7 @@ def _rows_call(lhs, rhs, walk: Walk, *, tm, tc, transposed, interpret):
 
 
 def _dweights_kernel(offsets, group, tile_of, n, lhs, g, out, acc, *, tm):
-    s = pl.program_id(1)
+    s = pl.program_id(2)
     here = group[s]
     first = (s == 0) | (group[jnp.maximum(s - 1, 0)] != here)
     last = (s == n[0] - 1) | (group[jnp.minimum(s + 1, n[0] - 1)] != here)
@@ -371,13 +392,15 @@ def _dweights_kernel(offsets, group, tile_of, n, lhs, g, out, acc, *, tm):
 
 
 @functools.partial(jax.jit, inline=True, static_argnames=(
-    "tm", "tk", "interpret"))
-def _dweights_call(lhs, g, walk: Walk, *, tm, tk, interpret):
+    "tm", "tk", "tn", "interpret"))
+def _dweights_call(lhs, g, walk: Walk, *, tm, tk, tn=None, interpret):
     """`lhs` [rows, K], `g` [rows, N] -> [G, K, N]: each group's
-    `lhs^T g` over its own rows, a program owning `tk` rows of K, on a
-    `Walk`'s tables for the weights' gradient."""
+    `lhs^T g` over its own rows, a program owning `tk` rows of K and
+    `tn` columns of N (None: all), on a `Walk`'s tables for the weights'
+    gradient."""
     rows, k = lhs.shape
     n = g.shape[1]
+    tn = tn or n
     groups = walk.offsets.shape[0] - 1
     tables = (walk.offsets, walk.group_w, walk.tile_w, walk.steps_w)
     itemsize = lhs.dtype.itemsize
@@ -385,24 +408,27 @@ def _dweights_call(lhs, g, walk: Walk, *, tm, tk, interpret):
         functools.partial(_dweights_kernel, tm=tm),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(k // tk, tables[3][0]),
+            grid=(k // tk, n // tn, tables[3][0]),
             in_specs=[
-                pl.BlockSpec((tm, tk),
-                             lambda ki, s, off, grp, til, n: (til[s], ki)),
-                pl.BlockSpec((tm, n),
-                             lambda ki, s, off, grp, til, n: (til[s], 0))],
+                pl.BlockSpec(
+                    (tm, tk),
+                    lambda ki, ni, s, off, grp, til, n: (til[s], ki)),
+                pl.BlockSpec(
+                    (tm, tn),
+                    lambda ki, ni, s, off, grp, til, n: (til[s], ni))],
             out_specs=pl.BlockSpec(
-                (None, tk, n),
-                lambda ki, s, off, grp, til, n: (grp[s], ki, 0)),
-            scratch_shapes=[pltpu.VMEM((tk, n), _F32)]),
+                (None, tk, tn),
+                lambda ki, ni, s, off, grp, til, n: (grp[s], ki, ni)),
+            scratch_shapes=[pltpu.VMEM((tk, tn), _F32)]),
         out_shape=jax.ShapeDtypeStruct((groups, k, n), lhs.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary"),
-            vmem_limit_bytes=(_dweights_vmem(tm, tk, n, itemsize)
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=(_dweights_vmem(tm, tk, tn, itemsize)
                               + _VMEM_MARGIN)),
         cost_estimate=pl.CostEstimate(
             flops=2 * rows * k * n, transcendentals=0,
-            bytes_accessed=itemsize * (rows * (k + n * (k // tk))
+            bytes_accessed=itemsize * (rows * (k * (n // tn)
+                                               + n * (k // tk))
                                        + groups * k * n)),
         interpret=interpret, name="ragged-dot-none_dweights",
     )(*tables, lhs, g)
@@ -424,7 +450,7 @@ def _grouped_bwd(tiles, interpret, residuals, g):
     d_lhs = _rows_call(g, rhs, walk, tm=tiles.rows, tc=tiles.drows_n,
                        transposed=True, interpret=interpret)
     d_rhs = _dweights_call(lhs, g, walk, tm=tiles.rows, tk=tiles.dweights_k,
-                           interpret=interpret)
+                           tn=tiles.dweights_n, interpret=interpret)
     return d_lhs, d_rhs.astype(rhs.dtype), None
 
 
@@ -452,7 +478,7 @@ def grouped_matmul(lhs, rhs, group_sizes, interpret: Optional[bool] = None,
     telemetry.record_static(
         "grouped_matmul", kernel=1, tile_rows=tiles.rows,
         tile_fwd_k=tiles.fwd_k, tile_drows_n=tiles.drows_n,
-        tile_dweights_k=tiles.dweights_k)
+        tile_dweights_k=tiles.dweights_k, tile_dweights_n=tiles.dweights_n)
     if walk is None:
         walk = row_walk(group_sizes, rows)
     return _grouped_call(lhs, rhs, walk, tiles=tiles,

@@ -8,7 +8,9 @@ routed to them,
 
     out[t] = sum over e in sel[t], e held here, of w[t, e] * expert_e(x[t]),
 
-each expert a SwiGLU.  What the experts on the other chips would add is
+each expert a SwiGLU (three matrices) or, where the tree holds no
+`gate_w`, two matrices with a squared ReLU between them,
+`relu(x W_up)^2 W_down`.  What the experts on the other chips would add is
 not here and nothing stands in for it: across chips the shares are summed
 by the exchange (`parallel/expert.py` has the all-to-all for the hybrid
 step's Switch layer, which is top-1 and drops tokens over a capacity; an
@@ -21,9 +23,9 @@ No assignment to a held expert is dropped, whatever the routing:
     the pairs that chose an expert held elsewhere last;
   - the first `rows` pairs (a static buffer, `capacity_factor` times the
     even share, so that a usual step fits) are gathered, go through the
-    three expert products grouped by expert (`ops/grouped_matmul.py`:
+    expert's products grouped by expert (`ops/grouped_matmul.py`:
     the program's own Pallas kernels, whose grid walks only the row tiles
-    that hold a live row; a width they cannot tile, no multiple of 128,
+    that hold a live row; a width they cannot tile, no multiple of 64,
     goes to `lax.ragged_dot`), are weighted, and are scatter-added back
     to their tokens;
   - pairs past the buffer go through the same code, a small buffer at a
@@ -154,17 +156,32 @@ def _held_weight_held(weights, sel, cfg: MoEConfig):
     return lax.stop_gradient(weights) + (scaled - lax.stop_gradient(scaled))
 
 
-def _swiglu_grouped(xg, experts, group_sizes, dtype):
-    """The three products of every held expert on its own rows of `xg`
-    [rows, D], which lie grouped by expert, `group_sizes` rows each."""
-    walk = gm.row_walk(group_sizes, xg.shape[0])    # one routing: once
+def _grouped_on(group_sizes, rows: int, dtype):
+    """`(lhs, weights) -> lhs` through each group's own matrix, for the
+    products of ONE routing: its tables are made once and shared."""
+    walk = gm.row_walk(group_sizes, rows)
 
     def grouped(lhs, w):
         return gm.grouped_matmul(lhs, w.astype(dtype), group_sizes,
                                  walk=walk)
+    return grouped
+
+
+def _swiglu_grouped(xg, experts, group_sizes, dtype):
+    """The three products of every held expert on its own rows of `xg`
+    [rows, D], which lie grouped by expert, `group_sizes` rows each."""
+    grouped = _grouped_on(group_sizes, xg.shape[0], dtype)
     h = jax.nn.silu(grouped(xg, experts["gate_w"])) * grouped(
         xg, experts["up_w"])
     return grouped(h, experts["down_w"])
+
+
+def _relu2_grouped(xg, experts, group_sizes, dtype):
+    """The two products of every held expert that has no gate,
+    `relu(x W_up)^2 W_down`, on rows grouped as `_swiglu_grouped`'s."""
+    grouped = _grouped_on(group_sizes, xg.shape[0], dtype)
+    return grouped(jnp.square(jax.nn.relu(grouped(xg, experts["up_w"]))),
+                   experts["down_w"])
 
 
 class _Plan(NamedTuple):
@@ -214,7 +231,8 @@ def _buffer(lo, x, experts, flat_w, plan: _Plan, k: int, rows: int):
         dead = ~live[:, None]
         xg = jnp.where(dead, 0, x[token])
     with jax.named_scope(".grouped"):
-        y = _swiglu_grouped(xg, experts, group_sizes, x.dtype)
+        form = _swiglu_grouped if "gate_w" in experts else _relu2_grouped
+        y = form(xg, experts, group_sizes, x.dtype)
     with jax.named_scope(".scatter"):
         y = jnp.where(dead, 0, y).astype(jnp.float32) * flat_w[pair][:, None]
         return jnp.zeros(x.shape, jnp.float32).at[token].add(y)
@@ -268,11 +286,12 @@ def held_experts(x, router_w, experts, cfg: MoEConfig, expert_bias=None,
                  sel=None):
     """`x` [T, D] -> `(out [T, D], Routing)`: the held experts' part of
     the layer.  `experts` holds `gate_w`, `up_w` [len(held), D, F] and
-    `down_w` [len(held), F, D], in the order of `cfg.held`."""
+    `down_w` [len(held), F, D], in the order of `cfg.held`; without
+    `gate_w` an expert is `relu(x up_w)^2 down_w`."""
     T = x.shape[0]
     k = cfg.top_k
     rows, past = cfg.buffer_rows(T), cfg.past_rows(T)
-    gm.record_walk(rows, *experts["gate_w"].shape[1:], len(cfg.held), x.dtype,
+    gm.record_walk(rows, *experts["up_w"].shape[1:], len(cfg.held), x.dtype,
                    live=T * k * len(cfg.held) // cfg.num_experts)
     # The layer's parts are children of whatever scope it is called under
     # (`<family>.moe`): a leading "." says so, and `bps.get_step_scopes()`

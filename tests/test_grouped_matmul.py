@@ -152,7 +152,7 @@ def test_tile_rule_at_the_cells_widths(k, n, whole):
 
 @pytest.mark.parametrize("rows,k,n", [
     (512, 64, 32),          # the tiny models of the CPU tests
-    (512, 2304, 448),       # half a lane tile too many
+    (512, 2304, 480),       # no whole half lane tiles: 7.5 x 64
     (520, 256, 128),        # rows no tile of 128 divides
 ])
 def test_tile_rule_refuses_and_ragged_dot_runs(rows, k, n):
@@ -214,3 +214,89 @@ def test_walk_at_the_even_routing(rows, groups, live, walked, needed):
     assert gm.row_tiles(rows, groups, 256, live) == {
         "row_tiles_walked": walked, "row_tiles_needed": needed,
         "row_tiles_buffer": rows // 256}
+
+
+# The tiles every accepted cell's products got before the rule took
+# widths of whole HALF lane tiles (PR 47): (rows, k, n, groups) -> tiles.
+TILES_BEFORE = {
+    (53248, 2048, 1024, 16): (256, 2048, 1024, 2048, 1024),   # trinity-mini
+    (53248, 1024, 2048, 16): (256, 1024, 2048, 1024, 2048),
+    (81920, 2304, 896, 16): (256, 2304, 896, 2304, 896),      # mellum
+    (81920, 896, 2304, 16): (256, 896, 2304, 896, 2304),
+    (40960, 2048, 768, 16): (256, 2048, 768, 2048, 768),      # keye
+    (40960, 768, 2048, 16): (256, 768, 2048, 768, 2048),
+}
+
+
+@pytest.mark.parametrize("shape", TILES_BEFORE, ids=str)
+def test_the_other_cells_shapes_keep_the_tiles_they_had(shape):
+    """Every width whole, the weights' gradient N whole too: the new
+    field is the old behaviour wherever K can be cut."""
+    assert tuple(gm.grouped_tiles(*shape, jnp.bfloat16)) == TILES_BEFORE[
+        shape]
+
+
+def test_tile_rule_at_a_width_of_whole_half_lane_tiles():
+    """1856 = 14.5 x 128 (the nemotron_h cell's experts, 7,680 rows on 8
+    of them): no divisor of it is whole lane tiles, so it is never cut;
+    the weights' gradient's float32 sum [2688, 1856] passes the budget
+    either way round, so the OTHER width goes in thirds: K where the
+    1856 is N, N where it is K."""
+    up = gm.grouped_tiles(7680, 2688, 1856, 8, jnp.bfloat16)
+    assert up == gm.Tiles(256, 2688, 1856, 896, 1856)
+    down = gm.grouped_tiles(7680, 1856, 2688, 8, jnp.bfloat16)
+    assert down == gm.Tiles(256, 1856, 2688, 1856, 896)
+    assert gm._dweights_vmem(256, 1856, 2688, 2) > gm.VMEM_BUDGET
+    assert gm._dweights_vmem(256, 1856, 896, 2) <= gm.VMEM_BUDGET
+    assert gm._divisors(1856) == [1856]
+    assert gm._divisors(2688) == [2688, 896, 384, 128]
+    assert gm.grouped_tiles(7680, 2688, 1824, 8, jnp.bfloat16) is None
+
+
+@pytest.mark.parametrize("kind", ["forward", "rows_gradient",
+                                  "weights_gradient"])
+def test_kernels_at_width_1856_against_ragged_dot(kind):
+    """The nemotron_h expert's two products at their published widths
+    (fewer rows and experts), in the interpreter, on the tiles the rule
+    gives them: forward and both gradients against `lax.ragged_dot`, the
+    last 64 columns of the 1856 as good as the first."""
+    rows, groups, sizes = 512, 2, [200, 250]
+    group_sizes = jnp.asarray(sizes, jnp.int32)
+    live = (jnp.arange(rows) < sum(sizes))[:, None]
+    for k_, n_ in ((2688, 1856), (1856, 2688)):
+        ks = jax.random.split(jax.random.key(k_), 3)
+        # (bfloat16, the cell's: in float32 a whole width of 1856 beside
+        # 2688 passes the budget and the rule hands the shape back)
+        dtype = jnp.bfloat16
+        lhs = jnp.where(live, jax.random.normal(ks[0], (rows, k_)),
+                        0.0).astype(dtype)
+        rhs = (jax.random.normal(ks[1], (groups, k_, n_))
+               * k_ ** -0.5).astype(dtype)
+        g = jnp.where(live, jax.random.normal(ks[2], (rows, n_)),
+                      0.0).astype(dtype)
+        tiles = gm.grouped_tiles(rows, k_, n_, groups, dtype)
+        assert tiles is not None and 1856 in (tiles.fwd_k, tiles.drows_n)
+
+        def loss(product):
+            return lambda a, b: jnp.sum(jnp.where(
+                live, product(a, b, group_sizes), 0.0).astype(jnp.float32)
+                * g.astype(jnp.float32))
+        if kind == "forward":
+            got = gm.grouped_matmul(lhs, rhs, group_sizes)
+            want = lax.ragged_dot(lhs, rhs, group_sizes)
+            got, want = (np.where(live, _f32(t), 0) for t in (got, want))
+        else:
+            arg = 0 if kind == "rows_gradient" else 1
+            got = _f32(jax.grad(loss(gm.grouped_matmul), arg)(lhs, rhs))
+            want = _f32(jax.grad(loss(lax.ragged_dot), arg)(lhs, rhs))
+            if arg == 0:
+                got, want = (np.where(live, t, 0) for t in (got, want))
+        _close(got, want, dtype)
+        # the columns a tile of 128 would have dropped
+        tail = (slice(None), slice(1792, 1856))
+        if kind == "forward" and n_ == 1856:
+            assert np.abs(got[tail]).max() > 0.1
+        if kind == "rows_gradient" and k_ == 1856:
+            assert np.abs(got[tail]).max() > 0.1
+    assert bps.get_metrics()["bps_grouped_kernel"] == 1
+    assert bps.get_metrics()["bps_grouped_tile_dweights_n"] == 896

@@ -1,0 +1,281 @@
+"""The `nemotron_h` decoder (`byteps_tpu/models/nemotron_h.py`) at tiny
+widths on the CPU against the plain reference of
+`benchmark/reference/nemotronh.py` (loss and every leaf's gradient, in
+float32 to rounding and in the cell's bfloat16 to the family's tiny
+limits), the plan by kind on the cell's nine layers and on the published
+52, the shares of an expert layer against the uncut reference, the gated
+norm by groups, the parameter count of the built tree against the
+configuration's, and the scopes and gauges a traced step leaves.  The
+broken variants are `tests/test_nemotron_h_variants.py`'s, so that the two
+files run on two workers."""
+
+import dataclasses
+import json
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import byteps_tpu as bps
+from benchmark.families import nemotronh as family_nemotronh
+from benchmark.harness import correct, manifest
+from benchmark.reference import nemotronh as reference
+from benchmark.tests import tiny_nemotronh
+from byteps_tpu.models import granite_hybrid, nemotron_h
+from byteps_tpu.parallel import dropless_moe
+
+_family, _agreement = tiny_nemotronh.family, tiny_nemotronh.agreement
+PUBLISHED = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+# (layers of the model that are run, experts held)
+CUTS = {
+    "the_cells_nine_layers": (None, None),
+    "one_of_each_kind": ([4, 5, 6], None),
+    "whole_layers_every_expert": ([5, 6, 7], range(128)),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("cut", CUTS)
+def test_against_reference(cut, dtype):
+    """In float32 the program IS the reference up to rounding (1e-5 on
+    the loss, 2e-4 on the worst leaf, no token's choice swapped); in
+    bfloat16 it is within the family's tolerances at these widths."""
+    layers, experts = CUTS[cut]
+    family = _family(
+        dtype, tiny_nemotronh.FLOAT32 if dtype == jnp.float32 else None,
+        layers=layers, experts=experts)
+    got = _agreement(family)
+    assert correct.agreement_ok(got, family.reference_check), got
+    if dtype == jnp.float32:
+        assert got["loss_rel_diff"] <= 1e-5
+        assert got["worst_grad_rel_diff"] <= 1e-4, got
+        assert all(s["swapped_share"] == 0 for s in family.selection)
+        parts = family.selection[-1]
+        for name in ("scan_rel_diff", "router_rel_diff", "experts_rel_diff",
+                     "attn_row_diff"):
+            assert parts[name] < 1e-5, (name, parts[name])
+    if experts is not None:
+        # every pair falls on a held expert: 6 rows a token in the one
+        # expert layer of the three
+        assert family.routing_counters[-1]["held_rows_per_token"] == [6.0]
+
+
+@pytest.mark.parametrize("pattern,counts", [
+    ("MEMEM*EME", {"mamba": 4, "moe": 4, "attention": 1}),
+    (PUBLISHED, {"mamba": 23, "moe": 23, "attention": 6}),
+], ids=["the_cells_stage", "the_published_52"])
+def test_the_plan_by_kind_walks_the_published_order(pattern, counts):
+    """Three stacks whatever the order: layer i of kind c takes the next
+    set of c's leaves, and the tree holds one stack a kind, as long as
+    the kind has layers."""
+    family = _family(jnp.float32)
+    cfg = dataclasses.replace(family.cfg,
+                              layer_kinds=nemotron_h.kinds_of(pattern))
+    plan = nemotron_h.layer_plan(cfg)
+    assert [k for k, _ in plan] == [nemotron_h.LETTERS[c] for c in pattern]
+    for kind, n in counts.items():
+        assert [j for k, j in plan if k == kind] == list(range(n))
+        assert cfg.count(kind) == n
+    shapes = jax.eval_shape(
+        lambda k: nemotron_h.init_params(k, cfg), jax.random.key(0))
+    assert set(shapes) == {"embed", "head", "final_ln", *counts}
+    for kind, n in counts.items():
+        assert {a.shape[0] for a in jax.tree.leaves(shapes[kind])} == {n}
+    # granite's plan by period would make a run, a group of leaves and a
+    # compiled body of every change of kind
+    granite = dataclasses.replace(
+        granite_hybrid.GraniteHybridConfig(
+            vocab_size=8, hidden_size=8, layer_types=("mamba",),
+            intermediate_size=8, num_heads=1, num_kv_heads=1, head_dim=8,
+            mamba_n_heads=1, mamba_d_head=8, mamba_d_state=8),
+        layer_types=tuple("attention" if c == "*" else "mamba"
+                          for c in pattern if c != "E"))
+    periods, runs = granite_hybrid._stack_plan(granite)
+    assert periods == 1 and len(runs) > 2
+    with pytest.raises(ValueError, match="no layer of kind '-'"):
+        nemotron_h.kinds_of("ME-*")
+
+
+def test_a_traced_step_leaves_the_plans_gauges_and_the_models_scopes():
+    family = _family(jnp.bfloat16)
+    params = jax.eval_shape(family.init, jax.random.key(0))
+    batch = jax.eval_shape(lambda k: family.make_batch(k, 1),
+                           jax.random.key(0))
+    text = jax.jit(jax.grad(family.loss)).lower(params, batch).as_text(
+        debug_info=True)
+    for scope in ("nemotronh.embed", "nemotronh.mamba.in_proj",
+                  "nemotronh.mamba.conv", "nemotronh.mamba.scan",
+                  "nemotronh.mamba.gate_norm", "nemotronh.mamba.out_proj",
+                  "nemotronh.attn", "nemotronh.moe", "nemotronh.head"):
+        assert scope in text, scope
+    assert "granite." not in text
+    jaxpr = str(jax.make_jaxpr(jax.grad(family.loss))(params, batch))
+    assert "ssd_fwd_c64" in jaxpr and "ssd_bwd_c64" in jaxpr
+    metrics = bps.get_metrics()
+    assert metrics["bps_layer_plan_stacks"] == 3
+    assert metrics['bps_layer_plan_layers{kind="mamba"}'] == 4
+    assert metrics['bps_layer_plan_layers{kind="moe"}'] == 4
+    assert metrics['bps_layer_plan_layers{kind="attention"}'] == 1
+    assert metrics["bps_ssd_scan_groups"] == 2
+    assert metrics["bps_ssd_scan_layers"] == 4
+    assert metrics["bps_ssd_chunk"] == 64
+
+
+def test_the_shares_add_up_to_the_layer():
+    """Guide, section 4: over the sixteen chips that share a layer, the
+    routed parts the shares compute, with the shared expert (which every
+    chip computes alike) counted ONCE, are the uncut reference's expert
+    layer for the same tokens, every pair on exactly one chip; and the
+    eight slices' logits laid side by side are the whole head's."""
+    family = _family(jnp.float32, layers=[1])
+    cfg, spec = family.cfg, family.spec
+    E, D = cfg.num_experts, cfg.hidden_size
+    F, Fs = cfg.moe_intermediate_size, cfg.moe_shared_intermediate_size
+    k = jax.random.split(jax.random.key(0), 6)
+    whole = {
+        "router_w": jax.random.normal(k[0], (D, E)) / 8,
+        "expert_up_w": jax.random.normal(k[1], (E, D, F)) / 8,
+        "expert_down_w": jax.random.normal(k[2], (E, F, D)) / 5,
+        "shared_up_w": jax.random.normal(k[3], (D, Fs)) / 8,
+        "shared_down_w": jax.random.normal(k[4], (Fs, D)) / 7,
+    }
+    m = jax.random.normal(k[5], (1, 192, D))
+    with jax.default_matmul_precision("highest"):
+        uncut, _ = reference.moe_part(
+            m, whole, {**spec, "held": tuple(range(E)), "mlp_block": 64})
+    total, rows = 0.0, 0
+    for chip in range(16):
+        held = tuple(range(chip * E // 16, (chip + 1) * E // 16))
+        assert len(held) == 8
+        moe = dataclasses.replace(cfg.moe, held=held)
+        experts = {n: whole["expert_" + n][jnp.asarray(held)]
+                   for n in ("up_w", "down_w")}
+        part, routing = dropless_moe.held_experts(m[0], whole["router_w"],
+                                                  experts, moe)
+        total, rows = total + part, rows + int(routing.held_rows)
+    shared = nemotron_h._relu2(m, whole["shared_up_w"],
+                               whole["shared_down_w"], jnp.float32)
+    assert rows == m.shape[1] * cfg.num_experts_per_tok
+    np.testing.assert_allclose(np.asarray(total + shared[0]),
+                               np.asarray(uncut[0]), atol=3e-5, rtol=3e-5)
+
+    V = 8 * 24
+    head = jax.random.normal(k[0], (V, D))
+    x = jax.random.normal(k[1], (2, 16, D))
+    side_by_side = jnp.concatenate(
+        [nemotron_h.head_logits(x, head[c * 24:(c + 1) * 24])
+         for c in range(8)], axis=-1)
+    np.testing.assert_allclose(np.asarray(side_by_side),
+                               np.asarray(x @ head.T), atol=1e-4, rtol=1e-5)
+
+
+def test_an_expert_is_two_matrices_with_a_squared_relu_between():
+    """`dropless_moe.held_experts` without a `gate_w`: every expert held,
+    top-2 of 4, against a loop over the experts; the scale 2.5 on weights
+    that sum to 1, and the bias moves the choice and not the weights."""
+    D, F, E, T = 16, 24, 4, 64
+    k = jax.random.split(jax.random.key(3), 4)
+    x = jax.random.normal(k[0], (T, D))
+    router = jax.random.normal(k[1], (D, E))
+    up = jax.random.normal(k[2], (E, D, F)) / 4
+    down = jax.random.normal(k[3], (E, F, D)) / 5
+    cfg = dropless_moe.MoEConfig(num_experts=E, top_k=2, held=(0, 1, 2, 3),
+                                 route_scale=2.5, row_multiple=8)
+    out, routing = dropless_moe.held_experts(
+        x, router, {"up_w": up, "down_w": down}, cfg)
+    np.testing.assert_allclose(np.asarray(routing.weights.sum(-1)), 2.5,
+                               rtol=1e-6)
+    with jax.default_matmul_precision("highest"):
+        want = sum(
+            jnp.where(routing.sel == e, routing.weights, 0.0).sum(-1)[:, None]
+            * (jnp.square(jax.nn.relu(x @ up[e])) @ down[e])
+            for e in range(E))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5,
+                               rtol=2e-5)
+    bias = jnp.asarray([0.0, 0.0, 0.0, 10.0])
+    sel, weights = dropless_moe.route(x, router, cfg, expert_bias=bias)
+    assert bool((sel == 3).any(-1).all())
+    scores = jax.nn.sigmoid(x @ router)
+    chosen = jnp.take_along_axis(scores, sel, -1)
+    np.testing.assert_allclose(
+        np.asarray(weights),
+        np.asarray(2.5 * chosen / chosen.sum(-1, keepdims=True)), rtol=1e-5)
+
+
+def test_the_gated_norm_is_by_groups_and_after_the_gate():
+    cfg = _family(jnp.float32).cfg
+    k = jax.random.split(jax.random.key(1), 3)
+    y = jax.random.normal(k[0], (2, 8, 64)) * jnp.arange(1, 65)
+    z = jax.random.normal(k[1], (2, 8, 64))
+    scale = jax.random.normal(k[2], (64,))
+    got = granite_hybrid._gate_norm_grouped(y, z, scale, cfg, 2)
+    want = reference.gated_group_norm(y, z, scale, 2, cfg.rms_norm_eps)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-6)
+    one = granite_hybrid._gate_norm(y, z, scale, cfg)
+    np.testing.assert_allclose(
+        np.asarray(one),
+        np.asarray(reference.gated_group_norm(y, z, scale, 1,
+                                              cfg.rms_norm_eps)),
+        rtol=1e-5, atol=1e-6)
+    assert float(jnp.abs(one - got).max()) > 0.1
+
+
+def test_model_flops_and_parameters_at_the_published_widths():
+    """The configuration file's own count, from the built tree:
+    666,962,944 parameters; the published keys are all there with the
+    catalog's values but for the three that are `reduced`; and the FLOPs
+    a token the issue reckoned."""
+    with open(os.path.join(manifest.BENCH, "configs",
+                           tiny_nemotronh.NAME + ".json")) as f:
+        config = json.load(f)
+    family = family_nemotronh.Family(config, config["job"])
+    shapes = jax.eval_shape(family.init, jax.random.key(0))
+    count = sum(math.prod(a.shape) for a in jax.tree.leaves(shapes))
+    assert count == 666_962_944
+    assert f"{count:,}" in config["deployment"]["parameters"]
+    per_kind = {kind: sum(math.prod(a.shape[1:])
+                          for a in jax.tree.leaves(shapes[kind]))
+                for kind in ("mamba", "moe", "attention")}
+    assert per_kind == {"mamba": 38_744_896, "moe": 100_125_312,
+                        "attention": 23_399_040}
+    for key, value in config["published"].items():
+        if key not in config["reduced"]:
+            assert config[key] == value, key
+    assert {k: config[k] for k in config["reduced"]} == {
+        "num_hidden_layers": 9, "n_routed_experts": 8, "vocab_size": 16384}
+    assert family.kinds == nemotron_h.kinds_of("MEMEM*EME")
+    assert (family.cfg.d_inner, family.cfg.conv_dim) == (4096, 6144)
+    assert shapes["mamba"]["in_proj_w"].shape == (4, 2688, 10304)
+    assert shapes["moe"]["expert_up_w"].shape == (4, 8, 2688, 1856)
+    assert family.cfg.moe.buffer_rows(16384) == 7680
+    assert config["job"] == {"per_chip_batch": 1, "seq_len": 16384,
+                             "optimizer": {"name": "adamw",
+                                           "learning_rate": 0.0001}}
+    assert config["deployment"]["chips_a_layer"] == 16
+    assert {"assumed", "left_out", "held", "deployment"} <= set(config)
+    flops = family.model_flops_per_sample() / family.seq_len
+    # 6 x 318.4M matmul parameters a token (1.91 GFLOP), attention's
+    # triangle 0.40, four scans 0.04
+    assert 2.3e9 < flops < 2.4e9
+
+
+def test_the_new_code_stays_out_of_the_other_cells_imports():
+    """The other families import nothing of the nemotron_h model."""
+    import subprocess
+    import sys
+    from testutil import cpu_env
+    code = ("import sys, byteps_tpu, byteps_tpu.models.afmoe, "
+            "benchmark.families.afmoe, benchmark.families.gpt2, "
+            "benchmark.families.vgg, benchmark.families.granitehybrid, "
+            "benchmark.families.mellum, benchmark.families.keye, "
+            "benchmark.jobs.ingraph; "
+            "bad = [m for m in sys.modules if 'nemotron' in m]"
+            "; assert not bad, bad")
+    r = subprocess.run([sys.executable, "-c", code], env=cpu_env(),
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]

@@ -190,3 +190,56 @@ def test_causal_conv1d_against_a_loop(taps, bias):
         jnp.asarray(x2), jnp.asarray(w))) - np.asarray(ssd.causal_conv1d(
             jnp.asarray(x), jnp.asarray(w)))).sum((0, 2)) > 0
     assert not moved[:6].any() and moved[6]
+
+
+@pytest.mark.parametrize("impl", ["jnp", "kernel"])
+def test_eight_groups_and_chunks_of_128_against_the_recurrence(impl):
+    """The nemotron_h cell's form of the scan (8 groups of 8 heads, a
+    block of heads a group, chunks of 128; the head's and the state's
+    sizes cut): values and every gradient against the recurrence, in
+    which head h reads group h // 8, and against the `jnp` form; and one
+    group's B and C given to all heads reads far off."""
+    b, s, h, p, g, n = 1, 256, 64, 8, 8, 16
+    ks = jax.random.split(jax.random.key(11), 7)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)) - 2.0)
+    A = -jnp.exp(jax.random.uniform(ks[2], (h,), minval=0.0, maxval=2.7))
+    args = (jax.random.normal(ks[0], (b, s, h, p)), dt, A,
+            jax.random.normal(ks[3], (b, s, g, n)),
+            jax.random.normal(ks[4], (b, s, g, n)),
+            jax.random.normal(ks[5], (h,)))
+    w = jax.random.normal(ks[6], (b, s, h, p))
+
+    def plain(x, dt, A, Bm, Cm, D):
+        bh, ch = (jnp.repeat(t, h // g, axis=2) for t in (Bm, Cm))
+
+        def step(state, inp):
+            x_t, dt_t, b_t, c_t = inp
+            state = (state * jnp.exp(dt_t * A)[..., None, None]
+                     + (dt_t[..., None] * x_t)[..., None]
+                     * b_t[:, :, None, :])
+            return state, (jnp.einsum("bhpn,bhn->bhp", state, c_t)
+                           + D[:, None] * x_t)
+        xs = tuple(t.swapaxes(0, 1) for t in (x, dt, bh, ch))
+        return lax.scan(step, jnp.zeros((b, h, p, n)), xs)[1].swapaxes(0, 1)
+
+    def value_and_grads(fn):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: (fn(*a) * w).sum(), argnums=range(6)))(*args)
+    with jax.default_matmul_precision("highest"):
+        want_value, want = value_and_grads(plain)
+        value, got = value_and_grads(
+            lambda *a: ssd.ssd_scan(*a, chunk=128, impl=impl))
+        y = ssd.ssd_scan(*args, chunk=128, impl=impl)
+        one_group = ssd.ssd_scan(
+            args[0], dt, A, jnp.broadcast_to(args[3][:, :, :1], args[3].shape),
+            jnp.broadcast_to(args[4][:, :, :1], args[4].shape), args[5],
+            chunk=128, impl=impl)
+        y_jnp = ssd.ssd_scan(*args, chunk=128, impl="jnp")
+    np.testing.assert_allclose(float(value), float(want_value), rtol=2e-5)
+    for name, a, e in zip(INPUTS, got, want):
+        err = float(jnp.linalg.norm(a - e) / jnp.linalg.norm(e))
+        assert err < 5e-5, (name, err)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_jnp), atol=2e-4,
+                               rtol=2e-5)
+    assert float(jnp.linalg.norm(one_group - y) / jnp.linalg.norm(y)) > 0.5
+    assert ssd._head_block(64, 8) == 8

@@ -25,7 +25,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import byteps_tpu as bps
 from benchmark.harness import measure
 from benchmark.tests import (tiny, tiny_afmoe, tiny_granitehybrid,  # noqa: F401
-                             tiny_keye, tiny_mellum)    # join `tiny`'s table
+                             tiny_keye, tiny_mellum,    # join `tiny`'s table
+                             tiny_nemotronh)
 from byteps_tpu.common import devprof
 from byteps_tpu.ops import flash_attention as fa
 from byteps_tpu.ops import ssd
@@ -69,6 +70,16 @@ FAMILIES = {
               "keye.attn.full_attention/out", "keye.moe", "keye.moe/route",
               "keye.moe/gather", "keye.moe/grouped", "keye.moe/scatter",
               "keye.moe/exact", "keye.head", "byteps.optimizer"}, True),
+    "nemotronh": ("nemotron-labs-twotower-30b-a3b-base.ingraph-1chip",
+                  {"nemotronh.embed", "nemotronh.mamba.in_proj",
+                   "nemotronh.mamba.conv", "nemotronh.mamba.scan",
+                   "nemotronh.mamba.gate_norm", "nemotronh.mamba.out_proj",
+                   "nemotronh.attn", "nemotronh.attn/qkv",
+                   "nemotronh.attn/out", "nemotronh.moe",
+                   "nemotronh.moe/route", "nemotronh.moe/gather",
+                   "nemotronh.moe/grouped", "nemotronh.moe/scatter",
+                   "nemotronh.moe/exact", "nemotronh.moe/shared",
+                   "nemotronh.head", "byteps.optimizer"}, True),
 }
 # The names the device trace was read by before this map: an unnamed
 # kernel call is called after the innermost scope around it.  The expert
@@ -85,6 +96,8 @@ KERNEL_SCOPES = {
     "keye": {"keye.attn.full_attention/select",
              "keye.attn.full_attention/sparse", "keye.moe/grouped",
              "keye.moe/exact/grouped"},
+    "nemotronh": {"nemotronh.mamba.scan", "nemotronh.attn",
+                  "nemotronh.moe/grouped", "nemotronh.moe/exact/grouped"},
 }
 PRODUCTS = ("fusion", "custom-call", "dot", "convolution", "ragged-dot")
 WORK = ("dot_general", "conv_general_dilated", "pallas_call")
@@ -139,6 +152,10 @@ def _family(name: str):
                                         "rope_type": "default"},
             sa_config={**config["published"]["sa_config"],
                        "indexer_head_dim": 64})
+        cell = dataclasses.replace(cell, config=config)
+    elif name == "nemotronh":   # M, *, E; experts of HALF a lane tile
+        config = tiny_nemotronh.config(layers=[4, 5, 6])
+        config["published"].update(tiny_nemotronh.ON_THE_CHIP)
         cell = dataclasses.replace(cell, config=config)
     if name in ("afmoe", "mellum", "keye"):
         # the narrowest widths the grouped kernels tile: a lane tile each
@@ -251,7 +268,7 @@ def test_every_familys_tiny_step_is_mapped(name, v5e, kernels,
     assert not [n for n, e in scopes.items() if e.get("lent")]
     grouped = {n: e for n, e in kernels.items()
                if n.startswith("ragged-dot-none_")}
-    if name in ("afmoe", "mellum", "keye"):
+    if name in ("afmoe", "mellum", "keye", "nemotronh"):
         assert {n.split(".")[0].rsplit("_", 1)[1] for n in grouped} == {
             "fwd", "drows", "dweights"}
         assert all(e["scope"].startswith(f"{name}.moe/")

@@ -530,3 +530,94 @@ def test_keye_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch):
     mem = compiled.memory_analysis()
     assert (mem.peak_memory_in_bytes
             + mem.generated_code_size_in_bytes) < 15.5e9
+
+
+def _nemotronh_family(layers):
+    import json
+
+    from benchmark.families import nemotronh as family_nemotronh
+    from benchmark.harness import manifest
+    with open(os.path.join(manifest.BENCH, "configs",
+                           "nemotron-labs-twotower-30b-a3b-base.json")) as f:
+        config = json.load(f)
+    config["held"].update(layers=layers, num_hidden_layers=len(layers))
+    return family_nemotronh.Family(config, config["job"])
+
+
+@pytest.mark.parametrize("layers", [[4, 5], [6]],
+                         ids=["mixer_and_attention", "expert_layer"])
+def test_nemotronh_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch,
+                                                           layers):
+    """The nemotron_h cell's step
+    (`benchmark/configs/nemotron-labs-twotower-30b-a3b-base.json`: one
+    sequence of 16,384, a plain `value_and_grad` and adamw, embedding and
+    head included) for one described chip, in two parts of the cell's
+    nine layers, because the twelve grouped kernels at width 1856 alone
+    take Mosaic 25 s (the nine layers compile in 40 s alone and in 80
+    beside five other workers, over a test's budget): layers 4 and 5, `M*`
+    (the scan's kernels `ssd_fwd_c128` / `ssd_bwd_c128` with 128 chunks of
+    state, the flash kernels on 32 heads), and layer 6, `E` (BOTH products
+    of an expert at width 1856 = 14.5 x 128 are the program's own kernels
+    under the names the trace's readers know, none of the compiler's
+    `ragged-dot` is left).  Arguments and temporaries as the compiler
+    counts them are printed.  The whole cell, compiled the same way
+    (PR 47): arguments 8,003,700,736, temporaries 6,225,738,752, peak
+    13,995,448,832 + 257,163,264 of code; the chip measured
+    `memory_peak_bytes` 14,370,686,464."""
+    import optax
+
+    from benchmark.reduce import afmoe_cost, ssd_cost
+    from byteps_tpu.ops import ssd
+    monkeypatch.setattr(fa, "_use_interpret", lambda interpret: False)
+    monkeypatch.setattr(ssd, "_use_interpret", lambda interpret: False)
+    family = _nemotronh_family(layers)
+    opt = family.optimizer()
+    one = SingleDeviceSharding(v5e[0])
+
+    def step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(family.loss)(params, batch)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+    params = jax.eval_shape(family.init, jax.random.key(0))
+    opt_state = jax.eval_shape(opt.init, params)
+    batch = jax.eval_shape(lambda k: family.make_batch(k, 1),
+                           jax.random.key(0))
+    t0 = time.perf_counter()
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(opt_state), on_chip(batch)).compile()
+    seconds = time.perf_counter() - t0
+    text = compiled.as_text()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    scans = sorted(c for c in map(ssd_cost.scan_call, calls) if c)
+    grouped = [afmoe_cost.grouped_call(c) for c in calls
+               if afmoe_cost.is_grouped(c)]
+    flash = sorted(f[:4] for f in map(afmoe_cost.attention_call, calls) if f)
+    if layers == [6]:
+        # two products forward, again under remat, and their two gradients
+        # each, in the first buffer and in the exact path's loop
+        assert len(grouped) == 2 * (2 + 2 + 2 * 2)
+        assert set(grouped) == {(8, 2688, 1856), (8, 1856, 2688)}
+        assert "ragged-dot-metadata" not in text
+        assert not scans and not flash
+    else:
+        # a layer's forward kernel, again under remat, and its backward
+        assert scans == [("backward", 128)] + [("forward", 128)] * 2
+        assert "f32[1,64,128,64,128]" in text       # 128 chunks of state
+        assert flash == [("dkv", 32, 16384, 128), ("dq", 32, 16384, 128),
+                         ("forward", 32, 16384, 128),
+                         ("forward", 32, 16384, 128)]
+        assert not grouped
+    mem = compiled.memory_analysis()
+    said = (f"arguments {mem.argument_size_in_bytes:,} temporaries "
+            f"{mem.temp_size_in_bytes:,} peak {mem.peak_memory_in_bytes:,} "
+            f"code {mem.generated_code_size_in_bytes:,} compiled in "
+            f"{seconds:.0f} s")
+    print(said)
+    # (the nine layers' arguments are 8.0e9 of the chip's 16.9e9)
+    assert mem.temp_size_in_bytes < 7.0e9, said

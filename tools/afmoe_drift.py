@@ -51,13 +51,15 @@ def main(argv=None) -> int:
     compile_cache.enable()
     if args.tiny:
         from benchmark.tests import (tiny, tiny_afmoe,  # noqa: F401
-                                     tiny_keye, tiny_mellum)
+                                     tiny_keye, tiny_mellum, tiny_nemotronh)
         cell = tiny.tiny_cell(args.workload)
     else:
         cell = manifest.load_cell(args.workload)
     name = cell.config["family"]
     families = importlib.import_module(f"benchmark.families.{name}")
-    model = importlib.import_module(f"byteps_tpu.models.{name}")
+    # (a family's model module is called after it, but for one)
+    model = importlib.import_module("byteps_tpu.models." + {
+        "nemotronh": "nemotron_h"}.get(name, name))
     pinned = cell.config["program_options"]["pinned"]
     option = "post_attn_norm_init"
     if args.inits and option not in pinned:

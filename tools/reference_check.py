@@ -20,7 +20,7 @@ older notes name.)
     python3 tools/reference_check.py --workload <cell> --record <out.pb>
 
 records instead the small trace that the family's readers are tested on
-(`RECORD` below: the granitehybrid, mellum and keye cells).
+(`RECORD` below: the granitehybrid, mellum, keye and nemotronh cells).
 """
 
 import argparse
@@ -36,6 +36,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 
+# a family's model module where it is not called after the family
+MODELS = {"nemotronh": "nemotron_h"}
+
+
 def _expert_extras(family, cell, params, seed, variant):
     """What the reference saw of the program's choice of experts, the
     program's own routing counters and, for the program as it is, the
@@ -46,8 +50,9 @@ def _expert_extras(family, cell, params, seed, variant):
 
     from benchmark.harness import seeded
     from byteps_tpu.parallel import dropless_moe
+    name = cell.config["family"]
     model = importlib.import_module(
-        f"byteps_tpu.models.{cell.config['family']}")
+        f"byteps_tpu.models.{MODELS.get(name, name)}")
     out = {"selection": list(family.selection),
            "routing_counters": list(family.routing_counters)}
     del family.selection[:], family.routing_counters[:]
@@ -162,10 +167,42 @@ def _keye_record(cell, out: str) -> int:
     return 0 if line["correct"] else 1
 
 
+def _nemotronh_record(cell, out: str) -> int:
+    """`benchmark/tests/data/tiny_nemotronh.xplane.pb`: the cell at tiny
+    widths but with what the chip's tiles ask (hidden 128, experts of 64
+    = HALF a lane tile, so the grouped kernels take a width no multiple
+    of 128, the shared expert 128; 16 mixer heads in 2 groups, chunks of
+    128), three layers (M, *, E), one sequence of 1,024 positions with
+    the streaming flash kernels asked for by hand, five traced steps
+    through the in-graph job."""
+    from unittest import mock
+
+    import jax
+
+    from benchmark.harness import chip, measure
+    from benchmark.reduce import xplane
+    from benchmark.tests import tiny_nemotronh
+    from byteps_tpu.ops import flash_attention
+    config = tiny_nemotronh.config(layers=[4, 5, 6])
+    config["published"].update(tiny_nemotronh.ON_THE_CHIP)
+    config["job"].update(per_chip_batch=1, seq_len=1024)
+    cell = dataclasses.replace(cell, config=config,
+                               job={**cell.job, **config["job"]})
+    with mock.patch.object(flash_attention, "_use_streaming",
+                           lambda q, streaming: True):
+        line, _ = measure.run_cell(
+            cell, seed=3, seconds=1.0, trace=True, devices=jax.devices()[:1],
+            peaks=chip.require(jax.devices(), 1), t_start=0.0)
+    print(line)
+    shutil.copy(xplane.find(os.path.join(measure.TRACE_ROOT, cell.name)), out)
+    return 0 if line["correct"] else 1
+
+
 EXTRAS = {"afmoe": _expert_extras, "mellum": _expert_extras,
-          "keye": _expert_extras, "granitehybrid": _granitehybrid_extras}
+          "keye": _expert_extras, "nemotronh": _expert_extras,
+          "granitehybrid": _granitehybrid_extras}
 RECORD = {"granitehybrid": _granitehybrid_record, "mellum": _mellum_record,
-          "keye": _keye_record}
+          "keye": _keye_record, "nemotronh": _nemotronh_record}
 
 
 def main(argv=None) -> int:
