@@ -449,6 +449,16 @@ _STATIC = {
             "bps_ssd_scan_groups",
             "groups of heads that share one B and C in that scan: a "
             "chunk's C B^T is computed once a group"),
+        "lane_block": _gauge(
+            "bps_ssd_lane_block",
+            "lanes of the slab of x one program of that scan's kernels "
+            "holds: its block of heads' columns of [S, heads x head size]"),
+        "wide_copies": _gauge(
+            "bps_ssd_wide_copies",
+            "transposed copies of a wide operand (x, y and their "
+            "gradients) one call of that scan makes outside its kernels, "
+            "both passes: 0 where the kernels read the mixer's layout, 4 "
+            "in the jnp form"),
     },
     "layer_plan": {
         "stacks": _gauge(

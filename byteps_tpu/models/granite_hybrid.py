@@ -224,13 +224,49 @@ def _gate_norm(y, z, scale, cfg):
     return _norm(y * jax.nn.silu(z), scale, cfg)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _stretch_sums(x, groups: int):
+    """[..., groups w] -> [..., groups]: the sum over each stretch of w
+    neighbours of the last dimension.  Written, with `_spread`, its
+    transpose, as slices of whole stretches, each the other's backward
+    pass (JAX's own transpose of a slice pads it to the whole width, one
+    array a stretch): the same sums as a reshape to [..., groups, w]
+    gives, but on the chip that reshape re-tiles the array (lanes become
+    rows), and in the backward pass the compiler made two float32 copies
+    of the whole width of it a layer once the scan handed the norm its
+    result in the mixer's own layout (PERF.md, Findings, PR 48)."""
+    w = x.shape[-1] // groups
+    return jnp.concatenate(
+        [x[..., g * w:(g + 1) * w].sum(-1, keepdims=True)
+         for g in range(groups)], axis=-1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _spread(r, w: int):
+    """[..., groups] -> [..., groups w]: each number over its stretch."""
+    return jnp.concatenate(
+        [jnp.broadcast_to(r[..., g:g + 1], (*r.shape[:-1], w))
+         for g in range(r.shape[-1])], axis=-1)
+
+
+_stretch_sums.defvjp(
+    lambda x, groups: (_stretch_sums(x, groups), x.shape[-1] // groups),
+    lambda groups, w, ct: (_spread(ct, w),))
+_spread.defvjp(
+    lambda r, w: (_spread(r, w), None),
+    lambda w, _, ct: (_stretch_sums(ct, ct.shape[-1] // w),))
+
+
 def _gate_norm_grouped(y, z, scale, cfg, groups: int):
     """The gated norm over each of `groups` stretches of the inner width
-    apart, under the one learned scale (`models/nemotron_h.py`)."""
+    apart, under the one learned scale (`models/nemotron_h.py`): `_norm`
+    a stretch, float32, over the array as the mixer has it."""
     gated = y * jax.nn.silu(z)
-    split = (*gated.shape[:-1], groups, gated.shape[-1] // groups)
-    return _norm(gated.reshape(split), scale.reshape(split[-2:]),
-                 cfg).reshape(gated.shape)
+    g32 = gated.astype(jnp.float32)
+    w = gated.shape[-1] // groups
+    mean_sq = _stretch_sums(g32 * g32, groups) / w
+    normed = g32 * _spread(jax.lax.rsqrt(mean_sq + cfg.rms_norm_eps), w)
+    return (normed * scale.astype(jnp.float32)).astype(gated.dtype)
 
 
 def _mamba(x, lp, cfg, scope: str = "granite.mamba", norm_groups: int = 1):

@@ -7,6 +7,7 @@ share (a pipeline stage, a slice of the tied vocabulary) to the whole
 model."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,7 @@ from benchmark.reference import granitehybrid as reference
 from benchmark.tests import granitehybrid_variants as variants
 from benchmark.tests import tiny_granitehybrid
 from byteps_tpu.models import granite_hybrid as gh
+from byteps_tpu.ops import ssd
 
 M, A = gh.MAMBA, gh.ATTENTION
 
@@ -302,6 +304,26 @@ def test_the_scan_writes_its_gauges_when_a_step_is_traced():
     assert metrics["bps_ssd_chunk"] == 64
     # 2 sequences x 8 heads x 4 chunks x [16, 32] float32
     assert metrics["bps_ssd_state_bytes"] == 2 * 8 * 4 * 16 * 32 * 4
+
+
+@pytest.mark.parametrize("impl,copies", [("kernel", 0), ("jnp", 4)])
+def test_the_scan_says_which_layout_ran(monkeypatch, impl, copies):
+    """A traced step's two gauges of the scan's layout: the lanes of the
+    slab of x a kernel program holds (this cut's 8 heads of 16), and the
+    transposed copies of a wide operand a call makes outside the kernels:
+    none on the model's path, which hands the kernels the mixer's own
+    [S, H P]; x, y, dy and dx in the `jnp` form, which keeps a layout of
+    its own."""
+    family = _family(layers=[4, 5, 6])
+    if impl != "kernel":
+        monkeypatch.setattr(ssd, "ssd_scan",
+                            functools.partial(ssd.ssd_scan, impl=impl))
+    jax.eval_shape(jax.grad(family.loss), seeded.params(family, 0),
+                   seeded.batch(family, 0, 2))
+    metrics = bps.get_metrics()
+    assert metrics["bps_ssd_lane_block"] == 8 * 16
+    assert metrics["bps_ssd_wide_copies"] == copies
+    assert metrics["bps_ssd_chunk"] == 64
 
 
 def test_the_new_code_stays_out_of_the_other_cells_imports():

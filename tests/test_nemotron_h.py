@@ -10,6 +10,7 @@ broken variants are `tests/test_nemotron_h_variants.py`'s, so that the two
 files run on two workers."""
 
 import dataclasses
+import functools
 import json
 import math
 import os
@@ -25,6 +26,7 @@ from benchmark.harness import correct, manifest
 from benchmark.reference import nemotronh as reference
 from benchmark.tests import tiny_nemotronh
 from byteps_tpu.models import granite_hybrid, nemotron_h
+from byteps_tpu.ops import ssd
 from byteps_tpu.parallel import dropless_moe
 
 _family, _agreement = tiny_nemotronh.family, tiny_nemotronh.agreement
@@ -123,6 +125,72 @@ def test_a_traced_step_leaves_the_plans_gauges_and_the_models_scopes():
     assert metrics["bps_ssd_scan_groups"] == 2
     assert metrics["bps_ssd_scan_layers"] == 4
     assert metrics["bps_ssd_chunk"] == 64
+
+
+@pytest.mark.parametrize("impl,copies", [("kernel", 0), ("jnp", 4)])
+def test_the_scan_says_which_layout_ran(monkeypatch, impl, copies):
+    """The scan's two gauges of its layout in this family's step: a kernel
+    program's slab (at this cut's 2 groups of 4 heads of 8 the whole
+    width, every head a program; at the published 8 groups of 8 heads of
+    64 one group's 512 lanes), and only the `jnp` form makes transposed
+    copies of x, y and their gradients."""
+    family = _family(jnp.bfloat16, layers=[4, 5, 6])
+    if impl != "kernel":
+        monkeypatch.setattr(ssd, "ssd_scan",
+                            functools.partial(ssd.ssd_scan, impl=impl))
+    params = jax.eval_shape(family.init, jax.random.key(0))
+    batch = jax.eval_shape(lambda k: family.make_batch(k, 1),
+                           jax.random.key(0))
+    jax.eval_shape(jax.grad(family.loss), params, batch)
+    metrics = bps.get_metrics()
+    assert metrics["bps_ssd_lane_block"] == 2 * 4 * 8
+    assert metrics["bps_ssd_wide_copies"] == copies
+    assert metrics["bps_ssd_scan_groups"] == 2
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_grouped_norm_is_the_norm_of_each_stretch(dtype):
+    """`_gate_norm_grouped` sums and spreads over stretches of the width
+    as the mixer has it (no reshape that would re-tile the array on the
+    chip): values and the three gradients against the norm of the array
+    reshaped to [.., groups, width / groups], which is what it was."""
+    cfg = _family(dtype, layers=[4]).cfg
+    groups, width = 4, 32
+    ks = jax.random.split(jax.random.key(5), 4)
+    y, z = (jax.random.normal(k, (2, 6, groups * width), dtype)
+            for k in ks[:2])
+    scale = 1.0 + 0.1 * jax.random.normal(ks[2], (groups * width,))
+    w = jax.random.normal(ks[3], y.shape)
+
+    def reshaped(y, z, scale):
+        gated = y * jax.nn.silu(z)
+        split = (*gated.shape[:-1], groups, width)
+        return granite_hybrid._norm(gated.reshape(split),
+                                    scale.reshape(groups, width),
+                                    cfg).reshape(gated.shape)
+
+    def value_and_grads(fn):
+        return jax.value_and_grad(
+            lambda *a: (fn(*a).astype(jnp.float32) * w).sum(),
+            argnums=(0, 1, 2))(y, z, scale)
+    want, want_grads = value_and_grads(reshaped)
+    got, got_grads = value_and_grads(
+        lambda y, z, scale: granite_hybrid._gate_norm_grouped(
+            y, z, scale, cfg, groups))
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(float(got), float(want), rtol=tol, atol=tol)
+    for a, e in zip(got_grads, want_grads):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(e, np.float32), rtol=tol,
+                                   atol=tol)
+    sums = granite_hybrid._stretch_sums(w, groups)
+    np.testing.assert_allclose(
+        np.asarray(sums), np.asarray(w.reshape(2, 6, groups, width).sum(-1)),
+        rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(
+        np.asarray(granite_hybrid._spread(sums, width)),
+        np.asarray(jnp.repeat(sums, width, axis=-1)))
 
 
 def test_the_shares_add_up_to_the_layer():

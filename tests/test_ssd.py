@@ -243,3 +243,132 @@ def test_eight_groups_and_chunks_of_128_against_the_recurrence(impl):
                                rtol=2e-5)
     assert float(jnp.linalg.norm(one_group - y) / jnp.linalg.norm(y)) > 0.5
     assert ssd._head_block(64, 8) == 8
+
+
+# (heads, head size, groups, state, chunk): the shapes at which the
+# kernels' blocks are whole tiles of the chip, 8 heads of a group a slab
+LANE_DENSE = {"p64_one_group": (8, 64, 1, 128, 64),
+              "p64_eight_groups_c128": (64, 64, 8, 128, 128),
+              "p128": (8, 128, 1, 128, 64)}
+
+
+@pytest.mark.parametrize("shape", LANE_DENSE)
+def test_lane_dense_slabs_are_the_jnp_form(shape):
+    """The layouts the kernels read at the published widths: x, y and
+    their gradients as [S, H P] with a program's 8 heads a slab of 512
+    lanes (1,024 at a head of 128, which a step of the walk takes alone),
+    B and C a group's 128 columns of [S, G N]: values and every gradient
+    against the `jnp` form, which moves its operands into a layout of its
+    own; bfloat16 values to bfloat16's rounding."""
+    h, p, g, n, chunk = LANE_DENSE[shape]
+    b, s = 1, 2 * chunk
+    assert ssd._lane_block(h, p, g, n) == 8 * p
+    ks = jax.random.split(jax.random.key(13), 7)
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (b, s, h)) - 2.0)
+    A = -jnp.exp(jax.random.uniform(ks[2], (h,), minval=0.0, maxval=2.7))
+    args = (jax.random.normal(ks[0], (b, s, h, p)), dt, A,
+            jax.random.normal(ks[3], (b, s, g, n)) / n ** 0.5,
+            jax.random.normal(ks[4], (b, s, g, n)) / n ** 0.5,
+            jax.random.normal(ks[5], (h,)))
+    w = jax.random.normal(ks[6], (b, s, h, p))
+
+    def value_and_grads(impl):
+        return jax.jit(jax.value_and_grad(
+            lambda *a: (ssd.ssd_scan(*a, chunk=chunk, impl=impl) * w).sum(),
+            argnums=range(6)))(*args)
+    with jax.default_matmul_precision("highest"):
+        value, got = value_and_grads("kernel")
+        want_value, want = value_and_grads("jnp")
+        y = ssd.ssd_scan(*args, chunk=chunk, impl="kernel")
+        y_jnp = ssd.ssd_scan(*args, chunk=chunk, impl="jnp")
+    np.testing.assert_allclose(float(value), float(want_value), rtol=2e-5)
+    for name, a, e in zip(INPUTS, got, want):
+        err = float(jnp.linalg.norm(a - e) / jnp.linalg.norm(e))
+        assert err < 2e-5, (name, err)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_jnp), atol=2e-4,
+                               rtol=2e-5)
+    low = tuple(t.astype(jnp.bfloat16) if t.ndim == 4 else t for t in args)
+    a = ssd.ssd_scan(*low, chunk=chunk, impl="kernel")
+    e = ssd.ssd_scan(*low, chunk=chunk, impl="jnp")
+    assert a.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(a, np.float32),
+                               np.asarray(e, np.float32), atol=0.25,
+                               rtol=0.05)
+
+
+@pytest.mark.parametrize("h,p,g,n,match", [
+    (32, 8, 4, 16, "8 heads of 8 make a block of 64, neither a multiple of "
+                   "128 nor the whole 256, and 32 heads are more than one "
+                   "program walks"),
+    (32, 64, 8, 128, "the rows of 4 heads make a block of 4, neither a "
+                     "multiple of 8 nor the whole 32"),
+    (32, 64, 2, 64, "a group's state of 64 make a block of 64, neither a "
+                    "multiple of 128 nor the whole 128"),
+    (12, 64, 1, 128, "12 heads a group cannot be walked in blocks of 8")],
+    ids=["lanes_of_x", "rows_of_dt", "lanes_of_a_group", "heads_a_group"])
+def test_a_block_the_chip_cannot_tile_is_refused_up_front(h, p, g, n, match):
+    """Off the interpreter the kernels refuse, before anything is lowered,
+    a block of one group's heads that is neither whole tiles of the chip
+    nor its array's whole width, where the heads are too many for one
+    program to hold them all; the interpreter takes the first three."""
+    args = (jnp.zeros((1, 32, h, p)), jnp.ones((1, 32, h)), -jnp.ones((h,)),
+            jnp.zeros((1, 32, g, n)), jnp.zeros((1, 32, g, n)),
+            jnp.zeros((h,)))
+    with pytest.raises(ValueError, match=match):
+        jax.eval_shape(lambda *a: ssd.ssd_scan(*a, chunk=16, interpret=False),
+                       *args)
+    if "cannot be walked" not in match:
+        jax.eval_shape(lambda *a: ssd.ssd_scan(*a, chunk=16, interpret=True),
+                       *args)
+
+
+@pytest.mark.parametrize("h,p,g,n,plan", [
+    (64, 64, 1, 128, (8, 1)),       # granite-4.0-h-micro: 8 heads a slab
+    (64, 64, 8, 128, (8, 1)),       # nemotron_h: a group's 8 heads
+    (8, 16, 1, 32, (8, 1)),         # the tiny granite cut: 128 lanes
+    (4, 8, 2, 16, (2, 2)),          # this file's shape: all of it a program
+    (16, 8, 2, 16, (8, 2))],        # the tiny nemotron_h cut, likewise
+    ids=["granite", "nemotron", "tiny_granite", "this_file", "tiny_nemotron"])
+def test_a_program_holds_a_groups_block_or_everything(h, p, g, n, plan):
+    """`(heads, groups)` of a kernel program, from the shapes alone: one
+    group's block of 8 heads where the chip tiles its slab, every head of
+    every group (each array's whole width a block) where it does not and
+    the heads are few; and nothing refused."""
+    assert ssd._plan(h, p, g, n) == (*plan, None)
+    assert ssd._lane_block(h, p, g, n) == plan[0] * plan[1] * p
+
+
+@pytest.mark.parametrize("dtype,heads", [(jnp.bfloat16, 8), (jnp.float32, 2)],
+                         ids=["bfloat16", "float32"])
+def test_the_forward_walk_by_dtype(dtype, heads):
+    """The forward kernel has a program's 8 heads of 64 in its text in
+    bfloat16 and walks pairs in float32, whose text Mosaic is four times
+    as long over; the backward kernel walks pairs in both."""
+    assert ssd._written_out(8, 64, dtype) == heads
+    assert ssd._tile_heads(8, 64) == 2
+
+
+def test_a_replaced_cumsum_reaches_kernels_already_traced():
+    """The kernels' calls are traced once a process and shape
+    (`_fwd_call`, `_bwd_call` under `jax.jit`); the cumulative sums are
+    taken outside them, so a `_cumsum` replaced AFTER a first call, as
+    the benchmark's broken variant replaces it, still changes the
+    result and the gradients."""
+    args, w = _inputs("mixed")
+
+    def value_and_grads():
+        return jax.value_and_grad(
+            lambda *a: (ssd.ssd_scan(*a, chunk=16, impl="kernel") * w).sum(),
+            argnums=(0, 1))(*args)
+    value, (dx, ddt) = value_and_grads()
+    size = ssd._fwd_call._cache_size(), ssd._bwd_call._cache_size()
+    again, _ = value_and_grads()
+    assert float(again) == float(value)
+    assert (ssd._fwd_call._cache_size(), ssd._bwd_call._cache_size()) == size
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(ssd, "_cumsum", lambda a: 0.5 * jnp.cumsum(a, axis=-1))
+        broken, (bx, bdt) = value_and_grads()
+    assert (ssd._fwd_call._cache_size(), ssd._bwd_call._cache_size()) == size
+    assert abs(float(broken) - float(value)) > 1e-2 * abs(float(value))
+    for a, e in ((bx, dx), (bdt, ddt)):
+        assert float(jnp.linalg.norm(a - e) / jnp.linalg.norm(e)) > 1e-2

@@ -230,31 +230,93 @@ def test_dropless_expert_layer_compiles(v5e, monkeypatch, experts, hidden,
         assert "/family.moe/" in line and ".grouped" in line
 
 
+# (positions, groups, chunk) of the two cells that run the scan, both with
+# 64 heads of 64 and a state of 128
+SCAN_CELLS = {"granite": (8192, 1, 256), "nemotron": (16384, 8, 128)}
+
+
+def _wide_moves(text: str, elements: int):
+    """The `transpose` and `copy` instructions of a compiled module, and
+    the fusions that hold one, with an operand or a result of `elements`
+    elements: a re-laid-out copy of an array of x's size."""
+    held = {m.group(1) for m in re.finditer(
+        r"^(?:ROOT )?%?(\S+) \([^\n]*\{\n(?:[^}]*\n)*?[^}\n]* "
+        r"(?:transpose|copy)\(", text, re.M)}
+    found = []
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?\S+ = .*? (transpose|copy|fusion)\(",
+                     line)
+        if not m or "tpu_custom_call" in line:
+            continue
+        if m.group(1) == "fusion":
+            calls = re.search(r"calls=%?([\w.-]+)", line)
+            if not calls or calls.group(1) not in held:
+                continue
+        sizes = [math.prod(map(int, dims.split(",")))
+                 for dims in re.findall(r"(?:bf16|f32)\[([\d,]+)\]", line)]
+        if elements in sizes:
+            found.append(line.strip()[:240])
+    return found
+
+
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
                          ids=["bfloat16", "float32"])
-def test_ssd_scan_compiles_at_granite_shapes(v5e, dtype):
-    """The chunked state-space scan of the granite-4.0-h-micro cell: one
-    sequence of 8192 positions, 64 heads of 64, one group, state 128,
-    chunks of 256; forward and backward kernels, under the names a device
-    trace tells them by."""
+@pytest.mark.parametrize("cell", SCAN_CELLS)
+def test_ssd_scan_compiles_at_the_cells_shapes(v5e, cell, dtype):
+    """The chunked state-space scan of the granite-4.0-h-micro cell (one
+    sequence of 8192 positions, one group, chunks of 256) and of the
+    nemotron_h cell (16384 positions, 8 groups, chunks of 128), 64 heads
+    of 64 and state 128 in both: forward and backward kernels, under the
+    names a device trace tells them by, given x and the groups' B and C as
+    the mixer has them ([S, 4096] and [S, G x 128]: `_mamba`'s reshapes)
+    and y read back the same way.  The kernels read those forms, so the
+    compiled module holds NO transposed copy of an array of x's size,
+    value or gradient: the test that fails if a later change brings one
+    back (until PR 48 there were six a layer, a sixth of the scan's
+    scope)."""
     from byteps_tpu.ops import ssd
+    S, G, chunk = SCAN_CELLS[cell]
     one = SingleDeviceSharding(v5e[0])
 
     def shape(*dims, dtype=jnp.float32):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
 
-    def grads(x, dt, A, B, C, D):
-        def loss(*args):
-            return ssd.ssd_scan(*args, chunk=256, impl="kernel",
-                                interpret=False).astype(jnp.float32).sum()
+    def grads(x, dt, A, B, C, D, w):
+        def loss(x, dt, A, B, C, D):
+            y = ssd.ssd_scan(
+                x.reshape(1, S, 64, 64), dt, A, B.reshape(1, S, G, 128),
+                C.reshape(1, S, G, 128), D, chunk=chunk, impl="kernel",
+                interpret=False)
+            return (y.reshape(1, S, 4096) * w).astype(jnp.float32).sum()
         return jax.grad(loss, tuple(range(6)))(x, dt, A, B, C, D)
 
-    group = shape(1, 8192, 1, 128, dtype=dtype)
-    text = _compile(grads, shape(1, 8192, 64, 64, dtype=dtype),
-                    shape(1, 8192, 64), shape(64), group, group,
-                    shape(64)).as_text()
-    assert "ssd_fwd_c256" in text and "ssd_bwd_c256" in text
+    wide = shape(1, S, 4096, dtype=dtype)
+    group = shape(1, S, G * 128, dtype=dtype)
+    text = _compile(grads, wide, shape(1, S, 64), shape(64), group, group,
+                    shape(64), wide).as_text()
+    assert f"ssd_fwd_c{chunk}" in text and f"ssd_bwd_c{chunk}" in text
     assert text.count("tpu_custom_call") == 2
+    assert not _wide_moves(text, S * 4096)
+    # and the benchmark's readers tell the two calls for what they are: a
+    # forward kernel that returned y as [1, S, 4096] beside its float32
+    # states read as a flash-attention forward call (`attn.roofline` of
+    # the nemotron_h cell 131% on the chip, PR 48)
+    from benchmark.reduce import afmoe_cost, ssd_cost
+    calls = [line for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    assert sorted(ssd_cost.scan_call(c) for c in calls) == [
+        ("backward", chunk), ("forward", chunk)]
+    assert not any(afmoe_cost.attention_call(c) for c in calls)
+
+
+def test_wide_moves_finds_a_transposed_copy(v5e):
+    """The reader of the test above, on a module that has what it looks
+    for: x handed to the scan head-major, as it was until PR 48."""
+    one = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((1, 8192, 4096), jnp.bfloat16, sharding=one)
+    text = _compile(lambda x: x.reshape(1, 8192, 64, 64).transpose(
+        0, 2, 1, 3).reshape(1, 64, 8192 * 64) * 2, x).as_text()
+    assert _wide_moves(text, 8192 * 4096)
 
 
 def test_flash_64_row_block_is_refused_up_front(v5e):
