@@ -416,7 +416,9 @@ def _gauge(name: str, help: str, cast=int) -> tuple:
 #: layer keeps both whatever the order they are traced in), and the
 #: expert layer's grouped product (`ops/grouped_matmul.py`: which path
 #: ran and on what tiles; `parallel/dropless_moe.py` adds the walk), and
-#: the attention over selected keys (`ops/sparse_attention.py`).
+#: the attention over selected keys (`ops/sparse_attention.py`), and what
+#: a model's rematerialised layers keep by name (`models/nemotron_h.py`,
+#: `models/granite_hybrid.py`: one set of gauges a `name` label).
 _STATIC = {
     "ingraph_exchange": {
         "leaves": _gauge(
@@ -564,6 +566,18 @@ _STATIC = {
             "layer (o, lse and the mask's words whole): what the remat "
             "policy \"selection\" keeps so that the forward kernel runs "
             "once", float),
+    },
+    "remat_kept": {
+        "layers": _gauge(
+            "bps_remat_kept_layers",
+            "layers of the last traced step whose `jax.checkpoint` policy "
+            "keeps the values named `name` from the forward pass "
+            "(models/nemotron_h.py, models/granite_hybrid.py)"),
+        "bytes": _gauge(
+            "bps_remat_kept_bytes",
+            "bytes those layers together hold under that name from the "
+            "forward pass to the backward pass, so that the recompute "
+            "does not make them again", float),
     },
 }
 

@@ -33,11 +33,26 @@ the streamed cross-entropy `fused_nll_sum`; with `ops/`: the flash kernels
 and the scan.
 
 No switches.  Attention is the flash kernels at the block their own rule
-picks, every layer is rematerialised whole (`jax.checkpoint`), the head
+picks, every layer is rematerialised (`jax.checkpoint`), the head
 and the cross-entropy are streamed `ce_chunk_rows` rows at a time and the
 scan is `ops/ssd.py`'s default form: one path, the one the benchmark's
 cell runs.  A sequence is a multiple of 128 positions (the flash kernels'
 tiling).
+
+What a rematerialised layer KEEPS (`KEPT_NAMES`, the policy
+`save_only_these_names` of the one `jax.checkpoint` call; no option): an
+attention layer the flash call's `o` and `lse`
+(`flash_attention.KEPT_NAME`; 34 MB a layer and sequence of 8,192 at the
+published widths), so that its recompute calls no forward kernel and the
+backward kernels read what the one call wrote; a mamba layer `in_proj`'s
+result before the split (`IN_PROJ_NAME`, which `_mamba` lays on it;
+[8192, 8512] bfloat16, 139 MB), so that its recompute starts at the
+convolution.  The runs are scanned, so a kept value is a stack over the
+run's layers from the forward pass to the backward pass
+(`bps_remat_kept_bytes{name}`: 1.26 GB for nine mamba layers, which the
+cell's 2 GB of room holds; PERF.md, Findings, PR 49).  Everything else is
+made again.  `models/nemotron_h.py` calls the same two mixers under a
+policy of its own, with its router's name added.
 
 A share of a deployment.  `layer_types` lists the layers that are run (a
 pipeline stage's) and `vocab_size` the rows of the TIED embedding held
@@ -58,13 +73,20 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..common import telemetry
-from ..ops import ssd
+from ..ops import flash_attention, ssd
 from .transformer import _rms_norm, flash_attention_fn, fused_nll_sum
 
 PyTree = Any
 MAMBA, ATTENTION = "mamba", "attention"
+# The name `in_proj`'s result carries for `jax.checkpoint` (`_mamba`): a
+# layer whose policy lists it starts its recompute at the convolution.
+IN_PROJ_NAME = "mamba.in_proj"
+# What this model's rematerialised layers keep from their forward pass, by
+# name (the module's docstring says why these and no more).
+KEPT_NAMES = (flash_attention.KEPT_NAME, IN_PROJ_NAME)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -280,7 +302,9 @@ def _mamba(x, lp, cfg, scope: str = "granite.mamba", norm_groups: int = 1):
     G, N, I = cfg.mamba_n_groups, cfg.mamba_d_state, cfg.d_inner
     with jax.named_scope(scope + ".in_proj"):
         u = _norm(x, lp["input_ln"], cfg)
-        zxbcdt = jnp.einsum("bsd,de->bse", u, lp["in_proj_w"].astype(dt))
+        zxbcdt = checkpoint_name(
+            jnp.einsum("bsd,de->bse", u, lp["in_proj_w"].astype(dt)),
+            IN_PROJ_NAME)
         z, xbc, raw = jnp.split(zxbcdt, [I, I + cfg.conv_dim], axis=-1)
     with jax.named_scope(scope + ".conv"):
         xbc = _conv(xbc, lp)
@@ -379,18 +403,40 @@ def _record_scan(cfg, batch: int, seq_len: int) -> None:
                                     chunk))
 
 
+def _record_kept(cfg, batch: int, seq_len: int, names, others=None) -> None:
+    """`bps_remat_kept_*`: what the layers' policy keeps under each of
+    `names`.  The two mixers' names are reckoned here from `cfg` (this
+    model's or `models/nemotron_h.py`'s); `others` is `{name: (layers,
+    bytes a layer)}` of a caller's own."""
+    rows, item = batch * seq_len, jnp.dtype(cfg.dtype).itemsize
+    kept = {
+        IN_PROJ_NAME: (cfg.count(MAMBA), rows * item * (
+            cfg.d_inner + cfg.conv_dim + cfg.mamba_n_heads)),
+        flash_attention.KEPT_NAME: (
+            cfg.count(ATTENTION), flash_attention.kept_bytes(
+                batch * cfg.num_heads, seq_len, cfg.head_dim, cfg.dtype)),
+        **(others or {})}
+    for name in names:
+        layers, nbytes = kept[name]
+        telemetry.record_static("remat_kept", labels={"name": name},
+                                layers=layers, bytes=layers * nbytes)
+
+
 def forward_hidden(params: PyTree, tokens: jax.Array,
                    cfg: GraniteHybridConfig) -> jax.Array:
     """tokens [B, S] int32 (ids of the held slice) -> the final hidden
     states [B, S, D], after the last norm."""
     _record_scan(cfg, *tokens.shape)
+    _record_kept(cfg, *tokens.shape, KEPT_NAMES)
     x = _embed(params, tokens, cfg)
     _, runs = _stack_plan(cfg)
 
     def period(x, run_leaves):
         for (kind, _), lps in zip(runs, run_leaves):
             layer = jax.checkpoint(
-                functools.partial(_layer, cfg=cfg, kind=kind))
+                functools.partial(_layer, cfg=cfg, kind=kind),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *KEPT_NAMES))
             x, _ = lax.scan(lambda x, lp, layer=layer: (layer(x, lp), None),
                             x, lps)
         return x, None

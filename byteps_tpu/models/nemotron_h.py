@@ -48,7 +48,7 @@ The plan by kind.  Three stacks of leaves, `params["mamba"]`, `["moe"]`,
 kind in the order they appear; the layers are walked in the published
 order, layer i of kind c taking the next set of c's leaves (a `lax.split`
 a stack, whose transpose is one concatenate: no leaf is cut or joined
-otherwise).  The walk is unrolled, each layer rematerialised whole
+otherwise).  The walk is unrolled, each layer rematerialised
 (`jax.checkpoint`): a `lax.scan` over the layers with a `lax.switch` on
 the kind would compile three bodies whatever the depth, but its backward
 pass adds a gradient the size of EVERY stack at every layer (the
@@ -61,6 +61,34 @@ rule picks (the STREAMING kernels where a head's K and V pass the
 resident budget: 16,384 positions at head size 128), every layer
 rematerialised, the head streamed `ce_chunk_rows` rows at a time.  A
 sequence is a multiple of 128 positions.
+
+What a rematerialised layer KEEPS (`KEPT_NAMES`, the policy
+`save_only_these_names` of the one `jax.checkpoint` call; no option).  A
+layer's backward pass makes the layer's values again from its input, but
+for what costs most to make again a byte, which the forward pass names
+and the layer holds until its backward pass, an array a layer (the walk
+is unrolled: no scan stacks them).  A layer and sequence of 16,384 at the
+published widths:
+
+    *   the flash call's `o` and `lse` (`flash_attention.KEPT_NAME`):
+        134 + 2 MB; the recompute calls no forward kernel, the two
+        backward kernels read what the one call wrote
+    M   `in_proj`'s result before the split (`granite_hybrid.IN_PROJ_NAME`),
+        [16384, 10304] bfloat16, 338 MB; the recompute starts at the
+        convolution (the input norm is made again: `in_proj_w`'s gradient
+        reads it)
+    E   the router's logits, `sel`, `weights`, the plan's sorted list and
+        each held expert's start and end (`dropless_moe.ROUTING_NAME`):
+        9.6 MB; no score product, top-k, sort or count a second time
+
+1.53 GB over the cell's nine layers (`bps_remat_kept_bytes{name}`), for
+43 of the 100 ms a step the recompute cost.  The rest is made again: the
+convolution, the scan's forward kernel, the gated norm, `qkv`, the
+gather and the experts' up products are 120-340 MB each a layer for less
+time a byte, in a cell with 2.3 GB left (PERF.md, Findings, PR 49).  A
+kept value is the one the forward pass made, where a
+recompute makes it from the same operands: same mathematics, and the
+same bits wherever the compiler rounds the two alike.
 
 A share of a deployment, as `afmoe.py` says it: `layer_kinds` lists the
 layers that are run (a pipeline stage's), `held_experts` the experts of
@@ -86,6 +114,7 @@ import jax
 import jax.numpy as jnp
 
 from ..common import telemetry
+from ..ops import flash_attention
 from ..parallel import dropless_moe
 from . import granite_hybrid
 from .afmoe import _unstack
@@ -96,6 +125,10 @@ MAMBA, MOE, ATTENTION = "mamba", "moe", "attention"
 # `hybrid_override_pattern`'s letters.  The family's fourth, "-" (a dense
 # MLP layer), is in no published pattern this module was written for.
 LETTERS = {"M": MAMBA, "E": MOE, "*": ATTENTION}
+# What a rematerialised layer keeps from its forward pass, by name (the
+# module's docstring says why these and no more).
+KEPT_NAMES = (flash_attention.KEPT_NAME, granite_hybrid.IN_PROJ_NAME,
+              dropless_moe.ROUTING_NAME)
 
 
 def kinds_of(pattern: str) -> Tuple[str, ...]:
@@ -308,6 +341,10 @@ def _record_plan(cfg: NemotronHConfig, batch: int, seq_len: int) -> None:
                                 layers=cfg.count(kind))
     if cfg.count(MAMBA):
         granite_hybrid._record_scan(cfg, batch, seq_len)
+    granite_hybrid._record_kept(
+        cfg, batch, seq_len, KEPT_NAMES,
+        {dropless_moe.ROUTING_NAME: (
+            cfg.count(MOE), cfg.moe.kept_bytes(batch * seq_len))})
 
 
 def forward_hidden(params: PyTree, tokens: jax.Array, cfg: NemotronHConfig,
@@ -324,8 +361,10 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg: NemotronHConfig,
     leaves = {kind: _unstack(params[kind], cfg.count(kind))
               for kind in (MAMBA, MOE, ATTENTION) if cfg.count(kind)}
     routed = []
+    keep = jax.checkpoint_policies.save_only_these_names(*KEPT_NAMES)
     for kind, j in layer_plan(cfg):
-        one = jax.checkpoint(functools.partial(_layer, cfg=cfg, kind=kind))
+        one = jax.checkpoint(functools.partial(_layer, cfg=cfg, kind=kind),
+                             policy=keep)
         x, r = one(x, leaves[kind][j],
                    sel[j] if kind == MOE and sel is not None else None)
         if kind == MOE:

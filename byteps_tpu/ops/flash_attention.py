@@ -75,6 +75,19 @@ attention kernels of its own); a TPU-native framework owns its hot ops
 (pallas guide: grid/BlockSpec tiling onto the MXU, f32 accumulation,
 custom-VJP pattern).
 
+What a rematerialised layer can keep (`KEPT_NAME`).  The forward rule
+names its two residuals that a kernel made, `o` and `lse` (`o` is the
+call's result as well, so ONE `o` a call), with `checkpoint_name`.  A
+layer under `jax.checkpoint(...,
+policy=save_only_these_names(flash_attention.KEPT_NAME))` holds them from
+its forward pass, its recompute has no consumer of the forward kernel left
+and does not call it, and the two backward kernels read what the one call
+wrote: B x H x S x (D x itemsize + 4) bytes a call.  Under any policy that
+does not list the name (None, `dots`, the transformer's `proj`, afmoe's
+`selection`) the name is an identity that lowers to nothing, and the
+compiled step is what it was.  Resident and streaming kernels alike,
+window or none.
+
 Layout: q, k, v are [BH, S, D] (batch*heads folded into the grid's first
 axis).  The block sizes must divide S; block_q must be a multiple of 128
 and block_k a multiple of 64 (`check_blocks` — the chip's lane rule, which
@@ -91,12 +104,21 @@ from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..common import telemetry
 
 NEG_INF = float("-inf")
+# The name a call's `o` and `lse` carry for `jax.checkpoint` (above).
+KEPT_NAME = "flash.attention"
+
+
+def kept_bytes(bh: int, s: int, d: int, dtype) -> int:
+    """Bytes `KEPT_NAME` names a call: `o` [bh, s, d] and `lse` [bh, 1, s]
+    float32."""
+    return bh * s * (d * jnp.dtype(dtype).itemsize + 4)
 
 # K+V (resident path) above this many bytes switch to the streaming path;
 # ~16MB VMEM/core on current TPUs, leave room for q/o/do tiles + scratch.
@@ -922,8 +944,9 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
         telemetry.record_static(
             "flash_tiles",
             **tile_schedule(s, block_q, block_k, causal, window))
-    out, lse = _fwd(q, k, v, scale, causal, block_q, block_k,
-                    _use_interpret(interpret), streaming, window)
+    out, lse = (checkpoint_name(t, KEPT_NAME) for t in _fwd(
+        q, k, v, scale, causal, block_q, block_k, _use_interpret(interpret),
+        streaming, window))
     return out, (q, k, v, out, lse)
 
 

@@ -55,6 +55,17 @@ same numbers, whose gradient is what the held experts' competition among
 themselves gives.  Under weights that sum to 1 that is the gradient the
 layer would have if each absent expert returned the weighted mean of the
 held ones the token chose: the absent experts' logits get none.
+
+What a rematerialised layer can keep (`ROUTING_NAME`).  What the router
+decided and what the plan sorted carry one name for `jax.checkpoint`: the
+score product's `logits` [T, E] float32 (the scores and the weights'
+gradient are a few elementwise passes from them), `sel` and `weights`
+[T, k], and the plan's `order`, `starts` and `ends`, integers.  A layer under
+`save_only_these_names(dropless_moe.ROUTING_NAME)` holds them from its
+forward pass and its recompute runs no score product, no top-k, no sort
+and no count a second time (`kept_bytes`: under 10 MB a layer at 16,384
+tokens and 128 experts).  Under a policy that does not list the name it is
+an identity that lowers to nothing.
 """
 
 from __future__ import annotations
@@ -67,8 +78,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import grouped_matmul as gm
+
+# The name the router's and the plan's results carry for `jax.checkpoint`
+# (the module's docstring says what a policy that lists it keeps).
+ROUTING_NAME = "moe.routing"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -109,6 +125,19 @@ class MoEConfig:
         m = self.row_multiple
         return -(-self.buffer_rows(n_tokens) // (8 * m)) * m
 
+    def sorted_rows(self, n_tokens: int) -> int:
+        """Entries of the plan's sorted list of pairs: whole buffers, the
+        first and, where the pairs pass it, the exact path's."""
+        pairs, rows = n_tokens * self.top_k, self.buffer_rows(n_tokens)
+        return max(rows, pairs + -(pairs - rows) % self.past_rows(n_tokens))
+
+    def kept_bytes(self, n_tokens: int) -> int:
+        """Bytes a layer's `ROUTING_NAME` names: the logits, `sel` and
+        `weights`, the sorted list and each held expert's start and end,
+        four bytes each."""
+        return 4 * (n_tokens * (self.num_experts + 2 * self.top_k)
+                    + self.sorted_rows(n_tokens) + 2 * len(self.held))
+
 
 class Routing(NamedTuple):
     """What the router decided, and the counters of the layer."""
@@ -128,6 +157,10 @@ def route(x, router_w, cfg: MoEConfig, expert_bias=None, sel=None):
     apart from the choice."""
     with jax.default_matmul_precision("highest"):
         logits = x.astype(jnp.float32) @ router_w.astype(jnp.float32)
+    # the product's result and not the scores: a score function's own
+    # derivative reads ITS result, whatever name is laid over that, and
+    # would have the product made again for it
+    logits = checkpoint_name(logits, ROUTING_NAME)
     if cfg.score_func == "sigmoid":
         scores = jax.nn.sigmoid(logits)
     else:
@@ -136,12 +169,13 @@ def route(x, router_w, cfg: MoEConfig, expert_bias=None, sel=None):
         biased = scores if expert_bias is None else (
             scores + lax.stop_gradient(expert_bias.astype(jnp.float32)))
         _, sel = lax.top_k(lax.stop_gradient(biased), cfg.top_k)
+    sel = checkpoint_name(sel, ROUTING_NAME)
     weights = jnp.take_along_axis(scores, sel, axis=-1)
     if cfg.route_norm:
         weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
     if cfg.hold_held_weight and len(cfg.held) < cfg.num_experts:
         weights = _held_weight_held(weights, sel, cfg)
-    return sel, weights * cfg.route_scale
+    return sel, checkpoint_name(weights * cfg.route_scale, ROUTING_NAME)
 
 
 def _held_weight_held(weights, sel, cfg: MoEConfig):
@@ -193,10 +227,10 @@ class _Plan(NamedTuple):
     held_rows: jax.Array  # ()
 
 
-def _plan(sel, cfg: MoEConfig, rows: int, past: int) -> _Plan:
+def _plan(sel, cfg: MoEConfig) -> _Plan:
     """Sorts the pairs by the held expert they chose, pairs of experts
-    held elsewhere last, and pads the list to whole buffers: the first of
-    `rows`, the exact path's of `past` each."""
+    held elsewhere last, and pads the list to whole buffers
+    (`MoEConfig.sorted_rows`)."""
     n_held = len(cfg.held)
     slot_of = np.full((cfg.num_experts,), n_held, np.int32)
     slot_of[list(cfg.held)] = np.arange(n_held)
@@ -205,10 +239,12 @@ def _plan(sel, cfg: MoEConfig, rows: int, past: int) -> _Plan:
     counts = (slot[:, None] == jnp.arange(n_held, dtype=jnp.int32)).sum(
         0, dtype=jnp.int32)
     ends = jnp.cumsum(counts)
-    pad = (rows - order.size if order.size <= rows
-           else -(order.size - rows) % past)
-    order = jnp.concatenate([order, jnp.zeros((pad,), jnp.int32)])
-    return _Plan(order, ends - counts, ends, ends[-1])
+    pad = cfg.sorted_rows(sel.shape[0]) - order.size
+    order, starts, ends = (
+        checkpoint_name(t, ROUTING_NAME) for t in (
+            jnp.concatenate([order, jnp.zeros((pad,), jnp.int32)]),
+            ends - counts, ends))
+    return _Plan(order, starts, ends, ends[-1])
 
 
 def _buffer(lo, x, experts, flat_w, plan: _Plan, k: int, rows: int):
@@ -298,7 +334,7 @@ def held_experts(x, router_w, experts, cfg: MoEConfig, expert_bias=None,
     # reads `<family>.moe/route`.
     with jax.named_scope(".route"):
         sel, weights = route(x, router_w, cfg, expert_bias, sel)
-        plan = _plan(sel, cfg, rows, past)
+        plan = _plan(sel, cfg)
         flat_w = weights.reshape(-1)
     out = _buffer(jnp.int32(0), x, experts, flat_w, plan, k, rows)
     if plan.order.size > rows:

@@ -106,11 +106,13 @@ def test_exact_path_takes_small_buffers(kind, passes):
     rows past it, 8 at a time, not for a second whole buffer."""
     rows, past = CFG.buffer_rows(T), CFG.past_rows(T)
     assert (rows, past) == (64, 8)
-    plan = dm._plan(_forced(kind), CFG, rows, past)
+    plan = dm._plan(_forced(kind), CFG)
     assert plan.order.size == T * K == rows + 40 * past
+    assert plan.order.size == CFG.sorted_rows(T)
     assert int(dm._past_buffers(rows, past, plan)) == passes
-    odd = dm._plan(_forced(kind)[:T - 1], CFG, rows, past)
+    odd = dm._plan(_forced(kind)[:T - 1], CFG)
     assert (odd.order.size - rows) % past == 0 and odd.order.size >= 380
+    assert odd.order.size == CFG.sorted_rows(T - 1)
 
 
 def test_own_routing_matches_plain_and_counts():

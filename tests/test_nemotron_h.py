@@ -26,8 +26,9 @@ from benchmark.harness import correct, manifest
 from benchmark.reference import nemotronh as reference
 from benchmark.tests import tiny_nemotronh
 from byteps_tpu.models import granite_hybrid, nemotron_h
-from byteps_tpu.ops import ssd
+from byteps_tpu.ops import flash_attention, ssd
 from byteps_tpu.parallel import dropless_moe
+from testutil import eqns, is_flash_forward, is_product, named_bytes
 
 _family, _agreement = tiny_nemotronh.family, tiny_nemotronh.agreement
 PUBLISHED = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
@@ -125,6 +126,84 @@ def test_a_traced_step_leaves_the_plans_gauges_and_the_models_scopes():
     assert metrics["bps_ssd_scan_groups"] == 2
     assert metrics["bps_ssd_scan_layers"] == 4
     assert metrics["bps_ssd_chunk"] == 64
+
+
+@pytest.mark.parametrize("again", [1, 2],
+                         ids=["kept_by_name", "a_plain_checkpoint"])
+def test_a_recompute_makes_again_only_what_its_layer_does_not_keep(
+        monkeypatch, again):
+    """In the differentiated step, a call for each time it runs: a *
+    layer's flash forward kernel, an M layer's `in_proj` product, an E
+    layer's score product, top-k and sort are there ONCE a layer, where
+    the same walk under a plain `jax.checkpoint` (a policy of no name)
+    shows each twice.  What is not kept is made again either way: the
+    scan's forward kernel."""
+    family = _family(jnp.float32)
+    cfg = family.cfg
+    if again == 2:
+        monkeypatch.setattr(nemotron_h, "KEPT_NAMES", ())
+    params = jax.eval_shape(family.init, jax.random.key(0))
+    batch = jax.eval_shape(lambda k: family.make_batch(k, 1),
+                           jax.random.key(0))
+    B, S, D = 1, family.seq_len, cfg.hidden_size
+    wide = cfg.d_inner + cfg.conv_dim + cfg.mamba_n_heads
+    step = list(eqns(jax.make_jaxpr(jax.grad(family.loss))(params,
+                                                           batch).jaxpr))
+
+    def count(found):
+        return sum(map(found, step))
+    a_layer = {
+        nemotron_h.ATTENTION: [is_flash_forward],
+        nemotron_h.MAMBA: [lambda e: is_product(e, (B, S, D), (D, wide))],
+        nemotron_h.MOE: [
+            lambda e: is_product(e, (B * S, D), (D, cfg.num_experts)),
+            lambda e: e.primitive.name == "top_k",
+            lambda e: e.primitive.name == "sort"],
+    }
+    for kind, made in a_layer.items():
+        assert [count(m) for m in made] == [again * cfg.count(kind)] * len(
+            made), kind
+    assert count(lambda e: e.primitive.name == "pallas_call"
+                 and e.params["name"] == "ssd_fwd_c64") == 2 * cfg.count(
+                     nemotron_h.MAMBA)
+
+
+def test_remat_kept_says_what_the_names_hold():
+    """`bps_remat_kept_*{name}`: the layers that keep a name and the bytes
+    they hold together, which are the bytes of what `checkpoint_name`
+    names in the traced step; at the cell's shapes `o` and `lse` of the *
+    layer (134 + 2 MB), four M layers' `in_proj` results ([16384, 10304]
+    bfloat16, 338 MB each) and four E layers' routing (9.6 MB each):
+    1.53 GB."""
+    family = _family(jnp.bfloat16)
+    params = jax.eval_shape(family.init, jax.random.key(0))
+    batch = jax.eval_shape(lambda k: family.make_batch(k, 1),
+                           jax.random.key(0))
+    named = named_bytes(jax.make_jaxpr(family.loss)(params, batch).jaxpr)
+    metrics = bps.get_metrics()
+    layers = {flash_attention.KEPT_NAME: 1, granite_hybrid.IN_PROJ_NAME: 4,
+              dropless_moe.ROUTING_NAME: 4}
+    assert set(nemotron_h.KEPT_NAMES) == set(layers) == set(named)
+    for name, n in layers.items():
+        assert metrics[f'bps_remat_kept_layers{{name="{name}"}}'] == n
+        assert metrics[f'bps_remat_kept_bytes{{name="{name}"}}'] == named[
+            name], name
+
+    with open(os.path.join(manifest.BENCH, "configs",
+                           tiny_nemotronh.NAME + ".json")) as f:
+        config = json.load(f)
+    family = family_nemotronh.Family(config, config["job"])
+    jax.eval_shape(family.loss,
+                   jax.eval_shape(family.init, jax.random.key(0)),
+                   jax.eval_shape(lambda k: family.make_batch(k, 1),
+                                  jax.random.key(0)))
+    metrics = bps.get_metrics()
+    assert [metrics[f'bps_remat_kept_bytes{{name="{name}"}}']
+            for name in layers] == [
+        32 * 16384 * (128 * 2 + 4), 4 * 16384 * 10304 * 2,
+        4 * 4 * (16384 * (128 + 2 * 6) + 98816 + 2 * 8)]
+    assert 32 * 16384 * 260 + 4 * 337_641_472 + 4 * 9_570_368 \
+        == 1_525_162_240
 
 
 @pytest.mark.parametrize("impl,copies", [("kernel", 0), ("jnp", 4)])

@@ -668,11 +668,11 @@ def test_nemotronh_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch,
         assert "ragged-dot-metadata" not in text
         assert not scans and not flash
     else:
-        # a layer's forward kernel, again under remat, and its backward
+        # the scan's forward kernel, again under remat, and its backward;
+        # the flash forward kernel ONCE: the layer keeps its `o` and `lse`
         assert scans == [("backward", 128)] + [("forward", 128)] * 2
         assert "f32[1,64,128,64,128]" in text       # 128 chunks of state
         assert flash == [("dkv", 32, 16384, 128), ("dq", 32, 16384, 128),
-                         ("forward", 32, 16384, 128),
                          ("forward", 32, 16384, 128)]
         assert not grouped
     mem = compiled.memory_analysis()

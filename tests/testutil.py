@@ -18,6 +18,58 @@ def cpu_env(extra=None):
     return cpu_subprocess_env(extra)
 
 
+def tiny_gpt2_config() -> dict:
+    """The gpt2-medium cell's configuration at the benchmark's tiny
+    widths."""
+    import json
+
+    from benchmark.harness import manifest
+    from benchmark.tests import tiny
+    with open(os.path.join(manifest.BENCH, "configs",
+                           "gpt2-medium.json")) as f:
+        return tiny.tiny_config(json.load(f))
+
+
+def eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs among its equations'
+    parameters, each as often as it is written (a scan's body once)."""
+    import jax
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from eqns(sub)
+
+
+def is_flash_forward(eqn) -> bool:
+    """A flash attention forward kernel's call: the Pallas call, unnamed
+    or `flash_fwd_w<window>`, that writes `o` [bh, s, d] and `lse`
+    [bh, 1, s] (dq writes one array, dK/dV two of one shape)."""
+    if eqn.primitive.name != "pallas_call":
+        return False
+    name = eqn.params.get("name")
+    out = [v.aval.shape for v in eqn.outvars]
+    return ((name is None or name.startswith("flash_fwd"))
+            and len(out) == 2 and len(out[0]) == 3
+            and out[1] == (out[0][0], 1, out[0][1]))
+
+
+def is_product(eqn, *shapes) -> bool:
+    """A `dot_general` of operands of exactly these shapes."""
+    return (eqn.primitive.name == "dot_general"
+            and tuple(v.aval.shape for v in eqn.invars) == shapes)
+
+
+def named_bytes(jaxpr) -> dict:
+    """`{name: bytes}` of what `checkpoint_name` names in a jaxpr."""
+    out = {}
+    for eqn in eqns(jaxpr):
+        if eqn.primitive.name == "name":
+            v = eqn.outvars[0].aval
+            out[eqn.params["name"]] = (out.get(eqn.params["name"], 0)
+                                       + v.size * v.dtype.itemsize)
+    return out
+
+
 class StubPSServer:
     """Minimal in-thread PS-protocol stub for wire tests.
 
