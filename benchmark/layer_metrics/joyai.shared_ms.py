@@ -1,0 +1,11 @@
+"""Expert layer: milliseconds of a step under the `shared` child of
+`joyai.moe`, every pass, the main stack's expert layers: the shared
+expert's three dense products (width 768) on every token, which no
+routing thins out.  From the program's map of its step
+(`benchmark/reduce/scopes.py`).  Source: program span."""
+
+from benchmark.reduce import scopes
+
+
+def read(ctx):
+    return scopes.scope_ms(ctx, r"^joyai\.moe$", children=("shared",))

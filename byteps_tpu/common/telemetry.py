@@ -579,6 +579,17 @@ _STATIC = {
             "forward pass to the backward pass, so that the recompute "
             "does not make them again", float),
     },
+    "loss_terms": {
+        "weight": _gauge(
+            "bps_loss_term_weight",
+            "weight of the term `loss` (`main`, `mtp`) in the last traced "
+            "step's loss: a model with a second prediction head adds two "
+            "streamed cross-entropies a step (models/joyai.py)", float),
+        "positions": _gauge(
+            "bps_loss_term_positions",
+            "positions that term's mean is over: the second head's last "
+            "position of a sequence has no target"),
+    },
 }
 
 

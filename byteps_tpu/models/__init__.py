@@ -13,9 +13,10 @@ decoders the benchmark runs as one chip's share of a deployment, `afmoe`
 `mellum` (sparse experts in every layer, windowed and YaRN full
 attention), `keye` (attention over the keys a learned indexer selects,
 rotary positions in three streams), `granite_hybrid` (state-space
-scans beside attention) and `nemotron_h` (layers that are one part each:
+scans beside attention), `nemotron_h` (layers that are one part each:
 a Mamba-2 mixer in 8 groups, squared-ReLU experts, grouped attention;
-stacked by kind).  Their attention calls come from one table,
+stacked by kind) and `joyai` (latent attention, a sigmoid router with a
+shared expert behind one dense layer, a multi-token-prediction module).  Their attention calls come from one table,
 `afmoe._ATTENTION`: `sliding_attention`, `full_attention`,
 `selected_attention`.
 """

@@ -340,6 +340,7 @@ def _layer(x, lp, sel, cfg: AfmoeConfig, kind: str, is_moe: bool):
 def _remat(fn, cfg):
     if not cfg.remat:
         return fn
+    from ..ops import flash_attention
     from ..ops.sparse_attention import KEPT_NAMES
     policies = {
         "none": None,
@@ -352,6 +353,11 @@ def _remat(fn, cfg):
         # selects nor calls the forward kernel again
         "selection": jax.checkpoint_policies.save_only_these_names(
             *KEPT_NAMES),
+        # what the layers of `models/nemotron_h.py` keep of an attention
+        # and an expert part: the flash call's `o` and `lse` and the
+        # router's choice (`models/joyai.py`, whose layers are both)
+        "kernels": jax.checkpoint_policies.save_only_these_names(
+            flash_attention.KEPT_NAME, dropless_moe.ROUTING_NAME),
     }
     if cfg.remat_policy not in policies:
         raise ValueError(f"remat_policy={cfg.remat_policy!r}; options: "
@@ -393,7 +399,18 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg,
     that family's, with the fields `_stack_plan` and `_remat` read, and
     `family` the prefix of its scopes (`<family>.head` holds the last norm
     here and the head in `loss_fn`)."""
-    x = embed(params, tokens, cfg)
+    x, routings = run_layers(params, embed(params, tokens, cfg), cfg, sel,
+                             with_routing, layer)
+    with jax.named_scope(family + ".head"):
+        x = _rms_norm(x, params["final_ln"], None, eps=cfg.rms_norm_eps)
+    return (x, routings) if with_routing else x
+
+
+def run_layers(params: PyTree, x: jax.Array, cfg, sel=None,
+               with_routing: bool = False, layer=_layer):
+    """The layers alone, period by period: x [B, S, D] from the embedding
+    -> `(x after the last layer, BEFORE the final norm; Routing or
+    None)`, the arguments `forward_hidden`'s."""
     routings = None
     for key, kinds, periods in _stack_plan(cfg):
         is_moe = key == "moe"
@@ -421,9 +438,7 @@ def forward_hidden(params: PyTree, tokens: jax.Array, cfg,
         if is_moe and with_routing:
             routings = jax.tree.map(
                 lambda a: a.reshape(periods * p, *a.shape[2:]), r)
-    with jax.named_scope(family + ".head"):
-        x = _rms_norm(x, params["final_ln"], None, eps=cfg.rms_norm_eps)
-    return (x, routings) if with_routing else x
+    return x, routings
 
 
 def head_logits(x: jax.Array, head: jax.Array) -> jax.Array:

@@ -413,8 +413,10 @@ def flash_auto_block(S: int, causal: bool = False) -> int:
 def flash_attention_fn(q, k, v, causal: bool, block: int = 0,
                        block_k: int = 0, window: Optional[int] = None):
     """Adapter: [B, H, S, Dh] heads-layout -> the Pallas flash-attention
-    kernel's [BH, S, Dh] layout.  A shape the kernel cannot tile (S not a
-    multiple of 128, or Dh not a multiple of 8) raises ValueError: an
+    kernel's [BH, S, Dh] layout; `v` may be another width than `q` and
+    `k` (latent attention's 192 and 128), and the result is as wide as
+    `v`.  A shape the kernel cannot tile (S not a
+    multiple of 128, or a width not a multiple of 8) raises ValueError: an
     explicit flash request never silently runs dense attention, which
     would materialize the S x S logits the caller chose flash to avoid
     and attribute dense throughput to a flash config.
@@ -432,25 +434,26 @@ def flash_attention_fn(q, k, v, causal: bool, block: int = 0,
     from ..ops.flash_attention import (BLOCK_K_MULTIPLE, BLOCK_Q_MULTIPLE,
                                        flash_attention)
     B, H, S, Dh = q.shape
+    Dv = v.shape[-1]
     auto_q, auto_k = flash_auto_tiles(S, causal)
     if not block or S % block or block % BLOCK_Q_MULTIPLE:
         block = 0
     if not block_k or S % block_k or block_k % BLOCK_K_MULTIPLE:
         block_k = block or auto_k
     block = block or auto_q
-    if block == 0 or Dh % 8:
+    if block == 0 or Dh % 8 or Dv % 8:
         raise ValueError(
             f"flash attention needs seq_len divisible by "
             f"{BLOCK_Q_MULTIPLE} (got {S}) and head_dim a multiple of 8 "
-            f"(got {Dh}); pad the sequence or ask for attn='dense' "
-            f"explicitly")
+            f"(got {Dh if Dh % 8 else Dv}); pad the sequence or ask for "
+            f"attn='dense' explicitly")
 
     def fold(t):
-        return t.reshape(B * H, S, Dh)
+        return t.reshape(B * H, S, t.shape[-1])
     windowed = () if window is None else (None, None, window)
     out = flash_attention(fold(q), fold(k), fold(v), causal, None,
                           block, block_k, *windowed)
-    return out.reshape(B, H, S, Dh)
+    return out.reshape(B, H, S, Dv)
 
 
 _ATTN_IMPLS = {"dense": dense_attention, "flash": flash_attention_fn}
@@ -611,11 +614,14 @@ def forward(params: PyTree, tokens: jax.Array, cfg: TransformerConfig,
 
 
 def fused_nll_sum(x: jax.Array, embed: jax.Array, targets: jax.Array,
-                  chunk_rows: int) -> jax.Array:
+                  chunk_rows: int, weights=None) -> jax.Array:
     """Streamed weight-tied LM cross-entropy: SUM of per-row NLL without
     ever materializing the full [B*S, vocab] logits.  (Callers divide by
     their own token count — the hybrid shard_map step normalizes by the
-    GLOBAL count across mesh axes.)
+    GLOBAL count across mesh axes.)  `weights` [B, S] multiplies each
+    row's NLL (0 for a position without a target: a second prediction
+    head's last positions, `models/joyai.py`); None is 1 everywhere and
+    the program it always was.
 
     Rows are processed in `chunk_rows`-sized chunks under `lax.scan`; each
     chunk computes its logits (activation-dtype matmul, f32 accumulation),
@@ -639,8 +645,9 @@ def fused_nll_sum(x: jax.Array, embed: jax.Array, targets: jax.Array,
     if pad:
         xs = jnp.concatenate([xs, jnp.zeros((pad, D), xs.dtype)])
         ts = jnp.concatenate([ts, jnp.zeros((pad,), ts.dtype)])
-    w = jnp.concatenate([jnp.ones((N,), jnp.float32),
-                         jnp.zeros((pad,), jnp.float32)])
+    rows_w = (jnp.ones((N,), jnp.float32) if weights is None
+              else weights.reshape(N).astype(jnp.float32))
+    w = jnp.concatenate([rows_w, jnp.zeros((pad,), jnp.float32)])
     nc = (N + pad) // C
     emb = embed.astype(x.dtype)
 

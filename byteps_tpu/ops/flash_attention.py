@@ -89,7 +89,8 @@ compiled step is what it was.  Resident and streaming kernels alike,
 window or none.
 
 Layout: q, k, v are [BH, S, D] (batch*heads folded into the grid's first
-axis).  The block sizes must divide S; block_q must be a multiple of 128
+axis); q and k may be one width and v, o and dO another (`flash_attention`
+says how).  The block sizes must divide S; block_q must be a multiple of 128
 and block_k a multiple of 64 (`check_blocks` — the chip's lane rule, which
 interpret mode does not enforce); D should be a multiple of 8.  A shape that doesn't satisfy the constraints is
 refused up front, on every backend: the kernel never degrades to dense
@@ -116,8 +117,8 @@ KEPT_NAME = "flash.attention"
 
 
 def kept_bytes(bh: int, s: int, d: int, dtype) -> int:
-    """Bytes `KEPT_NAME` names a call: `o` [bh, s, d] and `lse` [bh, 1, s]
-    float32."""
+    """Bytes `KEPT_NAME` names a call: `o` [bh, s, d] (`d` the VALUE
+    heads' width, where the keys' differs) and `lse` [bh, 1, s] float32."""
     return bh * s * (d * jnp.dtype(dtype).itemsize + 4)
 
 # K+V (resident path) above this many bytes switch to the streaming path;
@@ -158,11 +159,14 @@ def _use_interpret(interpret: Optional[bool]) -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _use_streaming(q, streaming: Optional[bool]) -> bool:
+def _use_streaming(k, streaming: Optional[bool], v=None) -> bool:
+    """Whether a head's K [S, Dk] and V [S, Dv] (None: as wide as K)
+    together pass the resident budget."""
     if streaming is not None:
         return streaming
-    _bh, s, d = q.shape
-    return 2 * s * d * q.dtype.itemsize > RESIDENT_VMEM_BUDGET
+    _bh, s, dk = k.shape
+    dv = dk if v is None else v.shape[2]
+    return s * (dk + dv) * k.dtype.itemsize > RESIDENT_VMEM_BUDGET
 
 
 def _pick(ints, traced, a, b):
@@ -733,6 +737,11 @@ def _lse_spec(block_q):
     return pl.BlockSpec((1, 1, block_q), lambda b, i, *_: (b, 0, i))
 
 
+def _whole_spec(s, d):
+    """A head's whole [S, d] operand, resident for every program of it."""
+    return pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0))
+
+
 def _walk_specs(walk, rows, d, column):
     """`(wide, lanes)` BlockSpecs over a streaming call's grid: blocks of
     `rows` of a [BH, S, D] operand and of a per-row statistic [BH, 1, S],
@@ -773,48 +782,57 @@ def _walk_call(kernel, walk, bh, in_specs, out_specs, scratch_shapes,
     return functools.partial(call, *columns)
 
 
-def _windowed(window, kind):
-    """The extra keywords of a windowed call, for the kernel and for
-    `pallas_call`: the window, and a name that says the kind of call and
-    the window (`flash_fwd_w2048`), which is how a device trace tells a
-    sliding layer's calls from a full layer's.  None for `window=None`,
-    which leaves those calls exactly as they were."""
-    if window is None:
-        return {}, {}
-    return {"window": window}, {"name": f"flash_{kind}_w{window}"}
+def _windowed(window, kind, dk, dv):
+    """The extra keywords of a call that is not the plain one, for the
+    kernel and for `pallas_call`: the window, and a name that says the
+    kind of call, the window (`flash_fwd_w2048`) and, where the keys'
+    width `dk` is not the values' `dv`, both (`flash_fwd_d192x128`),
+    which is how a device trace tells a sliding layer's calls from a full
+    layer's and a latent-attention call from either.  Nothing for
+    `window=None` and one width, which leaves those calls exactly as they
+    were."""
+    kw = {} if window is None else {"window": window}
+    name = f"flash_{kind}"
+    if dk != dv:
+        name += f"_d{dk}x{dv}"
+    if window is not None:
+        name += f"_w{window}"
+    return kw, ({} if name == f"flash_{kind}" else {"name": name})
 
 
 def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, streaming,
          window=None):
     bh, s, d = q.shape
-    kw, named = _windowed(window, "fwd")
-    out_shape = [jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+    dv = v.shape[2]             # o is as wide as v; q and k share `d`
+    kw, named = _windowed(window, "fwd", d, dv)
+    out_shape = [jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
                  jax.ShapeDtypeStruct((bh, 1, s), jnp.float32)]
     if streaming:
         walk = stream_walk(s, block_q, block_k, causal, window)
         rows, rows_lanes = _walk_specs(walk, block_q, d, 0)
+        rows_v, _ = _walk_specs(walk, block_q, dv, 0)
         keys, _ = _walk_specs(walk, block_k, d, 1)
+        keys_v, _ = _walk_specs(walk, block_k, dv, 1)
         return _walk_call(
             functools.partial(_fwd_kernel_str, sm_scale=sm_scale,
                               causal=causal, block_q=block_q,
                               block_k=block_k, **kw),
-            walk, bh, in_specs=[rows, keys, keys],
-            out_specs=[rows, rows_lanes],
+            walk, bh, in_specs=[rows, keys, keys_v],
+            out_specs=[rows_v, rows_lanes],
             scratch_shapes=[
                 pltpu.VMEM((block_q, 128), jnp.float32),  # running max m
                 pltpu.VMEM((block_q, 128), jnp.float32),  # running sum l
-                pltpu.VMEM((block_q, d), jnp.float32),   # accumulator
+                pltpu.VMEM((block_q, dv), jnp.float32),  # accumulator
             ],
             out_shape=out_shape, interpret=interpret, **named,
         )(q, k, v)
-    kv_spec = pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0))
     rows = _program_rows(s, block_q, block_k)
     return pl.pallas_call(
         functools.partial(_fwd_kernel_res, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_len=s, **kw),
         grid=(bh, s // rows),
-        in_specs=[_q_spec(rows, d), kv_spec, kv_spec],
-        out_specs=[_q_spec(rows, d), _lse_spec(rows)],
+        in_specs=[_q_spec(rows, d), _whole_spec(s, d), _whole_spec(s, dv)],
+        out_specs=[_q_spec(rows, dv), _lse_spec(rows)],
         out_shape=out_shape,
         interpret=interpret, **named,
     )(q, k, v)
@@ -825,21 +843,24 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, streaming,
     q, k, v, o, lse = residuals
     do = g
     bh, s, d = q.shape
-    kw, dq_named = _windowed(window, "dq")
-    dkv_named = _windowed(window, "dkv")[1]
+    dv = v.shape[2]             # v, o, do and dV; q, k, dQ and dK are `d`
+    kw, dq_named = _windowed(window, "dq", d, dv)
+    dkv_named = _windowed(window, "dkv", d, dv)[1]
     # delta_i = rowsum(dO_i * O_i): tiny elementwise pass, XLA fuses it.
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None, :]                 # (bh, 1, s)
     if streaming:
         walk = stream_walk(s, block_q, block_k, causal, window)
         rows, rows_lanes = _walk_specs(walk, block_q, d, 0)
+        rows_v, _ = _walk_specs(walk, block_q, dv, 0)
         keys, _ = _walk_specs(walk, block_k, d, 1)
+        keys_v, _ = _walk_specs(walk, block_k, dv, 1)
         dq = _walk_call(
             functools.partial(_dq_kernel_str, sm_scale=sm_scale,
                               causal=causal, block_q=block_q,
                               block_k=block_k, **kw),
             walk, bh,
-            in_specs=[rows, keys, keys, rows, rows_lanes, rows_lanes],
+            in_specs=[rows, keys, keys_v, rows_v, rows_lanes, rows_lanes],
             out_specs=rows,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
             out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
@@ -848,48 +869,49 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, streaming,
         # the same square by its keys: a program owns a block of keys
         walk = stream_walk(s, block_q, block_k, causal, window, by_keys=True)
         keys, _ = _walk_specs(walk, block_k, d, 0)
+        keys_v, _ = _walk_specs(walk, block_k, dv, 0)
         rows, rows_lanes = _walk_specs(walk, block_q, d, 1)
-        dk, dv = _walk_call(
+        rows_v, _ = _walk_specs(walk, block_q, dv, 1)
+        dk, dv_ = _walk_call(
             functools.partial(_dkv_kernel_str, sm_scale=sm_scale,
                               causal=causal, block_q=block_q,
                               block_k=block_k, **kw),
             walk, bh,
-            in_specs=[rows, keys, keys, rows, rows_lanes, rows_lanes],
-            out_specs=[keys, keys],
+            in_specs=[rows, keys, keys_v, rows_v, rows_lanes, rows_lanes],
+            out_specs=[keys, keys_v],
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                            pltpu.VMEM((block_k, d), jnp.float32)],
+                            pltpu.VMEM((block_k, dv), jnp.float32)],
             out_shape=[jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-                       jax.ShapeDtypeStruct((bh, s, d), v.dtype)],
+                       jax.ShapeDtypeStruct((bh, s, dv), v.dtype)],
             interpret=interpret, **dkv_named,
         )(q, k, v, do, lse, delta)
-        return dq, dk, dv
+        return dq, dk, dv_
 
-    full_spec2 = pl.BlockSpec((1, s, d), lambda b, i: (b, 0, 0))
     full_lse2 = pl.BlockSpec((1, 1, s), lambda b, i: (b, 0, 0))
     rows = _program_rows(s, block_q, block_k)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel_res, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_len=s, **kw),
         grid=(bh, s // rows),
-        in_specs=[_q_spec(rows, d), full_spec2, full_spec2,
-                  _q_spec(rows, d), _lse_spec(rows), _lse_spec(rows)],
+        in_specs=[_q_spec(rows, d), _whole_spec(s, d), _whole_spec(s, dv),
+                  _q_spec(rows, dv), _lse_spec(rows), _lse_spec(rows)],
         out_specs=_q_spec(rows, d),
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         interpret=interpret, **dq_named,
     )(q, k, v, do, lse, delta)
     keys = _program_rows(s, block_q, dkv_tile(block_k))
-    kb2 = pl.BlockSpec((1, keys, d), lambda b, i: (b, i, 0))
-    dk, dv = pl.pallas_call(
+    dk, dv_ = pl.pallas_call(
         functools.partial(_dkv_kernel_res, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, seq_len=s, **kw),
         grid=(bh, s // keys),
-        in_specs=[full_spec2, kb2, kb2, full_spec2, full_lse2, full_lse2],
-        out_specs=[kb2, kb2],
+        in_specs=[_whole_spec(s, d), _q_spec(keys, d), _q_spec(keys, dv),
+                  _whole_spec(s, dv), full_lse2, full_lse2],
+        out_specs=[_q_spec(keys, d), _q_spec(keys, dv)],
         out_shape=[jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, s, d), v.dtype)],
+                   jax.ShapeDtypeStruct((bh, s, dv), v.dtype)],
         interpret=interpret, **dkv_named,
     )(q, k, v, do, lse, delta)
-    return dq, dk, dv
+    return dq, dk, dv_
 
 
 # ---------------------------------------------------------------------------
@@ -902,7 +924,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     interpret: Optional[bool] = None,
                     streaming: Optional[bool] = None,
                     window: Optional[int] = None) -> jax.Array:
-    """Blockwise (flash) attention.  q, k, v: [BH, S, D] -> [BH, S, D].
+    """Blockwise (flash) attention.  q, k: [BH, S, Dk]; v: [BH, S, Dv]
+    -> [BH, S, Dv].  One width, Dk = Dv, is the call every model but one
+    makes, and compiles to what it always did.  Where the two differ
+    (latent attention: a query and a key are a 128-wide part and a 64-wide
+    rotary part side by side, 192, a value 128) every operand keeps its
+    own width in HBM, a tile's QK product is one of depth Dk, its PV
+    product one of width Dv, and the three kernels carry both widths in
+    their names (`flash_fwd_d192x128`).
 
     `block_q` is the rows of a group (in dK/dV: the keys of one), each
     with its own bounds, and sets what is computed above a causal
@@ -915,10 +944,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     (resident: never looped over; streaming: not in the grid).
     `window=None` leaves the calls unnamed.
 
-    sm_scale defaults to 1/sqrt(D).  interpret=None auto-selects the
+    sm_scale defaults to 1/sqrt(Dk).  interpret=None auto-selects the
     Pallas interpreter off-TPU so tests run on the CPU mesh.
-    streaming=None auto-selects: K/V-resident kernels while 2*S*D fits the
-    VMEM budget (fastest — K/V fetched once per batch*head), 3D-grid
+    streaming=None auto-selects: K/V-resident kernels while S*(Dk+Dv) fits
+    the VMEM budget (fastest — K/V fetched once per batch*head), 3D-grid
     streaming kernels beyond (O(block*D) VMEM at any S).
     """
     out, _ = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k,
@@ -930,11 +959,15 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
                streaming, window=None):
     bh, s, d = q.shape
     check_blocks(s, block_q, block_k)
+    if k.shape != q.shape or v.shape[:2] != q.shape[:2]:
+        raise ValueError(f"flash attention: q {q.shape}, k {k.shape}, v "
+                         f"{v.shape}: q and k share a shape, v their "
+                         f"first two dims")
     if window is not None and (not causal or window < 1):
         raise ValueError(f"window={window} needs causal=True and at least "
                          f"one key a row")
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    streaming = _use_streaming(q, streaming)
+    streaming = _use_streaming(k, streaming, v)
     if streaming:
         telemetry.record_static(
             "flash_stream",
@@ -954,9 +987,9 @@ def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, streaming,
                window, residuals, g):
     d = residuals[0].shape[-1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
+    k, v = residuals[1:3]
     return _bwd(scale, causal, block_q, block_k, _use_interpret(interpret),
-                _use_streaming(residuals[0], streaming), residuals, g,
-                window)
+                _use_streaming(k, streaming, v), residuals, g, window)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

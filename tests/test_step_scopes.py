@@ -25,6 +25,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 import byteps_tpu as bps
 from benchmark.harness import measure
 from benchmark.tests import (tiny, tiny_afmoe, tiny_granitehybrid,  # noqa: F401
+                             tiny_joyai,
                              tiny_keye, tiny_mellum,    # join `tiny`'s table
                              tiny_nemotronh)
 from byteps_tpu.common import devprof
@@ -80,6 +81,16 @@ FAMILIES = {
                    "nemotronh.moe/grouped", "nemotronh.moe/scatter",
                    "nemotronh.moe/exact", "nemotronh.moe/shared",
                    "nemotronh.head", "byteps.optimizer"}, True),
+    # the prediction module's layer opens the layer's scopes UNDER its own
+    "joyai": ("joyai-llm-flash.ingraph-1chip",
+              {"joyai.embed", "joyai.attn", "joyai.attn/qkv",
+               "joyai.attn/out", "joyai.dense", "joyai.moe",
+               "joyai.moe/route", "joyai.moe/gather", "joyai.moe/grouped",
+               "joyai.moe/scatter", "joyai.moe/exact", "joyai.moe/shared",
+               "joyai.mtp", "joyai.mtp/joyai.attn",
+               "joyai.mtp/joyai.attn/qkv", "joyai.mtp/joyai.attn/out",
+               "joyai.mtp/joyai.moe/grouped", "joyai.mtp/joyai.moe/shared",
+               "joyai.head", "byteps.optimizer"}, True),
 }
 # The names the device trace was read by before this map: an unnamed
 # kernel call is called after the innermost scope around it.  The expert
@@ -98,6 +109,9 @@ KERNEL_SCOPES = {
              "keye.moe/exact/grouped"},
     "nemotronh": {"nemotronh.mamba.scan", "nemotronh.attn",
                   "nemotronh.moe/grouped", "nemotronh.moe/exact/grouped"},
+    "joyai": {"joyai.attn", "joyai.moe/grouped", "joyai.moe/exact/grouped",
+              "joyai.mtp/joyai.attn", "joyai.mtp/joyai.moe/grouped",
+              "joyai.mtp/joyai.moe/exact/grouped"},
 }
 PRODUCTS = ("fusion", "custom-call", "dot", "convolution", "ragged-dot")
 WORK = ("dot_general", "conv_general_dilated", "pallas_call")
@@ -157,6 +171,13 @@ def _family(name: str):
         config = tiny_nemotronh.config(layers=[4, 5, 6])
         config["published"].update(tiny_nemotronh.ON_THE_CHIP)
         cell = dataclasses.replace(cell, config=config)
+    elif name == "joyai":       # dense, expert, the module; the published
+        config = tiny_joyai.config(layers=[0, 1])       # head: 192 and 128
+        config["published"].update(tiny_joyai.ON_THE_CHIP)
+        config["program_options"]["pinned"]["attn_impl"] = "flash"
+        config["job"]["seq_len"] = 128
+        cell = dataclasses.replace(cell, config=config,
+                                   job={**cell.job, **config["job"]})
     if name in ("afmoe", "mellum", "keye"):
         # the narrowest widths the grouped kernels tile: a lane tile each
         # (the tiny cuts' 64 and 32 go to `lax.ragged_dot`, the compiler's)
@@ -268,10 +289,11 @@ def test_every_familys_tiny_step_is_mapped(name, v5e, kernels,
     assert not [n for n, e in scopes.items() if e.get("lent")]
     grouped = {n: e for n, e in kernels.items()
                if n.startswith("ragged-dot-none_")}
-    if name in ("afmoe", "mellum", "keye", "nemotronh"):
+    if name in ("afmoe", "mellum", "keye", "nemotronh", "joyai"):
         assert {n.split(".")[0].rsplit("_", 1)[1] for n in grouped} == {
             "fwd", "drows", "dweights"}
-        assert all(e["scope"].startswith(f"{name}.moe/")
+        assert all(e["scope"].startswith((f"{name}.moe/",
+                                          f"{name}.mtp/{name}.moe/"))
                    and e["pass"] != "other" for e in grouped.values())
         assert {"forward", "recompute", "backward"} <= {
             e["pass"] for e in grouped.values()}

@@ -683,3 +683,81 @@ def test_nemotronh_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch,
     print(said)
     # (the nine layers' arguments are 8.0e9 of the chip's 16.9e9)
     assert mem.temp_size_in_bytes < 7.0e9, said
+
+
+@pytest.mark.parametrize("layers,modules", [([0], 1), ([1, 2, 3, 4], 0)],
+                         ids=["dense_layer_and_module", "expert_layers"])
+def test_joyai_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch,
+                                                       layers, modules):
+    """The joyai cell's step (`benchmark/configs/joyai-llm-flash.json`: one
+    sequence of 16,384, a plain `value_and_grad` and adamw, embedding,
+    head and the cross-entropies included) for one described chip, in two
+    parts of the cell, because the three layer bodies together (the dense
+    layer's, the scan's, the module's) take a minute to compile alone and
+    two beside five other workers, over a test's budget: layer 0 with the
+    prediction module, and the four expert layers under their one scan.
+    Every attention call is the STREAMING kernels with queries and keys
+    192 wide and values 128, under names that say so; a rematerialised
+    layer keeps its call's `o` and `lse`, so the forward kernel is
+    compiled ONCE a layer body; the experts' three products at width 768
+    are the program's own kernels.  The whole cell, compiled the same way
+    (PR 50): arguments 8,165,415,424, temporaries 11,366,176,768, peak
+    15,665,868,800 + 109,260,288 of code (the compiler fills the chip it
+    is given); with two sequences a step it needs 15.84 GiB of 15.75 and
+    does not compile; the chip measured `memory_peak_bytes`
+    15,599,556,096."""
+    import json
+
+    import optax
+
+    from benchmark.families import joyai as family_joyai
+    from benchmark.harness import manifest
+    from benchmark.reduce import afmoe_cost
+    monkeypatch.setattr(fa, "_use_interpret", lambda interpret: False)
+    with open(os.path.join(manifest.BENCH, "configs",
+                           "joyai-llm-flash.json")) as f:
+        config = json.load(f)
+    config["held"].update(layers=layers, num_hidden_layers=len(layers),
+                          num_nextn_predict_layers=modules)
+    family = family_joyai.Family(config, config["job"])
+    opt = family.optimizer()
+    one = SingleDeviceSharding(v5e[0])
+
+    def step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(family.loss)(params, batch)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+    params = jax.eval_shape(family.init, jax.random.key(0))
+    opt_state = jax.eval_shape(opt.init, params)
+    batch = jax.eval_shape(
+        lambda k: family.make_batch(k, config["job"]["per_chip_batch"]),
+        jax.random.key(0))
+    t0 = time.perf_counter()
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(opt_state), on_chip(batch)).compile()
+    seconds = time.perf_counter() - t0
+    text = compiled.as_text()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    named = sorted(re.findall(r"flash_[a-z]+_d192x128", " ".join(
+        c.split(" = ")[0] for c in calls)))
+    bodies = 1 + modules            # the dense layer's and the module's;
+    assert named == sorted(         # the scan's one
+        ["flash_fwd_d192x128", "flash_dq_d192x128",
+         "flash_dkv_d192x128"] * bodies), named
+    grouped = [afmoe_cost.grouped_call(c) for c in calls
+               if afmoe_cost.is_grouped(c)]
+    assert set(grouped) == {(16, 2048, 768), (16, 768, 2048)}
+    assert "ragged-dot-metadata" not in text
+    mem = compiled.memory_analysis()
+    said = (f"arguments {mem.argument_size_in_bytes:,} temporaries "
+            f"{mem.temp_size_in_bytes:,} peak {mem.peak_memory_in_bytes:,} "
+            f"code {mem.generated_code_size_in_bytes:,} compiled in "
+            f"{seconds:.0f} s")
+    print(said)
+    assert mem.peak_memory_in_bytes < 16.9e9, said

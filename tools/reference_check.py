@@ -20,7 +20,8 @@ older notes name.)
     python3 tools/reference_check.py --workload <cell> --record <out.pb>
 
 records instead the small trace that the family's readers are tested on
-(`RECORD` below: the granitehybrid, mellum, keye and nemotronh cells).
+(`RECORD` below: the granitehybrid, mellum, keye, nemotronh and joyai
+cells).
 """
 
 import argparse
@@ -126,7 +127,7 @@ def _mellum_record(cell, out: str) -> int:
     cell = dataclasses.replace(cell, config=config,
                                job={**cell.job, **config["job"]})
     with mock.patch.object(flash_attention, "_use_streaming",
-                           lambda q, streaming: True):
+                           lambda *_: True):
         line, _ = measure.run_cell(
             cell, seed=3, seconds=1.0, trace=True, devices=jax.devices()[:1],
             peaks=chip.require(jax.devices(), 1), t_start=0.0)
@@ -189,7 +190,41 @@ def _nemotronh_record(cell, out: str) -> int:
     cell = dataclasses.replace(cell, config=config,
                                job={**cell.job, **config["job"]})
     with mock.patch.object(flash_attention, "_use_streaming",
-                           lambda q, streaming: True):
+                           lambda *_: True):
+        line, _ = measure.run_cell(
+            cell, seed=3, seconds=1.0, trace=True, devices=jax.devices()[:1],
+            peaks=chip.require(jax.devices(), 1), t_start=0.0)
+    print(line)
+    shutil.copy(xplane.find(os.path.join(measure.TRACE_ROOT, cell.name)), out)
+    return 0 if line["correct"] else 1
+
+
+def _joyai_record(cell, out: str) -> int:
+    """`benchmark/tests/data/tiny_joyai.xplane.pb`: the cell at tiny widths
+    but with the published head (queries and keys 128 + 64 wide, values
+    128: the widths the kernels' names carry) and what else the chip's
+    tiles ask (`tiny_joyai.ON_THE_CHIP`), the dense layer, one expert
+    layer and the prediction module, one sequence of 1,024 positions in
+    tiles of 128 with the streaming flash kernels asked for by hand, five
+    traced steps through the in-graph job."""
+    from unittest import mock
+
+    import jax
+
+    from benchmark.harness import chip, measure
+    from benchmark.reduce import xplane
+    from benchmark.tests import tiny_joyai
+    from byteps_tpu.ops import flash_attention
+    config = tiny_joyai.config(layers=[0, 1])
+    config["published"].update(tiny_joyai.ON_THE_CHIP)
+    config["job"].update(per_chip_batch=1, seq_len=1024)
+    config["program_options"]["pinned"]["attn_impl"] = "flash"
+    config["program_options"]["left_at_rule"].update(attn_block=128,
+                                                     attn_block_k=128)
+    cell = dataclasses.replace(cell, config=config,
+                               job={**cell.job, **config["job"]})
+    with mock.patch.object(flash_attention, "_use_streaming",
+                           lambda *_: True):
         line, _ = measure.run_cell(
             cell, seed=3, seconds=1.0, trace=True, devices=jax.devices()[:1],
             peaks=chip.require(jax.devices(), 1), t_start=0.0)
@@ -200,9 +235,10 @@ def _nemotronh_record(cell, out: str) -> int:
 
 EXTRAS = {"afmoe": _expert_extras, "mellum": _expert_extras,
           "keye": _expert_extras, "nemotronh": _expert_extras,
-          "granitehybrid": _granitehybrid_extras}
+          "joyai": _expert_extras, "granitehybrid": _granitehybrid_extras}
 RECORD = {"granitehybrid": _granitehybrid_record, "mellum": _mellum_record,
-          "keye": _keye_record, "nemotronh": _nemotronh_record}
+          "keye": _keye_record, "nemotronh": _nemotronh_record,
+          "joyai": _joyai_record}
 
 
 def main(argv=None) -> int:
