@@ -415,7 +415,8 @@ def _gauge(name: str, help: str, cast=int) -> tuple:
 #: label, "none" for a call without one, so a step with both kinds of
 #: layer keeps both whatever the order they are traced in), and the
 #: expert layer's grouped product (`ops/grouped_matmul.py`: which path
-#: ran and on what tiles; `parallel/dropless_moe.py` adds the walk), and
+#: ran and on what tiles; `parallel/dropless_moe.py` adds the walk) and
+#: its row moves (`ops/moe_rows.py`: one set of row counts a `use`), and
 #: the attention over selected keys (`ops/sparse_attention.py`), and what
 #: a model's rematerialised layers keep by name (`models/nemotron_h.py`,
 #: `models/granite_hybrid.py`: one set of gauges a `name` label).
@@ -531,6 +532,26 @@ _STATIC = {
         "row_tiles_buffer": _gauge(
             "bps_grouped_row_tiles_buffer",
             "row tiles of the whole buffer, padding included"),
+    },
+    "moe_rows": {
+        "kernel": _gauge(
+            "bps_moe_move_kernel",
+            "1 where the last traced expert layer moves its rows with the "
+            "program's Pallas kernel (ops/moe_rows.py)"),
+        "tile_rows": _gauge(
+            "bps_moe_move_tile_rows",
+            "result rows a grid step of the last traced move takes"),
+        "rows": _gauge(
+            "bps_moe_move_rows",
+            "(row, choice) pairs the last traced move of the use `use` "
+            "(`gather`: tokens' rows into the buffer and the gradient's; "
+            "`scatter`: results back to their tokens and the transpose) "
+            "walks a call"),
+        "texts": _gauge(
+            "bps_moe_move_texts",
+            "distinct bodies of that kernel traced in this process: what "
+            "a run's set-up pays for, the same for four unrolled layers "
+            "as for one"),
     },
     "sparse_attention": {
         "rows": _gauge(

@@ -26,8 +26,12 @@ No assignment to a held expert is dropped, whatever the routing:
     expert's products grouped by expert (`ops/grouped_matmul.py`:
     the program's own Pallas kernels, whose grid walks only the row tiles
     that hold a live row; a width they cannot tile, no multiple of 64,
-    goes to `lax.ragged_dot`), are weighted, and are scatter-added back
-    to their tokens;
+    goes to `lax.ragged_dot`), are weighted, and are added back to their
+    tokens.  Both moves, and both their transposes, are one Pallas kernel
+    (`ops/moe_rows.py`: `out[i] = sum_j w[i, j] src[idx[i, j]]`, many row
+    copies in flight), under two small `jax.custom_vjp`s, `_rows_in` and
+    `_rows_out`: no XLA gather and no scatter-add of rows, forward or
+    backward.  A token's sum over its choices is float32 in a fixed order;
   - pairs past the buffer go through the same code, a small buffer at a
     time (`past_rows`, an eighth of the first), in a loop that runs only
     while pairs are left (`_past_the_buffer`).  That is the exact path: no
@@ -60,7 +64,7 @@ What a rematerialised layer can keep (`ROUTING_NAME`).  What the router
 decided and what the plan sorted carry one name for `jax.checkpoint`: the
 score product's `logits` [T, E] float32 (the scores and the weights'
 gradient are a few elementwise passes from them), `sel` and `weights`
-[T, k], and the plan's `order`, `starts` and `ends`, integers.  A layer under
+[T, k], and the plan's `order`, `place`, `starts` and `ends`, integers.  A layer under
 `save_only_these_names(dropless_moe.ROUTING_NAME)` holds them from its
 forward pass and its recompute runs no score product, no top-k, no sort
 and no count a second time (`kept_bytes`: under 10 MB a layer at 16,384
@@ -76,11 +80,11 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..ops import grouped_matmul as gm
+from ..ops import moe_rows
 
 # The name the router's and the plan's results carry for `jax.checkpoint`
 # (the module's docstring says what a policy that lists it keeps).
@@ -133,9 +137,9 @@ class MoEConfig:
 
     def kept_bytes(self, n_tokens: int) -> int:
         """Bytes a layer's `ROUTING_NAME` names: the logits, `sel` and
-        `weights`, the sorted list and each held expert's start and end,
-        four bytes each."""
-        return 4 * (n_tokens * (self.num_experts + 2 * self.top_k)
+        `weights`, the sorted list, each pair's place in it and each held
+        expert's start and end, four bytes each."""
+        return 4 * (n_tokens * (self.num_experts + 3 * self.top_k)
                     + self.sorted_rows(n_tokens) + 2 * len(self.held))
 
 
@@ -170,12 +174,25 @@ def route(x, router_w, cfg: MoEConfig, expert_bias=None, sel=None):
             scores + lax.stop_gradient(expert_bias.astype(jnp.float32)))
         _, sel = lax.top_k(lax.stop_gradient(biased), cfg.top_k)
     sel = checkpoint_name(sel, ROUTING_NAME)
-    weights = jnp.take_along_axis(scores, sel, axis=-1)
+    weights = _chosen(scores, sel)
     if cfg.route_norm:
         weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
     if cfg.hold_held_weight and len(cfg.held) < cfg.num_experts:
         weights = _held_weight_held(weights, sel, cfg)
     return sel, checkpoint_name(weights * cfg.route_scale, ROUTING_NAME)
+
+
+def _chosen(scores, sel):
+    """`take_along_axis(scores, sel, -1)`, [T, k], bit for bit, as a
+    compare and a sum over the experts: one value and E - 1 zeros a pair.
+    The compiler's gather of T k single scores out of [T, E], and the
+    scatter-add that is its transpose, took 2 ms and 2.3 ms a layer and
+    pass at 32,768 tokens of 8 choices over 128 experts, 42 ms of
+    trinity-mini's step; this is an elementwise pass over [T, k, E] that
+    is never stored, both ways (PERF.md, Findings, PR 52)."""
+    chosen = sel[:, :, None] == lax.broadcasted_iota(
+        sel.dtype, (1, 1, scores.shape[-1]), 2)
+    return jnp.where(chosen, scores[:, None, :], 0).sum(-1)
 
 
 def _held_weight_held(weights, sel, cfg: MoEConfig):
@@ -222,6 +239,7 @@ class _Plan(NamedTuple):
     """The sorted (token, choice) pairs; integers, nothing to
     differentiate."""
     order: jax.Array      # [rows + n * past] pair indices, held experts first
+    place: jax.Array      # [T * k] where each pair lies in `order`: its inverse
     starts: jax.Array     # [len(held)] where each held expert's pairs begin
     ends: jax.Array
     held_rows: jax.Array  # ()
@@ -232,46 +250,138 @@ def _plan(sel, cfg: MoEConfig) -> _Plan:
     held elsewhere last, and pads the list to whole buffers
     (`MoEConfig.sorted_rows`)."""
     n_held = len(cfg.held)
-    slot_of = np.full((cfg.num_experts,), n_held, np.int32)
-    slot_of[list(cfg.held)] = np.arange(n_held)
-    slot = jnp.asarray(slot_of)[sel.reshape(-1)]
+    # which held expert each pair chose, [held, pairs]: compares, not a
+    # lookup by T k indices (the compiler's gather of single integers
+    # took 2 ms a layer and pass)
+    chose = (jnp.asarray(cfg.held, jnp.int32)[:, None]
+             == sel.reshape(-1)[None, :])
+    slot = n_held + (chose * (jnp.arange(n_held, dtype=jnp.int32)
+                              - n_held)[:, None]).sum(0, dtype=jnp.int32)
     order = jnp.argsort(slot, stable=True).astype(jnp.int32)
-    counts = (slot[:, None] == jnp.arange(n_held, dtype=jnp.int32)).sum(
-        0, dtype=jnp.int32)
+    counts = chose.sum(1, dtype=jnp.int32)
     ends = jnp.cumsum(counts)
     pad = cfg.sorted_rows(sel.shape[0]) - order.size
-    order, starts, ends = (
+    order, place, starts, ends = (
         checkpoint_name(t, ROUTING_NAME) for t in (
             jnp.concatenate([order, jnp.zeros((pad,), jnp.int32)]),
-            ends - counts, ends))
-    return _Plan(order, starts, ends, ends[-1])
+            jnp.argsort(order).astype(jnp.int32), ends - counts, ends))
+    return _Plan(order, place, starts, ends, ends[-1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _rows_in(x, token, place, k):
+    """`x[token]` [rows, D], zeros where `token` is -1: the tokens' rows
+    into the buffer.  `place` [T * k] is the other side of the same
+    list, the buffer row of each pair (-1: none), by which the transpose
+    is the same kernel: a token's gradient is the float32 sum of its `k`
+    rows', rounded once."""
+    del place
+    return moe_rows.gather_sum(x, token, k=1, use="gather")
+
+
+def _rows_in_fwd(x, token, place, k):
+    return _rows_in(x, token, place, k), place
+
+
+def _rows_in_bwd(k, place, g):
+    return moe_rows.gather_sum(g, place, k=k, use="scatter"), None, None
+
+
+_rows_in.defvjp(_rows_in_fwd, _rows_in_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _rows_out(y, flat_w, token, pair, place, k, dtype):
+    """`out[t] = sum_j flat_w[t k + j] * y[place[t k + j]]` [T, D]
+    float32, j ascending: the buffer's results weighted and added back to
+    their tokens.  `token` and `pair` [rows] say whose each buffer row is
+    (`token` -1: nobody's; what `y` holds there, NaN included, reaches
+    neither the result, which never asks for it, nor a gradient).
+
+    Backward: the result's gradient is fetched by token ONCE, [rows, D];
+    `y`'s is that times the row's weight, the weight's its dot product
+    with `y`'s row (masked BEFORE the product, whose other side may be
+    NaN), handed back to the pair it came from.  The gradient is moved in
+    `dtype`, the layer's: `held_experts` rounds its float32 sum to it, so
+    what comes back is that dtype's values widened, and narrowing them
+    again loses nothing."""
+    del token, pair, dtype
+    return moe_rows.gather_sum(y, place, flat_w, k=k, out_dtype=jnp.float32,
+                               use="scatter")
+
+
+def _rows_out_fwd(y, flat_w, token, pair, place, k, dtype):
+    return (_rows_out(y, flat_w, token, pair, place, k, dtype),
+            (y, flat_w, token, pair))
+
+
+@jax.jit
+def _rows_out_grads(y, flat_w, token, pair, gg):
+    """`_rows_out`'s gradients from the result's, fetched by token (under
+    a plain `jax.jit`: a dozen elementwise operations traced once a
+    shape)."""
+    live = token >= 0
+    gg = gg.astype(jnp.float32)
+    # (single weights fetched and handed back: a scope of their own, so
+    # that whoever counts the layer's moves of ROWS does not count these)
+    with jax.named_scope(".weights"):
+        w_row = jnp.where(live, flat_w[pair], 0)
+    d_y = gg * w_row[:, None]
+    dots = (jnp.where(live[:, None], y, 0).astype(jnp.float32) * gg).sum(-1)
+    with jax.named_scope(".weights"):
+        d_w = jnp.zeros_like(flat_w).at[pair].add(jnp.where(live, dots, 0))
+    return d_y.astype(y.dtype), d_w
+
+
+def _rows_out_bwd(k, dtype, residuals, g):
+    y, flat_w, token, pair = residuals
+    gg = moe_rows.gather_sum(g.astype(dtype), token, k=1, use="gather")
+    return *_rows_out_grads(y, flat_w, token, pair, gg), None, None, None
+
+
+_rows_out.defvjp(_rows_out_fwd, _rows_out_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "rows"))
+def _buffer_rows(lo, plan: _Plan, *, k: int, rows: int):
+    """Whose the rows `[lo, lo + rows)` of the sorted list are: each row's
+    `pair` and `token` (-1: no pair fills the row), each pair's `place`
+    among these rows (-1: a pair of another buffer, or of an expert held
+    elsewhere), and the rows each held expert has here.  Integers, under
+    a plain `jax.jit`: traced once a shape, not once a buffer."""
+    pair = lax.dynamic_slice_in_dim(plan.order, lo, rows)
+    live = lo + jnp.arange(rows, dtype=jnp.int32) < plan.held_rows
+    place = jnp.where(
+        (plan.place >= lo)
+        & (plan.place < jnp.minimum(lo + rows, plan.held_rows)),
+        plan.place - lo, -1)
+    group_sizes = jnp.clip(jnp.minimum(plan.ends, lo + rows)
+                           - jnp.maximum(plan.starts, lo), 0)
+    return pair, jnp.where(live, pair // k, -1), place, group_sizes
 
 
 def _buffer(lo, x, experts, flat_w, plan: _Plan, k: int, rows: int):
     """What the pairs `[lo, lo + rows)` of the sorted list add to the
     layer's result, [T, D] float32."""
     with jax.named_scope(".gather"):
-        pair = lax.dynamic_slice_in_dim(plan.order, lo, rows)
-        live = lo + jnp.arange(rows, dtype=jnp.int32) < plan.held_rows
-        token = pair // k
-        group_sizes = jnp.clip(jnp.minimum(plan.ends, lo + rows)
-                               - jnp.maximum(plan.starts, lo), 0)
+        pair, token, place, group_sizes = _buffer_rows(lo, plan, k=k,
+                                                       rows=rows)
         # The grouped product's kernels (`ops/grouped_matmul.py`, as the
         # compiler's own before them) leave the rows past the last group
         # as they found them, in the forward and in the backward products
         # alike (the CPU's `lax.ragged_dot` writes zeros): whatever is
         # there, NaN included, must reach neither the result nor a
-        # gradient.  So the rows are masked on the way in, which masks
-        # the gradient of the gather, and on the way out BEFORE the
-        # weights are multiplied in, whose gradient is otherwise 0 * NaN.
-        dead = ~live[:, None]
-        xg = jnp.where(dead, 0, x[token])
+        # gradient.  So those rows are masked on the way in (a row of
+        # token -1 comes in as zeros, and its gradient goes nowhere: no
+        # pair's `place` names it), and on the way out BEFORE the weights
+        # are multiplied in (`_rows_out`), whose gradient is otherwise
+        # 0 * NaN.
+        xg = _rows_in(x, token, place, k)
     with jax.named_scope(".grouped"):
         form = _swiglu_grouped if "gate_w" in experts else _relu2_grouped
         y = form(xg, experts, group_sizes, x.dtype)
     with jax.named_scope(".scatter"):
-        y = jnp.where(dead, 0, y).astype(jnp.float32) * flat_w[pair][:, None]
-        return jnp.zeros(x.shape, jnp.float32).at[token].add(y)
+        return _rows_out(y, flat_w, token, pair, place, k, x.dtype)
 
 
 def _past_buffers(rows: int, past: int, plan: _Plan):

@@ -242,3 +242,120 @@ def test_layer_through_the_kernels_at_lane_widths(kind):
     for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(g_want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-3, atol=1e-5)
+
+
+def _parent_buffer(lo, x, experts, flat_w, plan, k, rows):
+    """`_buffer` as it stood before the row-move kernel: XLA's gather by
+    token, the masks, and a scatter-add of the weighted results."""
+    pair = jax.lax.dynamic_slice_in_dim(plan.order, lo, rows)
+    dead = ~(lo + jnp.arange(rows, dtype=jnp.int32) < plan.held_rows)[:, None]
+    token = pair // k
+    group_sizes = jnp.clip(jnp.minimum(plan.ends, lo + rows)
+                           - jnp.maximum(plan.starts, lo), 0)
+    xg = jnp.where(dead, 0, x[token])
+    y = dm._swiglu_grouped(xg, experts, group_sizes, x.dtype)
+    y = jnp.where(dead, 0, y).astype(jnp.float32) * flat_w[pair][:, None]
+    return jnp.zeros(x.shape, jnp.float32).at[token].add(y)
+
+
+@pytest.mark.parametrize("kind", ["spread_evenly", "every_pair_held"])
+def test_layer_agrees_with_the_gather_and_scatter_add_it_replaced(
+        kind, monkeypatch):
+    """Result and every gradient against the parent's formulation, through
+    the buffer and the exact path, within float32 rounding: the same
+    mathematics, a token's sum now in a fixed order."""
+    x, router_w, experts = _weights(4)
+    sel = _forced(kind)
+
+    def layer(x, router_w, experts):
+        out, _ = dm.held_experts(x, router_w, experts, CFG, sel=sel)
+        return (out * jnp.cos(out)).sum(), out
+
+    grad = jax.jit(jax.value_and_grad(layer, (0, 1, 2), has_aux=True))
+    (_, got), g = grad(x, router_w, experts)
+    assert bps.get_metrics()["bps_moe_move_kernel"] == 1
+    monkeypatch.setattr(dm, "_buffer", _parent_buffer)
+    (_, want), g_want = jax.jit(jax.value_and_grad(
+        layer, (0, 1, 2), has_aux=True))(x, router_w, experts)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-6, atol=2e-6)
+    for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(g_want)):
+        scale = float(jnp.abs(b).max()) or 1.0
+        np.testing.assert_allclose(np.asarray(a) / scale,
+                                   np.asarray(b) / scale, rtol=0, atol=4e-6)
+
+
+def test_unrolled_layers_trace_what_one_layer_traces():
+    """Four layers UNROLLED at one shape, each under its own
+    `jax.checkpoint`, under `value_and_grad`: the row-move kernel's
+    bodies in the process (`bps_moe_move_texts`) number what ONE layer
+    leaves, and the counters say what a layer moves."""
+    from byteps_tpu.ops import moe_rows
+    D, F = 256, 128                # a width no other test of the file has
+    cfg = dataclasses.replace(CFG, row_multiple=128)
+    k = jax.random.split(jax.random.key(7), 5)
+    x = jax.random.normal(k[0], (T, D))
+    layers = [{"router_w": jax.random.normal(k[1], (D, E)) / 8,
+               "gate_w": jax.random.normal(k[2], (len(HELD), D, F)) / 11,
+               "up_w": jax.random.normal(k[3], (len(HELD), D, F)) / 11,
+               "down_w": jax.random.normal(k[4], (len(HELD), F, D)) / 16}
+              ] * 4
+
+    def loss(layers, x):
+        for layer in layers:
+            x = x + jax.checkpoint(
+                lambda x, p: dm.held_experts(
+                    x, p.pop("router_w"), p, cfg)[0])(x, dict(layer))
+        return (x * x).sum()
+
+    def bodies(n):
+        jax.jit(jax.value_and_grad(loss)).lower(layers[:n], x)
+        return moe_rows.texts()
+
+    before = moe_rows.texts()
+    one = bodies(1)
+    metrics = bps.get_metrics()
+    assert metrics["bps_moe_move_texts"] == one
+    assert metrics['bps_moe_move_rows{use="gather"}'] in (
+        cfg.buffer_rows(T), cfg.past_rows(T))
+    assert metrics['bps_moe_move_rows{use="scatter"}'] == T * K
+    # rows into a buffer, results back weighted, the rows' gradient back
+    # (here the first buffer and the exact path's are one shape)
+    assert one - before == 3
+    assert bodies(4) == one
+    assert bps.get_metrics()["bps_moe_move_texts"] == one
+
+
+
+def test_route_and_plan_without_the_compilers_gathers():
+    """The weights are `take_along_axis(scores, sel)` bit for bit, in the
+    value and in the scores' gradient, and the plan sorts what a lookup
+    of each choice's slot would."""
+    x, router_w, _ = _weights(6)
+    scores = jax.nn.sigmoid(x @ router_w)
+    sel = jax.lax.top_k(scores, K)[1]
+    probe = jax.random.normal(jax.random.key(8), sel.shape)
+    want, g_want = jax.value_and_grad(lambda s: (
+        jnp.take_along_axis(s, sel, axis=-1) * probe).sum())(scores)
+    got, g = jax.value_and_grad(lambda s: (dm._chosen(s, sel) * probe).sum())(
+        scores)
+    assert float(got) == float(want)
+    np.testing.assert_array_equal(np.asarray(g), np.asarray(g_want))
+    np.testing.assert_array_equal(
+        np.asarray(dm._chosen(scores, sel)),
+        np.asarray(jnp.take_along_axis(scores, sel, axis=-1)))
+    slot_of = np.full((E,), len(HELD), np.int32)
+    slot_of[list(HELD)] = np.arange(len(HELD))
+    for kind in ("spread_evenly", "every_pair_held", "none_held"):
+        sel = _forced(kind)
+        plan = dm._plan(sel, CFG)
+        slot = slot_of[np.asarray(sel).reshape(-1)]
+        order = np.argsort(slot, kind="stable")
+        np.testing.assert_array_equal(np.asarray(plan.order)[:order.size],
+                                      order)
+        np.testing.assert_array_equal(np.asarray(plan.place)[order],
+                                      np.arange(order.size))
+        counts = np.bincount(slot, minlength=len(HELD) + 1)[:len(HELD)]
+        np.testing.assert_array_equal(np.asarray(plan.ends - plan.starts),
+                                      counts)
+        assert int(plan.held_rows) == counts.sum()

@@ -291,26 +291,31 @@ def test_parameter_count_at_the_published_widths():
 
 
 # sha256 of the lowered text of `value_and_grad(family.loss)` at tiny
-# widths on the parent commit of PR 49 (007eeae), the counters in its
-# functions' names taken out (`@argsort_286` -> `@argsort`).  For mellum
-# and afmoe it is the text of 9c5663d, before the attention adapter became
-# a table and `_rope` learnt of positions.  PR 49 laid `checkpoint_name`
-# over the flash call's `o` and `lse` and over the expert layer's routing:
-# under these cells' policies ("none"; keye's "selection", which lists
-# other names) that moves the counters and not one operation.
+# widths, the counters in its functions' names taken out
+# (`@argsort_286` -> `@argsort`).  gpt2's is the text of the parent commit
+# of PR 49 (007eeae) still.  PR 49 laid `checkpoint_name` over the flash
+# call's `o` and `lse` and over the expert layer's routing: under these
+# cells' policies ("none"; keye's "selection", which lists other names)
+# that moved the counters and not one operation.  mellum, afmoe and keye
+# were hashed again on PR 52's tree, which CHANGES their expert layers'
+# operations (the rows move by `ops/moe_rows.py`'s kernel, the router's
+# gathers of single numbers are compares and sums, the plan sorts twice)
+# and nothing else of them: a later PR that does not touch
+# `parallel/dropless_moe.py` or `ops/moe_rows.py` leaves all four as they
+# are (a moved line in either file does not change the text).
 PARENTS_LOWERED_STEPS = {
     "mellum": (tiny_mellum.config, family_mellum,
-               "206ccf2dc1cb06f475a6a67f6ba14f8f"
-               "f2d95b5d64530a631ea1b127650be8d2"),
+               "87775117ae84fe979b9bfa072483b80f"
+               "8e7d697dc958fa05f34da88de4989594"),
     "afmoe": (tiny_afmoe.config, family_afmoe,
-              "e8c155b90d4908d851f3de25e88a8c2a"
-              "d7cb53913a132776bd88fc1ecf5e4f1d"),
+              "57938723e23e75dfff28a5b281552d2b"
+              "89df13194d90a54d345705e33daf8f53"),
     "gpt2": (tiny_gpt2_config, family_gpt2,
              "fc640a6643cb19be841478581525d3dd"
              "5a3b42543fb3656c8c3f6d063f8187ca"),
     "keye": (tiny_keye.config, family_keye,
-             "1d3bf1bb17e7ec5fccf03c385e5e2ae7"
-             "dae68d6c0847da2400e5213c62525eef"),
+             "8c487d54c58a84667b36c77d699224d0"
+             "2d04f7ea03c90df5ff0b7218783d05c4"),
 }
 
 

@@ -134,7 +134,7 @@ def test_a_recompute_makes_again_only_what_its_layer_does_not_keep(
         monkeypatch, again):
     """In the differentiated step, a call for each time it runs: a *
     layer's flash forward kernel, an M layer's `in_proj` product, an E
-    layer's score product, top-k and sort are there ONCE a layer, where
+    layer's score product, top-k and sorts are there ONCE a layer, where
     the same walk under a plain `jax.checkpoint` (a policy of no name)
     shows each twice.  What is not kept is made again either way: the
     scan's forward kernel."""
@@ -160,9 +160,13 @@ def test_a_recompute_makes_again_only_what_its_layer_does_not_keep(
             lambda e: e.primitive.name == "top_k",
             lambda e: e.primitive.name == "sort"],
     }
+    # (an E layer's plan sorts twice: the pairs by expert, and each
+    # pair's place in that list)
+    sorts = {nemotron_h.MOE: [1, 1, 2]}
     for kind, made in a_layer.items():
-        assert [count(m) for m in made] == [again * cfg.count(kind)] * len(
-            made), kind
+        assert [count(m) for m in made] == [
+            again * cfg.count(kind) * n
+            for n in sorts.get(kind, [1] * len(made))], kind
     assert count(lambda e: e.primitive.name == "pallas_call"
                  and e.params["name"] == "ssd_fwd_c64") == 2 * cfg.count(
                      nemotron_h.MAMBA)
@@ -173,7 +177,7 @@ def test_remat_kept_says_what_the_names_hold():
     they hold together, which are the bytes of what `checkpoint_name`
     names in the traced step; at the cell's shapes `o` and `lse` of the *
     layer (134 + 2 MB), four M layers' `in_proj` results ([16384, 10304]
-    bfloat16, 338 MB each) and four E layers' routing (9.6 MB each):
+    bfloat16, 338 MB each) and four E layers' routing (10.0 MB each):
     1.53 GB."""
     family = _family(jnp.bfloat16)
     params = jax.eval_shape(family.init, jax.random.key(0))
@@ -201,9 +205,9 @@ def test_remat_kept_says_what_the_names_hold():
     assert [metrics[f'bps_remat_kept_bytes{{name="{name}"}}']
             for name in layers] == [
         32 * 16384 * (128 * 2 + 4), 4 * 16384 * 10304 * 2,
-        4 * 4 * (16384 * (128 + 2 * 6) + 98816 + 2 * 8)]
-    assert 32 * 16384 * 260 + 4 * 337_641_472 + 4 * 9_570_368 \
-        == 1_525_162_240
+        4 * 4 * (16384 * (128 + 3 * 6) + 98816 + 2 * 8)]
+    assert 32 * 16384 * 260 + 4 * 337_641_472 + 4 * 9_963_584 \
+        == 1_526_735_104
 
 
 @pytest.mark.parametrize("impl,copies", [("kernel", 0), ("jnp", 4)])

@@ -97,21 +97,27 @@ FAMILIES = {
 # layer's grouped products are the program's own kernels since PR 40,
 # named `ragged-dot-none_*` under `<family>.moe/grouped` (the exact
 # path's under `<family>.moe/exact/grouped`).
+def _moe(prefix):
+    """The kernels of an expert layer under `prefix`: the grouped
+    products, and since PR 52 the row moves (`ops/moe_rows.py`), in the
+    first buffer and on the exact path behind it."""
+    return {f"{prefix}{path}/{part}" for path in ("", "/exact")
+            for part in ("grouped", "gather", "scatter")}
+
+
 KERNEL_SCOPES = {
     "gpt2": {"transformer.attn"},
     "afmoe": {"afmoe.attn.sliding_attention", "afmoe.attn.full_attention",
-              "afmoe.moe/grouped", "afmoe.moe/exact/grouped"},
+              *_moe("afmoe.moe")},
     "granitehybrid": {"granite.mamba.scan", "granite.attn"},
     "mellum": {"mellum.attn.sliding_attention", "mellum.attn.full_attention",
-               "mellum.moe/grouped", "mellum.moe/exact/grouped"},
+               *_moe("mellum.moe")},
     "keye": {"keye.attn.full_attention/select",
-             "keye.attn.full_attention/sparse", "keye.moe/grouped",
-             "keye.moe/exact/grouped"},
+             "keye.attn.full_attention/sparse", *_moe("keye.moe")},
     "nemotronh": {"nemotronh.mamba.scan", "nemotronh.attn",
-                  "nemotronh.moe/grouped", "nemotronh.moe/exact/grouped"},
-    "joyai": {"joyai.attn", "joyai.moe/grouped", "joyai.moe/exact/grouped",
-              "joyai.mtp/joyai.attn", "joyai.mtp/joyai.moe/grouped",
-              "joyai.mtp/joyai.moe/exact/grouped"},
+                  *_moe("nemotronh.moe")},
+    "joyai": {"joyai.attn", *_moe("joyai.moe"), "joyai.mtp/joyai.attn",
+              *_moe("joyai.mtp/joyai.moe")},
 }
 PRODUCTS = ("fusion", "custom-call", "dot", "convolution", "ragged-dot")
 WORK = ("dot_general", "conv_general_dilated", "pallas_call")
@@ -289,7 +295,15 @@ def test_every_familys_tiny_step_is_mapped(name, v5e, kernels,
     assert not [n for n, e in scopes.items() if e.get("lent")]
     grouped = {n: e for n, e in kernels.items()
                if n.startswith("ragged-dot-none_")}
+    moves = {n: e for n, e in kernels.items() if n.startswith("moe_rows_")}
     if name in ("afmoe", "mellum", "keye", "nemotronh", "joyai"):
+        # the rows move by the program's kernel in every pass, under the
+        # scopes `moe.move_ms` and `moe.move_kernel_share` read
+        assert all(e["scope"].rsplit("/", 1)[1] in ("gather", "scatter")
+                   and e["op_name"].endswith("/pallas_call")
+                   for e in moves.values())
+        assert {"forward", "recompute", "backward"} <= {
+            e["pass"] for e in moves.values()}
         assert {n.split(".")[0].rsplit("_", 1)[1] for n in grouped} == {
             "fwd", "drows", "dweights"}
         assert all(e["scope"].startswith((f"{name}.moe/",
@@ -299,7 +313,7 @@ def test_every_familys_tiny_step_is_mapped(name, v5e, kernels,
             e["pass"] for e in grouped.values()}
         assert "ragged-dot-metadata" not in text
     else:
-        assert not grouped
+        assert not grouped and not moves
 
 
 def test_the_dp4_step_is_mapped_with_the_exchange_in_the_optimizer(

@@ -160,6 +160,45 @@ def test_streaming_flash_compiles_at_mellum_shapes(v5e, window):
                for kind in ("fwd", "dq", "dkv"))
 
 
+def _row_moves(text: str, width: int):
+    """Of a compiled step: `(left, kernels)`.  `left` are the compiler's
+    own moves of rows under an expert layer, `gather(` and `scatter(`
+    instructions (fused or not) under a `.moe` scope with an operand or a
+    result `[rows, width]`; `kernels` the program's (`ops/moe_rows.py`),
+    each `(name, path)` as `moe.move_kernel_share` reads them."""
+    from benchmark.reduce import afmoe_cost
+    left, kernels = [], []
+    for line in map(str.strip, text.splitlines()):
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = afmoe_cost.xplane.op_name(line.removeprefix("ROOT "))
+            if name.startswith("moe_rows_"):
+                path = re.search(r'op_name="([^"]*)"', line)
+                kernels.append((name, path.group(1) if path else ""))
+        elif (re.search(r" (gather|scatter)\(", line) and ".moe" in line
+              and re.search(rf"(?:bf16|f32)\[\d+,{width}\]", line)):
+            left.append(line[:240])
+    return left, kernels
+
+
+def _assert_rows_move_by_kernel(text: str, width: int, k: int):
+    """No gather or scatter of rows is left under the expert layers, and
+    every kernel that moves them carries a name and a path the counter
+    reads: `moe_rows_k1` (rows into a buffer, the result's gradient by
+    token), `moe_rows_k<k>w` (results back, weighted) and `moe_rows_k<k>`
+    (the rows' gradient back), under `.../<family>.moe/.gather` or
+    `.scatter` (the exact path's a scope deeper), ending `pallas_call`."""
+    left, kernels = _row_moves(text, width)
+    assert not left, left
+    assert {n.split(".")[0] for n, _ in kernels} == {
+        "moe_rows_k1", f"moe_rows_k{k}w", f"moe_rows_k{k}"}, kernels
+    from byteps_tpu.common import devprof
+    for name, path in kernels:
+        scope = devprof.classify_op_name(path)[0]
+        assert re.search(r"\.moe(/exact)?/(gather|scatter)$", scope), (
+            name, path)
+        assert path.split(";")[0].endswith("/pallas_call"), (name, path)
+
+
 @pytest.mark.parametrize(
     "experts,hidden,width,tokens,capacity,what",
     [(128, 2048, 1024, 8192, 1.25, "forward"),
@@ -178,7 +217,10 @@ def test_dropless_expert_layer_compiles(v5e, monkeypatch, experts, hidden,
     remat inside a scan, as the models have it.  The grouped products are
     the program's own kernels under the names the trace's readers know a
     grouped product by, none of the compiler's is left, and each kernel's
-    instruction is what `benchmark/reduce/afmoe_cost.py` takes it for."""
+    instruction is what `benchmark/reduce/afmoe_cost.py` takes it for.
+    The rows move by `ops/moe_rows.py`'s kernel: no `gather(` and no
+    `scatter(` over a `[rows, hidden]` operand is left under the layer's
+    scope (`_assert_rows_move_by_kernel`)."""
     from benchmark.reduce import afmoe_cost
     from byteps_tpu.parallel import dropless_moe as dm
     monkeypatch.setattr(fa, "_use_interpret", lambda interpret: False)
@@ -211,11 +253,18 @@ def test_dropless_expert_layer_compiles(v5e, monkeypatch, experts, hidden,
         text = _compile(layers, *args).as_text()
         assert "ragged-dot-none_fwd" in text
         assert "ragged-dot-metadata" not in text
+        left, kernels = _row_moves(text, hidden)
+        assert not left, left
+        assert {n.split(".")[0] for n, _ in kernels} == {
+            "moe_rows_k1", "moe_rows_k8w"}, kernels
         return
     text = _compile(jax.grad(loss, (0, 1, 2)), *args).as_text()
     assert "ragged-dot-metadata" not in text        # the compiler's own
+    # the rows move by the program's kernel, forward and backward
+    _assert_rows_move_by_kernel(text, hidden, 8)
     calls = [line for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "moe_rows_" not in line.split(" = ")[0]]
     names = [afmoe_cost.xplane.op_name(line.strip()) for line in calls]
     # 3 products: forward, again under remat, and two gradients each
     for kind, least in (("fwd", 6), ("drows", 3), ("dweights", 3)):
@@ -574,6 +623,8 @@ def test_keye_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch):
     # a scan over the layers: its body holds a layer's calls once
     assert sorted(k for k in kinds if k) == ["dkv", "dq", "forward",
                                              "select"]
+    _assert_rows_move_by_kernel(text, config["hidden_size"],
+                                config["num_experts_per_tok"])
     words = f"s32[1,32768,{sa.words(32768)}]"
     for line, kind in zip(calls, kinds):
         operands = line.split(" custom-call(", 1)[1]
@@ -606,6 +657,30 @@ def _nemotronh_family(layers):
     return family_nemotronh.Family(config, config["job"])
 
 
+def _nemotronh_step(layers, device):
+    """The cell's step cut to `layers`, a plain `value_and_grad` and
+    adamw, jitted, and its arguments' shapes on `device`."""
+    import optax
+    family = _nemotronh_family(layers)
+    opt = family.optimizer()
+    one = SingleDeviceSharding(device)
+
+    def step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(family.loss)(params, batch)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+    params = jax.eval_shape(family.init, jax.random.key(0))
+    opt_state = jax.eval_shape(opt.init, params)
+    batch = jax.eval_shape(lambda k: family.make_batch(k, 1),
+                           jax.random.key(0))
+    return jax.jit(step, donate_argnums=(0, 1)), (
+        on_chip(params), on_chip(opt_state), on_chip(batch))
+
+
 @pytest.mark.parametrize("layers", [[4, 5], [6]],
                          ids=["mixer_and_attention", "expert_layer"])
 def test_nemotronh_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch,
@@ -626,31 +701,13 @@ def test_nemotronh_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch,
     (PR 47): arguments 8,003,700,736, temporaries 6,225,738,752, peak
     13,995,448,832 + 257,163,264 of code; the chip measured
     `memory_peak_bytes` 14,370,686,464."""
-    import optax
-
     from benchmark.reduce import afmoe_cost, ssd_cost
     from byteps_tpu.ops import ssd
     monkeypatch.setattr(fa, "_use_interpret", lambda interpret: False)
     monkeypatch.setattr(ssd, "_use_interpret", lambda interpret: False)
-    family = _nemotronh_family(layers)
-    opt = family.optimizer()
-    one = SingleDeviceSharding(v5e[0])
-
-    def step(params, opt_state, batch):
-        loss, grads = jax.value_and_grad(family.loss)(params, batch)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss
-
-    def on_chip(tree):
-        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
-            s.shape, s.dtype, sharding=one), tree)
-    params = jax.eval_shape(family.init, jax.random.key(0))
-    opt_state = jax.eval_shape(opt.init, params)
-    batch = jax.eval_shape(lambda k: family.make_batch(k, 1),
-                           jax.random.key(0))
+    step, args = _nemotronh_step(layers, v5e[0])
     t0 = time.perf_counter()
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
-        on_chip(params), on_chip(opt_state), on_chip(batch)).compile()
+    compiled = step.lower(*args).compile()
     seconds = time.perf_counter() - t0
     text = compiled.as_text()
     calls = [line.strip().removeprefix("ROOT ")
@@ -667,6 +724,7 @@ def test_nemotronh_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch,
         assert set(grouped) == {(8, 2688, 1856), (8, 1856, 2688)}
         assert "ragged-dot-metadata" not in text
         assert not scans and not flash
+        _assert_rows_move_by_kernel(text, 2688, 6)
     else:
         # the scan's forward kernel, again under remat, and its backward;
         # the flash forward kernel ONCE: the layer keeps its `o` and `lse`
@@ -683,6 +741,60 @@ def test_nemotronh_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch,
     print(said)
     # (the nine layers' arguments are 8.0e9 of the chip's 16.9e9)
     assert mem.temp_size_in_bytes < 7.0e9, said
+
+
+def test_nemotronh_four_unrolled_expert_layers_share_the_move_kernels(
+        v5e, monkeypatch):
+    """The nemotron_h step cut to its four `E` layers (1, 3, 6, 8), which
+    the model UNROLLS, each under its own `jax.checkpoint`: traced and
+    lowered, not compiled.  The row-move kernel's `tpu_custom_call`
+    bodies in the lowered module number what ONE layer's module holds,
+    not four times that, and their distinct texts (name, operands and
+    result) are what the process traced (`bps_moe_move_texts`): a kernel's
+    call under a plain `jax.jit` is one body a shape, shared by the
+    layers, and a run's set-up pays its tracing and lowering once
+    (`ops/moe_rows.py`; PR 51's kernel, unshared, cost the cell 20 s).
+    Prints the seconds of tracing and lowering."""
+    import byteps_tpu as bps
+    from byteps_tpu.ops import moe_rows, ssd
+    monkeypatch.setattr(fa, "_use_interpret", lambda interpret: False)
+    monkeypatch.setattr(ssd, "_use_interpret", lambda interpret: False)
+
+    def lowered(layers):
+        step, args = _nemotronh_step(layers, v5e[0])
+        t0 = time.perf_counter()
+        traced = step.trace(*args)
+        t1 = time.perf_counter()
+        text = traced.lower().as_text()
+        return text, t1 - t0, time.perf_counter() - t1
+
+    def bodies(text):
+        """`(kernel's name, its operands' and result's types)` of every
+        move-kernel body in a lowered module."""
+        return [(m.group(1), line[line.rfind(" : "):])
+                for line in text.splitlines()
+                if "stablehlo.custom_call @tpu_custom_call" in line
+                for m in [re.search(r'kernel_name = "(moe_rows_[^"]+)"',
+                                    line)] if m]
+
+    one_layer = bodies(lowered([6])[0])
+    traced = moe_rows.texts()
+    text, trace_s, lower_s = lowered([1, 3, 6, 8])
+    four_layers = bodies(text)
+    print(f"four E layers: traced in {trace_s:.2f} s, lowered in "
+          f"{lower_s:.2f} s, {len(four_layers)} move-kernel bodies of "
+          f"{len(set(four_layers))} texts, "
+          f"{text.count('stablehlo.custom_call @tpu_custom_call')} kernel "
+          f"bodies in all, {len(text) // 1000}k characters")
+    # three more layers traced no body the one had not
+    assert moe_rows.texts() == traced >= len(set(one_layer))
+    assert bps.get_metrics()["bps_moe_move_texts"] == traced
+    # rows in, results back, the rows' gradient back; the first buffer and
+    # the exact path's
+    assert len(set(four_layers)) == 6, set(four_layers)
+    assert sorted(four_layers) == sorted(one_layer)
+    assert {n for n, _ in four_layers} == {"moe_rows_k1", "moe_rows_k6w",
+                                           "moe_rows_k6"}
 
 
 @pytest.mark.parametrize("layers,modules", [([0], 1), ([1, 2, 3, 4], 0)],
@@ -754,6 +866,7 @@ def test_joyai_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch,
                if afmoe_cost.is_grouped(c)]
     assert set(grouped) == {(16, 2048, 768), (16, 768, 2048)}
     assert "ragged-dot-metadata" not in text
+    _assert_rows_move_by_kernel(text, 2048, 8)
     mem = compiled.memory_analysis()
     said = (f"arguments {mem.argument_size_in_bytes:,} temporaries "
             f"{mem.temp_size_in_bytes:,} peak {mem.peak_memory_in_bytes:,} "
