@@ -31,7 +31,7 @@ _LAZY = {
         "get_transport_stats", "get_metrics", "get_server_stats",
         "get_health", "get_audit", "get_key_signals", "get_diagnosis",
         "get_tuner", "get_hierarchy", "get_autoscaler", "get_fleet",
-        "get_device_profile", "get_step_scopes",
+        "get_device_profile", "get_step_scopes", "get_compile_log",
         "mark_step", "current_step"),
     ".parallel.async_ps": ("AsyncPSTrainer",),
     ".parallel.hierarchy": ("HierarchicalReducer", "SliceGroup"),

@@ -38,6 +38,7 @@ from . import (devprof, flightrec, host_memory, signals, stage_spans,
 from .config import Config, get_config
 from .logging import get_logger, set_level, set_rank
 from ..core.native import get_core
+from ..utils import compile_cache
 
 PyTree = Any
 
@@ -149,6 +150,8 @@ def init(lazy: bool = True) -> None:
     """
     if _state.initialized:
         return
+    # The compile log, once a process; this job's set-up begins.
+    compile_cache.install().reopen()
     cfg = get_config(refresh=True)
     _state.config = cfg
     if cfg.num_worker > 1 and os.environ.get("BYTEPS_TPU_JAX_DIST", "0") == "1":
@@ -783,10 +786,18 @@ def push_pull_tree(tree: PyTree, name: Optional[str] = None,
     """
     _require_init()
     sess = _state.ps_session
+    # The compile log is told of the round only while set-up lasts: the
+    # first round during which nothing is compiled ends it.
+    log = compile_cache.LOG
+    began = (log.call_begin()
+             if log is not None and log.steady_at is None else None)
     with (sess.spans.round(name or "push_pull_tree") if sess is not None
           else stage_spans.off()):
-        return _push_pull_tree(tree, name, average, compression,
-                               leaf_names, fusion_bytes)
+        out = _push_pull_tree(tree, name, average, compression,
+                              leaf_names, fusion_bytes)
+    if began is not None:
+        log.call_end(began)
+    return out
 
 
 def _push_pull_tree(tree, name, average, compression, leaf_names,
@@ -1663,6 +1674,26 @@ def get_step_scopes() -> Optional[dict]:
     return devprof.get_step_scopes()
 
 
+def get_compile_log() -> dict:
+    """What this process traced, lowered and compiled, program by
+    program, from JAX's own monitoring events
+    (``utils/compile_cache.py``; ``bps.init()`` installs the listeners,
+    no option): ``{"process_start", "installed_at", "steady_at",
+    "records", "totals"}``.  A record is one outermost span of a stage:
+    ``kind`` (``TRACE`` / ``LOWER`` / ``COMPILE``), ``name``, ``start``
+    and ``end`` on ``time.time()``, ``start_us`` and ``end_us`` on the
+    tracer's clock, ``thread``, ``nested`` (spans of the same stage
+    inside it), ``cause`` (``train_step`` with its ``call``,
+    ``scope_map``, or None) and, for a ``COMPILE``, ``cache``: ``hit``
+    (with ``retrieval_s``), ``miss``, ``small`` or ``uncached``.
+    ``steady_at`` is where set-up ended: the start of the first call of
+    ``build_train_step``'s callable or of ``push_pull_tree`` during
+    which nothing was made; a ``COMPILE`` after it is a recompile
+    (``bps_recompiles_total``, one warning each).  At most 2,048 records
+    are kept; ``totals`` go on counting (docs/monitoring.md)."""
+    return compile_cache.snapshot()
+
+
 def get_fleet() -> dict:
     """The fleet observability plane's merged view (``BYTEPS_TPU_FLEET=1``,
     PS mode): the last CMD_FLEET fetch (per-worker window rings), the
@@ -1983,6 +2014,26 @@ def _merge_server_trace(path: str, exiting: bool = False) -> None:
                     "args": {"name": f"device{rank()} "
                              f"({(prof.profile().get('platform') or '?')}"
                              f")"}})
+        log = compile_cache.LOG
+        spans = [e for e in events if e.get("ph") == "X"]
+        if log is not None and spans:
+            # Compile lane (pid = COMPILE_PID_BASE + rank): what the
+            # process traced, lowered and compiled inside the tracer's
+            # window, on the tracer's clock as the device lane is: a
+            # recompile lies beside the step or the round it stretched.
+            pid = trace_analysis.COMPILE_PID_BASE + rank()
+            made = log.trace_events(
+                pid, min(e["ts"] for e in spans),
+                max(e["ts"] + e.get("dur", 0) for e in spans))
+            events.extend(made)
+            meta.append({"name": "process_name", "ph": "M", "pid": pid,
+                         "tid": 0, "args": {"name": f"compile{rank()}"}})
+            meta.append({"name": "compile_log", "ph": "M", "pid": pid,
+                         "tid": 0, "args": {
+                             "totals": log.totals(), "spans": len(made),
+                             "steady_at_us": None if log.steady_at is None
+                             else int(log.steady_at * 1e6
+                                      + log.offset_us)}})
         doc["traceEvents"] = meta + events
         with open(path, "w") as f:
             json.dump(doc, f)

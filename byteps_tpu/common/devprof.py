@@ -429,7 +429,10 @@ def get_step_scopes() -> Optional[Dict[str, dict]]:
     t0 = time.monotonic()
     try:
         from ..utils import compile_cache
-        with compile_cache.scopes_in_key():     # as the step was compiled
+        # As the step was compiled; and what this makes, should it make
+        # anything, is no recompile of the job's.
+        with compile_cache.scopes_in_key(), \
+                compile_cache.caused_by("scope_map"):
             text = fn.lower(*args).compile().as_text()
         scopes = parse_step_scopes(text)
         get_logger().info("scope map of the step: %d instructions in "

@@ -38,6 +38,11 @@ wait for their first byte, and each lane's time with bytes outstanding
 (docs/timeline.md, "What the wire waited for"); empty for a program
 whose ``ROUND`` carries none.
 
+``compile_log`` is what the file holds of the process's compile log
+(utils/compile_cache.py): its totals and the longest of its spans inside
+the traced window, where a recompile lies beside the step or round it
+stretched.
+
 ``update_critical_path_gauges`` feeds the per-component means into the
 PR-4 telemetry registry as ``bps_step_critical_path_seconds{component=…}``
 (plus ``bps_step_straggler_wait_seconds{worker=…}``), so ``tools/bps_top``
@@ -60,6 +65,9 @@ SERVER_PID_BASE = 10000
 # sides (an unbounded `pid >= SERVER_PID_BASE` would walk device spans
 # as server work and corrupt the critical path).
 DEVICE_PID_BASE = 20000
+# Compile lanes (utils/compile_cache.py: the TRACE / LOWER / COMPILE spans
+# of the process's compile log) start here, above the device band.
+COMPILE_PID_BASE = 30000
 
 WORKER_STAGES = ("QUEUE", "ENCODE", "PUSH", "PULL", "DECODE")
 SERVER_STAGES = ("RECV", "SUM", "MERGE_WAIT", "PUBLISH", "PULL_SEND")
@@ -231,7 +239,28 @@ def analyze(events: List[dict], worker: int = 0, top_k: int = 5) -> dict:
     return {"steps": step_rows, "mean_breakdown_us": mean,
             "top_blocking": top, "straggler_wait_us": straggler,
             "round_breakdown_us": round_breakdown(xs, worker),
-            "round_wire": round_wire(xs, worker)}
+            "round_wire": round_wire(xs, worker),
+            "compile_log": compile_log(events, worker)}
+
+
+def compile_log(events: List[dict], worker: int = 0, top_k: int = 5) -> dict:
+    """What the file holds of the worker's compile log (its lane, pid
+    ``COMPILE_PID_BASE + worker``; utils/compile_cache.py): the log's
+    ``totals`` and ``steady_at_us`` as they stood when the file was
+    written, and the ``longest`` of its ``TRACE`` / ``LOWER`` /
+    ``COMPILE`` spans inside the traced window; empty for a file without
+    the lane."""
+    lane = [e for e in events if e.get("pid") == COMPILE_PID_BASE + worker]
+    said = [e["args"] for e in lane if e.get("name") == "compile_log"]
+    if not said:
+        return {}
+    spans = sorted((e for e in lane if e.get("ph") == "X"),
+                   key=lambda e: -int(e.get("dur", 0)))
+    return {"totals": said[0]["totals"],
+            "steady_at_us": said[0]["steady_at_us"], "spans": len(spans),
+            "longest": [{"kind": e["tid"], "name": e["name"],
+                         "ts_us": e["ts"], "dur_us": e["dur"],
+                         **e.get("args", {})} for e in spans[:top_k]]}
 
 
 def round_breakdown(spans: List[dict], worker: int = 0) -> Dict[str, int]:
@@ -396,6 +425,25 @@ def format_report(result: dict) -> str:
                 lines.append(f"      {key:<22}{value:>10}")
         lines.append("      lane busy             " + " ".join(
             _fmt_us(us).strip() for us in wire["lane_busy_us"]))
+    made = result.get("compile_log", {})
+    if made:
+        totals = made["totals"]
+        lines.append(
+            "compile log of the process: "
+            + ", ".join(f"{k.lower()} {v['seconds']:.2f}s in "
+                        f"{v['records']} spans"
+                        for k, v in totals["by_kind"].items())
+            + "; programs " + " ".join(
+                f"{k}={v}" for k, v in totals["by_cache"].items())
+            + f"; recompiles {totals['recompiles']}")
+        lines.append(f"      {made['spans']} of its spans inside the traced "
+                     "window" + (", the longest:" if made["longest"] else ""))
+        for row in made["longest"]:
+            lines.append(
+                f"      {row['kind']:<8}{_fmt_us(row['dur_us'])}  "
+                f"{row['name']}" + "".join(
+                    f"  {k}={row[k]}" for k in ("cache", "cause", "nested")
+                    if row.get(k)))
     clock = result.get("profiler_offset")
     if clock:
         lines.append(f"comm.json + {clock['offset_us']:.1f}us = the "

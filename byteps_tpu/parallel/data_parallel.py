@@ -358,14 +358,25 @@ def build_train_step(
 class _JittedStep:
     """A jitted train step as both paths of `build_train_step` call it:
     between the device plane's hooks, compiled into cache entries of its
-    own (`compile_cache.scopes_in_key`), and remembered for the scope map
-    (`bps.get_step_scopes()`) whenever a call compiled it."""
+    own (`compile_cache.scopes_in_key`), remembered for the scope map
+    (`bps.get_step_scopes()`) whenever a call compiled it, and told to
+    the compile log (`bps.get_compile_log()`): what such a call made is
+    the step's own (`cause="train_step"`), and the first call during
+    which nothing is made anywhere ends set-up."""
 
     def __init__(self, fn):
         self.fn = fn
         self.programs = 0       # how many the callable held when last asked
+        self.calls = 0
 
     def __call__(self, params, opt_state, batch):
+        self.calls += 1
+        # Once set-up has ended this is all a step does about the log:
+        # one global and two of its attributes read, no call into it.
+        log = compile_cache.LOG
+        since = log.made if log is not None else 0
+        began = (log.call_begin()
+                 if log is not None and log.steady_at is None else None)
         with compile_cache.scopes_in_key():
             # Device-plane hook (common/devprof.py): unarmed this is one
             # None check; armed it resolves cached FLOPs pre-dispatch
@@ -383,6 +394,12 @@ class _JittedStep:
         if programs != self.programs:
             self.programs = programs
             devprof.remember_step(self.fn, (out[0], out[1], batch))
+            if log is not None:
+                # After the fact: the listener has already warned of one
+                # that came after set-up, which is what a recompile is.
+                log.claim(since, "train_step", self.calls)
+        if began is not None:
+            log.call_end(began)
         devprof.step_end(tok, out)
         return out
 

@@ -458,7 +458,6 @@ def main(argv=None) -> int:
 
     from byteps_tpu.utils import compile_cache
     cache = compile_cache.enable()
-    hits = compile_cache.HitCounter()
 
     try:
         device = phase_device(jax.devices(), args.chips)
@@ -476,7 +475,9 @@ def main(argv=None) -> int:
             say("ps", **phase_ps(cfg, FULL, args.seed))
         else:
             say("dp", **phase_dp(cfg, FULL_DP4, args.chips, args.seed))
-        say("compile_cache", dir=cache, hits=hits.hits, misses=hits.misses)
+        # Programs by the cache's answer and seconds by stage: whether
+        # this call's set-up was warm.
+        say("compile_cache", dir=cache, **compile_cache.summary())
     except SmokeError as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

@@ -593,13 +593,14 @@ def test_a_moved_line_finds_the_steps_entry_and_a_renamed_scope_does_not(
     """What the step's key holds of its metadata is the name stacks and
     not the source lines: a later tree that only shifts a line of a file
     the step is traced through compiles nothing anew."""
-    hits = bps.utils.compile_cache.HitCounter()
+    log = bps.utils.compile_cache.install()     # the cache's answers
 
     def run(scope, blank_lines):
-        before = (hits.hits, hits.misses)
+        before = dict(log.by_cache)
         step, args = _step_from_source(scope, blank_lines)
         step(*args)
-        return hits.hits - before[0], hits.misses - before[1]
+        return (log.by_cache["hit"] - before["hit"],
+                log.by_cache["miss"] - before["miss"])
     first = run("transformer.mlp", 0)
     assert first[1] >= 1                        # the step itself, cold
     assert run("transformer.mlp", 7) == (first[0] + first[1], 0)

@@ -547,6 +547,27 @@ bps.shutdown()
     members = bucket[0]["args"]["members"]
     assert len(members) == 3
     assert all("t7" in m for m in members)
+    # The compile lane (utils/compile_cache.py): this job is traced from
+    # step 0, so the programs its first round compiles op by op lie
+    # inside that ROUND, on the one clock; the second round makes none.
+    spans = [e for e in events if e.get("ph") == "X"]
+    first, second = sorted((e for e in spans if e["tid"] == "ROUND"),
+                           key=lambda e: e["ts"])
+    compiles = [e for e in spans if e["tid"] == "COMPILE"
+                and e["pid"] == trace_analysis.COMPILE_PID_BASE]
+
+    def inside(rnd):
+        return [e for e in compiles if rnd["ts"] <= e["ts"]
+                and e["ts"] + e["dur"] <= rnd["ts"] + rnd["dur"]]
+    assert inside(first) and not inside(second)
+    assert all(e["args"]["cache"] == "uncached" for e in compiles)
+    told = trace_analysis.analyze(events)["compile_log"]
+    assert told["spans"] >= len(compiles) > 0
+    assert told["totals"]["by_cache"]["uncached"] >= len(compiles)
+    # set-up ended where the second call began, just before its ROUND
+    assert abs(told["steady_at_us"] - second["ts"]) < 50_000
+    assert "compile log of the process" in trace_analysis.format_report(
+        {"compile_log": told})
 
 
 # ---------------------------------------------------------------------------

@@ -313,6 +313,9 @@ def main(argv=None) -> int:
     for variant in names:
         with variants[variant](family):
             ok = (not compare(args.seeds[0], variant)) and ok
+    # Whether this call's set-up was warm: programs by the cache's answer,
+    # seconds by stage.
+    print(json.dumps({"compile_log": compile_cache.summary()}), flush=True)
     return 0 if ok else 1
 
 

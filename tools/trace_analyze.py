@@ -6,7 +6,9 @@ on one aligned clock, see docs/timeline.md) and prints, per step: the
 critical partition chain and a queue / encode / wire / server merge-wait /
 sum / decode breakdown that sums to the measured step time — plus top-k
 blocking tensors (with fused-bucket member attribution) and per-worker
-straggler attribution from the server MERGE_WAIT spans.
+straggler attribution from the server MERGE_WAIT spans, and the totals
+of the process's compile log with the five longest of its TRACE / LOWER /
+COMPILE spans inside the traced window.
 
 Usage:
     python tools/trace_analyze.py traces/0/comm.json
