@@ -577,6 +577,17 @@ _STATIC = {
             "of the three kernels of that call's forward and backward "
             "pass, those that compute a tile's index scores (the others "
             "read the mask as bits)"),
+        "select_passes_min": _gauge(
+            "bps_sparse_select_passes_min",
+            "passes over its slab of scores that a block of rows of "
+            "`index_topk` runs where no row has a tie at its threshold to "
+            "break: one a bit of the threshold and one for the cut"),
+        "select_passes_max": _gauge(
+            "bps_sparse_select_passes_max",
+            "the same where some row of the block has such a tie: one a "
+            "bit of the threshold and one a bit of the cut's position "
+            "(which of the two a block ran is in lane J + 2 of `aux`, "
+            "`sparse_attention.select_passes`)"),
         "mask_bytes": _gauge(
             "bps_sparse_mask_bytes",
             "bytes of packed mask the forward kernel writes a sequence: "

@@ -301,21 +301,28 @@ def kept_keys(params: PyTree, tokens: jax.Array, cfg: KeyeConfig,
                           positions=positions)[1][1]
 
 
+def _indexers(params: PyTree, tokens: jax.Array, cfg: KeyeConfig,
+              positions=None):
+    """Every layer's indexer operands `(qi, ki, w)` (`_index`), the layers
+    walked as the step walks them."""
+    x = _embed(params, tokens, cfg)
+    for i, kind in enumerate(cfg.layer_types):
+        lp = jax.tree.map(lambda leaf: leaf[i], params["moe"])
+        a = _rms_norm(x, lp["input_ln"], None, eps=cfg.rms_norm_eps)
+        yield _index(a, lp, cfg, positions)
+        x, _ = _layer(x, lp, None, cfg, kind, positions=positions)
+
+
 def chosen_keys(params: PyTree, tokens: jax.Array, cfg: KeyeConfig,
                 positions=None):
     """The program's own selection, for whoever checks it: uint32
     [layers, B, S, S / 32], bit b of word c of row t set where the row
-    takes key 32 c + b.  The layers are walked as the step walks them;
-    the mask is the attention kernels' own (`sparse_attention.keep_mask`)
-    or, without kernels, `dense_keep`'s."""
+    takes key 32 c + b.  The mask is the attention kernels' own
+    (`sparse_attention.keep_mask`) or, without kernels, `dense_keep`'s."""
     from ..ops import sparse_attention
-    x = _embed(params, tokens, cfg)
     B, S = tokens.shape
     out = []
-    for i, kind in enumerate(cfg.layer_types):
-        lp = jax.tree.map(lambda leaf: leaf[i], params["moe"])
-        a = _rms_norm(x, lp["input_ln"], None, eps=cfg.rms_norm_eps)
-        qi, ki, w = _index(a, lp, cfg, positions)
+    for qi, ki, w in _indexers(params, tokens, cfg, positions):
         if cfg.attn_impl == "flash":
             kit = ki.transpose(0, 2, 1)
             keep = sparse_attention.keep_mask(
@@ -327,8 +334,21 @@ def chosen_keys(params: PyTree, tokens: jax.Array, cfg: KeyeConfig,
         bits = keep.reshape(B, S, S // 32, 32).astype(jnp.uint32)
         out.append((bits << jnp.arange(32, dtype=jnp.uint32)).sum(
             -1, dtype=jnp.uint32))
-        x, _ = _layer(x, lp, None, cfg, kind, positions=positions)
     return jnp.stack(out)
+
+
+def select_passes(params: PyTree, tokens: jax.Array, cfg: KeyeConfig,
+                  positions=None):
+    """[layers, B, S] float32: the passes over its slab of scores that
+    `index_topk` ran in each row's block, the longer count where a row of
+    the block had a tie at its threshold to break
+    (`sparse_attention.select_pass_counts`)."""
+    from ..ops import sparse_attention
+    return jnp.stack([
+        sparse_attention.select_passes(sparse_attention.select(
+            qi, ki.transpose(0, 2, 1), w, cfg.index_topk, cfg.attn_block_k),
+            cfg.index_heads)
+        for qi, ki, w in _indexers(params, tokens, cfg, positions)])
 
 
 synthetic_batch = afmoe.synthetic_batch

@@ -23,9 +23,17 @@ kernels that read the forward kernel's mask.
     EXACTLY by bisection on the integer's bits: 32 counting passes over
     the scratch, none over HBM.  Equal scores are taken lowest key first,
     as `lax.top_k` and a stable sort take them: the row's `cut` is the
-    last key that is taken AT the threshold (15 more passes).  The [S, S]
-    scores are never in memory: a block's [rows, S] slab lives in VMEM
-    and leaves it as two numbers a row, `tau` and `cut`.
+    last key that is taken AT the threshold.  The bisection carries the
+    counts it makes, so it ends knowing how many keys reach the threshold
+    and how many lie above it, and a row has a tie to BREAK only where
+    more reach it than the row wants.  A block with such a row places
+    every row's cut by a second bisection, on the position (15 more
+    passes at 32,768 keys); every other block takes all its rows' keys at
+    the threshold, the cut is the last of them, and one pass finds it.
+    How many passes a block ran is data: lane J + 2 of its rows
+    (`select_passes`; `select_pass_counts` gives the two numbers).  The
+    [S, S] scores are never in memory: a block's [rows, S] slab lives in
+    VMEM and leaves it as two numbers a row, `tau` and `cut`.
   - `sparse_attention` (kernels `sparse_fwd`, `sparse_dq`, `sparse_dkv`)
     is streaming flash attention over the table of causal tiles, all the
     main heads of a tile in one grid step, the query heads of a key-value
@@ -103,7 +111,8 @@ from . import flash_attention
 from .flash_attention import (FIRST, LAST, NEG_INF, _dot_f32, _dot_nt,
                               _scaled, _spread, _to_lanes, stream_table)
 
-# `aux`'s lanes: the J weights of a row, then its threshold and its cut.
+# `aux`'s lanes: the J weights of a row, then its threshold, its cut and
+# the passes its block of `select` ran.
 AUX_LANES = 128
 # What a kernel may take of a v5e's 128 MiB of VMEM.
 VMEM_LIMIT = 96 * 1024 * 1024
@@ -262,45 +271,113 @@ def _select_kernel(qi_ref, kit_ref, w_ref, aux_ref, keys_scr, *, heads, topk,
         return carry
     lax.fori_loop(0, tiles, fill, 0)
 
+    def sweep(step, start):
+        """One pass over the slab: `step(acc, sortable, first key)` on one
+        128-lane piece after another, `acc` [rows, 128] from `start`."""
+        def body(j, acc):
+            keys = keys_scr[:, at(j)]
+            for c in range(0, block_k, 128):
+                acc = step(acc, keys[:, c:c + 128], j * block_k + c)
+            return acc
+        return lax.fori_loop(0, tiles, body,
+                             jnp.full((rows, 128), start, jnp.int32))
+
     def count(test):
         """How many of a row's keys pass `test(sortable, first key)`."""
-        def body(j, acc):
-            hit = test(keys_scr[:, at(j)], j * block_k)
-            return acc + _lane_sums(hit.astype(jnp.int32))
-        acc = lax.fori_loop(0, tiles, body,
-                            jnp.zeros((rows, 128), jnp.int32))
+        acc = sweep(lambda acc, k, k0: acc + test(k, k0).astype(jnp.int32), 0)
         return jnp.sum(acc, axis=1, keepdims=True)
+
+    def wide(x):
+        """A number a row over the lanes of a piece, once a pass and not
+        once a compare (3 ms of a call's 29 at 32,768 rows)."""
+        return jnp.broadcast_to(x, (rows, 128))
 
     t = q0 + lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
     want = jnp.minimum(t + 1, topk)
-    # The want-th largest sortable, bit by bit from the top: `low` is
-    # always a value that `want` keys reach, and ends as the largest.
-    low = jnp.where(count(lambda k, _: k >= 0) >= want, 0, INT_MIN)
 
-    def bit(i, low):
-        cand = low + jnp.left_shift(jnp.int32(1), 30 - i)
-        return jnp.where(count(lambda k, _: k >= cand) >= want, cand, low)
-    low = lax.fori_loop(0, 31, bit, low)
+    # The want-th largest sortable, bit by bit from the sign down (the
+    # first candidate is INT_MIN + 2 ** 31, which wraps to 0): `low` is
+    # always a value that `want` keys reach, and ends as the largest.  The
+    # counts are carried along: `reach` is count(k >= low), what the last
+    # candidate taken passed on, and `above` what the last candidate
+    # refused was refused on.  That candidate is `low + 1` when the loop
+    # ends, so `above` ends as count(k > low) with no pass of its own.
+    def bit(i, carry):
+        low, reach, above = carry
+        cand = low + jnp.left_shift(jnp.int32(1), 31 - i)
+        spread = wide(cand)
+        some = count(lambda k, _: k >= spread)
+        taken = some >= want
+        return (jnp.where(taken, cand, low), jnp.where(taken, some, reach),
+                jnp.where(taken, above, some))
+    low, reach, above = lax.fori_loop(0, 32, bit, (
+        jnp.full((rows, 1), INT_MIN, jnp.int32),
+        jnp.full((rows, 1), tiles * block_k, jnp.int32),
+        jnp.zeros((rows, 1), jnp.int32)))
     # Of the keys AT the threshold the first `need` are taken: `cut` is
     # the largest position with fewer than `need` of them before it.
-    need = want - count(lambda k, _: k > low)
-
-    def before(cand):
-        def test(k, k0):
-            s = k0 + lax.broadcasted_iota(jnp.int32, k.shape, 1)
-            return (k == low) & (s < cand)
-        return count(test)
-
-    bits = max((seq_len - 1).bit_length(), 1)
+    need = want - above
+    at_low = wide(low)
 
     def place(i, cut):
-        cand = cut + jnp.left_shift(jnp.int32(1), bits - 1 - i)
-        return jnp.where(before(cand) < need, cand, cut)
-    cut = lax.fori_loop(0, bits, place, jnp.zeros((rows, 1), jnp.int32))
+        cand = cut + jnp.left_shift(jnp.int32(1), _cut_bits(seq_len) - 1 - i)
+        spread = wide(cand)
+
+        def before(k, k0):
+            s = k0 + lax.broadcasted_iota(jnp.int32, k.shape, 1)
+            return (k == at_low) & (s < spread)
+        return jnp.where(count(before) < need, cand, cut)
+
+    def cut_among_ties():
+        return lax.fori_loop(0, _cut_bits(seq_len), place,
+                             jnp.zeros((rows, 1), jnp.int32))
+
+    def last_at_threshold():
+        """Every key at the threshold is taken: the cut is the last of
+        them, one pass that keeps a lane's last piece with such a key."""
+        first = sweep(lambda acc, k, k0: jnp.maximum(
+            acc, jnp.where(k == at_low, k0, -1)), -1)
+        lane = lax.broadcasted_iota(jnp.int32, first.shape, 1)
+        return jnp.max(jnp.where(first < 0, -1, first + lane), axis=1,
+                       keepdims=True)
+
+    # A row has a tie to break only where more keys reach its threshold
+    # than it wants; a block with no such row skips the bisection.
+    tied = jnp.max((reach > want).astype(jnp.int32)) > 0
+    cut = lax.cond(tied, cut_among_ties, last_at_threshold)
+    short, long = select_pass_counts(seq_len)
+    passes = jnp.where(tied, long, short).astype(jnp.float32)
     lane = lax.broadcasted_iota(jnp.int32, w.shape, 1)
     aux_ref[0] = jnp.where(
         lane == heads, _unsortable(low),
-        jnp.where(lane == heads + 1, cut.astype(jnp.float32), w))
+        jnp.where(lane == heads + 1, cut.astype(jnp.float32),
+                  jnp.where(lane == heads + 2, passes, w)))
+
+
+def _cut_bits(seq_len: int) -> int:
+    return max((seq_len - 1).bit_length(), 1)
+
+
+def select_pass_counts(seq_len: int):
+    """`(short, long)`: the passes over its slab that a block of `select`
+    runs.  One a bit of the threshold, then one that takes the last key at
+    the threshold; or, where a row of the block has a tie to break, one a
+    bit of the cut's position."""
+    return 32 + 1, 32 + _cut_bits(seq_len)
+
+
+def select_rows(seq_len: int) -> int:
+    """The rows of a block of `select`: 128, fewer where their slab of
+    scores would pass SELECT_SLAB_BYTES."""
+    rows = 128
+    while rows > 8 and rows * seq_len * 4 > SELECT_SLAB_BYTES:
+        rows //= 2
+    return rows
+
+
+def select_passes(aux, heads: int):
+    """[B, S] float32: the slab passes the row's block of `select` ran."""
+    return aux[..., heads + 2]
 
 
 def select(qi, kit, w, topk: int, block_k: int = 0,
@@ -309,14 +386,13 @@ def select(qi, kit, w, topk: int, block_k: int = 0,
     and w [B, S, J]: lanes 0..J-1 the weights as given, lane J the row's
     threshold `tau` (its min(t + 1, topk)-th largest index score among
     the keys s <= t), lane J + 1 its `cut` (the last key taken at the
-    threshold, lowest keys first)."""
+    threshold, lowest keys first), lane J + 2 the passes over the slab
+    that the row's block ran (`select_passes`)."""
     b, heads, s, _ = qi.shape
-    if heads + 2 > AUX_LANES:
+    if heads + 3 > AUX_LANES:
         raise ValueError(f"{heads} indexer heads do not fit aux's lanes")
     block_k = block_k or auto_blocks(s)[1]
-    rows = 128
-    while rows > 8 and rows * s * 4 > SELECT_SLAB_BYTES:
-        rows //= 2
+    rows = select_rows(s)
     check_blocks(s, 128, block_k)
     w = jnp.pad(w.astype(jnp.float32),
                 ((0, 0), (0, 0), (0, AUX_LANES - heads)))
@@ -772,12 +848,14 @@ def selected_attention(q, k, v, qi, ki, w, topk: int, block_q: int = 0,
             _alone(select(qi, kit, w, topk, block_k, interpret)),
             SELECTION_NAME)
     block, tile, _ = stream_table(s, block_q, block_k, True)
+    short, long = select_pass_counts(s)
     written = {(i, j * block_k // WORD_KEYS) for i, j in zip(block, tile)}
     telemetry.record_static(
         "sparse_attention", rows=s, topk=min(topk, s),
         selected_pairs=selected_pairs(s, topk),
         visible_pairs=s * (s + 1) // 2, tiles_walked=len(block),
-        index_passes=1, mask_bytes=len(written) * block_q * 128 * 4,
+        index_passes=1, select_passes_min=short, select_passes_max=long,
+        mask_bytes=len(written) * block_q * 128 * 4,
         # o, lse and bits of a sequence: what `ATTENTION_NAME` names
         kept_bytes=s * (h * d * q.dtype.itemsize + h * 4 + words(s) * 4))
     with jax.named_scope(".sparse"):

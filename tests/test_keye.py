@@ -109,6 +109,94 @@ def test_selection_is_exact_against_a_sort_with_ties():
                                    atol=5e-5)
 
 
+def _index_operands(case, S, J, Di):
+    """Indexer operands [1, J, S, Di], [1, S, Di], [1, S, J] and the blocks
+    of 128 rows that hold a tie at a threshold.  Positive queries and keys
+    keep relu's exact zeros, which tie, out of the float rows."""
+    k = jax.random.split(jax.random.key(7), 3)
+    qi = jnp.abs(jax.random.normal(k[0], (1, J, S, Di)))
+    ki = jnp.abs(jax.random.normal(k[1], (1, S, Di)))
+    w = jax.random.normal(k[2], (1, S, J))
+    if case == "all_tied":
+        return jnp.round(qi), jnp.round(ki), jnp.round(2 * w), range(S // 128)
+    if case == "one_block_tied":
+        # whole numbers in the second block's rows and in the keys it sees
+        qi = qi.at[:, :, 128:256].set(jnp.round(qi[:, :, 128:256]))
+        ki = ki.at[:, :256].set(jnp.round(ki[:, :256]))
+        w = w.at[:, 128:256].set(jnp.round(2 * w[:, 128:256]))
+        return qi, ki, w, [1]
+    if case == "short_rows":
+        # two keys with one score in every row, both taken while the row
+        # takes all it sees: a threshold shared and no tie to break
+        ki = ki.at[:, 1].set(ki[:, 0])
+    return qi, ki, w, []
+
+
+@pytest.mark.parametrize("case,topk", [
+    ("no_tie", 64), ("one_block_tied", 64), ("all_tied", 64),
+    ("short_rows", 512)])
+def test_a_block_runs_the_tie_passes_only_where_it_has_a_tie(case, topk):
+    """`index_topk` bisects the cut's position, 9 passes at 512 keys, only
+    in a block of rows where some row's threshold is tied ACROSS its
+    selection; every other block takes the last key at the threshold in
+    one pass.  Either way `aux` is one number: the row's want-th largest
+    score and the last position taken at it, as a stable sort of the
+    kernels' own scores gives them, and the mask is that sort's."""
+    S, J, Di = 512, 3, 16
+    qi, ki, w, tied_blocks = _index_operands(case, S, J, Di)
+    kit = ki.transpose(0, 2, 1)
+    aux = sa.select(qi, kit, w, topk, 128)
+    keep = np.asarray(sa.keep_mask(qi, kit, aux, 128, 128))[0].astype(bool)
+    scores = np.asarray(sa.index_rows(qi, kit, w, 128))[0]
+    aux = np.asarray(aux)[0]
+    want = np.zeros((S, S), bool)
+    tau, cut = np.zeros(S, np.float32), np.zeros(S, np.float32)
+    straddled = np.zeros(S, bool)
+    for t in range(S):
+        row = scores[t, :t + 1]
+        order = np.argsort(-row, kind="stable")[:min(t + 1, topk)]
+        want[t, order] = True
+        tau[t] = row[order[-1]]
+        cut[t] = order[row[order] == tau[t]].max()
+        straddled[t] = (row >= tau[t]).sum() > len(order)
+    assert (keep == want).all()
+    assert (aux[:, :J] == np.asarray(w)[0]).all()
+    assert (aux[:, J].view(np.int32) == tau.view(np.int32)).all()
+    assert (aux[:, J + 1] == cut).all()
+    # the blocks the data were made to tie in, and no other
+    assert (np.flatnonzero(straddled.reshape(-1, 128).any(1)).tolist()
+            == list(tied_blocks))
+    short, long = sa.select_pass_counts(S)
+    assert (short, long) == (33, 41)
+    passes = np.asarray(sa.select_passes(aux, J))
+    assert (passes.reshape(-1, 128) == passes[::128, None]).all()
+    assert passes[::128].tolist() == [
+        long if block in tied_blocks else short for block in range(S // 128)]
+    if case == "short_rows":
+        # a row short of topk: its threshold is its least score, and the
+        # pair with one score is taken whole
+        assert (tau[:topk] == [scores[t, :t + 1].min()
+                               for t in range(topk)]).all()
+        assert (scores[1:, 0] == scores[1:, 1]).all()
+        assert (keep[1:topk, :2]).all()
+
+
+def test_the_model_reads_the_passes_of_every_layers_selection():
+    """`keye.select_passes` walks the layers as `chosen_keys` does and
+    gives every row the passes its block of `index_topk` ran: one of
+    `select_pass_counts`'s two numbers, the same in a block's 128 rows."""
+    family = _family(jnp.float32, tiny_keye.FLOAT32, layers=[0, 1])
+    tokens = seeded.batch(family, 0, 1)[0]
+    passes = np.asarray(jax.jit(lambda p, t: keye.select_passes(
+        p, t, family.cfg, family._streams(t)))(
+            seeded.params(family, 0), tokens))
+    assert passes.shape == (2, 1, family.seq_len)
+    assert set(np.unique(passes)) <= set(sa.select_pass_counts(
+        family.seq_len))
+    blocks = passes.reshape(2, -1, sa.select_rows(family.seq_len))
+    assert (blocks == blocks[..., :1]).all()
+
+
 def test_rows_short_of_topk_attend_to_everything():
     """A sequence no longer than `topk`: every row takes all it sees, and
     the layer's attention is dense causal attention."""
@@ -302,7 +390,11 @@ def test_parameter_count_at_the_published_widths():
 # gathers of single numbers are compares and sums, the plan sorts twice)
 # and nothing else of them: a later PR that does not touch
 # `parallel/dropless_moe.py` or `ops/moe_rows.py` leaves all four as they
-# are (a moved line in either file does not change the text).
+# are (a moved line in either file does not change the text).  keye was
+# hashed again on PR 54's tree, which changes the body of ITS kernel
+# `index_topk` (`ops/sparse_attention.py` `_select_kernel`: the passes a
+# block runs) and nothing else; the other three do not call it and kept
+# their texts.
 PARENTS_LOWERED_STEPS = {
     "mellum": (tiny_mellum.config, family_mellum,
                "87775117ae84fe979b9bfa072483b80f"
@@ -314,8 +406,8 @@ PARENTS_LOWERED_STEPS = {
              "fc640a6643cb19be841478581525d3dd"
              "5a3b42543fb3656c8c3f6d063f8187ca"),
     "keye": (tiny_keye.config, family_keye,
-             "8c487d54c58a84667b36c77d699224d0"
-             "2d04f7ea03c90df5ff0b7218783d05c4"),
+             "dc53e17b9f55173a83df5b93ea72df31"
+             "6111c3424b0dcb0858a7bccbc9d391b1"),
 }
 
 

@@ -171,6 +171,10 @@ def test_a_layer_selects_once_and_scores_its_tiles_once(policy, calls):
     assert {name: text.count(f"name={name}") for name in calls} == calls
     metrics = bps.get_metrics()
     assert metrics["bps_sparse_index_passes"] == 1
+    # a bit of the threshold a pass, then one pass or a bit of the cut's
+    assert (metrics["bps_sparse_select_passes_min"],
+            metrics["bps_sparse_select_passes_max"]) == (
+                33, 32 + (rows - 1).bit_length())
     assert metrics["bps_sparse_rows"] == rows
     # every row block's words up to its diagonal's chunk
     assert metrics["bps_sparse_mask_bytes"] == sum(

@@ -68,6 +68,30 @@ def _expert_extras(family, cell, params, seed, variant):
     return out
 
 
+def _keye_extras(family, cell, params, seed, variant):
+    """The expert decoders' extras and, for the program as it is,
+    `select_passes`: a layer's blocks of rows of `index_topk` by the
+    passes over their slab of scores they ran, the longer count where a
+    block had a tie at a threshold to break."""
+    import jax
+    import numpy as np
+
+    from benchmark.harness import seeded
+    from byteps_tpu.models import keye
+    from byteps_tpu.ops import sparse_attention
+    out = _expert_extras(family, cell, params, seed, variant)
+    if variant is None and family.cfg.attn_impl == "flash":
+        tokens = seeded.batch(family, seed, 1)[0]
+        passes = jax.jit(lambda p, t: keye.select_passes(
+            p, t, family.cfg, family._streams(t)))(params, tokens)
+        rows = sparse_attention.select_rows(tokens.shape[1])
+        out["select_passes"] = [
+            {int(n): int(blocks) for n, blocks in zip(
+                *np.unique(layer[0, ::rows], return_counts=True))}
+            for layer in jax.device_get(passes)]
+    return out
+
+
 def _granitehybrid_extras(family, cell, params, seed, variant):
     """The scan alone in float32 against the recurrence, the number that
     reaches `correct` through the loss, a sample of the check."""
@@ -234,7 +258,7 @@ def _joyai_record(cell, out: str) -> int:
 
 
 EXTRAS = {"afmoe": _expert_extras, "mellum": _expert_extras,
-          "keye": _expert_extras, "nemotronh": _expert_extras,
+          "keye": _keye_extras, "nemotronh": _expert_extras,
           "joyai": _expert_extras, "granitehybrid": _granitehybrid_extras}
 RECORD = {"granitehybrid": _granitehybrid_record, "mellum": _mellum_record,
           "keye": _keye_record, "nemotronh": _nemotronh_record,
