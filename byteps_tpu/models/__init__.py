@@ -15,8 +15,11 @@ attention), `keye` (attention over the keys a learned indexer selects,
 rotary positions in three streams), `granite_hybrid` (state-space
 scans beside attention), `nemotron_h` (layers that are one part each:
 a Mamba-2 mixer in 8 groups, squared-ReLU experts, grouped attention;
-stacked by kind) and `joyai` (latent attention, a sigmoid router with a
-shared expert behind one dense layer, a multi-token-prediction module).  Their attention calls come from one table,
+stacked by kind), `joyai` (latent attention, a sigmoid router with a
+shared expert behind one dense layer, a multi-token-prediction module)
+and `lfm2` (a doubly gated short convolution or grouped attention as a
+layer's mixer, a dense SwiGLU or sigmoid-routed experts as its
+feed-forward, scanned a run of one kind at a time).  Their attention calls come from one table,
 `afmoe._ATTENTION`: `sliding_attention`, `full_attention`,
 `selected_attention`.
 """

@@ -98,6 +98,7 @@ class MoEConfig:
     held: Tuple[int, ...]            # ids of the experts this chip holds
     route_scale: float = 1.0
     route_norm: bool = True          # weights of a token sum to route_scale
+    norm_eps: float = 1e-20          # beside that sum (lfm2's is 1e-6)
     score_func: str = "sigmoid"      # "sigmoid" | "softmax"
     capacity_factor: float = 1.25    # the buffer over the even share
     row_multiple: int = 512          # the buffer is a multiple of this
@@ -176,7 +177,7 @@ def route(x, router_w, cfg: MoEConfig, expert_bias=None, sel=None):
     sel = checkpoint_name(sel, ROUTING_NAME)
     weights = _chosen(scores, sel)
     if cfg.route_norm:
-        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+        weights = weights / (weights.sum(-1, keepdims=True) + cfg.norm_eps)
     if cfg.hold_held_weight and len(cfg.held) < cfg.num_experts:
         weights = _held_weight_held(weights, sel, cfg)
     return sel, checkpoint_name(weights * cfg.route_scale, ROUTING_NAME)

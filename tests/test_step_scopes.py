@@ -26,7 +26,8 @@ import byteps_tpu as bps
 from benchmark.harness import measure
 from benchmark.tests import (tiny, tiny_afmoe, tiny_granitehybrid,  # noqa: F401
                              tiny_joyai,
-                             tiny_keye, tiny_mellum,    # join `tiny`'s table
+                             tiny_keye, tiny_lfm2,
+                             tiny_mellum,               # join `tiny`'s table
                              tiny_nemotronh)
 from byteps_tpu.common import devprof
 from byteps_tpu.ops import flash_attention as fa
@@ -91,6 +92,12 @@ FAMILIES = {
                "joyai.mtp/joyai.attn/qkv", "joyai.mtp/joyai.attn/out",
                "joyai.mtp/joyai.moe/grouped", "joyai.mtp/joyai.moe/shared",
                "joyai.head", "byteps.optimizer"}, True),
+    "lfm2": ("lfm2-24b-a2b.ingraph-1chip",
+             {"lfm2.embed", "lfm2.conv.in_proj", "lfm2.conv.gate_conv",
+              "lfm2.conv.out_proj", "lfm2.attn", "lfm2.attn/qkv",
+              "lfm2.attn/out", "lfm2.dense", "lfm2.moe", "lfm2.moe/route",
+              "lfm2.moe/gather", "lfm2.moe/grouped", "lfm2.moe/scatter",
+              "lfm2.moe/exact", "lfm2.head", "byteps.optimizer"}, True),
 }
 # The names the device trace was read by before this map: an unnamed
 # kernel call is called after the innermost scope around it.  The expert
@@ -118,6 +125,7 @@ KERNEL_SCOPES = {
                   *_moe("nemotronh.moe")},
     "joyai": {"joyai.attn", *_moe("joyai.moe"), "joyai.mtp/joyai.attn",
               *_moe("joyai.mtp/joyai.moe")},
+    "lfm2": {"lfm2.conv.gate_conv", "lfm2.attn", *_moe("lfm2.moe")},
 }
 PRODUCTS = ("fusion", "custom-call", "dot", "convolution", "ragged-dot")
 WORK = ("dot_general", "conv_general_dilated", "pallas_call")
@@ -184,6 +192,11 @@ def _family(name: str):
         config["job"]["seq_len"] = 128
         cell = dataclasses.replace(cell, config=config,
                                    job={**cell.job, **config["job"]})
+    elif name == "lfm2":    # conv dense, attention expert, conv expert
+        config = tiny_lfm2.config(layers=[1, 2, 3])
+        config["published"].update(tiny_lfm2.ON_THE_CHIP)
+        config["assumed"]["head_dim"] = 64
+        cell = dataclasses.replace(cell, config=config)
     if name in ("afmoe", "mellum", "keye"):
         # the narrowest widths the grouped kernels tile: a lane tile each
         # (the tiny cuts' 64 and 32 go to `lax.ragged_dot`, the compiler's)
@@ -296,7 +309,7 @@ def test_every_familys_tiny_step_is_mapped(name, v5e, kernels,
     grouped = {n: e for n, e in kernels.items()
                if n.startswith("ragged-dot-none_")}
     moves = {n: e for n, e in kernels.items() if n.startswith("moe_rows_")}
-    if name in ("afmoe", "mellum", "keye", "nemotronh", "joyai"):
+    if name in ("afmoe", "mellum", "keye", "nemotronh", "joyai", "lfm2"):
         # the rows move by the program's kernel in every pass, under the
         # scopes `moe.move_ms` and `moe.move_kernel_share` read
         assert all(e["scope"].rsplit("/", 1)[1] in ("gather", "scatter")

@@ -874,3 +874,105 @@ def test_joyai_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch,
             f"{seconds:.0f} s")
     print(said)
     assert mem.peak_memory_in_bytes < 16.9e9, said
+
+
+def test_gated_short_conv_compiles_at_the_cells_shape(v5e):
+    """`ops/short_conv.py` at [4, 8192, 3 x 2048] bfloat16, forward and
+    backward: two Mosaic calls under their names, every result 2-D (what
+    keeps the benchmark's attention readers off them), the reshape round
+    them free and no temporary beside them."""
+    from benchmark.reduce import afmoe_cost, conv_cost
+    from byteps_tpu.ops import short_conv
+    one = SingleDeviceSharding(v5e[0])
+    bcx = jax.ShapeDtypeStruct((4, 8192, 6144), jnp.bfloat16, sharding=one)
+    taps = jax.ShapeDtypeStruct((3, 2048), jnp.float32, sharding=one)
+    g = jax.ShapeDtypeStruct((4, 8192, 2048), jnp.bfloat16, sharding=one)
+
+    def both(bcx, taps, g):
+        y, vjp = jax.vjp(lambda a, b: short_conv.gated_short_conv(
+            a, b, interpret=False), bcx, taps)
+        return y, vjp(g)
+    compiled = _compile(both, bcx, taps, g)
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in compiled.as_text().splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    assert sorted(map(conv_cost.call, calls)) == [
+        ("bwd", 32768, 2048), ("fwd", 32768, 2048)]
+    assert not any(afmoe_cost.attention_call(c) for c in calls)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("layers", [[1, 2], [3, 4, 5]],
+                         ids=["dense_conv_and_attention", "three_convs_run"])
+def test_lfm2_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch,
+                                                      layers):
+    """The lfm2 cell's step (`benchmark/configs/lfm2-24b-a2b.json`: four
+    sequences of 8,192, a plain `value_and_grad` and adamw, embedding,
+    tied head and cross-entropy included) for one described chip, in two
+    parts of the cell, because its five runs' bodies take 50 s to compile
+    alone and more beside five other workers: the dense conv layer with
+    the first attention layer, and the run of three conv expert layers
+    under its one scan.  A conv layer is the two `short_conv` calls,
+    the forward one made once again under remat; an attention layer the
+    RESIDENT flash kernels at head size 64, its `o` and `lse` kept; the
+    experts' products at width 1536 the program's own kernels.  The whole
+    cell, compiled the same way (PR 55): arguments 7,774,222,848,
+    temporaries 10,156,730,368, peak 15,573,386,752 + 76,397,056 of code,
+    of 16.91e9."""
+    import json
+
+    import optax
+
+    from benchmark.families import lfm2 as family_lfm2
+    from benchmark.harness import manifest
+    from benchmark.reduce import afmoe_cost, conv_cost
+    monkeypatch.setattr(fa, "_use_interpret", lambda interpret: False)
+    with open(os.path.join(manifest.BENCH, "configs",
+                           "lfm2-24b-a2b.json")) as f:
+        config = json.load(f)
+    dense = sum(i < 2 for i in layers)
+    config["held"].update(layers=layers, num_hidden_layers=len(layers),
+                          num_dense_layers=dense)
+    family = family_lfm2.Family(config, config["job"])
+    opt = family.optimizer()
+    one = SingleDeviceSharding(v5e[0])
+
+    def step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(family.loss)(params, batch)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+    params = jax.eval_shape(family.init, jax.random.key(0))
+    opt_state = jax.eval_shape(opt.init, params)
+    batch = jax.eval_shape(
+        lambda k: family.make_batch(k, config["job"]["per_chip_batch"]),
+        jax.random.key(0))
+    t0 = time.perf_counter()
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(opt_state), on_chip(batch)).compile()
+    seconds = time.perf_counter() - t0
+    text = compiled.as_text()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    conv = sorted(c[0] for c in map(conv_cost.call, calls) if c)
+    # one body a run that has the mixer: forward, its recompute, backward
+    assert conv == ["bwd", "fwd", "fwd"], conv
+    flash = sorted(c for c in map(afmoe_cost.attention_call, calls) if c)
+    assert flash == [(kind, 128, 8192, 64, None) for kind in (
+        "dkv", "dq", "forward")] * (2 in layers), flash
+    grouped = {afmoe_cost.grouped_call(c) for c in calls
+               if afmoe_cost.is_grouped(c)}
+    assert grouped == {(8, 2048, 1536), (8, 1536, 2048)}
+    assert "ragged-dot-metadata" not in text
+    _assert_rows_move_by_kernel(text, 2048, 4)
+    mem = compiled.memory_analysis()
+    said = (f"arguments {mem.argument_size_in_bytes:,} temporaries "
+            f"{mem.temp_size_in_bytes:,} peak {mem.peak_memory_in_bytes:,} "
+            f"code {mem.generated_code_size_in_bytes:,} compiled in "
+            f"{seconds:.0f} s")
+    print(said)
+    assert mem.peak_memory_in_bytes < 16.9e9, said

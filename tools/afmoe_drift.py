@@ -51,7 +51,8 @@ def main(argv=None) -> int:
     compile_cache.enable()
     if args.tiny:
         from benchmark.tests import (tiny, tiny_afmoe,  # noqa: F401
-                                     tiny_keye, tiny_mellum, tiny_nemotronh)
+                                     tiny_keye, tiny_lfm2, tiny_mellum,
+                                     tiny_nemotronh)
         cell = tiny.tiny_cell(args.workload)
     else:
         cell = manifest.load_cell(args.workload)

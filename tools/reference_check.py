@@ -259,7 +259,8 @@ def _joyai_record(cell, out: str) -> int:
 
 EXTRAS = {"afmoe": _expert_extras, "mellum": _expert_extras,
           "keye": _keye_extras, "nemotronh": _expert_extras,
-          "joyai": _expert_extras, "granitehybrid": _granitehybrid_extras}
+          "joyai": _expert_extras, "lfm2": _expert_extras,
+          "granitehybrid": _granitehybrid_extras}
 RECORD = {"granitehybrid": _granitehybrid_record, "mellum": _mellum_record,
           "keye": _keye_record, "nemotronh": _nemotronh_record,
           "joyai": _joyai_record}
