@@ -553,6 +553,17 @@ _STATIC = {
             "a run's set-up pays for, the same for four unrolled layers "
             "as for one"),
     },
+    "mamba_conv": {
+        "kernel": _gauge(
+            "bps_mamba_conv_kernel",
+            "1 where the last traced Mamba-2 mixer runs its convolution, "
+            "bias and silu as the program's Pallas kernels "
+            "(ops/short_conv.py mamba_conv)"),
+        "rows": _gauge(
+            "bps_mamba_conv_rows",
+            "rows a grid step of the last traced call `call` (`fwd`, "
+            "`bwd`) of those kernels takes"),
+    },
     "sparse_attention": {
         "rows": _gauge(
             "bps_sparse_rows",

@@ -76,7 +76,7 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ..common import telemetry
-from ..ops import flash_attention, ssd
+from ..ops import flash_attention, short_conv, ssd
 from .transformer import _rms_norm, flash_attention_fn, fused_nll_sum
 
 PyTree = Any
@@ -231,7 +231,17 @@ def _norm(x, scale, cfg):
 
 
 def _conv(xbc, lp):
-    return jax.nn.silu(ssd.causal_conv1d(xbc, lp["conv_w"], lp["conv_b"]))
+    """The mixer's convolution, bias and silu, [B, S, C] -> [B, S, C]:
+    `ops/short_conv.py`'s kernels (the benchmark's broken variants patch
+    this name with the `jnp` form, `ssd.causal_conv1d` and a silu).  The
+    call writes x, B and C as three arrays, x as wide as the gated norm's
+    scale, and the backward call reads their three cotangents: joined
+    here only for `_mamba` to split again, which the compiler folds away,
+    so neither the split nor its transpose is a pass over the array."""
+    inner = lp["gate_norm"].shape[-1]
+    state = (xbc.shape[-1] - inner) // 2
+    return jnp.concatenate(short_conv.mamba_conv(
+        xbc, lp["conv_w"], lp["conv_b"], parts=(inner, state, state)), -1)
 
 
 def _step_size(raw, dt_bias):

@@ -1,4 +1,6 @@
-"""A doubly gated short convolution as one Pallas TPU kernel each way.
+"""Short causal convolutions as one Pallas TPU kernel each way: two
+operators on one walk.  The doubly gated one (`gated_short_conv`), which
+this docstring describes first:
 
     y_t = C_t * sum_{k < K} w_k * (B * X)_{t-(K-1)+k}        [B | C | X] = bcx
 
@@ -56,6 +58,35 @@ the Pallas interpreter, as `ops/grouped_matmul.py`'s do.  The calls are
 under a plain `jax.jit`, so a process traces and lowers one body a shape
 (`ops/moe_rows.py` says what that saves a run's set-up), and they carry
 the names the device trace shows: `short_conv_fwd`, `short_conv_bwd`.
+
+The second operator (`mamba_conv`, PR 56) is the Mamba-2 mixers'
+(`models/granite_hybrid.py` `_conv`, and through it
+`models/nemotron_h.py`'s):
+
+    y_t = silu( bias + sum_{k < K} w_k x_{t-(K-1)+k} )    x = xBC [batch, S, C]
+
+the same blocks, halo, rotation and sum of `dw` over the sequential grid
+(the bias's gradient is one more row of that block), other arithmetic an
+element: bias, taps, sums and the silu in float32, ONE rounding to the
+result's dtype (the jnp form, `ops/ssd.py` `causal_conv1d` and a silu,
+rounds the sum and then takes the silu).  Backward from x and dy alone: the
+pre-activation a is made again, da = dy * silu'(a), dx_t = sum_k w_k
+da_{t+(K-1)-k}, dw_k = sum_t da_t x_{t-(K-1)+k}, dbias = sum_t da_t.  What
+the gated kernel did not have to do: the rows of da AFTER a block need a
+there, a convolution over the joined edge (the block's last rows before
+the next halo's first), not a product of two halos.  What it does
+otherwise, because on the chip these kernels are bound by the vector unit
+and its registers, not by memory (PERF.md, Findings, PR 56): a shifted
+tile is a rotation with NO select a row where the rows of a block divide a
+sequence (the tile's first or last 8 rows are then patched from a joined
+16-row piece, and a scalar says whether the halo is another sequence's);
+the sigmoid is one tanh; a grid step takes 128 rows and the inner loop 128
+lanes.  The result can come as several arrays, stretches of its lanes
+(`parts`: x, B and C as the scan takes them), whose cotangents the
+backward call reads as they are: a caller's split costs no pass.  The
+calls name themselves `mamba_conv_fwd` / `mamba_conv_bwd`: no reader of
+the benchmark's takes them for the gated calls (`^short_conv_`), the
+scan's or a flash call (every result is 2-D).
 """
 
 from __future__ import annotations
@@ -69,6 +100,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..common import telemetry
 from . import flash_attention
 
 _F32 = jnp.float32
@@ -334,6 +366,294 @@ def gated_short_conv(bcx: jax.Array, w: jax.Array, block_rows: int = 0,
     y = _conv(bcx.reshape(batch * seq_len, wide), w, seq_len, block_rows,
               flash_attention._use_interpret(interpret))
     return y.reshape(batch, seq_len, wide // 3)
+
+
+# ---------------------------------------------------------------------------
+# The Mamba-2 mixers' operator: taps, a bias and a silu
+# ---------------------------------------------------------------------------
+MAMBA_FWD_NAME, MAMBA_BWD_NAME = "mamba_conv_fwd", "mamba_conv_bwd"
+# Rows a grid step takes and lanes of the inner loop's chunk, at most.  On
+# the chip (PERF.md, Findings, PR 56; [8192, 4352] and [16384, 6144]) a
+# [128, 128] float32 tile is what the backward kernel's dozen live values
+# fit the registers at: 128 x 128 took 0.38 / 1.04 ms backward where
+# 256 x 512 took 0.56 / 1.88, forward 0.23 / 0.65 either way; 64 rows pay
+# their halo (16 rows a block) and 32 more.
+MAMBA_BLOCK_ROWS = 128
+MAMBA_CHUNK = 128
+
+
+# Rows of a float32 tile: what the kernels below patch a shifted tile's
+# edge with.
+EDGE = 8
+
+
+def _sigmoid(a):
+    """By tanh: one pass of the transcendental unit and no division."""
+    return 0.5 * jnp.tanh(0.5 * a) + 0.5
+
+
+def _silu_slope(a):
+    """silu'(a) = s (1 + a (1 - s)), s the sigmoid."""
+    s = _sigmoid(a)
+    return s * (1.0 + a * (1.0 - s))
+
+
+def _part_chunks(parts):
+    """`[(first lane, lanes of a chunk, chunks)]` of each part of the
+    width: a lane tile where tiles divide the part and its first lane,
+    else the part as one chunk."""
+    out, base = [], 0
+    for width in parts:
+        chunk = MAMBA_CHUNK if (
+            width % MAMBA_CHUNK == 0 and base % MAMBA_CHUNK == 0) else width
+        out.append((base, chunk, width // chunk))
+        base += width
+    return out
+
+
+def _moved_back(x, before, d: int):
+    """`x` [rows, n] float32 moved `d` < EDGE rows down, its first rows
+    the last of `before` [EDGE, n]: a rotation of the tile, and one of its
+    first EDGE rows joined to `before`, for the rows the first has wrong."""
+    edge = pltpu.roll(jnp.concatenate([before, x[:EDGE]], 0), d, 0)[EDGE:]
+    if x.shape[0] == EDGE:
+        return edge
+    return jnp.concatenate([edge, pltpu.roll(x, d, 0)[EDGE:]], 0)
+
+
+def _moved_on(x, after, d: int):
+    """`x` moved `d` rows up, its last rows the first of `after`."""
+    n = x.shape[0]
+    edge = pltpu.roll(jnp.concatenate([x[n - EDGE:], after], 0),
+                      2 * EDGE - d, 0)[:EDGE]
+    if n == EDGE:
+        return edge
+    return jnp.concatenate([pltpu.roll(x, n - d, 0)[:n - EDGE], edge], 0)
+
+
+def _pre_activation(x, before, wb_ref, lanes, taps: int, pos=None):
+    """`(a, [x moved back by K-1-k for every tap k])`: bias + the taps
+    over `x` [rows, n] float32 whose rows before the first are `before`
+    [EDGE, n].  `pos` (each row's position in its sequence), where a
+    sequence may start inside the tile: a tap across a start is masked."""
+    moved = []
+    a = wb_ref[taps:taps + 1, lanes]
+    for k in range(taps):
+        d = taps - 1 - k
+        xk = _moved_back(x, before, d) if d else x
+        if d and pos is not None:
+            xk = jnp.where(pos >= d, xk, 0.0)
+        moved.append(xk)
+        a = a + xk * wb_ref[k:k + 1, lanes]
+    return a, moved
+
+
+def _tile(seq_len: int, rows: int, block, n: int):
+    """`(pos, first, last)` of a grid step's tile of `n` lanes.  Where
+    `rows` divides the sequences no sequence starts or ends inside a tile:
+    `pos` is None and the scalars `first` / `last` say whether the tile
+    starts / ends one (its neighbour's rows are then zeros).  Else `pos`
+    [rows, n] masks each tap by its row's position, and the scalars are
+    None."""
+    if seq_len % rows == 0:
+        start = (block * rows) % seq_len
+        return None, start == 0, start == seq_len - rows
+    return _positions(block, rows, n, seq_len), None, None
+
+
+def _rows_before(prev_ref, lanes, first):
+    """The EDGE rows before a tile, float32: the halo's last, or zeros
+    where the scalar `first` says the tile starts a sequence (None: the
+    taps are masked by position instead)."""
+    before = prev_ref[:, lanes].astype(_F32)[HALO - EDGE:]
+    return before if first is None else jnp.where(first, 0.0, before)
+
+
+def _mamba_fwd_kernel(x_ref, prev_ref, wb_ref, *y_refs, parts, taps,
+                      seq_len):
+    rows = x_ref.shape[0]
+    block = pl.program_id(0)
+    for y_ref, (base, chunk, n) in zip(y_refs, _part_chunks(parts)):
+        def body(c, y_ref=y_ref, base=base, chunk=chunk):
+            lanes = _lanes(c, chunk, base)
+            pos, first, _ = _tile(seq_len, rows, block, chunk)
+            a, _ = _pre_activation(
+                x_ref[:, lanes].astype(_F32),
+                _rows_before(prev_ref, lanes, first), wb_ref, lanes, taps,
+                pos)
+            y_ref[:, _lanes(c, chunk)] = (a * _sigmoid(a)).astype(
+                y_ref.dtype)
+
+        _over_chunks(n, body)
+
+
+def _mamba_bwd_kernel(x_ref, prev_ref, next_ref, wb_ref, *refs, parts, taps,
+                      seq_len, total):
+    """`refs`: a block of each part's `dy`, then the halo after it of each,
+    then the two results `dx` and `dwb`."""
+    dy_refs, dy_next_refs = refs[:len(parts)], refs[len(parts):-2]
+    dx_ref, dwb_ref = refs[-2:]
+    rows = x_ref.shape[0]
+    block = pl.program_id(0)
+    ragged = total % rows != 0
+
+    @pl.when(block == 0)
+    def _():
+        dwb_ref[...] = jnp.zeros_like(dwb_ref)
+
+    for dy_ref, dy_next_ref, (base, chunk, n) in zip(
+            dy_refs, dy_next_refs, _part_chunks(parts)):
+        def body(c, dy_ref=dy_ref, dy_next_ref=dy_next_ref, base=base,
+                 chunk=chunk):
+            lanes, own = _lanes(c, chunk, base), _lanes(c, chunk)
+            pos, first, last = _tile(seq_len, rows, block, chunk)
+            x, dy = x_ref[:, lanes].astype(_F32), dy_ref[:, own].astype(_F32)
+            if ragged:
+                # a padded block holds whatever past the last row
+                live = block * rows + _rows((rows, chunk)) < total
+                x, dy = jnp.where(live, x, 0.0), jnp.where(live, dy, 0.0)
+            a, moved = _pre_activation(
+                x, _rows_before(prev_ref, lanes, first), wb_ref, lanes, taps,
+                pos)
+            da = dy * _silu_slope(a)
+            # the NEXT tile's first rows of da, for the taps that reach
+            # forward: their pre-activation is a convolution over the
+            # joined edge, this tile's last rows standing before the
+            # next's first (past a sequence's end they are masked, or
+            # zeros, whatever the halo holds)
+            edge, _ = _pre_activation(
+                next_ref[:, lanes].astype(_F32)[:EDGE], x[rows - EDGE:],
+                wb_ref, lanes, taps, None if pos is None else (
+                    ((block + 1) * rows + _rows((EDGE, chunk))) % seq_len))
+            after = (dy_next_ref[:, own].astype(_F32)[:EDGE]
+                     * _silu_slope(edge))
+            if pos is None:
+                after = jnp.where(last, 0.0, after)
+            dx = None
+            for k in range(taps):
+                d = taps - 1 - k
+                dak = _moved_on(da, after, d) if d else da
+                if d and pos is not None:
+                    dak = jnp.where(pos < seq_len - d, dak, 0.0)
+                term = dak * wb_ref[k:k + 1, lanes]
+                dx = term if dx is None else dx + term
+                dwb_ref[k:k + 1, lanes] += (da * moved[k]).sum(
+                    0, keepdims=True)
+            dwb_ref[taps:taps + 1, lanes] += da.sum(0, keepdims=True)
+            dx_ref[:, lanes] = dx.astype(dx_ref.dtype)
+
+        _over_chunks(n, body)
+
+
+def _taps_and_bias(w, bias):
+    """`_taps_block` with the bias in the row after the taps."""
+    return _taps_block(w).at[w.shape[0]].set(bias.astype(_F32))
+
+
+@functools.partial(jax.jit, static_argnames=("parts", "seq_len",
+                                             "block_rows", "interpret"))
+def _mamba_fwd_call(x, w, bias, *, parts, seq_len, block_rows, interpret):
+    total, width = x.shape
+    rows, steps, per, _ = _blocks(total, block_rows)
+    item = x.dtype.itemsize
+    telemetry.record_static("mamba_conv", kernel=1)
+    telemetry.record_static("mamba_conv", labels={"call": "fwd"}, rows=rows)
+    return pl.pallas_call(
+        functools.partial(_mamba_fwd_kernel, parts=parts, taps=w.shape[0],
+                          seq_len=seq_len),
+        grid=(steps,),
+        in_specs=[
+            pl.BlockSpec((rows, width), lambda i: (i, 0)),
+            pl.BlockSpec((HALO, width),
+                         lambda i: (jnp.maximum(i * per - 1, 0), 0)),
+            pl.BlockSpec((8, width), lambda i: (0, 0))],
+        out_specs=[pl.BlockSpec((rows, p), lambda i: (i, 0)) for p in parts],
+        out_shape=[jax.ShapeDtypeStruct((total, p), x.dtype) for p in parts],
+        compiler_params=_params(
+            2 * item * (2 * rows + HALO) * width + 8 * 4 * rows * MAMBA_CHUNK),
+        interpret=interpret, name=MAMBA_FWD_NAME,
+    )(x, x, _taps_and_bias(w, bias))
+
+
+@functools.partial(jax.jit, static_argnames=("seq_len", "block_rows",
+                                             "interpret"))
+def _mamba_bwd_call(x, w, bias, dys, *, seq_len, block_rows, interpret):
+    total, width = x.shape
+    parts = tuple(dy.shape[1] for dy in dys)
+    rows, steps, per, halos = _blocks(total, block_rows)
+    item = x.dtype.itemsize
+    telemetry.record_static("mamba_conv", labels={"call": "bwd"}, rows=rows)
+
+    def before(i):
+        return jnp.maximum(i * per - 1, 0), 0
+
+    def after(i):
+        return jnp.minimum((i + 1) * per, halos - 1), 0
+
+    return pl.pallas_call(
+        functools.partial(_mamba_bwd_kernel, parts=parts, taps=w.shape[0],
+                          seq_len=seq_len, total=total),
+        grid=(steps,),
+        in_specs=[
+            pl.BlockSpec((rows, width), lambda i: (i, 0)),
+            pl.BlockSpec((HALO, width), before),
+            pl.BlockSpec((HALO, width), after),
+            pl.BlockSpec((8, width), lambda i: (0, 0)),
+            *[pl.BlockSpec((rows, p), lambda i: (i, 0)) for p in parts],
+            *[pl.BlockSpec((HALO, p), after) for p in parts]],
+        out_specs=[pl.BlockSpec((rows, width), lambda i: (i, 0)),
+                   pl.BlockSpec((8, width), lambda i: (0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((total, width), x.dtype),
+                   jax.ShapeDtypeStruct((8, width), _F32)],
+        compiler_params=_params(
+            2 * item * 3 * (rows + HALO) * width
+            + 16 * 4 * rows * MAMBA_CHUNK),
+        interpret=interpret, name=MAMBA_BWD_NAME,
+    )(x, x, x, _taps_and_bias(w, bias), *dys, *dys)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _mamba(x, w, bias, parts, seq_len, block_rows, interpret):
+    return tuple(_mamba_fwd_call(x, w, bias, parts=parts, seq_len=seq_len,
+                                 block_rows=block_rows, interpret=interpret))
+
+
+def _mamba_fwd(x, w, bias, parts, seq_len, block_rows, interpret):
+    return (_mamba(x, w, bias, parts, seq_len, block_rows, interpret),
+            (x, w, bias))
+
+
+def _mamba_bwd(parts, seq_len, block_rows, interpret, residuals, dys):
+    x, w, bias = residuals
+    taps = w.shape[0]
+    dx, dwb = _mamba_bwd_call(x, w, bias, tuple(dys), seq_len=seq_len,
+                              block_rows=block_rows, interpret=interpret)
+    return dx, dwb[:taps].astype(w.dtype), dwb[taps].astype(bias.dtype)
+
+
+_mamba.defvjp(_mamba_fwd, _mamba_bwd)
+
+
+def mamba_conv(x: jax.Array, w: jax.Array, bias: jax.Array, parts=None,
+               block_rows: int = 0, interpret: Optional[bool] = None):
+    """`silu(bias + conv(x))`: `x` [batch, S, C], `w` [K, C] (`w[K-1]`
+    meets the current position), `bias` [C] -> [batch, S, C] in `x`'s
+    dtype, differentiable in all three.  With `parts`, widths that sum to
+    C, the result comes as a tuple of its stretches of lanes, each an
+    array of its own that the call wrote (and whose cotangent the backward
+    call reads), so that a caller who splits the result pays no copy for
+    it.  `block_rows` 0 takes `MAMBA_BLOCK_ROWS`."""
+    batch, seq_len, width = x.shape
+    split = tuple(parts) if parts else (width,)
+    if (w.shape[1] != width or bias.shape != (width,) or sum(split) != width
+            or not 1 <= w.shape[0] <= 7):
+        raise ValueError(f"x {x.shape}, taps {w.shape}, bias {bias.shape} "
+                         f"and parts {split} do not fit, or more than 7 taps")
+    ys = _mamba(x.reshape(batch * seq_len, width), w, bias, split, seq_len,
+                block_rows or MAMBA_BLOCK_ROWS,
+                flash_attention._use_interpret(interpret))
+    ys = tuple(y.reshape(batch, seq_len, -1) for y in ys)
+    return ys if parts else ys[0]
 
 
 def kept_bytes(batch: int, seq_len: int, width: int, dtype) -> int:

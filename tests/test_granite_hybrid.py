@@ -23,6 +23,7 @@ from benchmark.tests import granitehybrid_variants as variants
 from benchmark.tests import tiny_granitehybrid
 from byteps_tpu.models import granite_hybrid as gh
 from byteps_tpu.ops import ssd
+from testutil import mixer_trains_as_with_the_jnp_convolution
 
 M, A = gh.MAMBA, gh.ATTENTION
 
@@ -73,6 +74,19 @@ def test_the_jnp_form_of_the_scan_trains_the_same_model():
     with variants.jnp_scan(family):
         got = _agreement(family)
     assert correct.agreement_ok(got, family.reference_check), got
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["float32", "bfloat16"])
+def test_the_convolutions_kernel_trains_the_model_the_jnp_form_did(
+        monkeypatch, dtype, tol):
+    """One mamba layer with the mixer's convolution on
+    `ops/short_conv.py`'s kernels against the same program with
+    `ssd.causal_conv1d` and a silu in its place: float32 to rounding,
+    bfloat16 to what one rounding less of xBC explains."""
+    mixer_trains_as_with_the_jnp_convolution(
+        _family(dtype, layers=[4]), monkeypatch, tol)
 
 
 @pytest.fixture(scope="module")

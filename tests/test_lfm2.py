@@ -131,6 +131,29 @@ def test_the_kernels_against_the_jnp_form(shape):
                                    rtol=1e-5)
 
 
+def test_the_gated_calls_lower_to_what_they_lowered_to():
+    """`gated_short_conv`'s two calls at the cell's shape
+    ([4, 8192, 3 x 2048] bfloat16), forward and backward: the first 16 hex
+    digits of sha256 over the lowered text AS PR 55'S TREE lowered it
+    (commit 0bd15b7), before `ops/short_conv.py` held a second operator.
+    The Mamba mixers' kernels share the module's helpers and must leave
+    this operator's bodies, and so the lfm2 cell's compiled step, alone; a
+    change that means to change them writes its own digits here."""
+    import hashlib
+    bcx = jax.ShapeDtypeStruct((4, 8192, 6144), jnp.bfloat16)
+    taps = jax.ShapeDtypeStruct((3, 2048), jnp.float32)
+    g = jax.ShapeDtypeStruct((4, 8192, 2048), jnp.bfloat16)
+
+    def both(bcx, taps, g):
+        y, vjp = jax.vjp(lambda a, b: short_conv.gated_short_conv(
+            a, b, interpret=True), bcx, taps)
+        return y, vjp(g)
+    text = jax.jit(both).lower(bcx, taps, g).as_text()
+    assert "loc(" not in text
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == (
+        "133b197c01105c1e")
+
+
 def test_the_kernel_is_causal_and_a_sequence_its_own():
     """One position perturbed: nothing before it moves, the two after it
     do, and nothing in the next sequence (no tap reaches across)."""

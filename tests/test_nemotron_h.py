@@ -28,7 +28,8 @@ from benchmark.tests import tiny_nemotronh
 from byteps_tpu.models import granite_hybrid, nemotron_h
 from byteps_tpu.ops import flash_attention, ssd
 from byteps_tpu.parallel import dropless_moe
-from testutil import eqns, is_flash_forward, is_product, named_bytes
+from testutil import (eqns, is_flash_forward, is_product,
+                      mixer_trains_as_with_the_jnp_convolution, named_bytes)
 
 _family, _agreement = tiny_nemotronh.family, tiny_nemotronh.agreement
 PUBLISHED = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
@@ -65,6 +66,18 @@ def test_against_reference(cut, dtype):
         # every pair falls on a held expert: 6 rows a token in the one
         # expert layer of the three
         assert family.routing_counters[-1]["held_rows_per_token"] == [6.0]
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 3e-2)],
+                         ids=["float32", "bfloat16"])
+def test_the_convolutions_kernel_trains_the_model_the_jnp_form_did(
+        monkeypatch, dtype, tol):
+    """One `M` layer with its convolution on `ops/short_conv.py`'s kernels
+    (through `granite_hybrid._mamba`, no edit of this model's) against the
+    same program with the jnp form in its place, as it was until PR 56."""
+    mixer_trains_as_with_the_jnp_convolution(
+        _family(dtype, layers=[4]), monkeypatch, tol)
 
 
 @pytest.mark.parametrize("pattern,counts", [

@@ -26,6 +26,7 @@ from benchmark.tests import (tiny_afmoe, tiny_granitehybrid, tiny_keye,
                              tiny_nemotronh)
 from byteps_tpu.models import granite_hybrid, nemotron_h
 from byteps_tpu.ops import flash_attention as fa
+from byteps_tpu.ops import short_conv
 from byteps_tpu.parallel import dropless_moe
 from testutil import (eqns, is_flash_forward, is_product, named_bytes,
                       tiny_gpt2_config)
@@ -191,7 +192,13 @@ def test_the_granite_step_keeps_its_own_names(monkeypatch):
     wide = cfg.d_inner + cfg.conv_dim + cfg.mamba_n_heads
     makers = {fa.KEPT_NAME: is_flash_forward,
               granite_hybrid.IN_PROJ_NAME:
-                  lambda e: is_product(e, (B, S, D), (D, wide))}
+                  lambda e: is_product(e, (B, S, D), (D, wide)),
+              # the convolution's kernel keeps nothing of its own: its
+              # residual is a slice of `in_proj`'s kept result, made again
+              # with the forward call whatever the policy lists
+              "mamba.conv": lambda e: (
+                  e.primitive.name == "pallas_call"
+                  and e.params["name"] == short_conv.MAMBA_FWD_NAME)}
 
     def made():
         return {name: _count(jax.grad(family.loss), (params, batch), maker)
@@ -199,6 +206,7 @@ def test_the_granite_step_keeps_its_own_names(monkeypatch):
     assert made() == {name: 1 if name in granite_hybrid.KEPT_NAMES else 2
                       for name in makers}
     named = named_bytes(jax.make_jaxpr(family.loss)(params, batch).jaxpr)
+    assert set(named) == set(granite_hybrid.KEPT_NAMES)
     metrics = bps.get_metrics()
     for name in granite_hybrid.KEPT_NAMES:
         assert metrics[f'bps_remat_kept_layers{{name="{name}"}}'] == 1

@@ -116,13 +116,14 @@ KERNEL_SCOPES = {
     "gpt2": {"transformer.attn"},
     "afmoe": {"afmoe.attn.sliding_attention", "afmoe.attn.full_attention",
               *_moe("afmoe.moe")},
-    "granitehybrid": {"granite.mamba.scan", "granite.attn"},
+    "granitehybrid": {"granite.mamba.scan", "granite.mamba.conv",
+                      "granite.attn"},
     "mellum": {"mellum.attn.sliding_attention", "mellum.attn.full_attention",
                *_moe("mellum.moe")},
     "keye": {"keye.attn.full_attention/select",
              "keye.attn.full_attention/sparse", *_moe("keye.moe")},
-    "nemotronh": {"nemotronh.mamba.scan", "nemotronh.attn",
-                  *_moe("nemotronh.moe")},
+    "nemotronh": {"nemotronh.mamba.scan", "nemotronh.mamba.conv",
+                  "nemotronh.attn", *_moe("nemotronh.moe")},
     "joyai": {"joyai.attn", *_moe("joyai.moe"), "joyai.mtp/joyai.attn",
               *_moe("joyai.mtp/joyai.moe")},
     "lfm2": {"lfm2.conv.gate_conv", "lfm2.attn", *_moe("lfm2.moe")},
@@ -327,6 +328,18 @@ def test_every_familys_tiny_step_is_mapped(name, v5e, kernels,
         assert "ragged-dot-metadata" not in text
     else:
         assert not grouped and not moves
+    if name in ("granitehybrid", "nemotronh"):
+        # the mixers' convolution is the program's kernel in every pass
+        # (PR 56), under the scope `mamba.conv_ms` and
+        # `mamba.conv_kernel_share` read
+        convs = {n: e for n, e in kernels.items()
+                 if n.startswith("mamba_conv_")}
+        assert {(n.split(".")[0], e["pass"]) for n, e in convs.items()} == {
+            ("mamba_conv_fwd", "forward"), ("mamba_conv_fwd", "recompute"),
+            ("mamba_conv_bwd", "backward")}
+        assert all(e["scope"].endswith(".mamba.conv")
+                   and e["op_name"].endswith("/pallas_call")
+                   for e in convs.values())
 
 
 def test_the_dp4_step_is_mapped_with_the_exchange_in_the_optimizer(

@@ -797,6 +797,84 @@ def test_nemotronh_four_unrolled_expert_layers_share_the_move_kernels(
                                            "moe_rows_k6"}
 
 
+def test_nemotronh_four_unrolled_mixers_share_the_convolutions_kernels(
+        v5e, monkeypatch):
+    """The nemotron_h step cut to its four `M` layers (0, 2, 4, 7), which
+    the model UNROLLS, each under its own `jax.checkpoint`: traced and
+    lowered, not compiled.  The convolution's calls sit under a plain
+    `jax.jit` (`ops/short_conv.py` `_mamba_fwd_call` / `_mamba_bwd_call`),
+    so the lowered module holds the forward kernel's body once for the
+    forward pass and once for the recompute and the backward kernel's
+    once, for four layers as for one, and a run's set-up pays their
+    tracing and lowering once (ROADMAP.md S6).  Prints the seconds beside
+    the parent's (PR 55's tree, the jnp form, this sandbox, second call:
+    traced in 0.74 s, lowered in 0.42 s; this tree read 1.04 and 0.84)."""
+    from byteps_tpu.ops import ssd
+    monkeypatch.setattr(fa, "_use_interpret", lambda interpret: False)
+    monkeypatch.setattr(ssd, "_use_interpret", lambda interpret: False)
+
+    def lowered(layers):
+        step, args = _nemotronh_step(layers, v5e[0])
+        t0 = time.perf_counter()
+        traced = step.trace(*args)
+        t1 = time.perf_counter()
+        text = traced.lower().as_text()
+        return text, t1 - t0, time.perf_counter() - t1
+
+    def bodies(text):
+        return sorted(re.findall(r'kernel_name = "(mamba_conv_[a-z]+)"',
+                                 text))
+    one_layer = bodies(lowered([4])[0])
+    text, trace_s, lower_s = lowered([0, 2, 4, 7])
+    print(f"four M layers: traced in {trace_s:.2f} s, lowered in "
+          f"{lower_s:.2f} s (the parent's 0.74 and 0.42), "
+          f"{bodies(text)} kernel bodies, {len(text) // 1000}k characters")
+    assert bodies(text) == one_layer == [
+        "mamba_conv_bwd", "mamba_conv_fwd", "mamba_conv_fwd"]
+
+
+@pytest.mark.parametrize("cell,seq_len,inner,state", [
+    ("granite", 8192, 4096, 128), ("nemotron", 16384, 4096, 1024)])
+def test_mamba_conv_compiles_at_the_cells_shapes(v5e, cell, seq_len, inner,
+                                                 state):
+    """`ops/short_conv.py` `mamba_conv` at [1, 8192, 4352] and
+    [1, 16384, 6144] bfloat16, x, B and C as three results, forward and
+    backward: two Mosaic calls under their names, every result 2-D, and no
+    reader of the benchmark's takes either for a kernel of its own (the
+    gated convolution's, the scan's, a flash call); the reshape round them
+    free and no temporary beside them."""
+    from benchmark.reduce import afmoe_cost, conv_cost, flash_cost, ssd_cost
+    from byteps_tpu.ops import short_conv
+    one = SingleDeviceSharding(v5e[0])
+    width = inner + 2 * state
+    parts = (inner, state, state)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def both(x, w, b, gs):
+        ys, vjp = jax.vjp(lambda *a: short_conv.mamba_conv(
+            *a, parts=parts, interpret=False), x, w, b)
+        return ys, vjp(gs)
+    compiled = _compile(
+        both, shape(1, seq_len, width), shape(4, width, dtype=jnp.float32),
+        shape(width, dtype=jnp.float32),
+        tuple(shape(1, seq_len, p) for p in parts))
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in compiled.as_text().splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    assert sorted(c.split(" = ")[0].lstrip("%").split(".")[0]
+                  for c in calls) == ["mamba_conv_bwd", "mamba_conv_fwd"]
+    for call in calls:
+        assert conv_cost.call(call) is None
+        assert ssd_cost.scan_call(call) is None
+        assert flash_cost.classify(call) is None
+        assert not afmoe_cost.attention_call(call)
+        results = call.split(" custom-call(")[0]
+        assert not re.search(r"\[\d+,\d+,\d+", results), results
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 @pytest.mark.parametrize("layers,modules", [([0], 1), ([1, 2, 3, 4], 0)],
                          ids=["dense_layer_and_module", "expert_layers"])
 def test_joyai_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch,

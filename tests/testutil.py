@@ -156,3 +156,32 @@ class StubPSServer:
     def close(self):
         self._stop.set()
         self._accept.join(timeout=5)
+
+
+def mixer_trains_as_with_the_jnp_convolution(family, monkeypatch, tol):
+    """Loss and every gradient leaf of `family.loss`, the Mamba-2 mixers'
+    convolution on `ops/short_conv.py`'s kernels, against the same program
+    with `granite_hybrid._conv` as it was until PR 56 (`ssd.causal_conv1d`
+    and a silu), within `tol` of each leaf's norm."""
+    import jax
+    import jax.numpy as jnp
+
+    import byteps_tpu as bps
+    from benchmark.harness import seeded
+    from byteps_tpu.models import granite_hybrid
+    from byteps_tpu.ops import ssd
+    params = seeded.params(family, 0)
+    batch = seeded.batch(family, 0, family.reference_check["samples"])
+    loss, grads = jax.jit(jax.value_and_grad(family.loss))(params, batch)
+    assert bps.get_metrics()["bps_mamba_conv_kernel"] == 1
+    monkeypatch.setattr(
+        granite_hybrid, "_conv", lambda xbc, lp: jax.nn.silu(
+            ssd.causal_conv1d(xbc, lp["conv_w"], lp["conv_b"])))
+    want, want_grads = jax.jit(jax.value_and_grad(family.loss))(
+        params, batch)
+    assert abs(float(loss) - float(want)) <= tol * abs(float(want))
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, got), ref in zip(flat, jax.tree.leaves(want_grads)):
+        diff = float(jnp.linalg.norm((got - ref).astype(jnp.float32)))
+        assert diff <= tol * float(jnp.linalg.norm(ref)) + 1e-12, (
+            jax.tree_util.keystr(path), diff)
