@@ -463,6 +463,22 @@ _STATIC = {
             "both passes: 0 where the kernels read the mixer's layout, 4 "
             "in the jnp form"),
     },
+    "kda_scan": {
+        "layers": _gauge(
+            "bps_kda_scan_layers",
+            "layers of the last traced step that run the chunked delta-rule "
+            "scan (ops/kda.py)"),
+        "chunk": _gauge(
+            "bps_kda_chunk", "positions a chunk of that scan holds"),
+        "state_bytes": _gauge(
+            "bps_kda_state_bytes",
+            "bytes of chunk states ONE such layer keeps from its forward "
+            "pass for its backward pass"),
+        "kernel": _gauge(
+            "bps_kda_kernel",
+            "1 where the model calls that scan's Pallas form, 0 the jnp "
+            "form"),
+    },
     "layer_plan": {
         "stacks": _gauge(
             "bps_layer_plan_stacks",

@@ -16,10 +16,14 @@ rotary positions in three streams), `granite_hybrid` (state-space
 scans beside attention), `nemotron_h` (layers that are one part each:
 a Mamba-2 mixer in 8 groups, squared-ReLU experts, grouped attention;
 stacked by kind), `joyai` (latent attention, a sigmoid router with a
-shared expert behind one dense layer, a multi-token-prediction module)
-and `lfm2` (a doubly gated short convolution or grouped attention as a
+shared expert behind one dense layer, a multi-token-prediction module),
+`lfm2` (a doubly gated short convolution or grouped attention as a
 layer's mixer, a dense SwiGLU or sigmoid-routed experts as its
-feed-forward, scanned a run of one kind at a time).  Their attention calls come from one table,
+feed-forward, scanned a run of one kind at a time) and `kimi_linear` (a
+delta rule whose state decays a key channel at a time, the chunked
+kernels of `ops/kda.py`, three such layers to every one of latent
+attention without positions; 8-of-256 sigmoid-routed experts round a
+shared one).  Their attention calls come from one table,
 `afmoe._ATTENTION`: `sliding_attention`, `full_attention`,
 `selected_attention`.
 """

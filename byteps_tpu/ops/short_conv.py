@@ -546,7 +546,10 @@ def _mamba_bwd_kernel(x_ref, prev_ref, next_ref, wb_ref, *refs, parts, taps,
 
 
 def _taps_and_bias(w, bias):
-    """`_taps_block` with the bias in the row after the taps."""
+    """`_taps_block` with the bias in the row after the taps (zeros where
+    there is none)."""
+    if bias is None:
+        return _taps_block(w)
     return _taps_block(w).at[w.shape[0]].set(bias.astype(_F32))
 
 
@@ -628,26 +631,32 @@ def _mamba_bwd(parts, seq_len, block_rows, interpret, residuals, dys):
     taps = w.shape[0]
     dx, dwb = _mamba_bwd_call(x, w, bias, tuple(dys), seq_len=seq_len,
                               block_rows=block_rows, interpret=interpret)
-    return dx, dwb[:taps].astype(w.dtype), dwb[taps].astype(bias.dtype)
+    return (dx, dwb[:taps].astype(w.dtype),
+            None if bias is None else dwb[taps].astype(bias.dtype))
 
 
 _mamba.defvjp(_mamba_fwd, _mamba_bwd)
 
 
-def mamba_conv(x: jax.Array, w: jax.Array, bias: jax.Array, parts=None,
-               block_rows: int = 0, interpret: Optional[bool] = None):
+def mamba_conv(x: jax.Array, w: jax.Array, bias: Optional[jax.Array],
+               parts=None, block_rows: int = 0,
+               interpret: Optional[bool] = None):
     """`silu(bias + conv(x))`: `x` [batch, S, C], `w` [K, C] (`w[K-1]`
     meets the current position), `bias` [C] -> [batch, S, C] in `x`'s
-    dtype, differentiable in all three.  With `parts`, widths that sum to
-    C, the result comes as a tuple of its stretches of lanes, each an
-    array of its own that the call wrote (and whose cotangent the backward
-    call reads), so that a caller who splits the result pays no copy for
-    it.  `block_rows` 0 takes `MAMBA_BLOCK_ROWS`."""
+    dtype, differentiable in all three.  `bias` None is a convolution
+    without one (`models/kimi_linear.py`): the kernels' row for it holds
+    zeros and its gradient's row is left unread.  With `parts`, widths
+    that sum to C, the result comes as a tuple of its stretches of lanes,
+    each an array of its own that the call wrote (and whose cotangent the
+    backward call reads), so that a caller who splits the result pays no
+    copy for it.  `block_rows` 0 takes `MAMBA_BLOCK_ROWS`."""
     batch, seq_len, width = x.shape
     split = tuple(parts) if parts else (width,)
-    if (w.shape[1] != width or bias.shape != (width,) or sum(split) != width
+    if (w.shape[1] != width or sum(split) != width
+            or (bias is not None and bias.shape != (width,))
             or not 1 <= w.shape[0] <= 7):
-        raise ValueError(f"x {x.shape}, taps {w.shape}, bias {bias.shape} "
+        raise ValueError(f"x {x.shape}, taps {w.shape}, bias "
+                         f"{None if bias is None else bias.shape} "
                          f"and parts {split} do not fit, or more than 7 taps")
     ys = _mamba(x.reshape(batch * seq_len, width), w, bias, split, seq_len,
                 block_rows or MAMBA_BLOCK_ROWS,

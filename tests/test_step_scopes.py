@@ -26,7 +26,7 @@ import byteps_tpu as bps
 from benchmark.harness import measure
 from benchmark.tests import (tiny, tiny_afmoe, tiny_granitehybrid,  # noqa: F401
                              tiny_joyai,
-                             tiny_keye, tiny_lfm2,
+                             tiny_keye, tiny_kimilinear, tiny_lfm2,
                              tiny_mellum,               # join `tiny`'s table
                              tiny_nemotronh)
 from byteps_tpu.common import devprof
@@ -98,7 +98,17 @@ FAMILIES = {
               "lfm2.attn/out", "lfm2.dense", "lfm2.moe", "lfm2.moe/route",
               "lfm2.moe/gather", "lfm2.moe/grouped", "lfm2.moe/scatter",
               "lfm2.moe/exact", "lfm2.head", "byteps.optimizer"}, True),
+    "kimilinear": ("kimi-linear-48b-a3b-instruct.ingraph-1chip",
+                   {"kimi.embed", "kimi.kda.proj", "kimi.kda.conv",
+                    "kimi.kda.gates", "kimi.kda.scan", "kimi.kda.gate_norm",
+                    "kimi.kda.out_proj", "kimi.attn", "kimi.attn/qkv",
+                    "kimi.attn/out", "kimi.dense", "kimi.moe",
+                    "kimi.moe/route", "kimi.moe/gather", "kimi.moe/grouped",
+                    "kimi.moe/scatter", "kimi.moe/exact", "kimi.moe/shared",
+                    "kimi.head", "byteps.optimizer"}, True),
 }
+# Where a family's scopes start with another word than its name.
+SCOPE_PREFIX = {"kimilinear": "kimi"}
 # The names the device trace was read by before this map: an unnamed
 # kernel call is called after the innermost scope around it.  The expert
 # layer's grouped products are the program's own kernels since PR 40,
@@ -127,6 +137,8 @@ KERNEL_SCOPES = {
     "joyai": {"joyai.attn", *_moe("joyai.moe"), "joyai.mtp/joyai.attn",
               *_moe("joyai.mtp/joyai.moe")},
     "lfm2": {"lfm2.conv.gate_conv", "lfm2.attn", *_moe("lfm2.moe")},
+    "kimilinear": {"kimi.kda.conv", "kimi.kda.scan", "kimi.attn",
+                   *_moe("kimi.moe")},
 }
 PRODUCTS = ("fusion", "custom-call", "dot", "convolution", "ragged-dot")
 WORK = ("dot_general", "conv_general_dilated", "pallas_call")
@@ -197,6 +209,10 @@ def _family(name: str):
         config = tiny_lfm2.config(layers=[1, 2, 3])
         config["published"].update(tiny_lfm2.ON_THE_CHIP)
         config["assumed"]["head_dim"] = 64
+        cell = dataclasses.replace(cell, config=config)
+    elif name == "kimilinear":  # KDA dense, KDA expert, latent expert
+        config = tiny_kimilinear.config(layers=[1, 7, 8])
+        config["published"].update(tiny_kimilinear.ON_THE_CHIP)
         cell = dataclasses.replace(cell, config=config)
     if name in ("afmoe", "mellum", "keye"):
         # the narrowest widths the grouped kernels tile: a lane tile each
@@ -310,7 +326,9 @@ def test_every_familys_tiny_step_is_mapped(name, v5e, kernels,
     grouped = {n: e for n, e in kernels.items()
                if n.startswith("ragged-dot-none_")}
     moves = {n: e for n, e in kernels.items() if n.startswith("moe_rows_")}
-    if name in ("afmoe", "mellum", "keye", "nemotronh", "joyai", "lfm2"):
+    prefix = SCOPE_PREFIX.get(name, name)
+    if name in ("afmoe", "mellum", "keye", "nemotronh", "joyai", "lfm2",
+                "kimilinear"):
         # the rows move by the program's kernel in every pass, under the
         # scopes `moe.move_ms` and `moe.move_kernel_share` read
         assert all(e["scope"].rsplit("/", 1)[1] in ("gather", "scatter")
@@ -320,8 +338,8 @@ def test_every_familys_tiny_step_is_mapped(name, v5e, kernels,
             e["pass"] for e in moves.values()}
         assert {n.split(".")[0].rsplit("_", 1)[1] for n in grouped} == {
             "fwd", "drows", "dweights"}
-        assert all(e["scope"].startswith((f"{name}.moe/",
-                                          f"{name}.mtp/{name}.moe/"))
+        assert all(e["scope"].startswith((f"{prefix}.moe/",
+                                          f"{prefix}.mtp/{prefix}.moe/"))
                    and e["pass"] != "other" for e in grouped.values())
         assert {"forward", "recompute", "backward"} <= {
             e["pass"] for e in grouped.values()}
@@ -340,6 +358,21 @@ def test_every_familys_tiny_step_is_mapped(name, v5e, kernels,
         assert all(e["scope"].endswith(".mamba.conv")
                    and e["op_name"].endswith("/pallas_call")
                    for e in convs.values())
+    if name == "kimilinear":
+        # the scan and the convolution round it are the program's kernels
+        # in every pass, under the scopes `kimi.kda_mixer_ms` reads
+        mine = {(n.split(".")[0], e["pass"], e["scope"])
+                for n, e in kernels.items()
+                if n.startswith(("kda_", "mamba_conv_"))}
+        assert mine == {
+            (call + kind, which, "kimi.kda." + scope)
+            for call, scope in (("kda_", "scan"), ("mamba_conv_", "conv"))
+            for kind, which in (("fwd" + "_c64" * (call == "kda_"),
+                                 "forward"),
+                                ("fwd" + "_c64" * (call == "kda_"),
+                                 "recompute"),
+                                ("bwd" + "_c64" * (call == "kda_"),
+                                 "backward"))}
 
 
 def test_the_dp4_step_is_mapped_with_the_exchange_in_the_optimizer(

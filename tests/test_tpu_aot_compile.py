@@ -1054,3 +1054,119 @@ def test_lfm2_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch,
             f"{seconds:.0f} s")
     print(said)
     assert mem.peak_memory_in_bytes < 16.9e9, said
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+def test_kda_scan_compiles_at_the_cells_shape(v5e, dtype):
+    """The delta-rule scan of the kimi-linear cell (one sequence of 32,768
+    positions, 32 heads of 128, chunks of 64): forward and backward
+    kernels under the names a device trace tells them by, given q, k, v
+    and g as the mixer has them ([1, S, 4096]) and o read back the same
+    way.  The compiled module holds NO transposed copy of an array of
+    that size, value or gradient (beta's [S, 32] is the one operand laid
+    out anew), and none of the accepted readers takes the two calls for
+    its own."""
+    from benchmark.reduce import afmoe_cost, conv_cost, kda_cost, ssd_cost
+    from byteps_tpu.ops import kda
+    S, W, H = 32768, 4096, 32
+    one = SingleDeviceSharding(v5e[0])
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one)
+
+    def grads(q, k, v, g, beta, w):
+        def loss(q, k, v, g, beta):
+            o = kda.kda_scan(q, k, v, g, beta, interpret=False)
+            return (o * w).astype(jnp.float32).sum()
+        return jax.grad(loss, tuple(range(5)))(q, k, v, g, beta)
+
+    wide = shape(1, S, W, dtype=dtype)
+    compiled = _compile(grads, wide, wide, wide, shape(1, S, W),
+                        shape(1, S, H), wide)
+    text = compiled.as_text()
+    assert "kda_fwd_c64" in text and "kda_bwd_c64" in text
+    assert text.count("tpu_custom_call") == 2
+    assert not _wide_moves(text, S * W)
+    calls = [line for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    assert sorted(kda_cost.call(c) for c in calls) == [("bwd", 64),
+                                                       ("fwd", 64)]
+    assert not any(afmoe_cost.attention_call(c) or ssd_cost.scan_call(c)
+                   or conv_cost.call(c) for c in calls)
+    # the chunk states and little else: 32 x 512 x 64 KB
+    states = kda.state_bytes(1, H, S, 128, 128)
+    assert states == 1 << 30
+    assert compiled.memory_analysis().temp_size_in_bytes < states + (64 << 20)
+
+
+def test_kimilinear_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch):
+    """The kimi-linear cell's step
+    (`benchmark/configs/kimi-linear-48b-a3b-instruct.json`: ONE sequence
+    of 32,768, a plain `value_and_grad` and adamw, embedding, untied head
+    and cross-entropy included) for one described chip, cut to the model's
+    layer 1 (KDA, the dense SwiGLU): the new mixer whole, in 20 s alone
+    (the cell's three runs take 55 s to compile alone and 100 beside five
+    other workers; its latent-attention layer is the joyai cell's calls at
+    twice the length, its experts the other cells' kernels at width
+    1024).  The KDA layer is the scan's two calls and the convolution's
+    two, the forward ones made once again under remat (nothing of the
+    layer is kept), and NO transposed or re-tiled copy of an array of q's
+    size lies round them (a reshape of the scan's result to heads for its
+    norm made three a layer: `kimi_linear._gate_norm`).  The whole cell,
+    compiled the same way (PR 57): arguments 7,229,584,896, temporaries
+    9,255,834,624, peak 15,749,446,656 + 97,150,464 of code, of 16.91e9;
+    at 16,384 positions peak 14,127,934,976."""
+    import json
+
+    import optax
+
+    from benchmark.families import kimilinear
+    from benchmark.harness import manifest
+    from benchmark.reduce import kda_cost
+    from byteps_tpu.ops import ssd
+    monkeypatch.setattr(fa, "_use_interpret", lambda interpret: False)
+    monkeypatch.setattr(ssd, "_use_interpret", lambda interpret: False)
+    with open(os.path.join(manifest.BENCH, "configs",
+                           "kimi-linear-48b-a3b-instruct.json")) as f:
+        config = json.load(f)
+    config["held"].update(layers=[1], num_hidden_layers=1,
+                          layer_kinds=["kda"])
+    family = kimilinear.Family(config, config["job"])
+    opt = family.optimizer()
+    one = SingleDeviceSharding(v5e[0])
+
+    def step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(family.loss)(params, batch)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+    params = jax.eval_shape(family.init, jax.random.key(0))
+    opt_state = jax.eval_shape(opt.init, params)
+    batch = jax.eval_shape(
+        lambda k: family.make_batch(k, config["job"]["per_chip_batch"]),
+        jax.random.key(0))
+    t0 = time.perf_counter()
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(opt_state), on_chip(batch)).compile()
+    seconds = time.perf_counter() - t0
+    text = compiled.as_text()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    # forward, its recompute, backward
+    scan = sorted(c[0] for c in map(kda_cost.call, calls) if c)
+    assert scan == ["bwd", "fwd", "fwd"], scan
+    assert len([c for c in calls if c.startswith("%mamba_conv_")]) == 3
+    assert len(calls) == 6
+    assert not _wide_moves(text, 32768 * 4096)
+    mem = compiled.memory_analysis()
+    said = (f"arguments {mem.argument_size_in_bytes:,} temporaries "
+            f"{mem.temp_size_in_bytes:,} peak {mem.peak_memory_in_bytes:,} "
+            f"code {mem.generated_code_size_in_bytes:,} compiled in "
+            f"{seconds:.0f} s")
+    print(said)
+    assert mem.peak_memory_in_bytes < 16.9e9, said

@@ -51,16 +51,17 @@ def main(argv=None) -> int:
     compile_cache.enable()
     if args.tiny:
         from benchmark.tests import (tiny, tiny_afmoe,  # noqa: F401
-                                     tiny_keye, tiny_lfm2, tiny_mellum,
-                                     tiny_nemotronh)
+                                     tiny_keye, tiny_kimilinear,
+                                     tiny_lfm2, tiny_mellum, tiny_nemotronh)
         cell = tiny.tiny_cell(args.workload)
     else:
         cell = manifest.load_cell(args.workload)
     name = cell.config["family"]
     families = importlib.import_module(f"benchmark.families.{name}")
-    # (a family's model module is called after it, but for one)
+    # (a family's model module is called after it, but for two)
     model = importlib.import_module("byteps_tpu.models." + {
-        "nemotronh": "nemotron_h"}.get(name, name))
+        "nemotronh": "nemotron_h",
+        "kimilinear": "kimi_linear"}.get(name, name))
     pinned = cell.config["program_options"]["pinned"]
     option = "post_attn_norm_init"
     if args.inits and option not in pinned:

@@ -38,7 +38,7 @@ sys.path.insert(0, ROOT)
 
 
 # a family's model module where it is not called after the family
-MODELS = {"nemotronh": "nemotron_h"}
+MODELS = {"nemotronh": "nemotron_h", "kimilinear": "kimi_linear"}
 
 
 def _expert_extras(family, cell, params, seed, variant):
@@ -260,6 +260,7 @@ def _joyai_record(cell, out: str) -> int:
 EXTRAS = {"afmoe": _expert_extras, "mellum": _expert_extras,
           "keye": _keye_extras, "nemotronh": _expert_extras,
           "joyai": _expert_extras, "lfm2": _expert_extras,
+          "kimilinear": _expert_extras,
           "granitehybrid": _granitehybrid_extras}
 RECORD = {"granitehybrid": _granitehybrid_record, "mellum": _mellum_record,
           "keye": _keye_record, "nemotronh": _nemotronh_record,
