@@ -478,6 +478,13 @@ _STATIC = {
             "bps_kda_kernel",
             "1 where the model calls that scan's Pallas form, 0 the jnp "
             "form"),
+        "bwd_solve_products": _gauge(
+            "bps_kda_bwd_solve_products",
+            "float32 [chunk, chunk] x [chunk, chunk] products at "
+            "Precision.HIGHEST in one chunk of that scan's backward kernel, "
+            "counted from its traced body: 12 where the solve's gradient "
+            "is two products of the inverse, 30 where autodiff walks the "
+            "doubling product"),
     },
     "layer_plan": {
         "stacks": _gauge(

@@ -51,8 +51,16 @@ gradient:
     norms and the 1 / sqrt(K), the cumulative sum of g (a product with a
     triangle of ones, float32) and all of the above are inside.  The
     backward kernel is `jax.vjp` of the forward kernel's chunk
-    (`_chunk_kernel`) taken INSIDE its body: one chunk's intermediates are
-    made again in VMEM, and g's gradient leaves in float32.
+    (`_chunk_kernel`) taken INSIDE its body (`_chunk_transposed`): one
+    chunk's intermediates are made again in VMEM, and g's gradient leaves
+    in float32.  `jax.vjp` transposes everything but the solve, which has
+    a rule of its own (`_solve`, a `jax.custom_vjp`): the derivative of
+    an inverse needs the inverse alone, d inv = inv dn inv, so n's
+    cotangent is inv^T ct inv^T, two products of what the body has just
+    made, at the solve's precision; autodiff of the doubling product
+    would walk its ten products back with two transposed products each
+    and hold every round's factors for it.  `solve_products` counts what
+    a traced body holds (the gauge `bps_kda_bwd_solve_products`: 12).
   - `kda_scan_jnp`: the same equations in `jax.numpy`, all heads at once,
     a `lax.scan` over chunks whose step is rematerialised; the solve is
     `solve_triangular`.  What the kernels are tested against, and what
@@ -191,10 +199,12 @@ def kda_scan_jnp(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
 # ---------------------------------------------------------------------------
 # The kernels
 # ---------------------------------------------------------------------------
-def _hi(a, b):
-    """a b in float32, every bit of the operands."""
-    return jnp.dot(a, b, precision=lax.Precision.HIGHEST,
-                   preferred_element_type=_F32)
+def _hi(a, b, contract=((1,), (0,))):
+    """a b in float32, every bit of the operands; `contract` the
+    dimensions summed over: ((0,), (0,)) gives a^T b, ((1,), (1,)) a b^T."""
+    return lax.dot_general(a, b, (contract, ((), ())),
+                           precision=lax.Precision.HIGHEST,
+                           preferred_element_type=_F32)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
@@ -238,6 +248,32 @@ def _as_row(col):
                    keepdims=True)
 
 
+@jax.custom_vjp
+def _solve(n):
+    """(I - n)^-1 for n [C, C] float32, strictly lower and so nilpotent:
+    the doubling product (I + n)(I + n^2)(I + n^4) ... (I + n^(C/2))."""
+    C = n.shape[0]
+    inv = jnp.where(_eye(C), 1.0, 0.0) + n
+    for _ in range(max(C.bit_length() - 2, 0)):
+        n = _hi(n, n)
+        inv = inv + _hi(inv, n)
+    return inv
+
+
+def _solve_fwd(n):
+    inv = _solve(n)
+    return inv, inv
+
+
+def _solve_bwd(inv, ct):
+    """d inv = inv dn inv, the inverse's own derivative: n's cotangent is
+    inv^T ct inv^T, the transposes by the contracting dimensions."""
+    return (_hi(_hi(inv, ct, ((0,), (0,))), inv, ((1,), (1,))),)
+
+
+_solve.defvjp(_solve_fwd, _solve_bwd)
+
+
 def _chunk_kernel(q, k, v, g, beta, state):
     """`_chunk_jnp` for the kernels' bodies: beta a column [C, 1], and
     only what Mosaic lowers, forward and transposed: no slice, no
@@ -279,12 +315,8 @@ def _chunk_kernel(q, k, v, g, beta, state):
             mine = row_block == i
             akk = akk + jnp.where(mine, _nt(kl, keys), 0.0)
             aqk = aqk + jnp.where(mine, _nt(ql, keys), 0.0)
-    # (I + A)^-1, A = diag(beta) akk nilpotent: the doubling product
-    n = -beta * akk
-    inv = jnp.where(rows == cols, 1.0, 0.0) + n
-    for _ in range(max(C.bit_length() - 2, 0)):
-        n = _hi(n, n)
-        inv = inv + _hi(inv, n)
+    # (I + A)^-1, A = diag(beta) akk nilpotent
+    inv = _solve(-beta * akk)
     e = jnp.exp(G)
     last = _row(G, at, C - 1)
     st = state.astype(dtype)
@@ -293,6 +325,47 @@ def _chunk_kernel(q, k, v, g, beta, state):
     o = _nt((q32 * e).astype(dtype), st) + _nn(aqk.astype(dtype), u)
     tail = (k32 * jnp.exp(last - G)).astype(dtype)
     return o, jnp.exp(last) * state + _tn(u, tail)
+
+
+def _chunk_transposed(q, k, v, g, beta, state, do, dstate):
+    """The backward kernel's body: `_chunk_kernel` made again and its
+    transpose, by `jax.vjp` but for the solve (`_solve_bwd`), applied to
+    the cotangents of `o` and of the state the chunk left."""
+    _, pull = jax.vjp(_chunk_kernel, q, k, v, g, beta, state)
+    return pull((do, dstate))
+
+
+def _dots(jaxpr):
+    """Every `dot_general` of a jaxpr and of the jaxprs its equations
+    hold."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _dots(sub)
+
+
+@functools.lru_cache(maxsize=None)
+def solve_products(chunk: int, key_dim: int, value_dim: int,
+                   backward: bool = True) -> int:
+    """The [C, C] x [C, C] products at `Precision.HIGHEST` in one chunk's
+    body AS TRACED, the backward kernel's or the forward one's: the
+    solve's and its transpose's where neither head size is C.  Counted
+    from `jax.make_jaxpr` of what the kernel calls."""
+    square = (chunk, chunk)
+    keys, values = (chunk, key_dim), (chunk, value_dim)
+    state = (value_dim, key_dim)
+    wide = jnp.bfloat16
+    args = [(keys, wide), (keys, wide), (values, wide), (keys, _F32),
+            ((chunk, 1), _F32), (state, _F32)]
+    if backward:
+        args += [(values, _F32), (state, _F32)]
+    jaxpr = jax.make_jaxpr(_chunk_transposed if backward else _chunk_kernel)(
+        *(jax.ShapeDtypeStruct(*a) for a in args)).jaxpr
+    highest = (lax.Precision.HIGHEST,) * 2
+    return sum(eqn.params["precision"] == highest
+               and all(v.aval.shape == square for v in eqn.invars)
+               for eqn in _dots(jaxpr))
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref,
@@ -322,11 +395,10 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref,
     def _init():
         dstate_scr[:] = jnp.zeros_like(dstate_scr)
 
-    _, pull = jax.vjp(_chunk_kernel, q_ref[0, 0], k_ref[0, 0], v_ref[0, 0],
-                      g_ref[0, 0], _column(beta_ref[0, 0, pl.ds(at, 1), :]),
-                      st_ref[0, 0, 0])
-    dq, dk, dv, dg, dbeta, dstate_scr[:] = pull(
-        (do_ref[0, 0].astype(_F32), dstate_scr[:]))
+    dq, dk, dv, dg, dbeta, dstate_scr[:] = _chunk_transposed(
+        q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], g_ref[0, 0],
+        _column(beta_ref[0, 0, pl.ds(at, 1), :]), st_ref[0, 0, 0],
+        do_ref[0, 0].astype(_F32), dstate_scr[:])
     dq_ref[0, 0] = dq.astype(dq_ref.dtype)
     dk_ref[0, 0] = dk.astype(dk_ref.dtype)
     dv_ref[0, 0] = dv.astype(dv_ref.dtype)
@@ -480,4 +552,5 @@ def record(layers: int, batch: int, heads: int, seq_len: int, key_dim: int,
     telemetry.record_static(
         "kda_scan", layers=layers, chunk=chunk, kernel=1,
         state_bytes=state_bytes(batch, heads, seq_len, key_dim, value_dim,
-                                chunk))
+                                chunk),
+        bwd_solve_products=solve_products(chunk, key_dim, value_dim))

@@ -130,11 +130,36 @@ def test_bfloat16_operands_round_once_and_the_state_stays_float32():
         assert rel(a.astype(jnp.float32), b) < 2e-2
 
 
+@pytest.mark.parametrize("C", (64, 32))
+def test_the_solves_rule_is_autodiff_of_the_doubling_product(C):
+    """`_solve`'s rule, inv^T ct inv^T, against autodiff of the ten (eight)
+    products it stands for, outside any kernel: a strictly lower n with
+    entries up to 1 in size.  The two agree where n can move, below the
+    diagonal (off it the doubling product is no inverse)."""
+    keys = jax.random.split(jax.random.key(5), 2)
+    n = jnp.tril(jax.random.uniform(keys[0], (C, C), minval=-1.0), -1)
+    ct = jax.random.normal(keys[1], (C, C))
+    inv, pull = jax.vjp(kda._solve, n)
+    want, doubled = jax.vjp(kda._solve.fun, n)
+    assert bool((inv == want).all())
+    (got,), (want,) = pull(ct), doubled(ct)
+    assert got.dtype == jnp.float32
+    assert rel(jnp.tril(got, -1), jnp.tril(want, -1)) < 1e-5
+
+
+@pytest.mark.parametrize("backward,products", ((False, 10), (True, 12)))
+def test_full_precision_products_of_a_chunks_traced_body(backward, products):
+    """The solve's ten doubling products in either body, and two more for
+    its gradient in the backward one (autodiff of the ten gave twenty)."""
+    assert kda.solve_products(64, 128, 128, backward) == products
+
+
 def test_state_bytes_and_gauges():
     from byteps_tpu.common import telemetry
     assert kda.state_bytes(1, 32, 32768, 128, 128) == 32 * 512 * 65536
     kda.record(4, 1, 32, 32768, 128, 128)
     text = telemetry.get_registry().render_prometheus()
     for line in ("bps_kda_scan_layers 4", "bps_kda_chunk 64",
-                 "bps_kda_state_bytes 1073741824", "bps_kda_kernel 1"):
+                 "bps_kda_state_bytes 1073741824", "bps_kda_kernel 1",
+                 "bps_kda_bwd_solve_products 12"):
         assert line in text, line

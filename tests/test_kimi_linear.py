@@ -69,6 +69,7 @@ def test_a_share_against_the_reference():
         assert metrics[f'bps_remat_kept_bytes{{name="{kept}"}}'] > 0
     assert metrics["bps_kda_scan_layers"] == 1
     assert metrics["bps_kda_kernel"] == 1 and metrics["bps_kda_chunk"] == 64
+    assert metrics["bps_kda_bwd_solve_products"] == 12
     assert metrics["bps_layer_plan_stacks"] == 2
 
 

@@ -1160,6 +1160,10 @@ def test_kimilinear_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch):
     # forward, its recompute, backward
     scan = sorted(c[0] for c in map(kda_cost.call, calls) if c)
     assert scan == ["bwd", "fwd", "fwd"], scan
+    # the backward call's chunk takes the solve's gradient from the
+    # inverse: the ten doubling products made again and two more
+    import byteps_tpu as bps
+    assert bps.get_metrics()["bps_kda_bwd_solve_products"] == 12
     assert len([c for c in calls if c.startswith("%mamba_conv_")]) == 3
     assert len(calls) == 6
     assert not _wide_moves(text, 32768 * 4096)
