@@ -1,96 +1,26 @@
 """The afmoe model (`byteps_tpu/models/afmoe.py`) at tiny widths against
 its plain float32 reference (`benchmark/reference/afmoe.py`), through the
 benchmark's own family and comparison: loss and every gradient leaf, the
-choice of experts apart from the arithmetic, the seven broken variants,
-and the test that ties one chip's share to the whole model."""
+choice of experts apart from the arithmetic (the seven broken variants:
+`test_afmoe_variants.py`), and the test that ties one chip's share to the
+whole model."""
 
 import dataclasses
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from benchmark.families import afmoe as family_afmoe
-from benchmark.harness import correct, seeded
+from benchmark.harness import seeded
 from benchmark.reference import afmoe as reference
-from benchmark.tests import afmoe_variants, tiny_afmoe
+from benchmark.tests import tiny_afmoe
 from byteps_tpu.models import afmoe
 from byteps_tpu.parallel import dropless_moe
+from family_cases import Cases
 
-
-def _family(dtype=jnp.bfloat16, tolerances=None, **cut):
-    config = tiny_afmoe.config(**cut)
-    if tolerances:
-        config["reference_check"].update(tolerances)
-    family = family_afmoe.Family(config, config["job"])
-    family.cfg = dataclasses.replace(family.cfg, dtype=dtype)
-    return family
-
-
-def _agreement(family, seed=0):
-    got = correct.gradient_agreement(
-        family.loss, family.reference_loss, seeded.params(family, seed),
-        seeded.batch(family, seed, family.reference_check["samples"]))
-    jax.effects_barrier()
-    return got
-
-
-# (layers of the model that are run, dense layers the model is said to
-# have): layer 3 is full attention, and dense if the model has four.
-LAYERS = {
-    "dense_sliding": ([1], None),
-    "dense_full": ([3], 4),
-    "expert_sliding": ([4], None),
-    "expert_full": ([7], None),
-    "five_layer_stack": (None, None),
-}
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("layers", LAYERS)
-def test_against_reference(layers, dtype):
-    """In float32 the program IS the reference up to rounding; in
-    bfloat16 it is within the family's tolerances at these widths."""
-    run, dense = LAYERS[layers]
-    family = _family(
-        dtype, tiny_afmoe.FLOAT32 if dtype == jnp.float32 else None,
-        layers=run, published_dense_layers=dense)
-    kinds = {(i < family.cfg.num_dense_layers, t)
-             for i, t in enumerate(family.layer_types)}
-    if run is not None:
-        assert kinds == {(layers.startswith("dense"),
-                          afmoe.SLIDING if layers.endswith("sliding")
-                          else afmoe.FULL)}
-    got = _agreement(family)
-    assert correct.agreement_ok(got, family.reference_check), got
-    if dtype == jnp.float32:
-        assert all(s["swapped_share"] == 0 for s in family.selection)
-
-
-@pytest.fixture(scope="module")
-def float32_family():
-    # an expert layer of each kind is all the seven variants need
-    return _family(jnp.float32, tiny_afmoe.FLOAT32, layers=[4, 7])
-
-
-@pytest.mark.parametrize("variant", [None, *afmoe_variants.VARIANTS])
-def test_broken_variant_fails(float32_family, variant):
-    """Each way of breaking the program leaves at least one of the
-    comparisons that decide `correct`; the program as it is passes all."""
-    family = float32_family
-    if variant is None:
-        got = _agreement(family)
-        assert correct.agreement_ok(got, family.reference_check), got
-        return
-    with afmoe_variants.VARIANTS[variant](family):
-        got = _agreement(family)
-    assert not correct.agreement_ok(got, family.reference_check), got
-    if variant in ("top7", "router_in_bfloat16"):
-        # caught by the choice, which rounding does not explain
-        assert sum(s["unexplained_tokens"]
-                   for s in family.selection[-2:]) > 0
+CASES = Cases(tiny_afmoe, family_afmoe.Family)
+_family = CASES.family
 
 
 def test_expert_bias_moves_the_choice_and_not_the_weights():

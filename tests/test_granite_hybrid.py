@@ -2,9 +2,10 @@
 tiny widths against its plain float32 reference
 (`benchmark/reference/granitehybrid.py`), through the benchmark's own
 family and comparison: loss and every gradient leaf over several lists of
-layers, the fourteen broken variants, and the tests that tie one chip's
-share (a pipeline stage, a slice of the tied vocabulary) to the whole
-model."""
+layers, and the tests that tie one chip's share (a pipeline stage, a slice
+of the tied vocabulary) to the whole model.  (The lists of layers against
+the reference are `test_granite_hybrid_reference.py`'s, the fourteen broken
+variants `test_granite_hybrid_variants.py`'s.)"""
 
 import dataclasses
 import functools
@@ -23,49 +24,13 @@ from benchmark.tests import granitehybrid_variants as variants
 from benchmark.tests import tiny_granitehybrid
 from byteps_tpu.models import granite_hybrid as gh
 from byteps_tpu.ops import ssd
+from family_cases import Cases
 from testutil import mixer_trains_as_with_the_jnp_convolution
 
 M, A = gh.MAMBA, gh.ATTENTION
 
-
-def _family(dtype=jnp.bfloat16, tolerances=None, **cut):
-    config = tiny_granitehybrid.config(**cut)
-    if tolerances:
-        config["reference_check"].update(tolerances)
-    family = family_granite.Family(config, config["job"])
-    family.cfg = dataclasses.replace(family.cfg, dtype=dtype)
-    return family
-
-
-def _agreement(family, seed=0):
-    return correct.gradient_agreement(
-        family.loss, family.reference_loss, seeded.params(family, seed),
-        seeded.batch(family, seed, family.reference_check["samples"]))
-
-
-# layers of the model that are run; 5, 15, 25, 35 are attention
-LAYERS = {
-    "one_mamba": [0],
-    "one_attention": [5],
-    "mamba_attention_mamba": [4, 5, 6],
-    "a_later_stage": list(range(10, 20)),
-    "the_cells_ten": None,
-}
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("layers", LAYERS)
-def test_against_reference(layers, dtype):
-    """In float32 the program IS the reference up to rounding; in
-    bfloat16 it is within the family's tolerances at these widths."""
-    family = _family(
-        dtype, tiny_granitehybrid.FLOAT32 if dtype == jnp.float32 else None,
-        layers=LAYERS[layers])
-    if LAYERS[layers] is None:
-        assert family.layer_types == (M,) * 5 + (A,) + (M,) * 4
-    got = _agreement(family)
-    assert correct.agreement_ok(got, family.reference_check), got
+CASES = Cases(tiny_granitehybrid, family_granite.Family)
+_family, _agreement = CASES.family, CASES.agreement
 
 
 def test_the_jnp_form_of_the_scan_trains_the_same_model():
@@ -89,27 +54,6 @@ def test_the_convolutions_kernel_trains_the_model_the_jnp_form_did(
         _family(dtype, layers=[4]), monkeypatch, tol)
 
 
-@pytest.fixture(scope="module")
-def float32_family():
-    # a mamba layer before and after an attention layer is all the
-    # variants need
-    return _family(jnp.float32, tiny_granitehybrid.FLOAT32, layers=[4, 5, 6])
-
-
-@pytest.mark.parametrize("variant", [None, *variants.VARIANTS])
-def test_broken_variant_fails(float32_family, variant):
-    """Each way of breaking the program leaves at least one of the
-    comparisons that decide `correct`; the program as it is passes all."""
-    family = float32_family
-    if variant is None:
-        got = _agreement(family)
-        assert correct.agreement_ok(got, family.reference_check), got
-        return
-    with variants.VARIANTS[variant](family):
-        got = _agreement(family)
-    assert not correct.agreement_ok(got, family.reference_check), got
-
-
 def _scan_alone(family, seed=0):
     tokens = seeded.batch(family, seed, 1)[0]
     return float(jax.jit(lambda p, t: family.scan_disagreement(p, t))(
@@ -123,7 +67,10 @@ def test_the_scan_alone_tells_what_bfloat16_hides(variant):
     bfloat16 products.  The family's fourth number, the program's scan
     alone on float32 operands against the recurrence, tells it, and
     reaches `correct` through the loss."""
-    family = _family(jnp.bfloat16, layers=[4, 5, 6])
+    # the program as it is between two layers of the other kind; what
+    # breaks the scan on the one mamba layer it breaks
+    family = _family(jnp.bfloat16, layers=[4, 5, 6] if variant is None
+                     else [4])
     limits = family.reference_check
     if variant is None:
         got = _agreement(family)
@@ -149,6 +96,37 @@ def test_a_layer_list_without_a_scan_has_no_fourth_number():
     one_chunk = _family(jnp.float32, layers=[0])
     one_chunk.cfg = dataclasses.replace(one_chunk.cfg, mamba_chunk_size=256)
     assert _scan_alone(one_chunk) == 0.0
+
+
+def test_the_scan_writes_its_gauges_when_a_step_is_traced():
+    family = _family(layers=[4, 5, 6])
+    jax.eval_shape(family.loss, seeded.params(family, 0),
+                   seeded.batch(family, 0, 2))
+    metrics = bps.get_metrics()
+    assert metrics["bps_ssd_scan_layers"] == 2
+    assert metrics["bps_ssd_chunk"] == 64
+    # 2 sequences x 8 heads x 4 chunks x [16, 32] float32
+    assert metrics["bps_ssd_state_bytes"] == 2 * 8 * 4 * 16 * 32 * 4
+
+
+@pytest.mark.parametrize("impl,copies", [("kernel", 0), ("jnp", 4)])
+def test_the_scan_says_which_layout_ran(monkeypatch, impl, copies):
+    """A traced step's two gauges of the scan's layout: the lanes of the
+    slab of x a kernel program holds (this cut's 8 heads of 16), and the
+    transposed copies of a wide operand a call makes outside the kernels:
+    none on the model's path, which hands the kernels the mixer's own
+    [S, H P]; x, y, dy and dx in the `jnp` form, which keeps a layout of
+    its own."""
+    family = _family(layers=[4, 5, 6])
+    if impl != "kernel":
+        monkeypatch.setattr(ssd, "ssd_scan",
+                            functools.partial(ssd.ssd_scan, impl=impl))
+    jax.eval_shape(jax.grad(family.loss), seeded.params(family, 0),
+                   seeded.batch(family, 0, 2))
+    metrics = bps.get_metrics()
+    assert metrics["bps_ssd_lane_block"] == 8 * 16
+    assert metrics["bps_ssd_wide_copies"] == copies
+    assert metrics["bps_ssd_chunk"] == 64
 
 
 def test_a_slice_that_starts_elsewhere():
@@ -307,37 +285,6 @@ def test_model_flops_against_a_count_by_hand():
     assert family.model_flops_per_sample() == (
         6.0 * params * S + 2 * ssd_cost.model_flops(**family.scan_shape())
         + 12.0 * (S * (S + 1) // 2) * num["hidden_size"])
-
-
-def test_the_scan_writes_its_gauges_when_a_step_is_traced():
-    family = _family(layers=[4, 5, 6])
-    jax.eval_shape(family.loss, seeded.params(family, 0),
-                   seeded.batch(family, 0, 2))
-    metrics = bps.get_metrics()
-    assert metrics["bps_ssd_scan_layers"] == 2
-    assert metrics["bps_ssd_chunk"] == 64
-    # 2 sequences x 8 heads x 4 chunks x [16, 32] float32
-    assert metrics["bps_ssd_state_bytes"] == 2 * 8 * 4 * 16 * 32 * 4
-
-
-@pytest.mark.parametrize("impl,copies", [("kernel", 0), ("jnp", 4)])
-def test_the_scan_says_which_layout_ran(monkeypatch, impl, copies):
-    """A traced step's two gauges of the scan's layout: the lanes of the
-    slab of x a kernel program holds (this cut's 8 heads of 16), and the
-    transposed copies of a wide operand a call makes outside the kernels:
-    none on the model's path, which hands the kernels the mixer's own
-    [S, H P]; x, y, dy and dx in the `jnp` form, which keeps a layout of
-    its own."""
-    family = _family(layers=[4, 5, 6])
-    if impl != "kernel":
-        monkeypatch.setattr(ssd, "ssd_scan",
-                            functools.partial(ssd.ssd_scan, impl=impl))
-    jax.eval_shape(jax.grad(family.loss), seeded.params(family, 0),
-                   seeded.batch(family, 0, 2))
-    metrics = bps.get_metrics()
-    assert metrics["bps_ssd_lane_block"] == 8 * 16
-    assert metrics["bps_ssd_wide_copies"] == copies
-    assert metrics["bps_ssd_chunk"] == 64
 
 
 def test_the_new_code_stays_out_of_the_other_cells_imports():

@@ -23,48 +23,18 @@ from benchmark.families import afmoe as family_afmoe
 from benchmark.families import gpt2 as family_gpt2
 from benchmark.families import keye as family_keye
 from benchmark.families import mellum as family_mellum
-from benchmark.harness import correct, manifest, seeded
+from benchmark.harness import manifest, seeded
 from benchmark.reference import keye as reference
 from benchmark.tests import tiny_afmoe, tiny_keye, tiny_mellum
 from byteps_tpu.models import afmoe, keye
 from byteps_tpu.models import transformer as tfm
 from byteps_tpu.ops import sparse_attention as sa
 from byteps_tpu.parallel import dropless_moe
+from family_cases import Cases
 from testutil import tiny_gpt2_config
 
-
-_family, _agreement = tiny_keye.family, tiny_keye.agreement
-
-
-# (layers of the model that are run, experts held)
-CUTS = {
-    "one_layer": ([0], None),
-    "the_cells_four_layers": (None, None),
-    "whole_model_two_layers": ([2, 3], range(128)),
-}
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("cut", CUTS)
-def test_against_reference(cut, dtype):
-    """In float32 the program IS the reference up to rounding, its
-    selection the reference's own in every row; in bfloat16 it is within
-    the family's tolerances at these widths."""
-    layers, experts = CUTS[cut]
-    family = _family(
-        dtype, tiny_keye.FLOAT32 if dtype == jnp.float32 else None,
-        layers=layers, experts=experts)
-    got = _agreement(family)
-    assert correct.agreement_ok(got, family.reference_check), got
-    for record in family.selection:
-        assert record["miscounted_rows"] == 0
-        assert record["unexplained_rows"] == 0
-        if dtype == jnp.float32:
-            assert record["swapped_share"] == 0
-            assert record["key_swapped_share"] == 0
-            assert got["worst_grad_rel_diff"] < 1e-5
-            assert got["loss_rel_diff"] < 1e-6
+CASES = Cases(tiny_keye)
+_family = CASES.family
 
 
 def test_selection_is_exact_against_a_sort_with_ties():

@@ -2,51 +2,51 @@
 at tiny widths in float32, where the program as it is IS the reference up
 to rounding: each variant leaves at least one of the comparisons that
 decide `correct`, and the comparisons of single parts tell the variants
-that break THEM.  A file beside `test_keye.py` so that the two run on two
-workers."""
+that break THEM.  A file beside `test_keye.py`; the six variants that
+break the choice of keys are `test_keye_variants_selection.py`'s, which
+takes `SELECTION` and `broken_variant_fails` from here: the twelve cases,
+30 s each beside five other workers, are over what a file may cost."""
 
-import jax.numpy as jnp
 import pytest
 
 from benchmark.families import keye as family_keye
-from benchmark.harness import correct
 from benchmark.tests import keye_variants, tiny_keye
+from family_cases import Cases
 
-_family, _agreement = tiny_keye.family, tiny_keye.agreement
-
-
-@pytest.fixture(scope="module")
-def float32_family():
-    return _family(jnp.float32, tiny_keye.FLOAT32, layers=[0, 1])
-
-
+CASES = Cases(tiny_keye)
+# The layers a variant runs on: every layer of the model is of one kind
+# (attention over selected keys, routed experts), so each holds one.
+HELD = {None: [0], **dict.fromkeys(keye_variants.VARIANTS, [0])}
+SELECTION = ("top2047", "selection_not_causal", "a_selection_a_head",
+             "relu_left_out", "weights_left_out", "index_products_in_float8")
 # the program as it is, on a text batch and on three streams that differ
 STREAMS = {"as_it_is": None,
            "as_it_is_on_three_streams": family_keye.grid_positions}
 
 
-@pytest.mark.parametrize("variant", [*STREAMS, *keye_variants.VARIANTS])
-def test_broken_variant_fails(float32_family, variant):
-    """Each way of breaking the program leaves at least one of the
-    comparisons that decide `correct`; the program as it is passes all,
-    with text positions and with three streams that differ."""
-    family = float32_family
+@pytest.mark.parametrize("variant", [
+    *STREAMS, *(v for v in keye_variants.VARIANTS if v not in SELECTION)])
+def test_broken_variant_fails(variant):
+    broken_variant_fails(variant)
+
+
+def broken_variant_fails(variant):
     if variant in STREAMS:
+        family = CASES.float32(HELD[None])
         family.positions = STREAMS[variant]
         try:
-            got = _agreement(family)
+            CASES.broken_variant_fails(keye_variants.VARIANTS, None,
+                                       HELD[None])
         finally:
             family.positions = None
-        assert correct.agreement_ok(got, family.reference_check), got
         parts = family.selection[-1]
         assert parts["index_rel_diff"] < 1e-5
         assert parts["attn_row_diff"] < 1e-5
         assert parts["router_rel_diff"] < 1e-5
         assert parts["experts_rel_diff"] < 1e-5
         return
-    with keye_variants.VARIANTS[variant](family):
-        got = _agreement(family)
-    assert not correct.agreement_ok(got, family.reference_check), got
+    family, _ = CASES.broken_variant_fails(keye_variants.VARIANTS, variant,
+                                           HELD[variant])
     records = family.selection[-2:]
     told_by_the_choice = {"top2047", "relu_left_out", "weights_left_out",
                           "index_products_in_float8", "selection_not_causal"}

@@ -2,10 +2,10 @@
 its plain float32 reference (`benchmark/reference/mellum.py`), through the
 benchmark's own family and comparison: loss and every gradient leaf,
 sliding and full layers, a share and the whole model, the choice of
-experts apart from the arithmetic, the ten broken variants, the test
-that ties one chip's share to the whole layer, rotary positions against
-their closed form, and the machinery shared with `afmoe.py` left as it
-was."""
+experts apart from the arithmetic (the ten broken variants:
+`test_mellum_variants.py`), the test that ties one chip's share to the
+whole layer, rotary positions against their closed form, and the machinery
+shared with `afmoe.py` left as it was."""
 
 import dataclasses
 import math
@@ -17,106 +17,15 @@ import optax
 import pytest
 
 from benchmark.families import mellum as family_mellum
-from benchmark.harness import correct, seeded
 from benchmark.reference import mellum as reference
-from benchmark.tests import mellum_variants, tiny_mellum
+from benchmark.tests import tiny_mellum
 from byteps_tpu.models import afmoe, mellum
 from byteps_tpu.models import transformer as tfm
 from byteps_tpu.parallel import dropless_moe
+from family_cases import Cases
 
-
-def _family(dtype=jnp.bfloat16, tolerances=None, **cut):
-    config = tiny_mellum.config(**cut)
-    if tolerances:
-        config["reference_check"].update(tolerances)
-    family = family_mellum.Family(config, config["job"])
-    family.cfg = dataclasses.replace(family.cfg, dtype=dtype)
-    return family
-
-
-def _agreement(family, seed=0):
-    got = correct.gradient_agreement(
-        family.loss, family.reference_loss, seeded.params(family, seed),
-        seeded.batch(family, seed, family.reference_check["samples"]))
-    jax.effects_barrier()
-    return got
-
-
-# (layers of the model that are run, experts held): layer 3 is full
-# attention under YaRN, the others sliding under plain rotary positions.
-CUTS = {
-    "sliding": ([0], None),
-    "full": ([3], None),
-    "the_cells_four_layers": (None, None),
-    "whole_model_two_layers": ([2, 3], range(64)),
-}
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("cut", CUTS)
-def test_against_reference(cut, dtype):
-    """In float32 the program IS the reference up to rounding; in
-    bfloat16 it is within the family's tolerances at these widths."""
-    layers, experts = CUTS[cut]
-    family = _family(
-        dtype, tiny_mellum.FLOAT32 if dtype == jnp.float32 else None,
-        layers=layers, experts=experts)
-    if layers is not None and len(layers) == 1:
-        assert family.layer_types == (
-            afmoe.FULL if cut == "full" else afmoe.SLIDING,)
-    got = _agreement(family)
-    assert correct.agreement_ok(got, family.reference_check), got
-    if dtype == jnp.float32:
-        assert all(s["swapped_share"] == 0 for s in family.selection)
-    if experts is not None:
-        # every pair falls on a held expert: 8 rows a token a layer
-        assert family.routing_counters[-1]["held_rows_per_token"] == [8.0,
-                                                                      8.0]
-
-
-@pytest.fixture(scope="module")
-def float32_family():
-    # a layer of each kind is all the variants need
-    return _family(jnp.float32, tiny_mellum.FLOAT32, layers=[2, 3])
-
-
-@pytest.mark.parametrize("variant", [None, *mellum_variants.VARIANTS])
-def test_broken_variant_fails(float32_family, variant):
-    """Each way of breaking the program leaves at least one of the
-    comparisons that decide `correct`; the program as it is passes all."""
-    family = float32_family
-    if variant is None:
-        got = _agreement(family)
-        assert correct.agreement_ok(got, family.reference_check), got
-        parts = family.selection[-1]
-        assert parts["router_rel_diff"] < 1e-5
-        assert parts["experts_rel_diff"] < 1e-5
-        assert parts["attn_row_diff"] < 1e-5
-        return
-    with mellum_variants.VARIANTS[variant](family):
-        got = _agreement(family)
-    assert not correct.agreement_ok(got, family.reference_check), got
-    if variant in ("top7", "router_in_bfloat16"):
-        # caught by the choice, which rounding does not explain
-        assert sum(s["unexplained_tokens"]
-                   for s in family.selection[-2:]) > 0
-    # The parts alone, on the step's own operands, equal on both sides:
-    # each tells the variants that break IT, whatever the choice does.
-    parts = family.selection[-1]
-    told = {
-        "router_rel_diff": (family.router_rel_tol, {
-            "router_in_bfloat16", "norm_topk_prob_off"}),
-        # the router's weights scale what the experts add
-        "experts_rel_diff": (family.experts_rel_tol, {
-            "expert_products_in_float8", "held_expert_dropped",
-            "router_in_bfloat16", "norm_topk_prob_off"}),
-        "attn_row_diff": (family.attn_row_tol, {
-            "softmax_statistics_in_bfloat16", "window_off_by_one_tile"}),
-    }
-    for name, (limit, variants) in told.items():
-        assert (parts[name] > limit) == (variant in variants), (
-            name, parts[name])
+CASES = Cases(tiny_mellum, family_mellum.Family)
+_family = CASES.family
 
 
 def test_the_shares_add_up_to_the_layer():

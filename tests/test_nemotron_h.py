@@ -22,50 +22,19 @@ import pytest
 
 import byteps_tpu as bps
 from benchmark.families import nemotronh as family_nemotronh
-from benchmark.harness import correct, manifest
+from benchmark.harness import manifest
 from benchmark.reference import nemotronh as reference
 from benchmark.tests import tiny_nemotronh
 from byteps_tpu.models import granite_hybrid, nemotron_h
 from byteps_tpu.ops import flash_attention, ssd
 from byteps_tpu.parallel import dropless_moe
+from family_cases import Cases
 from testutil import (eqns, is_flash_forward, is_product,
                       mixer_trains_as_with_the_jnp_convolution, named_bytes)
 
-_family, _agreement = tiny_nemotronh.family, tiny_nemotronh.agreement
+CASES = Cases(tiny_nemotronh)
+_family = CASES.family
 PUBLISHED = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
-# (layers of the model that are run, experts held)
-CUTS = {
-    "the_cells_nine_layers": (None, None),
-    "one_of_each_kind": ([4, 5, 6], None),
-    "whole_layers_every_expert": ([5, 6, 7], range(128)),
-}
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
-                         ids=["float32", "bfloat16"])
-@pytest.mark.parametrize("cut", CUTS)
-def test_against_reference(cut, dtype):
-    """In float32 the program IS the reference up to rounding (1e-5 on
-    the loss, 2e-4 on the worst leaf, no token's choice swapped); in
-    bfloat16 it is within the family's tolerances at these widths."""
-    layers, experts = CUTS[cut]
-    family = _family(
-        dtype, tiny_nemotronh.FLOAT32 if dtype == jnp.float32 else None,
-        layers=layers, experts=experts)
-    got = _agreement(family)
-    assert correct.agreement_ok(got, family.reference_check), got
-    if dtype == jnp.float32:
-        assert got["loss_rel_diff"] <= 1e-5
-        assert got["worst_grad_rel_diff"] <= 1e-4, got
-        assert all(s["swapped_share"] == 0 for s in family.selection)
-        parts = family.selection[-1]
-        for name in ("scan_rel_diff", "router_rel_diff", "experts_rel_diff",
-                     "attn_row_diff"):
-            assert parts[name] < 1e-5, (name, parts[name])
-    if experts is not None:
-        # every pair falls on a held expert: 6 rows a token in the one
-        # expert layer of the three
-        assert family.routing_counters[-1]["held_rows_per_token"] == [6.0]
 
 
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),

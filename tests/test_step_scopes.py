@@ -10,6 +10,7 @@ the CPU.
 import contextlib
 import copy
 import dataclasses
+import functools
 import logging
 import os
 import re
@@ -175,10 +176,12 @@ def kernels(monkeypatch):
     monkeypatch.setattr(ssd, "_use_interpret", lambda interpret: False)
 
 
+@functools.lru_cache(maxsize=None)
 def _family(name: str):
     """`(a cell-like with the tiny job, the family)`: the cell's own
     configuration cut to tiny widths, and for the two expert families to
-    fewer layers (one of each kind), which the compiler's time asks."""
+    fewer layers (one of each kind), which the compiler's time asks.
+    Made once a family for all the module's tests, which read it."""
     cell = tiny.tiny_cell(FAMILIES[name][0])
     if name == "afmoe":     # dense sliding, expert sliding, expert full
         cell = dataclasses.replace(cell,
@@ -409,17 +412,21 @@ def test_the_scopes_change_no_operation(name, monkeypatch):
     context: a scope is metadata and nothing else."""
     cell, family = _family(name)
 
-    def lowered(debug_info=False):
+    def lowered():
+        """The step built and lowered anew: its text, and its text with
+        the name stacks (a lowering is the cost, so both from one)."""
         step, args = _abstract_step(family, int(cell.job["per_chip_batch"]),
                                     jax.devices()[:1])
-        return jax.jit(step).lower(*args).as_text(debug_info=debug_info)
-    scoped = lowered()
+        one = jax.jit(step).lower(*args)
+        return one.as_text(), one.as_text(debug_info=True)
+    scoped, scoped_with_names = lowered()
     assert len(scoped) > 50_000         # a whole train step, not a stub
-    assert "byteps.optimizer" in lowered(debug_info=True)
+    assert "byteps.optimizer" in scoped_with_names
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
-    assert "byteps.optimizer" not in lowered(debug_info=True)
-    assert lowered() == scoped
+    plain, plain_with_names = lowered()
+    assert "byteps.optimizer" not in plain_with_names
+    assert plain == scoped
 
 
 @pytest.mark.parametrize("op_name, scope, which", [

@@ -1,13 +1,20 @@
 #!/usr/bin/env python
-"""check_test_budget — per-test duration budget for the tier-1 suite.
+"""check_test_budget — what the tier-1 suite may cost, judged by name.
 
-The tier-1 verify runs the whole non-slow suite under one hard timeout
-(870s, ROADMAP).  Nothing has historically capped an INDIVIDUAL test,
-so the growing e2e set can blow the global timeout one slow test at a
-time, and the failure mode is the worst one — a timeout kill with no
-culprit named.  This gate closes that: any non-``slow``-marked test
-whose call phase exceeds ``--budget`` seconds (default 60) fails the
-check BY NAME.
+The driver runs the whole non-slow suite under one hard timeout, on six
+workers that are handed whole files (`DRIVER_COMMAND` below).  A run the
+clock cuts is the worst failure there is: it counts only as far as it
+got, writes no junit file and names no culprit.  Three things cut a run,
+and this gate fails on each BY NAME, from one recording:
+
+- a single non-``slow`` test whose call phase exceeds ``--budget``
+  seconds (default 60);
+- a FILE whose non-slow tests sum to more than ``FILE_BUDGET_S`` (350):
+  `--dist loadfile` hands out whole files in collection order, so the
+  wall time is at worst the total's sixth plus five sixths of the longest
+  file;
+- the TOTAL: its sixth (the wall time if the files fell evenly) over
+  ``TOTAL_SHARE`` (80%) of the driver's limit.
 
 Data source, in order of preference:
 
@@ -21,8 +28,8 @@ Data source, in order of preference:
    runs that already deselected slow tests, e.g. the tier-1 command).
 
 Wired as a fast tier-1 test (tests/test_test_budget.py) over the
-PREVIOUS run's recording — a budget breach lands on the next run, which
-is exactly when a reviewer is still looking at the PR that caused it.
+PREVIOUS run's recording — a breach lands on the next run, which is
+exactly when a reviewer is still looking at the PR that caused it.
 Also runnable standalone:
 
     python tools/check_test_budget.py [--budget 60] [--json]
@@ -30,7 +37,7 @@ Also runnable standalone:
 
 Exit codes: 0 = within budget (or no data yet), 1 = budget exceeded,
 2 = usage error.  ``BYTEPS_TPU_TEST_BUDGET_S`` overrides the default
-budget (documented in docs/env.md).
+per-test budget (documented in docs/env.md).
 """
 
 from __future__ import annotations
@@ -43,6 +50,17 @@ import sys
 from typing import Dict, Optional
 
 DEFAULT_BUDGET_S = 60.0
+#: The driver's limit and its workers, from the command it runs
+#: (`commands` in /root/TESTS_LAST_RUN.json; ROADMAP.md "Tier-1 verify"):
+DRIVER_COMMAND = ("timeout -k 10 1470 env JAX_PLATFORMS=cpu ... python -m "
+                  "pytest tests/ -q -m 'not slow' ... -p xdist -n 6 "
+                  "--dist loadfile")
+DRIVER_TIMEOUT_S = 1470.0
+DRIVER_WORKERS = 6
+#: What a file's non-slow tests may sum to, and the share of the driver's
+#: limit that the total's sixth may take.
+FILE_BUDGET_S = 350.0
+TOTAL_SHARE = 0.8
 
 #: pytest --durations row: "  12.34s call     tests/test_x.py::test_y"
 _DURATION_ROW = re.compile(
@@ -89,32 +107,69 @@ def parse_durations_log(text: str) -> Dict[str, dict]:
 def check(durations: Dict[str, dict],
           budget_s: float = DEFAULT_BUDGET_S) -> dict:
     """The gate as a pure function (the self-test's entry point):
-    non-slow tests over budget, slowest first."""
+    non-slow tests over budget, slowest first; files whose non-slow
+    tests sum to more than `FILE_BUDGET_S`, longest first; and the
+    non-slow total against what the driver's limit leaves it."""
     offenders = []
     slow_exempt = 0
+    by_file: Dict[str, float] = {}
     for nodeid, rec in durations.items():
         dur = float(rec.get("duration", 0.0))
         if rec.get("slow"):
             slow_exempt += 1
             continue
+        path = nodeid.split("::", 1)[0]
+        by_file[path] = by_file.get(path, 0.0) + dur
         if dur > budget_s:
             offenders.append({"nodeid": nodeid,
                               "duration": round(dur, 3)})
     offenders.sort(key=lambda r: -r["duration"])
+    files = sorted(({"file": f, "duration": round(d, 3)}
+                    for f, d in by_file.items() if d > FILE_BUDGET_S),
+                   key=lambda r: -r["duration"])
+    total = sum(by_file.values())
     return {"budget_s": budget_s, "tests": len(durations),
-            "slow_exempt": slow_exempt, "offenders": offenders}
+            "slow_exempt": slow_exempt, "offenders": offenders,
+            "file_budget_s": FILE_BUDGET_S, "files_over": files,
+            "total_s": round(total, 3),
+            "total_budget_s": TOTAL_SHARE * DRIVER_TIMEOUT_S
+            * DRIVER_WORKERS,
+            "total_over": total / DRIVER_WORKERS
+            > TOTAL_SHARE * DRIVER_TIMEOUT_S}
+
+
+def over(report: dict) -> bool:
+    return bool(report["offenders"] or report["files_over"]
+                or report["total_over"])
 
 
 def render(report: dict) -> str:
     lines = [f"check_test_budget: {report['tests']} test(s), budget "
              f"{report['budget_s']:g}s per non-slow test "
-             f"({report['slow_exempt']} slow-marked exempt)"]
+             f"({report['slow_exempt']} slow-marked exempt), "
+             f"{report['file_budget_s']:g}s per file, "
+             f"{report['total_budget_s']:g}s in all"]
     for o in report["offenders"]:
         lines.append(f"  {o['duration']:8.1f}s  {o['nodeid']}  "
                      f"<-- OVER BUDGET (mark it slow, split it, or "
                      f"speed it up)")
-    lines.append(f"{len(report['offenders'])} test(s) over budget"
-                 if report["offenders"] else "all tests within budget")
+    for o in report["files_over"]:
+        lines.append(f"  {o['duration']:8.1f}s  {o['file']}  "
+                     f"<-- FILE OVER BUDGET (one worker runs a whole "
+                     f"file: split it, or speed its tests up)")
+    if report["total_over"]:
+        lines.append(
+            f"  {report['total_s']:8.1f}s  in all, "
+            f"{report['total_s'] / DRIVER_WORKERS:.0f}s a worker of "
+            f"{DRIVER_WORKERS}  <-- TOTAL OVER {TOTAL_SHARE:.0%} of the "
+            f"driver's {DRIVER_TIMEOUT_S:g}s")
+    if over(report):
+        lines.append(f"{len(report['offenders'])} test(s), "
+                     f"{len(report['files_over'])} file(s) over budget, "
+                     f"total {report['total_s']:.0f}s"
+                     + " over budget" * report["total_over"])
+    else:
+        lines.append(f"all within budget (total {report['total_s']:.0f}s)")
     return "\n".join(lines)
 
 
@@ -156,7 +211,7 @@ def main(argv=None) -> int:
         print(json.dumps(report))
     else:
         print(render(report))
-    return 1 if report["offenders"] else 0
+    return 1 if over(report) else 0
 
 
 if __name__ == "__main__":
