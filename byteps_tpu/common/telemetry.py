@@ -524,6 +524,38 @@ _STATIC = {
             "tiles of K (and as many of V) copied in: a step whose tile "
             "is the one before it copies nothing"),
     },
+    "flash_block_diffusion": {
+        "steps": _gauge(
+            "bps_flash_bd_steps",
+            "grid steps one head's forward kernel walks in the last "
+            "traced flash call under a block-diffusion mask: the entries "
+            "of its table, every one a live tile"),
+        "live": _gauge(
+            "bps_flash_bd_live",
+            "of those, the steps that compute a tile of logits"),
+        "fetched": _gauge(
+            "bps_flash_bd_fetched",
+            "tiles of K (and as many of V) copied in: a step whose tile "
+            "is the one before it copies nothing"),
+        "whole": _gauge(
+            "bps_flash_bd_whole",
+            "of the live tiles, those the mask leaves whole and the "
+            "kernel does not mask"),
+        "pairs_needed_share": _gauge(
+            "bps_flash_bd_pairs_needed_share",
+            "the (row, key) pairs the mask needs, L^2 + L beta a head, "
+            "over the pairs the live tiles hold", float),
+    },
+    "block_diffusion_batch": {
+        "masked_share": _gauge(
+            "bps_bd_masked_share",
+            "masked tokens over tokens in the last block-diffusion batch "
+            "a caller handed `models/sdar.py` `record_batch`", float),
+        "weight_mean": _gauge(
+            "bps_bd_weight_mean",
+            "mean over ALL tokens of that batch's loss weights, masked / "
+            "t: 1 in expectation", float),
+    },
     "grouped_matmul": {
         "kernel": _gauge(
             "bps_grouped_kernel",

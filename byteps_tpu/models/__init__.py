@@ -23,9 +23,11 @@ feed-forward, scanned a run of one kind at a time) and `kimi_linear` (a
 delta rule whose state decays a key channel at a time, the chunked
 kernels of `ops/kda.py`, three such layers to every one of latent
 attention without positions; 8-of-256 sigmoid-routed experts round a
-shared one).  Their attention calls come from one table,
-`afmoe._ATTENTION`: `sliding_attention`, `full_attention`,
-`selected_attention`.
+shared one) and `sdar` (block-diffusion training: a clean and a noised
+copy of every sequence under the flash kernels' mask of three regions, a
+masked 1/t-weighted loss on the noised rows).  Their attention calls come
+from one table, `afmoe._ATTENTION`: `sliding_attention`,
+`full_attention`, `selected_attention`, `block_diffusion`.
 """
 
 from . import transformer

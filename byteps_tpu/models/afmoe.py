@@ -52,8 +52,10 @@ from .transformer import (_rms_norm, _rope, dense_attention,
 
 PyTree = Any
 SLIDING, FULL = "sliding_attention", "full_attention"
-# not a `layer_types` entry of this model: the kind of `models/keye.py`
+# not `layer_types` entries of this model: the kinds of `models/keye.py`
+# and of `models/sdar.py`
 SELECTED = "selected_attention"
+BLOCK_DIFFUSION = "block_diffusion"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -251,11 +253,24 @@ def _selected(cfg):
     return selected
 
 
+def _block_diffusion(cfg):
+    """`(q, k, v) -> ctx`, all [B, H, 2 L, Dh]: attention over the two
+    copies of a sequence, clean then noised, under the block-diffusion
+    mask of `cfg.block_length` (`ops/flash_attention.py` `bd_tile` has
+    the rule).  The mask is the kernels' alone: there is no dense form."""
+    def block_diffusion(q, k, v):
+        return flash_attention_fn(
+            q, k, v, False, cfg.attn_block, cfg.attn_block_k,
+            block_diffusion=(q.shape[2] // 2, cfg.block_length))
+    return block_diffusion
+
+
 # A layer's kind -> what builds its attention call from the configuration.
 _ATTENTION = {
     SLIDING: lambda cfg: _positional(cfg, cfg.sliding_window),
     FULL: lambda cfg: _positional(cfg, None),
     SELECTED: _selected,
+    BLOCK_DIFFUSION: _block_diffusion,
 }
 
 

@@ -411,7 +411,8 @@ def flash_auto_block(S: int, causal: bool = False) -> int:
 
 
 def flash_attention_fn(q, k, v, causal: bool, block: int = 0,
-                       block_k: int = 0, window: Optional[int] = None):
+                       block_k: int = 0, window: Optional[int] = None,
+                       block_diffusion: Optional[tuple] = None):
     """Adapter: [B, H, S, Dh] heads-layout -> the Pallas flash-attention
     kernel's [BH, S, Dh] layout; `v` may be another width than `q` and
     `k` (latent attention's 192 and 128), and the result is as wide as
@@ -430,15 +431,21 @@ def flash_attention_fn(q, k, v, causal: bool, block: int = 0,
     reverts to the AUTO choice.
 
     `window` (causal only) is the kernel's sliding window: row i sees the
-    keys i - window < j <= i.  None is full attention."""
+    keys i - window < j <= i.  None is full attention.
+
+    `block_diffusion` = `(L, beta)` (neither causal nor windowed) is the
+    kernel's mask over the two copies of a sequence, S = 2 L
+    (`models/sdar.py`): a tile then lies in one copy, so the tiles are
+    the rule's for L and an override must divide L."""
     from ..ops.flash_attention import (BLOCK_K_MULTIPLE, BLOCK_Q_MULTIPLE,
                                        flash_attention)
     B, H, S, Dh = q.shape
     Dv = v.shape[-1]
-    auto_q, auto_k = flash_auto_tiles(S, causal)
-    if not block or S % block or block % BLOCK_Q_MULTIPLE:
+    tiled = S if block_diffusion is None else block_diffusion[0]
+    auto_q, auto_k = flash_auto_tiles(tiled, causal)
+    if not block or tiled % block or block % BLOCK_Q_MULTIPLE:
         block = 0
-    if not block_k or S % block_k or block_k % BLOCK_K_MULTIPLE:
+    if not block_k or tiled % block_k or block_k % BLOCK_K_MULTIPLE:
         block_k = block or auto_k
     block = block or auto_q
     if block == 0 or Dh % 8 or Dv % 8:
@@ -451,6 +458,8 @@ def flash_attention_fn(q, k, v, causal: bool, block: int = 0,
     def fold(t):
         return t.reshape(B * H, S, t.shape[-1])
     windowed = () if window is None else (None, None, window)
+    if block_diffusion is not None:
+        windowed = (None, None, window, tuple(block_diffusion))
     out = flash_attention(fold(q), fold(k), fold(v), causal, None,
                           block, block_k, *windowed)
     return out.reshape(B, H, S, Dv)

@@ -61,6 +61,19 @@ Two execution strategies, auto-selected by VMEM footprint:
     all live, where the square has 4,096; a window of 1024 192 steps for
     189 tiles.
 
+A fourth kind of mask beside none, causal and window: BLOCK DIFFUSION
+(`block_diffusion=(L, beta)`, `models/sdar.py`), over the two copies of a
+sequence, clean then noised, in blocks of beta tokens.  A clean row sees
+the clean keys of its own and earlier blocks, a noised row the clean keys
+of EARLIER blocks and the noised keys of its OWN, three regions of the
+[2 L, 2 L] square of which the fourth is dead (`bd_tile`).  It is never
+an array: `stream_table` lists the live tiles (a block's run may have a
+gap; 1,088 a head at 2 L = 32,768 in tiles of 512, where the causal
+table has 2,080), a masked tile's rule is computed in the kernel from
+its first row, its first key and `(L, beta)` (`_bd_mask`), and a tile the
+table marks WHOLE is not masked.  Such a call always takes the streaming
+walk.
+
 What a tile costs on a v5e is the vector unit's work on its float32
 logits, not the MXU's: head size 64 and 128 take the same time a tile,
 and bfloat16 operands, or bfloat16 probabilities, bought nothing
@@ -138,12 +151,17 @@ BLOCK_Q_MULTIPLE = 128
 BLOCK_K_MULTIPLE = 64
 
 
-def check_blocks(s: int, block_q: int, block_k: int) -> None:
+def check_blocks(s: int, block_q: int, block_k: int,
+                 block_diffusion=None) -> None:
     """Raise ValueError unless (block_q, block_k) tile a length-`s`
     sequence in a way the chip's compiler accepts.  One rule for both
     paths: a streaming call's blocks are its grid's tiles, and its walk
     (`stream_walk`, from `k_band` / `q_band`) takes any pair this
-    allows, rows wider than keys or keys wider than rows."""
+    allows, rows wider than keys or keys wider than rows.  Under a
+    `block_diffusion` mask `(L, beta)` the sequence is the two copies,
+    `s` = 2 L, a tile lies in ONE copy (L a whole number of tiles of both
+    kinds) and holds whole blocks (`beta` divides both tiles): the
+    kernels' rule for a tile rests on both (`_bd_mask`)."""
     if (block_q <= 0 or block_k <= 0 or s % block_q or s % block_k
             or block_q % BLOCK_Q_MULTIPLE or block_k % BLOCK_K_MULTIPLE):
         raise ValueError(
@@ -151,6 +169,17 @@ def check_blocks(s: int, block_q: int, block_k: int) -> None:
             f"block_q={block_q}, block_k={block_k}: both must divide the "
             f"sequence, block_q must be a multiple of {BLOCK_Q_MULTIPLE} "
             f"and block_k a multiple of {BLOCK_K_MULTIPLE}")
+    if block_diffusion is None:
+        return
+    L, beta = block_diffusion
+    if (s != 2 * L or beta <= 0 or L % block_q or L % block_k
+            or block_q % beta or block_k % beta):
+        raise ValueError(
+            f"flash attention cannot lay a block-diffusion mask of "
+            f"L={L}, beta={beta} over seq_len {s} in tiles of "
+            f"block_q={block_q}, block_k={block_k}: the sequence is the two "
+            f"copies (2 L), L a whole number of tiles of both kinds, and "
+            f"beta divides both tiles")
 
 
 def _use_interpret(interpret: Optional[bool]) -> bool:
@@ -159,9 +188,13 @@ def _use_interpret(interpret: Optional[bool]) -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _use_streaming(k, streaming: Optional[bool], v=None) -> bool:
+def _use_streaming(k, streaming: Optional[bool], v=None,
+                   block_diffusion=None) -> bool:
     """Whether a head's K [S, Dk] and V [S, Dv] (None: as wide as K)
-    together pass the resident budget."""
+    together pass the resident budget; a call under a `block_diffusion`
+    mask streams whatever its size and whatever `streaming` says."""
+    if block_diffusion is not None:
+        return True
     if streaming is not None:
         return streaming
     _bh, s, dk = k.shape
@@ -275,6 +308,32 @@ def _mask(s, q0, k0, window, q_axis=0):
     return jnp.where(keep, s, NEG_INF)
 
 
+def _bd_mask(s, q0, k0, L, beta, q_axis=0):
+    """Mask a tile of logits of a block-diffusion call (`bd_tile` has the
+    rule) whose first row is `q0` and first key `k0` of the two copies.
+    Both are multiples of `beta` and the tile lies in one copy each way,
+    so a row's block starts `row - row % beta` into the tile's rows and
+    the keys the row keeps are ONE stretch of the tile's: from its block's
+    first token (noised keys) or from the tile's start (clean keys), to
+    its block's end (its own copy's keys) or its start (a noised row's
+    clean keys).  The block's start is found on a column of `block_q`
+    numbers and met by the keys' iota: two compares a pair, as a
+    window's."""
+    shape = [1, 1]
+    shape[q_axis] = s.shape[q_axis]
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, q_axis)
+    key = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
+    noised_q, noised_k = q0 >= L, k0 >= L
+    # the row's block's first token, counted in keys from the tile's first
+    start = (row - jax.lax.rem(row, beta)
+             + (q0 - jnp.where(noised_q, L, 0))
+             - (k0 - jnp.where(noised_k, L, 0)))
+    own_copy = jnp.logical_not(jnp.logical_xor(noised_q, noised_k))
+    keep = ((key >= jnp.where(noised_k, start, 0))
+            & (key < start + jnp.where(own_copy, beta, 0)))
+    return jnp.where(keep, s, NEG_INF)
+
+
 def k_band(qi, block_q, block_k, num_tiles, causal, window=None):
     """`(first, last)` tile of keys that the rows of block `qi` see, both
     inclusive and every tile between them live: up to the tile of the
@@ -314,11 +373,37 @@ def _bands(s, block_q, block_k, causal, window, by_keys):
     return blocks, lambda i: band(i, block_q, block_k, tiles, causal, window)
 
 
-# What the table says of an entry besides its block and its tile.
-FIRST, LAST = 1, 2
+# What the table says of an entry besides its block and its tile: the
+# ends of its block's run and, under a block-diffusion mask, that the rule
+# leaves the tile WHOLE.
+FIRST, LAST, WHOLE = 1, 2, 4
 
 
-def stream_table(s, block_q, block_k, causal, window=None, by_keys=False):
+def bd_tile(q0, k0, block_q, block_k, L, beta):
+    """What a block-diffusion mask leaves of the tile of rows [q0, q0 +
+    block_q) and keys [k0, k0 + block_k) of the two copies `[x ; x_t]`, L
+    tokens each in blocks of `beta`: None nothing, 0 some of its pairs,
+    WHOLE all of them.  The rule (BD3-LMs, arXiv:2503.09573, its
+    vectorised training), for the block b of a row's token and c of a
+    key's: a clean row sees the clean keys with c <= b; a noised row the
+    clean keys with c < b and the noised keys with c == b, those after it
+    too; nobody sees a noised key of another block.  A tile lies in one
+    copy and holds whole blocks (`check_blocks`), so it is judged by the
+    first and last block of its rows and of its keys."""
+    rows = (q0 % L // beta, (q0 % L + block_q - 1) // beta)
+    keys = (k0 % L // beta, (k0 % L + block_k - 1) // beta)
+    if k0 >= L:                         # noised keys: their own block's
+        if q0 < L or keys[0] > rows[1] or rows[0] > keys[1]:
+            return None
+        return WHOLE * (rows[0] == rows[1] == keys[0] == keys[1])
+    ahead = int(q0 >= L)                # a noised row's own block is not seen
+    if keys[0] + ahead > rows[1]:
+        return None
+    return WHOLE * (keys[1] + ahead <= rows[0])
+
+
+def stream_table(s, block_q, block_k, causal, window=None, by_keys=False,
+                 block_diffusion=None):
     """A streaming call's grid, one head of it: `(block, tile, flags)`,
     three equally long tuples with an entry for every LIVE tile and no
     other, in the order the kernel visits them.  Forward and dQ: row
@@ -327,15 +412,35 @@ def stream_table(s, block_q, block_k, causal, window=None, by_keys=False):
     band of row tiles (`q_band`).  `block` is the block of the axis the
     program owns, `tile` the tile of the axis it walks, `flags` FIRST and
     LAST on the ends of a block's run (a block of one tile carries
-    both)."""
+    both).
+
+    Under a `block_diffusion` mask `(L, beta)` a block's live tiles are
+    those `bd_tile` keeps, in rising order, and the run may have a GAP: a
+    block of noised rows walks the clean tiles before its own and then
+    its own tile of the noised copy, a block of clean keys the clean row
+    tiles from its own on and then the noised row tiles from its own
+    on; FIRST and LAST still mark the run's ends, and WHOLE the tiles no
+    rule crosses (at 2 L = 32,768 in tiles of 512: 528 + 528 + 32 = 1,088
+    entries, 992 of them whole, where the causal table has 2,080)."""
     blocks, band = _bands(s, block_q, block_k, causal, window, by_keys)
     block, tile, flags = [], [], []
     for i in range(blocks):
-        first, last = band(i)
-        for j in range(first, last + 1):
+        if block_diffusion is None:
+            first, last = band(i)
+            live = [(j, 0) for j in range(first, last + 1)]
+        else:
+            live = []
+            for j in range(s // (block_q if by_keys else block_k)):
+                qi, ki = (j, i) if by_keys else (i, j)
+                whole = bd_tile(qi * block_q, ki * block_k, block_q, block_k,
+                                *block_diffusion)
+                if whole is not None:
+                    live.append((j, whole))
+        for j, whole in live:
             block.append(i)
             tile.append(j)
-            flags.append(FIRST * (j == first) | LAST * (j == last))
+            flags.append(whole | FIRST * (j == live[0][0])
+                         | LAST * (j == live[-1][0]))
     return tuple(block), tuple(tile), tuple(flags)
 
 
@@ -355,15 +460,21 @@ class Walk(NamedTuple):
     grid: tuple                 # the grid's axes after bh
 
 
-def stream_walk(s, block_q, block_k, causal, window=None, by_keys=False):
+def stream_walk(s, block_q, block_k, causal, window=None, by_keys=False,
+                block_diffusion=None):
     """The `Walk` of a streaming call (`by_keys`: of its dK/dV kernel).
     Either the grid is (bh, entry of the table), every step a live tile;
     or, where blocks x the longest band is within BAND_GRID_SLACK of the
     live tiles (a window; no mask at all), it is (bh, block, step of the
     longest band): step `j` of block `i` is tile `first + j` of its band,
     computed and not looked up, and past the band's end the last tile
-    again, which is not copied twice and computes nothing."""
-    table = stream_table(s, block_q, block_k, causal, window, by_keys)
+    again, which is not copied twice and computes nothing.  A
+    `block_diffusion` call's runs have gaps and are 1 to `L / tile + 1`
+    tiles long: the table."""
+    table = stream_table(s, block_q, block_k, causal, window, by_keys,
+                         block_diffusion)
+    if block_diffusion is not None:
+        return Walk(table, None, (len(table[0]),))
     blocks, band = _bands(s, block_q, block_k, causal, window, by_keys)
     longest = max(last - first + 1
                   for first, last in map(band, range(blocks)))
@@ -372,17 +483,27 @@ def stream_walk(s, block_q, block_k, causal, window=None, by_keys=False):
     return Walk(table, band, (blocks, longest))
 
 
-def stream_schedule(s, block_q, block_k, causal, window=None):
+def stream_schedule(s, block_q, block_k, causal, window=None,
+                    block_diffusion=None):
     """What one head of a streaming call's forward (and dQ) kernel does,
     counted from the walk it really takes: the grid steps, how many of
     them compute (the table's entries: all, on a table grid), and the
     tiles of K (and as many of V) copied in: a step whose tile is the
-    one before it copies nothing, on either grid."""
-    walk = stream_walk(s, block_q, block_k, causal, window)
+    one before it copies nothing, on either grid.  Of a `block_diffusion`
+    call also the tiles it leaves `whole` (unmasked) and the share of the
+    computed (row, key) pairs that the rule needs, L^2 + L beta a head."""
+    walk = stream_walk(s, block_q, block_k, causal, window,
+                       block_diffusion=block_diffusion)
     tile = walk.table[1]
     fetched = sum(a != b for a, b in zip(tile, (None,) + tile))
-    return {"steps": math.prod(walk.grid), "live": len(tile),
-            "fetched": fetched}
+    out = {"steps": math.prod(walk.grid), "live": len(tile),
+           "fetched": fetched}
+    if block_diffusion is not None:
+        L, beta = block_diffusion
+        out["whole"] = sum(f & WHOLE != 0 for f in walk.table[2])
+        out["pairs_needed_share"] = L * (L + beta) / (
+            len(tile) * block_q * block_k)
+    return out
 
 
 def _dot_nt(a, b):
@@ -431,22 +552,33 @@ def _spread(x, width):
     return wide if wide.shape[1] == width else wide[:, :width]
 
 
-def _online_step(q_scaled, k, v, carry, edge, window=None):
+def _masked(s, edge, window, bd, q_axis=0):
+    """The tile of logits `s` under the call's mask: `edge` None leaves it
+    whole, else it is the tile's `(first query, first key)`, under the
+    causal diagonal and a `window`, or under the block-diffusion rule of
+    `bd` = (L, beta)."""
+    if edge is None:
+        return s
+    if bd is not None:
+        return _bd_mask(s, *edge, *bd, q_axis=q_axis)
+    return _mask(s, *edge, window, q_axis=q_axis)
+
+
+def _online_step(q_scaled, k, v, carry, edge, window=None, bd=None):
     """One online-softmax accumulation step shared by both forward paths;
     `carry` None starts one.  `edge` is None for a tile every row sees
     whole, else the tile's `(first query, first key)`, and the tile is
-    masked.  The carry's `m` and `l` are columns or replicated along
-    lanes (`_spread`), and come back as they came."""
-    s = _dot_nt(q_scaled, k.astype(jnp.float32))          # (bq, bk)
-    if edge is not None:
-        s = _mask(s, *edge, window)
+    masked (`_masked`).  The carry's `m` and `l` are columns or
+    replicated along lanes (`_spread`), and come back as they came."""
+    s = _masked(_dot_nt(q_scaled, k.astype(jnp.float32)),  # (bq, bk)
+                edge, window, bd)
     m_new = jnp.max(s, axis=-1, keepdims=True)
     if carry is None:
         p = jnp.exp(s - m_new)
         return m_new, jnp.sum(p, axis=-1, keepdims=True), _dot_f32(p, v)
     m, l, acc = carry
     m_new = jnp.maximum(m, m_new)
-    if edge is None or window is None:
+    if edge is None or (window is None and bd is None):
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(s - _spread(m_new, s.shape[1]))
     else:
@@ -454,7 +586,9 @@ def _online_step(q_scaled, k, v, carry, edge, window=None):
         # nothing before it has seen any key: its running max is still
         # -inf, and exp(-inf - -inf) would be NaN.  (Only the streaming
         # path meets this: a resident program starts each row at its own
-        # key.  Causal alone never does: every row sees key 0.)
+        # key.  Causal alone never does: every row sees key 0.  Under a
+        # block-diffusion mask the first block of the noised copy does,
+        # whose rows see no clean key at all.)
         m_safe = jnp.where(m_new == NEG_INF, 0.0, m_new)
         alpha = jnp.exp(m - m_safe)
         p = jnp.exp(s - _spread(m_safe, s.shape[1]))
@@ -462,25 +596,22 @@ def _online_step(q_scaled, k, v, carry, edge, window=None):
     return m_new, l_new, acc * _spread(alpha, acc.shape[1]) + _dot_f32(p, v)
 
 
-def _dq_step(q_scaled, k, v, do, lse, delta, edge, window=None):
+def _dq_step(q_scaled, k, v, do, lse, delta, edge, window=None, bd=None):
     """A tile's part of dQ, short of the softmax's scale."""
-    s = _dot_nt(q_scaled, k.astype(jnp.float32))
-    if edge is not None:
-        s = _mask(s, *edge, window)
+    s = _masked(_dot_nt(q_scaled, k.astype(jnp.float32)), edge, window, bd)
     p = jnp.exp(s - lse)                                 # (bq, bk)
     return _dot_f32(p * (_dot_nt(do, v) - delta), k)
 
 
-def _dkv_step(q, k_scaled, v, do, lse, delta, edge, window=None):
+def _dkv_step(q, k_scaled, v, do, lse, delta, edge, window=None, bd=None):
     """A tile's parts of dK (short of the softmax's scale) and dV,
     computed on the tile TRANSPOSED: keys down, queries across.  So `lse`
     and `delta` come as the lane rows [1, bq] they are stored as, and
     P^T dO and dS^T Q are plain products, where the tile the other way
     round turns two [bq] rows into columns and transposes P and dS, every
     turn of the loop."""
-    st = _dot_nt(k_scaled, q.astype(jnp.float32))         # (bk, bq)
-    if edge is not None:
-        st = _mask(st, *edge, window, q_axis=1)
+    st = _masked(_dot_nt(k_scaled, q.astype(jnp.float32)),  # (bk, bq)
+                 edge, window, bd, q_axis=1)
     pt = jnp.exp(st - lse)
     dst = pt * (_dot_nt(v, do) - delta)
     return _dot_f32(dst, q), _dot_f32(pt, do)
@@ -617,6 +748,20 @@ def _edge(causal, q0, k0):
     return (q0, k0) if causal else None
 
 
+def _steps(live, flags_ref, bd, step):
+    """Run `step(masked)` where the grid step computes.  A causal or
+    windowed call masks every tile it computes or none (`_edge`), and a
+    step past its band's end computes nothing (`live`).  A
+    block-diffusion call asks the table: a tile it marks WHOLE is not
+    masked, the others are (`_bd_mask`: two compares and a select a pair,
+    on 96 of 2 L = 32,768's 1,088 tiles)."""
+    if bd is None:
+        return _when(live, step)
+    whole = flags_ref[pl.program_id(1)] & WHOLE != 0
+    pl.when(whole)(lambda: step(False))
+    pl.when(jnp.logical_not(whole))(lambda: step(True))
+
+
 def _entry(table, band):
     """`(block, tile, first, last, live)` of the grid step a streaming
     kernel is at: the block it owns, the tile it walks, whether the step
@@ -644,7 +789,7 @@ def _when(live, step):
 
 def _fwd_kernel_str(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref,
                     o_ref, lse_ref, m_scr, l_scr, acc_scr, *, sm_scale,
-                    causal, block_q, block_k, band, window=None):
+                    causal, block_q, block_k, band, window=None, bd=None):
     qi, kb, first, last, live = _entry((block_ref, tile_ref, flags_ref),
                                        band)
 
@@ -654,17 +799,17 @@ def _fwd_kernel_str(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _step():
+    def _step(masked=causal):
         # m and l live in scratch REPLICATED along lanes, [block_q, 128]:
         # as [block_q, 1] columns their way out of scratch and back took
         # 0.8 us of a tile's 1.9 (PERF.md section 6, PR 37)
         m, l, acc = _online_step(
             _scaled(q_ref[0], sm_scale), k_ref[0], v_ref[0],
             (m_scr[:], l_scr[:], acc_scr[:]),
-            _edge(causal, qi * block_q, kb * block_k), window)
+            _edge(masked, qi * block_q, kb * block_k), window, bd)
         m_scr[:], l_scr[:], acc_scr[:] = m, l, acc
 
-    _when(live, _step)
+    _steps(live, flags_ref, bd, _step)
 
     @pl.when(last)
     def _finish():
@@ -677,7 +822,7 @@ def _fwd_kernel_str(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref,
 
 def _dq_kernel_str(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref,
                    do_ref, lse_ref, delta_ref, dq_ref, dq_scr, *, sm_scale,
-                   causal, block_q, block_k, band, window=None):
+                   causal, block_q, block_k, band, window=None, bd=None):
     qi, kb, first, last, live = _entry((block_ref, tile_ref, flags_ref),
                                        band)
 
@@ -685,13 +830,13 @@ def _dq_kernel_str(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref,
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    def _step():
+    def _step(masked=causal):
         dq_scr[:] = dq_scr[:] + _dq_step(
             _scaled(q_ref[0], sm_scale), k_ref[0], v_ref[0], do_ref[0],
             lse_ref[0, 0, :][:, None], delta_ref[0, 0, :][:, None],
-            _edge(causal, qi * block_q, kb * block_k), window)
+            _edge(masked, qi * block_q, kb * block_k), window, bd)
 
-    _when(live, _step)
+    _steps(live, flags_ref, bd, _step)
 
     @pl.when(last)
     def _finish():
@@ -701,7 +846,7 @@ def _dq_kernel_str(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref,
 def _dkv_kernel_str(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref,
                     do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_scr,
                     dv_scr, *, sm_scale, causal, block_q, block_k, band,
-                    window=None):
+                    window=None, bd=None):
     ki, qb, first, last, live = _entry((block_ref, tile_ref, flags_ref),
                                        band)
 
@@ -710,15 +855,15 @@ def _dkv_kernel_str(block_ref, tile_ref, flags_ref, q_ref, k_ref, v_ref,
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def _step():
+    def _step(masked=causal):
         dk_i, dv_i = _dkv_step(
             q_ref[0], _scaled(k_ref[0], sm_scale), v_ref[0], do_ref[0],
             lse_ref[0], delta_ref[0],
-            _edge(causal, qb * block_q, ki * block_k), window)
+            _edge(masked, qb * block_q, ki * block_k), window, bd)
         dk_scr[:] = dk_scr[:] + dk_i
         dv_scr[:] = dv_scr[:] + dv_i
 
-    _when(live, _step)
+    _steps(live, flags_ref, bd, _step)
 
     @pl.when(last)
     def _finish():
@@ -782,33 +927,38 @@ def _walk_call(kernel, walk, bh, in_specs, out_specs, scratch_shapes,
     return functools.partial(call, *columns)
 
 
-def _windowed(window, kind, dk, dv):
+def _windowed(window, kind, dk, dv, bd=None):
     """The extra keywords of a call that is not the plain one, for the
-    kernel and for `pallas_call`: the window, and a name that says the
-    kind of call, the window (`flash_fwd_w2048`) and, where the keys'
-    width `dk` is not the values' `dv`, both (`flash_fwd_d192x128`),
-    which is how a device trace tells a sliding layer's calls from a full
-    layer's and a latent-attention call from either.  Nothing for
-    `window=None` and one width, which leaves those calls exactly as they
-    were."""
+    kernel and for `pallas_call`: the window or the block-diffusion mask,
+    and a name that says the kind of call, the window (`flash_fwd_w2048`),
+    the block-diffusion mask's block length (`flash_fwd_bd4`) and, where
+    the keys' width `dk` is not the values' `dv`, both
+    (`flash_fwd_d192x128`), which is how a device trace tells a sliding
+    layer's calls from a full layer's and a latent-attention or a
+    block-diffusion call from either.  Nothing for `window=None`, no such
+    mask and one width, which leaves those calls exactly as they were."""
     kw = {} if window is None else {"window": window}
     name = f"flash_{kind}"
     if dk != dv:
         name += f"_d{dk}x{dv}"
     if window is not None:
         name += f"_w{window}"
+    if bd is not None:
+        kw["bd"] = bd
+        name += f"_bd{bd[1]}"
     return kw, ({} if name == f"flash_{kind}" else {"name": name})
 
 
 def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, streaming,
-         window=None):
+         window=None, bd=None):
     bh, s, d = q.shape
     dv = v.shape[2]             # o is as wide as v; q and k share `d`
-    kw, named = _windowed(window, "fwd", d, dv)
+    kw, named = _windowed(window, "fwd", d, dv, bd)
     out_shape = [jax.ShapeDtypeStruct((bh, s, dv), q.dtype),
                  jax.ShapeDtypeStruct((bh, 1, s), jnp.float32)]
     if streaming:
-        walk = stream_walk(s, block_q, block_k, causal, window)
+        walk = stream_walk(s, block_q, block_k, causal, window,
+                           block_diffusion=bd)
         rows, rows_lanes = _walk_specs(walk, block_q, d, 0)
         rows_v, _ = _walk_specs(walk, block_q, dv, 0)
         keys, _ = _walk_specs(walk, block_k, d, 1)
@@ -839,18 +989,19 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret, streaming,
 
 
 def _bwd(sm_scale, causal, block_q, block_k, interpret, streaming,
-         residuals, g, window=None):
+         residuals, g, window=None, bd=None):
     q, k, v, o, lse = residuals
     do = g
     bh, s, d = q.shape
     dv = v.shape[2]             # v, o, do and dV; q, k, dQ and dK are `d`
-    kw, dq_named = _windowed(window, "dq", d, dv)
-    dkv_named = _windowed(window, "dkv", d, dv)[1]
+    kw, dq_named = _windowed(window, "dq", d, dv, bd)
+    dkv_named = _windowed(window, "dkv", d, dv, bd)[1]
     # delta_i = rowsum(dO_i * O_i): tiny elementwise pass, XLA fuses it.
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)[:, None, :]                 # (bh, 1, s)
     if streaming:
-        walk = stream_walk(s, block_q, block_k, causal, window)
+        walk = stream_walk(s, block_q, block_k, causal, window,
+                           block_diffusion=bd)
         rows, rows_lanes = _walk_specs(walk, block_q, d, 0)
         rows_v, _ = _walk_specs(walk, block_q, dv, 0)
         keys, _ = _walk_specs(walk, block_k, d, 1)
@@ -867,7 +1018,8 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, streaming,
             interpret=interpret, **dq_named,
         )(q, k, v, do, lse, delta)
         # the same square by its keys: a program owns a block of keys
-        walk = stream_walk(s, block_q, block_k, causal, window, by_keys=True)
+        walk = stream_walk(s, block_q, block_k, causal, window, by_keys=True,
+                           block_diffusion=bd)
         keys, _ = _walk_specs(walk, block_k, d, 0)
         keys_v, _ = _walk_specs(walk, block_k, dv, 0)
         rows, rows_lanes = _walk_specs(walk, block_q, d, 1)
@@ -917,13 +1069,15 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, streaming,
 # ---------------------------------------------------------------------------
 # Public API
 # ---------------------------------------------------------------------------
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                     causal: bool = False, sm_scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
                     interpret: Optional[bool] = None,
                     streaming: Optional[bool] = None,
-                    window: Optional[int] = None) -> jax.Array:
+                    window: Optional[int] = None,
+                    block_diffusion: Optional[tuple] = None) -> jax.Array:
     """Blockwise (flash) attention.  q, k: [BH, S, Dk]; v: [BH, S, Dv]
     -> [BH, S, Dv].  One width, Dk = Dv, is the call every model but one
     makes, and compiles to what it always did.  Where the two differ
@@ -944,6 +1098,20 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     (resident: never looped over; streaming: not in the grid).
     `window=None` leaves the calls unnamed.
 
+    `block_diffusion` = `(L, beta)` (neither causal nor windowed) is the
+    mask block-diffusion training lays over the two copies of a sequence,
+    `[x ; x_t]`, S = 2 L rows in blocks of `beta` tokens (`bd_tile` has
+    the rule): L^2 + L beta pairs a head of the square's 4 L^2.  The mask
+    is never an array: the live tiles are the table's (`stream_table`,
+    by rows and by keys, a block's run with a gap in it), a tile's mask
+    is computed in the kernel from its first row, its first key and `(L,
+    beta)`, and a tile the rule leaves whole is not masked.  Such a call
+    ALWAYS takes the streaming walk, within the resident budget too
+    (`streaming` says nothing here): a resident program's loop bounds
+    are one band's, and a second set of them for two runs a group would
+    serve the tests' sizes alone.  The three kernels carry the block
+    length in their names (`flash_fwd_bd4`).
+
     sm_scale defaults to 1/sqrt(Dk).  interpret=None auto-selects the
     Pallas interpreter off-TPU so tests run on the CPU mesh.
     streaming=None auto-selects: K/V-resident kernels while S*(Dk+Dv) fits
@@ -951,14 +1119,14 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     streaming kernels beyond (O(block*D) VMEM at any S).
     """
     out, _ = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k,
-                        interpret, streaming, window)
+                        interpret, streaming, window, block_diffusion)
     return out
 
 
 def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
-               streaming, window=None):
+               streaming, window=None, block_diffusion=None):
     bh, s, d = q.shape
-    check_blocks(s, block_q, block_k)
+    check_blocks(s, block_q, block_k, block_diffusion)
     if k.shape != q.shape or v.shape[:2] != q.shape[:2]:
         raise ValueError(f"flash attention: q {q.shape}, k {k.shape}, v "
                          f"{v.shape}: q and k share a shape, v their "
@@ -966,9 +1134,17 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
     if window is not None and (not causal or window < 1):
         raise ValueError(f"window={window} needs causal=True and at least "
                          f"one key a row")
+    if block_diffusion is not None and (causal or window is not None):
+        raise ValueError(f"block_diffusion={block_diffusion} is a mask of "
+                         f"its own: neither causal nor windowed")
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
-    streaming = _use_streaming(k, streaming, v)
-    if streaming:
+    streaming = _use_streaming(k, streaming, v, block_diffusion)
+    if block_diffusion is not None:
+        telemetry.record_static(
+            "flash_block_diffusion",
+            **stream_schedule(s, block_q, block_k, False,
+                              block_diffusion=block_diffusion))
+    elif streaming:
         telemetry.record_static(
             "flash_stream",
             labels={"window": "none" if window is None else str(window)},
@@ -979,17 +1155,18 @@ def _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
             **tile_schedule(s, block_q, block_k, causal, window))
     out, lse = (checkpoint_name(t, KEPT_NAME) for t in _fwd(
         q, k, v, scale, causal, block_q, block_k, _use_interpret(interpret),
-        streaming, window))
+        streaming, window, block_diffusion))
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd(causal, sm_scale, block_q, block_k, interpret, streaming,
-               window, residuals, g):
+               window, block_diffusion, residuals, g):
     d = residuals[0].shape[-1]
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(d)
     k, v = residuals[1:3]
     return _bwd(scale, causal, block_q, block_k, _use_interpret(interpret),
-                _use_streaming(k, streaming, v), residuals, g, window)
+                _use_streaming(k, streaming, v, block_diffusion), residuals,
+                g, window, block_diffusion)
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

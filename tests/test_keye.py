@@ -397,7 +397,7 @@ def test_the_other_decoders_steps_lower_to_the_text_they_had(name):
 
 def test_the_attention_adapter_is_one_table():
     assert set(afmoe._ATTENTION) == {afmoe.SLIDING, afmoe.FULL,
-                                     afmoe.SELECTED}
+                                     afmoe.SELECTED, afmoe.BLOCK_DIFFUSION}
     with pytest.raises(KeyError):
         afmoe._attn_fn(None, "no_such_attention")
     with pytest.raises(ValueError):

@@ -29,7 +29,7 @@ from benchmark.tests import (tiny, tiny_afmoe, tiny_granitehybrid,  # noqa: F401
                              tiny_joyai,
                              tiny_keye, tiny_kimilinear, tiny_lfm2,
                              tiny_mellum,               # join `tiny`'s table
-                             tiny_nemotronh)
+                             tiny_nemotronh, tiny_sdarmoe)
 from byteps_tpu.common import devprof
 from byteps_tpu.ops import flash_attention as fa
 from byteps_tpu.ops import ssd
@@ -107,9 +107,16 @@ FAMILIES = {
                     "kimi.moe/route", "kimi.moe/gather", "kimi.moe/grouped",
                     "kimi.moe/scatter", "kimi.moe/exact", "kimi.moe/shared",
                     "kimi.head", "byteps.optimizer"}, True),
+    "sdarmoe": ("sdar-30b-a3b-chat.ingraph-1chip",
+                {"sdar.noise", "sdar.attn.block_diffusion",
+                 "sdar.attn.block_diffusion/qkv",
+                 "sdar.attn.block_diffusion/out", "sdar.moe",
+                 "sdar.moe/route", "sdar.moe/gather", "sdar.moe/grouped",
+                 "sdar.moe/scatter", "sdar.moe/exact", "sdar.head",
+                 "byteps.optimizer"}, True),
 }
 # Where a family's scopes start with another word than its name.
-SCOPE_PREFIX = {"kimilinear": "kimi"}
+SCOPE_PREFIX = {"kimilinear": "kimi", "sdarmoe": "sdar"}
 # The names the device trace was read by before this map: an unnamed
 # kernel call is called after the innermost scope around it.  The expert
 # layer's grouped products are the program's own kernels since PR 40,
@@ -141,6 +148,7 @@ KERNEL_SCOPES = {
     "lfm2": {"lfm2.conv.gate_conv", "lfm2.attn", *_moe("lfm2.moe")},
     "kimilinear": {"kimi.kda.conv", "kimi.kda.scan", "kimi.kda.gate_norm",
                    "kimi.attn", *_moe("kimi.moe")},
+    "sdarmoe": {"sdar.attn.block_diffusion", *_moe("sdar.moe")},
 }
 PRODUCTS = ("fusion", "custom-call", "dot", "convolution", "ragged-dot")
 WORK = ("dot_general", "conv_general_dilated", "pallas_call")
@@ -218,7 +226,10 @@ def _family(name: str):
         config = tiny_kimilinear.config(layers=[1, 7, 8])
         config["published"].update(tiny_kimilinear.ON_THE_CHIP)
         cell = dataclasses.replace(cell, config=config)
-    if name in ("afmoe", "mellum", "keye"):
+    elif name == "sdarmoe":     # two of the six layers, all alike
+        cell = dataclasses.replace(cell,
+                                   config=tiny_sdarmoe.config(layers=[0, 1]))
+    if name in ("afmoe", "mellum", "keye", "sdarmoe"):
         # the narrowest widths the grouped kernels tile: a lane tile each
         # (the tiny cuts' 64 and 32 go to `lax.ragged_dot`, the compiler's)
         config = copy.deepcopy(cell.config)
@@ -332,7 +343,7 @@ def test_every_familys_tiny_step_is_mapped(name, v5e, kernels,
     moves = {n: e for n, e in kernels.items() if n.startswith("moe_rows_")}
     prefix = SCOPE_PREFIX.get(name, name)
     if name in ("afmoe", "mellum", "keye", "nemotronh", "joyai", "lfm2",
-                "kimilinear"):
+                "kimilinear", "sdarmoe"):
         # the rows move by the program's kernel in every pass, under the
         # scopes `moe.move_ms` and `moe.move_kernel_share` read
         assert all(e["scope"].rsplit("/", 1)[1] in ("gather", "scatter")

@@ -160,6 +160,34 @@ def test_streaming_flash_compiles_at_mellum_shapes(v5e, window):
                for kind in ("fwd", "dq", "dkv"))
 
 
+def test_block_diffusion_flash_compiles_at_sdar_shapes(v5e):
+    """32 heads of 128 over the two copies of ONE sequence of 16,384
+    tokens, 2 L = 32,768 rows, under the block-diffusion mask of blocks of
+    4: the grid is (heads, entries of the table), 1,088 live tiles a head
+    where the causal call's has 2,080, the table's three columns
+    scalar-prefetch operands; a tile's mask an integer remainder on a
+    column of 512 numbers and two compares, which the chip's compiler has
+    to take; a whole tile behind one `pl.when`, a masked one behind the
+    other.  The kernels carry the mask in their names and keep the forms
+    the benchmark tells them by (`benchmark/reduce/bd_cost.py`)."""
+    from benchmark.reduce import bd_cost
+    L, beta = 16384, 4
+    x = jax.ShapeDtypeStruct((32, 2 * L, 128), jnp.bfloat16,
+                             sharding=SingleDeviceSharding(v5e[0]))
+
+    def grads(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
+            q, k, v, False, None, 512, 512, False, None, None,
+            (L, beta)).astype(jnp.float32)), (0, 1, 2))(q, k, v)
+    text = _compile(grads, x, x, x).as_text()
+    lines = [line.strip().removeprefix("ROOT ").replace("%transpose_", "%")
+             .replace("%jvp_", "%") for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    assert sorted(bd_cost.call(line) for line in lines) == [
+        (kind, 32, L, 128, beta) for kind in ("dkv", "dq", "forward")], lines
+    assert all(line.count("s32[1088]") >= 3 for line in lines), lines
+
+
 def _row_moves(text: str, width: int):
     """Of a compiled step: `(left, kernels)`.  `left` are the compiler's
     own moves of rows under an expert layer, `gather(` and `scatter(`
@@ -1279,3 +1307,75 @@ def test_kimilinear_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch):
             f"{seconds:.0f} s")
     print(said)
     assert mem.peak_memory_in_bytes < 16.9e9, said
+
+
+# 40 s alone; the sdar cell's set-up compiles this step on the chip in
+# every check of every PR (`first_setup_s`).
+@pytest.mark.slow
+def test_sdar_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch):
+    """The sdar cell's step (`benchmark/configs/sdar-30b-a3b-chat.json`:
+    six layers, ONE sequence of 16,384 tokens as 32,768 rows, a plain
+    `value_and_grad` and adamw over 645,623,296 parameters) for one
+    described chip, at SIX layers: a scan over the layers whose body holds
+    the masked forward kernel twice (whole-layer remat: `remat_policy`
+    none), dQ and dK/dV once, and the expert layer's kernels; peak
+    15,699,713,024 + 42,608,640 of code, of 16.91e9 (arguments
+    7,747,781,120, temporaries 12,598,603,776).  With the flash call's `o`
+    and `lse` kept by name (`remat_policy` kernels, 1.6 GB over six
+    layers) the compiler refuses the step, 16.82G of 15.75G: five layers
+    would fit that way (peak 15,652,873,728), and six without it were
+    chosen (the configuration says why)."""
+    import dataclasses
+    import json
+
+    import optax
+
+    from benchmark.families import sdarmoe as family_sdarmoe
+    from benchmark.harness import manifest
+    from benchmark.reduce import bd_cost
+    monkeypatch.setattr(fa, "_use_interpret", lambda interpret: False)
+    with open(os.path.join(manifest.BENCH, "configs",
+                           "sdar-30b-a3b-chat.json")) as f:
+        config = json.load(f)
+    family = family_sdarmoe.Family(config, config["job"])
+    assert family.cfg.num_layers == 6
+    assert family.cfg.remat_policy == "none"
+    opt = family.optimizer()
+    one = SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+    params = jax.eval_shape(family.init, jax.random.key(0))
+    opt_state = jax.eval_shape(opt.init, params)
+    batch = jax.eval_shape(lambda k: family.make_batch(k, 1),
+                           jax.random.key(0))
+
+    def compiled(loss):
+        def step(params, opt_state, batch):
+            value, grads = jax.value_and_grad(loss)(params, batch)
+            updates, opt_state = opt.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state, value
+        return jax.jit(step, donate_argnums=(0, 1)).lower(
+            on_chip(params), on_chip(opt_state), on_chip(batch)).compile()
+    step = compiled(family.loss)
+    text = step.as_text()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in text.splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    masked = sorted(c[0] for c in map(bd_cost.call, calls) if c)
+    assert masked == ["dkv", "dq", "forward", "forward"], masked
+    _assert_rows_move_by_kernel(text, config["hidden_size"],
+                                config["num_experts_per_tok"])
+    mem = step.memory_analysis()
+    said = (f"arguments {mem.argument_size_in_bytes:,} temporaries "
+            f"{mem.temp_size_in_bytes:,} peak {mem.peak_memory_in_bytes:,} "
+            f"code {mem.generated_code_size_in_bytes:,}")
+    print(said)
+    assert (mem.peak_memory_in_bytes
+            + mem.generated_code_size_in_bytes) < 16.0e9, said
+    # and what was not chosen does not fit
+    kept = dataclasses.replace(family.cfg, remat_policy="kernels")
+    from byteps_tpu.models import sdar
+    with pytest.raises(Exception, match="hbm"):
+        compiled(lambda p, b: sdar.loss_fn(p, b, kept))

@@ -36,6 +36,8 @@ def main(argv=None) -> int:
     ap.add_argument("--inits", type=float, nargs="*", default=[])
     ap.add_argument("--steps", type=int, default=32)
     ap.add_argument("--every", type=int, default=3)
+    ap.add_argument("--capacity", type=float,
+                    help="another `moe_capacity_factor` than the cell pins")
     ap.add_argument("--tiny", action="store_true",
                     help="the cell at the widths of benchmark/tests")
     ap.add_argument("--out")
@@ -52,16 +54,20 @@ def main(argv=None) -> int:
     if args.tiny:
         from benchmark.tests import (tiny, tiny_afmoe,  # noqa: F401
                                      tiny_keye, tiny_kimilinear,
-                                     tiny_lfm2, tiny_mellum, tiny_nemotronh)
+                                     tiny_lfm2, tiny_mellum, tiny_nemotronh,
+                                     tiny_sdarmoe)
         cell = tiny.tiny_cell(args.workload)
     else:
         cell = manifest.load_cell(args.workload)
     name = cell.config["family"]
     families = importlib.import_module(f"benchmark.families.{name}")
-    # (a family's model module is called after it, but for two)
+    # (a family's model module is called after it, but for three)
     model = importlib.import_module("byteps_tpu.models." + {
-        "nemotronh": "nemotron_h",
-        "kimilinear": "kimi_linear"}.get(name, name))
+        "nemotronh": "nemotron_h", "kimilinear": "kimi_linear",
+        "sdarmoe": "sdar"}.get(name, name))
+    # sdar routes the two copies of a batch, whose noise is part of it:
+    # its `routing` takes the batch whole and counts by the ROW
+    two_copies = name == "sdarmoe"
     pinned = cell.config["program_options"]["pinned"]
     option = "post_attn_norm_init"
     if args.inits and option not in pinned:
@@ -71,6 +77,9 @@ def main(argv=None) -> int:
         config = copy.deepcopy(cell.config)
         if init is not None:
             config["program_options"]["pinned"][option] = init
+        if args.capacity is not None:
+            config["program_options"]["pinned"][
+                "moe_capacity_factor"] = args.capacity
         return families.Family(config, cell.job)
 
     family = family_at(pinned.get(option))
@@ -82,10 +91,13 @@ def main(argv=None) -> int:
     n = cell.job["per_chip_batch"]
 
     @jax.jit
-    def counters(params, tokens):
-        routing = model.routing(params, tokens, family.cfg)
+    def counters(params, batch):
+        tokens = batch[0]
+        routing = model.routing(params, batch if two_copies else tokens,
+                                family.cfg)
+        rows = tokens.size * (2 if two_copies else 1)
         return jax.vmap(
-            lambda r: dropless_moe.counters(r, tokens.size))(routing)
+            lambda r: dropless_moe.counters(r, rows))(routing)
 
     out = open(args.out, "a") if args.out else None
     for init in args.inits or [pinned.get(option)]:
@@ -96,13 +108,14 @@ def main(argv=None) -> int:
                 seeded.batch(family, seed, n),
                 NamedSharding(mesh, PartitionSpec("dp")))
             line = {"seed": seed, option: init,
+                    "moe_capacity_factor": family.cfg.moe_capacity_factor,
                     "device": jax.devices()[0].device_kind,
                     "step_ms": [], "loss": [], "counters": {}}
             for i in range(args.steps):
                 if i % args.every == 0:
                     line["counters"][i] = jax.tree.map(
                         lambda a: [round(float(x), 4) for x in a],
-                        counters(params, batch[0]))
+                        counters(params, batch))
                 t0 = time.perf_counter()
                 params, opt_state, loss = step(params, opt_state, batch)
                 line["loss"].append(round(float(loss), 4))

@@ -38,7 +38,11 @@ sys.path.insert(0, ROOT)
 
 
 # a family's model module where it is not called after the family
-MODELS = {"nemotronh": "nemotron_h", "kimilinear": "kimi_linear"}
+MODELS = {"nemotronh": "nemotron_h", "kimilinear": "kimi_linear",
+          "sdarmoe": "sdar"}
+# families whose layers run TWO copies of a batch `(tokens, masked,
+# weight)`: `routing` takes the batch whole, and counts by the row
+TWO_COPIES = {"sdarmoe"}
 
 
 def _expert_extras(family, cell, params, seed, variant):
@@ -58,13 +62,16 @@ def _expert_extras(family, cell, params, seed, variant):
            "routing_counters": list(family.routing_counters)}
     del family.selection[:], family.routing_counters[:]
     if variant is None:
-        tokens = seeded.batch(family, seed, cell.job["per_chip_batch"])[0]
-        routing = jax.jit(lambda p, t: model.routing(p, t, family.cfg))(
-            params, tokens)
+        batch = seeded.batch(family, seed, cell.job["per_chip_batch"])
+        whole = name in TWO_COPIES
+        routing = jax.jit(lambda p, b: model.routing(
+            p, b if whole else b[0], family.cfg))(params, batch)
+        rows = batch[0].size * (2 if whole else 1)
         out["step_counters"] = jax.tree.map(
             lambda a: [float(x) for x in a],
-            jax.vmap(lambda r: dropless_moe.counters(r, tokens.size))(
-                routing))
+            jax.vmap(lambda r: dropless_moe.counters(r, rows))(routing))
+        if whole:
+            out["noise"] = jax.tree.map(float, model.batch_counters(batch))
     return out
 
 
@@ -260,7 +267,7 @@ def _joyai_record(cell, out: str) -> int:
 EXTRAS = {"afmoe": _expert_extras, "mellum": _expert_extras,
           "keye": _keye_extras, "nemotronh": _expert_extras,
           "joyai": _expert_extras, "lfm2": _expert_extras,
-          "kimilinear": _expert_extras,
+          "kimilinear": _expert_extras, "sdarmoe": _expert_extras,
           "granitehybrid": _granitehybrid_extras}
 RECORD = {"granitehybrid": _granitehybrid_record, "mellum": _mellum_record,
           "keye": _keye_record, "nemotronh": _nemotronh_record,
@@ -298,8 +305,9 @@ def main(argv=None) -> int:
         f"benchmark.families.{name}").Family(cell.config, cell.job)
     variants = {}
     if args.variants:
-        variants = importlib.import_module(
-            f"benchmark.tests.{name}_variants").VARIANTS
+        module = importlib.import_module(f"benchmark.tests.{name}_variants")
+        # a family's controls of its precision limits, where it has them
+        variants = {**module.VARIANTS, **getattr(module, "CONTROLS", {})}
     names = list(variants) if args.variants == ["all"] else args.variants
     samples = family.reference_check["samples"]
     out = open(args.out, "a") if args.out else None
