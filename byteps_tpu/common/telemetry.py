@@ -485,6 +485,12 @@ _STATIC = {
             "counted from its traced body: 12 where the solve's gradient "
             "is two products of the inverse, 30 where autodiff walks the "
             "doubling product"),
+        "heads_per_program": _gauge(
+            "bps_kda_heads_per_program",
+            "heads of one chunk a program of that scan's kernel `call` "
+            "(`fwd`, `bwd`) holds, their equations laid out in step: the "
+            "most of 4, 2, 1 (`bwd`: 2, 1) that divides the heads where a "
+            "head's columns are whole tiles of 128 lanes, else 1"),
     },
     "layer_plan": {
         "stacks": _gauge(

@@ -42,12 +42,48 @@ gradient:
 
   - `kda_scan`: two Pallas TPU kernels, `kda_fwd_c<C>` and `kda_bwd_c<C>`
     (the names are how a device trace tells them from the flash, SSD and
-    convolution calls), grid (batch, heads, chunks), the chunks innermost
-    and the state, or its gradient, in VMEM scratch across them.  They
+    convolution calls), grid (batch, heads / hp, chunks), the chunks
+    innermost and the states of a program's `hp` heads, or their
+    gradients, in VMEM scratch [hp, V, K] across them.  They
     read the MIXER'S OWN layout: q, k, v, g and the results as [B, S, H K]
-    (a head's [C, 128] block is whole lane tiles; no transposed copy of a
+    (a program's block is a slab [C, hp K] of its heads' columns, whole
+    lane tiles; no transposed copy of a
     wide operand, `ops/ssd.py` says what those cost); only beta and its
-    gradient, [B, S, H] float32, are laid head-major outside.  The l2
+    gradient, [B, S, H] float32, are laid head-major outside, a program's
+    block the [S/C, C] of its `hp` heads, beside the chunk states' [1,
+    hp, 1, V, K].  HEADS A PROGRAM (`heads_per_program`, a rule of shapes
+    and no option): the most of 4, 2, 1 in the forward kernel, of 2, 1 in
+    the backward one, that divides the heads where K and V are multiples
+    of 128 lanes (a head is then a constant lane slice of the slab, at a
+    tile's edge), else 1, the parent's program (the tests' small
+    shapes).  A chunk of one head is ONE dependency
+    chain of two kinds of work, the solve's [C, C] float32 products on
+    the MXU and the diagonals' rotations, `exp`s and sums on the vector
+    units, each idle while the other runs; and Mosaic's scheduler keeps
+    close to the ORDER OF THE TEXT.  So a head's arithmetic is traced
+    once (`jax.make_jaxpr` of `_chunk_kernel` / `_chunk_transposed`, not
+    a character of which knows of this) and the program's text is that
+    jaxpr's equations laid out IN STEP (`_in_step`): equation i of every
+    head of the program, then equation i + 1; the heads' results stored
+    side by side.  Same products, same precisions, same order within a
+    head; the results are the one-head program's bit for bit.  What the
+    chip said at the cell's shapes (B 1, S 32,768, H 32, K = V = 128,
+    bfloat16; a call's ms in a device trace, Mosaic's seconds a kernel;
+    PERF.md, Findings, PR 63): forward 36.34 ms at one head; the heads ONE
+    AFTER ANOTHER in the text (`ssd._walk_heads`' way) 34.32 at two,
+    33.32 at four, 32.83 at eight, 4.04 / hp off and no more: a
+    program's fill and drain shared, nothing overlapped; IN STEP 23.99 at
+    two (0.5 s), **19.59 at four (1.0 s)**, 19.20 at eight (3.4 s), 19.11
+    at sixteen (8.7 s).  Backward 70.23 at one; one after another 62.18
+    at two, 61.37 at four, by a loop of two 70.04 (a step of the grid
+    costs nothing: 24 ns); in step **51.02 at two (1.3 s)**, 48.73 at
+    four (3.1 s), 46.66 at eight (10.3 s).  Four forward: eight buys 2%
+    of a call for three times the compile.  Two backward: a run's set-up
+    traces and lowers every head's equations, 1,400 a head there and 560
+    forward, on a host that traces five times slower than it does
+    anything else, and with four heads both ways the cell's warm set-up
+    read 231.4 s against the parent's 221.4; the two heads more would
+    have bought 9 ms of a 1,530 ms step.  The l2
     norms and the 1 / sqrt(K), the cumulative sum of g (a product with a
     triangle of ones, float32) and all of the above are inside.  The
     backward kernel is `jax.vjp` of the forward kernel's chunk
@@ -86,6 +122,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.extend import core as jex_core
 
 from ..common import telemetry
 from . import ssd
@@ -368,26 +405,96 @@ def solve_products(chunk: int, key_dim: int, value_dim: int,
                for eqn in _dots(jaxpr))
 
 
+def heads_per_program(heads: int, key_dim: int, value_dim: int,
+                      backward: bool = False) -> int:
+    """Heads of one chunk a program of the forward kernel holds, or of the
+    backward one: the most of 4, 2, 1 (backward 2, 1: its text is two and
+    a half times the forward's, and a run's set-up traces and lowers
+    every head's) that divides the heads, where a head's columns are
+    whole tiles of 128 lanes (a head is then a constant slice of the slab
+    at a tile's edge); else 1."""
+    if key_dim % 128 or value_dim % 128:
+        return 1
+    return next(hp for hp in ((2, 1) if backward else (4, 2, 1))
+                if heads % hp == 0)
+
+
+def _called(eqn):
+    """The jaxpr an equation calls and nothing else (`jnp`'s own `jit`s,
+    `_solve` and `_down` where nothing differentiates them), or None."""
+    if eqn.primitive.name in ("jit", "custom_vjp_call"):
+        return eqn.params.get("jaxpr") or eqn.params.get("call_jaxpr")
+    return None
+
+
+def _in_step(jaxpr, consts, heads):
+    """`jaxpr` for every head's arguments, AN EQUATION AT A TIME: equation
+    i of every head stands before equation i + 1 of any, calls inlined.
+    Mosaic's scheduler keeps close to the order of the text, so this is
+    what lets one head's products run under another's vector passes
+    (module docstring).  -> every head's results."""
+    envs = [dict(zip((*jaxpr.constvars, *jaxpr.invars), (*consts, *args)))
+            for args in heads]
+
+    def read(env, v):
+        return env[v] if isinstance(v, jex_core.Var) else v.val
+    for eqn in jaxpr.eqns:
+        vals = [[read(env, v) for v in eqn.invars] for env in envs]
+        inner = _called(eqn)
+        if inner is not None:
+            outs = _in_step(inner.jaxpr, inner.consts, vals)
+        else:
+            outs = [eqn.primitive.bind(*x, **eqn.params) for x in vals]
+            if not eqn.primitive.multiple_results:
+                outs = [[o] for o in outs]
+        for env, o in zip(envs, outs):
+            env.update(zip(eqn.outvars, o))
+    return [[read(env, v) for v in jaxpr.outvars] for env in envs]
+
+
+def _heads_in_step(chunk_fn, heads):
+    """`chunk_fn` (`_chunk_kernel`, `_chunk_transposed`) of every head's
+    arguments, traced ONCE and its equations laid out head by head
+    (`_in_step`); with one head, the function's own text."""
+    closed = jax.make_jaxpr(chunk_fn)(*(
+        jax.ShapeDtypeStruct(x.shape, x.dtype) for x in heads[0]))
+    return _in_step(closed.jaxpr, closed.consts, heads)
+
+
+def _head_slices(ref, hp):
+    """A program's slab [C, hp w] as its heads' [C, w], constant slices of
+    what is loaded once."""
+    slab = ref[0, 0]
+    w = slab.shape[1] // hp
+    return [slab[:, h * w:(h + 1) * w] for h in range(hp)]
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, o_ref, st_ref,
-                state_scr):
+                state_scr, *, hp):
+    """One chunk of a program's `hp` heads, their results stored side by
+    side."""
     c = pl.program_id(2)
 
     @pl.when(c == 0)
     def _init():
         state_scr[:] = jnp.zeros_like(state_scr)
 
-    st_ref[0, 0, 0] = state_scr[:]                  # the chunk's start
-    o, state_scr[:] = _chunk_kernel(
-        q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], g_ref[0, 0],
-        _column(beta_ref[0, 0, pl.ds(c, 1), :]), state_scr[:])
-    o_ref[0, 0] = o.astype(o_ref.dtype)
+    st_ref[0, :, 0] = state_scr[:]                  # the chunk's start
+    heads = zip(*(_head_slices(r, hp) for r in (q_ref, k_ref, v_ref, g_ref)),
+                (_column(beta_ref[0, h, pl.ds(c, 1), :]) for h in range(hp)),
+                (state_scr[h] for h in range(hp)))
+    os, states = zip(*_heads_in_step(_chunk_kernel, list(heads)))
+    for h in range(hp):
+        state_scr[h] = states[h]
+    o_ref[0, 0] = ssd._columns([o.astype(o_ref.dtype) for o in os])
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref,
-                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate_scr):
-    """One chunk, the chunks walked last to first: the chunk made again
-    from the state that entered it, and its transpose applied to `do` and
-    the gradient of the state it left."""
+                dq_ref, dk_ref, dv_ref, dg_ref, dbeta_ref, dstate_scr, *,
+                hp):
+    """One chunk of a program's `hp` heads, the chunks walked last to
+    first: a head's chunk made again from the state that entered it, and
+    its transpose applied to `do` and the gradient of the state it left."""
     c = pl.program_id(2)
     at = pl.num_programs(2) - 1 - c
 
@@ -395,29 +502,36 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, beta_ref, st_ref, do_ref,
     def _init():
         dstate_scr[:] = jnp.zeros_like(dstate_scr)
 
-    dq, dk, dv, dg, dbeta, dstate_scr[:] = _chunk_transposed(
-        q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], g_ref[0, 0],
-        _column(beta_ref[0, 0, pl.ds(at, 1), :]), st_ref[0, 0, 0],
-        do_ref[0, 0].astype(_F32), dstate_scr[:])
-    dq_ref[0, 0] = dq.astype(dq_ref.dtype)
-    dk_ref[0, 0] = dk.astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
-    dg_ref[0, 0] = dg
-    dbeta_ref[0, 0, pl.ds(at, 1), :] = _as_row(dbeta)
+    heads = zip(*(_head_slices(r, hp) for r in (q_ref, k_ref, v_ref, g_ref)),
+                (_column(beta_ref[0, h, pl.ds(at, 1), :]) for h in range(hp)),
+                (st_ref[0, h, 0] for h in range(hp)),
+                (do.astype(_F32) for do in _head_slices(do_ref, hp)),
+                (dstate_scr[h] for h in range(hp)))
+    dq, dk, dv, dg, dbeta, dstates = zip(
+        *_heads_in_step(_chunk_transposed, list(heads)))
+    for h in range(hp):
+        dstate_scr[h] = dstates[h]
+        dbeta_ref[0, h, pl.ds(at, 1), :] = _as_row(dbeta[h])
+    for ref, parts in ((dq_ref, dq), (dk_ref, dk), (dv_ref, dv),
+                       (dg_ref, dg)):
+        ref[0, 0] = ssd._columns([t.astype(ref.dtype) for t in parts])
 
 
-def _specs(B, S, H, K, V, chunk, reverse):
+def _specs(B, S, H, K, V, chunk, hp, reverse):
+    """The blocks of a program of `hp` heads: the wide operands a slab of
+    `hp` heads' columns of the mixer's layout, whole 128-lane tiles."""
     nc = S // chunk
 
     def at(c):
         return nc - 1 - c if reverse else c
-    keys = pl.BlockSpec((1, 1, chunk, K), lambda b, h, c: (b, at(c), 0, h))
-    values = pl.BlockSpec((1, 1, chunk, V),
+    keys = pl.BlockSpec((1, 1, chunk, hp * K),
+                        lambda b, h, c: (b, at(c), 0, h))
+    values = pl.BlockSpec((1, 1, chunk, hp * V),
                           lambda b, h, c: (b, at(c), 0, h))
-    # beta and its gradient: a head's whole [S/C, C], where it is while
-    # the head's chunks are walked
-    betas = pl.BlockSpec((1, 1, nc, chunk), lambda b, h, c: (b, h, 0, 0))
-    states = pl.BlockSpec((1, 1, 1, V, K),
+    # beta and its gradient: the heads' whole [S/C, C], where they are
+    # while the heads' chunks are walked
+    betas = pl.BlockSpec((1, hp, nc, chunk), lambda b, h, c: (b, h, 0, 0))
+    states = pl.BlockSpec((1, hp, 1, V, K),
                           lambda b, h, c: (b, h, at(c), 0, 0))
     return keys, values, betas, states
 
@@ -455,19 +569,21 @@ def _fwd_call(q, k, v, g, beta, chunk, interpret):
     B, S, wide = q.shape
     H = beta.shape[-1]
     K, V = wide // H, v.shape[-1] // H
-    keys, values, betas, states = _specs(B, S, H, K, V, chunk, False)
+    hp = heads_per_program(H, K, V)
+    keys, values, betas, states = _specs(B, S, H, K, V, chunk, hp, False)
     item = q.dtype.itemsize
     q, k, v, g = (_chunked(t, chunk) for t in (q, k, v, g))
     o, states = pl.pallas_call(
-        _fwd_kernel, grid=(B, H, S // chunk),
+        functools.partial(_fwd_kernel, hp=hp),
+        grid=(B, H // hp, S // chunk),
         in_specs=[keys, keys, values, keys, betas],
         out_specs=[values, states],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct((B, H, S // chunk, V, K), _F32)],
-        scratch_shapes=[pltpu.VMEM((V, K), _F32)],
-        compiler_params=_params(
+        scratch_shapes=[pltpu.VMEM((hp, V, K), _F32)],
+        compiler_params=_params(hp * (
             2 * chunk * (item * (2 * K + 2 * V) + 4 * K) + 4 * S * 2
-            + 3 * 4 * V * K),
+            + 3 * 4 * V * K)),
         interpret=interpret, name=FWD_NAME.format(chunk),
     )(q, k, v, g, _head_major(beta, chunk))
     return o.reshape(B, S, H * V), states
@@ -479,11 +595,13 @@ def _bwd_call(q, k, v, g, beta, states, do, chunk, interpret):
     B, S, wide = q.shape
     H = beta.shape[-1]
     K, V = wide // H, v.shape[-1] // H
-    keys, values, betas, st = _specs(B, S, H, K, V, chunk, True)
+    hp = heads_per_program(H, K, V, backward=True)
+    keys, values, betas, st = _specs(B, S, H, K, V, chunk, hp, True)
     item = q.dtype.itemsize
     q, k, v, g, do = (_chunked(t, chunk) for t in (q, k, v, g, do))
     dq, dk, dv, dg, dbeta = pl.pallas_call(
-        _bwd_kernel, grid=(B, H, S // chunk),
+        functools.partial(_bwd_kernel, hp=hp),
+        grid=(B, H // hp, S // chunk),
         in_specs=[keys, keys, values, keys, betas, st, values],
         out_specs=[keys, keys, values, keys, betas],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -491,10 +609,10 @@ def _bwd_call(q, k, v, g, beta, states, do, chunk, interpret):
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct(g.shape, _F32),
                    jax.ShapeDtypeStruct((B, H, S // chunk, chunk), _F32)],
-        scratch_shapes=[pltpu.VMEM((V, K), _F32)],
-        compiler_params=_params(
+        scratch_shapes=[pltpu.VMEM((hp, V, K), _F32)],
+        compiler_params=_params(hp * (
             2 * chunk * (item * (4 * K + 3 * V) + 8 * K) + 4 * S * 4
-            + 3 * 4 * V * K),
+            + 3 * 4 * V * K)),
         interpret=interpret, name=BWD_NAME.format(chunk),
     )(q, k, v, g, _head_major(beta, chunk), states, do)
     return (dq.reshape(B, S, wide), dk.reshape(B, S, wide),
@@ -554,3 +672,8 @@ def record(layers: int, batch: int, heads: int, seq_len: int, key_dim: int,
         state_bytes=state_bytes(batch, heads, seq_len, key_dim, value_dim,
                                 chunk),
         bwd_solve_products=solve_products(chunk, key_dim, value_dim))
+    for call, backward in (("fwd", False), ("bwd", True)):
+        telemetry.record_static(
+            "kda_scan", labels={"call": call},
+            heads_per_program=heads_per_program(heads, key_dim, value_dim,
+                                                backward))

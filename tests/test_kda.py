@@ -58,24 +58,38 @@ def rel(a, b):
     return float(jnp.linalg.norm(a - b) / jnp.linalg.norm(b))
 
 
+# (heads, head size) -> heads a program of the forward and of the
+# backward kernel holds: the rule engages on fours (forward) or pairs of
+# heads of whole 128-lane tiles
+PROGRAMS = {(2, 32): (1, 1), (2, 128): (2, 2), (4, 128): (4, 2),
+            (3, 128): (1, 1)}
+
+
+@pytest.mark.parametrize("heads,size", PROGRAMS)
 @pytest.mark.parametrize("form", FORMS)
-def test_both_forms_are_the_recurrence_with_its_five_gradients(form):
-    """Two sequences of three chunks, two heads: the batch's second
-    sequence starts from a zero state (the recurrence is taken a sequence
-    at a time)."""
-    args, ct = operands(2, 192, 2, 32)
+def test_both_forms_are_the_recurrence_with_its_five_gradients(form, heads,
+                                                               size):
+    """Two sequences of three chunks: the batch's second sequence starts
+    from a zero state (the recurrence is taken a sequence at a time).
+    The kernels in all their programs, four and two heads a program and
+    the fall-back of one."""
+    assert tuple(kda.heads_per_program(heads, size, size, backward)
+                 for backward in (False, True)) == PROGRAMS[heads, size]
+    args, ct = operands(2, 192, heads, size)
     want = everything(recurrence, args, ct)
     got = everything(FORMS[form], args, ct)
     for name, a, b in zip(("o", "dq", "dk", "dv", "dg", "dbeta"), got, want):
         assert rel(a, b) < 2e-5, (name, rel(a, b))
 
 
+@pytest.mark.parametrize("heads,size", ((1, 32), (2, 128)))
 @pytest.mark.parametrize("form", FORMS)
-def test_decays_of_ten_a_step_for_a_whole_chunk_stay_finite_and_right(form):
+def test_decays_of_ten_a_step_for_a_whole_chunk_stay_finite_and_right(
+        form, heads, size):
     """g near -10 at every position and channel: a chunk's cumulative sum
     reaches -640, and `exp(-G)` alone is infinite from the ninth row on.
     Every exponent here is a difference that is never positive."""
-    (q, k, v, g, beta), ct = operands(1, 128, 1, 32, seed=1)
+    (q, k, v, g, beta), ct = operands(1, 128, heads, size, seed=1)
     g = -10.0 + 0.5 * g
     assert float(jnp.exp(-jnp.cumsum(g[0, :64], 0)).max()) == float("inf")
     want = everything(recurrence, (q, k, v, g, beta), ct)
@@ -101,17 +115,62 @@ def test_a_chunk_of_32_and_a_length_that_is_no_multiple_of_64():
             fn(*args, chunk=48)
 
 
+@pytest.mark.parametrize("heads,size", ((2, 16), (2, 128)))
 @pytest.mark.parametrize("form", FORMS)
-def test_no_position_reads_a_later_one(form):
+def test_no_position_reads_a_later_one(form, heads, size):
     """One position's q, k, v, g and beta changed: nothing before it
     moves, and what follows does."""
-    args, _ = operands(1, 128, 2, 16, seed=3)
+    args, _ = operands(1, 128, heads, size, seed=3)
     at = 70
     moved = tuple(t.at[:, at].add(1.0) if i < 4 else t.at[:, at].mul(0.5)
                   for i, t in enumerate(args))
     a, b = FORMS[form](*args), FORMS[form](*moved)
     assert bool((a[:, :at] == b[:, :at]).all())
     assert float(jnp.abs(a[:, at:] - b[:, at:]).max()) > 1e-3
+
+
+@pytest.mark.parametrize("heads", (2, 3, 4))
+def test_every_head_lands_at_its_own_place(heads):
+    """Every head its own inputs, and head h's alone changed: head h's
+    columns of `o` and of the four wide gradients, its column of beta's
+    gradient and its chunk states move, no other head's by a bit.  With
+    two and four heads a program (2 and 4 heads) as with one (3)."""
+    K, at = 128, heads - 1
+    args, ct = operands(1, 128, heads, K, seed=6)
+    cols = slice(at * K, (at + 1) * K)
+    moved = tuple(t.at[..., cols].multiply(0.5) for t in args[:4]) + (
+        args[4].at[..., at].multiply(0.5),)
+    mine = jnp.arange(heads) == at
+    for a, b in zip(everything(kda.kda_scan, args, ct),
+                    everything(kda.kda_scan, moved, ct)):
+        same = (a == b).reshape(128, heads, -1).all((0, 2))
+        assert bool((same == ~mine).all()), same
+    (_, a), (_, b) = (kda._fwd_call(*x, 64, True) for x in (args, moved))
+    assert a.shape == (1, heads, 2, K, K)
+    same = (a[:, :, 1] == b[:, :, 1]).all((0, 2, 3))
+    assert bool((same == ~mine).all()) and not bool(a[:, :, 0].any())
+
+
+def test_the_heads_equations_stand_in_step():
+    """`_heads_in_step`: every head's results are the function's own, bit
+    for bit, and in the text that is traced equation i of every head
+    stands before equation i + 1 of any (calls inlined): the order the
+    chip's scheduler overlaps the heads by."""
+    def fn(x, y):
+        z = kda._solve(jnp.tril(x, -1)) @ y
+        return jnp.where(z > 0, jnp.exp(-z), z), kda._hi(y, y).sum()
+    keys = jax.random.split(jax.random.key(8), 6)
+    heads = [(jax.random.normal(keys[i], (16, 16)),
+              jax.random.normal(keys[i + 3], (16, 16))) for i in range(3)]
+    for got, args in zip(kda._heads_in_step(fn, heads), heads):
+        assert all(bool((a == b).all()) for a, b in zip(got, fn(*args)))
+
+    def text(heads):
+        return [e.primitive.name for e in jax.make_jaxpr(
+            functools.partial(kda._heads_in_step, fn))(heads).jaxpr.eqns]
+    alone = text(heads[:1])
+    assert "jit" not in alone and alone.count("dot_general") == 8
+    assert text(heads) == [name for name in alone for _ in heads]
 
 
 def test_bfloat16_operands_round_once_and_the_state_stays_float32():
@@ -157,9 +216,15 @@ def test_full_precision_products_of_a_chunks_traced_body(backward, products):
 def test_state_bytes_and_gauges():
     from byteps_tpu.common import telemetry
     assert kda.state_bytes(1, 32, 32768, 128, 128) == 32 * 512 * 65536
+    assert [kda.heads_per_program(*a) for a in (
+        (32, 128, 128), (32, 128, 64), (3, 128, 128), (2, 32, 32),
+        (2, 256, 128), (6, 128, 128))] == [4, 1, 1, 1, 2, 2]
+    assert kda.heads_per_program(32, 128, 128, backward=True) == 2
     kda.record(4, 1, 32, 32768, 128, 128)
     text = telemetry.get_registry().render_prometheus()
     for line in ("bps_kda_scan_layers 4", "bps_kda_chunk 64",
                  "bps_kda_state_bytes 1073741824", "bps_kda_kernel 1",
-                 "bps_kda_bwd_solve_products 12"):
+                 "bps_kda_bwd_solve_products 12",
+                 'bps_kda_heads_per_program{call="fwd"} 4',
+                 'bps_kda_heads_per_program{call="bwd"} 2'):
         assert line in text, line

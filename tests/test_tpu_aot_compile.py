@@ -1352,6 +1352,9 @@ def test_kimilinear_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch):
     # inverse: the ten doubling products made again and two more
     import byteps_tpu as bps
     assert bps.get_metrics()["bps_kda_bwd_solve_products"] == 12
+    # four heads of a chunk a program forward, two backward (PR 63)
+    heads = 'bps_kda_heads_per_program{call="%s"}'
+    assert [bps.get_metrics()[heads % c] for c in ("fwd", "bwd")] == [4, 2]
     assert len([c for c in calls if c.startswith("%mamba_conv_")]) == 3
     # the head norm and the output gate: the forward kernel, again under
     # remat, the backward one, and no float32 copy of the heads' width
