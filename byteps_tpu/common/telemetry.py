@@ -419,7 +419,10 @@ def _gauge(name: str, help: str, cast=int) -> tuple:
 #: its row moves (`ops/moe_rows.py`: one set of row counts a `use`), and
 #: the attention over selected keys (`ops/sparse_attention.py`), and what
 #: a model's rematerialised layers keep by name (`models/nemotron_h.py`,
-#: `models/granite_hybrid.py`: one set of gauges a `name` label).
+#: `models/granite_hybrid.py`: one set of gauges a `name` label), and a
+#: looped model's walks and the exit statistics of a batch
+#: (`models/ouro.py`: `loop`, and `loop_exit` with one set of gauges a
+#: `step` label).
 _STATIC = {
     "ingraph_exchange": {
         "leaves": _gauge(
@@ -561,6 +564,41 @@ _STATIC = {
             "bps_bd_weight_mean",
             "mean over ALL tokens of that batch's loss weights, masked / "
             "t: 1 in expectation", float),
+    },
+    "loop": {
+        "steps": _gauge(
+            "bps_loop_steps",
+            "walks the last traced step of a looped model makes over its "
+            "layers with the same weights (models/ouro.py)"),
+        "layer_applications": _gauge(
+            "bps_loop_layer_applications",
+            "layer applications of that step's forward pass: walks x "
+            "layers held"),
+        "kept_bytes": _gauge(
+            "bps_loop_kept_bytes",
+            "bytes of layer inputs that step's whole-layer remat keeps "
+            "from the forward pass for the backward pass: applications x "
+            "rows x hidden x the activations' item size", float),
+    },
+    "loop_exit": {
+        "share": _gauge(
+            "bps_exit_share",
+            "mean over the tokens of the probability of leaving at walk "
+            "`step` (label, from 1), in the last batch a caller handed "
+            "`models/ouro.py` `record_exit`: the shares sum to 1", float),
+        "nll": _gauge(
+            "bps_loop_nll",
+            "mean over the tokens of that batch of the head's "
+            "cross-entropy after walk `step` (label, from 1), nats", float),
+        "expected_steps": _gauge(
+            "bps_exit_expected_steps",
+            "mean over the tokens of that batch of the walk a token "
+            "leaves at, sum t p_t: 1 where the gate has collapsed onto "
+            "the first walk", float),
+        "entropy": _gauge(
+            "bps_exit_entropy",
+            "mean over the tokens of that batch of the exit "
+            "distribution's entropy, nats: at most ln(walks)", float),
     },
     "grouped_matmul": {
         "kernel": _gauge(

@@ -25,7 +25,11 @@ kernels of `ops/kda.py`, three such layers to every one of latent
 attention without positions; 8-of-256 sigmoid-routed experts round a
 shared one) and `sdar` (block-diffusion training: a clean and a noised
 copy of every sequence under the flash kernels' mask of three regions, a
-masked 1/t-weighted loss on the noised rows).  Their attention calls come
+masked 1/t-weighted loss on the noised rows) and `ouro` (a LOOPED
+decoder: a stack of sandwich-normed layers walked four times with the
+same weights as one scan of layer applications, an exit gate after every
+walk, the expected cross-entropy over the exit walk as one streamed head
+call under differentiated weights).  Their attention calls come
 from one table, `afmoe._ATTENTION`: `sliding_attention`,
 `full_attention`, `selected_attention`, `block_diffusion`.
 """

@@ -1,6 +1,6 @@
 """`family_cases.Cases`, the one body of the families' reference and
 broken-variant cases, on a family made up here (one weight vector), and
-the five families' tables of variants against the variants there are."""
+the families' tables of variants against the variants there are."""
 
 import contextlib
 import types
@@ -14,10 +14,12 @@ import test_granite_hybrid_variants
 import test_keye_variants
 import test_mellum_variants
 import test_nemotron_h_variants
+import test_ouro_variants
 import test_sdar_variants
 from benchmark.tests import (afmoe_variants, granitehybrid_variants,
                              keye_variants, mellum_variants,
-                             nemotronh_variants, sdarmoe_variants)
+                             nemotronh_variants, ouro_variants,
+                             sdarmoe_variants)
 from family_cases import Cases
 
 LIMITS = dict(samples=2, loss_rel_tol=1e-3, grad_rel_tol=1e-3,
@@ -140,7 +142,9 @@ def test_the_told_table_is_held_both_ways(told):
     (test_mellum_variants, mellum_variants),
     (test_nemotron_h_variants, nemotronh_variants),
     (test_sdar_variants, sdarmoe_variants),
-], ids=["afmoe", "granitehybrid", "keye", "mellum", "nemotronh", "sdarmoe"])
+    (test_ouro_variants, ouro_variants),
+], ids=["afmoe", "granitehybrid", "keye", "mellum", "nemotronh", "sdarmoe",
+        "ouro"])
 def test_a_familys_table_names_every_variant_and_its_layers(module,
                                                             variants):
     """One row a variant: the layers it runs on, among those the program

@@ -29,7 +29,7 @@ from benchmark.tests import (tiny, tiny_afmoe, tiny_granitehybrid,  # noqa: F401
                              tiny_joyai,
                              tiny_keye, tiny_kimilinear, tiny_lfm2,
                              tiny_mellum,               # join `tiny`'s table
-                             tiny_nemotronh, tiny_sdarmoe)
+                             tiny_nemotronh, tiny_ouro, tiny_sdarmoe)
 from byteps_tpu.common import devprof
 from byteps_tpu.ops import flash_attention as fa
 from byteps_tpu.ops import ssd
@@ -114,6 +114,12 @@ FAMILIES = {
                  "sdar.moe/route", "sdar.moe/gather", "sdar.moe/grouped",
                  "sdar.moe/scatter", "sdar.moe/exact", "sdar.head",
                  "byteps.optimizer"}, True),
+    "ouro": ("ouro-2.6b.ingraph-1chip",
+             {"ouro.embed", "ouro.attn.full_attention",
+              "ouro.attn.full_attention/qkv", "ouro.attn.full_attention/out",
+              "ouro.attn.full_attention/post_norm", "ouro.mlp",
+              "ouro.mlp/post_norm", "ouro.exit", "ouro.head",
+              "byteps.optimizer"}, True),
 }
 # Where a family's scopes start with another word than its name.
 SCOPE_PREFIX = {"kimilinear": "kimi", "sdarmoe": "sdar"}
@@ -152,6 +158,7 @@ KERNEL_SCOPES = {
                    "kimi.attn", *_moe("kimi.moe")},
     "sdarmoe": {"sdar.attn.block_diffusion",
                 "sdar.attn.block_diffusion/qkv/heads", *_moe("sdar.moe")},
+    "ouro": {"ouro.attn.full_attention"},
 }
 PRODUCTS = ("fusion", "custom-call", "dot", "convolution", "ragged-dot")
 WORK = ("dot_general", "conv_general_dilated", "pallas_call")
@@ -232,6 +239,14 @@ def _family(name: str):
     elif name == "sdarmoe":     # two of the six layers, all alike
         cell = dataclasses.replace(cell,
                                    config=tiny_sdarmoe.config(layers=[0, 1]))
+    elif name == "ouro":
+        # ONE of the eight layers, walked twice: with two the loop in a
+        # loop's own instructions (a slice of a layer's weights and a
+        # gradient's write into the stack a leaf, in BOTH loops, and at
+        # these widths a buffer in fast memory each) outnumber two
+        # layers' products, 84 of 209, under `_check`'s floors
+        cell = dataclasses.replace(
+            cell, config=tiny_ouro.config(layers=[0], walks=2))
     if name in ("afmoe", "mellum", "keye", "sdarmoe"):
         # the narrowest widths the grouped kernels tile: a lane tile each
         # (the tiny cuts' 64 and 32 go to `lax.ragged_dot`, the compiler's)

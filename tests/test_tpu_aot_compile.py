@@ -1453,3 +1453,82 @@ def test_sdar_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch):
     from byteps_tpu.models import sdar
     with pytest.raises(Exception, match="hbm"):
         compiled(lambda p, b: sdar.loss_fn(p, b, kept))
+
+
+# 13 s and 16 s alone: the form that ships is tier-1's, the other's
+# reading is kept as a slow case.
+@pytest.mark.parametrize("form", [
+    "flat", pytest.param("scan_of_walks", marks=pytest.mark.slow)])
+def test_ouro_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch, form):
+    """The ouro cell's step (`benchmark/configs/ouro-2.6b.json`: eight
+    layers walked four times, ONE sequence of 8,192 tokens, a plain
+    `value_and_grad` and adamw over 612,438,017 parameters) for one
+    described chip, in the loop's two forms (ISSUE 64: memory first).
+
+    `flat`, the form that ships (`ouro.walks`: one scan over the 32 layer
+    applications that reads layer `i mod 8`): arguments 7,349,323,776,
+    temporaries 6,888,796,160, peak 12,106,292,224 + 26,582,016 of code,
+    of 16.91e9.  `scan_of_walks` (a scan over the walks round
+    `afmoe.run_layers`, `benchmark/tests/ouro_variants.py`
+    `scan_of_walks`): temporaries
+    12,611,866,112, peak 14,850,430,464: the inner scan's transpose hands
+    the outer one a whole 1.64 GB stack of gradients a walk.  (Four calls
+    of `run_layers` one after another: 16 kernel calls, temporaries
+    14,491,262,976, peak 15,977,119,744; not kept as a case.)  On the chip
+    `flat` ran 1,397.5 ms a step and `scan_of_walks` 1,406-1,408 (PERF.md,
+    Findings, PR 64).  Either way the layer's program is there ONCE: the
+    flash forward kernel twice (whole-layer remat), dQ and dK/dV once."""
+    import json
+
+    import optax
+
+    from benchmark.families import ouro as family_ouro
+    from benchmark.harness import manifest
+    from benchmark.reduce import afmoe_cost
+    from benchmark.tests import ouro_variants
+    from byteps_tpu.models import ouro
+    monkeypatch.setattr(fa, "_use_interpret", lambda interpret: False)
+    if form == "scan_of_walks":
+        monkeypatch.setattr(ouro, "walks", ouro_variants.scan_of_walks)
+    with open(os.path.join(manifest.BENCH, "configs",
+                           "ouro-2.6b.json")) as f:
+        config = json.load(f)
+    family = family_ouro.Family(config, config["job"])
+    assert (family.cfg.num_layers, family.cfg.total_ut_steps) == (8, 4)
+    assert family.cfg.remat_policy == "none"
+    opt = family.optimizer()
+    one = SingleDeviceSharding(v5e[0])
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one), tree)
+    params = jax.eval_shape(family.init, jax.random.key(0))
+    opt_state = jax.eval_shape(opt.init, params)
+    batch = jax.eval_shape(lambda k: family.make_batch(k, 1),
+                           jax.random.key(0))
+
+    def step(params, opt_state, batch):
+        value, grads = jax.value_and_grad(family.loss)(params, batch)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, value
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        on_chip(params), on_chip(opt_state), on_chip(batch)).compile()
+    calls = [line.strip().removeprefix("ROOT ")
+             for line in compiled.as_text().splitlines()
+             if " custom-call(" in line and "tpu_custom_call" in line]
+    kinds = sorted(afmoe_cost.attention_call(c)[0] for c in calls)
+    assert kinds == ["dkv", "dq", "forward", "forward"], calls
+    # 16 ungrouped heads of 128 over 8,192 positions, no window
+    assert {afmoe_cost.attention_call(c)[1:] for c in calls} == {
+        (16, 8192, 128, None)}
+    mem = compiled.memory_analysis()
+    said = (f"{form}: arguments {mem.argument_size_in_bytes:,} temporaries "
+            f"{mem.temp_size_in_bytes:,} peak {mem.peak_memory_in_bytes:,} "
+            f"code {mem.generated_code_size_in_bytes:,}")
+    print(said)
+    assert (mem.peak_memory_in_bytes
+            + mem.generated_code_size_in_bytes) < 15.75 * 2 ** 30, said
+    if form == "flat":
+        assert mem.peak_memory_in_bytes <= 12_150_000_000, said
+    else:
+        assert mem.peak_memory_in_bytes >= 14_500_000_000, said

@@ -264,11 +264,22 @@ def _joyai_record(cell, out: str) -> int:
     return 0 if line["correct"] else 1
 
 
+def _ouro_extras(family, cell, params, seed, variant):
+    """What the two parts compared alone read (`benchmark/families/ouro.py`
+    `parts_disagreement`) and the exit gauges the check's batch set."""
+    import byteps_tpu as bps
+    out = {"parts": list(family.selection),
+           "exit": {k: v for k, v in bps.get_metrics().items()
+                    if k.startswith(("bps_exit_", "bps_loop_"))}}
+    del family.selection[:]
+    return out
+
+
 EXTRAS = {"afmoe": _expert_extras, "mellum": _expert_extras,
           "keye": _keye_extras, "nemotronh": _expert_extras,
           "joyai": _expert_extras, "lfm2": _expert_extras,
           "kimilinear": _expert_extras, "sdarmoe": _expert_extras,
-          "granitehybrid": _granitehybrid_extras}
+          "granitehybrid": _granitehybrid_extras, "ouro": _ouro_extras}
 RECORD = {"granitehybrid": _granitehybrid_record, "mellum": _mellum_record,
           "keye": _keye_record, "nemotronh": _nemotronh_record,
           "joyai": _joyai_record}
