@@ -681,18 +681,21 @@ _STATIC = {
     "head_norm_rope": {
         "kernel": _gauge(
             "bps_head_norm_rope_kernel",
-            "1 where the last traced attention half norms and turns its "
-            "queries and keys by the program's Pallas kernels "
-            "(ops/head_norm_rope.py head_norm_rope)"),
+            "1 where the last traced attention half norms and turns (or, "
+            "with no scale, only turns) its queries and keys by the "
+            "program's Pallas kernels (ops/head_norm_rope.py "
+            "head_norm_rope)"),
         "rows": _gauge(
             "bps_head_norm_rope_rows",
             "rows a grid step of the last traced call `call` (`fwd`, "
-            "`bwd`) of those kernels takes"),
+            "`bwd`; `turn_fwd`, `turn_bwd` for the turn alone) of those "
+            "kernels takes"),
         "bytes": _gauge(
             "bps_head_norm_rope_bytes",
             "bytes the last traced call `call` has to move: the heads "
             "read and written forward; the heads, their cotangent and "
-            "their gradient backward"),
+            "their gradient backward (the turn alone: the cotangent and "
+            "the gradient)"),
     },
     "sparse_attention": {
         "rows": _gauge(

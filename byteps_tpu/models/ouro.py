@@ -8,7 +8,11 @@ token leaves at.
     RMS-normed, four scales a layer: `x = x + rms(Attn(rms(x)))`, `x = x +
     rms(SwiGLU(rms(x)))`.  Attention is full and causal in every layer,
     rotary positions over the whole head (half-split, `rope_theta`), no
-    q / k norm, no bias, as many key-value heads as the config says.
+    q / k norm, no bias, as many key-value heads as the config says.  The
+    queries and keys are read where they lie in the projection's result
+    and turned by `ops/head_norm_rope.py` `queries_and_keys` with no scale
+    (PR 65): its kernels, the norm's term absent, where a head is whole
+    lane tiles over whole tiles of rows, else `transformer._rope`'s lines.
   - `total_ut_steps` walks: `h_0` the embedded tokens; walk t runs ALL
     the layers over `h_{t-1}` and norms the result with the final norm,
     INSIDE the loop: `h_t = rms(layers(h_{t-1}))`, and walk t + 1 reads
@@ -26,9 +30,10 @@ token leaves at.
 Why a module of its own: no other model of this package reads a
 parameter more than once a step.  What it shares is imported, not
 copied: the attention adapter `afmoe._attn_fn`, `afmoe._swiglu`,
-`afmoe._remat`, `transformer._rms_norm` and `_rope`, and the streamed
-head `transformer.fused_nll_sum`, which runs ONCE over the rows of all
-the walks with `p` as its per-row weights.  The parameter tree is one
+`afmoe._remat`, `transformer._rms_norm`, the rotary tables and the turn of
+`ops/head_norm_rope.py`, and the streamed head
+`transformer.fused_nll_sum`, which runs ONCE over the rows of all the
+walks with `p` as its per-row weights.  The parameter tree is one
 stacked group, `dense`, as `afmoe.run_layers` walks one (the config has
 what its plan reads, and the tests walk T copies of the stack with it).
 
@@ -57,11 +62,13 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..common import telemetry
+from ..ops import head_norm_rope
 from . import afmoe, transformer
 from .afmoe import FULL
-from .transformer import _rms_norm, _rope, fused_nll_sum
+from .transformer import _rms_norm, fused_nll_sum
 
 PyTree = Any
 
@@ -161,22 +168,41 @@ def num_params(cfg: OuroConfig) -> int:
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
-def _attention(x, lp, cfg: OuroConfig, kind: str):
-    """The attention half of a layer: x [B, S, D] -> x + norm(attn)."""
+def _rope_tables(seq_len: int, cfg: OuroConfig):
+    """`(cos, sin)` float32 [S, Dh / 2] that every layer application turns
+    its queries and keys by."""
+    return head_norm_rope.rope_tables(seq_len, cfg.head_dim, cfg.rope_theta)
+
+
+def _attention(x, lp, cfg: OuroConfig, kind: str, tables=None):
+    """The attention half of a layer: x [B, S, D] -> x + norm(attn).
+    `tables`: `_rope_tables`, which `walks` makes once for the whole loop
+    (made here, outside the half's scope, where a caller brings none)."""
     dt = cfg.dtype
     B, S, D = x.shape
     H, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     norm = functools.partial(_rms_norm, bias=None, eps=cfg.rms_norm_eps)
+    if tables is None:
+        tables = _rope_tables(S, cfg)
     with jax.named_scope(f"ouro.attn.{kind}"):
         with jax.named_scope(".qkv"):
             a = norm(x, lp["input_ln"])
             qkv = jnp.einsum("bsd,de->bse", a, lp["qkv_w"].astype(dt))
-            q, k, v = jnp.split(qkv, [H * Dh, (H + Hkv) * Dh], axis=-1)
-
-            def heads(t):
-                return t.reshape(B, S, -1, Dh).transpose(0, 2, 1, 3)
-            q, k, v = heads(q), heads(k), heads(v)
-            q, k = _rope(q, cfg.rope_theta), _rope(k, cfg.rope_theta)
+            if head_norm_rope.takes(S, Dh):
+                # the kernels read `qkv` row-major.  Left to itself the
+                # compiler lays the product's result S-minor, as v's
+                # transpose likes it, and copies it for them: 15 ms a step
+                # of the benchmark's cell, whose product is 7% faster
+                # row-major besides (PERF.md, Findings, PR 65)
+                qkv = with_layout_constraint(
+                    qkv, Layout(major_to_minor=(0, 1, 2)))
+            # q and k are read where they lie in `qkv` and turned, no head
+            # normed (no scale); v alone is sliced out
+            q, k = head_norm_rope.queries_and_keys(
+                qkv, None, None, *tables, eps=cfg.rms_norm_eps, heads=H,
+                kv_heads=Hkv)
+            v = qkv[..., (H + Hkv) * Dh:]
+            v = v.reshape(B, S, Hkv, Dh).transpose(0, 2, 1, 3)
             if Hkv != H:
                 k = jnp.repeat(k, H // Hkv, axis=1)
                 v = jnp.repeat(v, H // Hkv, axis=1)
@@ -198,11 +224,13 @@ def _mlp(x, lp, cfg: OuroConfig):
             return x + norm(f, lp["post_mlp_ln"])
 
 
-def _layer(x, lp, sel, cfg: OuroConfig, kind: str, is_moe: bool = False):
-    """One layer application, `afmoe.run_layers`' signature.  x [B, S, D];
-    returns `(x, None)`: no layer routes."""
+def _layer(x, lp, sel, cfg: OuroConfig, kind: str, is_moe: bool = False,
+           tables=None):
+    """One layer application, `afmoe.run_layers`' signature and the
+    rotary `tables` of `_attention`.  x [B, S, D]; returns `(x, None)`: no
+    layer routes."""
     del sel, is_moe
-    return _mlp(_attention(x, lp, cfg, kind), lp, cfg), None
+    return _mlp(_attention(x, lp, cfg, kind, tables), lp, cfg), None
 
 
 def _embed(params, tokens, cfg: OuroConfig):
@@ -232,6 +260,9 @@ def walks(params: PyTree, tokens: jax.Array, cfg: OuroConfig):
     into the stack where it lies."""
     L, T = cfg.num_layers, cfg.total_ut_steps
     group = params["dense"]
+    # once, outside the loop and every scope: 32 applications' three
+    # passes read the one pair
+    tables = _rope_tables(tokens.shape[1], cfg)
 
     def end_of_walk(u):
         with jax.named_scope("ouro.exit"):
@@ -242,7 +273,7 @@ def walks(params: PyTree, tokens: jax.Array, cfg: OuroConfig):
         lp = jax.tree.map(
             lambda a: lax.dynamic_index_in_dim(a, i % L, keepdims=False),
             group)
-        x, _ = _layer(x, lp, None, cfg, FULL)
+        x, _ = _layer(x, lp, None, cfg, FULL, tables=tables)
         return lax.cond(i % L == L - 1, end_of_walk, lambda u: u, x)
     application = afmoe._remat(application, cfg)
 

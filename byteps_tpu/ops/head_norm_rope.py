@@ -14,7 +14,9 @@ and gives them as the flash adapter takes them:
 [B, S, Dh / 2], that the CALLER makes (`rope_tables`, the lines of
 `models/transformer.py` `_rope`: plain `theta`, frequencies of the
 caller's own, an amplitude, the rows' positions); `None` for a layer that
-norms and does not turn.
+norms and does not turn.  `scale` is `None` for a model that norms no head
+(`models/ouro.py`): the same walk with the norm's term absent, the TURN
+ALONE, `y = x`.
 
 Why a kernel.  As jnp operations the three steps are a split of the
 projection's result (a copy), a transpose to [B, H, S, Dh], the norm in
@@ -41,7 +43,9 @@ root made again:
 outside the call, which the compiler fuses with the sum of `t`'s other
 cotangents: the concatenate the split's transpose was), `dscale` summed
 over the sequential grid in one [8, Dh] float32 block, as
-`ops/short_conv.py`'s `dw` is.
+`ops/short_conv.py`'s `dw` is.  The turn alone is linear, `dx = dy`: its
+backward call reads the cotangent and the tables and NOTHING of `t`, and
+has no `dscale`.
 
 How.  The grid is (sequence, row block, group of heads), the group
 innermost so that a block of the tables is fetched once for every head of
@@ -60,9 +64,10 @@ says the shapes fit them (`Dh` whole lane tiles, whole row blocks), else
 harness's short operands, a 64-lane head) and the tests' oracle.  The
 rule reads shapes alone: no option, no environment variable.
 
-The calls name themselves `head_norm_rope_fwd` / `head_norm_rope_bwd`,
-sit under a plain `jax.jit` (one traced and lowered body a shape and
-process) and run in the Pallas interpreter off the TPU.
+The calls name themselves `head_norm_rope_fwd` / `head_norm_rope_bwd`
+in either form (the gauges' `call` label tells a turn alone, `turn_fwd` /
+`turn_bwd`), sit under a plain `jax.jit` (one traced and lowered body a
+shape and process) and run in the Pallas interpreter off the TPU.
 """
 
 from __future__ import annotations
@@ -115,9 +120,21 @@ def rope_tables(seq_len: int, head_dim: int, theta: float, inv_freq=None,
     return cos, sin
 
 
+def _head_dim(scale, cos) -> int:
+    """A head's width: the scale's, or twice the tables' where no scale is
+    given (the turn alone); 0 where neither says."""
+    if scale is not None:
+        return scale.shape[-1]
+    return 0 if cos is None else 2 * cos.shape[-1]
+
+
 def _check(t, scale, cos, sin, first, heads):
-    head_dim = scale.shape[-1]
-    ok = (t.ndim == 3 and scale.ndim == 1 and head_dim % 2 == 0
+    """`(Dh, heads)` of operands that fit."""
+    head_dim = _head_dim(scale, cos)
+    if heads is None and head_dim:
+        heads = t.shape[-1] // head_dim - first
+    ok = (t.ndim == 3 and head_dim > 0 and head_dim % 2 == 0
+          and (scale is None or scale.ndim == 1)
           and heads >= 1 and first >= 0
           and (first + heads) * head_dim <= t.shape[-1]
           and (cos is None) == (sin is None))
@@ -126,24 +143,27 @@ def _check(t, scale, cos, sin, first, heads):
             (t.shape[1], head_dim // 2), (*t.shape[:2], head_dim // 2))
     if not ok:
         raise ValueError(
-            f"head_norm_rope: t {t.shape}, scale {scale.shape}, heads "
-            f"{first} .. {first + heads} and tables "
+            f"head_norm_rope: t {t.shape}, scale "
+            f"{getattr(scale, 'shape', None)}, heads {first} .. "
+            f"{first + (heads or 0)} and tables "
             f"{[getattr(v, 'shape', None) for v in (cos, sin)]} do not fit")
-    return head_dim
+    return head_dim, heads
 
 
 def normed_and_turned_jnp(t, scale, cos, sin, *, eps: float, first: int = 0,
                           heads: Optional[int] = None):
     """The operator as the models ran it until PR 62: a slice, a transpose,
     `_rms_norm` (float32, rounded to the activations' dtype), `_rope`'s
-    turn (float32 again, two half-width slices and a concatenate)."""
-    heads = t.shape[-1] // scale.shape[-1] - first if heads is None else heads
-    Dh = _check(t, scale, cos, sin, first, heads)
+    turn (float32 again, two half-width slices and a concatenate); with no
+    `scale` the slice, the transpose and the turn."""
+    Dh, heads = _check(t, scale, cos, sin, first, heads)
     B, S, _ = t.shape
     x = t[..., first * Dh:(first + heads) * Dh]
-    x32 = x.reshape(B, S, heads, Dh).transpose(0, 2, 1, 3).astype(_F32)
-    y = x32 * lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
-    y = (y * scale.astype(_F32)).astype(t.dtype)
+    y = x.reshape(B, S, heads, Dh).transpose(0, 2, 1, 3)
+    if scale is not None:
+        x32 = y.astype(_F32)
+        y = x32 * lax.rsqrt((x32 * x32).mean(-1, keepdims=True) + eps)
+        y = (y * scale.astype(_F32)).astype(t.dtype)
     if cos is None:
         return y
     if cos.ndim == 3:
@@ -180,7 +200,9 @@ def _whole(cos_ref, sin_ref, cos2_ref, sin2_ref):
         sin2_ref[...] = jnp.concatenate([-sin, sin], axis=1)
 
 
-def _fwd_kernel(t_ref, scale_ref, *refs, eps, turned):
+def _fwd_kernel(t_ref, *refs, eps, normed, turned):
+    if normed:
+        scale_ref, *refs = refs
     if turned:
         cos_ref, sin_ref, o_ref, cos2_ref, sin2_ref = refs
         _whole(cos_ref, sin_ref, cos2_ref, sin2_ref)
@@ -189,8 +211,9 @@ def _fwd_kernel(t_ref, scale_ref, *refs, eps, turned):
     Dh = o_ref.shape[-1]
 
     def body(h):
-        n, _ = _norm(t_ref[:, _lanes(h, Dh)].astype(_F32), eps)
-        y = n * scale_ref[...]
+        y = t_ref[:, _lanes(h, Dh)].astype(_F32)
+        if normed:
+            y = _norm(y, eps)[0] * scale_ref[...]
         if turned:
             y = y * cos2_ref[...] + _half_turn(y) * sin2_ref[...]
         o_ref[0, h] = y.astype(o_ref.dtype)
@@ -198,24 +221,35 @@ def _fwd_kernel(t_ref, scale_ref, *refs, eps, turned):
     _over_chunks(o_ref.shape[1], body)
 
 
-def _bwd_kernel(t_ref, scale_ref, do_ref, *refs, eps, turned):
-    if turned:
-        cos_ref, sin_ref, dt_ref, ds_ref, cos2_ref, sin2_ref = refs
-        _whole(cos_ref, sin_ref, cos2_ref, sin2_ref)
+def _bwd_kernel(*refs, eps, normed, turned):
+    """Operands `t` and `scale` (normed), `do`, the tables (turned);
+    results `dt` and `ds` (normed); the tables' scratch (turned)."""
+    if normed:
+        t_ref, scale_ref, do_ref, *refs = refs
     else:
-        dt_ref, ds_ref = refs
+        do_ref, *refs = refs
+    if turned:
+        cos_ref, sin_ref, *refs, cos2_ref, sin2_ref = refs
+        _whole(cos_ref, sin_ref, cos2_ref, sin2_ref)
+    dt_ref, *ds_ref = refs
     rows, Dh = do_ref.shape[2:]
 
-    @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0)
-             & (pl.program_id(2) == 0))
-    def _():
-        ds_ref[...] = jnp.zeros_like(ds_ref)
+    if normed:
+        ds_ref, = ds_ref
+
+        @pl.when((pl.program_id(0) == 0) & (pl.program_id(1) == 0)
+                 & (pl.program_id(2) == 0))
+        def _():
+            ds_ref[...] = jnp.zeros_like(ds_ref)
 
     def body(h):
         lanes = _lanes(h, Dh)
         dy = do_ref[0, h].astype(_F32)
         if turned:
             dy = dy * cos2_ref[...] + _half_turn(dy * sin2_ref[...])
+        if not normed:          # the turn alone is linear: dx = dy
+            dt_ref[:, lanes] = dy.astype(dt_ref.dtype)
+            return
         n, r = _norm(t_ref[:, lanes].astype(_F32), eps)
         dn = dy * scale_ref[...]
         dx = r * (dn - n * jnp.mean(dn * n, axis=-1, keepdims=True))
@@ -250,13 +284,11 @@ def takes(seq_len: int, head_dim: int) -> bool:
     return head_dim % LANES == 0 and seq_len % LANES == 0
 
 
-def _plan(t, scale, cos, first, heads, block_rows):
+def _plan(B, S, Dh, cos, first, heads, block_rows):
     """The grid and the specs both calls share: `rows` a step and `group`
     heads; `stretch` the heads where they lie in `t` [B S, W], `own` the
     same block of an array of these heads alone, `by_head` of
     [B, heads, S, Dh], `one` the scale, `table` a row block of a table."""
-    B, S, _ = t.shape
-    Dh = scale.shape[-1]
     rows, g = _rows(S, block_rows), _group(first, heads, Dh)
     blocks = S // rows
     per_sequence = cos is not None and cos.ndim == 3
@@ -289,13 +321,12 @@ def _params(nbytes: int):
         vmem_limit_bytes=nbytes + _VMEM_MARGIN)
 
 
-def _record(call: str, t, rows: int, heads: int, head_dim: int,
-            arrays: int) -> None:
+def _record(call: str, normed: bool, rows: int, nbytes: int) -> None:
+    """`nbytes`: the heads, once an array the call reads or writes."""
     telemetry.record_static("head_norm_rope", kernel=1)
     telemetry.record_static(
-        "head_norm_rope", labels={"call": call}, rows=rows,
-        bytes=arrays * t.shape[0] * t.shape[1] * heads * head_dim
-        * t.dtype.itemsize)
+        "head_norm_rope", labels={"call": call if normed else f"turn_{call}"},
+        rows=rows, bytes=nbytes)
 
 
 @functools.partial(jax.jit, static_argnames=("first", "heads", "eps",
@@ -303,14 +334,16 @@ def _record(call: str, t, rows: int, heads: int, head_dim: int,
 def _fwd_call(t, scale, cos, sin, *, first, heads, eps, block_rows,
               interpret):
     B, S, W = t.shape
-    Dh = scale.shape[-1]
-    p = _plan(t, scale, cos, first, heads, block_rows)
+    normed, Dh = scale is not None, _head_dim(scale, cos)
+    p = _plan(B, S, Dh, cos, first, heads, block_rows)
     tables, scratch = _tables(cos, sin, p.rows)
-    _record("fwd", t, p.rows, heads, Dh, 2)
+    _record("fwd", normed, p.rows,
+            2 * B * S * heads * Dh * t.dtype.itemsize)
     return pl.pallas_call(
-        functools.partial(_fwd_kernel, eps=eps, turned=bool(tables)),
+        functools.partial(_fwd_kernel, eps=eps, normed=normed,
+                          turned=bool(tables)),
         grid=p.grid,
-        in_specs=[p.stretch, p.one, *[p.table] * len(tables)],
+        in_specs=[p.stretch, *[p.one] * normed, *[p.table] * len(tables)],
         out_specs=p.by_head,
         out_shape=jax.ShapeDtypeStruct((B, heads, S, Dh), t.dtype),
         scratch_shapes=scratch,
@@ -318,32 +351,42 @@ def _fwd_call(t, scale, cos, sin, *, first, heads, eps, block_rows,
             2 * p.rows * (2 * t.dtype.itemsize * p.group + 4) * Dh
             + 10 * 4 * p.rows * Dh),
         interpret=interpret, name=FWD_NAME,
-    )(t.reshape(B * S, W), scale.astype(_F32).reshape(1, Dh), *tables)
+    )(t.reshape(B * S, W),
+      *([scale.astype(_F32).reshape(1, Dh)] if normed else []), *tables)
 
 
 @functools.partial(jax.jit, static_argnames=("first", "heads", "eps",
                                              "block_rows", "interpret"))
 def _bwd_call(t, scale, cos, sin, do, *, first, heads, eps, block_rows,
               interpret):
-    B, S, W = t.shape
-    Dh = scale.shape[-1]
-    p = _plan(t, scale, cos, first, heads, block_rows)
+    """`(dt [B S, heads Dh], dscale [8, Dh] float32)` of a normed call
+    from `t`, `scale`, the tables and the cotangent; `(dt,)` of a turn
+    alone from the tables and the cotangent (`t` and `scale` None)."""
+    B, _, S, Dh = do.shape
+    normed = scale is not None
+    p = _plan(B, S, Dh, cos, first, heads, block_rows)
     tables, scratch = _tables(cos, sin, p.rows)
-    _record("bwd", t, p.rows, heads, Dh, 3)
+    _record("bwd", normed, p.rows,
+            (3 if normed else 2) * B * S * heads * Dh * do.dtype.itemsize)
+    dt = (p.own, jax.ShapeDtypeStruct((B * S, heads * Dh), do.dtype))
+    ds = (pl.BlockSpec((FOLD, Dh), lambda b, i, j: (0, 0)),
+          jax.ShapeDtypeStruct((FOLD, Dh), _F32))
+    results = (dt, ds) if normed else (dt,)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, eps=eps, turned=bool(tables)),
+        functools.partial(_bwd_kernel, eps=eps, normed=normed,
+                          turned=bool(tables)),
         grid=p.grid,
-        in_specs=[p.stretch, p.one, p.by_head, *[p.table] * len(tables)],
-        out_specs=[p.own,
-                   pl.BlockSpec((FOLD, Dh), lambda b, i, j: (0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((B * S, heads * Dh), t.dtype),
-                   jax.ShapeDtypeStruct((FOLD, Dh), _F32)],
+        in_specs=[*([p.stretch, p.one] if normed else []), p.by_head,
+                  *[p.table] * len(tables)],
+        out_specs=[spec for spec, _ in results],
+        out_shape=[shape for _, shape in results],
         scratch_shapes=scratch,
         compiler_params=_params(
-            2 * p.rows * (3 * t.dtype.itemsize * p.group + 4) * Dh
+            2 * p.rows * (3 * do.dtype.itemsize * p.group + 4) * Dh
             + 18 * 4 * p.rows * Dh),
         interpret=interpret, name=BWD_NAME,
-    )(t.reshape(B * S, W), scale.astype(_F32).reshape(1, Dh), do, *tables)
+    )(*((t.reshape(B * S, -1), scale.astype(_F32).reshape(1, Dh))
+        if normed else ()), do, *tables)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
@@ -353,36 +396,42 @@ def _core(t, scale, cos, sin, first, heads, eps, block_rows, interpret):
 
 
 def _core_fwd(t, scale, cos, sin, first, heads, eps, block_rows, interpret):
+    # a turn alone is linear: of `t` its backward pass wants the width and
+    # no value, which a slice of no rows carries
     return (_core(t, scale, cos, sin, first, heads, eps, block_rows,
-                  interpret), (t, scale, cos, sin))
+                  interpret),
+            (t if scale is not None else t[:, :0], scale, cos, sin))
 
 
 def _core_bwd(first, heads, eps, block_rows, interpret, residuals, do):
     t, scale, cos, sin = residuals
-    dt, ds = _bwd_call(t, scale, cos, sin, do, first=first, heads=heads,
-                       eps=eps, block_rows=block_rows, interpret=interpret)
-    Dh = scale.shape[-1]
+    normed = scale is not None
+    dt, *ds = _bwd_call(t if normed else None, scale, cos, sin, do,
+                        first=first, heads=heads, eps=eps,
+                        block_rows=block_rows, interpret=interpret)
+    B, _, S, Dh = do.shape
     before, after = first * Dh, t.shape[-1] - (first + heads) * Dh
-    dt = jnp.pad(dt.reshape(*t.shape[:2], heads * Dh),
+    dt = jnp.pad(dt.reshape(B, S, heads * Dh),
                  ((0, 0), (0, 0), (before, after)))
     # the tables come of positions and constants: nothing flows to them
-    return dt, ds.sum(0).astype(scale.dtype), None, None
+    return (dt, ds[0].sum(0).astype(scale.dtype) if normed else None,
+            None, None)
 
 
 _core.defvjp(_core_fwd, _core_bwd)
 
 
-def head_norm_rope(t: jax.Array, scale: jax.Array, cos=None, sin=None, *,
-                   eps: float, first: int = 0, heads: Optional[int] = None,
-                   block_rows: int = 0,
+def head_norm_rope(t: jax.Array, scale: Optional[jax.Array], cos=None,
+                   sin=None, *, eps: float, first: int = 0,
+                   heads: Optional[int] = None, block_rows: int = 0,
                    interpret: Optional[bool] = None) -> jax.Array:
     """The module's docstring's `out`, by the kernels whatever the shapes
-    are: `t` [B, S, W], `scale` [Dh], `cos` / `sin` [S, Dh / 2] or
-    [B, S, Dh / 2] or both None, heads `first .. first + heads` of the
-    width (to its end where `heads` is None) -> [B, heads, S, Dh] in `t`'s
-    dtype; differentiable in `t` and `scale`."""
-    heads = t.shape[-1] // scale.shape[-1] - first if heads is None else heads
-    _check(t, scale, cos, sin, first, heads)
+    are: `t` [B, S, W], `scale` [Dh] or None (the turn alone, which wants
+    the tables), `cos` / `sin` [S, Dh / 2] or [B, S, Dh / 2] or both None,
+    heads `first .. first + heads` of the width (to its end where `heads`
+    is None) -> [B, heads, S, Dh] in `t`'s dtype; differentiable in `t`
+    and `scale`."""
+    _, heads = _check(t, scale, cos, sin, first, heads)
     return _core(t, scale, cos, sin, first, heads, float(eps),
                  block_rows or BLOCK_ROWS,
                  flash_attention._use_interpret(interpret))
@@ -392,12 +441,13 @@ def queries_and_keys(t, q_scale, k_scale, cos=None, sin=None, *, eps: float,
                      heads: int, kv_heads: int):
     """What a model's `_qkv` calls on its projection's result
     `[q | k | ...]`: `(q [B, heads, S, Dh], k [B, kv_heads, S, Dh])`,
-    each read where it lies in `t`, normed under its own scale and turned
-    by the one pair of tables; by `head_norm_rope` where `takes` says so,
-    else by the jnp form.  Under the relative scope `.heads`, a child of
-    the caller's `qkv` (the benchmark's `qk_heads.*` read it; the tables
-    are made outside it, by the caller)."""
-    form = (head_norm_rope if takes(t.shape[1], q_scale.shape[-1])
+    each read where it lies in `t`, normed under its own scale (both None:
+    not normed, and `eps` idle) and turned by the one pair of tables; by
+    `head_norm_rope` where `takes` says so, else by the jnp form.  Under
+    the relative scope `.heads`, a child of the caller's `qkv` (the
+    benchmark's `qk_heads.*` read it; the tables are made outside it, by
+    the caller)."""
+    form = (head_norm_rope if takes(t.shape[1], _head_dim(q_scale, cos))
             else normed_and_turned_jnp)
     with jax.named_scope(".heads"):
         return (form(t, q_scale, cos, sin, eps=eps, first=0, heads=heads),

@@ -4,12 +4,15 @@ of L layers walked T times is a T x L-layer model built from T copies of
 the same weights, and a shared leaf's gradient the SUM of the copies'),
 the exit distribution, the streamed head over the walks' rows against the
 plain one, the gate's gradient, the counters and what the configuration
-refuses.  The program against its plain float32 reference
+refuses; the queries' and keys' rotary turn on `ops/head_norm_rope.py`'s
+kernels against the jnp form, and the parent's program where the rule of
+shapes says no.  The program against its plain float32 reference
 (`benchmark/reference/ouro.py`), loss and every gradient leaf:
 `test_ouro_reference.py` and the unbroken case of
 `test_ouro_variants.py`."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -20,8 +23,9 @@ from benchmark.families import ouro as family_ouro
 from benchmark.harness import manifest
 from benchmark.tests import ouro_variants, tiny_ouro
 from byteps_tpu.models import afmoe, ouro
-from byteps_tpu.models.transformer import _rms_norm
+from byteps_tpu.models.transformer import _rms_norm, _rope
 from family_cases import Cases
+from testutil import qk_kernels_train_as_the_jnp_form_did
 
 CASES = Cases(tiny_ouro, family_ouro.Family)
 CELL = "ouro-2.6b.ingraph-1chip"
@@ -223,6 +227,65 @@ def test_the_counters(small):
             float(counters["share"][t]))
         assert metrics[f'bps_loop_nll{{step="{t + 1}"}}'] == pytest.approx(
             float(counters["nll"][t]))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_turn_kernels_train_the_model_the_jnp_form_did(monkeypatch,
+                                                           dtype):
+    """A layer walked twice at heads of 128 over 128 rows, its queries and
+    keys turned by `ops/head_norm_rope.py`'s kernels (no scale: the turn
+    alone), against the same program on the jnp form: float32 to rounding;
+    in bfloat16 no further from the float32 program than the jnp form."""
+    qk_kernels_train_as_the_jnp_form_did(
+        lambda dtype: CASES.family(dtype, layers=[0], walks=2), monkeypatch,
+        dtype)
+
+
+def _parents_queries_and_keys(t, q_scale, k_scale, cos, sin, *, eps, heads,
+                              kv_heads, theta):
+    """`models/ouro.py` `_attention`'s lines for q and k until PR 65: the
+    projection's result split, laid out by head, turned by
+    `transformer._rope`, which makes its tables itself from `theta`."""
+    assert q_scale is None and k_scale is None
+    B, S, _ = t.shape
+    Dh = 2 * cos.shape[-1]
+    q, k, _ = jnp.split(t, [heads * Dh, (heads + kv_heads) * Dh], axis=-1)
+
+    def by_head(x):
+        return x.reshape(B, S, -1, Dh).transpose(0, 2, 1, 3)
+    return _rope(by_head(q), theta), _rope(by_head(k), theta)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("head_dim,seq_len", [(64, 128), (128, 96)],
+                         ids=["a_head_of_64", "a_ragged_sequence"])
+def test_where_the_rule_says_no_the_program_is_the_parents(
+        monkeypatch, head_dim, seq_len, dtype):
+    """A head of half a lane tile, or a sequence of no whole tiles of
+    rows: no kernel is called, and loss and every gradient are, bit for
+    bit, those of the program whose queries and keys go through
+    `transformer._rope` as they did."""
+    import byteps_tpu as bps
+    from byteps_tpu.common import telemetry
+    from byteps_tpu.ops import head_norm_rope
+    family = CASES.family(dtype, layers=[0], walks=2)
+    # (the flash adapter wants whole tiles of rows too: dense attention)
+    cfg = family.cfg = dataclasses.replace(
+        family.cfg, head_dim=head_dim,
+        attn_impl="flash" if seq_len % 128 == 0 else "dense")
+    params = family.init(jax.random.key(0))
+    batch = ouro.synthetic_batch(jax.random.key(1), 1, seq_len, cfg)
+    telemetry.record_static("head_norm_rope", kernel=0)
+    got = jax.jit(jax.value_and_grad(family.loss))(params, batch)
+    assert bps.get_metrics()["bps_head_norm_rope_kernel"] == 0
+    monkeypatch.setattr(head_norm_rope, "queries_and_keys", functools.partial(
+        _parents_queries_and_keys, theta=cfg.rope_theta))
+    want = jax.jit(jax.value_and_grad(family.loss))(params, batch)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
 
 
 def test_a_batch_and_what_the_configuration_refuses():

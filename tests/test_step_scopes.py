@@ -158,7 +158,8 @@ KERNEL_SCOPES = {
                    "kimi.attn", *_moe("kimi.moe")},
     "sdarmoe": {"sdar.attn.block_diffusion",
                 "sdar.attn.block_diffusion/qkv/heads", *_moe("sdar.moe")},
-    "ouro": {"ouro.attn.full_attention"},
+    "ouro": {"ouro.attn.full_attention",
+             "ouro.attn.full_attention/qkv/heads"},
 }
 PRODUCTS = ("fusion", "custom-call", "dot", "convolution", "ragged-dot")
 WORK = ("dot_general", "conv_general_dilated", "pallas_call")
@@ -247,6 +248,10 @@ def _family(name: str):
         # layers' products, 84 of 209, under `_check`'s floors
         cell = dataclasses.replace(
             cell, config=tiny_ouro.config(layers=[0], walks=2))
+        # heads of a lane tile over the tiny cell's 128 rows, which
+        # `ops/head_norm_rope.py`'s rule sends to its kernels (PR 65: the
+        # turn alone, no head normed)
+        cell.config["published"].update(head_dim=128)
     if name in ("afmoe", "mellum", "keye", "sdarmoe"):
         # the narrowest widths the grouped kernels tile: a lane tile each
         # (the tiny cuts' 64 and 32 go to `lax.ragged_dot`, the compiler's)
@@ -409,11 +414,11 @@ def test_every_familys_tiny_step_is_mapped(name, v5e, kernels,
         assert all(e["scope"].endswith(".gate_norm")
                    and e["op_name"].endswith("/pallas_call")
                    for e in norms.values())
-    if name in ("afmoe", "mellum", "sdarmoe"):
-        # the queries' and keys' norm and turn are the program's kernel in
-        # every pass (PR 62), under the scope `qk_heads.ms_per_step` and
-        # `qk_heads.kernel_share` read, a child of `qkv`
-        # (`attn.around_kernel_ms` counts it)
+    if name in ("afmoe", "mellum", "sdarmoe", "ouro"):
+        # the queries' and keys' norm and turn (ouro, PR 65: the turn
+        # alone) are the program's kernel in every pass (PR 62), under the
+        # scope `qk_heads.ms_per_step` and `qk_heads.kernel_share` read, a
+        # child of `qkv` (`attn.around_kernel_ms` counts it)
         heads = {n: e for n, e in kernels.items()
                  if n.startswith("head_norm_rope_")}
         assert {(n.split(".")[0], e["pass"]) for n, e in heads.items()} == {

@@ -1001,7 +1001,9 @@ def test_gated_norm_compiles_at_the_cells_shapes(v5e, cell, seq_len, groups,
     ("mellum_q", 4, 8192, 5120, 0, 32, "rows"),
     ("trinity_k_sliding", 4, 8192, 9216, 32, 4, "rows"),
     ("trinity_q_full", 4, 8192, 9216, 0, 32, None),
-    ("positions_a_sequence", 4, 8192, 5120, 32, 4, "sequences")])
+    ("positions_a_sequence", 4, 8192, 5120, 32, 4, "sequences"),
+    ("ouro_q_turn_alone", 1, 8192, 6144, 0, 16, "rows"),
+    ("ouro_k_turn_alone", 1, 8192, 6144, 16, 16, "rows")])
 def test_head_norm_rope_compiles_at_the_cells_shapes(
         v5e, cell, batch, seq_len, width, first, heads, tables):
     """`ops/head_norm_rope.py` `head_norm_rope` on the projection's whole
@@ -1010,7 +1012,11 @@ def test_head_norm_rope_compiles_at_the_cells_shapes(
     tables a row, a row and sequence, or none), forward and backward: two
     Mosaic calls under their names, the forward result 4-D and the
     backward ones 2-D, and no reader of the benchmark's takes either for a
-    kernel of its own; no float32 copy of the heads beside them."""
+    kernel of its own; no float32 copy of the heads beside them.  The ouro
+    cell's calls (PR 65: 16 query heads and 16 key heads behind them, NO
+    scale: the turn alone) the same, their backward call given the
+    cotangent and the tables and no operand as wide as the projection's
+    result."""
     from benchmark.reduce import (afmoe_cost, conv_cost, flash_cost,
                                   kda_cost, mla_cost, ssd_cost)
     from byteps_tpu.ops import head_norm_rope
@@ -1023,10 +1029,12 @@ def test_head_norm_rope_compiles_at_the_cells_shapes(
              "sequences": (shape(batch, seq_len, 64, dtype=jnp.float32),) * 2
              }[tables]
 
+    normed = not cell.endswith("turn_alone")
+
     def both(t, scale, g, *cs):
         y, vjp = jax.vjp(lambda t, scale: head_norm_rope.head_norm_rope(
-            t, scale, *cs, eps=1e-6, first=first, heads=heads,
-            interpret=False), t, scale)
+            t, scale if normed else None, *cs, eps=1e-6, first=first,
+            heads=heads, interpret=False), t, scale)
         return y, vjp(g)
     compiled = _compile(both, shape(batch, seq_len, width), shape(128),
                         shape(batch, heads, seq_len, 128), *table)
@@ -1046,6 +1054,8 @@ def test_head_norm_rope_compiles_at_the_cells_shapes(
         assert not afmoe_cost.attention_call(call)
         results = call.split(" custom-call(")[0]
         assert not re.search(r"\[\d+,\d+,\d+\]", results), results
+        if not normed and call.startswith("%head_norm_rope_bwd"):
+            assert f"{width}]" not in call.split("custom_call_target")[0]
     rows = batch * seq_len
     assert not re.search(rf"f32\[({rows}|{batch},{seq_len}),"
                          rf"({heads * 128}|{width})\]", text)
@@ -1477,7 +1487,19 @@ def test_ouro_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch, form):
     14,491,262,976, peak 15,977,119,744; not kept as a case.)  On the chip
     `flat` ran 1,397.5 ms a step and `scan_of_walks` 1,406-1,408 (PERF.md,
     Findings, PR 64).  Either way the layer's program is there ONCE: the
-    flash forward kernel twice (whole-layer remat), dQ and dK/dV once."""
+    flash forward kernel twice (whole-layer remat), dQ and dK/dV once.
+
+    Since PR 65 the queries and keys are read where they lie in the
+    projection's result and turned by `ops/head_norm_rope.py`'s kernels
+    with no scale: in the described chip's program the forward call four
+    times (q and k, the pass and its recompute) and the backward call
+    twice, none of them a flash call to the benchmark's cost, and the
+    compiled peak no higher than the parent's 12,106,292,224 (`flat`:
+    11,942,721,024 now; `scan_of_walks` 14,686,871,040).
+    The projection's result is pinned row-major, as the kernels read it:
+    no layout copy of it stands between the product and the calls (the
+    compiler laid it S-minor and copied it twice a layer application, 15
+    ms a step on the chip: PERF.md, Findings, PR 65)."""
     import json
 
     import optax
@@ -1513,9 +1535,17 @@ def test_ouro_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch, form):
         return optax.apply_updates(params, updates), opt_state, value
     compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
         on_chip(params), on_chip(opt_state), on_chip(batch)).compile()
+    text = compiled.as_text()
+    assert not re.search(r"%copy[\w.]* = bf16\[(1,)?8192,6144\]", text)
     calls = [line.strip().removeprefix("ROOT ")
-             for line in compiled.as_text().splitlines()
+             for line in text.splitlines()
              if " custom-call(" in line and "tpu_custom_call" in line]
+    turns = [c for c in calls if c.startswith("%head_norm_rope_")]
+    assert sorted(c.split(" = ")[0].lstrip("%").split(".")[0]
+                  for c in turns) == (["head_norm_rope_bwd"] * 2
+                                      + ["head_norm_rope_fwd"] * 4)
+    assert not any(afmoe_cost.attention_call(c) for c in turns)
+    calls = [c for c in calls if c not in turns]
     kinds = sorted(afmoe_cost.attention_call(c)[0] for c in calls)
     assert kinds == ["dkv", "dq", "forward", "forward"], calls
     # 16 ungrouped heads of 128 over 8,192 positions, no window
@@ -1529,6 +1559,6 @@ def test_ouro_train_step_compiles_at_the_cells_shapes(v5e, monkeypatch, form):
     assert (mem.peak_memory_in_bytes
             + mem.generated_code_size_in_bytes) < 15.75 * 2 ** 30, said
     if form == "flat":
-        assert mem.peak_memory_in_bytes <= 12_150_000_000, said
+        assert mem.peak_memory_in_bytes <= 12_106_292_224, said
     else:
         assert mem.peak_memory_in_bytes >= 14_500_000_000, said
